@@ -646,7 +646,7 @@ def test_tenancy_config_parsing_and_validation():
             load_config(bad)
 
 
-# -- bench_matrix satellite: stale rows + rc=3 preservation -------------------
+# -- bench_matrix satellite: stale rows ---------------------------------------
 
 
 def test_merge_matrix_marks_legacy_rows_stale_true(tmp_path, monkeypatch):
@@ -654,7 +654,6 @@ def test_merge_matrix_marks_legacy_rows_stale_true(tmp_path, monkeypatch):
 
     mfile = tmp_path / "m.json"
     monkeypatch.setattr(bench, "MATRIX_FILE", str(mfile))
-    monkeypatch.setattr(bench, "_MATRIX_PREIMAGE", None)
     monkeypatch.setenv("BENCH_GATE", "0")
     mfile.write_text(json.dumps({
         "legacy_tpu": {"qps": 5.0},                      # pre-provenance
@@ -665,65 +664,3 @@ def test_merge_matrix_marks_legacy_rows_stale_true(tmp_path, monkeypatch):
     assert "stale_note" in data["legacy_tpu"]
     assert data["legacy_tpu"]["backend"] == "tpu-v5e"
     assert "stale" not in data["live_cpu"]
-
-
-def test_rc3_unreachable_exit_never_overwrites_live_rows(tmp_path,
-                                                         monkeypatch):
-    """The preimage restore: a session that overwrote a live row and then
-    hit the rc=3 unreachable-device exit puts the live row back; rows it
-    newly ADDED survive (they were measured before the device died)."""
-    import bench
-
-    mfile = tmp_path / "m.json"
-    monkeypatch.setattr(bench, "MATRIX_FILE", str(mfile))
-    monkeypatch.setattr(bench, "_MATRIX_PREIMAGE", None)
-    monkeypatch.setenv("BENCH_GATE", "0")
-    live = {"backend": "tpu-v5e", "round": 6, "qps": 777.0}
-    stale = {"backend": "tpu-v5e", "round": 2, "stale": True, "qps": 1.0}
-    mfile.write_text(json.dumps({"headline_tpu": live,
-                                 "old_tpu": stale}))
-    bench._merge_matrix({
-        "headline_tpu": {"backend": "tpu-v5e", "round": 7, "qps": 3.0},
-        "fresh_row": {"backend": "tpu-v5e", "round": 7, "qps": 9.0},
-        "old_tpu": {"backend": "tpu-v5e", "round": 7, "qps": 8.0},
-    })
-    restored = bench._restore_live_rows()
-    assert restored == ["headline_tpu"]
-    on_disk = json.loads(mfile.read_text())
-    assert on_disk["headline_tpu"] == live        # live history restored
-    assert on_disk["fresh_row"]["qps"] == 9.0     # new keys kept
-    assert on_disk["old_tpu"]["qps"] == 8.0       # stale rows replaceable
-
-
-def test_probe_device_failure_restores_then_exits_rc3(tmp_path, monkeypatch):
-    import subprocess
-    import sys
-    import types
-
-    import bench
-
-    mfile = tmp_path / "m.json"
-    monkeypatch.setattr(bench, "MATRIX_FILE", str(mfile))
-    monkeypatch.setattr(bench, "_MATRIX_PREIMAGE", None)
-    monkeypatch.setenv("BENCH_GATE", "0")
-    live = {"backend": "tpu-v5e", "round": 6, "qps": 42.0}
-    mfile.write_text(json.dumps({"row_tpu": live}))
-    bench._merge_matrix({"row_tpu": {"backend": "tpu-v5e", "qps": 0.1}})
-    fake_jax = types.SimpleNamespace(
-        config=types.SimpleNamespace(jax_platforms="tpu"))
-    monkeypatch.setitem(sys.modules, "jax", fake_jax)
-    monkeypatch.setattr(subprocess, "run", lambda *a, **k: (_ for _ in ()).throw(
-        subprocess.TimeoutExpired(cmd="probe", timeout=1)))
-    # the rc=3 exit also dumps an incident bundle (PR-10 satellite):
-    # route it into the test tmp dir, not the checkout's cwd
-    inc_dir = tmp_path / "incidents"
-    monkeypatch.setenv("INCIDENT_DIR", str(inc_dir))
-    with pytest.raises(SystemExit) as ei:
-        bench._probe_device(timeout_s=1)
-    assert ei.value.code == 3
-    assert json.loads(mfile.read_text())["row_tpu"] == live
-    bundles = list(inc_dir.glob("incident-*.json"))
-    assert len(bundles) == 1  # the dying session preserved its evidence
-    doc = json.loads(bundles[0].read_text())
-    assert doc["incident"]["class"] == "bench"
-    assert "unreachable device" in doc["incident"]["reason"]

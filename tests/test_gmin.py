@@ -130,8 +130,8 @@ def test_gmin_disables_after_repeated_distinct_failures(tmp_path, monkeypatch):
 def test_vmem_tile_plan():
     """plan_tiles keeps every shape under the 12 MB budget by shrinking the
     store tile (then the query tile); fits_vmem refuses only when even the
-    smallest tiling is over (the round-2 relay wedge was a VMEM-oversized
-    kernel reaching Mosaic — this is the gate that prevents a repeat)."""
+    smallest tiling is over (a kernel over Mosaic's scoped-VMEM limit is a
+    compile error — this is the gate that keeps one from reaching it)."""
     from weaviate_tpu.ops import gmin_scan as gs
 
     # SIFT-shaped: full 512x512 tiles fit

@@ -137,8 +137,8 @@ def test_roofline_time_and_qps_forms_agree():
     # equals the time form over 1 second of the same work
     f = 2.0 * 256 * 100_000 * 128
     b = 100_000 * 512
-    a = costmodel.roofline(f, b, 1.0, "tpu-v5e")
-    q = costmodel.roofline_from_qps(256.0, 100_000, 128, 256, 512, "tpu-v5e")
+    a = costmodel.roofline(f, b, 1.0, costmodel.TPU_V5E)
+    q = costmodel.roofline_from_qps(256.0, 100_000, 128, 256, 512, costmodel.TPU_V5E)
     assert a == q
 
 
@@ -193,7 +193,7 @@ def _stamped_shape(device_ms=4.0, wall_ms=10.0, **kw):
 
 
 def test_perf_window_summary_and_clear():
-    w = perf.PerfWindow(window_s=60.0, backend="tpu-v5e")
+    w = perf.PerfWindow(window_s=60.0, backend=costmodel.TPU_V5E)
     for _ in range(4):
         w.record_dispatch(_stamped_shape(), rows=16)
     w.note_phase("queue_wait", 1.2)
@@ -221,7 +221,7 @@ def test_perf_window_gauges(tmp_path):
     from weaviate_tpu.monitoring import noop_metrics
 
     m = noop_metrics()
-    w = perf.PerfWindow(window_s=60.0, metrics=m, backend="tpu-v5e")
+    w = perf.PerfWindow(window_s=60.0, metrics=m, backend=costmodel.TPU_V5E)
     w.record_dispatch(_stamped_shape(), rows=16)
     text = m.expose().decode()
     assert "weaviate_device_mfu_pct" in text
@@ -236,7 +236,7 @@ def test_duty_interval_anchored_at_fetch_not_record_time():
     (hydration-delayed) record call."""
     import time
 
-    w = perf.PerfWindow(window_s=60.0, backend="tpu-v5e")
+    w = perf.PerfWindow(window_s=60.0, backend=costmodel.TPU_V5E)
     fetch_mono = time.monotonic() - 0.05  # both fetched 50ms ago
     for _ in range(2):
         s = costmodel.DispatchShape(costmodel.TIER_EXACT, n=1000, dim=16,
@@ -267,7 +267,7 @@ def test_gather_empty_shard_records_zero_cost(tmp_path):
         assert shape is not None and shape.tier == costmodel.TIER_GATHER
         assert shape.n == 0 and shape.flops() == 0 and shape.bytes() == 0
         assert shape.t_fetch == 0.0  # no device call ran
-        w = perf.PerfWindow(window_s=60.0, backend="tpu-v5e")
+        w = perf.PerfWindow(window_s=60.0, backend=costmodel.TPU_V5E)
         w.record_dispatch(shape, rows=1)
         s = w.summary()
         assert s["duty_cycle"] == 0.0 and s["device_busy_s"] == 0.0

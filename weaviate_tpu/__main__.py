@@ -28,18 +28,14 @@ def main(argv=None) -> int:
     ap.add_argument("--data-path", default=None)
     args = ap.parse_args(argv)
 
-    # honor JAX_PLATFORMS even when a site hook (sitecustomize) imported
-    # jax before this process's env was consulted — the 12-factor contract
-    # is that the container env picks the backend, and without this a host
-    # that pins a device backend silently overrides `JAX_PLATFORMS=cpu`
-    # (first insert then blocks on an unreachable accelerator)
-    if os.environ.get("JAX_PLATFORMS"):
-        try:
-            import jax
+    # this process owns the device: place the compile cache, then bring the
+    # backend up NOW — a server that cannot reach the platform it was
+    # started for fails here, before it binds a port, instead of serving on
+    # whatever it got
+    from weaviate_tpu import device
 
-            jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-        except Exception as e:  # noqa: BLE001 — serving beats backend pinning
-            print(f"warning: could not apply JAX_PLATFORMS: {e}", flush=True)
+    cache_dir = device.enable_compile_cache()
+    ident = device.identity()
 
     from weaviate_tpu.config import load_config
     from weaviate_tpu.server import App, RestServer
@@ -71,7 +67,9 @@ def main(argv=None) -> int:
         parts.append(f"metrics :{rest.metrics_port}")
     if app.cluster_node is not None:
         parts.append(f"clusterapi {app.cluster_node.address}")
-    print(f"weaviate-tpu {__version__} serving " + ", ".join(parts), flush=True)
+    print(f"weaviate-tpu {__version__} on {ident['platform']} "
+          f"({ident['count']} x {ident['device_kind']}), compile cache "
+          f"{cache_dir}, serving " + ", ".join(parts), flush=True)
     stop.wait()
 
     grpc_srv.stop()

@@ -16,24 +16,16 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 import time
 from typing import Optional, Sequence
 
 import numpy as np
 
+from weaviate_tpu import _native
 from weaviate_tpu.entities import vectorindex as vi
 from weaviate_tpu.index.interface import AllowList, VectorIndex
 from weaviate_tpu.index.tpu import VectorLog
-
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "_native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "libhnsw.so")
-_SRC_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "native",
-    "hnsw.cpp",
-)
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -44,17 +36,7 @@ def _load_lib() -> ctypes.CDLL:
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_SO_PATH):
-            if not os.path.exists(_SRC_PATH):
-                raise ImportError(f"native hnsw source not found at {_SRC_PATH}")
-            os.makedirs(_NATIVE_DIR, exist_ok=True)
-            subprocess.run(
-                ["g++", "-O3", "-march=native", "-std=c++17", "-fopenmp", "-shared", "-fPIC",
-                 "-o", _SO_PATH, _SRC_PATH],
-                check=True,
-                capture_output=True,
-            )
-        lib = ctypes.CDLL(_SO_PATH)
+        lib = ctypes.CDLL(_native.ensure_built("hnsw"))
         u64p = ctypes.POINTER(ctypes.c_uint64)
         f32p = ctypes.POINTER(ctypes.c_float)
         i32p = ctypes.POINTER(ctypes.c_int32)

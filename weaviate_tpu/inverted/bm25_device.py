@@ -34,8 +34,8 @@ from weaviate_tpu.inverted.bm25 import BM25Searcher
 from weaviate_tpu.monitoring import costmodel
 from weaviate_tpu.monitoring.metrics import record_device_fallback
 
-# below this many total postings the host engine wins: one relay round
-# trip costs more than scoring a handful of arrays in numpy
+# below this many total postings the host engine wins: one device dispatch
+# costs more than scoring a handful of arrays in numpy
 DEVICE_MIN_POSTINGS = 0  # tuned by bench; 0 = always device when eligible
 
 # device bytes pinned for dense rows (a row is n_pad * 4 bytes; at 1M docs
@@ -90,20 +90,6 @@ class DeviceBM25:
 
             from weaviate_tpu.ops import bm25_scan  # noqa: PLC0415
 
-            # honor the CURRENT process env even when a site hook imported
-            # jax earlier and froze jax.config.jax_platforms to the env of
-            # that moment (same 12-factor contract as __main__.py) —
-            # without this, a host pinned to an unreachable accelerator
-            # hangs HERE on first keyword query instead of serving on the
-            # backend the env asks for. Env-wins is deliberate: config
-            # cannot distinguish "explicitly updated" from "snapshotted at
-            # import", so the live env var is the operator's intent; a
-            # script that pins the platform via jax.config.update must set
-            # JAX_PLATFORMS too (tests/conftest.py does exactly that).
-            live = getattr(getattr(jax._src, "xla_bridge", None),
-                           "_backends", None)  # don't fight a LIVE backend
-            if os.environ.get("JAX_PLATFORMS") and not live:
-                jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
             jax.devices()  # raises if no backend comes up
             self._jax = (jax, bm25_scan)
         return self._jax

@@ -4,8 +4,7 @@ The four observability planes — tracing/perf (PR 3/7), quality (PR 8),
 and the memory ledger (PR 9) — each answer "what is happening" on their
 own axis, but nothing connects a *symptom* (breaker OPEN, recall
 degradation, headroom alert, SLO burn) to a *preserved, correlated
-diagnostic bundle*: BENCH_r02-r05 chip sessions all died on an
-unreachable device with their evidence lost (an opaque rc=3), and the
+diagnostic bundle*, and the
 north star ("heavy traffic from millions of users") had no SLO
 definition to alert against. This module is the layer that turns the
 planes into an incident-response system, with three cooperating pieces:
@@ -546,7 +545,7 @@ class FlightRecorder:
     incident class and a drop-not-queue enqueue; the capture (plane
     summaries + file IO) runs on a lazily-started worker thread.
     ``dump_now`` captures synchronously for the paths where the process
-    is about to die (SIGTERM/atexit teardown, bench rc=3)."""
+    is about to die (SIGTERM/atexit teardown, the bench storm modes)."""
 
     def __init__(self, incident_dir: str, max_bytes: int = 64 * 1024 * 1024,
                  rate_limit_s: float = 300.0, journal: Optional[OpsJournal]
@@ -997,7 +996,7 @@ def teardown_dump() -> Optional[str]:
 def emergency_dump(reason: str, directory: Optional[str] = None,
                    detail: Optional[dict] = None) -> Optional[str]:
     """Best-effort bundle for processes without a wired recorder (the
-    bench's rc=3 unreachable-device exit, the storm modes): uses the
+    bench's storm modes): uses the
     configured recorder when one is live (forced), else writes a one-shot
     bundle of whatever plane state this process still holds — including
     the perf/quality/memory ``recent_summaries()`` stashes, which survive

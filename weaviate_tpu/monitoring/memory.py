@@ -2,8 +2,8 @@
 
 Every ROADMAP scale item (the mesh promotion, the clustered 10M-100M
 layouts, multi-tier quantization) is gated by one resource the
-observability plane could not see: **bytes**. An HBM OOM on a chip
-session surfaces as an opaque rc=3, and the host side holds several
+observability plane could not see: **bytes**. An HBM OOM surfaces as an
+opaque allocator error, and the host side holds several
 unaccounted caches (the breaker's fallback rows, the auditor's rows
 cache, the shard allowList cache, COW transients). This module is the
 capacity twin of the perf window (monitoring/perf.py) and the quality
@@ -612,7 +612,7 @@ class MemoryLedger:
                 # the exhaustion transition is an ops-journal event AND an
                 # incident trigger (monitoring/incidents.py): the bundle
                 # preserves the byte ledger + forecast around the alert —
-                # the post-mortem an HBM-OOM rc=3 never left behind. Lazy
+                # the post-mortem an HBM OOM does not leave behind. Lazy
                 # import; one-comparison no-ops when the plane is off.
                 try:
                     from weaviate_tpu.monitoring import incidents

@@ -41,7 +41,7 @@ The CAPSTONE consumer is the incident plane (monitoring/incidents.py):
 a breaker trip or SLO burn preserves the window's duty-cycle/roofline/
 ledger picture at the moment of the incident — and
 ``recent_summaries()`` keeps the last windows reachable even after the
-owning App is torn down (the bench's rc=3 emergency dump reads it).
+owning App is torn down (the bench's emergency dump reads it).
 """
 
 from __future__ import annotations
@@ -292,7 +292,7 @@ class PerfWindow:
         span = self._observed_span(now)
         if span <= 0.0:
             return 0.0, 0.0
-        peak = costmodel.PEAKS.get(self.backend, costmodel.PEAKS["cpu"])
+        peak = costmodel.PEAKS[self.backend]
         mfu = 100.0 * (self._flops / span / 1e12) / peak["tflops"]
         bw = 100.0 * (self._bytes / span / 1e9) / peak["hbm_gbs"]
         return round(mfu, 3), round(bw, 3)

@@ -43,8 +43,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from weaviate_tpu.monitoring.metrics import record_device_fallback
-from weaviate_tpu.ops.gmin_scan import G, _VMEM_BUDGET, mosaic_g
-from weaviate_tpu.ops.pq_gmin import build_cb_chunks
+from weaviate_tpu.ops.gmin_scan import (G, _VMEM_BUDGET, compiler_params,
+                                        mosaic_g)
+from weaviate_tpu.ops.pq_gmin import adc_rescore_groups, build_cb_chunks
 
 C4 = 16       # centroids per 4-bit sub-quantizer (one nibble)
 _MSEG = 8     # segments per one-hot chunk (rows = _MSEG * C4 = 128)
@@ -224,6 +225,7 @@ def pq4_group_min_scores(q, codes3p, bias2, cb_chunks, alpha: float, *,
         ],
         out_specs=pl.BlockSpec((qb, scg), lambda i, j: (j, i)),
         scratch_shapes=[_vmem((ag, scg, d), jnp.float32)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(q, codes3p, bias2, cb_chunks)
 
@@ -282,10 +284,7 @@ def pq4_funnel_topk(codes4p, codes8, norms4, norms8, tombs, n, q, cb4_chunks,
     qr = qf if rot is None else jnp.matmul(
         qf, rot, preferred_element_type=jnp.float32)
     cap, mb = codes4p.shape
-    m8 = codes8.shape[1]
     ncols = cap // G
-    b = q.shape[0]
-    c8 = flat_cb8.shape[0] // m8
 
     slot = jnp.arange(cap)
     dead = jnp.logical_or(tombs, slot >= n)
@@ -313,29 +312,9 @@ def pq4_funnel_topk(codes4p, codes8, norms4, norms8, tombs, n, q, cb4_chunks,
     else:
         _, gidx = jax.lax.approx_min_k(gmin, rg4, recall_target=0.99)
 
-    # stage 2: exact 8-bit ADC of the C survivors (block gathers — rg4
-    # contiguous G*M-byte slices per query, the pq_gmin rescore idiom)
-    offs = (jnp.arange(G) * ncols)[None, None, :]
-    slots = (gidx[:, :, None] + offs).reshape(b, rg4 * G)
-    if codes8_blk is not None:
-        cand_codes = jnp.take(codes8_blk, gidx, axis=0).reshape(
-            b, rg4, G, m8).reshape(b, rg4 * G, m8).astype(jnp.int32)
-    else:
-        cand_codes = jnp.take(codes8, slots, axis=0).astype(jnp.int32)
-    seg_off = (jnp.arange(m8, dtype=jnp.int32) * c8)[None, None, :]
-    cand = jnp.take(flat_cb8, cand_codes + seg_off, axis=0).reshape(
-        b, rg4 * G, qr.shape[1])
-    bias_blk = bias2.T  # [ncols, G]
-    cand_bias = jnp.take(bias_blk, gidx, axis=0).reshape(b, rg4 * G)
-    if metric == "l2-squared":
-        q_sq = jnp.sum(qr ** 2, axis=-1, keepdims=True)
-        qx = jnp.einsum("bd,brd->br", qr, cand)
-        nrm_blk = norms8.reshape(G, ncols).T
-        nrm = jnp.take(nrm_blk, gidx, axis=0).reshape(b, rg4 * G)
-        ed8 = jnp.maximum(q_sq - 2.0 * qx + nrm, 0.0)
-    else:
-        ed8 = rescore_distances(cand, qr, metric)
-    ed8 = jnp.where(jnp.isinf(cand_bias), jnp.inf, ed8)
+    # stage 2: exact 8-bit ADC of the C survivors (pq_gmin's rescore)
+    ed8, slots = adc_rescore_groups(qr, gidx, codes8, codes8_blk, flat_cb8,
+                                    bias2, norms8, metric)
     neg, pos = jax.lax.top_k(-ed8, rc)
     d2 = -neg
     slots2 = jnp.take_along_axis(slots, pos, axis=1)

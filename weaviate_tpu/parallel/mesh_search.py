@@ -45,16 +45,13 @@ from weaviate_tpu.ops.topk import (
 
 SHARD_AXIS = "shard"
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6 spells it jax.shard_map(check_vma=)
-    def shard_map_compat(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:  # older jax: jax.experimental.shard_map.shard_map(check_rep=)
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
 
-    def shard_map_compat(f, *, mesh, in_specs, out_specs):
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
+def _shard_map(f, *, mesh, in_specs, out_specs):
+    """jax.shard_map with the replication check off: the kernels' outputs
+    are made replicated by an explicit all_gather + reselect, which the
+    checker cannot see through pallas_call."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 # rows of a slab scored per scan step (bounds the [B, chunk] block in HBM,
 # same rationale as index/tpu.py _SCAN_CHUNK)
@@ -207,7 +204,7 @@ def mesh_search_step(
         (d_top, i_top), _ = jax.lax.scan(step, init, tuple(xs))
         return _merge_local(d_top, i_top, s2d_l, my, n_loc, k, fused)
 
-    return shard_map_compat(
+    return _shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(
@@ -251,7 +248,7 @@ def mesh_search_gmin_step(
             k, metric, rg, active_g, interpret, blk_l)
         return _merge_local(d_top, i_top, s2d_l, my, n_loc, k, fused)
 
-    return shard_map_compat(
+    return _shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(
@@ -294,7 +291,7 @@ def mesh_search_pq_gmin_step(
             pq_gmin.build_codes_blocks(codes_l))
         return _merge_local(d_top, i_top, s2d_l, my, n_loc, k, fused)
 
-    return shard_map_compat(
+    return _shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(
@@ -403,7 +400,7 @@ def mesh_search_pq_step(
         i_loc = jnp.where(jnp.isinf(d_top), -1, i_top)
         return _merge_local(d_top, i_loc, s2d_l, my, n_loc, k, fused)
 
-    return shard_map_compat(
+    return _shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(
@@ -461,7 +458,7 @@ def mesh_search_ivf_step(
         i_loc = jnp.where(jnp.isinf(d_top), -1, i_top)
         return _merge_local(d_top, i_loc, s2d_l, my, n_loc, k, fused)
 
-    return shard_map_compat(
+    return _shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(
@@ -518,7 +515,7 @@ def mesh_search_pq4_step(
             use_pallas=False, interpret=False, exact=exact, rot=r)
         return _merge_local(d_top, i_top, s2d_l, my, n_loc, k, fused)
 
-    return shard_map_compat(
+    return _shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(
@@ -554,7 +551,7 @@ def mesh_write_rows_step(arr2d, arr1d, chunks2d, vals1d, offsets, takes, mesh):
         return (jnp.where(active, written2, a2_l),
                 jnp.where(active, written1, a1_l))
 
-    return shard_map_compat(
+    return _shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(
@@ -595,7 +592,7 @@ def mesh_insert_step(store, sq_norms, chunks, offsets, takes, use_norms, mesh):
             new_norms = norms_l
         return new_store, new_norms
 
-    return shard_map_compat(
+    return _shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(
@@ -619,7 +616,7 @@ def mesh_delete_step(tombs, rows, mesh):
         local = jnp.where(mine, rows_r - lo, n_loc)
         return tombs_l.at[local].set(True, mode="drop")
 
-    return shard_map_compat(
+    return _shard_map(
         shard_fn, mesh=mesh, in_specs=(P(SHARD_AXIS), P()),
         out_specs=P(SHARD_AXIS),
     )(tombs, rows)
@@ -640,7 +637,7 @@ def mesh_write_pairs_step(s2d, pairs, offsets, takes, mesh):
         written = jax.lax.dynamic_update_slice(s2d_l, pairs_l[0], (off, 0))
         return jnp.where(active, written, s2d_l)
 
-    return shard_map_compat(
+    return _shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(
@@ -661,7 +658,7 @@ def mesh_grow_pairs(arr, new_loc, fill, mesh):
         out = jnp.full((new_loc, arr_l.shape[1]), fill, arr_l.dtype)
         return jax.lax.dynamic_update_slice(out, arr_l, (0, 0))
 
-    return shard_map_compat(
+    return _shard_map(
         shard_fn, mesh=mesh, in_specs=(P(SHARD_AXIS, None),),
         out_specs=P(SHARD_AXIS, None),
     )(arr)
@@ -676,7 +673,7 @@ def mesh_grow_2d(store, new_loc, mesh):
         out = jnp.zeros((new_loc, store_l.shape[1]), store_l.dtype)
         return jax.lax.dynamic_update_slice(out, store_l, (0, 0))
 
-    return shard_map_compat(
+    return _shard_map(
         shard_fn, mesh=mesh, in_specs=(P(SHARD_AXIS, None),),
         out_specs=P(SHARD_AXIS, None),
     )(store)
@@ -688,7 +685,7 @@ def mesh_grow_1d(arr, new_loc, mesh):
         out = jnp.zeros((new_loc,), arr_l.dtype)
         return jax.lax.dynamic_update_slice(out, arr_l, (0,))
 
-    return shard_map_compat(
+    return _shard_map(
         shard_fn, mesh=mesh, in_specs=(P(SHARD_AXIS),),
         out_specs=P(SHARD_AXIS),
     )(arr)

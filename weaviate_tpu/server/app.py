@@ -467,11 +467,21 @@ class App:
     # -- meta ----------------------------------------------------------------
 
     def meta(self) -> dict:
-        """GET /v1/meta payload (handlers_meta)."""
+        """GET /v1/meta payload (handlers_meta), plus what this process
+        runs on: the device as JAX reports it, where compiled programs are
+        cached, and what became of each native library (loaded / built /
+        build_failed; absent = not requested yet)."""
+        import jax
+
+        from weaviate_tpu import _native, device
+
         return {
             "hostname": self.config.origin or "http://[::]:8080",
             "version": VERSION,
             "modules": self.modules.meta() if self.modules is not None else {},
+            "device": device.identity(),
+            "compile_cache_dir": jax.config.jax_compilation_cache_dir,
+            "native": dict(_native.STATUS),
         }
 
     def shutdown(self) -> None:

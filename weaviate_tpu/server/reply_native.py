@@ -13,17 +13,11 @@ is unavailable or an image is rejected, and callers use the upb path.
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
+import logging
 import threading
 from typing import Optional, Sequence
 
-_NATIVE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "_native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "libreply.so")
-_SRC_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "native", "reply.cpp")
+from weaviate_tpu import _native
 
 _lib = None
 _lib_failed = False
@@ -40,13 +34,7 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _lib_failed:
             return _lib
         try:
-            if not os.path.exists(_SO_PATH):
-                os.makedirs(_NATIVE_DIR, exist_ok=True)
-                subprocess.run(
-                    ["g++", "-O3", "-march=native", "-std=c++17", "-shared",
-                     "-fPIC", "-o", _SO_PATH, _SRC_PATH],
-                    check=True, capture_output=True)
-            lib = ctypes.CDLL(_SO_PATH)
+            lib = ctypes.CDLL(_native.ensure_built("reply"))
             lib.build_search_reply.restype = ctypes.c_int64
             lib.build_search_reply.argtypes = [
                 ctypes.POINTER(ctypes.c_char_p),
@@ -83,8 +71,11 @@ def _load() -> Optional[ctypes.CDLL]:
                 ctypes.c_int64,
             ]
             _lib = lib
-        except Exception:  # noqa: BLE001 — native tier is best-effort
+        except Exception as e:  # noqa: BLE001 — the upb path serves
             _lib_failed = True
+            logging.getLogger(__name__).warning(
+                "native reply marshaller unavailable (%s: %s); replies "
+                "marshal through upb", type(e).__name__, e)
         return _lib
 
 

@@ -15,19 +15,13 @@ segment handle is unavailable, and callers use the Python reader.
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
+import logging
 import threading
 from typing import Optional, Sequence
 
 import numpy as np
 
-_NATIVE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "_native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "liblsmget.so")
-_SRC_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "native", "lsm_get.cpp")
+from weaviate_tpu import _native
 
 _lib = None
 _lib_failed = False
@@ -42,13 +36,7 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _lib_failed:
             return _lib
         try:
-            if not os.path.exists(_SO_PATH):
-                os.makedirs(_NATIVE_DIR, exist_ok=True)
-                subprocess.run(
-                    ["g++", "-O3", "-march=native", "-std=c++17", "-shared",
-                     "-fPIC", "-o", _SO_PATH, _SRC_PATH],
-                    check=True, capture_output=True)
-            lib = ctypes.CDLL(_SO_PATH)
+            lib = ctypes.CDLL(_native.ensure_built("lsmget"))
             lib.lsm_seg_open.restype = ctypes.c_void_p
             lib.lsm_seg_open.argtypes = [ctypes.c_char_p]
             lib.lsm_seg_close.restype = None
@@ -62,8 +50,11 @@ def _load() -> Optional[ctypes.CDLL]:
                 ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int8),
             ]
             _lib = lib
-        except Exception:  # noqa: BLE001 — native tier is best-effort
+        except Exception as e:  # noqa: BLE001 — the Python reader serves
             _lib_failed = True
+            logging.getLogger(__name__).warning(
+                "native LSM point-get plane unavailable (%s: %s); point "
+                "gets run in the Python reader", type(e).__name__, e)
         return _lib
 
 
