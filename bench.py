@@ -1914,7 +1914,7 @@ def run_serving_bench(args, rng):
                 app.tracer.clear()  # phase stats cover the counted window only
             if app.perf_window is not None:
                 # same discipline for the perf-attribution window: the
-                # roofline/duty-cycle row fields cover the counted window
+                # duty-cycle row fields cover the counted window
                 app.perf_window.clear()
             base_audits = None
             if app.quality_auditor is not None:
@@ -1995,17 +1995,11 @@ def run_serving_bench(args, rng):
                     row["online_recall_delta"] = round(abs(
                         row["online_recall"] - row["recall@10"]), 4)
             if app.perf_window is not None:
-                # the shared-costmodel window summary (monitoring/perf.py):
-                # roofline + duty cycle + per-stage shares of the
-                # host-overhead ledger — the before/after baseline the
-                # ROADMAP item-1/2/3 PRs measure their win against.
+                # the perf window's summary (monitoring/perf.py): duty
+                # cycle + per-stage shares of the host-overhead ledger.
                 # Coverage is FULL (every dispatch feeds the window;
                 # trace sampling only thins trace_phases above).
                 ps = app.perf_window.summary()
-                if ps.get("roofline"):
-                    row["roofline"] = ps["roofline"]
-                if ps.get("roofline_device_busy"):
-                    row["roofline_device_busy"] = ps["roofline_device_busy"]
                 row["duty_cycle"] = ps.get("duty_cycle")
                 row["phase_share"] = {
                     p: v.get("share_of_wall")

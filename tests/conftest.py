@@ -123,7 +123,7 @@ def _summaries_sessionfinish(exitstatus):
     summaries at unconfigure) — ci_check.sh sets PERF_SUMMARY_FILE /
     QUALITY_SUMMARY_FILE under CI_ARTIFACT_DIR and the workflow uploads
     both in ci-failure-logs, so a red run's bundle carries the
-    duty-cycle/roofline/ledger picture and the recall picture."""
+    duty-cycle/ledger picture and the recall picture."""
     import importlib
     import json as _json
 

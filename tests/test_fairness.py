@@ -311,7 +311,24 @@ def _gql_near(vec):
             % (K, json.dumps([float(x) for x in vec])))
 
 
-def test_tenant_tag_propagates_rest_to_trace_to_slow_log(tmp_path, caplog):
+class _SlowLines(logging.Handler):
+    """Keeps the slow-query lines that name `needle` and says when the
+    first has come."""
+
+    def __init__(self, needle: str):
+        super().__init__(logging.WARNING)
+        self.needle = needle
+        self.lines: list = []
+        self.seen = threading.Event()
+
+    def emit(self, record):
+        line = record.getMessage()
+        if self.needle in line:
+            self.lines.append(line)
+            self.seen.set()
+
+
+def test_tenant_tag_propagates_rest_to_trace_to_slow_log(tmp_path):
     """X-Tenant-Id rides the contextvar into the trace root, the
     coalescer admission annotation, the dispatch record — and lands in
     the slow-query JSON line, so 'whose query was slow' is answerable."""
@@ -320,14 +337,24 @@ def test_tenant_tag_propagates_rest_to_trace_to_slow_log(tmp_path, caplog):
     app, idx, vecs = _mk_app(tmp_path, tracing_on=True, slow_ms=0.0001)
     srv = RestServer(app, port=0)
     srv.start()
+    slow = logging.getLogger("weaviate_tpu.slowquery")
+    seen = _SlowLines("tenant-42")
+    slow.addHandler(seen)
+    level = slow.level
+    slow.setLevel(logging.WARNING)
     try:
-        with caplog.at_level(logging.WARNING,
-                             logger="weaviate_tpu.slowquery"):
-            st, hdrs, out = _rest(
-                srv.port, "POST", "/v1/graphql",
-                {"query": _gql_near(vecs[0])},
-                headers={"X-Tenant-Id": "tenant-42"})
-            assert st == 200 and "errors" not in out
+        st, hdrs, out = _rest(
+            srv.port, "POST", "/v1/graphql",
+            {"query": _gql_near(vecs[0])},
+            headers={"X-Tenant-Id": "tenant-42"})
+        assert st == 200 and "errors" not in out
+        # the REST handler writes the response INSIDE its trace scope:
+        # Tracer.finish (ring append, then the slow-log line) runs on the
+        # handler thread after the client already has its reply. The line
+        # is the last thing finish does for this request, so waiting for it
+        # is waiting for the ring too (reading the ring at once lost that
+        # race on a loaded worker)
+        assert seen.seen.wait(30.0), "no slow-query line for tenant-42"
         traces = app.tracer.snapshot()
         mine = [t for t in traces
                 if t["root"].get("attrs", {}).get("tenant") == "tenant-42"]
@@ -341,22 +368,12 @@ def test_tenant_tag_propagates_rest_to_trace_to_slow_log(tmp_path, caplog):
         spans = list(walk(mine[-1]["root"]))
         assert any(s.get("attrs", {}).get("tenant") == "tenant-42"
                    for s in spans)
-        # the slow log is emitted by Tracer.finish on the HANDLER thread
-        # AFTER the ring append (and possibly after the response was
-        # read), so the record can trail the snapshot() above — poll
-        # briefly instead of racing it
-        deadline = time.monotonic() + 5.0
-        lines: list = []
-        while not lines and time.monotonic() < deadline:
-            lines = [r.getMessage() for r in caplog.records
-                     if r.name == "weaviate_tpu.slowquery"]
-            if not lines:
-                time.sleep(0.02)
-        assert lines
-        docs = [json.loads(ln) for ln in lines]
+        docs = [json.loads(ln) for ln in seen.lines]
         assert any(d["root"].get("attrs", {}).get("tenant") == "tenant-42"
                    for d in docs)
     finally:
+        slow.setLevel(level)
+        slow.removeHandler(seen)
         srv.stop()
         app.shutdown()
 

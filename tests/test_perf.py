@@ -3,17 +3,19 @@ monitoring/perf.py) and its wiring.
 
 The acceptance-critical invariants pinned here:
 
-  1. ATTRIBUTION IDENTITY — per-rider flops/bytes are integer telescoping
-     splits, so when every rider of a coalesced dispatch is sampled they
-     sum BIT-EXACTLY to the dispatch totals (the cost-model twin of the
-     PR-3 device-time identity).
+  1. ONE TIMELINE — while profiling.device_trace has a capture open the
+     window keeps every closed host phase as an interval on
+     perf_counter_ns, bounded, nested as the code nests, and within 1 ms
+     of the same `wv/*` annotations read back from the capture's own
+     /host:CPU plane.
   2. DUTY-CYCLE MATH — the busy integrator computes the interval UNION
      (overlaps merged, window trimmed) on synthetic interval sets.
   3. DISABLED = ZERO PERF WORK — with TRACING_ENABLED unset, the serving
      path constructs no DispatchShape and never touches the PerfWindow
      (spy-asserted the same way as the tracing spy).
-  4. EXPOSITION — /debug/perf serves the window summary end to end and
-     /metrics carries the rolling roofline/duty gauges.
+  4. EXPOSITION — /debug/perf serves the window summary and the last
+     capture end to end and /metrics carries the duty gauge; the host-wall
+     roofline fields and gauges are gone.
 
 Plus: cost-model tier formulas, the shared-costmodel BM25 batch shape,
 the front-door gate sheds surfaced in coalescer stats, and the
@@ -86,18 +88,39 @@ def _dispatch_spans(trace_dicts):
 
 # -- cost model ---------------------------------------------------------------
 
-def test_split_exact_sums_bit_exactly():
-    for total, rows in [(0, [1, 2]), (7, [1, 1, 1]),
-                        (2 * 21 * 50_000 * 64, [1] * 21),
-                        (123456789, [3, 7, 11, 2]),
-                        (10**15, [5, 9, 2, 200])]:
-        parts = costmodel.split_exact(total, rows, sum(rows))
-        assert sum(parts) == total
-        assert all(isinstance(p, int) for p in parts)
-    # partial coverage (unsampled riders): parts stay proportional and
-    # never exceed the total
-    parts = costmodel.split_exact(1000, [1, 1], 4)
-    assert sum(parts) == 500
+def test_capture_log_bounded_and_anchored(monkeypatch):
+    """Intervals are kept only while a capture is open, relative to the
+    stamp taken before start_trace, at most CAPTURE_LOG_MAX of them (the
+    rest counted), with the ledger restricted to the capture beside them."""
+    monkeypatch.setattr(perf, "CAPTURE_LOG_MAX", 4)
+    w = perf.PerfWindow(window_s=60.0)
+    assert w.last_capture() is None
+    w.note_interval("hydrate", 10, 20)          # no capture open: dropped
+    w.capture_begin()
+    for i in range(6):
+        w.note_interval("hydrate", 1_000 + i * 1_000_000,
+                        2_001_000 + i * 1_000_000, tid=7)
+    w.capture_end(t0_ns=1_000, t1_ns=9_000_001_000,
+                  options={"python_tracer_level": 0})
+    w.note_interval("hydrate", 30, 40)          # closed again: dropped
+    cap = w.last_capture()
+    assert cap["id"] == 1 and cap["t0_ns"] == 0
+    assert set(cap) == {"id", "seconds", "t0_ns", "t1_ns", "options",
+                        "dropped", "intervals", "phases"}
+    assert cap["t1_ns"] == 9_000_000_000
+    assert cap["seconds"] == 9.0
+    assert cap["options"] == {"python_tracer_level": 0}
+    assert cap["dropped"] == 2
+    assert cap["intervals"] == [["hydrate", 7, i * 1_000_000, 2_000_000]
+                                for i in range(4)]
+    assert cap["phases"] == {"hydrate": {"samples": 4, "p50_ms": 2.0,
+                                         "p99_ms": 2.0}}
+    # the capture is beside the summary, not in it: incident bundles and
+    # the CI artifact carry the summary verbatim
+    assert "capture" not in w.summary()
+    w.capture_begin()
+    w.capture_end(0, 1, {})
+    assert w.last_capture()["id"] == 2 and w.last_capture()["intervals"] == []
 
 
 def test_dispatch_shape_tier_formulas():
@@ -193,7 +216,7 @@ def _stamped_shape(device_ms=4.0, wall_ms=10.0, **kw):
 
 
 def test_perf_window_summary_and_clear():
-    w = perf.PerfWindow(window_s=60.0, backend=costmodel.TPU_V5E)
+    w = perf.PerfWindow(window_s=60.0)
     for _ in range(4):
         w.record_dispatch(_stamped_shape(), rows=16)
     w.note_phase("queue_wait", 1.2)
@@ -208,9 +231,10 @@ def test_perf_window_summary_and_clear():
     shares = [v["share_of_wall"] for v in s["phases"].values()]
     assert all(sh is not None for sh in shares)
     assert sum(shares) == pytest.approx(1.0, abs=0.01)
-    # both roofline forms present and consistent with the cost model
-    assert s["roofline"]["mfu_pct"] > 0.0
-    assert s["roofline_device_busy"]["mfu_pct"] > 0.0
+    # no roofline from analytic work over a host wall: a share of the
+    # chip's peaks is read from a capture (benchmarks/readers/xplane_ops)
+    assert not {"roofline", "roofline_device_busy", "regimes", "backend",
+                "device_busy_s", "device_fetch_s"} & set(s)
     w.clear()
     s2 = w.summary()
     assert s2["dispatches"] == 0 and s2["duty_cycle"] == 0.0
@@ -221,12 +245,13 @@ def test_perf_window_gauges(tmp_path):
     from weaviate_tpu.monitoring import noop_metrics
 
     m = noop_metrics()
-    w = perf.PerfWindow(window_s=60.0, metrics=m, backend=costmodel.TPU_V5E)
+    w = perf.PerfWindow(window_s=60.0, metrics=m)
     w.record_dispatch(_stamped_shape(), rows=16)
     text = m.expose().decode()
-    assert "weaviate_device_mfu_pct" in text
     assert "weaviate_device_duty_cycle" in text
     assert "weaviate_perf_phase_share" in text
+    assert "weaviate_device_mfu_pct" not in text
+    assert "weaviate_device_hbm_bw_pct" not in text
 
 
 def test_duty_interval_anchored_at_fetch_not_record_time():
@@ -236,7 +261,7 @@ def test_duty_interval_anchored_at_fetch_not_record_time():
     (hydration-delayed) record call."""
     import time
 
-    w = perf.PerfWindow(window_s=60.0, backend=costmodel.TPU_V5E)
+    w = perf.PerfWindow(window_s=60.0)
     fetch_mono = time.monotonic() - 0.05  # both fetched 50ms ago
     for _ in range(2):
         s = costmodel.DispatchShape(costmodel.TIER_EXACT, n=1000, dim=16,
@@ -246,7 +271,8 @@ def test_duty_interval_anchored_at_fetch_not_record_time():
         s.device_ms = 10.0
         s.t_fetch_mono = fetch_mono
         w.record_dispatch(s)  # second record is "after a slow hydrate"
-    busy = w.summary()["device_busy_s"]
+    s = w.summary()
+    busy = s["duty_cycle"] * s["observed_s"]
     assert busy == pytest.approx(0.010, abs=0.004)  # union, not 0.020
 
 
@@ -267,10 +293,10 @@ def test_gather_empty_shard_records_zero_cost(tmp_path):
         assert shape is not None and shape.tier == costmodel.TIER_GATHER
         assert shape.n == 0 and shape.flops() == 0 and shape.bytes() == 0
         assert shape.t_fetch == 0.0  # no device call ran
-        w = perf.PerfWindow(window_s=60.0, backend=costmodel.TPU_V5E)
+        w = perf.PerfWindow(window_s=60.0)
         w.record_dispatch(shape, rows=1)
         s = w.summary()
-        assert s["duty_cycle"] == 0.0 and s["device_busy_s"] == 0.0
+        assert s["duty_cycle"] == 0.0
     finally:
         app.shutdown()
 
@@ -314,51 +340,57 @@ def test_teardown_signal_half_retries_after_thread_failure(monkeypatch):
         signal.signal(signal.SIGTERM, prev)
 
 
-def test_per_dispatch_mfu_divides_by_wall_not_fetch():
-    """A dispatch whose result was already resident fetches in ~0 ms; the
-    blocked-fetch time is a LOWER bound on device time, so the roofline
-    fact must divide by the dispatch's enqueue->fetch wall — dividing by
-    the fetch would fabricate absurd >100% MFU (seen live: 418%)."""
-    import time
-
+def test_dispatch_span_has_no_host_wall_roofline():
+    """The per-dispatch roofline (analytic FLOPs over a host wall, seen
+    live at 418% MFU before it was re-based) and the rider split of
+    flops/bytes beside it are gone from the dispatch span: the tier, the
+    scanned rows and the ledger stay."""
     tracing.configure(tracing.Tracer())
     try:
         tr = tracing.Tracer().start_request("test", "q")
         shape = costmodel.DispatchShape(
             costmodel.TIER_EXACT, n=2000, dim=32, batch=14,
             bytes_per_row=128, k=5)
-        shape.enqueue_ms = 800.0
-        shape.device_ms = 0.002      # result was resident: ~instant fetch
-        shape.finalize_ms = 0.2
-        t = time.perf_counter()
-        shape.t_start = t - 0.850
-        shape.t_end = t
+        shape.enqueue_ms, shape.device_ms, shape.finalize_ms = 800.0, 0.002, 0.2
         rec = tracing.DispatchRecord([(tr.root, 14, 0.0)], owned=True,
                                      actual_rows=14)
         rec.phase("device_search", 0.2)
         rec.attach_shape(shape)
         rec.finish()
         d = [s for s in tr.root.children if s.name == "dispatch"][0]
-        expect = costmodel.roofline(
-            shape.flops(), shape.bytes(),
-            d.attrs["dispatch_wall_ms"] / 1000.0)["mfu_pct"]
-        assert d.attrs["mfu_pct"] == expect
-        assert d.attrs["mfu_pct"] < 1.0  # honest: most of the wall is host
+        assert d.attrs["tier"] == costmodel.TIER_EXACT
+        assert d.attrs["n_live"] == 2000 and d.attrs["dim"] == 32
+        assert d.attrs["ledger_ms"] == {
+            "enqueue": 800.0, "device": 0.002, "gather_hop": 0.198}
+        assert not {"mfu_pct", "hbm_bw_pct", "arith_intensity", "regime",
+                    "flops", "bytes", "dispatch_flops", "dispatch_bytes",
+                    "dispatch_wall_ms"} & set(d.attrs)
+        # an attribution span is a share of a dispatch, not an interval
+        # that ran: no start
+        assert "start_ms" not in d.to_dict(tr.root.start_ns)
     finally:
         tracing.configure(None)
 
 
 # -- serving-path integration -------------------------------------------------
 
-def test_rider_flops_bytes_sum_bit_exact(tmp_path):
-    """Coalesced dispatch: every rider's integer flops/bytes attribution
-    sums EXACTLY to the dispatch totals (acceptance criterion)."""
+def _nested_in(inner, outer):
+    return outer[2] <= inner[2] and inner[2] + inner[3] <= outer[2] + outer[3]
+
+
+def test_coalesced_capture_has_queue_wait_on_the_waiters_threads(tmp_path):
+    """Coalesced dispatch under an open capture: every rider's admission
+    wait is an interval on ITS OWN thread, nested in that thread's
+    traverser span; the lane's dispatch phases and scatter are there once
+    per dispatch, on the thread that did the work."""
     app, idx, vecs = _mk_app(tmp_path)
     try:
         n_req = 10
         barrier = threading.Barrier(n_req)
+        tids = []
 
         def run(i):
+            tids.append(threading.get_native_id())
             with tracing.request("test", f"q{i}"):
                 barrier.wait()
                 app.traverser.get_class(GetParams(
@@ -366,35 +398,40 @@ def test_rider_flops_bytes_sum_bit_exact(tmp_path):
                     near_vector={"vector": (vecs[i] + 0.5).tolist()},
                     limit=K))
 
+        w = perf.get_window()
+        w.capture_begin()
         threads = [threading.Thread(target=run, args=(i,))
                    for i in range(n_req)]
         for t in threads:
             t.start()
         for t in threads:
             t.join(timeout=30)
-        by_dispatch: dict = {}
-        for d in _dispatch_spans(app.tracer.snapshot()):
-            by_dispatch.setdefault(d["attrs"]["dispatch_id"], []).append(
-                d["attrs"])
-        assert by_dispatch
-        coalesced = [v for v in by_dispatch.values() if len(v) > 1]
-        assert coalesced, "requests never shared a dispatch"
-        for riders in by_dispatch.values():
-            a0 = riders[0]
-            assert a0["tier"] == costmodel.TIER_EXACT
-            # the dispatch's analytic totals match the cost model at the
-            # dispatch's actual rows
-            assert a0["dispatch_flops"] == 2 * a0["actual_rows"] * \
-                a0["n_live"] * a0["dim"]
-            # BIT-exact: integer sums, no approx
-            assert sum(r["flops"] for r in riders) == a0["dispatch_flops"]
-            assert sum(r["bytes"] for r in riders) == a0["dispatch_bytes"]
-            assert all(isinstance(r["flops"], int) for r in riders)
+        w.capture_end(0, 1, {})
+        iv = w.last_capture()["intervals"]
+        by_name: dict = {}
+        for x in iv:
+            by_name.setdefault(x[0], []).append(x)
+        waits = by_name["queue_wait"]
+        assert sorted(x[1] for x in waits) == sorted(tids)
+        for q in waits:
+            span = [x for x in by_name["traverser.get_class"] if x[1] == q[1]]
+            assert len(span) == 1 and _nested_in(q, span[0])
+        dispatches = {d["attrs"]["dispatch_id"]
+                      for d in _dispatch_spans(app.tracer.snapshot())}
+        assert len(dispatches) < n_req, "requests never shared a dispatch"
+        for name in ("enqueue", "device_wait", "gather_hop", "hydrate",
+                     "scatter"):
+            assert len(by_name[name]) == len(dispatches), name
+            # the flusher / dispatch pool did this work, not a waiter
+            assert not {x[1] for x in by_name[name]} & set(tids), name
+        assert len(by_name["request"]) == n_req
+        # the same waits are the ledger's queue_wait stage
+        assert w.summary()["phases"]["queue_wait"]["samples"] == n_req
     finally:
         app.shutdown()
 
 
-def test_dispatch_span_carries_roofline_and_ledger(tmp_path):
+def test_dispatch_span_carries_tier_and_ledger(tmp_path):
     app, idx, vecs = _mk_app(tmp_path)
     try:
         with tracing.request("test", "q"):
@@ -406,8 +443,8 @@ def test_dispatch_span_carries_roofline_and_ledger(tmp_path):
         a = d[0]["attrs"]
         assert a["tier"] == costmodel.TIER_EXACT
         assert a["n_live"] == N and a["dim"] == DIM
-        assert a["mfu_pct"] >= 0.0 and a["hbm_bw_pct"] >= 0.0
-        assert a["regime"] in ("compute-bound", "hbm-bandwidth-bound")
+        assert not {"mfu_pct", "hbm_bw_pct", "regime", "flops",
+                    "bytes"} & set(a)
         led = a["ledger_ms"]
         assert {"enqueue", "device", "gather_hop", "hydrate"} <= set(led)
         assert all(v >= 0.0 for v in led.values())
@@ -415,6 +452,70 @@ def test_dispatch_span_carries_roofline_and_ledger(tmp_path):
         s = perf.get_window().summary()
         assert s["dispatches"] >= 1
         assert s["duty_cycle"] > 0.0
+    finally:
+        app.shutdown()
+
+
+def test_a_failed_dispatch_or_finalize_leaves_no_phase_open(tmp_path,
+                                                            monkeypatch):
+    """A dispatch that raises while it is being built closes its `enqueue`;
+    a finalize whose host half raises after the fetch closes its
+    `gather_hop`; a second fetch closes the first one's hop. Every
+    annotation that was entered is left, and the capture log has the
+    intervals."""
+    from weaviate_tpu.index import tpu as tpu_mod
+
+    app, idx, vecs = _mk_app(tmp_path, coalesce=False)
+    try:
+        vidx = idx.single_local_shard().vector_index
+        q = vecs[:2] + 0.5
+        vidx.search_by_vectors(q, K)
+        open_now = []
+
+        class Ann:
+            def __init__(self, name, **stats):
+                self.name = name
+
+            def __enter__(self):
+                open_now.append(self.name)
+
+            def __exit__(self, *exc):
+                open_now.remove(self.name)
+
+            def set_metadata(self, **stats):
+                pass
+
+        monkeypatch.setattr(tracing, "_TraceMe", Ann)
+
+        def boom(*a, **kw):
+            raise RuntimeError("boom")
+
+        w = perf.get_window()
+        w.capture_begin()
+        with monkeypatch.context() as m:
+            m.setattr(vidx, "_dispatch_scan", boom)
+            with pytest.raises(RuntimeError):
+                vidx.search_by_vectors(q, K)
+        assert open_now == []
+        with monkeypatch.context() as m:
+            m.setattr(tpu_mod, "unpack_fused", boom)
+            with pytest.raises(RuntimeError):
+                vidx.search_by_vectors(q, K)
+        assert open_now == []
+        shape = costmodel.DispatchShape(costmodel.TIER_EXACT, n=N, dim=DIM,
+                                        batch=2, batch_padded=2,
+                                        bytes_per_row=DIM * 4, k=K)
+        tpu_mod._fetch_packed(np.zeros(4), shape)
+        tpu_mod._fetch_packed(np.zeros(4), shape)
+        assert open_now == ["wv/gather_hop"] and shape.fetches == 2
+        shape.end_hop()
+        assert open_now == []
+        w.capture_end(0, 1, {})
+        names = [x[0] for x in w.last_capture()["intervals"]]
+        assert names == ["enqueue",
+                         "enqueue", "device_wait", "gather_hop",
+                         "device_wait", "gather_hop",
+                         "device_wait", "gather_hop"]
     finally:
         app.shutdown()
 
@@ -432,7 +533,10 @@ def test_pq_tiers_report_their_bytes(tmp_path):
                 near_vector={"vector": (vecs[0] + 0.5).tolist()}, limit=K))
         a = _dispatch_spans(app.tracer.snapshot())[0]["attrs"]
         assert a["tier"] == costmodel.TIER_PQ_RESCORE
-        assert a["dispatch_bytes"] == a["n_live"] * 2 * DIM
+        vidx.search_by_vectors(vecs[:1] + 0.5, K)
+        shape = vidx.pop_dispatch_shape()
+        assert shape.tier == costmodel.TIER_PQ_RESCORE
+        assert shape.bytes() == shape.n * 2 * DIM == a["n_live"] * 2 * DIM
     finally:
         app.shutdown()
 
@@ -456,6 +560,11 @@ def test_disabled_serving_path_constructs_no_perf_objects(tmp_path,
                         spy("PerfWindow.record_dispatch"))
     monkeypatch.setattr(perf.PerfWindow, "note_phase",
                         spy("PerfWindow.note_phase"))
+    # the capture log and the profiler's annotations ride the same switch
+    monkeypatch.setattr(perf.PerfWindow, "note_interval",
+                        spy("PerfWindow.note_interval"))
+    monkeypatch.setattr(tracing, "Phase", spy("Phase"))
+    monkeypatch.setattr(tracing, "_TraceMe", spy("TraceAnnotation"))
     try:
         assert app.perf_window is None
         assert perf.get_window() is None
@@ -498,13 +607,15 @@ def test_debug_perf_endpoint_and_metrics(tmp_path):
         assert "phases" in body and "device" in body["phases"]
         assert body["phases"]["device"]["p99_ms"] >= 0.0
         assert body["tiers"].get(costmodel.TIER_EXACT, 0) >= 1
-        # rolling gauges ride the same scrape as everything else
+        assert body["capture"] is None  # no /debug/pprof/trace yet
+        assert "roofline" not in body and "regimes" not in body
+        # the duty gauge rides the same scrape as everything else
         with urllib.request.urlopen(
                 f"http://127.0.0.1:{srv.port}/metrics", timeout=30) as r:
             text = r.read().decode()
-        assert "weaviate_device_mfu_pct" in text
-        assert "weaviate_device_hbm_bw_pct" in text
         assert "weaviate_device_duty_cycle" in text
+        assert "weaviate_device_mfu_pct" not in text
+        assert "weaviate_device_hbm_bw_pct" not in text
     finally:
         srv.stop()
         app.shutdown()
@@ -609,6 +720,102 @@ def test_device_trace_teardown_stops_capture(monkeypatch):
     assert profiling.stop_active_trace() is True
     assert profiling.stop_active_trace() is False  # idempotent
     assert stopped == [1]
+
+
+def test_capture_intervals_match_the_profilers_own_annotations(tmp_path):
+    """A capture through profiling.device_trace while two threads run fake
+    phases: the intervals of /debug/perf's `capture` are nested as the code
+    nests them, bounded, on two threads, and each `wv/*` event of the
+    capture's own /host:CPU plane has its interval within 1 ms -- the stamp
+    taken before start_trace is the xplane's zero."""
+    import glob
+    import statistics
+    import time
+
+    from jax.profiler import ProfileData
+
+    from weaviate_tpu.monitoring import profiling
+
+    import jax
+
+    jax.devices()  # a server's backend is up long before its first capture
+    tracing.configure(tracing.Tracer())
+    w = perf.configure(perf.PerfWindow())
+    stop = threading.Event()
+    tids = []
+
+    def worker():
+        tids.append(threading.get_native_id())
+        while not stop.is_set():
+            with tracing.request("test", "fake"):
+                with tracing.span("traverser.get_class"):
+                    enq = tracing.Phase("enqueue")
+                    time.sleep(0.001)
+                    enq.end(rows=3, tier="exact_scan")
+                    with tracing.Stopwatch("hydrate", rows=3):
+                        time.sleep(0.002)
+            time.sleep(0.001)
+
+    threads = [threading.Thread(target=worker) for _ in range(2)]
+    for t in threads:
+        t.start()
+    try:
+        text = profiling.device_trace(str(tmp_path), seconds=0.5)
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(timeout=30)
+    assert "python_tracer_level=0 host_tracer_level=1" in text
+    cap = w.last_capture()
+    assert cap["options"] == profiling.TRACE_OPTIONS
+    assert cap["dropped"] == 0
+    assert cap["t0_ns"] == 0 < cap["t1_ns"] and cap["seconds"] >= 0.5
+    iv = cap["intervals"]
+    assert 40 <= len(iv) <= perf.CAPTURE_LOG_MAX
+    assert {x[1] for x in iv} == set(tids) and len(set(tids)) == 2
+    # nesting, per thread: hydrate and enqueue in a traverser span in a
+    # request (an interval is logged when it closes, so the outer ones of
+    # the last few may be missing, never the other way round; one that was
+    # open when the capture began is kept, from before zero, without the
+    # inner ones that had closed by then)
+    assert all(x[2] + x[3] >= 0 for x in iv)
+    for tid in tids:
+        mine = [x for x in iv if x[1] == tid]
+        for outer_name, inner_names in (
+                ("request", ("traverser.get_class",)),
+                ("traverser.get_class", ("enqueue", "hydrate"))):
+            outers = [x for x in mine if x[0] == outer_name and x[2] >= 0]
+            assert outers
+            for o in outers:
+                inner = [x for x in mine if x[0] in inner_names
+                         and _nested_in(x, o)]
+                assert len(inner) == len(inner_names)
+    assert set(cap["phases"]) == {"request", "traverser.get_class",
+                                  "enqueue", "hydrate"}
+    assert cap["phases"]["hydrate"]["p50_ms"] >= 2.0
+
+    # the same phases as the profiler saw them
+    (path,) = glob.glob(str(tmp_path / "traces" / "**" / "*.xplane.pb"),
+                        recursive=True)
+    events = [(ev.name[3:], ev.start_ns, ev.duration_ns, dict(ev.stats))
+              for plane in ProfileData.from_file(path).planes
+              if plane.name == "/host:CPU"
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("wv/")]
+    assert len(events) >= 40
+    assert {e[0] for e in events} == set(cap["phases"])
+    errs = []
+    for name, start, dur, stats in events:
+        near = min((x for x in iv if x[0] == name),
+                   key=lambda x: abs(x[2] - start))
+        errs.append(max(abs(near[2] - start), abs(near[3] - dur)))
+        if name == "hydrate":
+            assert stats == {"rows": 3}
+        if name == "enqueue":   # what end() added
+            assert stats == {"rows": 3, "tier": "exact_scan"}
+    assert statistics.median(errs) < 0.25e6
+    # one thread preempted between its two clock reads may miss; not many
+    assert sum(e < 1e6 for e in errs) >= 0.95 * len(errs)
 
 
 def test_trace_teardown_install_registers_sigterm_chain():

@@ -31,7 +31,7 @@ import pytest
 from weaviate_tpu.config import Config
 from weaviate_tpu.entities.filters import LocalFilter
 from weaviate_tpu.entities.storobj import StorObj
-from weaviate_tpu.monitoring import tracing
+from weaviate_tpu.monitoring import perf, tracing
 from weaviate_tpu.serving.coalescer import (
     CoalescerShutdownError,
     QueryCoalescer,
@@ -46,6 +46,7 @@ def _reset_global_tracer():
     """Tests install process-global tracers; never let one leak across."""
     yield
     tracing.configure(None)
+    perf.configure(None)
 
 
 def _mk_app(tmp_path, tracing_on=True, coalesce=True, window_ms=200.0,
@@ -222,6 +223,12 @@ def test_disabled_serving_path_makes_zero_tracing_calls(tmp_path, monkeypatch):
     monkeypatch.setattr(tracing, "DispatchRecord", spy("DispatchRecord"))
     monkeypatch.setattr(tracing.Tracer, "start_request",
                         spy("Tracer.start_request"))
+    # the intervals ride the same switch: no Phase, no profiler
+    # annotation, nothing offered to the perf window's capture log
+    monkeypatch.setattr(tracing, "Phase", spy("Phase"))
+    monkeypatch.setattr(tracing, "_TraceMe", spy("TraceAnnotation"))
+    monkeypatch.setattr(perf, "note_interval", spy("perf.note_interval"))
+    monkeypatch.setattr(perf, "note_phase", spy("perf.note_phase"))
     srv = GrpcServer(app, port=0, max_workers=8)
     srv.start()
     try:
@@ -245,6 +252,14 @@ def test_disabled_serving_path_makes_zero_tracing_calls(tmp_path, monkeypatch):
                 near_vector=pb.NearVectorParams(
                     vector=(vecs[1] + 0.5).tolist())))
             assert len(rep.results) == K
+            # and the batch twin, whose entry has a decode and an encode
+            # half of its own
+            brep = cl.batch_search(pb.BatchSearchRequest(requests=[
+                pb.SearchRequest(class_name="Tr", limit=K,
+                                 near_vector=pb.NearVectorParams(
+                                     vector=(vecs[i] + 0.5).tolist()))
+                for i in range(3)]))
+            assert [len(r.results) for r in brep.replies] == [K] * 3
         finally:
             cl.close()
         assert calls == []
@@ -469,6 +484,94 @@ def test_grpc_trailing_request_id_and_trace(tmp_path):
         assert doc["trace_id"] == "12" * 16
         assert doc["request_id"] == "grid-9"
     finally:
+        srv.stop()
+        app.shutdown()
+
+
+def test_grpc_batch_trace_is_a_waterfall_with_decode_and_encode(tmp_path):
+    """A gRPC BatchSearch root carries `entry.decode` and `entry.encode`
+    children around the traverser's span, every span that ran has its
+    `start_ms` from the root's start, the two halves are ledger phases of
+    /debug/perf, and the benchmark's `trace_span` reader still reads the
+    root less ALL its children (so `entry_self_ms` now leaves out what
+    decode and encode name)."""
+    from benchmarks.lib.spec import Spec
+    from weaviate_tpu.grpcapi import weaviate_pb2 as pb
+    from weaviate_tpu.server.grpc_server import GrpcServer, SearchClient
+
+    app, idx, vecs = _mk_app(tmp_path, coalesce=False)
+    srv = GrpcServer(app, port=0, max_workers=8)
+    srv.start()
+    cl = SearchClient(f"127.0.0.1:{srv.port}")
+    try:
+        rep = cl.batch_search(pb.BatchSearchRequest(requests=[
+            pb.SearchRequest(class_name="Tr", limit=K,
+                             near_vector=pb.NearVectorParams(
+                                 vector=(vecs[i] + 0.5).tolist()))
+            for i in range(4)]))
+        assert [len(r.results) for r in rep.replies] == [K] * 4
+        doc = app.tracer.snapshot()[-1]
+        assert (doc["kind"], doc["name"]) == ("grpc", "BatchSearch")
+        root = doc["root"]
+        assert root["start_ms"] == 0.0
+        kids = root["children"]
+        # memtable-resident rows: the raw lane looked at the request and
+        # declined, the general path decoded it again
+        assert [c["name"] for c in kids] == [
+            "entry.decode", "entry.decode", "traverser.get_class_batched",
+            "entry.encode"]
+        assert kids[0]["attrs"] == {"lane": "raw"}
+        # a waterfall: each child starts after the one before it ended,
+        # inside the root
+        t = 0.0
+        for c in kids:
+            assert c["start_ms"] >= t - 1e-3
+            t = c["start_ms"] + c["duration_ms"]
+        assert t <= root["duration_ms"] + 1e-3
+        # attribution spans (a dispatch's share) are not intervals
+        d = _dispatch_spans([doc])
+        assert d and all("start_ms" not in s for s in d)
+        # the reader: root less all children, as before
+        reader = Spec().reader("trace_span")
+        got = reader.read({"traces": {"traces": [doc]}, "client": {}},
+                          kind="grpc", names=["BatchSearch"])
+        want = root["duration_ms"] - sum(c["duration_ms"] for c in kids)
+        assert got == pytest.approx(want, abs=1e-6)
+        assert 0.0 <= got < root["duration_ms"] - kids[2]["duration_ms"]
+        # and the ledger has the two halves as phases of their own; of the
+        # two decodes only the one whose rows were served
+        phases = app.perf_window.summary()["phases"]
+        assert phases["decode"]["samples"] == 1
+        assert phases["decode"]["p50_ms"] == pytest.approx(
+            kids[1]["duration_ms"], abs=2e-3)
+        assert phases["encode"]["samples"] == 1
+        assert phases["encode"]["p50_ms"] == pytest.approx(
+            kids[3]["duration_ms"], abs=2e-3)
+        # flushed to segments the raw lane serves: no traverser, the
+        # dispatch hangs under the root between the two halves
+        shard = idx.single_local_shard()
+        for b in (shard.objects, shard.docid_lookup):
+            b.flush_memtable()
+        if shard.raw_plane_ready():
+            cl.batch_search(pb.BatchSearchRequest(requests=[
+                pb.SearchRequest(class_name="Tr", limit=K,
+                                 near_vector=pb.NearVectorParams(
+                                     vector=(vecs[i] + 0.5).tolist()))
+                for i in range(4)]))
+            kids = app.tracer.snapshot()[-1]["root"]["children"]
+            assert [c["name"] for c in kids] == [
+                "entry.decode", "dispatch", "entry.encode"]
+            assert app.perf_window.summary()["phases"]["decode"][
+                "samples"] == 2
+        # the single Search has both halves too
+        cl.search(pb.SearchRequest(
+            class_name="Tr", limit=K,
+            near_vector=pb.NearVectorParams(vector=(vecs[1] + 0.5).tolist())))
+        kids = app.tracer.snapshot()[-1]["root"]["children"]
+        assert [c["name"] for c in kids] == [
+            "entry.decode", "traverser.get_class", "entry.encode"]
+    finally:
+        cl.close()
         srv.stop()
         app.shutdown()
 

@@ -224,8 +224,8 @@ class TracingConfig:
     ring_size: int = 256          # completed traces kept for /debug/traces
     slow_query_threshold_ms: float = 1000.0  # <=0 disables the slow log
     # rolling window of the perf-attribution plane (monitoring/perf.py):
-    # /debug/perf summaries, duty cycle, and the roofline gauges aggregate
-    # over this many trailing seconds. Rides TRACING_ENABLED.
+    # /debug/perf summaries and the duty cycle aggregate over this many
+    # trailing seconds. Rides TRACING_ENABLED.
     perf_window_s: float = 60.0
 
 

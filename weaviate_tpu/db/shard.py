@@ -645,11 +645,13 @@ class Shard:
     ) -> list[list[SearchResult]]:
         m = self.metrics
         cls = self.class_def.name
-        t0 = time.perf_counter()
-        allow = self.build_allow_list(flt)
-        t1 = time.perf_counter()
-        filter_ms = (t1 - t0) * 1000.0 if flt is not None else None
-        if filter_ms is not None:
+        filter_ms = None
+        if flt is None:
+            allow = self.build_allow_list(flt)
+        else:
+            with tracing.Stopwatch("filter") as sw:
+                allow = self.build_allow_list(flt)
+            filter_ms = sw.ms
             if rec is not None:
                 rec.phase("filter", filter_ms)
             if m is not None:
@@ -665,7 +667,7 @@ class Shard:
                 dispatched[0] = True
             lock_wait = self._pop_lock_wait()
             # widening runs several dispatches; the popped shape (and so
-            # the ledger/roofline facts) describes the LAST round
+            # the ledger facts) describes the LAST round
             shape = self._pop_dispatch_shape()
             # target-distance rounds are ragged re-dispatches of the same
             # rows — not a representative recall sample; drop the pin
@@ -680,21 +682,21 @@ class Shard:
             for i, (ri, rd) in enumerate(zip(row_ids, row_dists)):
                 ids[i, : len(ri)] = ri
                 dists[i, : len(ri)] = rd
-            hydrated = self._hydrate_batch(ids, dists, include_vector)
-            t3 = time.perf_counter()
+            with tracing.Stopwatch("hydrate", rows=len(dists)) as hyd:
+                hydrated = self._hydrate_batch(ids, dists, include_vector)
             if rec is not None:
                 rec.phase("device_search", (t2 - t1) * 1000.0)
-                rec.phase("hydrate", (t3 - t2) * 1000.0)
+                rec.phase("hydrate", hyd.ms)
             if shape is not None:
                 if filter_ms is not None:
                     shape.filter_ms = filter_ms
-                shape.hydrate_ms = (t3 - t2) * 1000.0
+                shape.hydrate_ms = hyd.ms
             self._trace_dispatch_facts(rec, q.shape[0], k, lock_wait, shape)
             if m is not None:
                 m.filtered_vector_search.labels(cls, self.name).observe(
                     (t2 - t1) * 1000.0)
                 m.filtered_vector_objects.labels(cls, self.name).observe(
-                    (t3 - t2) * 1000.0)
+                    hyd.ms)
                 m.vector_index_ops.labels("search", cls, self.name).inc(q.shape[0])
                 m.query_dimensions.labels("nearVector", "search", cls).inc(
                     int(q.shape[0] * q.shape[1]))
@@ -706,20 +708,19 @@ class Shard:
         shape = self._pop_dispatch_shape()
         self._maybe_audit(self._pop_audit_snap(), q, k, allow, ids, dists)
         t2 = time.perf_counter()
-        hydrated = self._hydrate_batch(ids, dists, include_vector)
-        t3 = time.perf_counter()
+        with tracing.Stopwatch("hydrate", rows=len(dists)) as hyd:
+            hydrated = self._hydrate_batch(ids, dists, include_vector)
         if rec is not None:
             rec.phase("device_search", (t2 - t1) * 1000.0)
-            rec.phase("hydrate", (t3 - t2) * 1000.0)
+            rec.phase("hydrate", hyd.ms)
         if shape is not None:
             if filter_ms is not None:
                 shape.filter_ms = filter_ms
-            shape.hydrate_ms = (t3 - t2) * 1000.0
+            shape.hydrate_ms = hyd.ms
         self._trace_dispatch_facts(rec, q.shape[0], k, lock_wait, shape)
         if m is not None:
             m.filtered_vector_search.labels(cls, self.name).observe((t2 - t1) * 1000.0)
-            m.filtered_vector_objects.labels(cls, self.name).observe(
-                (t3 - t2) * 1000.0)
+            m.filtered_vector_objects.labels(cls, self.name).observe(hyd.ms)
             m.vector_index_ops.labels("search", cls, self.name).inc(q.shape[0])
             m.query_dimensions.labels("nearVector", "search", cls).inc(
                 int(q.shape[0] * q.shape[1]))
@@ -769,7 +770,8 @@ class Shard:
             dists = np.asarray(dists, dtype=np.float32).copy()
             dists[dists > float(target_distance)] = np.inf
         tracing.annotate_current("host_fallback", reason)
-        return self._hydrate_batch(ids, dists, include_vector)
+        with tracing.Stopwatch("hydrate", rows=len(dists)):
+            return self._hydrate_batch(ids, dists, include_vector)
 
     def _pop_audit_snap(self):
         """The pinned IndexSnapshot this thread's last dispatch read —
@@ -838,8 +840,8 @@ class Shard:
         first = tracing.note_shape((id(vidx), int(padded), int(k)))
         if shape is not None:
             # perf attribution is FULL-coverage like shape registration:
-            # every dispatch feeds the rolling window (duty cycle, window
-            # roofline, ledger percentiles) even when no rider was sampled
+            # every dispatch feeds the rolling window (duty cycle, ledger
+            # percentiles) even when no rider was sampled
             # — trace sampling thins /debug/traces, never /debug/perf
             w = perf.get_window()
             if w is not None:
@@ -953,9 +955,9 @@ class Shard:
         filter_ms = None
         allow = None
         if flt is not None:
-            t0 = time.perf_counter()
-            allow = self.build_allow_list(flt)
-            filter_ms = (time.perf_counter() - t0) * 1000.0
+            with tracing.Stopwatch("filter") as sw:
+                allow = self.build_allow_list(flt)
+            filter_ms = sw.ms
             if m is not None:
                 m.filtered_vector_filter.labels(cls, self.name).observe(
                     filter_ms)
@@ -1020,22 +1022,23 @@ class Shard:
                     self._record_device_success(br)
                 self._maybe_audit(audit_snap, q, k, allow, ids, dists)
                 t1 = time.perf_counter()
-                hydrated = self._hydrate_batch(ids, dists, include_vector)
-                t2 = time.perf_counter()
+                with tracing.Stopwatch("hydrate", rows=len(dists)) as hyd:
+                    hydrated = self._hydrate_batch(ids, dists,
+                                                   include_vector)
                 if rec is not None:
                     rec.phase("device_search", (t1 - t0) * 1000.0)
-                    rec.phase("hydrate", (t2 - t1) * 1000.0)
+                    rec.phase("hydrate", hyd.ms)
                 if shape is not None:
                     if filter_ms is not None:
                         shape.filter_ms = filter_ms
-                    shape.hydrate_ms = (t2 - t1) * 1000.0
+                    shape.hydrate_ms = hyd.ms
                 self._trace_dispatch_facts(rec, q.shape[0], k, lock_wait,
                                            shape)
                 if m is not None:
                     m.filtered_vector_search.labels(cls, self.name).observe(
                         (t1 - t0) * 1000.0)
                     m.filtered_vector_objects.labels(cls, self.name).observe(
-                        (t2 - t1) * 1000.0)
+                        hyd.ms)
                     m.vector_index_ops.labels("search", cls, self.name).inc(q.shape[0])
                     m.query_dimensions.labels("nearVector", "search", cls).inc(
                         int(q.shape[0] * q.shape[1]))
@@ -1111,18 +1114,17 @@ class Shard:
             self._maybe_audit(self._pop_audit_snap(), q, k, None, ids,
                               dists)
             t2 = time.perf_counter()
-            out = self.hydrate_raw_packed(ids, dists)
-            t3 = time.perf_counter()
+            with tracing.Stopwatch("hydrate", rows=len(dists)) as hyd:
+                out = self.hydrate_raw_packed(ids, dists)
             if rec is not None:
                 rec.phase("device_search", (t2 - t1) * 1000.0)
-                rec.phase("hydrate", (t3 - t2) * 1000.0)
+                rec.phase("hydrate", hyd.ms)
             if shape is not None:
-                shape.hydrate_ms = (t3 - t2) * 1000.0
+                shape.hydrate_ms = hyd.ms
             self._trace_dispatch_facts(rec, q.shape[0], k, lock_wait, shape)
             if m is not None:
                 m.filtered_vector_search.labels(cls, self.name).observe((t2 - t1) * 1000.0)
-                m.filtered_vector_objects.labels(cls, self.name).observe(
-                    (t3 - t2) * 1000.0)
+                m.filtered_vector_objects.labels(cls, self.name).observe(hyd.ms)
                 m.vector_index_ops.labels("search", cls, self.name).inc(q.shape[0])
                 m.query_dimensions.labels("nearVector", "search", cls).inc(
                     int(q.shape[0] * q.shape[1]))

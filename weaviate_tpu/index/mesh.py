@@ -81,7 +81,7 @@ from weaviate_tpu.index.tpu import (
 # dispatch-shape recording for the perf-attribution plane: a
 # costmodel.DispatchShape is built per dispatch ONLY while the tracer is
 # up (tracing.get_tracer() gate — the zero-cost-when-disabled contract);
-# shapes carry ndev so the roofline normalizes to per-chip work
+# shapes carry ndev, the chips one SPMD program spans
 from weaviate_tpu.monitoring import costmodel, tracing
 # memory ledger (monitoring/memory.py): per-device slab components are
 # stamped analytically at every buffer mutation; unconfigured => one
@@ -1333,181 +1333,189 @@ class MeshVectorIndex(VectorIndex):
         faults.fire("index.mesh.dispatch")
         shape = None
         t_enq0 = 0.0
+        enqueue = None
         if tracing.get_tracer() is not None:
-            t_enq0 = time.perf_counter()
-        q, b = self._prep_queries(vectors)
-        chunk = min(snap.n_loc, _MESH_SCAN_CHUNK)
-        kk = max(1, min(k, snap.live, chunk))
-        use_allow = allow_list is not None
-        words = self._allow_words(snap, allow_list) if use_allow else snap.zero_words
-        fused = fused_dispatch_enabled()
-        exact = getattr(self.config, "exact_topk", False)
+            enqueue = tracing.Phase("enqueue")
+            t_enq0 = enqueue.start_ns / 1e9
+        try:
+            q, b = self._prep_queries(vectors)
+            chunk = min(snap.n_loc, _MESH_SCAN_CHUNK)
+            kk = max(1, min(k, snap.live, chunk))
+            use_allow = allow_list is not None
+            words = self._allow_words(snap, allow_list) if use_allow else snap.zero_words
+            fused = fused_dispatch_enabled()
+            exact = getattr(self.config, "exact_topk", False)
 
-        if snap.compressed:
-            rescore = self.config.pq.rescore
-            packed_dev = None
-            funnel_budgets = None
-            if snap.codes4 is not None and snap.pq4 is not None:
-                # the 4-bit rung: per-chip three-stage funnel (nibble scan
-                # -> 8-bit ADC re-rank -> exact rescore against the chip's
-                # own store slab), budgets recall-guarded per shard
-                from weaviate_tpu.ops import pq4 as pq4_ops
-                from weaviate_tpu.ops import pq_gmin
+            if snap.compressed:
+                rescore = self.config.pq.rescore
+                packed_dev = None
+                funnel_budgets = None
+                if snap.codes4 is not None and snap.pq4 is not None:
+                    # the 4-bit rung: per-chip three-stage funnel (nibble scan
+                    # -> 8-bit ADC re-rank -> exact rescore against the chip's
+                    # own store slab), budgets recall-guarded per shard
+                    from weaviate_tpu.ops import pq4 as pq4_ops
+                    from weaviate_tpu.ops import pq_gmin
 
-                rg4, rc = self._funnel_budgets(kk, snap.n_loc)
-                if rc >= kk:
-                    _, flat_cb8 = pq_gmin.cached_cb_constants(self)
-                    packed_dev = mesh_search_pq4_step(
-                        snap.codes4,
+                    rg4, rc = self._funnel_budgets(kk, snap.n_loc)
+                    if rc >= kk:
+                        _, flat_cb8 = pq_gmin.cached_cb_constants(self)
+                        packed_dev = mesh_search_pq4_step(
+                            snap.codes4,
+                            snap.codes,
+                            snap.recon_norms4,
+                            snap.recon_norms,
+                            snap.tombs,
+                            snap.counts_dev,
+                            words,
+                            snap.pq4._dev_codebook(),
+                            flat_cb8,
+                            snap.store,
+                            jnp.asarray(q),
+                            snap.pq4.rotation_dev(),
+                            snap.slot_to_doc_dev,
+                            kk,
+                            self.metric,
+                            use_allow,
+                            rg4,
+                            rc,
+                            exact,
+                            fused,
+                            self.mesh,
+                        )
+                        funnel_budgets = (rg4, rc)
+                if packed_dev is None and not rescore:
+                    # codes-only tier: try the fused per-shard ADC kernel
+                    # (mesh twin of the single-chip pq_gmin dispatch)
+                    packed_dev = self._pq_gmin_step_or_none(
+                        snap, q, kk, words, use_allow, fused)
+                if packed_dev is None:
+                    nchunks_eff = max(1, snap.n_loc // chunk)
+                    pool_target = self.config.pq.rescore_limit or 1024
+                    r_chunk = min(
+                        max(2 * kk, -(-pool_target // nchunks_eff), 64), 256, chunk)
+                    # the concatenated per-chip pool must cover k (tpu.py:1080)
+                    r_chunk = max(r_chunk, min(-(-kk // nchunks_eff), chunk))
+                    packed_dev = mesh_search_pq_step(
                         snap.codes,
-                        snap.recon_norms4,
                         snap.recon_norms,
                         snap.tombs,
                         snap.counts_dev,
                         words,
-                        snap.pq4._dev_codebook(),
-                        flat_cb8,
+                        snap.pq._dev_codebook(),
                         snap.store,
                         jnp.asarray(q),
-                        snap.pq4.rotation_dev(),
+                        snap.pq.rotation_dev(),
                         snap.slot_to_doc_dev,
                         kk,
+                        r_chunk,
                         self.metric,
                         use_allow,
-                        rg4,
-                        rc,
                         exact,
+                        rescore,
                         fused,
                         self.mesh,
                     )
-                    funnel_budgets = (rg4, rc)
-            if packed_dev is None and not rescore:
-                # codes-only tier: try the fused per-shard ADC kernel
-                # (mesh twin of the single-chip pq_gmin dispatch)
-                packed_dev = self._pq_gmin_step_or_none(
-                    snap, q, kk, words, use_allow, fused)
-            if packed_dev is None:
-                nchunks_eff = max(1, snap.n_loc // chunk)
-                pool_target = self.config.pq.rescore_limit or 1024
-                r_chunk = min(
-                    max(2 * kk, -(-pool_target // nchunks_eff), 64), 256, chunk)
-                # the concatenated per-chip pool must cover k (tpu.py:1080)
-                r_chunk = max(r_chunk, min(-(-kk // nchunks_eff), chunk))
-                packed_dev = mesh_search_pq_step(
-                    snap.codes,
-                    snap.recon_norms,
-                    snap.tombs,
-                    snap.counts_dev,
-                    words,
-                    snap.pq._dev_codebook(),
-                    snap.store,
-                    jnp.asarray(q),
-                    snap.pq.rotation_dev(),
-                    snap.slot_to_doc_dev,
-                    kk,
-                    r_chunk,
-                    self.metric,
-                    use_allow,
-                    exact,
-                    rescore,
-                    fused,
-                    self.mesh,
-                )
-            if t_enq0:
-                if funnel_budgets is not None:
-                    rg4_s, rc_s = funnel_budgets
-                    shape = DispatchShape(
-                        TIER_PQ_ADC4, n=snap.n_total, dim=snap.dim, batch=b,
-                        batch_padded=q.shape[0],
-                        bytes_per_row=snap.pq4.segments // 2,
-                        k=int(kk), ndev=snap.n_dev,
-                        extra={
-                            # per-shard budgets x n_dev: whole-dispatch
-                            # survivor counts (bytes() attributes stages
-                            # 2/3 per batch row, costmodel.py)
-                            "funnel_c": rg4_s * 16 * snap.n_dev,
-                            "funnel_rescore": rc_s * snap.n_dev,
-                            "funnel_stage2_bytes_per_row": snap.pq.segments,
-                            "funnel_stage3_bytes_per_row":
-                                snap.dim * snap.store.dtype.itemsize,
-                        })
-                else:
-                    shape = DispatchShape(
-                        TIER_PQ_RESCORE if rescore else TIER_PQ_CODES,
-                        n=snap.n_total, dim=snap.dim, batch=b,
-                        batch_padded=q.shape[0],
-                        bytes_per_row=(snap.dim * snap.store.dtype.itemsize
-                                       if rescore else snap.pq.segments),
-                        k=int(kk), ndev=snap.n_dev)
-        else:
-            top_p = self._ivf_plan(snap, kk)
-            if top_p is not None:
-                nlist, cap_p, _gen = snap.ivf_meta
-                gp = ivf_ops.group_steps(q.shape[0], cap_p, snap.dim, top_p)
-                packed_dev = mesh_search_ivf_step(
-                    snap.store,
-                    snap.tombs,
-                    snap.counts_dev,
-                    words,
-                    snap.ivf_centroids,
-                    snap.ivf_buckets,
-                    jnp.asarray(q),
-                    snap.slot_to_doc_dev,
-                    kk,
-                    self.metric,
-                    use_allow,
-                    top_p,
-                    exact,
-                    gp,
-                    fused,
-                    self.mesh,
-                )
-                with self._ivf_lock:
-                    st = self._ivf_stats
-                    st["dispatches"] += 1
-                    st["probed_rows"] += snap.n_dev * top_p * cap_p
-                    st["base_rows"] += int(snap.n_total)
                 if t_enq0:
-                    probed = snap.n_dev * top_p * cap_p + nlist
-                    shape = DispatchShape(
-                        TIER_EXACT, n=probed, dim=snap.dim, batch=b,
-                        batch_padded=q.shape[0],
-                        bytes_per_row=snap.dim * snap.store.dtype.itemsize,
-                        k=int(kk), ndev=snap.n_dev,
-                        extra={"ivf": True, "ivf_top_p": top_p,
-                               "ivf_nlist": nlist,
-                               "probed_fraction": round(
-                                   min(probed / max(snap.n_total, 1), 1.0), 4)})
+                    if funnel_budgets is not None:
+                        rg4_s, rc_s = funnel_budgets
+                        shape = DispatchShape(
+                            TIER_PQ_ADC4, n=snap.n_total, dim=snap.dim, batch=b,
+                            batch_padded=q.shape[0],
+                            bytes_per_row=snap.pq4.segments // 2,
+                            k=int(kk), ndev=snap.n_dev,
+                            extra={
+                                # per-shard budgets x n_dev: whole-dispatch
+                                # survivor counts (bytes() attributes stages
+                                # 2/3 per batch row, costmodel.py)
+                                "funnel_c": rg4_s * 16 * snap.n_dev,
+                                "funnel_rescore": rc_s * snap.n_dev,
+                                "funnel_stage2_bytes_per_row": snap.pq.segments,
+                                "funnel_stage3_bytes_per_row":
+                                    snap.dim * snap.store.dtype.itemsize,
+                            })
+                    else:
+                        shape = DispatchShape(
+                            TIER_PQ_RESCORE if rescore else TIER_PQ_CODES,
+                            n=snap.n_total, dim=snap.dim, batch=b,
+                            batch_padded=q.shape[0],
+                            bytes_per_row=(snap.dim * snap.store.dtype.itemsize
+                                           if rescore else snap.pq.segments),
+                            k=int(kk), ndev=snap.n_dev)
             else:
-                packed_dev = self._gmin_step_or_none(
-                    snap, q, kk, words, use_allow, fused)
-                if packed_dev is None:
-                    packed_dev = mesh_search_step(
+                top_p = self._ivf_plan(snap, kk)
+                if top_p is not None:
+                    nlist, cap_p, _gen = snap.ivf_meta
+                    gp = ivf_ops.group_steps(q.shape[0], cap_p, snap.dim, top_p)
+                    packed_dev = mesh_search_ivf_step(
                         snap.store,
-                        snap.sq_norms,
                         snap.tombs,
                         snap.counts_dev,
                         words,
+                        snap.ivf_centroids,
+                        snap.ivf_buckets,
                         jnp.asarray(q),
                         snap.slot_to_doc_dev,
                         kk,
                         self.metric,
                         use_allow,
-                        self.metric == vi.DISTANCE_L2,
+                        top_p,
                         exact,
+                        gp,
                         fused,
                         self.mesh,
                     )
-                if t_enq0:
-                    shape = DispatchShape(
-                        TIER_EXACT, n=snap.n_total, dim=snap.dim, batch=b,
-                        batch_padded=q.shape[0],
-                        bytes_per_row=snap.dim * snap.store.dtype.itemsize,
-                        k=int(kk), ndev=snap.n_dev)
+                    with self._ivf_lock:
+                        st = self._ivf_stats
+                        st["dispatches"] += 1
+                        st["probed_rows"] += snap.n_dev * top_p * cap_p
+                        st["base_rows"] += int(snap.n_total)
+                    if t_enq0:
+                        probed = snap.n_dev * top_p * cap_p + nlist
+                        shape = DispatchShape(
+                            TIER_EXACT, n=probed, dim=snap.dim, batch=b,
+                            batch_padded=q.shape[0],
+                            bytes_per_row=snap.dim * snap.store.dtype.itemsize,
+                            k=int(kk), ndev=snap.n_dev,
+                            extra={"ivf": True, "ivf_top_p": top_p,
+                                   "ivf_nlist": nlist,
+                                   "probed_fraction": round(
+                                       min(probed / max(snap.n_total, 1), 1.0), 4)})
+                else:
+                    packed_dev = self._gmin_step_or_none(
+                        snap, q, kk, words, use_allow, fused)
+                    if packed_dev is None:
+                        packed_dev = mesh_search_step(
+                            snap.store,
+                            snap.sq_norms,
+                            snap.tombs,
+                            snap.counts_dev,
+                            words,
+                            jnp.asarray(q),
+                            snap.slot_to_doc_dev,
+                            kk,
+                            self.metric,
+                            use_allow,
+                            self.metric == vi.DISTANCE_L2,
+                            exact,
+                            fused,
+                            self.mesh,
+                        )
+                    if t_enq0:
+                        shape = DispatchShape(
+                            TIER_EXACT, n=snap.n_total, dim=snap.dim, batch=b,
+                            batch_padded=q.shape[0],
+                            bytes_per_row=snap.dim * snap.store.dtype.itemsize,
+                            k=int(kk), ndev=snap.n_dev)
+        except BaseException:
+            if enqueue is not None:  # a dispatch that failed being built
+                enqueue.end()
+            raise
 
         if shape is not None:
+            now_ns = enqueue.end(rows=b, tier=shape.tier)
             shape.t_start = t_enq0
-            shape.enqueue_ms = (time.perf_counter() - t_enq0) * 1000.0
+            shape.enqueue_ms = (now_ns - enqueue.start_ns) / 1e6
             if fused:
                 shape.fused = True
                 shape.translate_ms = 0.0
@@ -1540,8 +1548,10 @@ class MeshVectorIndex(VectorIndex):
                 if shape.fetches:
                     shape.fetches = 0  # a retried finalize re-counts
                 t0 = time.perf_counter()
-                out = finish()
-                t1 = time.perf_counter()
+                try:
+                    out = finish()
+                finally:  # also when the host half of finalize raised
+                    t1 = shape.end_hop()
                 shape.finalize_ms = (t1 - t0) * 1000.0
                 shape.t_end = t1
                 return out

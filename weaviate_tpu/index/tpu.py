@@ -341,17 +341,23 @@ def _unpack(packed: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
 
 def _fetch_packed(packed_dev, shape=None) -> np.ndarray:
     """The ONE blocking device->host fetch of a dispatch's finalize. With a
-    perf shape attached (tracer up), stamps the fetch duration as the
-    ledger's `device` stage — what finalize spends blocked on the device —
-    so the gather-hop split (finalize minus fetch) is measurable; without
-    one (disabled path) this is exactly np.asarray."""
+    perf shape attached (tracer up), the blocked time is the `device_wait`
+    interval and the ledger's `device` stage — what finalize spends blocked
+    on the device — and the `gather_hop` interval opens at its end
+    (finalize closes it); without one (disabled path) this is exactly
+    np.asarray."""
     if shape is None:
         return np.asarray(packed_dev)
-    t0 = time.perf_counter()
-    out = np.asarray(packed_dev)
+    shape.end_hop()  # a second fetch of one finalize ends the first one's hop
+    wait = tracing.Phase("device_wait", rows=shape.batch, tier=shape.tier)
+    try:
+        out = np.asarray(packed_dev)
+    finally:
+        end_ns = wait.end()
+    shape.hop = tracing.Phase("gather_hop", rows=shape.batch)
     shape.fetches += 1  # the fused-dispatch invariant counts these
-    shape.t_fetch = time.perf_counter()
-    shape.device_ms = (shape.t_fetch - t0) * 1000.0
+    shape.t_fetch = end_ns / 1e9
+    shape.device_ms = (end_ns - wait.start_ns) / 1e6
     # duty-cycle anchor: the in-flight interval ends HERE, not at the
     # perf window's record call (hydration runs in between)
     shape.t_fetch_mono = time.monotonic()
@@ -2895,89 +2901,96 @@ class TpuVectorIndex(VectorIndex):
         # (pop_dispatch_shape, the pop_read_lock_wait idiom).
         shape = None
         t_enq0 = 0.0
+        enqueue = None
         if tracing.get_tracer() is not None:
-            t_enq0 = time.perf_counter()
-        q, b = self._prep_queries_staged(vectors)
-        stage_buf = q  # returned to the pool by the finalize wrapper
-        k_eff = min(k, snap.live)
-        # fused dispatch: the device translation table rides the snapshot,
-        # so the program's final top-k emits doc ids directly (the legacy
-        # host slot_to_doc translation only runs with the toggle off)
-        s2d = (snap.slot_to_doc_dev
-               if fused_dispatch_enabled() else None)
-        if allow_list is not None and len(allow_list) < self.config.flat_search_cutoff:
-            if t_enq0:
-                shape = costmodel.DispatchShape(
-                    costmodel.TIER_GATHER,
-                    n=min(len(allow_list), snap.live), dim=snap.dim,
-                    batch=b, batch_padded=q.shape[0],
-                    bytes_per_row=snap.dim * 4, k=int(k_eff))
-            fin = self._dispatch_small_allow(snap, q, b, k_eff, allow_list,
+            enqueue = tracing.Phase("enqueue")
+            t_enq0 = enqueue.start_ns / 1e9
+        try:
+            q, b = self._prep_queries_staged(vectors)
+            stage_buf = q  # returned to the pool by the finalize wrapper
+            k_eff = min(k, snap.live)
+            # fused dispatch: the device translation table rides the snapshot,
+            # so the program's final top-k emits doc ids directly (the legacy
+            # host slot_to_doc translation only runs with the toggle off)
+            s2d = (snap.slot_to_doc_dev
+                   if fused_dispatch_enabled() else None)
+            if allow_list is not None and len(allow_list) < self.config.flat_search_cutoff:
+                if t_enq0:
+                    shape = costmodel.DispatchShape(
+                        costmodel.TIER_GATHER,
+                        n=min(len(allow_list), snap.live), dim=snap.dim,
+                        batch=b, batch_padded=q.shape[0],
+                        bytes_per_row=snap.dim * 4, k=int(k_eff))
+                fin = self._dispatch_small_allow(snap, q, b, k_eff, allow_list,
+                                                 shape, s2d)
+            elif (ivf_plan := self._ivf_plan(snap, k_eff)) is not None:
+                # partition-pruned path (ROADMAP item 3): scan only the
+                # probed buckets; large allowLists compose via the same
+                # packed words, small ones took the gather tier above
+                if t_enq0:
+                    shape = self._ivf_shape(snap, ivf_plan, b, q.shape[0],
+                                            k_eff)
+                fin = self._dispatch_ivf(snap, q, b, k_eff, allow_list,
+                                         ivf_plan, shape, s2d)
+            elif snap.compressed:
+                if t_enq0:
+                    rescore = (self.config.pq.rescore
+                               and snap.rescore_dev is not None)
+                    funnel = (snap.codes4 is not None
+                              and self.metric in (vi.DISTANCE_L2,
+                                                  vi.DISTANCE_DOT,
+                                                  vi.DISTANCE_COSINE))
+                    if funnel:
+                        # the 4-bit funnel tier: stage 1 reads M/2 packed
+                        # bytes per scanned row; the re-ranking stages are
+                        # attributed in extra (C/c rows at M and 2·D bytes)
+                        # — a mid-dispatch refusal re-labels this below
+                        rg4_s, rc_s = self._funnel_budgets(
+                            int(k_eff), snap.capacity)
+                        shape = costmodel.DispatchShape(
+                            costmodel.TIER_PQ_ADC4,
+                            n=snap.n, dim=snap.dim, batch=b,
+                            batch_padded=q.shape[0],
+                            bytes_per_row=snap.pq4.segments // 2,
+                            k=int(k_eff),
+                            extra={"funnel_c": rg4_s * 16,
+                                   "funnel_rescore": rc_s,
+                                   "funnel_stage2_bytes_per_row":
+                                       snap.pq.segments,
+                                   "funnel_stage3_bytes_per_row":
+                                       (2 * snap.dim if rescore else 0)})
+                    else:
+                        shape = costmodel.DispatchShape(
+                            costmodel.TIER_PQ_RESCORE if rescore
+                            else costmodel.TIER_PQ_CODES,
+                            n=snap.n, dim=snap.dim, batch=b,
+                            batch_padded=q.shape[0],
+                            # rescore scans the bf16 copy (2·D); codes-only
+                            # reads the uint8 codes (M = segments bytes/row)
+                            bytes_per_row=(2 * snap.dim if rescore
+                                           else snap.pq.segments),
+                            k=int(k_eff))
+                fin = self._dispatch_full_pq(snap, q, b, k_eff, allow_list,
                                              shape, s2d)
-        elif (ivf_plan := self._ivf_plan(snap, k_eff)) is not None:
-            # partition-pruned path (ROADMAP item 3): scan only the
-            # probed buckets; large allowLists compose via the same
-            # packed words, small ones took the gather tier above
-            if t_enq0:
-                shape = self._ivf_shape(snap, ivf_plan, b, q.shape[0],
-                                        k_eff)
-            fin = self._dispatch_ivf(snap, q, b, k_eff, allow_list,
-                                     ivf_plan, shape, s2d)
-        elif snap.compressed:
-            if t_enq0:
-                rescore = (self.config.pq.rescore
-                           and snap.rescore_dev is not None)
-                funnel = (snap.codes4 is not None
-                          and self.metric in (vi.DISTANCE_L2,
-                                              vi.DISTANCE_DOT,
-                                              vi.DISTANCE_COSINE))
-                if funnel:
-                    # the 4-bit funnel tier: stage 1 reads M/2 packed
-                    # bytes per scanned row; the re-ranking stages are
-                    # attributed in extra (C/c rows at M and 2·D bytes)
-                    # — a mid-dispatch refusal re-labels this below
-                    rg4_s, rc_s = self._funnel_budgets(
-                        int(k_eff), snap.capacity)
+            else:
+                if t_enq0:
                     shape = costmodel.DispatchShape(
-                        costmodel.TIER_PQ_ADC4,
-                        n=snap.n, dim=snap.dim, batch=b,
-                        batch_padded=q.shape[0],
-                        bytes_per_row=snap.pq4.segments // 2,
-                        k=int(k_eff),
-                        extra={"funnel_c": rg4_s * 16,
-                               "funnel_rescore": rc_s,
-                               "funnel_stage2_bytes_per_row":
-                                   snap.pq.segments,
-                               "funnel_stage3_bytes_per_row":
-                                   (2 * snap.dim if rescore else 0)})
-                else:
-                    shape = costmodel.DispatchShape(
-                        costmodel.TIER_PQ_RESCORE if rescore
-                        else costmodel.TIER_PQ_CODES,
-                        n=snap.n, dim=snap.dim, batch=b,
-                        batch_padded=q.shape[0],
-                        # rescore scans the bf16 copy (2·D); codes-only
-                        # reads the uint8 codes (M = segments bytes/row)
-                        bytes_per_row=(2 * snap.dim if rescore
-                                       else snap.pq.segments),
+                        costmodel.TIER_EXACT, n=snap.n, dim=snap.dim,
+                        batch=b, batch_padded=q.shape[0],
+                        bytes_per_row=snap.dim * snap.store.dtype.itemsize,
                         k=int(k_eff))
-            fin = self._dispatch_full_pq(snap, q, b, k_eff, allow_list,
-                                         shape, s2d)
-        else:
-            if t_enq0:
-                shape = costmodel.DispatchShape(
-                    costmodel.TIER_EXACT, n=snap.n, dim=snap.dim,
-                    batch=b, batch_padded=q.shape[0],
-                    bytes_per_row=snap.dim * snap.store.dtype.itemsize,
-                    k=int(k_eff))
-            allow_words = (self._allow_words(snap, allow_list)
-                           if allow_list is not None else None)
-            fin = self._dispatch_scan(snap, q, b, k_eff, allow_words,
-                                      shape=shape, s2d=s2d)
+                allow_words = (self._allow_words(snap, allow_list)
+                               if allow_list is not None else None)
+                fin = self._dispatch_scan(snap, q, b, k_eff, allow_words,
+                                          shape=shape, s2d=s2d)
+        except BaseException:
+            if enqueue is not None:  # a dispatch that failed being built
+                enqueue.end()
+            raise
         if shape is not None:
-            now = time.perf_counter()
+            now_ns = enqueue.end(rows=b, tier=shape.tier)
             shape.t_start = t_enq0
-            shape.enqueue_ms = (now - t_enq0) * 1000.0
+            shape.enqueue_ms = (now_ns - enqueue.start_ns) / 1e6
             if s2d is not None:
                 # the fused-dispatch ledger invariant: one blocking fetch,
                 # zero host-translation time (test-pinned; the perf window
@@ -3013,9 +3026,11 @@ class TpuVectorIndex(VectorIndex):
                     # fetch violation in /debug/perf
                     shape.fetches = 0
                 t0 = time.perf_counter()
-                out = fin()
-                fetched = True
-                t1 = time.perf_counter()
+                try:
+                    out = fin()
+                    fetched = True
+                finally:  # also when the host half of finalize raised
+                    t1 = shape.end_hop()
                 shape.finalize_ms = (t1 - t0) * 1000.0
                 shape.t_end = t1
                 return out

@@ -1,14 +1,14 @@
 """Analytic device cost model: FLOPs/bytes per dispatch vs platform peaks.
 
-One roofline model shared by every consumer — bench.py's offline matrix
-rows, the serving path's per-dispatch attribution (monitoring/tracing.py
-DispatchRecord facts), the rolling perf window behind ``/debug/perf``
-(monitoring/perf.py), and the BM25 device engine's batch-shape recording
-(inverted/bm25_device.py). Before this module the model lived only in
-bench.py (``PEAKS``/``_roofline``) plus an ad-hoc stats dict in the BM25
-engine, so the serving path could not say where a dispatch sat against the
-hardware; now bench and serving compute the same numbers from the same
-formulas.
+The shape of a dispatch (``DispatchShape``: tier, rows scanned, bytes per
+row) with the host-overhead ledger the index stamps on it while it runs,
+read by the serving path (monitoring/tracing.py DispatchRecord facts, the
+rolling window behind ``/debug/perf`` in monitoring/perf.py) and by the
+BM25 device engine's batch-shape recording (inverted/bm25_device.py); and
+the roofline arithmetic of bench.py's offline rows. The served path computes
+no roofline of its own: a share of the chip's peaks is read from a
+profiler capture (benchmarks/readers/xplane_ops.py), not from analytic
+work over a host wall.
 
 Conventions (inherited from the bench model, kept deliberately):
 
@@ -37,6 +37,7 @@ so index/db/serving layers can import it without cycles or backend init.
 from __future__ import annotations
 
 import os
+import time
 from typing import Optional
 
 # -- platform peaks -----------------------------------------------------------
@@ -139,7 +140,7 @@ class DispatchShape:
                  "enqueue_ms", "device_ms", "finalize_ms",
                  "filter_ms", "hydrate_ms", "t_start", "t_end",
                  "t_fetch", "t_fetch_mono", "fused", "fetches",
-                 "translate_ms")
+                 "translate_ms", "hop")
 
     def __init__(self, tier: str, n: int, dim: float, batch: int,
                  bytes_per_row: float, k: int = 0,
@@ -155,7 +156,7 @@ class DispatchShape:
         self.extra = extra
         # devices the SPMD program spans (mesh dispatches): `n` stays the
         # GLOBAL row count so flops()/bytes() keep reporting whole-dispatch
-        # work; per-chip attribution divides by ndev (monitoring/perf.py)
+        # work; a per-chip reading divides by ndev
         self.ndev = max(int(ndev), 1)
         self.enqueue_ms = -1.0
         self.device_ms = -1.0
@@ -183,6 +184,9 @@ class DispatchShape:
         self.fused = False
         self.fetches = 0
         self.translate_ms = -1.0
+        # the open `gather_hop` interval (a tracing.Phase): _fetch_packed
+        # opens it at fetch end, the dispatch's finalize closes it
+        self.hop = None
 
     # -- analytic totals -----------------------------------------------------
 
@@ -220,6 +224,12 @@ class DispatchShape:
             return -1.0
         return max(self.finalize_ms - self.device_ms, 0.0)
 
+    def end_hop(self) -> float:
+        """Close the open `gather_hop` interval (finalize, on the thread
+        that fetched) -> the perf_counter seconds at its end."""
+        hop, self.hop = self.hop, None
+        return hop.end() / 1e9 if hop is not None else time.perf_counter()
+
     def ledger(self) -> dict:
         """{phase: ms} of every measured host-overhead ledger stage."""
         out = {}
@@ -254,11 +264,6 @@ class DispatchShape:
         rows: QPS is per query row, batches/s = qps/batch)."""
         return roofline_from_qps(qps, self.n, self.dim, self.batch,
                                  self.bytes_per_row, backend)
-
-    def roofline(self, seconds: float, backend: Optional[str] = None) -> dict:
-        """Per-dispatch roofline: this shape's work over `seconds` of
-        device time."""
-        return roofline(self.flops(), self.bytes(), seconds, backend)
 
 
 def fused_invariant_ok(shape: "DispatchShape") -> bool:
@@ -332,25 +337,3 @@ def roofline_from_qps(qps, n, dim, batch, bytes_per_row,
     batches_per_s = qps / batch
     return roofline(flops_per_batch * batches_per_s,
                     bytes_per_batch * batches_per_s, 1.0, backend)
-
-
-# -- exact attribution split --------------------------------------------------
-
-def split_exact(total: int, rows: list, rows_total: int) -> list:
-    """Split an integer `total` (flops/bytes) across riders proportionally
-    to their `rows`, such that the parts SUM BIT-EXACTLY to the covered
-    fraction: part_i = round(T·c_i/R) - round(T·c_{i-1}/R) over cumulative
-    rows c — a telescoping sum, so when the riders cover all rows_total
-    rows, sum(parts) == total with no float residue (the flops/bytes twin
-    of the PR-3 device-time identity)."""
-    total = int(total)
-    rt = max(int(rows_total), 1)
-    out = []
-    cum = 0
-    prev = 0
-    for r in rows:
-        cum += int(r)
-        edge = (total * cum + rt // 2) // rt  # integer round-half-up
-        out.append(edge - prev)
-        prev = edge
-    return out

@@ -816,9 +816,15 @@ class MemoryLedger:
                 m.memory_drift.labels("device").set(drift)
             except Exception:  # noqa: BLE001
                 pass
-        return {"allocator_bytes_in_use": in_use,
-                "ledger_per_device_bytes": per_dev,
-                "drift_bytes": drift}
+        out = {"allocator_bytes_in_use": in_use,
+               "ledger_per_device_bytes": per_dev,
+               "drift_bytes": drift}
+        if "peak_bytes_in_use" in stats:
+            # the allocator's high-water mark since the process started:
+            # what a transient (a dispatch's workspace, a COW copy) reached
+            # that bytes_in_use, read after the fact, no longer shows
+            out["allocator_peak_bytes"] = int(stats["peak_bytes_in_use"])
+        return out
 
     def summary(self) -> dict:
         """The /debug/memory body: device/host/disk component tables +

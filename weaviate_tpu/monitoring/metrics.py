@@ -334,25 +334,15 @@ class Metrics:
             "weaviate_coalescer_queue_depth)",
             ("tenant",))
 
-        # continuous device-performance attribution (monitoring/perf.py):
-        # rolling-window roofline gauges + the host-overhead ledger's
-        # per-dispatch phase shares. Registered once here (the coalescer
-        # pattern); the perf window only touches them inside try/except.
-        self.device_mfu = g(
-            "weaviate_device_mfu_pct",
-            "achieved model FLOPs utilization over the rolling perf "
-            "window, percent of platform peak (wall-clock form — the "
-            "serving-level number; the device-busy form is in "
-            "/debug/perf)")
-        self.device_hbm_bw = g(
-            "weaviate_device_hbm_bw_pct",
-            "achieved HBM bandwidth over the rolling perf window, "
-            "percent of platform peak")
+        # the rolling perf window (monitoring/perf.py): the duty-cycle
+        # gauge + the host-overhead ledger's per-dispatch phase shares.
+        # Registered once here (the coalescer pattern); the perf window
+        # only touches them inside try/except.
         self.device_duty_cycle = g(
             "weaviate_device_duty_cycle",
             "fraction of wall-clock with an in-flight device dispatch "
-            "(enqueue->fetch intervals, overlap-merged) — low duty at "
-            "high kernel MFU = the orchestration gap")
+            "(enqueue->fetch intervals, overlap-merged), as the host "
+            "sees it")
         self.perf_phase_share = Histogram(
             "weaviate_perf_phase_share",
             "per-dispatch share of the host-overhead ledger "

@@ -1,47 +1,41 @@
-"""Continuous device-performance attribution: the rolling perf window.
+"""The host side of a dispatch, measured continuously: the rolling perf window.
 
-The r05 chip session measured 13.7k QPS at 1.78% MFU on ONE manual
-profile; the hypothesis — host-side gather/rescore and per-dispatch
-orchestration dominate — needs a *continuous* measurement so the fused
-multi-stage search (ROADMAP items 1-3) gets a real before/after. This
-module aggregates what the dispatch plane records:
+What the device does is read from a profiler capture and nowhere else
+(benchmarks/lib/xplane.py); this module keeps what the HOST did around it:
 
-- every device dispatch's analytic cost (costmodel.DispatchShape: flops,
-  bytes, tier) and host-overhead ledger (enqueue / device fetch /
-  gather hop / hydrate), fed by db/shard.py for EVERY dispatch while the
-  tracer is up — full coverage, independent of trace sampling;
-- per-request queue waits and per-dispatch scatter times from the
-  coalescer (``note_phase``);
+- the **ledger**: per-phase durations over the last ``window_s`` seconds
+  (``decode`` / ``queue_wait`` / ``filter`` / ``enqueue`` / ``device`` /
+  ``gather_hop`` / ``hydrate`` / ``scatter`` / ``encode``), fed by
+  db/shard.py for EVERY dispatch while the tracer is up (full coverage,
+  independent of trace sampling), by the coalescer per admitted request
+  and by the gRPC entry per sampled request;
 - the **device duty cycle**: the fraction of wall-clock with an in-flight
   device dispatch, integrated from [enqueue-start, fetch-end] intervals.
-  kernel-level MFU high + duty cycle low = the orchestration gap; both
-  high = the kernel itself is the limit. This is the number that directly
-  tests the orchestration-gap hypothesis.
+  It is the lane controller's sensor (``control_signals``), not a device
+  measurement: the chip's own busy share comes from a capture;
+- the **capture log**: while ``profiling.device_trace`` has a profiler
+  session open, every closed host phase is kept as ``(name, thread id,
+  start_ns, end_ns)`` on ``time.perf_counter_ns``, anchored at the stamp
+  taken immediately before ``start_trace`` -- the profiler's own zero. The
+  last finished capture is ``/debug/perf``'s ``capture``: the host's
+  timeline on the same clock as the xplane's device lines, and the ledger
+  restricted to the very seconds the xplane covers
+  (benchmarks/readers/host_gaps.py gives each device gap to the phase
+  that was open in it).
 
-Exposure: rolling-window Prometheus gauges (``weaviate_device_mfu_pct``,
-``weaviate_device_hbm_bw_pct``, ``weaviate_device_duty_cycle``), a
-per-dispatch phase-share histogram (``weaviate_perf_phase_share``), the
-``GET /debug/perf`` window summary (server/rest.py, same authorizer as
-pprof), and the ``roofline``/``duty_cycle``/``phase_share`` fields on
-bench.py serving rows.
+Exposure: ``GET /debug/perf`` (server/rest.py, same authorizer as pprof),
+the gauge ``weaviate_device_duty_cycle`` and the per-dispatch phase-share
+histogram ``weaviate_perf_phase_share``.
 
 Lifecycle mirrors the tracer (monitoring/tracing.py): a process-wide
 module global installed by App when TRACING_ENABLED is set, None
-otherwise — every serving-path entry point is then a one-comparison
+otherwise -- every serving-path entry point is then a one-comparison
 no-op and constructs nothing (spy-pinned in tests/test_perf.py).
 
-The QUALITY twin of this window lives in monitoring/quality.py: the
-shadow recall auditor measures what the serving path ANSWERS (recall,
-rank overlap, distance error at ``GET /debug/quality``) the way this
-window measures what it COSTS — same rolling-window idiom, same
-zero-cost-disabled lifecycle, same authorizer.
-
-The CAPSTONE consumer is the incident plane (monitoring/incidents.py):
-``summary()`` is captured verbatim into every flight-recorder bundle, so
-a breaker trip or SLO burn preserves the window's duty-cycle/roofline/
-ledger picture at the moment of the incident — and
-``recent_summaries()`` keeps the last windows reachable even after the
-owning App is torn down (the bench's emergency dump reads it).
+``summary()`` is captured verbatim into every incident bundle
+(monitoring/incidents.py) and ``recent_summaries()`` keeps the last
+windows reachable after the owning App is torn down; neither carries the
+capture log.
 """
 
 from __future__ import annotations
@@ -53,10 +47,15 @@ from typing import Optional
 
 from weaviate_tpu.monitoring import costmodel
 
-# ledger stages in display order (the /debug/perf breakdown; scatter is
-# fed by the coalescer, queue_wait per admitted request)
-PHASES = ("queue_wait", "filter", "enqueue", "device", "gather_hop",
-          "hydrate", "scatter")
+# ledger stages in display order (the /debug/perf breakdown; decode and
+# encode are fed by the gRPC entry per sampled request, queue_wait and
+# scatter by the coalescer, the rest by the shard per dispatch)
+PHASES = ("decode", "queue_wait", "filter", "enqueue", "device",
+          "gather_hop", "hydrate", "scatter", "encode")
+
+# intervals one capture keeps; beyond it they are counted as `dropped`
+# (a 5 s capture of the busiest cell closes about 3,000)
+CAPTURE_LOG_MAX = 65536
 
 # per-phase sample cap (deque maxlen): queue_wait gets one sample per
 # ADMITTED REQUEST, so a 60 s window at r05-scale QPS (~13.7k/s) would
@@ -111,8 +110,7 @@ class DutyCycle:
 
     def busy_s(self, now: Optional[float] = None) -> float:
         """Merged busy seconds within the trailing window. The PerfWindow
-        divides this by ITS observed span so duty and the window roofline
-        share one denominator."""
+        divides this by ITS observed span."""
         now = time.monotonic() if now is None else now
         self._trim(now)
         return max(self._busy_total, 0.0)
@@ -131,39 +129,40 @@ class DutyCycle:
 
 
 class PerfWindow:
-    """Rolling-window aggregate of dispatch cost + host-overhead ledgers.
+    """Rolling-window aggregate of the host-overhead ledger, the duty
+    cycle, and the capture log.
 
     ``record_dispatch`` is the per-dispatch hot-path entry: one lock, O(1)
     amortized (eviction pops), gauge sets guarded so a broken metrics
     stack can never take down serving. ``summary()`` is the on-demand
-    /debug/perf body."""
+    /debug/perf body (with ``last_capture()`` beside it)."""
 
     def __init__(self, window_s: float = 60.0, metrics=None,
-                 backend: Optional[str] = None,
                  sample_hint: float = 1.0):
         self.window_s = max(float(window_s), 1e-3)
         self.metrics = metrics
-        self.backend = backend or costmodel.detect_backend()
         # trace sample rate, surfaced in the summary: dispatch coverage
         # here is FULL (shard feeds every dispatch while the tracer is
         # up), but readers correlating with /debug/traces need the rate
         self.sample_hint = float(sample_hint)
         self._lock = threading.Lock()
-        # (t_end_mono, flops, bytes, device_s, wall_s, tier, regime, rows)
+        # (t_end_mono, tier, rows, fused, fused-invariant violated)
         self._entries: deque = deque()
         # phase name -> deque[(t_mono, ms)], count-capped (see
         # _PHASE_SAMPLES_MAX) on top of the time-horizon eviction
         self._phase: dict[str, deque] = {
             p: deque(maxlen=_PHASE_SAMPLES_MAX) for p in PHASES}
         self._duty = DutyCycle(self.window_s)
-        # running sums over the live window (evicted incrementally)
-        self._flops = 0
-        self._bytes = 0
-        self._device_s = 0.0
-        self._rows = 0
-        self._started = time.monotonic()
+        self._rows = 0  # running sum over the live window
         self._first_entry: Optional[float] = None
         self._total_dispatches = 0  # lifetime, never evicted
+        # the capture log: a list of (name, tid, start_ns, end_ns) while
+        # profiling.device_trace has a session open, None otherwise --
+        # note_interval's one comparison outside a capture
+        self._capture_log: Optional[list] = None
+        self._capture_dropped = 0
+        self._captures = 0
+        self._last_capture: Optional[dict] = None
 
     # -- hot path ------------------------------------------------------------
 
@@ -173,80 +172,59 @@ class PerfWindow:
         for every dispatch while the perf plane is up."""
         now = time.monotonic()
         ledger = shape.ledger()
-        device_s = max(shape.device_ms, 0.0) / 1000.0
-        flops = shape.flops()
-        byts = shape.bytes()
-        # mesh dispatches (shape.ndev > 1) count GLOBAL work in n — the
-        # whole sharded program's rows. The roofline compares achieved
-        # rates against ONE chip's peak, so normalize to per-chip work;
-        # arithmetic intensity (flops/bytes) is unchanged by the division,
-        # so the regime classification stays identical
-        nd = max(int(getattr(shape, "ndev", 1)), 1)
-        if nd > 1:
-            flops //= nd
-            byts //= nd
-        regime = (costmodel.regime(flops, byts, self.backend)
-                  if device_s > 0.0 else None)
         # the shape's wall endpoints are perf_counter stamps; the window
         # runs on time.monotonic. Only DURATIONS are trusted
-        # (clock-agnostic deltas); the in-flight interval — enqueue start
-        # to FETCH end, the device-busy span — is anchored at the
-        # monotonic fetch stamp `_fetch_packed` took (NOT at this record
-        # call: hydration runs in between, and re-anchoring here would
-        # shift concurrent dispatches' intervals by their differing
-        # hydrate times and corrupt the overlap merge)
+        # (clock-agnostic deltas); the in-flight interval -- enqueue start
+        # to FETCH end -- is anchored at the monotonic fetch stamp
+        # `_fetch_packed` took (NOT at this record call: hydration runs in
+        # between, and re-anchoring here would shift concurrent
+        # dispatches' intervals by their differing hydrate times and
+        # corrupt the overlap merge)
         wall_s = max(shape.t_end - shape.t_start, 0.0)
         # no fetch stamp = no device call ran (an empty gather-tier early
-        # return): it must contribute NO duty interval — counting its
+        # return): it must contribute NO duty interval -- counting its
         # host-only wall as "device in flight" would read near-1.0 duty on
         # a workload whose device is idle, inverting the signal
         inflight_s = (max(shape.t_fetch - shape.t_start, 0.0)
                       if shape.t_fetch > 0.0 else 0.0)
         fetch_end = (shape.t_fetch_mono
                      if 0.0 < shape.t_fetch_mono <= now else now)
-        fused = bool(shape.fused)
         # the fused-dispatch invariant (one blocking fetch, zero host
-        # translation): violations are counted per window — a fused
+        # translation): violations are counted per window -- a fused
         # dispatch quietly re-growing host translation work must be
         # dashboard-visible, not just test-pinned
         viol = not costmodel.fused_invariant_ok(shape)
+        nrows = int(rows) or shape.batch
         with self._lock:
             self._evict(now)
             self._entries.append(
-                (now, flops, byts, device_s, shape.tier, regime,
-                 int(rows) or shape.batch, fused, viol))
-            self._flops += flops
-            self._bytes += byts
-            self._device_s += device_s
-            self._rows += int(rows) or shape.batch
+                (now, shape.tier, nrows, bool(shape.fused), viol))
+            self._rows += nrows
             self._total_dispatches += 1
             if self._first_entry is None:
-                # anchor the observed span at this dispatch's START so
-                # the first entry's window roofline divides by its own
-                # wall, not by an epsilon
+                # anchor the observed span at this dispatch's START, so
+                # the first entry's duty divides by its own wall
                 self._first_entry = now - wall_s
             for name, ms in ledger.items():
                 self._phase[name].append((now, ms))
             if inflight_s > 0.0:
                 self._duty.record(fetch_end - inflight_s, fetch_end)
             duty = self._duty_locked(now)
-            mfu, bw = self._window_roofline_locked(now)
         m = self.metrics
         if m is not None:
             try:
                 m.device_duty_cycle.set(duty)
-                m.device_mfu.set(mfu)
-                m.device_hbm_bw.set(bw)
                 total = sum(ledger.values())
                 if total > 0.0:
                     for name, ms in ledger.items():
                         m.perf_phase_share.labels(name).observe(ms / total)
-            except Exception:  # noqa: BLE001 — metrics must not break serving
+            except Exception:  # noqa: BLE001 -- metrics must not break serving
                 pass
 
     def note_phase(self, name: str, ms: float) -> None:
         """Record one sample of a ledger stage measured outside the shard
-        dispatch (coalescer queue_wait per request, scatter per lane)."""
+        dispatch (coalescer queue_wait per request, scatter per lane, the
+        gRPC entry's decode and encode per sampled request)."""
         now = time.monotonic()
         with self._lock:
             d = self._phase.get(name)
@@ -259,14 +237,78 @@ class PerfWindow:
             while d and d[0][0] < horizon:
                 d.popleft()
 
+    def note_interval(self, name: str, start_ns: int, end_ns: int,
+                      tid: Optional[int] = None) -> None:
+        """One closed host phase on ``time.perf_counter_ns``; `tid` is the
+        OS id of the thread it ran on (the id the profiler's host lines
+        carry), the calling thread's by default. Kept only while a
+        capture is open."""
+        if self._capture_log is None:
+            return
+        if tid is None:
+            tid = threading.get_native_id()
+        with self._lock:
+            log = self._capture_log
+            if log is None:
+                return
+            if len(log) < CAPTURE_LOG_MAX:
+                log.append((name, tid, int(start_ns), int(end_ns)))
+            else:
+                self._capture_dropped += 1
+
+    # -- the capture (monitoring/profiling.py device_trace) ------------------
+
+    def capture_begin(self) -> None:
+        """A profiler session is about to start: open the capture log."""
+        with self._lock:
+            self._capture_log = []
+            self._capture_dropped = 0
+
+    def capture_end(self, t0_ns: int, t1_ns: int, options: dict) -> None:
+        """The session is over: close the log and keep the capture as
+        ``last_capture()``. `t0_ns` was stamped immediately before
+        ``start_trace`` (the xplane's zero, to within the anchor error
+        PERF.md records), `t1_ns` when the traced span ended, immediately
+        before ``stop_trace``; every published time is relative to
+        `t0_ns`."""
+        with self._lock:
+            log, self._capture_log = self._capture_log or [], None
+            dropped = self._capture_dropped
+            self._captures += 1
+            cid = self._captures
+        by_name: dict[str, list] = {}
+        for name, _, s, e in log:
+            by_name.setdefault(name, []).append((e - s) / 1e6)
+        phases = {}
+        for name, vals in sorted(by_name.items()):
+            vals.sort()
+            phases[name] = {"samples": len(vals),
+                            "p50_ms": round(_pct(vals, 50.0), 3),
+                            "p99_ms": round(_pct(vals, 99.0), 3)}
+        doc = {
+            "id": cid,
+            "seconds": round((t1_ns - t0_ns) / 1e9, 6),
+            "t0_ns": 0,
+            "t1_ns": int(t1_ns - t0_ns),
+            "options": dict(options),
+            "dropped": dropped,
+            "intervals": [[name, tid, s - t0_ns, e - s]
+                          for name, tid, s, e in log],
+            "phases": phases,
+        }
+        with self._lock:
+            self._last_capture = doc
+
+    def last_capture(self) -> Optional[dict]:
+        """The last finished capture (/debug/perf `capture`), None before
+        the first."""
+        with self._lock:
+            return self._last_capture
+
     def _evict(self, now: float) -> None:
         horizon = now - self.window_s
         while self._entries and self._entries[0][0] < horizon:
-            _, f, b, ds, _, _, r, _, _ = self._entries.popleft()
-            self._flops -= f
-            self._bytes -= b
-            self._device_s -= ds
-            self._rows -= r
+            self._rows -= self._entries.popleft()[2]
         for d in self._phase.values():
             while d and d[0][0] < horizon:
                 d.popleft()
@@ -277,32 +319,20 @@ class PerfWindow:
         return min(self.window_s, max(now - self._first_entry, 1e-9))
 
     def _duty_locked(self, now: float) -> float:
-        """Duty over the window's OWN observed span — one denominator for
-        duty, busy seconds, and the wall roofline (a fetch-anchored
-        interval may predate the first record; clamping keeps the three
-        mutually consistent)."""
+        """Duty over the window's OWN observed span (a fetch-anchored
+        interval may predate the first record; clamping keeps duty and
+        `observed_s` mutually consistent)."""
         span = self._observed_span(now)
         if span <= 0.0:
             return 0.0
         return min(self._duty.busy_s(now) / span, 1.0)
-
-    def _window_roofline_locked(self, now: float) -> tuple:
-        """(wall mfu_pct, wall bw_pct) over the observed window span —
-        the serving-level numbers comparable to the bench/r05 rows."""
-        span = self._observed_span(now)
-        if span <= 0.0:
-            return 0.0, 0.0
-        peak = costmodel.PEAKS[self.backend]
-        mfu = 100.0 * (self._flops / span / 1e12) / peak["tflops"]
-        bw = 100.0 * (self._bytes / span / 1e9) / peak["hbm_gbs"]
-        return round(mfu, 3), round(bw, 3)
 
     # -- introspection -------------------------------------------------------
 
     def control_signals(self) -> dict:
         """The cheap per-tick sensor read for the control plane's lane
         controller (serving/controller.py): duty cycle, mean queue wait,
-        and the dispatch count over the window — means only, no
+        and the dispatch count over the window -- means only, no
         percentile sorts, so a 1 Hz tick costs O(window samples) adds
         under the lock and nothing else."""
         now = time.monotonic()
@@ -323,68 +353,39 @@ class PerfWindow:
             for d in self._phase.values():
                 d.clear()
             self._duty = DutyCycle(self.window_s)
-            self._flops = self._bytes = 0
-            self._device_s = 0.0
             self._rows = 0
             self._first_entry = None
-            self._started = time.monotonic()
 
     def summary(self) -> dict:
-        """The /debug/perf body: window roofline (wall-clock AND
-        device-busy forms), duty cycle, per-phase p50/p99 + share of the
-        accounted dispatch wall, tier/regime tallies."""
+        """The /debug/perf body less `capture`: duty cycle, per-phase
+        p50/p99 + share of the accounted dispatch wall, tier tally."""
         now = time.monotonic()
         with self._lock:
             self._evict(now)
             span = self._observed_span(now)
             duty = self._duty_locked(now)
             n = len(self._entries)
-            flops, byts = self._flops, self._bytes
-            device_s, rows = self._device_s, self._rows
+            rows = self._rows
             phase_ms = {p: [ms for _, ms in d]
                         for p, d in self._phase.items() if d}
             tiers: dict[str, int] = {}
-            regimes: dict[str, int] = {}
             fused_n = fused_viol = 0
-            for _, _, _, _, tier, regime, _, fused, viol in self._entries:
+            for _, tier, _, fused, viol in self._entries:
                 tiers[tier] = tiers.get(tier, 0) + 1
                 if fused:
                     fused_n += 1
                 if viol:
                     fused_viol += 1
-                if regime:
-                    regimes[regime] = regimes.get(regime, 0) + 1
             total_dispatches = self._total_dispatches
-        busy_s = duty * span
         out: dict = {
             "window_s": self.window_s,
             "observed_s": round(span, 3),
-            "backend": self.backend,
             "trace_sample_rate": self.sample_hint,
             "dispatches": n,
             "dispatches_lifetime": total_dispatches,
             "rows": rows,
             "duty_cycle": round(duty, 4),
-            # union of in-flight (enqueue->fetch) intervals — the
-            # device-busy roofline's denominator
-            "device_busy_s": round(busy_s, 4),
-            # sum of blocked-fetch times: a LOWER bound on device time
-            # (a result landing during host overlap fetches in ~0 ms), so
-            # it is reported but never used as a roofline denominator
-            "device_fetch_s": round(device_s, 4),
         }
-        # wall roofline: achieved over the observed window span — the
-        # serving-level MFU (what r05's 1.78% measured). device-busy
-        # roofline: the same work over only the in-flight seconds —
-        # utilization while the device had a dispatch in flight
-        # (wall mfu = duty_cycle x this). The gap between the two IS the
-        # orchestration overhead the duty cycle measures.
-        if span > 0.0 and flops > 0:
-            out["roofline"] = costmodel.roofline(
-                flops / span, byts / span, 1.0, self.backend)
-            if busy_s > 0.0:
-                out["roofline_device_busy"] = costmodel.roofline(
-                    flops, byts, busy_s, self.backend)
         phases: dict = {}
         total_accounted = sum(sum(v) for v in phase_ms.values())
         for p in PHASES:
@@ -402,7 +403,6 @@ class PerfWindow:
             }
         out["phases"] = phases
         out["tiers"] = dict(sorted(tiers.items(), key=lambda kv: -kv[1]))
-        out["regimes"] = dict(sorted(regimes.items(), key=lambda kv: -kv[1]))
         # fused-dispatch coverage + invariant violations over the window
         # (costmodel.fused_invariant_ok): share near 1.0 with violations 0
         # is the steady state; violations > 0 means host post-processing
@@ -456,6 +456,23 @@ def unconfigure(window: PerfWindow) -> None:
 
 def get_window() -> Optional[PerfWindow]:
     return _window
+
+
+def note_phase(name: str, ms: float) -> None:
+    """`PerfWindow.note_phase` on the installed window; one comparison
+    while the plane is down."""
+    w = _window
+    if w is not None:
+        w.note_phase(name, ms)
+
+
+def note_interval(name: str, start_ns: int, end_ns: int,
+                  tid: Optional[int] = None) -> None:
+    """`PerfWindow.note_interval` on the installed window; one comparison
+    while the plane is down."""
+    w = _window
+    if w is not None:
+        w.note_interval(name, start_ns, end_ns, tid)
 
 
 def recent_summaries() -> list:

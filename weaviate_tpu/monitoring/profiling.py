@@ -232,18 +232,34 @@ def install_trace_teardown() -> bool:
         return False
 
 
+# The profiler session device_trace opens. Without the Python tracer: it
+# hooks every Python call of every thread, which slowed the traced server
+# by a third (PERF.md section 6) -- Python time is the stack sampler's job
+# (/debug/pprof/profile). Host tracer level 1 is the lowest that keeps
+# TraceMe events, and with them the program's `wv/*` annotations
+# (monitoring/tracing.py); the device planes do not depend on either.
+TRACE_OPTIONS = {"python_tracer_level": 0, "host_tracer_level": 1}
+
+
 def device_trace(data_path: str, seconds: float = 3.0) -> str:
     """Capture a JAX device trace for ?seconds — the TPU twin of pprof's
     execution trace (the reference's /debug/pprof/trace). Records XLA op
     timelines and device (TPU/HBM) activity for whatever the serving path
-    runs during the window; writes a perfetto/tensorboard trace under
+    runs during the window, with the program's own `wv/*` phases on the
+    host threads' lines; writes a perfetto/tensorboard trace under
     <data>/traces/<stamp>/ and returns its path + file listing (view with
-    `tensorboard --logdir` or ui.perfetto.dev). One capture at a time —
-    concurrent requests get an explicit error, not a corrupt trace."""
+    `tensorboard --logdir` or ui.perfetto.dev). While it runs the perf
+    window keeps every closed host phase (monitoring/perf.py capture log),
+    from the stamp taken here immediately before `start_trace`, which is
+    the xplane's zero, to the one taken before `stop_trace`. One capture
+    at a time — concurrent requests get an explicit error, not a corrupt
+    trace."""
     import glob
     import tempfile
 
     import jax
+
+    from weaviate_tpu.monitoring import perf
 
     if not _trace_lock.acquire(blocking=False):
         raise TraceBusyError("a device trace is already being captured")
@@ -254,6 +270,9 @@ def device_trace(data_path: str, seconds: float = 3.0) -> str:
         # not merge into one tensorboard/perfetto session
         out_dir = tempfile.mkdtemp(
             prefix=time.strftime("%Y%m%d-%H%M%S-"), dir=root)
+        options = jax.profiler.ProfileOptions()
+        for key, value in TRACE_OPTIONS.items():
+            setattr(options, key, value)
         # arm the emergency teardown BEFORE starting: a SIGTERM landing
         # between start_trace and the finally must still stop the capture
         # (atexit for normal exits; the chaining SIGTERM handler when one
@@ -261,17 +280,31 @@ def device_trace(data_path: str, seconds: float = 3.0) -> str:
         install_trace_teardown()
         with _teardown_lock:
             _teardown_state["active"] = True
-        jax.profiler.start_trace(out_dir)
+        window = perf.get_window()
+        if window is not None:
+            window.capture_begin()
+        t0_ns = time.perf_counter_ns()
         try:
+            jax.profiler.start_trace(out_dir, profiler_options=options)
             time.sleep(max(0.0, min(float(seconds), 60.0)))
         finally:
-            stop_active_trace()
+            # the log closes where the traced span ends: stop_trace then
+            # takes seconds to collect and write the xplane (5 to 11 s for
+            # a 5 s capture on the v5e), which no device line covers
+            try:
+                if window is not None:
+                    window.capture_end(t0_ns, time.perf_counter_ns(),
+                                       TRACE_OPTIONS)
+            finally:
+                stop_active_trace()
         files = sorted(
             os.path.relpath(p, out_dir)
             for p in glob.glob(os.path.join(out_dir, "**"), recursive=True)
             if os.path.isfile(p))
         return (f"device trace written to {out_dir}\n"
                 + "".join(f"  {f}\n" for f in files)
+                + "options: " + " ".join(
+                    f"{k}={v}" for k, v in TRACE_OPTIONS.items()) + "\n"
                 + "view: tensorboard --logdir <dir>  (or ui.perfetto.dev)\n")
     finally:
         _trace_lock.release()

@@ -5,7 +5,7 @@ all trade recall for speed via tunable candidate budgets, yet recall is
 only measured at bench time against a static fixture — live traffic has
 zero quality signal, so a PQ-tier regression, tombstone accumulation
 after deletes, or a too-aggressive budget would ship silently. This
-module is the quality twin of the /debug/perf roofline ledger
+module is the quality twin of the /debug/perf host ledger
 (monitoring/perf.py): a continuous, production-path recall meter.
 
 How it works:

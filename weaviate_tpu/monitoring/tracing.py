@@ -43,10 +43,23 @@ Exposure (all bounded):
     ``weaviate_trace_dispatch_rows_total``), observation exception-guarded
     like every other serving-path metric.
 
+One timeline. Every span keeps its start on ``time.perf_counter_ns`` and
+is served with ``start_ms`` relative to its trace's root, so
+``/debug/traces`` reads as a waterfall. Every host phase of the served
+path -- the spans opened through ``request()`` / ``span()`` and the
+``Phase`` intervals of the dispatch ledger (``enqueue``, ``device_wait``,
+``gather_hop``, ``filter``, ``hydrate``, ``scatter``) -- is also a
+``wv/<name>`` ``jax.profiler.TraceAnnotation`` on the thread that does the
+work (inert unless a profiler session is open; in a capture it lies on the
+host thread's line above the ``XLA Ops`` it caused), and on closing goes
+to the perf window's capture log (monitoring/perf.py ``note_interval``:
+dropped unless ``profiling.device_trace`` has a capture open).
+
 Disabled (``TRACING_ENABLED`` unset) the module global ``_tracer`` is
 ``None`` and every entry point returns after that one comparison: no span
-objects, no ContextVar writes, no locks — the serving hot path makes zero
-tracing calls (pinned by a spy test in tests/test_tracing.py). Enabled,
+objects, no annotations, no ContextVar writes, no locks — the serving hot
+path makes zero tracing calls (pinned by a spy test in
+tests/test_tracing.py). Enabled,
 the cost is O(spans) per sampled request with no locks on the dispatch
 hot path (phase recording appends to a plain list owned by one thread;
 the only locks are per-trace child-append and the ring append at finish).
@@ -67,7 +80,7 @@ import uuid
 from collections import deque
 from typing import Any, Iterator, Optional
 
-from weaviate_tpu.monitoring import costmodel
+from weaviate_tpu.monitoring import perf
 
 _SLOW_LOG = logging.getLogger("weaviate_tpu.slowquery")
 
@@ -110,26 +123,77 @@ def clean_request_id(value: Optional[str]) -> str:
     return rid or gen_request_id()
 
 
-class Span:
-    """One timed node in a request's trace tree. Children may be appended
-    from other threads (coalesced-dispatch attribution), so the append goes
-    through the owning trace's lock; everything else is single-writer."""
+class Phase:
+    """One host phase as an interval (a span's, or a stage of the dispatch
+    ledger): a ``wv/<name>`` annotation on the calling thread's profiler
+    line from construction to ``end()``, and ``(name, thread, start_ns,
+    end_ns)`` for the perf window's capture log. The thread that opens it
+    ends it. Built only while the tracer is up (call sites gate on it, or
+    go through ``Stopwatch``)."""
 
-    __slots__ = ("name", "trace", "attrs", "children", "duration_ms", "_t0")
+    __slots__ = ("name", "start_ns", "_ann")
+
+    def __init__(self, name: str, **stats):
+        self.name = name
+        # None where no tracer was ever installed (a hand-built shape in a
+        # test); TraceMe records nothing unless a profiler session is open
+        self._ann = _TraceMe("wv/" + name, **stats) if _TraceMe else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.start_ns = time.perf_counter_ns()
+
+    def end(self, **stats) -> int:
+        """Close the interval -> its end stamp. `stats` (rows, tier) are
+        added to the annotation: what was not known when it opened."""
+        end_ns = time.perf_counter_ns()
+        ann = self._ann
+        if ann is not None:
+            if stats:
+                ann.set_metadata(**stats)
+            ann.__exit__(None, None, None)
+        perf.note_interval(self.name, self.start_ns, end_ns)
+        return end_ns
+
+    def __enter__(self) -> "Phase":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
+class Span:
+    """One timed node in a request's trace tree: a ``Phase`` (interval +
+    annotation) with attributes and children. Children may be appended
+    from other threads (coalesced-dispatch attribution), so the append goes
+    through the owning trace's lock; everything else is single-writer, and
+    the thread that opens a span ends it. An attribution span
+    (``child_done``: a dispatch's share, not an interval that ran) has no
+    phase and so no start."""
+
+    __slots__ = ("name", "trace", "attrs", "children", "duration_ms",
+                 "_phase")
 
     def __init__(self, name: str, trace: "Trace",
                  attrs: Optional[dict] = None,
-                 duration_ms: Optional[float] = None):
+                 duration_ms: Optional[float] = None,
+                 stats: Optional[dict] = None):
         self.name = name
         self.trace = trace
         self.attrs: dict[str, Any] = dict(attrs) if attrs else {}
         self.children: list[Span] = []
         self.duration_ms = duration_ms
-        self._t0 = time.perf_counter() if duration_ms is None else None
+        self._phase = (Phase(name, **(stats or {}))
+                       if duration_ms is None else None)
+
+    @property
+    def start_ns(self) -> Optional[int]:
+        """The ``time.perf_counter_ns`` stamp this span opened at."""
+        return self._phase.start_ns if self._phase is not None else None
 
     def end(self) -> None:
-        if self.duration_ms is None and self._t0 is not None:
-            self.duration_ms = (time.perf_counter() - self._t0) * 1000.0
+        if self.duration_ms is None and self._phase is not None:
+            self.duration_ms = (
+                self._phase.end() - self._phase.start_ns) / 1e6
 
     def child_start(self, name: str, attrs: Optional[dict] = None) -> "Span":
         """Open a child span (the caller owns closing it — prefer the
@@ -153,14 +217,16 @@ class Span:
         with self.trace.lock:
             self.attrs[key] = value
 
-    def to_dict(self) -> dict:
+    def to_dict(self, root_ns: int) -> dict:
         d: dict[str, Any] = {"name": self.name}
+        if self.start_ns is not None:
+            d["start_ms"] = round((self.start_ns - root_ns) / 1e6, 3)
         if self.duration_ms is not None:
             d["duration_ms"] = round(self.duration_ms, 3)
         if self.attrs:
             d["attrs"] = dict(self.attrs)
         if self.children:
-            d["children"] = [c.to_dict() for c in self.children]
+            d["children"] = [c.to_dict(root_ns) for c in self.children]
         return d
 
 
@@ -182,7 +248,8 @@ class Trace:
         self.name = name
         self.lock = threading.Lock()
         self.start_unix_ms = time.time() * 1000.0
-        self.root = Span("request", self, attrs)
+        self.root = Span("request", self, attrs,
+                         stats={"kind": kind, "request": name})
 
     def traceparent(self) -> str:
         """The outbound W3C header value for this trace's root."""
@@ -199,7 +266,7 @@ class Trace:
             "start_unix_ms": round(self.start_unix_ms, 1),
             "duration_ms": (round(self.root.duration_ms, 3)
                             if self.root.duration_ms is not None else None),
-            "root": self.root.to_dict(),
+            "root": self.root.to_dict(self.root.start_ns),
         }
 
 
@@ -252,23 +319,10 @@ class DispatchRecord:
         self.attrs.update(kw)
 
     def attach_shape(self, shape) -> None:
-        """Fold a costmodel.DispatchShape's analytic facts + host-overhead
-        ledger into this record (db/shard.py calls it right after the
-        dispatch's phases land, before finish()). The roofline facts
-        themselves are computed at finish()."""
-        self.attrs.update(tier=shape.tier, n_live=shape.n,
-                          dim=shape.dim, flops=shape.flops(),
-                          bytes=shape.bytes())
-        if shape.t_end > shape.t_start:
-            # the dispatch's enqueue->fetch wall: the per-dispatch roofline
-            # denominator. The blocked-fetch time is only a LOWER bound on
-            # device time (a result that landed while the host was doing
-            # enqueue/compile work fetches in ~0 ms), so dividing by it
-            # can fabricate >100% MFU; the wall form is an honest
-            # serving-level number (kernel-level lives in /debug/perf's
-            # device-busy aggregate)
-            self.attrs["dispatch_wall_ms"] = round(
-                (shape.t_end - shape.t_start) * 1000.0, 3)
+        """Fold a costmodel.DispatchShape's facts + host-overhead ledger
+        into this record (db/shard.py calls it right after the dispatch's
+        phases land, before finish())."""
+        self.attrs.update(tier=shape.tier, n_live=shape.n, dim=shape.dim)
         for name, ms in shape.ledger().items():
             self.ledger_entries.append((name, ms))
 
@@ -286,38 +340,12 @@ class DispatchRecord:
         if padded > 0:
             self.attrs["padding_waste"] = round(
                 max(0.0, 1.0 - rows_total / padded), 4)
-        # roofline facts (costmodel): the dispatch's analytic work over its
-        # enqueue->fetch WALL — the serving-level per-dispatch utilization.
-        # Deliberately NOT over the blocked-fetch time: that is a lower
-        # bound on device time (a dispatch overlapping host work fetches
-        # in ~0 ms and would read as >100% MFU); kernel-level utilization
-        # comes from /debug/perf's device-busy aggregate instead.
-        flops = self.attrs.get("flops")
-        ledger = dict(self.ledger_entries)
-        if flops:
-            dev_ms = self.attrs.get("dispatch_wall_ms") or device_ms
-            if dev_ms > 0.0:
-                rf = costmodel.roofline(
-                    flops, self.attrs.get("bytes", 0), dev_ms / 1000.0)
-                self.attrs.update(
-                    mfu_pct=rf["mfu_pct"], hbm_bw_pct=rf["bw_pct"],
-                    arith_intensity=rf["arith_intensity_flops_per_byte"],
-                    regime=rf["regime"])
-        if ledger:
+        if self.ledger_entries:
             self.attrs["ledger_ms"] = {
-                k: round(v, 3) for k, v in ledger.items()}
-        # per-rider flops/bytes: telescoping integer split, so when every
-        # rider is sampled the parts sum BIT-EXACTLY to the dispatch
-        # totals (the flops/bytes twin of the device-time identity)
-        rider_rows = [r for _, r, _ in self.riders]
-        rider_flops = (costmodel.split_exact(flops, rider_rows, rows_total)
-                       if flops else None)
-        rider_bytes = (costmodel.split_exact(
-            self.attrs.get("bytes", 0), rider_rows, rows_total)
-            if flops else None)
+                k: round(v, 3) for k, v in self.ledger_entries}
         t = _tracer
         m = t.metrics if t is not None else None
-        for i, (span, rows, wait_ms) in enumerate(self.riders):
+        for span, rows, wait_ms in self.riders:
             share = rows / rows_total
             attrs = {
                 **self.attrs,
@@ -328,11 +356,6 @@ class DispatchRecord:
                 "dispatch_device_ms": device_ms,
                 "dispatch_total_ms": total_ms,
             }
-            if rider_flops is not None:
-                attrs["flops"] = rider_flops[i]
-                attrs["bytes"] = rider_bytes[i]
-                attrs["dispatch_flops"] = flops
-                attrs["dispatch_bytes"] = self.attrs.get("bytes", 0)
             d = span.child_done("dispatch", duration_ms=total_ms * share,
                                 attrs=attrs)
             for nm, ms in self.phases:
@@ -472,6 +495,9 @@ class Tracer:
 # -- module state + zero-hop accessors ----------------------------------------
 
 _tracer: Optional[Tracer] = None
+# jax.profiler.TraceAnnotation, imported when a tracer is first installed
+# (this module is imported by code that must not import jax)
+_TraceMe = None
 
 # the active span of the current request (serving thread + anything
 # contextvars copies into); None when disabled, unsampled, or off-request
@@ -485,7 +511,11 @@ _DISPATCH = contextvars.ContextVar("weaviate_trace_dispatch", default=None)
 
 def configure(tracer: Optional[Tracer]) -> Optional[Tracer]:
     """Install (or clear, with None) the process-wide tracer."""
-    global _tracer
+    global _tracer, _TraceMe
+    if tracer is not None and _TraceMe is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceMe = TraceAnnotation
     _tracer = tracer
     return tracer
 
@@ -500,6 +530,31 @@ def unconfigure(tracer: Tracer) -> None:
 
 def get_tracer() -> Optional[Tracer]:
     return _tracer
+
+
+class Stopwatch:
+    """``with tracing.Stopwatch("hydrate", rows=n) as sw: ...`` then
+    ``sw.ms``: a stage the caller times whether or not the tracer is up
+    (the shard's histograms), measured ONCE. While the tracer is up the
+    stamps are a ``Phase``'s, so the ledger, the capture log and the
+    histogram read one interval; otherwise a bare ``perf_counter_ns``
+    pair and nothing else."""
+
+    __slots__ = ("_phase", "start_ns", "ms")
+
+    def __init__(self, name: str, **stats):
+        self._phase = Phase(name, **stats) if _tracer is not None else None
+        self.start_ns = (self._phase.start_ns if self._phase is not None
+                         else time.perf_counter_ns())
+        self.ms = -1.0
+
+    def __enter__(self) -> "Stopwatch":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end_ns = (self._phase.end() if self._phase is not None
+                  else time.perf_counter_ns())
+        self.ms = (end_ns - self.start_ns) / 1e6
 
 
 def current_span() -> Optional[Span]:

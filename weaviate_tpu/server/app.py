@@ -77,7 +77,7 @@ class App:
             # perf.py): rides the tracer's enablement — the perf window is
             # fed by every dispatch's cost-model shape, which the index
             # only builds while the tracer is up (one zero-cost contract
-            # for both planes). /debug/perf + the rolling roofline gauges.
+            # for both planes). /debug/perf + the duty-cycle gauge.
             self.perf_window = perf.configure(perf.PerfWindow(
                 window_s=tc.perf_window_s,
                 metrics=self.metrics,

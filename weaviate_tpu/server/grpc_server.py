@@ -11,6 +11,7 @@ concurrent kNN queries ride one device dispatch instead of N.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import time
 from concurrent import futures
@@ -21,12 +22,24 @@ import numpy as np
 
 from weaviate_tpu.entities.filters import LocalFilter
 from weaviate_tpu.grpcapi import weaviate_pb2 as pb
-from weaviate_tpu.monitoring import incidents, tracing
+from weaviate_tpu.monitoring import incidents, perf, tracing
 from weaviate_tpu.serving import robustness
 from weaviate_tpu.server import reply_native
 from weaviate_tpu.usecases.traverser import GetParams
 
 _SERVICE = "weaviatetpu.v1.Weaviate"
+
+
+@contextlib.contextmanager
+def _entry_phase(name: str):
+    """One half of the Entry layer's own work, `decode` (request message to
+    query rows and params) or `encode` (results to reply bytes): the
+    `entry.<name>` child span of the request's root and the `<name>` phase
+    of the /debug/perf ledger. No trace, no work."""
+    with tracing.span("entry." + name) as s:
+        yield
+    if s is not None:
+        perf.note_phase(name, s.duration_ms)
 
 
 def _request_meta(context) -> tuple[str, Optional[str], float, float,
@@ -266,7 +279,8 @@ class SearchServicer:
             if tenant:
                 tracing.annotate_current("tenant", tenant)
             try:
-                params = params_from_proto(request)
+                with _entry_phase("decode"):
+                    params = params_from_proto(request)
             except Exception as e:
                 self._note_slo("client", start, tenant)
                 context.abort(grpc.StatusCode.INVALID_ARGUMENT, str(e))
@@ -295,12 +309,14 @@ class SearchServicer:
                 return
             self._note_slo("ok", start, tenant)
             took = time.perf_counter() - start
-            fast = fast_reply_bytes(results, request, took)
-            if fast is not None:
-                return fast  # pre-serialized; the passthrough serializer ships it
-            reply = pb.SearchReply(took_seconds=took)
-            reply.results.extend(result_to_proto(r, request) for r in results)
-            return reply
+            with _entry_phase("encode"):
+                fast = fast_reply_bytes(results, request, took)
+                if fast is not None:
+                    return fast  # pre-serialized; the passthrough serializer ships it
+                reply = pb.SearchReply(took_seconds=took)
+                reply.results.extend(
+                    result_to_proto(r, request) for r in results)
+                return reply
 
     def _raw_batch_lane(self, request: pb.BatchSearchRequest,
                         start: float) -> Optional[bytes]:
@@ -309,6 +325,31 @@ class SearchServicer:
         device search -> packed native point-gets -> packed native reply
         marshalling, with no per-result Python objects anywhere. None =>
         the general path (which is always correct) serves the batch."""
+        # a batch the raw lane declines is decoded again by the general
+        # path: two `entry.decode` spans, this one marked, and the ledger's
+        # `decode` takes only the one whose rows are served
+        with tracing.span("entry.decode", lane="raw") as decode:
+            decoded = self._raw_batch_decode(request)
+        if decoded is None:
+            return None
+        shard, q, k = decoded
+        try:
+            out = shard.search_raw_packed(q, k)
+        except Exception:  # noqa: BLE001 — the general path re-runs + reports
+            return None
+        if out is None:
+            return None
+        if decode is not None:
+            perf.note_phase("decode", decode.duration_ms)
+        vbuf, voffs, vflags, flat_dists, counts = out
+        with _entry_phase("encode"):
+            return reply_native.build_batch_reply_packed(
+                vbuf, voffs, vflags, flat_dists, counts,
+                time.perf_counter() - start)
+
+    def _raw_batch_decode(self, request: pb.BatchSearchRequest):
+        """-> (shard, [B, D] float32 queries, k) when the raw lane can
+        serve the batch, else None."""
         reqs = request.requests
         if not reqs:
             return None
@@ -344,16 +385,7 @@ class SearchServicer:
         q = np.empty((len(reqs), dim), dtype=np.float32)
         for i, r in enumerate(reqs):
             q[i] = np.fromiter(r.near_vector.vector, np.float32, dim)
-        try:
-            out = shard.search_raw_packed(q, k)
-        except Exception:  # noqa: BLE001 — the general path re-runs + reports
-            return None
-        if out is None:
-            return None
-        vbuf, voffs, vflags, flat_dists, counts = out
-        return reply_native.build_batch_reply_packed(
-            vbuf, voffs, vflags, flat_dists, counts,
-            time.perf_counter() - start)
+        return shard, q, k
 
     def BatchSearch(self, request: pb.BatchSearchRequest, context) -> pb.BatchSearchReply:
         """Per-slot error isolation end to end: a malformed request or failed
@@ -416,16 +448,22 @@ class SearchServicer:
                 return raw
         slot_params: list = [None] * len(request.requests)
         parse_errs: dict[int, str] = {}
-        for i, r in enumerate(request.requests):
-            try:
-                slot_params[i] = params_from_proto(r)
-            except Exception as e:
-                parse_errs[i] = str(e)
+        with _entry_phase("decode"):
+            for i, r in enumerate(request.requests):
+                try:
+                    slot_params[i] = params_from_proto(r)
+                except Exception as e:
+                    parse_errs[i] = str(e)
         valid = [(i, p) for i, p in enumerate(slot_params) if i not in parse_errs]
         results = self.app.traverser.get_class_batched([p for _, p in valid]) if valid else []
         took = time.perf_counter() - start
         slot_out: dict[int, object] = {i: res for (i, _), res in zip(valid, results)}
-        if not parse_errs and len(valid) == len(request.requests):
+        with _entry_phase("encode"):
+            return self._batch_reply(request, slot_out, parse_errs, took)
+
+    def _batch_reply(self, request, slot_out, parse_errs, took) -> bytes:
+        """The BatchSearchReply's wire bytes from the slots' results."""
+        if not parse_errs:
             whole = self._whole_batch_fast(request, slot_out, took)
             if whole is not None:
                 return whole

@@ -106,9 +106,10 @@ for _m, _p, _n in [
     # authorizer as the pprof surface below: span trees name classes and
     # filters and are not for anonymous remote clients
     ("GET", r"/debug/traces", "debug_traces"),
-    # rolling perf-attribution window (monitoring/perf.py): roofline,
-    # duty cycle, host-overhead ledger percentiles — same authorizer as
-    # pprof (it names classes and exposes serving internals)
+    # rolling perf-attribution window (monitoring/perf.py): duty cycle,
+    # host-overhead ledger percentiles, the last capture's host timeline —
+    # same authorizer as pprof (it names classes and exposes serving
+    # internals)
     ("GET", r"/debug/perf", "debug_perf"),
     # shadow recall auditor window (monitoring/quality.py): online
     # recall/RBO/distance-error estimates per tier + audit accounting —
@@ -436,7 +437,8 @@ class Handler(BaseHTTPRequestHandler):
         if w is None:
             self._reply(200, {"enabled": False})
             return
-        self._reply(200, {"enabled": True, **w.summary()})
+        self._reply(200, {"enabled": True, **w.summary(),
+                          "capture": w.last_capture()})
 
     def h_debug_quality(self):
         from weaviate_tpu.monitoring import quality
@@ -528,9 +530,11 @@ class Handler(BaseHTTPRequestHandler):
             "/debug/traces": "completed request traces ring (span trees "
                              "with device-time attribution; "
                              "TRACING_ENABLED)",
-            "/debug/perf": "rolling device-performance window: roofline, "
-                           "duty cycle, host-overhead ledger percentiles "
-                           "(rides TRACING_ENABLED)",
+            "/debug/perf": "rolling host-side perf window: duty cycle, "
+                           "host-overhead ledger percentiles, and the "
+                           "last /debug/pprof/trace capture's host "
+                           "intervals on the profiler's clock (rides "
+                           "TRACING_ENABLED)",
             "/debug/quality": "shadow recall auditor window: online "
                               "recall/RBO/distance-error per tier, audit "
                               "accounting (RECALL_AUDIT_SAMPLE_RATE > 0)",

@@ -179,7 +179,7 @@ class _Waiter:
     flush path prunes expired waiters, and wait() is bounded by it."""
 
     __slots__ = ("vectors", "event", "result", "error", "enqueued_at",
-                 "trace_span", "deadline", "max_wait_s", "tenant",
+                 "trace_span", "tid", "deadline", "max_wait_s", "tenant",
                  "tenant_label")
 
     def __init__(self, vectors: np.ndarray, max_wait_s: float = 30.0,
@@ -190,6 +190,9 @@ class _Waiter:
         self.error: Optional[BaseException] = None
         self.enqueued_at = time.monotonic()
         self.trace_span = tracing.current_span()
+        # the waiting thread, for its `queue_wait` interval in a capture
+        self.tid = (threading.get_native_id()
+                    if tracing.get_tracer() is not None else 0)
         self.deadline = robustness.current_deadline()
         self.max_wait_s = max_wait_s
         self.tenant = tenant
@@ -1236,10 +1239,15 @@ class QueryCoalescer:
             # request — full coverage, independent of trace sampling (the
             # perf window exists only while the tracer is up, so the
             # disabled path is the one comparison above)
+            # the same waits as intervals on the waiters' own threads (a
+            # wait, not work: the one phase with no `wv/*` annotation)
+            now_ns = time.perf_counter_ns()
             try:
                 for w in lane.items:
-                    pw.note_phase("queue_wait",
-                                  (now - w.enqueued_at) * 1000.0)
+                    wait_s = now - w.enqueued_at
+                    pw.note_phase("queue_wait", wait_s * 1000.0)
+                    pw.note_interval("queue_wait", now_ns - int(wait_s * 1e9),
+                                     now_ns, w.tid)
             except Exception:  # noqa: BLE001 — must not break serving
                 pass
 
@@ -1254,7 +1262,7 @@ class QueryCoalescer:
         if not self._mark_settled(lane):
             return  # reaper/failure path won the race; results discarded
         pw = perf.get_window()
-        scatter_t0 = time.perf_counter() if pw is not None else 0.0
+        scatter = tracing.Phase("scatter") if pw is not None else None
         pos = 0
         try:
             for w in lane.items:
@@ -1273,7 +1281,7 @@ class QueryCoalescer:
             # the ledger's final stage: result scatter back to the waiters
             try:
                 pw.note_phase(
-                    "scatter", (time.perf_counter() - scatter_t0) * 1000.0)
+                    "scatter", (scatter.end() - scatter.start_ns) / 1e6)
             except Exception:  # noqa: BLE001 — must not break serving
                 pass
         now = time.monotonic()
