@@ -13,6 +13,7 @@ import pytest
 from weaviate_tpu.grpcapi import weaviate_pb2 as pb
 from weaviate_tpu.server import App
 from weaviate_tpu.server.grpc_server import GrpcServer, SearchClient
+from weaviate_tpu.server.reply_native import varint
 
 
 @pytest.fixture(scope="module")
@@ -379,3 +380,309 @@ def test_batch_search_per_slot_errors(setup):
     assert reply.replies[1].error_message  # malformed where_json
     assert reply.replies[2].error_message  # unknown class
     assert not reply.replies[1].results and not reply.replies[2].results
+
+
+# ---------------------------------------------------------------------------
+# The Entry layer's decode: a query vector leaves the request as its packed
+# float32 bytes, never as one Python float an element.
+# ---------------------------------------------------------------------------
+
+def _len_field(number, payload):
+    return varint(number << 3 | 2) + varint(len(payload)) + payload
+
+
+def _awkward_floats(dim, seed):
+    """`dim` float32 bit patterns: quiet NaNs with payloads, both
+    infinities, both zeros, denormals, the largest and smallest normals,
+    then random bits (signalling NaNs quieted: the old loop took every
+    element through a Python float, which quiets them)."""
+    special = [0x7FC00001, 0xFFC12345, 0x7FFFFFFF, 0x7F800000, 0xFF800000,
+               0x80000000, 0x00000000, 0x00000001, 0x807FFFFF, 0x7F7FFFFF,
+               0x00800000, 0x3F800000]
+    rng = np.random.default_rng(seed)
+    bits = rng.integers(0, 2**32, dim, dtype=np.uint64).astype(np.uint32)
+    nan = (bits & 0x7F800000) == 0x7F800000
+    bits[nan] |= 0x00400000
+    bits[:min(dim, len(special))] = special[:dim]
+    rng.shuffle(bits)
+    return bits
+
+
+def _vector_wire(bits, encoding, number=1):
+    """Wire bytes of a `repeated float` field holding `bits`, as a proto3
+    parser has to accept them."""
+    raw = bits.astype("<u4").tobytes()
+    if encoding == "packed":
+        return _len_field(number, raw)
+    if encoding == "unpacked":  # one fixed32 an element
+        tag = varint(number << 3 | 5)
+        return b"".join(tag + raw[i:i + 4] for i in range(0, len(raw), 4))
+    assert encoding == "split"  # two packed runs (the first may be empty)
+    cut = (len(bits) // 3) * 4
+    return _len_field(number, raw[:cut]) + _len_field(number, raw[cut:])
+
+
+def _old_decode(vector, dim):
+    return np.fromiter(vector, np.float32, dim)
+
+
+class _StubShard:
+    def raw_plane_ready(self):
+        return True
+
+
+def _stub_servicer():
+    """A SearchServicer whose app resolves every class to one ready shard:
+    `_raw_batch_decode` runs for real, nothing behind it does."""
+    from types import SimpleNamespace as NS
+
+    from weaviate_tpu.server.grpc_server import SearchServicer
+
+    shard = _StubShard()
+    app = NS(
+        traverser=NS(explorer=NS(query_limit=10, max_results=10000)),
+        schema=NS(resolve_class_name=lambda c: c or None),
+        db=NS(get_index=lambda c: NS(single_local_shard=lambda: shard)))
+    return SearchServicer(app), shard
+
+
+@pytest.mark.parametrize("encoding", ["packed", "unpacked", "split"])
+@pytest.mark.parametrize("dim", [1, 16, 100, 128, 768])
+def test_vector_decode_is_bit_exact(dim, encoding):
+    """The helper and the raw lane's batch decode give the wire's own bit
+    patterns, equal to what the per-element loop gave, whichever encoding
+    of the repeated field the client chose."""
+    from weaviate_tpu.server.grpc_server import _vector_f32, params_from_proto
+
+    slots = 5
+    bits = np.stack([_awkward_floats(dim, 1000 * dim + s)
+                     for s in range(slots)])
+    # one vector: NearVectorParams (field 1) and HybridParams (field 2,
+    # behind its query string)
+    nv = pb.NearVectorParams.FromString(_vector_wire(bits[0], encoding))
+    assert len(nv.vector) == dim
+    got = _vector_f32(nv)
+    assert got.dtype == np.float32 and got.shape == (dim,)
+    assert (got.view(np.uint32) == bits[0]).all()
+    assert (got.view(np.uint32)
+            == _old_decode(nv.vector, dim).view(np.uint32)).all()
+    hy = pb.HybridParams.FromString(
+        _vector_wire(bits[1], encoding, number=2)
+        + _len_field(1, "größe".encode()) + b"\x1d" + b"\x00\x00\x00\x3f")
+    assert hy.query == "größe" and hy.alpha == 0.5
+    assert (_vector_f32(hy).view(np.uint32) == bits[1]).all()
+    p = params_from_proto(pb.SearchRequest(class_name="C", hybrid=hy))
+    assert (p.hybrid["vector"].view(np.uint32) == bits[1]).all()
+    assert p.hybrid["query"] == "größe" and p.hybrid["alpha"] == 0.5
+
+    # the batch: every slot in that encoding, fields in reverse order
+    wire = b"".join(
+        _len_field(1, _len_field(6, _vector_wire(bits[s], encoding))
+                   + b"\x10\x07" + _len_field(1, b"Cls"))
+        for s in range(slots))
+    breq = pb.BatchSearchRequest.FromString(wire)
+    sv, shard = _stub_servicer()
+    got_shard, q, k = sv._raw_batch_decode(breq)
+    assert got_shard is shard and k == 7
+    assert q.dtype == np.float32 and q.shape == (slots, dim)
+    assert q.flags.c_contiguous and q.flags.aligned
+    assert (q.view(np.uint32) == bits).all()
+    old = np.stack([_old_decode(r.near_vector.vector, dim)
+                    for r in breq.requests])
+    assert (q.view(np.uint32) == old.view(np.uint32)).all()
+
+
+@pytest.mark.parametrize("case", [
+    "certainty", "distance", "both", "reordered", "unknown_field", "empty",
+    "hybrid_vector_only", "hybrid_query_only"])
+def test_params_from_proto_vector_beside_other_fields(case):
+    """`certainty` / `distance` follow the vector in the canonical bytes
+    and an unknown field follows those: the helper finds the payload by its
+    tag and length, not by counting from the end."""
+    import struct
+
+    from weaviate_tpu.server.grpc_server import _vector_f32, params_from_proto
+
+    bits = _awkward_floats(24, 7)
+    vec = _vector_wire(bits, "packed")
+    cert = b"\x11" + struct.pack("<d", 0.75)
+    dist = b"\x19" + struct.pack("<d", 0.25)
+    if case in ("hybrid_vector_only", "hybrid_query_only"):
+        hy = pb.HybridParams.FromString(
+            _vector_wire(bits, "split", number=2)
+            if case == "hybrid_vector_only" else _len_field(1, b"words"))
+        p = params_from_proto(pb.SearchRequest(class_name="C", hybrid=hy))
+        if case == "hybrid_vector_only":
+            assert (p.hybrid["vector"].view(np.uint32) == bits).all()
+            assert p.hybrid["query"] == ""
+        else:
+            assert "vector" not in p.hybrid
+            assert _vector_f32(hy).shape == (0,)
+        return
+    wire = {
+        "certainty": vec + cert,
+        "distance": vec + dist,
+        "both": vec + cert + dist,
+        # distance first, two fixed32 elements, then a packed run
+        "reordered": dist + _vector_wire(bits[:2], "unpacked")
+        + _vector_wire(bits[2:], "packed"),
+        "unknown_field": _len_field(15, b"hi") + vec + b"\x28\x07" + dist,
+        "empty": dist,
+    }[case]
+    nv = pb.NearVectorParams.FromString(wire)
+    p = params_from_proto(pb.SearchRequest(class_name="C", near_vector=nv))
+    if case == "empty":
+        assert p.near_vector is None
+        assert _vector_f32(nv).shape == (0,)
+        assert _vector_f32(pb.NearVectorParams()).shape == (0,)
+        return
+    assert (p.near_vector["vector"].view(np.uint32) == bits).all()
+    assert p.near_vector.get("certainty") == (
+        0.75 if case in ("certainty", "both") else None)
+    assert p.near_vector.get("distance") == (
+        None if case == "certainty" else 0.25)
+
+
+_PLAIN = dict(class_name="Cls", limit=3)
+
+
+def _plain_slot(dim=16, seed=0, **over):
+    vec = np.random.default_rng(seed).standard_normal(dim).astype(np.float32)
+    kw = dict(_PLAIN, near_vector=pb.NearVectorParams(vector=vec.tolist()))
+    kw.update(over)
+    return pb.SearchRequest(**kw)
+
+
+def _odd_slot(what):
+    """A slot the raw lane has to refuse, or its wire bytes."""
+    nv = lambda **kw: pb.NearVectorParams(  # noqa: E731
+        vector=[0.5] * kw.pop("dim", 16), **kw)
+    if what == "unknown_field_in_slot":
+        return _plain_slot().SerializeToString() + _len_field(15, b"new")
+    if what == "unknown_field_in_near_vector":
+        return pb.SearchRequest(**_PLAIN).SerializeToString() + _len_field(
+            6, nv().SerializeToString() + b"\x28\x01")
+    return {
+        "narrower": lambda: _plain_slot(near_vector=nv(dim=15)),
+        "wider": lambda: _plain_slot(near_vector=nv(dim=17)),
+        "other_class": lambda: _plain_slot(class_name="Clt"),
+        "no_class": lambda: _plain_slot(class_name=""),
+        "other_limit": lambda: _plain_slot(limit=4),
+        "no_limit": lambda: _plain_slot(limit=0),
+        "offset": lambda: _plain_slot(offset=1),
+        "properties": lambda: _plain_slot(properties=["rank"]),
+        "additional": lambda: _plain_slot(additional_properties=["vector"]),
+        "where": lambda: _plain_slot(where_json="{}"),
+        "consistency": lambda: _plain_slot(consistency_level="ONE"),
+        "no_near_vector": lambda: pb.SearchRequest(**_PLAIN),
+        "empty_vector": lambda: _plain_slot(
+            near_vector=pb.NearVectorParams(distance=0.5)),
+        "certainty": lambda: _plain_slot(near_vector=nv(certainty=0.0)),
+        "distance": lambda: _plain_slot(near_vector=nv(distance=0.5)),
+        "near_object": lambda: _plain_slot(
+            near_object=pb.NearObjectParams(id="x")),
+        "bm25": lambda: _plain_slot(bm25=pb.BM25Params(query="x")),
+        "hybrid": lambda: _plain_slot(hybrid=pb.HybridParams(query="x")),
+    }[what]()
+
+
+@pytest.mark.parametrize("where", ["first", "middle", "last"])
+@pytest.mark.parametrize("what", [
+    "narrower", "wider", "other_class", "no_class", "other_limit",
+    "no_limit", "offset", "properties", "additional", "where", "consistency",
+    "no_near_vector", "empty_vector", "certainty", "distance", "near_object",
+    "bm25", "hybrid", "unknown_field_in_slot",
+    "unknown_field_in_near_vector"])
+def test_raw_lane_declines_a_batch_with_one_odd_slot(what, where):
+    """The raw lane serves a batch only if EVERY slot is class, limit and a
+    vector of the batch's width and nothing else; one odd slot anywhere
+    and the general path gets the whole batch."""
+    sv, _ = _stub_servicer()
+    slots = [_plain_slot(seed=s) for s in range(6)]
+    plain = pb.BatchSearchRequest(requests=slots)
+    assert sv._raw_batch_decode(plain)[1].shape == (6, 16)
+    odd = _odd_slot(what)
+    at = {"first": 0, "middle": 3, "last": 5}[where]
+    wire = b"".join(
+        _len_field(1, s if isinstance(s, bytes) else s.SerializeToString())
+        for s in slots[:at] + [odd] + slots[at + 1:])
+    assert sv._raw_batch_decode(pb.BatchSearchRequest.FromString(wire)) is None
+
+
+def test_raw_lane_declines_widths_that_cancel_and_top_level_extras():
+    """Two slots whose widths differ by +1 and -1 leave the request's
+    length as it was; a field of BatchSearchRequest this build does not
+    know follows the slots. Both decline."""
+    sv, _ = _stub_servicer()
+    nv = lambda d: pb.NearVectorParams(vector=[0.5] * d)  # noqa: E731
+    breq = pb.BatchSearchRequest(requests=[
+        _plain_slot(), _plain_slot(near_vector=nv(15)),
+        _plain_slot(near_vector=nv(17)), _plain_slot()])
+    assert breq.ByteSize() == pb.BatchSearchRequest(
+        requests=[_plain_slot()] * 4).ByteSize()
+    assert sv._raw_batch_decode(breq) is None
+    plain = pb.BatchSearchRequest(requests=[_plain_slot()] * 4)
+    assert sv._raw_batch_decode(plain) is not None
+    extra = pb.BatchSearchRequest.FromString(
+        plain.SerializeToString() + b"\x10\x01")
+    assert sv._raw_batch_decode(extra) is None
+    assert sv._raw_batch_decode(pb.BatchSearchRequest()) is None
+
+
+def _c_calls(fn):
+    """How many C functions `fn()` calls, by the interpreter's own count:
+    no clock in it."""
+    import sys
+
+    n = 0
+
+    def prof(frame, event, arg):
+        nonlocal n
+        if event == "c_call":
+            n += 1
+
+    sys.setprofile(prof)
+    try:
+        out = fn()
+    finally:
+        sys.setprofile(None)
+    return n, out
+
+
+def test_decode_makes_no_call_per_element():
+    """A 256 x 768 BatchSearchRequest is decoded in fewer than 16 C calls a
+    slot (the per-element loop made 768 and more a slot), and in fact in
+    fewer than one: the raw lane does no Python work a slot. So a later
+    edit cannot bring either loop back unnoticed."""
+    from weaviate_tpu.server.grpc_server import params_from_proto
+
+    rng = np.random.default_rng(3)
+    vecs = rng.standard_normal((256, 768)).astype(np.float32)
+    breq = pb.BatchSearchRequest.FromString(pb.BatchSearchRequest(requests=[
+        pb.SearchRequest(class_name="Cls", limit=10,
+                         near_vector=pb.NearVectorParams(vector=v.tolist()))
+        for v in vecs]).SerializeToString())
+    sv, _ = _stub_servicer()
+    calls, (_, q, _) = _c_calls(lambda: sv._raw_batch_decode(breq))
+    assert (q == vecs).all()
+    assert calls < 16 * 256
+    assert calls < 256, calls
+    # the general path's decode, a slot at a time
+    calls, params = _c_calls(
+        lambda: [params_from_proto(r) for r in breq.requests])
+    assert (np.stack([p.near_vector["vector"] for p in params]) == vecs).all()
+    assert calls < 16 * 256, calls
+
+
+def test_hybrid_vector_reaches_the_dense_leg(setup):
+    """A hybrid query's vector takes the same decode: with alpha 1 it is a
+    near-vector search by another name."""
+    _, _, client, vecs = setup
+    near = client.search(pb.SearchRequest(
+        class_name="Doc", limit=3,
+        near_vector=pb.NearVectorParams(vector=vecs[4].tolist())))
+    hyb = client.search(pb.SearchRequest(
+        class_name="Doc", limit=3,
+        hybrid=pb.HybridParams(vector=vecs[4].tolist(), alpha=1.0)))
+    assert [r.id for r in hyb.results] == [r.id for r in near.results]
+    assert hyb.results[0].id == str(uuidlib.UUID(int=5))

@@ -563,6 +563,21 @@ def test_grpc_batch_trace_is_a_waterfall_with_decode_and_encode(tmp_path):
                 "entry.decode", "dispatch", "entry.encode"]
             assert app.perf_window.summary()["phases"]["decode"][
                 "samples"] == 2
+            # one slot with an offset: the raw lane reads that off the
+            # request's bytes and declines, the general path decodes and
+            # serves, and the ledger takes that one sample, not two
+            cl.batch_search(pb.BatchSearchRequest(requests=[
+                pb.SearchRequest(class_name="Tr", limit=K, offset=i % 2,
+                                 near_vector=pb.NearVectorParams(
+                                     vector=(vecs[i] + 0.5).tolist()))
+                for i in range(4)]))
+            kids = app.tracer.snapshot()[-1]["root"]["children"]
+            assert [c["name"] for c in kids] == [
+                "entry.decode", "entry.decode",
+                "traverser.get_class_batched", "entry.encode"]
+            assert kids[0]["attrs"] == {"lane": "raw"}
+            assert app.perf_window.summary()["phases"]["decode"][
+                "samples"] == 3
         # the single Search has both halves too
         cl.search(pb.SearchRequest(
             class_name="Tr", limit=K,

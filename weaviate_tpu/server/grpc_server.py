@@ -42,6 +42,89 @@ def _entry_phase(name: str):
         perf.note_phase(name, s.duration_ms)
 
 
+def _varint_at(buf: bytes, pos: int) -> tuple[int, int]:
+    """(value, position after it) of the varint at `pos`."""
+    n = shift = 0
+    while True:
+        b = buf[pos]
+        pos += 1
+        n |= (b & 0x7F) << shift
+        if b < 0x80:
+            return n, pos
+        shift += 7
+
+
+def _len_tag(message, field: str) -> bytes:
+    """The tag byte(s) of a length-delimited field, from the descriptor."""
+    number = message.DESCRIPTOR.fields_by_name[field].number
+    return reply_native.varint(number << 3 | 2)
+
+
+def _vector_f32(msg) -> np.ndarray:
+    """The `repeated float vector` of a NearVectorParams / HybridParams as a
+    float32 array, copied out as its packed bytes and never as one Python
+    float an element. upb serialises a parsed message canonically: known
+    fields in field-number order, a repeated scalar as ONE packed run
+    whatever mix of packed, split or unpacked elements the client sent,
+    unknown fields last. So the payload lies behind the tags of the fields
+    numbered below it (none, or HybridParams.query), which are walked and
+    not assumed. The array is a read-only view of the serialised bytes."""
+    buf = msg.SerializeToString()
+    number = msg.DESCRIPTOR.fields_by_name["vector"].number
+    pos, end = 0, len(buf)
+    while pos < end:
+        tag, pos = _varint_at(buf, pos)
+        if tag >> 3 > number:
+            break  # canonical order: past where it would be, so it is empty
+        if tag & 7 != 2:
+            raise ValueError(f"wire type {tag & 7} before the vector")
+        n, pos = _varint_at(buf, pos)
+        if tag >> 3 == number:
+            return np.frombuffer(buf, "<f4", n >> 2, pos)
+        pos += n
+    return np.empty(0, np.float32)
+
+
+_REQUESTS_TAG = _len_tag(pb.BatchSearchRequest, "requests")
+_NEAR_VECTOR_TAG = _len_tag(pb.SearchRequest, "near_vector")
+_VECTOR_TAG = _len_tag(pb.NearVectorParams, "vector")
+
+
+def _plain_batch_queries(request: pb.BatchSearchRequest, cls: str,
+                         limit: int, dim: int) -> Optional[np.ndarray]:
+    """[B, dim] float32 queries of a batch in which EVERY slot is nothing
+    but `cls`, `limit` and a `dim`-wide near_vector.vector; None for any
+    other batch. One serialisation of the whole request and one comparison
+    over it, no Python work a slot or an element.
+
+    A slot of that kind has one canonical serialisation (see _vector_f32):
+    a head that is the same bytes in every slot (slot length, class_name,
+    limit, near_vector length, vector length) and then its 4*dim payload
+    bytes. So the request is eligible exactly when its canonical bytes are
+    B such records back to back: if record i starts where expected with the
+    expected head, its lengths say that it ends where record i+1 is
+    expected, that near_vector fills the slot to its end and the payload
+    fills near_vector, so no other field of SearchRequest (they would sit
+    before near_vector and change the head, or after it and change the
+    slot's length), no certainty or distance, and no other width can hide
+    in it. A field this build does not know also declines the batch: the
+    general path serves it."""
+    varint = reply_native.varint
+    nbytes = 4 * dim
+    vector = _VECTOR_TAG + varint(nbytes)
+    slot = pb.SearchRequest(class_name=cls, limit=limit).SerializeToString() \
+        + _NEAR_VECTOR_TAG + varint(len(vector) + nbytes) + vector
+    head = _REQUESTS_TAG + varint(len(slot) + nbytes) + slot
+    wire = request.SerializeToString()
+    n, record = len(request.requests), len(head) + nbytes
+    if len(wire) != n * record:
+        return None
+    records = np.frombuffer(wire, np.uint8).reshape(n, record)
+    if not (records[:, :len(head)] == np.frombuffer(head, np.uint8)).all():
+        return None
+    return np.ascontiguousarray(records[:, len(head):]).view("<f4")
+
+
 def _request_meta(context) -> tuple[str, Optional[str], float, float,
                                     Optional[str]]:
     """(request_id, traceparent, explicit_timeout_ms, transport_timeout_ms,
@@ -129,7 +212,7 @@ def params_from_proto(req: pb.SearchRequest) -> GetParams:
     """searchParamsFromProto twin (server.go:137)."""
     near_vector = None
     if req.HasField("near_vector") and len(req.near_vector.vector):
-        near_vector = {"vector": list(req.near_vector.vector)}
+        near_vector = {"vector": _vector_f32(req.near_vector)}
         if req.near_vector.HasField("certainty"):
             near_vector["certainty"] = req.near_vector.certainty
         if req.near_vector.HasField("distance"):
@@ -150,7 +233,7 @@ def params_from_proto(req: pb.SearchRequest) -> GetParams:
     if req.HasField("hybrid") and (req.hybrid.query or len(req.hybrid.vector)):
         hybrid = {"query": req.hybrid.query}
         if len(req.hybrid.vector):
-            hybrid["vector"] = list(req.hybrid.vector)
+            hybrid["vector"] = _vector_f32(req.hybrid)
         if req.hybrid.HasField("alpha"):
             hybrid["alpha"] = req.hybrid.alpha
         if req.hybrid.fusion_type:
@@ -362,17 +445,6 @@ class SearchServicer:
         dim = len(f0.near_vector.vector) if f0.HasField("near_vector") else 0
         if dim == 0:
             return None
-        for r in reqs:
-            if (r.class_name != cls or int(r.limit) != limit or r.offset
-                    or r.properties or r.additional_properties or r.where_json
-                    or r.consistency_level
-                    or not r.HasField("near_vector")
-                    or len(r.near_vector.vector) != dim
-                    or r.near_vector.HasField("certainty")
-                    or r.near_vector.HasField("distance")
-                    or r.HasField("near_object") or r.HasField("bm25")
-                    or r.HasField("hybrid")):
-                return None
         resolved = self.app.schema.resolve_class_name(cls)
         idx = self.app.db.get_index(resolved) if resolved else None
         if idx is None:
@@ -382,9 +454,9 @@ class SearchServicer:
             return None
         if not shard.raw_plane_ready():
             return None  # before ANY device work: the general path searches once
-        q = np.empty((len(reqs), dim), dtype=np.float32)
-        for i, r in enumerate(reqs):
-            q[i] = np.fromiter(r.near_vector.vector, np.float32, dim)
+        q = _plain_batch_queries(request, cls, limit, dim)
+        if q is None:
+            return None
         return shard, q, k
 
     def BatchSearch(self, request: pb.BatchSearchRequest, context) -> pb.BatchSearchReply:
