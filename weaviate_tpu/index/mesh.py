@@ -556,12 +556,20 @@ class MeshVectorIndex(VectorIndex):
             ids64, vecs = ids64[keep], vecs[keep]
             if len(ids64) == 0:
                 return
-        self._pending.update(zip(ids64.tolist(), vecs))
-        self.live += len(ids64)
         self._staged_gen += 1
         self._mark_staged()
-        if len(self._pending) >= _FLUSH_CHUNK:
-            self._flush_pending()
+        self.live += len(ids64)
+        if len(ids64) < _FLUSH_CHUNK:
+            self._pending.update(zip(ids64.tolist(), vecs))
+            if len(self._pending) >= _FLUSH_CHUNK:
+                self._flush_pending()
+            return
+        # a long run (a whole import's log is one) lands as it is: the
+        # slabs are sized once from its row count and the rows go down in
+        # insert steps, never through a dict entry a row and one stacked
+        # copy of the log (single-chip twin: tpu.py _write_block)
+        self._flush_pending()  # earlier staged singles keep their slots
+        self._write_balanced(ids64, vecs)
 
     def _stage_delete(self, doc_id: int, log: bool = True) -> None:
         row = self._doc_to_row.pop(doc_id, None)
@@ -666,29 +674,32 @@ class MeshVectorIndex(VectorIndex):
                 "compression for this index", e)
 
     def _write_balanced(self, docs: np.ndarray, rows: np.ndarray) -> None:
-        """Land [count, D] rows across slabs in whole-mesh insert steps."""
+        """Land [count, D] rows across slabs in whole-mesh insert steps.
+        Shard s takes the contiguous run `assign[s]` of `rows`, a step's
+        worth at a time."""
         assign = self._assign_balanced(rows.shape[0])
         needed = max(
             int(self._counts[s]) + len(assign[s]) for s in range(self.n_dev)
         )
         self._grow(needed)
-        queues = [list(a) for a in assign]
-        while any(queues):
-            max_rem = max(len(q) for q in queues)
+        first = [int(a[0]) if len(a) else 0 for a in assign]
+        left = [len(a) for a in assign]
+        while any(left):
             max_off = max(
-                int(self._counts[s]) for s in range(self.n_dev) if queues[s]
+                int(self._counts[s]) for s in range(self.n_dev) if left[s]
             )
-            c = min(_bucket_rows(max_rem), _MAX_WRITE_C, self.n_loc - max_off)
+            c = min(_bucket_rows(max(left)), _MAX_WRITE_C, self.n_loc - max_off)
             c = max(c, 1)
             chunks = np.zeros((self.n_dev, c, self.dim), np.float32)
             pairs = np.zeros((self.n_dev, c, 2), np.uint32)
             offsets = self._counts.astype(np.int32)
             takes = np.zeros(self.n_dev, dtype=np.int32)
-            taken: list[np.ndarray] = []
+            taken: list[slice] = []
             for s in range(self.n_dev):
-                take = min(c, len(queues[s]))
-                sel = np.array(queues[s][:take], dtype=np.int64)
-                queues[s] = queues[s][take:]
+                take = min(c, left[s])
+                sel = slice(first[s], first[s] + take)
+                first[s] += take
+                left[s] -= take
                 if take:
                     chunks[s, :take] = rows[sel]
                     du = docs[sel].view(np.uint64)
@@ -698,7 +709,7 @@ class MeshVectorIndex(VectorIndex):
                 takes[s] = take
                 taken.append(sel)
             chunks_dev = jax.device_put(
-                jnp.asarray(chunks), shard_spec(self.mesh, None, None)
+                chunks, shard_spec(self.mesh, None, None)
             )
             self._store, self._sq_norms = mesh_insert_step(
                 self._store,
@@ -713,8 +724,7 @@ class MeshVectorIndex(VectorIndex):
             # dispatch's on-device slot->doc stays in lockstep with the host map
             self._s2d_dev = mesh_write_pairs_step(
                 self._s2d_dev,
-                jax.device_put(jnp.asarray(pairs),
-                               shard_spec(self.mesh, None, None)),
+                jax.device_put(pairs, shard_spec(self.mesh, None, None)),
                 jnp.asarray(offsets),
                 jnp.asarray(takes),
                 self.mesh,
@@ -759,7 +769,7 @@ class MeshVectorIndex(VectorIndex):
                         self.mesh,
                     )
             for s in range(self.n_dev):
-                take = len(taken[s])
+                take = int(takes[s])
                 if not take:
                     continue
                 base = s * self.n_loc + int(self._counts[s])
@@ -1513,7 +1523,7 @@ class MeshVectorIndex(VectorIndex):
             raise
 
         if shape is not None:
-            now_ns = enqueue.end(rows=b, tier=shape.tier)
+            now_ns = enqueue.end(rows=b, tier=shape.tier, ndev=shape.ndev)
             shape.t_start = t_enq0
             shape.enqueue_ms = (now_ns - enqueue.start_ns) / 1e6
             if fused:

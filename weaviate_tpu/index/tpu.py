@@ -349,7 +349,10 @@ def _fetch_packed(packed_dev, shape=None) -> np.ndarray:
     if shape is None:
         return np.asarray(packed_dev)
     shape.end_hop()  # a second fetch of one finalize ends the first one's hop
-    wait = tracing.Phase("device_wait", rows=shape.batch, tier=shape.tier)
+    # a mesh dispatch says how many chips its one program spans
+    spans = {"ndev": shape.ndev} if shape.ndev != 1 else {}
+    wait = tracing.Phase("device_wait", rows=shape.batch, tier=shape.tier,
+                         **spans)
     try:
         out = np.asarray(packed_dev)
     finally:

@@ -800,10 +800,11 @@ class MemoryLedger:
         try:
             import jax
 
-            stats = jax.local_devices()[0].memory_stats()
+            every = [d.memory_stats() or {} for d in jax.local_devices()]
         except Exception:  # noqa: BLE001 — absent backend support
             return None
-        if not stats or "bytes_in_use" not in stats:
+        stats = every[0]
+        if "bytes_in_use" not in stats:
             return None
         in_use = int(stats["bytes_in_use"])
         pulled = device_provider_components()
@@ -824,6 +825,14 @@ class MemoryLedger:
             # what a transient (a dispatch's workspace, a COW copy) reached
             # that bytes_in_use, read after the fact, no longer shows
             out["allocator_peak_bytes"] = int(stats["peak_bytes_in_use"])
+        # a mesh index spreads one shard over every local chip, and the
+        # fullest one is what limits: the maximum over the devices of the
+        # two numbers above (equal to them on one chip)
+        out["fullest_bytes_in_use"] = max(
+            int(s.get("bytes_in_use", 0)) for s in every)
+        if "allocator_peak_bytes" in out:
+            out["fullest_peak_bytes"] = max(
+                int(s.get("peak_bytes_in_use", 0)) for s in every)
         return out
 
     def summary(self) -> dict:

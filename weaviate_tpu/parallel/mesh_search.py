@@ -114,7 +114,15 @@ def _merge_local(d_top, i_loc, s2d_l, my, n_loc, k, fused):
 
 def make_mesh(n_devices: Optional[int] = None, devices=None) -> Mesh:
     if devices is None:
-        devices = jax.devices()[: n_devices or len(jax.devices())]
+        devices = jax.devices()
+        if n_devices:
+            if n_devices > len(devices):
+                # a pinned meshDevices is the deployment's: never served
+                # from fewer chips than it names
+                raise ValueError(
+                    f"mesh of {n_devices} devices asked for, this process "
+                    f"has {len(devices)}")
+            devices = devices[:n_devices]
     return Mesh(np.array(devices), (SHARD_AXIS,))
 
 
