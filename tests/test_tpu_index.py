@@ -234,3 +234,73 @@ def test_growth_past_min_capacity(tmp_path, rng):
     ids, _ = idx.search_by_vector(vecs[n - 1], 1)
     assert ids[0] == n - 1
     assert len(idx) == n
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["slots", "fused"])
+@pytest.mark.parametrize("batch", [1, 8])
+@pytest.mark.parametrize("metric", [vi.DISTANCE_L2, vi.DISTANCE_COSINE, vi.DISTANCE_DOT])
+def test_scan_reads_only_live_chunks_of_a_part_full_slab(metric, batch, fused):
+    """The scan's loop indexes the whole slab in place and walks
+    `active_chunks` of its chunks: at a part-full slab (3 of 4 chunks live,
+    n not a multiple of the chunk) the answers are exact float32 brute
+    force's over the live, allowed, untombstoned rows, although every dead
+    row would win if it were read (a copy of a query, or NaN)."""
+    import jax.numpy as jnp
+
+    from weaviate_tpu.index import tpu
+    from weaviate_tpu.ops.topk import unpack_fused, unpack_topk
+
+    rng = np.random.default_rng(7)
+    chunk, dim, k = tpu._SCAN_CHUNK, 8, 10
+    cap, n = 4 * chunk, 2 * chunk + 12_345
+    store = rng.standard_normal((cap, dim)).astype(np.float32)
+    if metric == vi.DISTANCE_COSINE:
+        store /= np.linalg.norm(store, axis=1, keepdims=True)
+    q = store[rng.integers(0, n, batch)] \
+        + 0.05 * rng.standard_normal((batch, dim)).astype(np.float32)
+    if metric == vi.DISTANCE_COSINE:
+        q /= np.linalg.norm(q, axis=1, keepdims=True)
+    # the dead tail, inside the last live chunk and in the dead one: rows
+    # that beat every live row for query 0, and NaN
+    store[n::2] = q[0] * (100.0 if metric == vi.DISTANCE_DOT else 1.0)
+    store[n + 1::2] = np.nan
+
+    def exact(rows_ok):
+        live = store[:n].astype(np.float64)
+        qq = q.astype(np.float64)
+        if metric == vi.DISTANCE_L2:
+            d = ((qq[:, None, :] - live[None, :, :]) ** 2).sum(-1)
+        else:
+            d = -(qq @ live.T) if metric == vi.DISTANCE_DOT else 1.0 - qq @ live.T
+        d[:, ~rows_ok] = np.inf
+        ids = np.argsort(d, axis=1, kind="stable")[:, :k]
+        return ids, np.take_along_axis(d, ids, axis=1)
+
+    # tombstone each query's true nearest rows and a twentieth of the rest;
+    # allow every other word's worth of rows at random
+    tombs = np.zeros(cap, bool)
+    tombs[exact(np.ones(n, bool))[0][:, :3].ravel()] = True
+    tombs[rng.random(cap) < 0.05] = True
+    allow = rng.random(cap) < 0.5
+    words = np.packbits(allow.reshape(-1, 32), axis=1,
+                        bitorder="little").view("<u4").ravel()
+    want_ids, want_d = exact(~tombs[:n] & allow[:n])
+
+    slots = np.arange(cap, dtype=np.uint64) * 7 + (1 << 32) + 3  # doc ids
+    s2d = np.stack([slots & 0xFFFFFFFF, slots >> 32], 1).astype(np.uint32)
+    sq = jnp.asarray((store ** 2).sum(1)) if metric == vi.DISTANCE_L2 else None
+    args = (jnp.asarray(store), sq, jnp.asarray(tombs), n, jnp.asarray(q),
+            jnp.asarray(words))
+    statics = dict(k=k, metric=metric, use_allow=True, exact=False,
+                   active_chunks=-(-n // chunk), rescore_r=40)
+    if fused:
+        ids, dists = unpack_fused(np.asarray(
+            tpu._search_full_fused(*args, jnp.asarray(s2d), **statics)))
+        want = slots[want_ids]
+    else:
+        dists, ids = unpack_topk(np.asarray(tpu._search_full(*args, **statics)))
+        want = want_ids
+    np.testing.assert_array_equal(ids, want)
+    # the benchmark's rule for a returned distance (benchmarks/lib/check.py)
+    slack = np.maximum(1e-3 * np.abs(want_d), 1e-3)
+    assert (np.abs(dists - want_d) <= slack).all()
