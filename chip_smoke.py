@@ -5,7 +5,7 @@ process never initialises a JAX backend), and drives it as a client would:
 REST `/v1/batch/objects` for writes, gRPC `Search` / `BatchSearch` for reads.
 
 Deployment: BASELINE.json config 1 — 1,000,000 x 128-d float32, l2-squared,
-k=10, one `hnsw_tpu` shard — with data from bench.py's clustered generator
+k=10, one `hnsw_tpu` shard — with data from a clustered generator
 (no dataset can be fetched; docs/dataset_download_attempts.md). Then two
 200,000-row PQ classes so that each remaining Pallas kernel is compiled by
 Mosaic once, and, when the server reports several devices, the same 1M rows
@@ -201,12 +201,19 @@ class Server:
 # -- data and reference -------------------------------------------------------
 
 
+N_CLUSTERS = 1024
+
+
+def make_data(n: int, dim: int, rng) -> np.ndarray:
+    """SIFT-like clustered distribution: a mixture of gaussians."""
+    centers = rng.standard_normal((N_CLUSTERS, dim), dtype=np.float32) * 2.0
+    assign = rng.integers(0, N_CLUSTERS, n)
+    return centers[assign] + 0.35 * rng.standard_normal((n, dim), dtype=np.float32)
+
+
 def make_dataset(seed: int, rows: int):
     """(vectors [rows, DIM] f32, single queries, batch queries), all from
-    `seed`: bench.py's clustered generator, queries = stored rows plus
-    noise (the bench's own query model)."""
-    from bench import make_data
-
+    `seed`: the clustered generator, queries = stored rows plus noise."""
     rng = np.random.default_rng(seed)
     vecs = make_data(rows, DIM, rng)
     picks = rng.integers(0, rows, N_SINGLE + BATCH)
@@ -217,13 +224,19 @@ def make_dataset(seed: int, rows: int):
 
 def exact_topk(vecs: np.ndarray, queries: np.ndarray, k: int,
                allow: np.ndarray | None = None) -> np.ndarray:
-    """Exact float32 brute force (bench.py's numpy ground truth) -> [Q, k]
-    row ids, restricted to the rows in `allow` when given."""
-    from bench import exact_gt
-
-    if allow is None:
-        return np.stack(exact_gt(vecs, queries, k))
-    return allow[np.stack(exact_gt(vecs[allow], queries, k))]
+    """Exact float32 brute force (a chunked BLAS matmul in numpy, L2)
+    -> [Q, k] row ids, restricted to the rows in `allow` when given."""
+    rows = vecs if allow is None else vecs[allow]
+    norms = (rows.astype(np.float32) ** 2).sum(1)
+    out = []
+    for s in range(0, len(queries), 256):
+        q = queries[s:s + 256].astype(np.float32)
+        d = (q ** 2).sum(1, keepdims=True) - 2.0 * (q @ rows.T) + norms[None, :]
+        part = np.argpartition(d, k, axis=1)[:, :k]
+        for i in range(q.shape[0]):
+            out.append(part[i][np.argsort(d[i, part[i]], kind="stable")])
+    ids = np.stack(out)
+    return ids if allow is None else allow[ids]
 
 
 def check_answers(name: str, vecs, queries, got_ids, got_dists, want_ids,

@@ -6,8 +6,8 @@ journey on the fault harness.
 
 Journeys run against the REAL serving stack (App + coalescer + shard +
 index) like tests/test_robustness.py; timing assertions are deliberately
-loose functional bounds (the tight 2x-p99 isolation claim is bench.py
---tenants' job on a quiet host, not a shared CI runner's).
+loose functional bounds (a tight 2x-p99 isolation claim needs a quiet
+host and a cell of the benchmark, not a shared CI runner).
 """
 
 import http.client
@@ -629,7 +629,7 @@ def test_abusive_tenant_storm_light_tenants_stay_isolated(tmp_path):
         assert abusive_out["other"] == 0
         # loose absolute tail bound: stalled dispatches are 15 ms and the
         # abuser's backlog is budget-capped, so a light request never
-        # waits out a deep queue (CI-safe bound, not the bench's 2x gate)
+        # waits out a deep queue (a CI-safe bound)
         for t, lat in light_lat.items():
             p99 = float(np.percentile(np.asarray(lat), 99))
             assert p99 < 5.0, f"{t} p99 {p99:.2f}s under storm"
@@ -661,23 +661,3 @@ def test_tenancy_config_parsing_and_validation():
                 {"TENANT_METRICS_TOP_K": "0"}):
         with pytest.raises(ConfigError):
             load_config(bad)
-
-
-# -- bench_matrix satellite: stale rows ---------------------------------------
-
-
-def test_merge_matrix_marks_legacy_rows_stale_true(tmp_path, monkeypatch):
-    import bench
-
-    mfile = tmp_path / "m.json"
-    monkeypatch.setattr(bench, "MATRIX_FILE", str(mfile))
-    monkeypatch.setenv("BENCH_GATE", "0")
-    mfile.write_text(json.dumps({
-        "legacy_tpu": {"qps": 5.0},                      # pre-provenance
-        "live_cpu": {"backend": "cpu", "qps": 100.0},
-    }))
-    data = bench._merge_matrix({"new_row": {"backend": "cpu", "qps": 1.0}})
-    assert data["legacy_tpu"]["stale"] is True
-    assert "stale_note" in data["legacy_tpu"]
-    assert data["legacy_tpu"]["backend"] == "tpu-v5e"
-    assert "stale" not in data["live_cpu"]

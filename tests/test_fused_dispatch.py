@@ -1,19 +1,22 @@
-"""Fully fused device dispatch (index/tpu.py): one program from scan to
-final doc ids, zero host post-processing.
+"""The device dispatch (index/tpu.py): one program from scan to final doc
+ids, zero host post-processing. It is the only way a result leaves the
+device (PR 27 removed the host-translation twin of every program).
 
-Pins the fused-dispatch PR's contracts:
+Pins its contracts:
 
-1. bit-identity — fused vs legacy (host slot_to_doc translation) return
-   EXACTLY the same ids and distances on every tier: exact scan, PQ
-   rescore, PQ codes-only, small-allowList gather (compressed and not),
-   and target-distance widening; sync == async both ways;
-2. snapshot pinning survives fusion — enqueue, then delete the winners
-   and compact(): finalize still returns the OLD snapshot's exact doc
-   ids (the device translation table is pinned by the snapshot like
-   every other device buffer);
-3. the perf-ledger invariant — a fused dispatch records exactly ONE
-   blocking fetch and ZERO host-translation time
-   (costmodel.fused_invariant_ok; the window counts violations);
+1. bit-identity — on every tier (exact scan, filtered scan, PQ rescore,
+   PQ codes-only, small-allowList gather compressed and not) the served
+   ids and distances are EXACTLY what the tier's slot-returning body
+   gives when the test jits it and translates its slots on the host
+   through snap.slot_to_doc; sync == async; doc ids are not the slot
+   numbers, 64-bit ids and the 2^64-1 "missing" id survive every tier's
+   epilogue; target-distance widening matches exact brute force;
+2. snapshot pinning — enqueue, then delete the winners and compact():
+   finalize still returns the OLD snapshot's exact doc ids (the device
+   translation table is pinned by the snapshot like every other device
+   buffer);
+3. the perf-ledger invariant — a dispatch records exactly ONE blocking
+   fetch (costmodel.fused_invariant_ok; the window counts violations);
 4. the satellites — the sorted doc->slot map is gone (gather resolves
    via a cached vectorized membership pass), the slot_to_doc COW copy is
    gone from the write path (append-only invariant), R_BUCKETS has one
@@ -21,6 +24,9 @@ Pins the fused-dispatch PR's contracts:
    per-bucket host buffers.
 """
 
+import inspect
+
+import jax
 import numpy as np
 import pytest
 
@@ -28,6 +34,8 @@ from weaviate_tpu.entities.vectorindex import parse_and_validate_config
 from weaviate_tpu.index import tpu
 from weaviate_tpu.index.tpu import TpuVectorIndex
 from weaviate_tpu.monitoring import costmodel, perf, tracing
+from weaviate_tpu.ops import gmin_scan, pq_gmin
+from weaviate_tpu.ops.topk import unpack_topk
 from weaviate_tpu.storage.bitmap import Bitmap
 
 DIM = 16
@@ -36,12 +44,12 @@ DIM = 16
 @pytest.fixture(autouse=True)
 def _reset_globals():
     yield
-    tpu.set_fused_enabled(None)
     tracing.configure(None)
     perf.configure(None)
 
 
-def _mk_index(tmp_path, n=500, pq=None, seed=0, name="fx", **cfg_extra):
+def _mk_index(tmp_path, n=500, pq=None, seed=0, name="fx", ids=None,
+              **cfg_extra):
     rng = np.random.default_rng(seed)
     # small-integer vectors: every L2 distance is exact integer arithmetic
     # in f32 regardless of accumulation order, so equality checks are exact
@@ -51,95 +59,188 @@ def _mk_index(tmp_path, n=500, pq=None, seed=0, name="fx", **cfg_extra):
         d["pq"] = pq
     cfg = parse_and_validate_config("hnsw_tpu", d)
     idx = TpuVectorIndex(cfg, str(tmp_path / name), persist=False)
-    idx.add_batch(np.arange(n), vecs)
+    ids = np.arange(n) if ids is None else ids
+    idx.add_batch(ids.astype(np.int64), vecs)
     idx.flush()
     return idx, vecs
 
 
+TIERS = ("exact", "filtered_scan", "gather", "pq_rescore", "pq_codes",
+         "pq_gather")
+_PQ = {"enabled": True, "segments": 4, "centroids": 16}
+
+
+def _tier(tmp_path, tier, n=500, ids=None):
+    """(index, vectors, allowList) of one read tier; `ids` [n] are the doc
+    ids of the rows (default: the slot numbers), and the allowLists name
+    the docs of rows 3, 7, 11 (and 401), whatever their ids."""
+    ids = np.arange(n, dtype=np.uint64) if ids is None else ids
+    if tier.startswith("pq"):
+        rescore = tier == "pq_rescore"
+        idx, vecs = _mk_index(tmp_path, n=n, name=tier, ids=ids,
+                              pq={**_PQ, "rescore": rescore})
+        assert idx.compressed
+        assert (idx._rescore_dev is not None) == rescore
+    else:
+        idx, vecs = _mk_index(tmp_path, n=n, name=tier, ids=ids)
+    allow = None
+    if tier == "filtered_scan":
+        # over the cutoff (so the masked full scan serves): every row's
+        # doc, padded with ids no row has
+        absent = np.arange(idx.config.flat_search_cutoff + 64,
+                           dtype=np.uint64) + np.uint64(1 << 62)
+        allow = Bitmap(np.concatenate([ids.astype(np.uint64), absent]))
+    elif tier == "gather":
+        allow = Bitmap(ids[[3, 7, 11, 401]].astype(np.uint64))
+    elif tier == "pq_gather":
+        allow = Bitmap(ids[[3, 7, 11]].astype(np.uint64))
+    return idx, vecs, allow
+
+
 def _tiers(tmp_path, n=500):
-    """(name, index, allowList) per read tier, sharing one dataset."""
-    out = []
-    idx, vecs = _mk_index(tmp_path, n=n, name="exact")
-    out.append(("exact", idx, vecs, None))
-    cutoff = idx.config.flat_search_cutoff
-    big_allow = Bitmap(np.arange(0, cutoff + 64, dtype=np.uint64))
-    out.append(("filtered_scan", idx, vecs, big_allow))
-    out.append(("gather", idx, vecs,
-                Bitmap(np.array([3, 7, 11, 401], dtype=np.uint64))))
-    pq_r, vecs_r = _mk_index(
-        tmp_path, n=n, name="pqr",
-        pq={"enabled": True, "segments": 4, "centroids": 16})
-    assert pq_r.compressed and pq_r._rescore_dev is not None
-    out.append(("pq_rescore", pq_r, vecs_r, None))
-    pq_c, vecs_c = _mk_index(
-        tmp_path, n=n, name="pqc",
-        pq={"enabled": True, "segments": 4, "centroids": 16,
-            "rescore": False})
-    assert pq_c.compressed and pq_c._rescore_dev is None
-    out.append(("pq_codes", pq_c, vecs_c, None))
-    out.append(("pq_gather", pq_c, vecs_c,
-                Bitmap(np.array([3, 7, 11], dtype=np.uint64))))
-    return out
+    """(name, index, vectors, allowList) of every read tier."""
+    return [(t, *_tier(tmp_path, t, n)) for t in TIERS]
 
 
-# -- 1. fused == legacy bit identity, sync == async ---------------------------
+# -- 1. device translation == host translation of the same body ---------------
+
+_GMIN_STATICS = ("use_allow", "k", "metric", "rg", "active_g", "interpret")
+# every search program a tier above can reach, beside the traced body it
+# translates: (module, program, body, the body's static arguments)
+_PROGRAMS = (
+    (tpu, "_search_full_fused", tpu._scan_full, tpu._SCAN_STATICS),
+    (gmin_scan, "search_gmin_fused", gmin_scan.gmin_topk, _GMIN_STATICS),
+    (pq_gmin, "search_pq_gmin_fused", pq_gmin.pq_gmin_topk, _GMIN_STATICS),
+    (tpu, "_search_pq_recon_fused", tpu._pq_recon_topk,
+     ("k", "r_chunk", "metric", "use_allow", "exact", "active_chunks",
+      "do_rescore")),
+    (tpu, "_search_pq_fused", tpu._pq_lut_topk,
+     ("r", "use_allow", "exact", "active_chunks")),
+    (tpu, "_score_rows_fused", tpu._score_rows_topk, ("k", "metric")),
+    (tpu, "_search_gathered_fused", tpu._gathered_topk, ("k", "metric")),
+)
 
 
-def test_fused_legacy_bit_identity_all_tiers_sync_and_async(tmp_path):
-    for name, idx, vecs, allow in _tiers(tmp_path):
-        q = vecs[:9] + 0.01
-        tpu.set_fused_enabled(True)
-        f_sync = idx.search_by_vectors(q, 10, allow)
-        f_async = idx.search_by_vectors_async(q, 10, allow)()
-        tpu.set_fused_enabled(False)
-        l_sync = idx.search_by_vectors(q, 10, allow)
-        l_async = idx.search_by_vectors_async(q, 10, allow)()
-        for got in (f_sync, f_async, l_async):
-            np.testing.assert_array_equal(got[0], l_sync[0], err_msg=name)
-            np.testing.assert_array_equal(got[1], l_sync[1], err_msg=name)
-        assert f_sync[0].dtype == np.uint64, name
-        assert f_sync[1].dtype == np.float32, name
+def _spy_programs(monkeypatch):
+    """Wrap every search program so that a dispatch records which program
+    served and with what -> the list of (body, statics, args, kwargs), the
+    arguments being the program's less its translation table."""
+    calls = []
+    for mod, name, body, statics in _PROGRAMS:
+        real = getattr(mod, name)
+        sig = getattr(real, "_plain", real)  # _ScanProgram holds two jits
+        at = list(inspect.signature(sig).parameters).index("s2d")
+
+        def spy(*args, _real=real, _at=at, _body=body, _statics=statics,
+                **kwargs):
+            assert len(args) > _at  # s2d is always passed by position
+            calls.append((_body, _statics, args[:_at] + args[_at + 1:],
+                          kwargs))
+            return _real(*args, **kwargs)
+
+        monkeypatch.setattr(mod, name, spy)
+    return calls
+
+
+def _host_translated(call, snap, b, k):
+    """The reference: the tier's slot-returning body under jax.jit, its
+    slots translated on the host through the snapshot's slot_to_doc."""
+    body, statics, args, kwargs = call
+    out = jax.jit(body, static_argnames=statics)(*args, **kwargs)
+    if isinstance(out, tuple):
+        top, slots = (np.asarray(x) for x in out)
+    else:  # _scan_full packs (dists | slots)
+        top, slots = unpack_topk(np.asarray(out))
+    ids = np.where(slots >= 0, snap.slot_to_doc[np.clip(slots, 0, None)], -1)
+    return (ids.astype(np.uint64)[:b, :k], top.astype(np.float32)[:b, :k])
+
+
+# a batch under 8 rows is refused by the Pallas group-min kernels, so the
+# same tiers then run the lax.scan programs
+@pytest.mark.parametrize("batch", [9, 3])
+@pytest.mark.parametrize("tier", TIERS)
+def test_fused_legacy_bit_identity_all_tiers_sync_and_async(
+        tmp_path, monkeypatch, tier, batch):
+    n = 500
+    doc_ids = np.uint64(10_000) + np.uint64(3) * np.arange(n, dtype=np.uint64)
+    idx, vecs, allow = _tier(tmp_path, tier, n, doc_ids)
+    calls = _spy_programs(monkeypatch)
+    q = vecs[:batch] + 0.01
+    snap = idx._read_snapshot()
+    got_sync = idx.search_by_vectors(q, 10, allow)
+    got_async = idx.search_by_vectors_async(q, 10, allow)()
+    assert len(calls) == 2 and calls[0][0] is calls[1][0], calls
+    served = {"exact": "_scan_full", "filtered_scan": "_scan_full",
+              "pq_rescore": "_scan_full", "pq_codes": "_pq_recon_topk"}
+    if batch < 8 and tier in served:
+        assert calls[0][0].__name__ == served[tier]
+    want = _host_translated(calls[0], snap, batch, got_sync[0].shape[1])
+    for got in (got_sync, got_async):
+        np.testing.assert_array_equal(got[0], want[0])
+        np.testing.assert_array_equal(got[1], want[1])
+    assert got_sync[0].dtype == np.uint64
+    assert got_sync[1].dtype == np.float32
+    found = got_sync[0][np.isfinite(got_sync[1])]
+    assert found.size and np.isin(found, doc_ids).all()
 
 
 def test_fused_target_distance_widening_matches_legacy(tmp_path):
+    """The widening loop against exact brute force (integer vectors: every
+    distance is exact in f32)."""
     idx, vecs = _mk_index(tmp_path)
-    q = vecs[5] + 0.01
-    tpu.set_fused_enabled(True)
-    ids_f, d_f = idx.search_by_vector_distance(q, 300.0, 64)
-    tpu.set_fused_enabled(False)
-    ids_l, d_l = idx.search_by_vector_distance(q, 300.0, 64)
-    np.testing.assert_array_equal(ids_f, ids_l)
-    np.testing.assert_array_equal(d_f, d_l)
-    assert len(ids_f) > 0
+    q = vecs[5] + np.float32(1.0)
+    ids, dists = idx.search_by_vector_distance(q, 300.0, 64)
+    d = ((vecs - q) ** 2).sum(1)
+    inside = np.flatnonzero(d <= 300.0)
+    assert 0 < inside.size < 64
+    np.testing.assert_array_equal(dists, np.sort(d[inside]))
+    assert set(ids.tolist()) == set(inside.tolist())
 
 
-def test_fused_missing_slots_carry_legacy_sentinel(tmp_path):
-    """Fewer matches than k: missing slots must read inf/2^64-1 exactly
-    like the legacy host translation emitted (np.int64(-1) as uint64)."""
-    idx, vecs = _mk_index(tmp_path)
-    cutoff = idx.config.flat_search_cutoff
-    # masked full scan with only 3 live matches (the rest are absent ids)
-    allow = Bitmap(np.array(
-        [0, 1, 2] + list(range(10**6, 10**6 + cutoff + 50)),
-        dtype=np.uint64))
-    tpu.set_fused_enabled(True)
-    ids, dists = idx.search_by_vectors(vecs[:2] + 0.01, 8, allow)
-    assert (ids[:, 3:] == np.uint64(0xFFFFFFFFFFFFFFFF)).all()
-    assert np.isinf(dists[:, 3:]).all()
+@pytest.mark.parametrize("tier", ["filtered_scan", "gather", "pq_rescore",
+                                  "pq_codes", "pq_gather"])
+def test_fused_missing_slots_carry_legacy_sentinel(tmp_path, tier):
+    """Fewer matches than k: the missing columns read inf / 2^64-1
+    (np.int64(-1) as uint64, the id the API has always carried there),
+    through every tier's epilogue that a filter can starve."""
+    idx, vecs, allow = _tier(tmp_path, tier)
+    if allow is None or tier == "filtered_scan":
+        # masked full scan with only 3 live matches (the rest are absent)
+        cutoff = idx.config.flat_search_cutoff
+        allow = Bitmap(np.array(
+            [0, 1, 2] + list(range(10**6, 10**6 + cutoff + 50)),
+            dtype=np.uint64))
+        k, live = 8, 3
+    else:
+        # the gather tiers size k to the allowList; a tombstoned member
+        # leaves its column empty
+        idx.delete(7)
+        idx.flush()
+        k, live = len(allow), len(allow) - 1
+    ids, dists = idx.search_by_vectors(vecs[:2] + 0.01, k, allow)
+    assert ids.shape == (2, k)
+    assert np.isfinite(dists[:, :live]).all()
+    assert (ids[:, live:] == np.uint64(0xFFFFFFFFFFFFFFFF)).all()
+    assert np.isinf(dists[:, live:]).all()
 
 
-def test_fused_keeps_64bit_doc_ids(tmp_path):
-    """Doc ids above 2^32 survive the device translation table's two-word
-    round trip bit-exactly (jax may run with x64 disabled)."""
-    cfg = parse_and_validate_config("hnsw_tpu", {"distance": "l2-squared"})
-    idx = TpuVectorIndex(cfg, str(tmp_path / "big"), persist=False)
-    big = np.array([2**63 + 7, 2**40 + 1, 3], dtype=np.uint64)
-    vecs = np.eye(3, DIM, dtype=np.float32)
-    idx.add_batch(big.astype(np.int64), vecs)
-    idx.flush()
-    tpu.set_fused_enabled(True)
-    ids, _ = idx.search_by_vectors(vecs, 3)
-    assert {int(x) for x in ids[0]} == {int(x) for x in big}
+@pytest.mark.parametrize("tier", TIERS)
+def test_fused_keeps_64bit_doc_ids(tmp_path, tier):
+    """Doc ids above 2^32 (and above 2^63) survive the device translation
+    table's two-word round trip bit-exactly through every tier's epilogue
+    (jax may run with x64 disabled)."""
+    n = 500
+    doc_ids = np.uint64(2**40 + 1) + np.uint64(5) * np.arange(n, dtype=np.uint64)
+    doc_ids[::2] += np.uint64(2**63)
+    idx, vecs, allow = _tier(tmp_path, tier, n, doc_ids)
+    ids, dists = idx.search_by_vectors(vecs[[3, 7, 11]], 3, allow)
+    found = ids[np.isfinite(dists)]
+    assert found.size >= 3 and np.isin(found, doc_ids).all()
+    if allow is not None:
+        assert np.isin(found, allow.to_array()).all()
+    if not tier.startswith("pq") or tier == "pq_gather":
+        # exact distances: every query is a stored row and finds its doc
+        np.testing.assert_array_equal(ids[:, 0], doc_ids[[3, 7, 11]])
 
 
 # -- 2. snapshot pinning across delete + compact ------------------------------
@@ -149,7 +250,6 @@ def test_fused_finalize_pins_snapshot_across_delete_compact(tmp_path):
     """Enqueue -> delete the winners + compact -> finalize returns the
     OLD snapshot's exact answer, on every tier (the PR-4 contract, now
     including the device slot->doc table)."""
-    tpu.set_fused_enabled(True)
     for name, idx, vecs, allow in _tiers(tmp_path):
         q = vecs[:4] + 0.01
         want = idx.search_by_vectors(q, 5, allow)
@@ -167,7 +267,7 @@ def test_fused_finalize_pins_snapshot_across_delete_compact(tmp_path):
             assert not set(winners[:3]) & {int(x) for x in fresh[0].ravel()}
 
 
-# -- 3. the perf-ledger fused-dispatch invariant ------------------------------
+# -- 3. the perf-ledger one-fetch invariant -----------------------------------
 
 
 def _with_perf_window():
@@ -183,13 +283,10 @@ def _pop_shape(idx):
 
 def test_fused_invariant_one_fetch_zero_translation(tmp_path):
     win = _with_perf_window()
-    tpu.set_fused_enabled(True)
     for name, idx, vecs, allow in _tiers(tmp_path):
         ids, dists = idx.search_by_vectors(vecs[:4] + 0.01, 5, allow)
         shape = _pop_shape(idx)
-        assert shape.fused is True, name
         assert shape.fetches == 1, name
-        assert shape.translate_ms == 0.0, name
         assert costmodel.fused_invariant_ok(shape), name
         win.record_dispatch(shape, rows=4)
     s = win.summary()
@@ -197,28 +294,11 @@ def test_fused_invariant_one_fetch_zero_translation(tmp_path):
     assert s["fused"]["violations"] == 0
 
 
-def test_legacy_dispatch_measures_translation_and_passes_trivially(tmp_path):
-    win = _with_perf_window()
-    tpu.set_fused_enabled(False)
-    idx, vecs = _mk_index(tmp_path)
-    idx.search_by_vectors(vecs[:4] + 0.01, 5)
-    shape = _pop_shape(idx)
-    assert shape.fused is False
-    assert shape.fetches == 1
-    assert shape.translate_ms >= 0.0  # measured, not -1
-    assert costmodel.fused_invariant_ok(shape)  # no claim, no violation
-    win.record_dispatch(shape, rows=4)
-    s = win.summary()
-    assert s["fused"] == {"dispatches": 0, "violations": 0}
-
-
 def test_fused_invariant_violation_is_counted(tmp_path):
     win = _with_perf_window()
     shape = costmodel.DispatchShape(costmodel.TIER_EXACT, n=100, dim=DIM,
                                     batch=4, bytes_per_row=DIM * 4, k=5)
-    shape.fused = True
     shape.fetches = 2  # a second blocking fetch broke the contract
-    shape.translate_ms = 0.0
     assert not costmodel.fused_invariant_ok(shape)
     win.record_dispatch(shape, rows=4)
     assert win.summary()["fused"] == {"dispatches": 1, "violations": 1}
@@ -228,13 +308,12 @@ def test_fused_empty_gather_owes_no_fetch(tmp_path):
     """The empty-allowList gather early return runs no device work: zero
     fetches is NOT an invariant violation there (shape.n == 0)."""
     _with_perf_window()
-    tpu.set_fused_enabled(True)
     idx, vecs = _mk_index(tmp_path)
     allow = Bitmap(np.array([10**7, 10**7 + 1], dtype=np.uint64))
     ids, dists = idx.search_by_vectors(vecs[:2], 5, allow)
     assert ids.shape == (2, 0)
     shape = _pop_shape(idx)
-    assert shape.fused and shape.fetches == 0 and shape.n == 0
+    assert shape.fetches == 0 and shape.n == 0
     assert costmodel.fused_invariant_ok(shape)
 
 
@@ -261,7 +340,7 @@ def test_gather_cached_allowlist_never_returns_deleted_docs(tmp_path):
     (allow_token, n, capacity) key does not change on deletes, so a
     REUSED AllowList object after a delete hits a stale slot list — the
     gather kernels must mask tombstones on device with the dispatching
-    snapshot's own tombs (both tiers, fused and legacy)."""
+    snapshot's own tombs (both tiers)."""
     for compress in (False, True):
         pq = ({"enabled": True, "segments": 4, "centroids": 16}
               if compress else None)
@@ -269,18 +348,13 @@ def test_gather_cached_allowlist_never_returns_deleted_docs(tmp_path):
                               name=f"stale{int(compress)}")
         allow = Bitmap(np.array([3, 7, 11], dtype=np.uint64))
         q = vecs[:2] + 0.01
-        for fused in (True, False):
-            tpu.set_fused_enabled(fused)
-            ids0, _ = idx.search_by_vectors(q, 3, allow)  # warms the cache
-            assert 3 in {int(x) for x in ids0.ravel()}
+        ids0, _ = idx.search_by_vectors(q, 3, allow)  # warms the cache
+        assert 3 in {int(x) for x in ids0.ravel()}
         idx.delete(3)
         idx.flush()
-        for fused in (True, False):
-            tpu.set_fused_enabled(fused)
-            ids1, d1 = idx.search_by_vectors(q, 3, allow)  # same object
-            got = {int(x) for x in ids1.ravel() if x != 2**64 - 1}
-            assert got == {7, 11}, (compress, fused, ids1, d1)
-        tpu.set_fused_enabled(None)
+        ids1, d1 = idx.search_by_vectors(q, 3, allow)  # same object
+        got = {int(x) for x in ids1.ravel() if x != 2**64 - 1}
+        assert got == {7, 11}, (compress, ids1, d1)
 
 
 def test_gather_fully_deleted_filter_short_circuits_empty(tmp_path):
@@ -296,13 +370,10 @@ def test_gather_fully_deleted_filter_short_circuits_empty(tmp_path):
     idx.pop_dispatch_shape()
     idx.delete(3, 7)
     idx.flush()
-    for fused in (True, False):
-        tpu.set_fused_enabled(fused)
-        ids, dists = idx.search_by_vectors(q, 3, allow)
-        assert ids.shape == (2, 0) and dists.shape == (2, 0), fused
-        shape = _pop_shape(idx)
-        assert shape.n == 0 and shape.fetches == 0, fused
-    tpu.set_fused_enabled(None)
+    ids, dists = idx.search_by_vectors(q, 3, allow)
+    assert ids.shape == (2, 0) and dists.shape == (2, 0)
+    shape = _pop_shape(idx)
+    assert shape.n == 0 and shape.fetches == 0
 
 
 def test_gather_resolves_readded_doc_to_newest_slot(tmp_path):
@@ -326,7 +397,6 @@ def test_gather_old_pinned_snapshot_keeps_its_predelete_world(tmp_path):
     when the shared slot cache was (re)computed after a delete — the
     cached list carries no tombstone knowledge; each dispatch's own
     device tombs mask decides."""
-    tpu.set_fused_enabled(True)
     idx, vecs = _mk_index(tmp_path)
     allow = Bitmap(np.array([3, 7, 11], dtype=np.uint64))
     q = vecs[:2] + 0.01
@@ -339,7 +409,6 @@ def test_gather_old_pinned_snapshot_keeps_its_predelete_world(tmp_path):
     # a dispatch pinned on A consumes the same cache — doc 3 must be back
     ids_a, dists_a = idx._dispatch_search(snap_a, q, 3, allow)()
     assert 3 in {int(x) for x in ids_a.ravel()}
-    tpu.set_fused_enabled(None)
 
 
 def test_slot_to_doc_cow_copy_dropped_host_tombs_kept(tmp_path):
@@ -439,50 +508,3 @@ def test_drop_blocks_stage_buffer_reparking(tmp_path):
     idx.drop()
     fin()
     assert idx._stage_free == {}
-
-
-def test_fused_override_token_still_ours_discipline(tmp_path):
-    """set_fused_enabled returns a token; unset reverts only the CURRENT
-    override (a stale token is a no-op) — and App.shutdown() uses it, so
-    a torn-down App leaves no toggle residue while a newer App's setting
-    survives."""
-    t1 = tpu.set_fused_enabled(False)
-    t2 = tpu.set_fused_enabled(True)
-    tpu.unset_fused_enabled(t1)  # stale: the newer override survives
-    assert tpu.fused_dispatch_enabled() is True
-    tpu.unset_fused_enabled(t2)  # current: reverts to the env default
-    assert tpu._fused_override is None
-    # App-level: shutdown reverts its own override
-    from weaviate_tpu.config import Config
-    from weaviate_tpu.server import App
-
-    tpu._fused_env = None
-    cfg = Config()
-    cfg.fused_dispatch_enabled = False
-    app = App(config=cfg, data_path=str(tmp_path / "appdata"))
-    try:
-        assert tpu.fused_dispatch_enabled() is False
-    finally:
-        app.shutdown()
-    assert tpu.fused_dispatch_enabled() is True  # env default restored
-
-
-def test_fused_toggle_env_and_setter(monkeypatch):
-    tpu.set_fused_enabled(None)
-    tpu._fused_env = None
-    monkeypatch.setenv("FUSED_DISPATCH_ENABLED", "false")
-    assert tpu.fused_dispatch_enabled() is False
-    tpu.set_fused_enabled(True)
-    assert tpu.fused_dispatch_enabled() is True
-    tpu.set_fused_enabled(None)
-    assert tpu.fused_dispatch_enabled() is False  # env default again
-    tpu._fused_env = None  # drop the cached env parse for other tests
-
-
-def test_config_knob_parses(monkeypatch):
-    from weaviate_tpu.config import load_config
-
-    monkeypatch.setenv("FUSED_DISPATCH_ENABLED", "false")
-    assert load_config().fused_dispatch_enabled is False
-    monkeypatch.delenv("FUSED_DISPATCH_ENABLED")
-    assert load_config().fused_dispatch_enabled is True

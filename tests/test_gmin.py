@@ -1,6 +1,7 @@
 """Fused group-min fast-scan kernel (ops/gmin_scan.py) vs the legacy
 lax.scan kernel and exact numpy ground truth — interpret mode on the CPU
-mesh (the compiled Mosaic path is exercised on real TPU by bench.py)."""
+mesh (the compiled Mosaic path is exercised on real TPU by chip_smoke.py
+and the benchmark's sift cell)."""
 
 import numpy as np
 import pytest

@@ -2,9 +2,9 @@
 
 Pins the IVF PR's contracts:
 
-1. ``top_p = all partitions`` is equivalent to the flat fused path on
+1. ``top_p = all partitions`` is equivalent to the flat path on
    every read tier — exact, filtered scan, PQ rescore, PQ codes-only —
-   sync == async, fused == legacy: distances BIT-equal, ids equal up to
+   sync == async: distances BIT-equal, ids equal up to
    reordering inside exact-distance tie groups (on tie-free data that is
    bit-identity; the helper degenerates to array_equal there);
 2. disabled IVF is a true zero-hop no-op: nothing trains, no device
@@ -43,7 +43,6 @@ DIM = 16
 def _reset_globals():
     yield
     tpu.set_ivf_config(None)
-    tpu.set_fused_enabled(None)
     tracing.configure(None)
     perf.configure(None)
     controller.configure(None)
@@ -87,49 +86,42 @@ def assert_tie_equiv(got, want, msg=""):
                 f"{msg}: tie-group mismatch row {r} dist {v}"
 
 
-# -- 1. top_p = all ≡ flat, every tier, sync+async, fused+legacy --------------
+# -- 1. top_p = all ≡ flat, every tier, sync+async ----------------------------
 
 
-def _tiers(tmp_path, n=600):
-    out = []
-    idx, vecs = _mk_index(tmp_path, n=n, name="exact", exactTopK=True)
-    out.append(("exact", idx, vecs, None))
-    cutoff = idx.config.flat_search_cutoff
-    out.append(("filtered_scan", idx, vecs,
-                Bitmap(np.arange(0, cutoff + 64, dtype=np.uint64))))
-    pq_r, vecs_r = _mk_index(
-        tmp_path, n=n, name="pqr", exactTopK=True,
-        pq={"enabled": True, "segments": 4, "centroids": 16})
-    assert pq_r.compressed and pq_r._rescore_dev is not None
-    out.append(("pq_rescore", pq_r, vecs_r, None))
-    pq_c, vecs_c = _mk_index(
-        tmp_path, n=n, name="pqc", exactTopK=True,
-        pq={"enabled": True, "segments": 4, "centroids": 16,
-            "rescore": False})
-    assert pq_c.compressed and pq_c._rescore_dev is None
-    out.append(("pq_codes", pq_c, vecs_c, None))
-    return out
+def _tier(tmp_path, tier, n=600):
+    """(index, vectors, allowList) of one read tier."""
+    pq = None
+    if tier.startswith("pq"):
+        pq = {"enabled": True, "segments": 4, "centroids": 16,
+              "rescore": tier == "pq_rescore"}
+    idx, vecs = _mk_index(tmp_path, n=n, name=tier, exactTopK=True, pq=pq)
+    if pq is not None:
+        assert idx.compressed
+        assert (idx._rescore_dev is not None) == pq["rescore"]
+    allow = None
+    if tier == "filtered_scan":
+        allow = Bitmap(np.arange(0, idx.config.flat_search_cutoff + 64,
+                                 dtype=np.uint64))
+    return idx, vecs, allow
 
 
-def test_top_p_all_matches_flat_all_tiers_sync_async(tmp_path):
+@pytest.mark.parametrize("tier", ["exact", "filtered_scan", "pq_rescore",
+                                  "pq_codes"])
+def test_top_p_all_matches_flat_all_tiers_sync_async(tmp_path, tier):
     tpu.set_ivf_config(_ivf())  # trains at import time (min_n < n)
-    tiers = _tiers(tmp_path)
-    for name, idx, vecs, allow in tiers:
-        assert idx._ivf_buckets is not None, name
-        q = vecs[:9] + np.float32(1.0)
-        for fused in (True, False):
-            tpu.set_fused_enabled(fused)
-            # top_p=8 == nlist: every partition probed
-            tpu.set_ivf_config(_ivf())
-            i_sync = idx.search_by_vectors(q, 10, allow)
-            i_async = idx.search_by_vectors_async(q, 10, allow)()
-            tpu.set_ivf_config(None)  # flat control on the same index
-            flat = idx.search_by_vectors(q, 10, allow)
-            tag = f"{name} fused={fused}"
-            assert_tie_equiv(i_sync, flat, tag + " sync")
-            assert_tie_equiv(i_async, flat, tag + " async")
-            assert i_sync[0].dtype == np.uint64, tag
-            assert i_sync[1].dtype == np.float32, tag
+    idx, vecs, allow = _tier(tmp_path, tier)
+    assert idx._ivf_buckets is not None
+    q = vecs[:9] + np.float32(1.0)
+    # top_p=8 == nlist: every partition probed
+    i_sync = idx.search_by_vectors(q, 10, allow)
+    i_async = idx.search_by_vectors_async(q, 10, allow)()
+    tpu.set_ivf_config(None)  # flat control on the same index
+    flat = idx.search_by_vectors(q, 10, allow)
+    assert_tie_equiv(i_sync, flat, tier + " sync")
+    assert_tie_equiv(i_async, flat, tier + " async")
+    assert i_sync[0].dtype == np.uint64
+    assert i_sync[1].dtype == np.float32
 
 
 def test_ivf_target_distance_matches_flat(tmp_path):

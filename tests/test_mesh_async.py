@@ -22,6 +22,7 @@ import threading
 import time
 
 import numpy as np
+import pytest
 
 from weaviate_tpu.entities.vectorindex import parse_and_validate_config
 from weaviate_tpu.index.mesh import MeshVectorIndex
@@ -139,9 +140,29 @@ def test_mesh_async_read_takes_zero_index_locks_one_fetch(tmp_path):
     assert shape is not None
     assert shape.ndev == 8
     assert shape.fetches == 1
-    if shape.fused:
-        assert shape.translate_ms == 0.0
-        assert costmodel.fused_invariant_ok(shape)
+    assert costmodel.fused_invariant_ok(shape)
+
+
+def test_mesh_search_step_refuses_a_host_translation(tmp_path):
+    """mesh_search_step keeps its `fused` static for the benchmark's
+    compile tests (ROADMAP.md Queue 3); the program translates on the
+    device and nothing else, so anything but True is refused."""
+    from weaviate_tpu.parallel.mesh_search import mesh_search_step
+
+    idx, vecs, _ = _mk_index(tmp_path)
+    idx.search_by_vectors(vecs[:4], 3)  # publish
+    snap = idx._read_snapshot()
+
+    def step(fused):
+        return mesh_search_step(
+            snap.store, snap.sq_norms, snap.tombs, snap.counts_dev,
+            snap.zero_words, vecs[:4], snap.slot_to_doc_dev, 3, idx.metric,
+            False, False, False, fused, idx.mesh)
+
+    assert np.asarray(step(True)).shape == (4, 9)  # [B, 3k]: doc ids inside
+    for bad in (False, None, 1):
+        with pytest.raises(ValueError, match="fused must be True"):
+            step(bad)
 
 
 def test_mesh_reader_never_blocks_on_writer_held_lock(tmp_path):

@@ -165,6 +165,27 @@ def test_roofline_time_and_qps_forms_agree():
     assert a == q
 
 
+def test_roofline_math_tpu_row():
+    # 10k QPS over n=1M, d=128, batch=16384, f32 store:
+    # flops/batch = 2*16384*1e6*128 = 4.194e12; batches/s = 10000/16384
+    r = costmodel.roofline_from_qps(10_000.0, 1_000_000, 128, 16_384, 128 * 4,
+                                    costmodel.TPU_V5E)
+    assert r["tflops"] == pytest.approx(2 * 16384 * 1e6 * 128 * (10000 / 16384) / 1e12, rel=1e-3)
+    assert r["hbm_gbs"] == pytest.approx(1e6 * 512 * (10000 / 16384) / 1e9, abs=0.01)
+    assert r["mfu_pct"] == pytest.approx(100 * r["tflops"] / 197.0, abs=0.01)
+    assert r["bw_pct"] == pytest.approx(100 * r["hbm_gbs"] / 819.0, abs=0.01)
+    # AI = 2*B/bytes_per_elem = 2*16384/4 = 8192 >> ridge (~240): compute-bound
+    assert r["arith_intensity_flops_per_byte"] == pytest.approx(8192, rel=1e-3)
+    assert r["regime"] == "compute-bound"
+
+
+def test_roofline_small_batch_is_bandwidth_bound():
+    # batch=256 f32: AI = 128 flops/byte < v5e ridge ~240
+    r = costmodel.roofline_from_qps(1_000.0, 100_000, 128, 256, 128 * 4,
+                                    costmodel.TPU_V5E)
+    assert r["regime"] == "hbm-bandwidth-bound"
+
+
 # -- duty cycle ---------------------------------------------------------------
 
 def test_duty_cycle_union_math():

@@ -6,7 +6,7 @@ Pins the funnel PR's contracts:
 1. FUNNEL == EXACT when the budgets cover the candidate set (rc >= n):
    stage 3 reports exact distances, so on tie-free integer data the
    funnel's answer equals the exact scan's — per tier (full store, IVF,
-   mesh), fused == legacy, sync == async.
+   mesh), sync == async.
 2. The OPQ rotation is a real rotation (orthonormal round-trip), it
    lowers quantization error on correlated data, and the 4-bit ladder is
    fit in the SAME rotated space as the 8-bit one (pinned matrix).
@@ -54,7 +54,6 @@ def _reset_globals():
     yield
     controller._plane = saved
     tpu.set_ivf_config(None)
-    tpu.set_fused_enabled(None)
     tracing.configure(None)
     perf.configure(None)
     memory.configure(None)
@@ -74,8 +73,8 @@ def _mk_index(tmp_path, n=256, seed=0, name="f4", pq=PQ4, **cfg_extra):
     rng = np.random.default_rng(seed)
     vecs = rng.integers(-8, 8, (n, DIM)).astype(np.float32)
     # exactTopK: stage-1 keeps are lax.top_k, so with budgets >= live rows
-    # the funnel is a complete scan (approx_min_k recall is the bench's
-    # domain, not an equality pin's)
+    # the funnel is a complete scan (approx_min_k recall is the
+    # benchmark's domain, not an equality pin's)
     d = {"distance": "l2-squared", "exactTopK": True, **cfg_extra}
     if pq is not None:
         d["pq"] = pq
@@ -98,27 +97,24 @@ def _brute(vecs, q, k):
 # -- 1. funnel == exact when the budgets cover the set ------------------------
 
 
-def test_funnel_matches_exact_fused_legacy_sync_async(tmp_path):
+@pytest.mark.parametrize("lane", ["sync", "async"])
+def test_funnel_matches_exact_fused_legacy_sync_async(tmp_path, lane):
     idx, vecs = _mk_index(tmp_path)
     q = (vecs[:12] + 0.25).astype(np.float32)
-    lanes = {}
-    for fused in (True, False):
-        tpu.set_fused_enabled(fused)
-        lanes[("sync", fused)] = idx.search_by_vectors(q, 5)
-        lanes[("async", fused)] = idx.search_by_vectors_async(q, 5)()
-    want_ids, want_d = zip(*(_brute(vecs, q[i], 5) for i in range(len(q))))
-    for (lane, fused), (ids, dists) in lanes.items():
-        for i in range(len(q)):
-            np.testing.assert_allclose(
-                dists[i], want_d[i], rtol=0, atol=1e-4,
-                err_msg=f"{lane} fused={fused} q{i}")
-            assert {int(x) for x in ids[i]} == {int(x) for x in want_ids[i]}, \
-                (lane, fused, i)
-    # every lane bit-agrees with every other (same program, same snapshot)
-    ref_ids, ref_d = lanes[("sync", True)]
-    for key, (ids, dists) in lanes.items():
-        np.testing.assert_array_equal(ids, ref_ids, err_msg=str(key))
-        np.testing.assert_array_equal(dists, ref_d, err_msg=str(key))
+    if lane == "sync":
+        ids, dists = idx.search_by_vectors(q, 5)
+    else:
+        ids, dists = idx.search_by_vectors_async(q, 5)()
+    for i in range(len(q)):
+        want_ids, want_d = _brute(vecs, q[i], 5)
+        np.testing.assert_allclose(dists[i], want_d, rtol=0, atol=1e-4,
+                                   err_msg=f"{lane} q{i}")
+        assert {int(x) for x in ids[i]} == {int(x) for x in want_ids}, \
+            (lane, i)
+    # the lanes bit-agree (same program, same snapshot)
+    ref_ids, ref_d = idx.search_by_vectors(q, 5)
+    np.testing.assert_array_equal(ids, ref_ids)
+    np.testing.assert_array_equal(dists, ref_d)
 
 
 def test_funnel_dispatches_on_the_pq_adc4_tier(tmp_path):
@@ -157,13 +153,11 @@ def test_funnel_composes_with_ivf_probe(tmp_path):
     idx, vecs = _mk_index(tmp_path, name="ivf4")
     assert idx._ivf_centroids is not None  # trained at import
     q = (vecs[:10] + 0.25).astype(np.float32)
-    for fused in (True, False):
-        tpu.set_fused_enabled(fused)
-        ids, dists = idx.search_by_vectors(q, 5)
-        for i in range(len(q)):
-            want_ids, want_d = _brute(vecs, q[i], 5)
-            np.testing.assert_allclose(dists[i], want_d, rtol=0, atol=1e-4)
-            assert {int(x) for x in ids[i]} == {int(x) for x in want_ids}
+    ids, dists = idx.search_by_vectors(q, 5)
+    for i in range(len(q)):
+        want_ids, want_d = _brute(vecs, q[i], 5)
+        np.testing.assert_allclose(dists[i], want_d, rtol=0, atol=1e-4)
+        assert {int(x) for x in ids[i]} == {int(x) for x in want_ids}
     allow = Bitmap(np.arange(100, 200).astype(np.uint64))
     ids_f, _ = idx.search_by_vectors(q, 5, allow_list=allow)
     flat = ids_f.ravel()
@@ -174,7 +168,6 @@ def test_funnel_composes_with_ivf_probe(tmp_path):
 def test_funnel_snapshot_pins_across_recompress_and_compact(tmp_path):
     """Enqueue -> delete winners + compact (which re-encodes BOTH
     ladders) -> finalize answers from the OLD snapshot's codes4/opq."""
-    tpu.set_fused_enabled(True)
     idx, vecs = _mk_index(tmp_path)
     q = (vecs[:4] + 0.25).astype(np.float32)
     want = idx.search_by_vectors(q, 5)
@@ -245,9 +238,8 @@ def test_bits8_mode_never_touches_the_funnel(tmp_path, monkeypatch):
     def boom(*a, **k):
         raise AssertionError("funnel entry point touched in bits=8 mode")
 
-    for name in ("search_pq4_funnel", "search_pq4_funnel_fused",
-                 "search_ivf_pq4", "search_ivf_pq4_fused",
-                 "pq4_funnel_topk", "plan_funnel"):
+    for name in ("search_pq4_funnel_fused", "search_ivf_pq4_fused",
+                 "pq4_funnel_topk", "ivf_pq4_topk", "plan_funnel"):
         monkeypatch.setattr(pq4_ops, name, boom)
     pq8 = {"enabled": True, "segments": 4, "centroids": 32, "rescore": True}
     idx, vecs = _mk_index(tmp_path, pq=pq8, name="no4")

@@ -1,7 +1,7 @@
 """Fused PQ-ADC group-min kernel (ops/pq_gmin.py) vs the legacy
 reconstruction scan and exact-ADC numpy ground truth — interpret mode on
 the CPU mesh (the compiled Mosaic path is exercised on real TPU by
-bench.py, same contract as the dense kernel's tests)."""
+chip_smoke.py, same contract as the dense kernel's tests)."""
 
 import numpy as np
 import pytest
@@ -120,10 +120,6 @@ def test_pq_gmin_failure_separate_from_dense(tmp_path, monkeypatch):
     def boom(*a, **k):
         raise RuntimeError("mosaic says no")
 
-    # both entries: the fused-dispatch default routes through the _fused
-    # twin, the legacy toggle through the plain one — either failing must
-    # break only the PQ domain
-    monkeypatch.setattr(pq_gmin, "search_pq_gmin", boom)
     monkeypatch.setattr(pq_gmin, "search_pq_gmin_fused", boom)
     q = vecs[:16]
     ids, _ = idx.search_by_vectors(q, 3)  # falls back, still answers

@@ -7,8 +7,8 @@ The acceptance-critical invariants pinned here:
   1. GROUND-TRUTH AGREEMENT — on tie-free integer data the audited live
      answer matches the exact host plane bit-for-bit, so every audit
      scores recall 1.0 / RBO 1.0 / relerr 0.0 across the exact, PQ, and
-     gather tiers (the bench's online_recall-vs-bench-recall agreement,
-     in miniature and deterministic).
+     gather tiers (online recall against exact ground truth, in
+     miniature and deterministic).
   2. SNAPSHOT PINNING — an audit that runs AFTER deletes published a new
      generation still compares against the generation the live dispatch
      read; the same audit against the CURRENT state would score < 1.

@@ -245,6 +245,7 @@ def test_scan_reads_only_live_chunks_of_a_part_full_slab(metric, batch, fused):
     n not a multiple of the chunk) the answers are exact float32 brute
     force's over the live, allowed, untombstoned rows, although every dead
     row would win if it were read (a copy of a query, or NaN)."""
+    import jax
     import jax.numpy as jnp
 
     from weaviate_tpu.index import tpu
@@ -298,7 +299,10 @@ def test_scan_reads_only_live_chunks_of_a_part_full_slab(metric, batch, fused):
             tpu._search_full_fused(*args, jnp.asarray(s2d), **statics)))
         want = slots[want_ids]
     else:
-        dists, ids = unpack_topk(np.asarray(tpu._search_full(*args, **statics)))
+        # the body the one-chip program and (ROADMAP Queue 1 item 2) the
+        # mesh share, jitted here: it returns slots
+        scan = jax.jit(tpu._scan_full, static_argnames=tpu._SCAN_STATICS)
+        dists, ids = unpack_topk(np.asarray(scan(*args, **statics)))
         want = want_ids
     np.testing.assert_array_equal(ids, want)
     # the benchmark's rule for a returned distance (benchmarks/lib/check.py)

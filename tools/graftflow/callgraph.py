@@ -245,7 +245,7 @@ class Program:
                     out.append(hit)
             return out
         if bd is not None:
-            # module-alias path: tpu._score_rows(...), gmin_scan.gmin_topk
+            # module-alias path: tpu._score_rows_topk(...), gmin_scan.gmin_topk
             tgt = self._module_of_dotted(f"{bd}.{meth}", mod)
             if tgt is not None:
                 tm, sym = tgt

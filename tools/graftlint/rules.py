@@ -102,7 +102,6 @@ HOT_PREFIXES = (
 # (path, qualname) pairs whose JOB is crossing the device->host boundary:
 # JGL001 stays silent inside them. Keep this list tiny and obvious.
 JGL001_BOUNDARY = {
-    ("weaviate_tpu/index/tpu.py", "_unpack"),
     ("weaviate_tpu/ops/topk.py", "unpack_topk"),
     ("weaviate_tpu/ops/bm25_scan.py", "unpack_topk"),
 }

@@ -420,7 +420,7 @@ class ControllerConfig:
     brownout_cap_scale: float = 0.5    # stage 2: tenant row cap x
     brownout_retry_scale: float = 2.0  # stage 2: Retry-After hints x
     brownout_rate_scale: float = 0.5   # stage 2: rate-quota refill x
-    # recall-guarded budget: the EWMA floor the bench/acceptance pins,
+    # recall-guarded budget: the EWMA floor the acceptance tests pin,
     # the slack that must exist before a cut, the margin that forces an
     # immediate back-off, and the per-tier sample count before acting
     recall_floor: float = 0.98
@@ -551,12 +551,6 @@ class Config:
     # TPU extensions
     device_mesh_shards: int = 0  # 0 = one shard per local device
     store_dtype: str = "float32"
-    # fully fused device dispatch (index/tpu.py): final top-k ->
-    # tombstone/allowList masking -> slot->doc translation run in ONE XLA
-    # program, so a search's single packed fetch carries final doc ids
-    # and finalize() does zero host translation. Off = the legacy host
-    # slot_to_doc path (the bench's --fused A/B lever)
-    fused_dispatch_enabled: bool = True
     ivf: IvfConfig = field(default_factory=IvfConfig)
     coalescer: CoalescerConfig = field(default_factory=CoalescerConfig)
     tracing: TracingConfig = field(default_factory=TracingConfig)
@@ -740,8 +734,7 @@ class Config:
 def ivf_from_env(env: Optional[Mapping[str, str]] = None) -> IvfConfig:
     """Parse the IVF knob surface. Shared by load_config AND the index
     layer's bare-library fallback (index/tpu.py ivf_settings) — one knob
-    must never read differently with vs without an App (the
-    FUSED_DISPATCH_ENABLED discipline)."""
+    must never read differently with vs without an App."""
     e = dict(os.environ) if env is None else env
     return IvfConfig(
         enabled=_bool(e, "IVF_ENABLED"),
@@ -829,7 +822,6 @@ def load_config(env: Optional[Mapping[str, str]] = None) -> Config:
 
     cfg.device_mesh_shards = _int(e, "TPU_DEVICE_MESH_SHARDS", 0)
     cfg.store_dtype = e.get("TPU_STORE_DTYPE", "float32")
-    cfg.fused_dispatch_enabled = _bool(e, "FUSED_DISPATCH_ENABLED", True)
 
     cfg.ivf = ivf_from_env(e)
 

@@ -5,7 +5,7 @@ One module so that no caller decides any of the three for itself: the
 start-up line, ``/v1/meta``, the cost model's peaks table and
 ``chip_smoke.py`` all read ``identity()``; every ``pallas_call`` site takes
 its ``interpret`` flag from ``pallas_interpret()``; every entry point
-(``python -m weaviate_tpu``, ``bench.py``, ``__graft_entry__``) places the
+(``python -m weaviate_tpu``, ``__graft_entry__``) places the
 compile cache with ``enable_compile_cache()`` before first backend use.
 
 Importing this module does not import jax; calling ``identity()`` or

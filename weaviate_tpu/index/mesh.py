@@ -10,7 +10,7 @@ weaviate_tpu/parallel/mesh_search.py):
   loop);
 - search: chunked masked scan per slab + local top-k, cross-chip merge over
   ICI (all_gather + reselect) inside the same jit, then on-device slot→doc
-  translation against the sharded pair table — the fused dispatch returns
+  translation against the sharded pair table — the program returns
   the packed [B, 3k] buffer, so finalize is ONE fetch and dtype views
   (the single-chip one-fetch/zero-translation invariant, now across chips);
 - delete: tombstone scatter where each chip claims the global rows in its
@@ -75,7 +75,6 @@ from weaviate_tpu.index.tpu import (
     _bucket_rows,
     _fetch_packed,
     _snap_top_p,
-    fused_dispatch_enabled,
     ivf_settings,
 )
 # dispatch-shape recording for the perf-attribution plane: a
@@ -100,7 +99,7 @@ from weaviate_tpu.monitoring.costmodel import (
 )
 from weaviate_tpu.monitoring.metrics import record_device_fallback
 from weaviate_tpu.ops import ivf as ivf_ops
-from weaviate_tpu.ops.topk import unpack_fused, unpack_topk
+from weaviate_tpu.ops.topk import unpack_fused
 # the recall-guarded probe-depth cap shares the single-chip controller;
 # controller imports nothing from the index layer, so no cycle
 from weaviate_tpu.serving import controller
@@ -1353,7 +1352,6 @@ class MeshVectorIndex(VectorIndex):
             kk = max(1, min(k, snap.live, chunk))
             use_allow = allow_list is not None
             words = self._allow_words(snap, allow_list) if use_allow else snap.zero_words
-            fused = fused_dispatch_enabled()
             exact = getattr(self.config, "exact_topk", False)
 
             if snap.compressed:
@@ -1390,7 +1388,6 @@ class MeshVectorIndex(VectorIndex):
                             rg4,
                             rc,
                             exact,
-                            fused,
                             self.mesh,
                         )
                         funnel_budgets = (rg4, rc)
@@ -1398,7 +1395,7 @@ class MeshVectorIndex(VectorIndex):
                     # codes-only tier: try the fused per-shard ADC kernel
                     # (mesh twin of the single-chip pq_gmin dispatch)
                     packed_dev = self._pq_gmin_step_or_none(
-                        snap, q, kk, words, use_allow, fused)
+                        snap, q, kk, words, use_allow)
                 if packed_dev is None:
                     nchunks_eff = max(1, snap.n_loc // chunk)
                     pool_target = self.config.pq.rescore_limit or 1024
@@ -1423,7 +1420,6 @@ class MeshVectorIndex(VectorIndex):
                         use_allow,
                         exact,
                         rescore,
-                        fused,
                         self.mesh,
                     )
                 if t_enq0:
@@ -1472,7 +1468,6 @@ class MeshVectorIndex(VectorIndex):
                         top_p,
                         exact,
                         gp,
-                        fused,
                         self.mesh,
                     )
                     with self._ivf_lock:
@@ -1493,7 +1488,7 @@ class MeshVectorIndex(VectorIndex):
                                        min(probed / max(snap.n_total, 1), 1.0), 4)})
                 else:
                     packed_dev = self._gmin_step_or_none(
-                        snap, q, kk, words, use_allow, fused)
+                        snap, q, kk, words, use_allow)
                     if packed_dev is None:
                         packed_dev = mesh_search_step(
                             snap.store,
@@ -1508,7 +1503,7 @@ class MeshVectorIndex(VectorIndex):
                             use_allow,
                             self.metric == vi.DISTANCE_L2,
                             exact,
-                            fused,
+                            True,  # fused: the only epilogue there is
                             self.mesh,
                         )
                     if t_enq0:
@@ -1526,29 +1521,16 @@ class MeshVectorIndex(VectorIndex):
             now_ns = enqueue.end(rows=b, tier=shape.tier, ndev=shape.ndev)
             shape.t_start = t_enq0
             shape.enqueue_ms = (now_ns - enqueue.start_ns) / 1e6
-            if fused:
-                shape.fused = True
-                shape.translate_ms = 0.0
             self._read_local.dispatch_shape = shape
         if quality.get_auditor() is not None:
             self._read_local.audit_snap = snap  # graftflow: disable=JGL018 TLS pin by design: at most one snapshot per serving thread, overwritten on the next sampled dispatch — the shadow audit must re-read the SAME snapshot the live dispatch answered from
         self._track_inflight(1)
         done = [False]
-        slot_to_doc = snap.slot_to_doc
 
         def finish():
             packed = _fetch_packed(packed_dev, shape)
-            if fused:
-                ids, dists = unpack_fused(packed)
-                return ids[:b], dists[:b]
-            top, idx = unpack_topk(packed)
-            top = top[:b]
-            idx = idx[:b]
-            t0 = time.perf_counter() if shape is not None else 0.0
-            ids = np.where(idx >= 0, slot_to_doc[np.clip(idx, 0, None)], -1)
-            if shape is not None:
-                shape.translate_ms = (time.perf_counter() - t0) * 1000.0
-            return ids.astype(np.uint64), top.astype(np.float32)
+            ids, dists = unpack_fused(packed)
+            return ids[:b], dists[:b]
 
         def finalize():
             try:
@@ -1621,7 +1603,7 @@ class MeshVectorIndex(VectorIndex):
         return rg, active_g
 
     def _pq_gmin_step_or_none(self, snap: MeshSnapshot, q: np.ndarray,
-                              kk: int, words, use_allow: bool, fused: bool):
+                              kk: int, words, use_allow: bool):
         """Enqueue the fused per-shard PQ codes kernel, or None for the
         legacy reconstruction scan — separate failure domain
         (self._pqg_state); gating and codebook constants are the shared
@@ -1644,7 +1626,7 @@ class MeshVectorIndex(VectorIndex):
         interpret = device.pallas_interpret()
         cb_chunks, flat_cb = pq_gmin.cached_cb_constants(self)
         key = ("pq", q.shape[0], kk, rg, active_g, snap.n_loc, m, c,
-               use_allow, fused)
+               use_allow)
         return gmin_scan.guarded_kernel_call(
             self._pqg_state, key,
             lambda: mesh_search_pq_gmin_step(
@@ -1664,13 +1646,12 @@ class MeshVectorIndex(VectorIndex):
                 rg,
                 active_g,
                 interpret,
-                fused,
                 self.mesh,
             ),
             "mesh pq codes kernel", component="index.mesh.pq_gmin")
 
     def _gmin_step_or_none(self, snap: MeshSnapshot, q: np.ndarray, kk: int,
-                           words, use_allow: bool, fused: bool):
+                           words, use_allow: bool):
         """Enqueue the fused group-min mesh kernel, or None for the legacy
         scan. Validation mirrors tpu.py's _gmin_packed_or_none: per
         compiled shape — a Mosaic rejection on a NEW shape falls back for
@@ -1686,7 +1667,7 @@ class MeshVectorIndex(VectorIndex):
         if plan is None:
             return None
         rg, active_g = plan
-        key = (q.shape[0], kk, rg, active_g, snap.n_loc, use_allow, fused)
+        key = (q.shape[0], kk, rg, active_g, snap.n_loc, use_allow)
         interpret = device.pallas_interpret()
         return gmin_scan.guarded_kernel_call(
             self, key,
@@ -1705,7 +1686,6 @@ class MeshVectorIndex(VectorIndex):
                 rg,
                 active_g,
                 interpret,
-                fused,
                 self.mesh,
             ),
             "mesh gmin kernel", component="index.mesh.gmin")
@@ -1917,7 +1897,7 @@ class MeshVectorIndex(VectorIndex):
                 "staged_lag": self._staged_gen - max(self._published_gen, 0),
                 "per_device": per_device,
                 "compressed": self.compressed,
-                # rescore=false is the MULTICHIP_r05 footgun: raw ADC
+                # rescore=false is a footgun: raw ADC
                 # distances at recall ~0.24 — surfaced, not just documented
                 "pq": None if self._pq is None else {
                     "segments": self._pq.segments,

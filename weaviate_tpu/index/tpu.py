@@ -15,7 +15,7 @@ device do what it is good at:
 - a query batch is ONE [B, N] distance matmul on the MXU + a masked
   k-selection (ops/distances.py, ops/topk.py). Per-chunk selection defaults
   to lax.approx_min_k at recall_target=0.95 (the TPU PartialReduce /ScaNN
-  primitive; measured recall 1.0 on the bench workloads, and never below the
+  primitive; recall 0.998-0.999 in the benchmark's cells, and never below the
   target — comparable to HNSW's >=0.99 fixture bar, recall_test.go:137);
   config exactTopK=true forces lax.top_k for guaranteed recall 1.0;
 - tombstones (delete.go semantics) are a device bool mask, filters
@@ -88,14 +88,11 @@ from weaviate_tpu.serving import controller
 # harness is configured
 from weaviate_tpu.testing import faults, sanitizers
 # the one rescore-candidate bucket table (shared with the control plane's
-# recall-guarded cap — serving/controller.py R_BUCKETS aliases it), and
-# config's env-bool parser so FUSED_DISPATCH_ENABLED reads the same truth
-# table with or without an App
+# recall-guarded cap — serving/controller.py R_BUCKETS aliases it)
 from weaviate_tpu.config.config import (IVF_TOP_P_BUCKETS, IvfConfig,
                                         PQ4_FUNNEL_C_BUCKETS,
                                         PQ4_FUNNEL_RESCORE_BUCKETS,
                                         RESCORE_R_BUCKETS, ivf_from_env)
-from weaviate_tpu.config.config import _bool as _env_bool
 # the partition-pruned scan plane (ROADMAP item 3): k-means/PCA training
 # helpers on the write path, probed-bucket search kernels on the read
 # path (ops/ivf.py); every hook below is a one-comparison no-op while
@@ -115,61 +112,10 @@ _LOG_VERSION = 2  # v2 = per-record checksums + skip-ahead corrupt-region replay
 # query-batch padding buckets (limit distinct compiled shapes)
 _B_BUCKETS = (1, 4, 16, 64, 256, 1024)
 
-# -- fused-dispatch toggle ----------------------------------------------------
-# When on (the default), every search dispatch is END-TO-END device
-# resident: the final top-k, tombstone/allowList masking, and slot->doc
-# translation run in ONE XLA program against the snapshot's device
-# translation table (IndexSnapshot.slot_to_doc_dev), so the single packed
-# fetch carries final doc ids and finalize() is dtype views — zero host
-# post-processing. Off = the legacy host slot_to_doc translation (kept as
-# the bench's --fused A/B control and as a safety hatch).
-_fused_override: Optional[bool] = None
-_fused_env: Optional[bool] = None
-_fused_token: Optional[object] = None
-
-
-def set_fused_enabled(on: Optional[bool]) -> Optional[object]:
-    """Override the fused-dispatch toggle process-wide (App applies the
-    config knob here; bench/tests flip it for A/B runs). None reverts to
-    the FUSED_DISPATCH_ENABLED environment default — re-read fresh, so
-    the revert actually honors an env change made since the last parse.
-    Returns an opaque token identifying THIS override — pass it to
-    unset_fused_enabled so a torn-down App reverts only its own setting,
-    never a newer App's (the tracer/perf still-ours unconfigure
-    discipline)."""
-    global _fused_override, _fused_token, _fused_env
-    _fused_override = on
-    _fused_token = object() if on is not None else None
-    if on is None:
-        _fused_env = None  # drop the cached parse: revert means re-read
-    return _fused_token
-
-
-def unset_fused_enabled(token: Optional[object]) -> None:
-    """Revert set_fused_enabled's override iff `token` is still the
-    CURRENT one (a newer override wins); None tokens are no-ops."""
-    global _fused_override, _fused_token, _fused_env
-    if token is not None and token is _fused_token:
-        _fused_override = None
-        _fused_token = None
-        _fused_env = None  # revert means re-read the environment
-
-
-def fused_dispatch_enabled() -> bool:
-    global _fused_env
-    if _fused_override is not None:
-        return _fused_override
-    if _fused_env is None:
-        # the SAME parser Config uses: one knob must never read
-        # differently in library use vs under an App
-        _fused_env = _env_bool(os.environ, "FUSED_DISPATCH_ENABLED", True)
-    return _fused_env
-
-
 # -- IVF scan-plane toggle ----------------------------------------------------
-# Same process-wide override/env-fallback shape as the fused-dispatch
-# toggle above: App applies Config.ivf here at init (token-scoped so a
-# torn-down App reverts only its own setting); bare-library indexes read
+# A process-wide override with an environment fallback: App applies
+# Config.ivf here at init (token-scoped so a torn-down App reverts only
+# its own setting); bare-library indexes read
 # the IVF_* environment through config's own parser, so one knob can
 # never read differently with vs without an App. Disabled (the default)
 # => ivf_settings() is None and every IVF hook — write-path training,
@@ -334,11 +280,6 @@ def _pack(top: jax.Array, idx: jax.Array) -> jax.Array:
     return jnp.concatenate([jax.lax.bitcast_convert_type(top, jnp.int32), idx], axis=1)
 
 
-def _unpack(packed: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    k = packed.shape[1] // 2
-    return packed[:, :k].view(np.float32), packed[:, k:]
-
-
 def _fetch_packed(packed_dev, shape=None) -> np.ndarray:
     """The ONE blocking device->host fetch of a dispatch's finalize. With a
     perf shape attached (tracer up), the blocked time is the `device_wait`
@@ -379,7 +320,7 @@ class _ScanProgram:
     """One full-store scan body as the top-level programs the index runs.
 
     `compiler_options` is accepted on a top-level jax.jit only, and the CPU
-    compiler refuses the TPU's option names, so the body is jitted twice and
+    compiler refuses the TPU's option names, so the program is jitted twice and
     the platform of the device that holds the slab (the first argument: a
     jax.Array, or a ShapeDtypeStruct with a sharding when a test compiles
     for a described chip) picks. Not jax.default_backend(): a CPU process
@@ -507,31 +448,18 @@ def _scan_full(
     return _pack(top, idx)
 
 
-def _search_full(
-    store, sq_norms, tombs, n, q, allow_words, k, metric, use_allow, exact=False,
-    active_chunks=None, rescore_r=0,
-):
-    """_scan_full as a top-level program (the packed fetch carries slots)."""
-    return _scan_full(store, sq_norms, tombs, n, q, allow_words, k, metric,
-                      use_allow, exact, active_chunks, rescore_r)
-
-
 def _search_full_fused(
     store, sq_norms, tombs, n, q, allow_words, s2d, k, metric, use_allow,
     exact=False, active_chunks=None, rescore_r=0,
 ):
-    """_scan_full with the slot->doc translation fused into the SAME XLA
-    program: the one packed fetch carries final doc ids (ops/topk FUSED
-    layout)."""
+    """_scan_full as the top-level program: the slot->doc translation
+    runs in the SAME XLA program, so the one packed fetch carries final doc
+    ids (ops/topk FUSED layout)."""
     packed = _scan_full(store, sq_norms, tombs, n, q, allow_words, k,
                         metric, use_allow, exact, active_chunks, rescore_r)
     return retranslate_packed(packed, s2d)
 
 
-_search_full = _ScanProgram(
-    jax.jit(_search_full, static_argnames=_SCAN_STATICS),
-    jax.jit(_search_full, static_argnames=_SCAN_STATICS,
-            compiler_options=_TPU_SCAN_OPTIONS))
 _search_full_fused = _ScanProgram(
     jax.jit(_search_full_fused, static_argnames=_SCAN_STATICS),
     jax.jit(_search_full_fused, static_argnames=_SCAN_STATICS,
@@ -543,17 +471,11 @@ _search_full_fused = _ScanProgram(
 _PQ_SCAN_CHUNK = 32768
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "k", "r_chunk", "metric", "use_allow", "exact", "active_chunks",
-        "do_rescore",
-    ),
-)
-def _search_pq_recon(codes, recon_norms, tombs, n, codebook, rescore_store, q,
-                     allow_words, k, r_chunk, metric, use_allow, exact=False,
-                     active_chunks=None, do_rescore=True, rot=None):
-    """PQ scan the MXU way: asymmetric ADC distance equals the distance to
+def _pq_recon_topk(codes, recon_norms, tombs, n, codebook, rescore_store, q,
+                   allow_words, k, r_chunk, metric, use_allow, exact=False,
+                   active_chunks=None, do_rescore=True, rot=None):
+    """PQ scan the MXU way -> ([B, k] dists, [B, k] slots, -1 = missing):
+    asymmetric ADC distance equals the distance to
     the RECONSTRUCTED row (segments are disjoint dims), so each chunk's
     codes gather their centroids into a [chunk, D] block that feeds one
     bf16 matmul — identical math to the LUT scan
@@ -638,7 +560,7 @@ def _search_pq_recon(codes, recon_norms, tombs, n, codebook, rescore_store, q,
     top = -neg
     final = jnp.take_along_axis(cand_i, pos, axis=1)
     final = jnp.where(jnp.isinf(top), -1, final).astype(jnp.int32)
-    return _pack(top, final)
+    return top, final
 
 
 @functools.partial(
@@ -652,23 +574,22 @@ def _search_pq_recon_fused(codes, recon_norms, tombs, n, codebook,
                            rescore_store, q, allow_words, s2d, k, r_chunk,
                            metric, use_allow, exact=False, active_chunks=None,
                            do_rescore=True, rot=None):
-    """_search_pq_recon with device-side slot->doc translation fused in."""
-    packed = _search_pq_recon(codes, recon_norms, tombs, n, codebook,
+    """_pq_recon_topk as a top-level program, its winners translated to doc
+    ids in the same program."""
+    top, idx = _pq_recon_topk(codes, recon_norms, tombs, n, codebook,
                               rescore_store, q, allow_words, k, r_chunk,
                               metric, use_allow, exact, active_chunks,
                               do_rescore, rot)
-    return retranslate_packed(packed, s2d)
+    return translate_pack(top, idx, s2d)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("r", "use_allow", "exact", "active_chunks")
-)
-def _search_pq(codes, tombs, n, lut, allow_words, r, use_allow, exact=False,
-               active_chunks=None):
-    """PQ twin of _search_full: scan the [cap, M] code matrix in HBM chunks,
+def _pq_lut_topk(codes, tombs, n, lut, allow_words, r, use_allow, exact=False,
+                 active_chunks=None):
+    """PQ twin of _scan_full: scan the [cap, M] code matrix in HBM chunks,
     score each chunk via the additive LUT gather (compress/pq.py
     lut_scan_block — product_quantization.go:56-75 LookUp, vectorized),
-    exact cross-chunk merge of the top-r candidate slots."""
+    exact cross-chunk merge of the top-r candidate slots
+    -> ([B, r] dists, [B, r] slots, -1 = missing)."""
     from weaviate_tpu.compress.pq import lut_scan_block
 
     cap, m = codes.shape
@@ -706,7 +627,7 @@ def _search_pq(codes, tombs, n, lut, allow_words, r, use_allow, exact=False,
         xs.append(allow_c)
     (top, idx), _ = jax.lax.scan(step, init, tuple(xs))
     idx = jnp.where(jnp.isinf(top), -1, idx).astype(jnp.int32)
-    return _pack(top, idx)
+    return top, idx
 
 
 @functools.partial(
@@ -714,10 +635,11 @@ def _search_pq(codes, tombs, n, lut, allow_words, r, use_allow, exact=False,
 )
 def _search_pq_fused(codes, tombs, n, lut, allow_words, s2d, r, use_allow,
                      exact=False, active_chunks=None):
-    """_search_pq (LUT tier) with device-side slot->doc translation."""
-    packed = _search_pq(codes, tombs, n, lut, allow_words, r, use_allow,
-                        exact, active_chunks)
-    return retranslate_packed(packed, s2d)
+    """_pq_lut_topk as a top-level program, its winners translated to doc
+    ids in the same program."""
+    top, idx = _pq_lut_topk(codes, tombs, n, lut, allow_words, r, use_allow,
+                            exact, active_chunks)
+    return translate_pack(top, idx, s2d)
 
 
 def _gather_live(rows, row_valid, tombs):
@@ -733,60 +655,49 @@ def _gather_live(rows, row_valid, tombs):
                            jnp.logical_not(jnp.take(tombs, safe)))
 
 
-@functools.partial(jax.jit, static_argnames=("k", "metric"))
-def _score_rows(sub, q, rows, row_valid, tombs, k, metric):
-    """Score an uploaded [R, D] row block against [B, D] queries (the gather
-    path when the float store lives host-side under PQ). rows [R] carries
-    each block position's store slot for the device tombstone mask."""
-    dists = DISTANCE_FNS[metric](q.astype(sub.dtype), sub, None)
+def _gather_topk(dists, rows, row_valid, tombs, k):
+    """Gather-tier selection: mask the [B, R] block, take the top k, and map
+    the winners' POSITIONS in the uploaded `rows` block back to store slots
+    on device -> ([B, k] dists, [B, k] slots, -1 = missing)."""
     masked = jnp.where(_gather_live(rows, row_valid, tombs)[None, :],
                        dists, jnp.inf)
     neg, idx = jax.lax.top_k(-masked, k)
     top = -neg
-    return _pack(top, jnp.where(jnp.isinf(top), -1, idx).astype(jnp.int32))
+    safe = jnp.clip(idx, 0, rows.shape[0] - 1)
+    slots = jnp.where(jnp.isinf(top), -1, jnp.take(rows, safe))
+    return top, slots.astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "metric"))
-def _search_gathered(store, q, rows, row_valid, tombs, k, metric):
+def _score_rows_topk(sub, q, rows, row_valid, tombs, k, metric):
+    """Score an uploaded [R, D] row block against [B, D] queries (the gather
+    path when the float store lives host-side under PQ). rows [R] carries
+    each block position's store slot for the device tombstone mask."""
+    dists = DISTANCE_FNS[metric](q.astype(sub.dtype), sub, None)
+    return _gather_topk(dists, rows, row_valid, tombs, k)
+
+
+def _gathered_topk(store, q, rows, row_valid, tombs, k, metric):
     """Gather path for small allowLists (flat_search.go:19 analog): score only
     the gathered rows. rows [R] int32 (padded), row_valid [R] bool; the
     snapshot's tombs mask rides the same program (see _gather_live)."""
     sub = jnp.take(store, rows, axis=0, mode="fill", fill_value=0)
     dists = DISTANCE_FNS[metric](q.astype(store.dtype), sub, None)
-    masked = jnp.where(_gather_live(rows, row_valid, tombs)[None, :],
-                       dists, jnp.inf)
-    kk = min(k, sub.shape[0])
-    neg, idx = jax.lax.top_k(-masked, kk)
-    top = -neg
-    return _pack(top, jnp.where(jnp.isinf(top), -1, idx).astype(jnp.int32))
-
-
-def _rows_to_slots(packed, rows):
-    """Gather-tier epilogue: the kernel's idx are POSITIONS into the
-    uploaded `rows` block — map them back to store slots on device so the
-    shared translate_pack can emit final doc ids."""
-    kc = packed.shape[1] // 2
-    top = jax.lax.bitcast_convert_type(packed[:, :kc], jnp.float32)
-    idx = packed[:, kc:]
-    safe = jnp.clip(idx, 0, rows.shape[0] - 1)
-    slots = jnp.where(idx >= 0, jnp.take(rows, safe), -1)
-    return top, slots
+    return _gather_topk(dists, rows, row_valid, tombs, min(k, sub.shape[0]))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
 def _score_rows_fused(sub, q, rows, row_valid, tombs, s2d, k, metric):
-    """_score_rows with slot->doc translation fused in (rows carries each
-    uploaded block position's store slot)."""
-    top, slots = _rows_to_slots(
-        _score_rows(sub, q, rows, row_valid, tombs, k, metric), rows)
+    """_score_rows_topk as a top-level program, its winners translated to
+    doc ids in the same program."""
+    top, slots = _score_rows_topk(sub, q, rows, row_valid, tombs, k, metric)
     return translate_pack(top, slots, s2d)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
 def _search_gathered_fused(store, q, rows, row_valid, tombs, s2d, k, metric):
-    """_search_gathered with slot->doc translation fused in."""
-    top, slots = _rows_to_slots(
-        _search_gathered(store, q, rows, row_valid, tombs, k, metric), rows)
+    """_gathered_topk as a top-level program, its winners translated to doc
+    ids in the same program."""
+    top, slots = _gathered_topk(store, q, rows, row_valid, tombs, k, metric)
     return translate_pack(top, slots, s2d)
 
 
@@ -2602,7 +2513,7 @@ class TpuVectorIndex(VectorIndex):
         return blk
 
     def _search_full_gmin(self, snap: IndexSnapshot, q: np.ndarray, kk: int,
-                          allow_words, store=None, sq_norms=None, s2d=None):
+                          allow_words, store=None, sq_norms=None):
         from weaviate_tpu.ops import gmin_scan
 
         interpret = device.pallas_interpret()
@@ -2626,13 +2537,11 @@ class TpuVectorIndex(VectorIndex):
             interpret,
             self._gen_blocks(s, gmin_scan.build_rescore_blocks),
         )
-        if s2d is not None:
-            return gmin_scan.search_gmin_fused(*args, s2d, *statics)
-        return gmin_scan.search_gmin(*args, *statics)
+        return gmin_scan.search_gmin_fused(*args, snap.slot_to_doc_dev,
+                                           *statics)
 
     def _gmin_packed_or_none(self, snap: IndexSnapshot, q: np.ndarray,
-                             kk: int, allow_words, store=None, sq_norms=None,
-                             s2d=None):
+                             kk: int, allow_words, store=None, sq_norms=None):
         """Run the fused scan, or None to use the legacy kernel. Validation
         is per compiled shape: each distinct (b, k, rg, active_g, use_allow)
         is a separate Mosaic compilation with its own VMEM footprint
@@ -2653,18 +2562,16 @@ class TpuVectorIndex(VectorIndex):
             return None
         # capacity is part of the key: the compilation is parameterized by
         # the [capacity, D] store, so growth invalidates prior validation
-        # (and fused translation is its own program — its own validation)
         key = (q.shape[0], kk, self._gmin_rg(kk, snap.capacity), active_g,
-               snap.capacity, allow_words is not None, store is not None,
-               s2d is not None)
+               snap.capacity, allow_words is not None, store is not None)
         return gmin_scan.guarded_kernel_call(
             self, key,
             lambda: self._search_full_gmin(snap, q, kk, allow_words, store,
-                                           sq_norms, s2d),
+                                           sq_norms),
             "fused gmin kernel", component="index.tpu.gmin")
 
     def _pq_gmin_packed_or_none(self, snap: IndexSnapshot, q: np.ndarray,
-                                b: int, k: int, allow_list, s2d=None):
+                                b: int, k: int, allow_list):
         """Run the fused PQ codes kernel, or None for the legacy recon
         scan. Same per-shape validation contract as the dense kernel, on a
         SEPARATE failure domain (self._pqg_state); gating and codebook
@@ -2686,8 +2593,7 @@ class TpuVectorIndex(VectorIndex):
         words = (self._allow_words(snap, allow_list) if use_allow
                  else jnp.zeros((snap.capacity // 32,), jnp.uint32))
         cb_chunks, flat_cb = pq_gmin.cached_cb_constants(self, snap.pq)
-        key = (q.shape[0], kk, rg, active_g, snap.capacity, m, c, use_allow,
-               s2d is not None)
+        key = (q.shape[0], kk, rg, active_g, snap.capacity, m, c, use_allow)
 
         def thunk():
             args = (snap.codes, snap.recon_norms, snap.tombs, snap.n,
@@ -2696,9 +2602,8 @@ class TpuVectorIndex(VectorIndex):
                        snap.pq.rotation_dev(),
                        self._gen_blocks(snap.codes,
                                         pq_gmin.build_codes_blocks))
-            if s2d is not None:
-                return pq_gmin.search_pq_gmin_fused(*args, s2d, *statics)
-            return pq_gmin.search_pq_gmin(*args, *statics)
+            return pq_gmin.search_pq_gmin_fused(
+                *args, snap.slot_to_doc_dev, *statics)
 
         return gmin_scan.guarded_kernel_call(
             self._pqg_state, key, thunk,
@@ -2729,7 +2634,7 @@ class TpuVectorIndex(VectorIndex):
         return pq4_ops.plan_funnel(k, n, c_cap, rc_cap)
 
     def _pq4_funnel_packed_or_none(self, snap: IndexSnapshot, q: np.ndarray,
-                                   b: int, k: int, allow_list, s2d=None):
+                                   b: int, k: int, allow_list):
         """Run the three-stage 4-bit funnel (ops/pq4.py), or None for the
         8-bit fallback paths. Its own failure domain (self._pq4_state) and
         per-shape validation, like the other fused kernels — but unlike
@@ -2769,7 +2674,7 @@ class TpuVectorIndex(VectorIndex):
         _cb8_chunks, flat_cb8 = pq_gmin.cached_cb_constants(self, snap.pq)
         codes8_blk = self._gen_blocks(snap.codes, pq_gmin.build_codes_blocks)
         key = (bq, kk, rg4, rc, active_g, snap.capacity, mb, use_allow,
-               use_pallas, s2d is not None)
+               use_pallas)
 
         def thunk():
             args = (snap.codes4, snap.codes, snap.recon_norms4,
@@ -2780,9 +2685,8 @@ class TpuVectorIndex(VectorIndex):
                            use_pallas=use_pallas, interpret=interpret,
                            exact=exact, rot=snap.opq_rot,
                            codes8_blk=codes8_blk)
-            if s2d is not None:
-                return pq4_ops.search_pq4_funnel_fused(*args, s2d, **statics)
-            return pq4_ops.search_pq4_funnel(*args, **statics)
+            return pq4_ops.search_pq4_funnel_fused(
+                *args, snap.slot_to_doc_dev, **statics)
 
         packed = gmin_scan.guarded_kernel_call(
             self._pq4_state, key, thunk,
@@ -2964,11 +2868,6 @@ class TpuVectorIndex(VectorIndex):
             q, b = self._prep_queries_staged(vectors)
             stage_buf = q  # returned to the pool by the finalize wrapper
             k_eff = min(k, snap.live)
-            # fused dispatch: the device translation table rides the snapshot,
-            # so the program's final top-k emits doc ids directly (the legacy
-            # host slot_to_doc translation only runs with the toggle off)
-            s2d = (snap.slot_to_doc_dev
-                   if fused_dispatch_enabled() else None)
             if allow_list is not None and len(allow_list) < self.config.flat_search_cutoff:
                 if t_enq0:
                     shape = costmodel.DispatchShape(
@@ -2977,7 +2876,7 @@ class TpuVectorIndex(VectorIndex):
                         batch=b, batch_padded=q.shape[0],
                         bytes_per_row=snap.dim * 4, k=int(k_eff))
                 fin = self._dispatch_small_allow(snap, q, b, k_eff, allow_list,
-                                                 shape, s2d)
+                                                 shape)
             elif (ivf_plan := self._ivf_plan(snap, k_eff)) is not None:
                 # partition-pruned path (ROADMAP item 3): scan only the
                 # probed buckets; large allowLists compose via the same
@@ -2986,7 +2885,7 @@ class TpuVectorIndex(VectorIndex):
                     shape = self._ivf_shape(snap, ivf_plan, b, q.shape[0],
                                             k_eff)
                 fin = self._dispatch_ivf(snap, q, b, k_eff, allow_list,
-                                         ivf_plan, shape, s2d)
+                                         ivf_plan, shape)
             elif snap.compressed:
                 if t_enq0:
                     rescore = (self.config.pq.rescore
@@ -3026,7 +2925,7 @@ class TpuVectorIndex(VectorIndex):
                                            else snap.pq.segments),
                             k=int(k_eff))
                 fin = self._dispatch_full_pq(snap, q, b, k_eff, allow_list,
-                                             shape, s2d)
+                                             shape)
             else:
                 if t_enq0:
                     shape = costmodel.DispatchShape(
@@ -3037,7 +2936,7 @@ class TpuVectorIndex(VectorIndex):
                 allow_words = (self._allow_words(snap, allow_list)
                                if allow_list is not None else None)
                 fin = self._dispatch_scan(snap, q, b, k_eff, allow_words,
-                                          shape=shape, s2d=s2d)
+                                          shape=shape)
         except BaseException:
             if enqueue is not None:  # a dispatch that failed being built
                 enqueue.end()
@@ -3046,12 +2945,6 @@ class TpuVectorIndex(VectorIndex):
             now_ns = enqueue.end(rows=b, tier=shape.tier)
             shape.t_start = t_enq0
             shape.enqueue_ms = (now_ns - enqueue.start_ns) / 1e6
-            if s2d is not None:
-                # the fused-dispatch ledger invariant: one blocking fetch,
-                # zero host-translation time (test-pinned; the perf window
-                # counts violations)
-                shape.fused = True
-                shape.translate_ms = 0.0
             self._read_local.dispatch_shape = shape
         # shadow-audit snapshot pin (monitoring/quality.py): record which
         # snapshot THIS dispatch read so a sampled audit re-executes
@@ -3226,11 +3119,10 @@ class TpuVectorIndex(VectorIndex):
                        min(probed / max(snap.n, 1), 1.0), 4)})
 
     def _dispatch_ivf(self, snap: IndexSnapshot, q: np.ndarray, b: int,
-                      k: int, allow_list, plan: tuple[int, int],
-                      shape=None, s2d=None):
+                      k: int, allow_list, plan: tuple[int, int], shape):
         """Partition-pruned search: probe the centroids, score only the
-        probed buckets (ops/ivf.py), finish through the SAME packed /
-        fused-translate epilogue as every flat tier. Covers the exact,
+        probed buckets (ops/ivf.py), finish through the SAME translate
+        epilogue as every flat tier. Covers the exact,
         PQ-rescore, and PQ-codes tiers; tombstones and allowLists mask
         with identical semantics to the flat kernels (the snapshot's own
         device tombs, the same packed filter words)."""
@@ -3282,11 +3174,8 @@ class TpuVectorIndex(VectorIndex):
                          jnp.asarray(q), words, snap.pq4._dev_codebook(),
                          snap.pq._dev_codebook(), snap.ivf_centroids,
                          snap.ivf_buckets, snap.opq_rot, snap.rescore_dev)
-                if s2d is not None:
-                    packed_dev = pq4_ops.search_ivf_pq4_fused(
-                        *args4, s2d, *statics4)
-                else:
-                    packed_dev = pq4_ops.search_ivf_pq4(*args4, *statics4)
+                packed_dev = pq4_ops.search_ivf_pq4_fused(
+                    *args4, snap.slot_to_doc_dev, *statics4)
                 with self._ivf_lock:
                     st = self._ivf_stats
                     st["dispatches"] += 1
@@ -3298,24 +3187,7 @@ class TpuVectorIndex(VectorIndex):
                     st["stage1_rows"] += r_cand
                     st["stage2_survivors"] += min(c1, r_cand)
                     st["stage3_survivors"] += min(rc, r_cand)
-                if s2d is not None:
-                    return self._finalize_fused(packed_dev, shape, b)
-                slot_to_doc = snap.slot_to_doc
-
-                def finalize4():
-                    packed = _fetch_packed(packed_dev, shape)
-                    top, idx = _unpack(packed)
-                    top = top[:b]
-                    idx = idx[:b]
-                    t0 = time.perf_counter() if shape is not None else 0.0
-                    ids = np.where(idx >= 0,
-                                   slot_to_doc[np.clip(idx, 0, None)], -1)
-                    if shape is not None:
-                        shape.translate_ms = \
-                            (time.perf_counter() - t0) * 1000.0
-                    return ids.astype(np.uint64), top.astype(np.float32)
-
-                return finalize4
+                return self._finalize_fused(packed_dev, shape, b)
             if shape is not None and shape.tier == costmodel.TIER_PQ_ADC4:
                 # budgets can't cover this k over the probed set: the
                 # 8-bit IVF tier serves — re-label (no phantom traffic)
@@ -3330,22 +3202,16 @@ class TpuVectorIndex(VectorIndex):
             args = (store, snap.tombs, snap.n, jnp.asarray(q), words,
                     snap.ivf_centroids, snap.ivf_buckets,
                     snap.ivf_pca_proj, snap.ivf_pca_rows)
-            if s2d is not None:
-                packed_dev = ivf_ops.search_ivf_dense_fused(
-                    *args, s2d, *statics)
-            else:
-                packed_dev = ivf_ops.search_ivf_dense(*args, *statics)
+            packed_dev = ivf_ops.search_ivf_dense_fused(
+                *args, snap.slot_to_doc_dev, *statics)
         else:
             args = (snap.codes, snap.recon_norms, snap.tombs, snap.n,
                     jnp.asarray(q), words, snap.pq._dev_codebook(),
                     snap.ivf_centroids, snap.ivf_buckets,
                     snap.ivf_pca_proj, snap.ivf_pca_rows,
                     snap.pq.rotation_dev())
-            if s2d is not None:
-                packed_dev = ivf_ops.search_ivf_codes_fused(
-                    *args, s2d, *statics)
-            else:
-                packed_dev = ivf_ops.search_ivf_codes(*args, *statics)
+            packed_dev = ivf_ops.search_ivf_codes_fused(
+                *args, snap.slot_to_doc_dev, *statics)
         # probe accounting (health / bench probed_fraction): a leaf lock,
         # three integer adds — nothing nests inside it
         with self._ivf_lock:
@@ -3353,38 +3219,20 @@ class TpuVectorIndex(VectorIndex):
             st["dispatches"] += 1
             st["probed_rows"] += top_p * cap_p
             st["base_rows"] += int(snap.n)
-        if s2d is not None:
-            return self._finalize_fused(packed_dev, shape, b)
-        slot_to_doc = snap.slot_to_doc
-
-        def finalize():
-            # the ONE blocking fetch of the legacy (non-fused) IVF
-            # dispatch, outside any lock
-            packed = _fetch_packed(packed_dev, shape)
-            top, idx = _unpack(packed)
-            top = top[:b]
-            idx = idx[:b]
-            t0 = time.perf_counter() if shape is not None else 0.0
-            ids = np.where(idx >= 0, slot_to_doc[np.clip(idx, 0, None)], -1)
-            if shape is not None:
-                shape.translate_ms = (time.perf_counter() - t0) * 1000.0
-            return ids.astype(np.uint64), top.astype(np.float32)
-
-        return finalize
+        return self._finalize_fused(packed_dev, shape, b)
 
     def _dispatch_scan(self, snap: IndexSnapshot, q: np.ndarray, b: int,
                        k_eff: int, allow_words, store=None, sq_norms=None,
-                       shape=None, s2d=None):
+                       shape=None):
         """Full-store scan (fused gmin when eligible, legacy lax.scan kernel
         otherwise) over `store` — the f32 store uncompressed, or the bf16
         rescore copy under PQ-with-rescore (scanning codes first would read
-        MORE HBM than the copy the rescore pass consults anyway). With
-        `s2d` (the snapshot's device translation table) the slot->doc
-        translation fuses into the same program and finalize is a
-        reshape."""
+        MORE HBM than the copy the rescore pass consults anyway). The
+        slot->doc translation runs in the same program, against the
+        snapshot's device table, and finalize is a reshape."""
         kk = min(max(k_eff, 1), snap.n)
         packed_dev = self._gmin_packed_or_none(snap, q, kk, allow_words,
-                                               store, sq_norms, s2d)
+                                               store, sq_norms)
         if packed_dev is None:
             sq = snap.sq_norms if sq_norms is None else sq_norms
             args = (
@@ -3404,33 +3252,14 @@ class TpuVectorIndex(VectorIndex):
                 -(-snap.n // _SCAN_CHUNK),
                 self._rescore_r(kk, snap.n),
             )
-            if s2d is not None:
-                packed_dev = _search_full_fused(*args, s2d, *statics)
-            else:
-                packed_dev = _search_full(*args, *statics)
-        if s2d is not None:
-            return self._finalize_fused(packed_dev, shape, b)
-        slot_to_doc = snap.slot_to_doc
-
-        def finalize():
-            # the ONE deliberate blocking fetch per search dispatch
-            # (results packed [B,2k] = a single transfer), outside any lock
-            packed = _fetch_packed(packed_dev, shape)
-            top, idx = _unpack(packed)
-            top = top[:b]
-            idx = idx[:b]
-            t0 = time.perf_counter() if shape is not None else 0.0
-            ids = np.where(idx >= 0, slot_to_doc[np.clip(idx, 0, None)], -1)
-            if shape is not None:
-                shape.translate_ms = (time.perf_counter() - t0) * 1000.0
-            return ids.astype(np.uint64), top.astype(np.float32)
-
-        return finalize
+            packed_dev = _search_full_fused(*args, snap.slot_to_doc_dev,
+                                            *statics)
+        return self._finalize_fused(packed_dev, shape, b)
 
     def _finalize_fused(self, packed_dev, shape, b: int,
                         k: Optional[int] = None):
-        """Finalize for a FUSED dispatch: the one blocking fetch already
-        carries final doc ids, so the host half is dtype views plus two
+        """Every tier's finalize: the one blocking fetch already carries
+        final doc ids, so the host half is dtype views plus two
         vectorized word copies (ops/topk.unpack_fused) — no slot->doc
         table read, no per-row work (the JGL015 contract, and the reason
         the perf ledger's gather_hop share collapses)."""
@@ -3444,7 +3273,7 @@ class TpuVectorIndex(VectorIndex):
         return finalize
 
     def _dispatch_full_pq(self, snap: IndexSnapshot, q: np.ndarray, b: int,
-                          k: int, allow_list, shape=None, s2d=None):
+                          k: int, allow_list, shape):
         """Compressed full-store search.
 
         With rescore enabled a full bf16 copy of the rows already lives in
@@ -3466,26 +3295,9 @@ class TpuVectorIndex(VectorIndex):
         # 8-bit codes (M) — and the two re-ranking stages restore recall.
         # A broken/ineligible funnel falls through to the 8-bit paths
         # below (the codes and rescore slabs both still exist).
-        packed4 = self._pq4_funnel_packed_or_none(snap, q, b, k, allow_list,
-                                                  s2d)
+        packed4 = self._pq4_funnel_packed_or_none(snap, q, b, k, allow_list)
         if packed4 is not None:
-            if s2d is not None:
-                return self._finalize_fused(packed4, shape, b, k)
-            slot_to_doc = snap.slot_to_doc
-
-            def finalize4():
-                packed = _fetch_packed(packed4, shape)
-                top, slots = _unpack(packed)
-                top, slots = top[:b], slots[:b]
-                t0 = time.perf_counter() if shape is not None else 0.0
-                ids = np.where(slots >= 0,
-                               slot_to_doc[np.clip(slots, 0, None)], -1)
-                if shape is not None:
-                    shape.translate_ms = (time.perf_counter() - t0) * 1000.0
-                return (ids[:, :k].astype(np.uint64),
-                        top[:, :k].astype(np.float32))
-
-            return finalize4
+            return self._finalize_fused(packed4, shape, b, k)
         if shape is not None and shape.tier == costmodel.TIER_PQ_ADC4:
             # the funnel refused mid-dispatch (broken kernel / shallow
             # budgets): re-label the shape for the tier that actually
@@ -3502,13 +3314,11 @@ class TpuVectorIndex(VectorIndex):
             return self._dispatch_scan(
                 snap, q, b, k, allow_words,
                 store=snap.rescore_dev, sq_norms=snap.rescore_sq_norms,
-                shape=shape, s2d=s2d)
-        slot_to_doc = snap.slot_to_doc
+                shape=shape)
         # codes-only tier from here: raw ADC distances, no rescoring pass.
         # Fast path: the fused PQ-ADC group-min kernel (ops/pq_gmin.py) —
         # reconstruction-as-matmul in VMEM, codes never expand in HBM
-        packed_dev = self._pq_gmin_packed_or_none(snap, q, b, k, allow_list,
-                                                  s2d)
+        packed_dev = self._pq_gmin_packed_or_none(snap, q, b, k, allow_list)
         if packed_dev is None:
             # legacy reconstruction-scan path:
             # per-chunk candidate depth: selection cost on TPU grows sharply
@@ -3561,10 +3371,8 @@ class TpuVectorIndex(VectorIndex):
                     False,
                     snap.pq.rotation_dev(),
                 )
-                if s2d is not None:
-                    packed_dev = _search_pq_recon_fused(*args, s2d, *statics)
-                else:
-                    packed_dev = _search_pq_recon(*args, *statics)
+                packed_dev = _search_pq_recon_fused(
+                    *args, snap.slot_to_doc_dev, *statics)
             else:
                 lut = build_lut(jnp.asarray(q), snap.pq._dev_codebook(),
                                 self.metric)
@@ -3575,28 +3383,9 @@ class TpuVectorIndex(VectorIndex):
                     getattr(self.config, "exact_topk", False),
                     -(-snap.n // _PQ_SCAN_CHUNK),
                 )
-                if s2d is not None:
-                    packed_dev = _search_pq_fused(*args, s2d, *statics)
-                else:
-                    packed_dev = _search_pq(*args, *statics)
-        if s2d is not None:
-            return self._finalize_fused(packed_dev, shape, b, k)
-
-        def finalize():
-            # the ONE deliberate blocking fetch per PQ search dispatch,
-            # outside any lock
-            packed = _fetch_packed(packed_dev, shape)
-            top, slots = _unpack(packed)
-            top, slots = top[:b], slots[:b]
-            t0 = time.perf_counter() if shape is not None else 0.0
-            # (cosine: the recon path already emits 1 - dot directly)
-            ids = np.where(slots >= 0, slot_to_doc[np.clip(slots, 0, None)], -1)
-            if shape is not None:
-                shape.translate_ms = (time.perf_counter() - t0) * 1000.0
-            return (ids[:, :k].astype(np.uint64),
-                    top[:, :k].astype(np.float32))
-
-        return finalize
+                packed_dev = _search_pq_fused(
+                    *args, snap.slot_to_doc_dev, *statics)
+        return self._finalize_fused(packed_dev, shape, b, k)
 
     def _allow_slots(self, snap: IndexSnapshot,
                      allow_list: AllowList) -> np.ndarray:
@@ -3644,13 +3433,12 @@ class TpuVectorIndex(VectorIndex):
         return slots
 
     def _dispatch_small_allow(self, snap: IndexSnapshot, q: np.ndarray,
-                              b: int, k: int, allow_list: AllowList,
-                              shape=None, s2d=None):
+                              b: int, k: int, allow_list: AllowList, shape):
         """Gather path (flatSearch over allowList, flat_search.go:19): the
         host-side doc->slot resolution is one cached vectorized membership
         pass (`_allow_slots`); the row scoring is one enqueued device
-        call, and with `s2d` the result-side slot->doc translation rides
-        the same program."""
+        call, and the result-side slot->doc translation rides the same
+        program."""
         empty = (np.zeros((b, 0), np.uint64), np.zeros((b, 0), np.float32))
         slots = self._allow_slots(snap, allow_list)
         # short-circuit when NOTHING can match in THIS snapshot: the
@@ -3679,42 +3467,14 @@ class TpuVectorIndex(VectorIndex):
             # float rows live host-side under PQ: upload the gathered block
             sub = np.zeros((r, snap.dim), np.float32)
             sub[: slots.size] = snap.host_vecs[slots]
-            if s2d is not None:
-                packed_dev = _score_rows_fused(
-                    jnp.asarray(sub), jnp.asarray(q), rows_dev, valid_dev,
-                    snap.tombs, s2d, kk, self.metric)
-            else:
-                packed_dev = _score_rows(
-                    jnp.asarray(sub), jnp.asarray(q), rows_dev, valid_dev,
-                    snap.tombs, kk, self.metric)
+            packed_dev = _score_rows_fused(
+                jnp.asarray(sub), jnp.asarray(q), rows_dev, valid_dev,
+                snap.tombs, snap.slot_to_doc_dev, kk, self.metric)
         else:
-            if s2d is not None:
-                packed_dev = _search_gathered_fused(
-                    snap.store, jnp.asarray(q), rows_dev, valid_dev,
-                    snap.tombs, s2d, kk, self.metric)
-            else:
-                packed_dev = _search_gathered(
-                    snap.store, jnp.asarray(q), rows_dev, valid_dev,
-                    snap.tombs, kk, self.metric)
-        if s2d is not None:
-            return self._finalize_fused(packed_dev, shape, b)
-        slot_to_doc = snap.slot_to_doc
-
-        def finalize():
-            # the ONE deliberate blocking fetch of the gather-path
-            # dispatch, outside any lock
-            packed = _fetch_packed(packed_dev, shape)
-            top, idx = _unpack(packed)
-            top = top[:b]
-            idx = idx[:b]
-            t0 = time.perf_counter() if shape is not None else 0.0
-            safe = np.clip(idx, 0, r - 1)
-            ids = np.where(idx >= 0, slot_to_doc[rows[safe]], -1)
-            if shape is not None:
-                shape.translate_ms = (time.perf_counter() - t0) * 1000.0
-            return ids.astype(np.uint64), top.astype(np.float32)
-
-        return finalize
+            packed_dev = _search_gathered_fused(
+                snap.store, jnp.asarray(q), rows_dev, valid_dev,
+                snap.tombs, snap.slot_to_doc_dev, kk, self.metric)
+        return self._finalize_fused(packed_dev, shape, b)
 
     # -- host fallback plane (serving/robustness.py circuit breaker) ---------
 

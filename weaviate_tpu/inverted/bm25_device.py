@@ -77,8 +77,7 @@ class DeviceBM25:
         self._cache_lock = threading.RLock()
         self._jax = None  # lazy import: module import must not init backend
         # shape of the most recent search_batch dispatch as a shared
-        # cost-model shape (monitoring/costmodel.py): bench's keyword
-        # roofline row reads it — flops = 2·Q·U·n_pad per matmul sweep,
+        # cost-model shape (monitoring/costmodel.py) — flops = 2·Q·U·n_pad per matmul sweep,
         # HBM traffic = the [U, n_pad] f32 row matrix read once
         self.last_batch_shape: Optional[costmodel.DispatchShape] = None
 
@@ -243,7 +242,7 @@ class DeviceBM25:
             import jax.numpy as jnp  # noqa: PLC0415
         except Exception as e:
             # a dead backend silently serving every keyword query at host
-            # speed is the bench.py zipf regression all over again — count
+            # speed is a regression nobody sees — count
             # it and log (rate-limited) before degrading
             record_device_fallback("bm25_device.search", "backend_init", e)
             return s.search(query, limit, properties=properties,

@@ -5,12 +5,12 @@ row) with the host-overhead ledger the index stamps on it while it runs,
 read by the serving path (monitoring/tracing.py DispatchRecord facts, the
 rolling window behind ``/debug/perf`` in monitoring/perf.py) and by the
 BM25 device engine's batch-shape recording (inverted/bm25_device.py); and
-the roofline arithmetic of bench.py's offline rows. The served path computes
+the roofline arithmetic of a scan at a measured rate. The served path computes
 no roofline of its own: a share of the chip's peaks is read from a
 profiler capture (benchmarks/readers/xplane_ops.py), not from analytic
 work over a host wall.
 
-Conventions (inherited from the bench model, kept deliberately):
+Conventions (kept deliberately):
 
 - FLOPs are the *useful* distance math — ``2 · B · N · D`` per scan batch
   (the matmul at the heart of every tier) — not implementation FLOPs, so
@@ -139,8 +139,7 @@ class DispatchShape:
                  "bytes_per_row", "k", "extra", "ndev",
                  "enqueue_ms", "device_ms", "finalize_ms",
                  "filter_ms", "hydrate_ms", "t_start", "t_end",
-                 "t_fetch", "t_fetch_mono", "fused", "fetches",
-                 "translate_ms", "hop")
+                 "t_fetch", "t_fetch_mono", "fetches", "hop")
 
     def __init__(self, tier: str, n: int, dim: float, batch: int,
                  bytes_per_row: float, k: int = 0,
@@ -172,18 +171,10 @@ class DispatchShape:
         # NOT a usable anchor)
         self.t_fetch = 0.0
         self.t_fetch_mono = 0.0
-        # fused-dispatch ledger (index/tpu.py): `fused` marks a dispatch
-        # whose program emitted final doc ids (slot->doc translation on
-        # device), `fetches` counts blocking device->host fetches
-        # (_fetch_packed), and `translate_ms` is the measured host-side
-        # slot->doc translation — stamped 0.0 at dispatch on the fused
-        # path (nothing to measure, by construction), measured on the
-        # legacy path, -1 = not measured. The invariant a fused dispatch
-        # must keep: exactly ONE fetch and ZERO translation
+        # blocking device->host fetches (_fetch_packed). Every program
+        # emits final doc ids, so a dispatch owes exactly ONE
         # (fused_invariant_ok; violations counted by the perf window).
-        self.fused = False
         self.fetches = 0
-        self.translate_ms = -1.0
         # the open `gather_hop` interval (a tracing.Phase): _fetch_packed
         # opens it at fetch end, the dispatch's finalize closes it
         self.hop = None
@@ -212,14 +203,10 @@ class DispatchShape:
 
     def hop_ms(self) -> float:
         """The host hop between the device fetch and hydration — finalize
-        wall minus the blocking fetch. REDEFINED by the fused dispatch:
-        on the legacy path this is unpack + the host slot->doc gather
-        (the gather/rescore hop the r05 profile flagged); on a fused
-        dispatch the translation runs ON DEVICE inside the same program,
-        so the hop is dtype views + two word copies and its share of
-        accounted wall collapses toward zero (docs/performance.md
-        "anatomy of a fused dispatch"). -1 when the split was not
-        measured."""
+        wall minus the blocking fetch. The slot->doc translation runs ON
+        DEVICE inside the search program, so the hop is the unpack: dtype
+        views + two word copies (docs/performance.md "anatomy of a
+        dispatch"). -1 when the split was not measured."""
         if self.finalize_ms < 0.0 or self.device_ms < 0.0:
             return -1.0
         return max(self.finalize_ms - self.device_ms, 0.0)
@@ -250,8 +237,7 @@ class DispatchShape:
         """Flat dict of the analytic shape (bench rows, trace facts)."""
         d = {"tier": self.tier, "n": self.n, "dim": round(self.dim, 2),
              "batch": self.batch, "batch_padded": self.batch_padded,
-             "k": self.k, "flops": self.flops(), "bytes": self.bytes(),
-             "fused": self.fused}
+             "k": self.k, "flops": self.flops(), "bytes": self.bytes()}
         if self.ndev != 1:
             d["ndev"] = self.ndev
         if self.extra:
@@ -267,16 +253,10 @@ class DispatchShape:
 
 
 def fused_invariant_ok(shape: "DispatchShape") -> bool:
-    """The fused-dispatch ledger invariant: a dispatch that claims device-
-    side translation must have made exactly ONE blocking fetch and spent
-    ZERO measured host-translation time. Non-fused dispatches trivially
-    pass (they make no claim). The perf window counts violations per
-    window (monitoring/perf.py), and tests/test_fused_dispatch.py pins
-    the contract per tier."""
-    if not shape.fused:
-        return True
-    if shape.translate_ms != 0.0:
-        return False
+    """The dispatch ledger invariant: the one packed fetch carries final
+    doc ids, so a dispatch makes exactly ONE blocking fetch. The perf
+    window counts violations per window (monitoring/perf.py), and
+    tests/test_fused_dispatch.py pins the contract per tier."""
     if shape.n <= 0:
         # empty-gather early return: no device work ran, no fetch owed
         return shape.fetches <= 1
@@ -302,7 +282,7 @@ def regime(flops: float, bytes_: float,
 def roofline(flops: float, bytes_: float, seconds: float,
              backend: Optional[str] = None) -> dict:
     """Achieved-vs-peak roofline for `flops`/`bytes_` of work done in
-    `seconds`: the per-dispatch / per-window form (bench's QPS form wraps
+    `seconds`: the per-dispatch / per-window form (roofline_from_qps wraps
     this). backend=None detects the live platform."""
     backend = backend or detect_backend()
     peak = PEAKS[backend]
@@ -325,8 +305,8 @@ def roofline(flops: float, bytes_: float, seconds: float,
 def roofline_from_qps(qps, n, dim, batch, bytes_per_row,
                       backend: Optional[str] = None) -> dict:
     """Achieved-vs-peak roofline fields for one flat-scan row at a
-    measured QPS (the bench.py form — field-for-field what bench's old
-    ``_roofline`` emitted; tests/test_bench_roofline.py pins the math).
+    measured QPS (tests/test_perf.py pins the peaks, the ridge and the
+    arithmetic).
 
     FLOPs are the *useful* distance math (2·B·N·D per batch), bytes the
     store bytes read per batch; arithmetic intensity 2·B/bytes_per_elem —

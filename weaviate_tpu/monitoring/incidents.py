@@ -545,7 +545,7 @@ class FlightRecorder:
     incident class and a drop-not-queue enqueue; the capture (plane
     summaries + file IO) runs on a lazily-started worker thread.
     ``dump_now`` captures synchronously for the paths where the process
-    is about to die (SIGTERM/atexit teardown, the bench storm modes)."""
+    is about to die (SIGTERM/atexit teardown)."""
 
     def __init__(self, incident_dir: str, max_bytes: int = 64 * 1024 * 1024,
                  rate_limit_s: float = 300.0, journal: Optional[OpsJournal]
@@ -995,8 +995,7 @@ def teardown_dump() -> Optional[str]:
 
 def emergency_dump(reason: str, directory: Optional[str] = None,
                    detail: Optional[dict] = None) -> Optional[str]:
-    """Best-effort bundle for processes without a wired recorder (the
-    bench's storm modes): uses the
+    """Best-effort bundle for processes without a wired recorder: uses the
     configured recorder when one is live (forced), else writes a one-shot
     bundle of whatever plane state this process still holds — including
     the perf/quality/memory ``recent_summaries()`` stashes, which survive

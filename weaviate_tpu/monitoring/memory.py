@@ -45,8 +45,7 @@ Exposure: ``GET /debug/memory`` (same authorizer as pprof/perf/quality),
 bounded-cardinality gauges (``weaviate_device_bytes{component}``,
 ``weaviate_host_bytes{component}``, ``weaviate_disk_bytes{component}``,
 ``weaviate_memory_headroom_pct{scope}``, ``weaviate_write_flush_ms``,
-``weaviate_cow_copy_bytes_total``), and the ``memory`` blocks on
-bench.py serving/e2e rows. See docs/memory.md.
+``weaviate_cow_copy_bytes_total``). See docs/memory.md.
 
 Lifecycle mirrors the tracer/perf/quality planes: a process-wide module
 global installed by App (``MEMORY_LEDGER_ENABLED``, default on) and

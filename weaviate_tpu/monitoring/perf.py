@@ -146,7 +146,7 @@ class PerfWindow:
         # up), but readers correlating with /debug/traces need the rate
         self.sample_hint = float(sample_hint)
         self._lock = threading.Lock()
-        # (t_end_mono, tier, rows, fused, fused-invariant violated)
+        # (t_end_mono, tier, rows, one-fetch invariant violated)
         self._entries: deque = deque()
         # phase name -> deque[(t_mono, ms)], count-capped (see
         # _PHASE_SAMPLES_MAX) on top of the time-horizon eviction
@@ -189,16 +189,14 @@ class PerfWindow:
                       if shape.t_fetch > 0.0 else 0.0)
         fetch_end = (shape.t_fetch_mono
                      if 0.0 < shape.t_fetch_mono <= now else now)
-        # the fused-dispatch invariant (one blocking fetch, zero host
-        # translation): violations are counted per window -- a fused
-        # dispatch quietly re-growing host translation work must be
-        # dashboard-visible, not just test-pinned
+        # the dispatch invariant (one blocking fetch): violations are
+        # counted per window -- a dispatch quietly re-growing a second
+        # host round trip must be dashboard-visible, not just test-pinned
         viol = not costmodel.fused_invariant_ok(shape)
         nrows = int(rows) or shape.batch
         with self._lock:
             self._evict(now)
-            self._entries.append(
-                (now, shape.tier, nrows, bool(shape.fused), viol))
+            self._entries.append((now, shape.tier, nrows, viol))
             self._rows += nrows
             self._total_dispatches += 1
             if self._first_entry is None:
@@ -369,13 +367,10 @@ class PerfWindow:
             phase_ms = {p: [ms for _, ms in d]
                         for p, d in self._phase.items() if d}
             tiers: dict[str, int] = {}
-            fused_n = fused_viol = 0
-            for _, tier, _, fused, viol in self._entries:
+            violations = 0
+            for _, tier, _, viol in self._entries:
                 tiers[tier] = tiers.get(tier, 0) + 1
-                if fused:
-                    fused_n += 1
-                if viol:
-                    fused_viol += 1
+                violations += viol
             total_dispatches = self._total_dispatches
         out: dict = {
             "window_s": self.window_s,
@@ -403,11 +398,12 @@ class PerfWindow:
             }
         out["phases"] = phases
         out["tiers"] = dict(sorted(tiers.items(), key=lambda kv: -kv[1]))
-        # fused-dispatch coverage + invariant violations over the window
-        # (costmodel.fused_invariant_ok): share near 1.0 with violations 0
-        # is the steady state; violations > 0 means host post-processing
-        # crept back into a dispatch that claims device-side translation
-        out["fused"] = {"dispatches": fused_n, "violations": fused_viol}
+        # invariant violations over the window
+        # (costmodel.fused_invariant_ok): every dispatch translates on the
+        # device, so `dispatches` is all of them; violations > 0 means a
+        # second blocking fetch crept back into a dispatch. The block
+        # keeps its name: dashboards and incident bundles read it
+        out["fused"] = {"dispatches": n, "violations": violations}
         return out
 
 

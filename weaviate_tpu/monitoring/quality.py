@@ -49,9 +49,8 @@ constructs nothing (spy-pinned in tests/test_quality_auditor.py).
 
 Exposure: ``GET /debug/quality`` (same authorizer as pprof/perf),
 bounded-label gauges ``weaviate_recall_at_k{tier}`` /
-``weaviate_distance_relerr{tier}``, audit outcome/lag counters, and the
-``online_recall`` field on bench.py serving rows (cross-checked against
-the bench's own recall computation). See docs/quality.md.
+``weaviate_distance_relerr{tier}``, and audit outcome/lag counters. See
+docs/quality.md.
 """
 
 from __future__ import annotations

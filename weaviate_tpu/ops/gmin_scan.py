@@ -261,37 +261,21 @@ def build_rescore_blocks(store):
     jax.jit,
     static_argnames=("use_allow", "k", "metric", "rg", "active_g", "interpret"),
 )
-def search_gmin(store, sq_norms, tombs, n, q, allow_words, use_allow,
-                k, metric, rg, active_g=G, interpret=False,
-                rescore_blk=None):
-    """Full fused search: group-min fast scan -> top-RG groups -> exact
-    rescore of RG*G members -> top-k. Drop-in twin of _search_full for the
-    matmul metrics; returns packed [B, 2k] (see ops/topk.pack_topk).
+def search_gmin_fused(store, sq_norms, tombs, n, q, allow_words, s2d,
+                      use_allow, k, metric, rg, active_g=G, interpret=False,
+                      rescore_blk=None):
+    """The full-store search as one program: group-min fast scan -> top-RG
+    groups -> exact rescore of RG*G members -> top-k (gmin_topk) -> doc
+    ids. The matmul metrics' fast twin of index/tpu.py _search_full_fused.
 
     allow_words: packed uint32 allowList bitmap over slots (ignored unless
     use_allow). rescore_blk: optional build_rescore_blocks(store) output —
     when given, the candidate rescore reads contiguous group blocks instead
-    of strided rows (16x fewer gather descriptors).
-    """
-    from weaviate_tpu.ops.topk import pack_topk
-
-    top, idx = gmin_topk(store, sq_norms, tombs, n, q, allow_words, use_allow,
-                         k, metric, rg, active_g, interpret, rescore_blk)
-    return pack_topk(top, idx)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("use_allow", "k", "metric", "rg", "active_g", "interpret"),
-)
-def search_gmin_fused(store, sq_norms, tombs, n, q, allow_words, s2d,
-                      use_allow, k, metric, rg, active_g=G, interpret=False,
-                      rescore_blk=None):
-    """search_gmin with the slot->doc translation fused into the SAME
-    program: s2d is the device-resident [capacity, 2] uint32 doc-id word
-    table (index/tpu.py IndexSnapshot.slot_to_doc_dev) and the return is
-    the FUSED [B, 3k] layout (ops/topk.translate_pack) — final doc ids
-    leave the device in the one packed fetch, no host translation."""
+    of strided rows (16x fewer gather descriptors). s2d is the
+    device-resident [capacity, 2] uint32 doc-id word table (index/tpu.py
+    IndexSnapshot.slot_to_doc_dev) and the return is the FUSED [B, 3k]
+    layout (ops/topk.translate_pack): final doc ids leave the device in the
+    one packed fetch."""
     from weaviate_tpu.ops.topk import translate_pack
 
     top, idx = gmin_topk(store, sq_norms, tombs, n, q, allow_words, use_allow,
@@ -301,8 +285,8 @@ def search_gmin_fused(store, sq_norms, tombs, n, q, allow_words, s2d,
 
 def gmin_topk(store, sq_norms, tombs, n, q, allow_words, use_allow,
               k, metric, rg, active_g=G, interpret=False, rescore_blk=None):
-    """search_gmin's traceable body -> ([B, k] dists, [B, k] slot idx, -1
-    for missing). Unjitted so it can run per-shard inside shard_map (the
+    """search_gmin_fused's traceable body -> ([B, k] dists, [B, k] slot idx,
+    -1 for missing). Unjitted so it can run per-shard inside shard_map (the
     mesh kernel) as well as under the single-chip jit wrapper."""
     from weaviate_tpu.ops.topk import bitmap_to_mask
 

@@ -1,9 +1,9 @@
 """Partition-pruned (IVF) scan plane: clustered layout + probed search.
 
 ROADMAP item 3 (the KScaNN/KBest recipe, PAPERS.md): a flat scan is O(N)
-per dispatch no matter how fused the program is — at production corpus
-sizes the headroom the fused dispatch (PR 14) won back burns on rows the
-query never needed. This module holds the IVF plane's two halves:
+per dispatch no matter how few host hops the program makes — at
+production corpus sizes the headroom burns on rows the query never
+needed. This module holds the IVF plane's two halves:
 
 HOST (write path, under the index write lock):
   - ``kmeans_fit``: Lloyd's k-means over a bounded training sample ->
@@ -19,20 +19,19 @@ HOST (write path, under the index write lock):
     bucket overflows its padding.
 
 DEVICE (read path, one program per dispatch — traced together with the
-shared epilogue so IVF composes with the fused dispatch instead of
-forking it):
+shared epilogue, so IVF leaves the device the way every tier does):
   - ``probe``: one [B, nlist] centroid distance block + exact top_p
     selection -> the probed partitions per query;
-  - ``search_ivf_dense`` / ``search_ivf_codes``: gather the probed
+  - ``ivf_dense_topk`` / ``ivf_codes_topk``: gather the probed
     buckets' slots, mask validity exactly like the flat kernels
     (capacity padding, tombstones via the snapshot's own device mask,
     allowList via the SAME packed words the flat kernels consume), an
     optional PCA low-dim prefilter pass, then full-fidelity scoring of
     the survivors through the shared rescore core
     (ops/topk.rescore_distances) and the shared top-k/slot->doc
-    epilogue (merge_top_k / pack_topk / translate_pack). ``*_fused``
-    twins emit the fused packed layout with final doc ids, exactly like
-    every other tier's kernel.
+    epilogue (merge_top_k / translate_pack): the jitted
+    ``search_ivf_*_fused`` programs emit the packed layout with final doc
+    ids, exactly like every other tier's.
 
 Candidate memory is bounded: probed buckets are scored in groups of
 ``gp`` probes per lax.scan step (the caller sizes gp so one step's
@@ -57,8 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from weaviate_tpu.entities import vectorindex as vi
-from weaviate_tpu.ops.topk import (merge_top_k, pack_topk,
-                                   rescore_distances, retranslate_packed)
+from weaviate_tpu.ops.topk import (merge_top_k, rescore_distances,
+                                   translate_pack)
 
 Array = jax.Array
 
@@ -386,18 +385,14 @@ def group_steps(b: int, cap_p: int, dim: int, top_p: int,
     return max(1, min(top_p, budget_elems // per_probe))
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("k", "metric", "use_allow", "top_p", "pre_c", "exact",
-                     "gp", "steps2"),
-)
-def search_ivf_dense(store, tombs, n, q, allow_words, centroids,
-                     buckets, pca_proj, pca_rows, k, metric, use_allow,
-                     top_p, pre_c, exact, gp, steps2):
+def ivf_dense_topk(store, tombs, n, q, allow_words, centroids,
+                   buckets, pca_proj, pca_rows, k, metric, use_allow,
+                   top_p, pre_c, exact, gp, steps2):
     """IVF search over a dense row store (the exact tier's f32/bf16
     store, or the PQ-rescore tier's bf16 copy): probe -> gather the
     probed buckets -> optional PCA prefilter -> full-dim scoring of the
-    survivors through the shared rescore core -> packed top-k.
+    survivors through the shared rescore core -> ([B, k] dists, [B, k]
+    slots, -1 missing).
 
     pre_c > 0 enables the low-dim prefilter: candidates are first ranked
     in the pca_proj subspace (dp dims instead of D) and only the best
@@ -432,7 +427,7 @@ def search_ivf_dense(store, tombs, n, q, allow_words, centroids,
         top, idx = _grouped_topk(slots2, valid2, score_full, k, exact)
     else:
         top, idx = _grouped_topk(slots_g, valid_g, score_full, k, exact)
-    return pack_topk(top, jnp.where(jnp.isinf(top), -1, idx))
+    return top, jnp.where(jnp.isinf(top), -1, idx)
 
 
 @functools.partial(
@@ -444,31 +439,27 @@ def search_ivf_dense_fused(store, tombs, n, q, allow_words, centroids,
                            buckets, pca_proj, pca_rows, s2d, k,
                            metric, use_allow, top_p, pre_c, exact, gp,
                            steps2):
-    """search_ivf_dense with the device-side slot->doc translation fused
-    into the SAME program (ops/topk FUSED layout) — the IVF plane rides
-    the fused dispatch's one-fetch/zero-translation contract."""
-    packed = search_ivf_dense(store, tombs, n, q, allow_words, centroids,
+    """ivf_dense_topk as a top-level program with the slot->doc
+    translation in the SAME program (ops/topk FUSED layout): the IVF plane
+    leaves the device through the one fetch every tier makes."""
+    top, idx = ivf_dense_topk(store, tombs, n, q, allow_words, centroids,
                               buckets, pca_proj, pca_rows, k,
                               metric, use_allow, top_p, pre_c, exact, gp,
                               steps2)
-    return retranslate_packed(packed, s2d)
+    return translate_pack(top, idx, s2d)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("k", "metric", "use_allow", "top_p", "pre_c", "exact",
-                     "gp", "steps2"),
-)
-def search_ivf_codes(codes, recon_norms, tombs, n, q, allow_words,
-                     codebook, centroids, buckets, pca_proj,
-                     pca_rows, rot, k, metric, use_allow, top_p, pre_c,
-                     exact, gp, steps2):
+def ivf_codes_topk(codes, recon_norms, tombs, n, q, allow_words,
+                   codebook, centroids, buckets, pca_proj,
+                   pca_rows, rot, k, metric, use_allow, top_p, pre_c,
+                   exact, gp, steps2):
     """IVF search over the codes-only PQ tier: probed candidates are
     scored by the SAME asymmetric-ADC math as the flat reconstruction
     scan (gather codes -> reconstruct from the bf16 codebook -> one
     f32-accumulated product against the (rotated) query, plus the
     precomputed ||recon||^2 for L2) — per candidate instead of per HBM
-    chunk. No rescore pass, exactly like the flat codes tier."""
+    chunk. No rescore pass, exactly like the flat codes tier.
+    -> ([B, k] dists, [B, k] slots, -1 missing)."""
     qf = q.astype(jnp.float32)
     parts = _probe(qf, centroids, top_p, metric)
     slots_g = _candidate_slots(parts, buckets, gp)
@@ -512,7 +503,7 @@ def search_ivf_codes(codes, recon_norms, tombs, n, q, allow_words,
         top, idx = _grouped_topk(slots2, valid2, score_adc, k, exact)
     else:
         top, idx = _grouped_topk(slots_g, valid_g, score_adc, k, exact)
-    return pack_topk(top, jnp.where(jnp.isinf(top), -1, idx))
+    return top, jnp.where(jnp.isinf(top), -1, idx)
 
 
 @functools.partial(
@@ -524,9 +515,10 @@ def search_ivf_codes_fused(codes, recon_norms, tombs, n, q, allow_words,
                            codebook, centroids, buckets, pca_proj,
                            pca_rows, rot, s2d, k, metric, use_allow, top_p,
                            pre_c, exact, gp, steps2):
-    """search_ivf_codes with device-side slot->doc translation fused in."""
-    packed = search_ivf_codes(codes, recon_norms, tombs, n, q, allow_words,
+    """ivf_codes_topk as a top-level program, its winners translated to
+    doc ids in the same program."""
+    top, idx = ivf_codes_topk(codes, recon_norms, tombs, n, q, allow_words,
                               codebook, centroids, buckets,
                               pca_proj, pca_rows, rot, k, metric,
                               use_allow, top_p, pre_c, exact, gp, steps2)
-    return retranslate_packed(packed, s2d)
+    return translate_pack(top, idx, s2d)

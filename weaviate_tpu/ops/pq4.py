@@ -340,32 +340,15 @@ _FUNNEL_STATICS = ("use_allow", "k", "metric", "rg4", "rc", "active_g",
 
 
 @functools.partial(jax.jit, static_argnames=_FUNNEL_STATICS)
-def search_pq4_funnel(codes4p, codes8, norms4, norms8, tombs, n, q,
-                      cb4_chunks, codebook4, flat_cb8, rescore_rows,
-                      allow_words, use_allow, k, metric, rg4, rc, active_g=G,
-                      use_pallas=False, interpret=False, exact=False,
-                      rot=None, codes8_blk=None):
-    """Jitted packed wrapper (pack_topk layout) — the funnel twin of
-    pq_gmin.search_pq_gmin."""
-    from weaviate_tpu.ops.topk import pack_topk
-
-    top, idx = pq4_funnel_topk(
-        codes4p, codes8, norms4, norms8, tombs, n, q, cb4_chunks, codebook4,
-        flat_cb8, rescore_rows, allow_words, use_allow, k, metric, rg4, rc,
-        active_g, use_pallas, interpret, exact, rot, codes8_blk)
-    return pack_topk(top, idx)
-
-
-@functools.partial(jax.jit, static_argnames=_FUNNEL_STATICS)
 def search_pq4_funnel_fused(codes4p, codes8, norms4, norms8, tombs, n, q,
                             cb4_chunks, codebook4, flat_cb8, rescore_rows,
                             allow_words, s2d, use_allow, k, metric, rg4, rc,
                             active_g=G, use_pallas=False, interpret=False,
                             exact=False, rot=None, codes8_blk=None):
-    """search_pq4_funnel with the slot->doc translation fused into the
-    same program (ops/topk.translate_pack FUSED [B, 3k] layout): one
-    packed fetch carries final doc ids — the PR-14
-    one-fetch/zero-translation invariant."""
+    """pq4_funnel_topk as a top-level program with the slot->doc
+    translation in the same program (ops/topk.translate_pack FUSED [B, 3k]
+    layout): one packed fetch carries final doc ids — the funnel twin of
+    pq_gmin.search_pq_gmin_fused."""
     from weaviate_tpu.ops.topk import translate_pack
 
     top, idx = pq4_funnel_topk(
@@ -382,14 +365,14 @@ _IVF_STATICS = ("k", "metric", "use_allow", "top_p", "c1", "rc", "exact",
                 "gp", "steps2")
 
 
-@functools.partial(jax.jit, static_argnames=_IVF_STATICS)
-def search_ivf_pq4(codes4p, codes8, norms4, norms8, tombs, n, q, allow_words,
-                   codebook4, codebook8, centroids, buckets, rot,
-                   rescore_rows, k, metric, use_allow, top_p, c1, rc, exact,
-                   gp, steps2):
+def ivf_pq4_topk(codes4p, codes8, norms4, norms8, tombs, n, q, allow_words,
+                 codebook4, codebook8, centroids, buckets, rot,
+                 rescore_rows, k, metric, use_allow, top_p, c1, rc, exact,
+                 gp, steps2):
     """IVF-probed three-stage funnel: probe -> grouped 4-bit byte-LUT ADC
     over the probed buckets (keep c1) -> grouped exact 8-bit ADC of the
-    survivors (keep rc) -> bf16/exact rescore -> packed top-k. The probe,
+    survivors (keep rc) -> bf16/exact rescore -> ([B, k] dists, [B, k]
+    slots, -1 missing). The probe,
     candidate grouping, masking, and collect-then-merge discipline are
     ops/ivf.py's own (shared helpers), so the funnel composes with
     partitions, filters, and tombstones as a tier, not a fork."""
@@ -401,7 +384,7 @@ def search_ivf_pq4(codes4p, codes8, norms4, norms8, tombs, n, q, allow_words,
         _regroup,
         _slot_valid,
     )
-    from weaviate_tpu.ops.topk import pack_topk, rescore_distances
+    from weaviate_tpu.ops.topk import rescore_distances
 
     qf = q.astype(jnp.float32)
     parts = _probe(qf, centroids, top_p, metric)
@@ -432,7 +415,7 @@ def search_ivf_pq4(codes4p, codes8, norms4, norms8, tombs, n, q, allow_words,
             return -s
         return 1.0 - s
 
-    # stage 2: exact 8-bit ADC (search_ivf_codes' scoring, per survivor)
+    # stage 2: exact 8-bit ADC (ivf_codes_topk's scoring, per survivor)
     flat_cb8 = codebook8.reshape(m8 * c8, ds8).astype(jnp.bfloat16)
     seg_off = (jnp.arange(m8, dtype=jnp.int32) * c8)[None, None, :]
     qd = qr.astype(jnp.bfloat16)
@@ -468,7 +451,7 @@ def search_ivf_pq4(codes4p, codes8, norms4, norms8, tombs, n, q, allow_words,
         idx = jnp.take_along_axis(idx2, pos, axis=1)
     else:
         top, idx = top2[:, :k], idx2[:, :k]
-    return pack_topk(top, jnp.where(jnp.isinf(top), -1, idx))
+    return top, jnp.where(jnp.isinf(top), -1, idx)
 
 
 @functools.partial(jax.jit, static_argnames=_IVF_STATICS)
@@ -476,11 +459,12 @@ def search_ivf_pq4_fused(codes4p, codes8, norms4, norms8, tombs, n, q,
                          allow_words, codebook4, codebook8, centroids,
                          buckets, rot, rescore_rows, s2d, k, metric,
                          use_allow, top_p, c1, rc, exact, gp, steps2):
-    """search_ivf_pq4 with device-side slot->doc translation fused in."""
-    from weaviate_tpu.ops.topk import retranslate_packed
+    """ivf_pq4_topk as a top-level program, its winners translated to doc
+    ids in the same program."""
+    from weaviate_tpu.ops.topk import translate_pack
 
-    packed = search_ivf_pq4(
+    top, idx = ivf_pq4_topk(
         codes4p, codes8, norms4, norms8, tombs, n, q, allow_words, codebook4,
         codebook8, centroids, buckets, rot, rescore_rows, k, metric,
         use_allow, top_p, c1, rc, exact, gp, steps2)
-    return retranslate_packed(packed, s2d)
+    return translate_pack(top, idx, s2d)

@@ -336,31 +336,14 @@ def pq_gmin_topk(codes, recon_norms, tombs, n, q, cb_chunks, flat_cb,
     jax.jit,
     static_argnames=("use_allow", "k", "metric", "rg", "active_g", "interpret"),
 )
-def search_pq_gmin(codes, recon_norms, tombs, n, q, cb_chunks, flat_cb,
-                   allow_words, use_allow, k, metric, rg, active_g=G,
-                   interpret=False, rot=None, codes_blk=None):
-    """Jitted packed wrapper (pack_topk layout), the codes-only twin of
-    gmin_scan.search_gmin."""
-    from weaviate_tpu.ops.topk import pack_topk
-
-    top, idx = pq_gmin_topk(codes, recon_norms, tombs, n, q, cb_chunks,
-                            flat_cb, allow_words, use_allow, k, metric, rg,
-                            active_g, interpret, rot, codes_blk)
-    return pack_topk(top, idx)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("use_allow", "k", "metric", "rg", "active_g", "interpret"),
-)
 def search_pq_gmin_fused(codes, recon_norms, tombs, n, q, cb_chunks, flat_cb,
                          allow_words, s2d, use_allow, k, metric, rg,
                          active_g=G, interpret=False, rot=None,
                          codes_blk=None):
-    """search_pq_gmin with the slot->doc translation fused into the same
-    program (ops/topk.translate_pack, the FUSED [B, 3k] layout): the one
-    packed fetch carries final doc ids — gmin_scan.search_gmin_fused's
-    codes-only twin."""
+    """pq_gmin_topk as a top-level program with the slot->doc translation
+    in the same program (ops/topk.translate_pack, the FUSED [B, 3k]
+    layout): the one packed fetch carries final doc ids —
+    gmin_scan.search_gmin_fused's codes-only twin."""
     from weaviate_tpu.ops.topk import translate_pack
 
     top, idx = pq_gmin_topk(codes, recon_norms, tombs, n, q, cb_chunks,

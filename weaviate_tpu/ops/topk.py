@@ -70,8 +70,8 @@ def unpack_topk(packed) -> tuple:
 
 
 # sentinel word for a missing result slot: both words 0xFFFFFFFF make the
-# reassembled uint64 doc id 2**64-1 — exactly what the legacy host
-# translation emitted for idx -1 (np.int64(-1) viewed as uint64)
+# reassembled uint64 doc id 2**64-1 (np.int64(-1) viewed as uint64), the
+# "missing" id the API has always carried for idx -1
 _MISS_WORD = 0xFFFFFFFF
 
 

@@ -10,11 +10,11 @@ Every kernel here is a whole-mesh step:
 
 - mesh_search_step:  chunked masked kNN per slab (tombstones + allowList
   bitmap, same semantics as the single-chip scan in index/tpu.py) with the
-  cross-chip merge riding ICI. With ``fused=True`` every search kernel
-  translates its LOCAL winners through its slab of the sharded slot->doc
-  word table BEFORE the collective, so the gathered candidates already
-  carry final doc ids and the merged output is the PR-14 packed [B, 3k]
-  fused layout — one fetch, zero host translation, across chips.
+  cross-chip merge riding ICI. Every search kernel translates its LOCAL
+  winners through its slab of the sharded slot->doc word table BEFORE the
+  collective, so the gathered candidates already carry final doc ids and
+  the merged output is the packed [B, 3k] layout of ops/topk
+  translate_pack — one fetch, zero host translation, across chips.
 - mesh_insert_step:  ALL shards land their staged rows in ONE program — the
   host ships a [n_dev, C, D] block sharded over the mesh, each chip writes its
   own chunk at its own offset (and derives l2 norms on device). No per-shard
@@ -39,8 +39,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from weaviate_tpu.ops.distances import DISTANCE_FNS
 from weaviate_tpu.ops.topk import (
-    bitmap_to_mask, merge_top_k, pack_topk, rescore_distances,
-    translate_pack,
+    bitmap_to_mask, merge_top_k, rescore_distances, translate_pack,
 )
 
 SHARD_AXIS = "shard"
@@ -58,22 +57,11 @@ def _shard_map(f, *, mesh, in_specs, out_specs):
 _MESH_SCAN_CHUNK = 131072
 
 
-def _merge_across_shards(d_top, i_glob, k):
-    """Cross-chip merge inside a shard_fn: all_gather the per-chip (dist,
-    global-row) candidate sets over ICI, reselect k, pack. Shared by every
-    search kernel so the merge semantics cannot diverge."""
-    d_all = jax.lax.all_gather(d_top, SHARD_AXIS, axis=1, tiled=True)
-    i_all = jax.lax.all_gather(i_glob, SHARD_AXIS, axis=1, tiled=True)
-    neg, pos = jax.lax.top_k(-d_all, k)
-    d_fin = -neg
-    i_fin = jnp.take_along_axis(i_all, pos, axis=1)
-    i_fin = jnp.where(jnp.isinf(d_fin), -1, i_fin).astype(jnp.int32)
-    return pack_topk(d_fin, i_fin)
-
-
 def _merge_across_shards_fused(d_top, i_loc, s2d_l, k):
-    """Cross-chip merge with the slot->doc translation fused BEFORE the
-    collective: each chip gathers its k winners' doc-id words from its
+    """The shared per-shard epilogue of every mesh search kernel
+    (i_loc [B, k] = LOCAL slab rows, -1 for missing): the cross-chip merge
+    with the slot->doc translation BEFORE the collective, so the merge
+    semantics cannot diverge between kernels. Each chip gathers its k winners' doc-id words from its
     LOCAL slab of the sharded [cap, 2] uint32 table (a k-row gather — the
     table itself never crosses ICI), packs (dist | id_lo | id_hi) into the
     PR-14 fused [B, 3k] layout, all_gathers the per-chip packed blocks,
@@ -98,18 +86,6 @@ def _merge_across_shards_fused(d_top, i_loc, s2d_l, k):
     hi = jnp.take_along_axis(hi_all, pos, axis=1)
     return jnp.concatenate(
         [jax.lax.bitcast_convert_type(d_fin, jnp.int32), lo, hi], axis=1)
-
-
-def _merge_local(d_top, i_loc, s2d_l, my, n_loc, k, fused):
-    """The shared per-shard epilogue of every mesh search kernel
-    (i_loc [B, k] = LOCAL slab rows, -1 for missing): fused mode
-    translates LOCAL winners through the local s2d slab and merges packed
-    doc-id candidates; legacy mode rebases to global rows and merges
-    (dist, row) pairs for the host-side slot->doc translation."""
-    if fused:
-        return _merge_across_shards_fused(d_top, i_loc, s2d_l, k)
-    i_glob = jnp.where(i_loc >= 0, i_loc + my * n_loc, -1)
-    return _merge_across_shards(d_top, i_glob, k)
 
 
 def make_mesh(n_devices: Optional[int] = None, devices=None) -> Mesh:
@@ -153,17 +129,22 @@ def mesh_search_step(
     allow_words: [n_dev * n_loc / 32] uint32 sharded — packed filter bitmap
     queries:     [B, D] replicated
     s2d:         [n_dev * n_loc, 2] uint32 sharded — per-slab slot->doc id
-                 words (consumed only under fused=True; XLA dead-code
-                 eliminates the operand otherwise)
-    -> fused=True: FUSED packed [B, 3k] i32 (translate_pack layout, doc ids
-       already resolved on device), replicated.
-       fused=False: packed [B, 2k] i32 (pack_topk), replicated; global
-       row = slab row + shard_index * n_loc (the host maps rows -> docIDs).
+                 words
+    fused:       must be True: the program translates on the device and
+                 nothing else. The argument stays because the benchmark's
+                 compile tests pass it (ROADMAP.md Queue 3); anything else
+                 is refused.
+    -> FUSED packed [B, 3k] i32 (translate_pack layout, doc ids already
+       resolved on device), replicated.
 
     Per-chunk selection is lax.approx_min_k (the TPU PartialReduce primitive)
     unless exact; the cross-chunk and cross-chip merges are exact, mirroring
     the single-chip scan in index/tpu.py.
     """
+    if not isinstance(fused, bool) or not fused:
+        raise ValueError(
+            "mesh_search_step translates on the device and nothing else: "
+            f"fused must be True, got {fused!r}")
     n_dev = mesh.devices.size
     n_loc = store.shape[0] // n_dev
     dim = store.shape[1]
@@ -210,7 +191,7 @@ def mesh_search_step(
         if use_allow:
             xs.append(allow_c)
         (d_top, i_top), _ = jax.lax.scan(step, init, tuple(xs))
-        return _merge_local(d_top, i_top, s2d_l, my, n_loc, k, fused)
+        return _merge_across_shards_fused(d_top, i_top, s2d_l, k)
 
     return _shard_map(
         shard_fn,
@@ -226,23 +207,20 @@ def mesh_search_step(
 @functools.partial(
     jax.jit,
     static_argnames=("k", "metric", "use_allow", "use_norms", "rg",
-                     "active_g", "interpret", "fused", "mesh"),
+                     "active_g", "interpret", "mesh"),
 )
 def mesh_search_gmin_step(
     store, sq_norms, tombs, n_per_shard, allow_words, queries, s2d,
-    k, metric, use_allow, use_norms, rg, active_g, interpret, fused, mesh,
+    k, metric, use_allow, use_norms, rg, active_g, interpret, mesh,
 ):
     """Fused group-min kNN, mesh-sharded: each chip runs the SAME Pallas
     fast-scan + exact-rescore the single-chip index uses
     (ops/gmin_scan.gmin_topk) over its own HBM slab — distances never
     round-trip through HBM — and the cross-chip merge all_gathers k
-    (dist, global-row) pairs over ICI and reselects, exactly like
+    (dist, doc-id) candidates over ICI and reselects, exactly like
     mesh_search_step. Same argument layout as mesh_search_step plus the
     gmin parameters (rg kept groups, active_g live slices per slab)."""
     from weaviate_tpu.ops import gmin_scan
-
-    n_dev = mesh.devices.size
-    n_loc = store.shape[0] // n_dev
 
     def shard_fn(store_l, norms_l, tombs_l, n_all, allow_l, q, s2d_l):
         my = jax.lax.axis_index(SHARD_AXIS)
@@ -254,7 +232,7 @@ def mesh_search_gmin_step(
         d_top, i_top = gmin_scan.gmin_topk(
             store_l, norms, tombs_l, n_mine, q, allow_l, use_allow,
             k, metric, rg, active_g, interpret, blk_l)
-        return _merge_local(d_top, i_top, s2d_l, my, n_loc, k, fused)
+        return _merge_across_shards_fused(d_top, i_top, s2d_l, k)
 
     return _shard_map(
         shard_fn,
@@ -270,24 +248,20 @@ def mesh_search_gmin_step(
 @functools.partial(
     jax.jit,
     static_argnames=("k", "metric", "use_allow", "rg", "active_g",
-                     "interpret", "fused", "mesh"),
+                     "interpret", "mesh"),
 )
 def mesh_search_pq_gmin_step(
     codes, recon_norms, tombs, n_per_shard, allow_words, cb_chunks, flat_cb,
-    queries, rot, s2d, k, metric, use_allow, rg, active_g, interpret, fused,
-    mesh,
+    queries, rot, s2d, k, metric, use_allow, rg, active_g, interpret, mesh,
 ):
     """Codes-only fused ADC kNN, mesh-sharded: each chip runs the SAME
     reconstruction-as-matmul Pallas scan the single-chip index uses
     (ops/pq_gmin.pq_gmin_topk) over its own uint8 code slab — codes never
     expand in HBM — and the cross-chip merge all_gathers k (ADC dist,
-    global-row) pairs over ICI and reselects, exactly like the dense
+    doc-id) candidates over ICI and reselects, exactly like the dense
     mesh_search_gmin_step. ADC distances are deterministic per slab, so the
     merge is exact w.r.t. the quantizer."""
     from weaviate_tpu.ops import pq_gmin
-
-    n_dev = mesh.devices.size
-    n_loc = codes.shape[0] // n_dev
 
     def shard_fn(codes_l, norms_l, tombs_l, n_all, allow_l, cb_c, fcb, q, r,
                  s2d_l):
@@ -297,7 +271,7 @@ def mesh_search_pq_gmin_step(
             codes_l, norms_l, tombs_l, n_mine, q, cb_c, fcb, allow_l,
             use_allow, k, metric, rg, active_g, interpret, r,
             pq_gmin.build_codes_blocks(codes_l))
-        return _merge_local(d_top, i_top, s2d_l, my, n_loc, k, fused)
+        return _merge_across_shards_fused(d_top, i_top, s2d_l, k)
 
     return _shard_map(
         shard_fn,
@@ -314,19 +288,19 @@ def mesh_search_pq_gmin_step(
 @functools.partial(
     jax.jit,
     static_argnames=("k", "r_chunk", "metric", "use_allow", "exact",
-                     "do_rescore", "fused", "mesh"),
+                     "do_rescore", "mesh"),
 )
 def mesh_search_pq_step(
     codes, recon_norms, tombs, n_per_shard, allow_words, codebook,
     rescore_store, queries, rot, s2d, k, r_chunk, metric, use_allow, exact,
-    do_rescore, fused, mesh,
+    do_rescore, mesh,
 ):
     """Mesh twin of the single-chip PQ reconstruction scan
-    (index/tpu.py _search_pq_recon): each chip scans its OWN code slab —
+    (index/tpu.py _pq_recon_topk): each chip scans its OWN code slab —
     gather centroids per chunk into a [chunk, D] block, one bf16 matmul,
     collect per-chunk top-r — then exact-rescores its local candidate pool
     against its local rescore slab and keeps a local top-k; the cross-chip
-    merge all_gathers k (dist, global-row) pairs per chip over ICI and
+    merge all_gathers k (dist, doc-id) candidates per chip over ICI and
     reselects. Rescored distances are exact f32, so the final merge is
     exact.
 
@@ -337,7 +311,7 @@ def mesh_search_pq_step(
     allow_words:  [n_dev * n_loc / 32] uint32 sharded
     codebook:     [M, C, ds] f32 replicated
     rescore_store:[n_dev * n_loc, D] sharded (bf16/f32 row copy)
-    -> packed [B, 2k] i32 replicated; rows are global (slab + shard*n_loc).
+    -> FUSED packed [B, 3k] i32 (translate_pack layout), replicated.
     """
     n_dev = mesh.devices.size
     n_loc = codes.shape[0] // n_dev
@@ -406,7 +380,7 @@ def mesh_search_pq_step(
         d_top = -neg
         i_top = jnp.take_along_axis(cand_i, pos, axis=1)
         i_loc = jnp.where(jnp.isinf(d_top), -1, i_top)
-        return _merge_local(d_top, i_loc, s2d_l, my, n_loc, k, fused)
+        return _merge_across_shards_fused(d_top, i_loc, s2d_l, k)
 
     return _shard_map(
         shard_fn,
@@ -424,14 +398,14 @@ def mesh_search_pq_step(
 @functools.partial(
     jax.jit,
     static_argnames=("k", "metric", "use_allow", "top_p", "exact", "gp",
-                     "fused", "mesh"),
+                     "mesh"),
 )
 def mesh_search_ivf_step(
     store, tombs, n_per_shard, allow_words, centroids, buckets, queries,
-    s2d, k, metric, use_allow, top_p, exact, gp, fused, mesh,
+    s2d, k, metric, use_allow, top_p, exact, gp, mesh,
 ):
     """Partition-pruned kNN over the sharded dense store: the mesh twin of
-    ops/ivf.search_ivf_dense. Centroids are replicated (every chip probes
+    ops/ivf.ivf_dense_topk. Centroids are replicated (every chip probes
     the SAME nlist partitions — the KScaNN-style balanced assignment is
     done at build time per device), but buckets are per-device: buckets
     [n_dev, nlist, cap_p] int32 sharded over dim 0 holds LOCAL slab slot
@@ -439,8 +413,8 @@ def mesh_search_ivf_step(
     physically live in its own HBM slab. Per-shard candidate scoring and
     local top-k mirror the single-chip grouped scan exactly (shared
     _probe/_candidate_slots/_slot_valid/_grouped_topk helpers); the
-    cross-chip merge is the same fused/legacy epilogue as every other mesh
-    search kernel. No PCA prefilter tier here: the probed per-device pool
+    cross-chip merge is the same epilogue as every other mesh search
+    kernel. No PCA prefilter tier here: the probed per-device pool
     is already 1/n_dev of the single-chip pool, below where the prefilter
     pays for its extra gather."""
     from weaviate_tpu.ops import ivf as ivf_ops
@@ -464,7 +438,7 @@ def mesh_search_ivf_step(
         d_top, i_top = ivf_ops._grouped_topk(slots_g, valid_g, score_full,
                                              k, exact)
         i_loc = jnp.where(jnp.isinf(d_top), -1, i_top)
-        return _merge_local(d_top, i_loc, s2d_l, my, n_loc, k, fused)
+        return _merge_across_shards_fused(d_top, i_loc, s2d_l, k)
 
     return _shard_map(
         shard_fn,
@@ -481,19 +455,19 @@ def mesh_search_ivf_step(
 @functools.partial(
     jax.jit,
     static_argnames=("k", "metric", "use_allow", "rg4", "rc", "exact",
-                     "fused", "mesh"),
+                     "mesh"),
 )
 def mesh_search_pq4_step(
     codes4, codes8, recon_norms4, recon_norms8, tombs, n_per_shard,
     allow_words, codebook4, flat_cb8, rescore_store, queries, rot, s2d,
-    k, metric, use_allow, rg4, rc, exact, fused, mesh,
+    k, metric, use_allow, rg4, rc, exact, mesh,
 ):
     """The 4-bit Quick-ADC funnel, mesh-sharded: each chip runs the SAME
     three-stage funnel the single-chip index uses (ops/pq4.pq4_funnel_topk
     — byte-LUT nibble scan -> exact 8-bit ADC of the top rg4*G survivors
     -> exact rescore of the top rc against the chip's own store slab, the
     per-chip stage-3 source) over its own packed uint8 slab, and the
-    cross-chip merge all_gathers k (exact dist, global-row) pairs over ICI
+    cross-chip merge all_gathers k (exact dist, doc-id) candidates over ICI
     and reselects, exactly like the other mesh search kernels. Stage-3
     distances are exact f32, so the merge is exact.
 
@@ -510,9 +484,6 @@ def mesh_search_pq4_step(
     one gather per packed byte."""
     from weaviate_tpu.ops import pq4 as pq4_ops
 
-    n_dev = mesh.devices.size
-    n_loc = codes4.shape[0] // n_dev
-
     def shard_fn(c4_l, c8_l, n4_l, n8_l, tombs_l, n_all, allow_l, cb4, fcb8,
                  rs_l, q, r, s2d_l):
         my = jax.lax.axis_index(SHARD_AXIS)
@@ -521,7 +492,7 @@ def mesh_search_pq4_step(
             c4_l, c8_l, n4_l, n8_l, tombs_l, n_mine, q, None, cb4, fcb8,
             rs_l, allow_l, use_allow, k, metric, rg4, rc,
             use_pallas=False, interpret=False, exact=exact, rot=r)
-        return _merge_local(d_top, i_top, s2d_l, my, n_loc, k, fused)
+        return _merge_across_shards_fused(d_top, i_top, s2d_l, k)
 
     return _shard_map(
         shard_fn,

@@ -46,17 +46,11 @@ class App:
 
         self.stack_sampler = StackSampler()
 
-        # fused device dispatch (index/tpu.py): apply the config knob to
-        # the index layer's process-wide toggle — like the tracer, the
-        # index reaches it without plumbing. Default on; the bench's
-        # --fused A/B and FUSED_DISPATCH_ENABLED flip it.
+        # IVF scan plane (index/tpu.py, ROADMAP item 3): a process-wide
+        # toggle — like the tracer, the index layer reads Config.ivf
+        # without plumbing, and the token scopes the revert to THIS App
         from weaviate_tpu.index import tpu as tpu_index
 
-        self._fused_token = tpu_index.set_fused_enabled(
-            self.config.fused_dispatch_enabled)
-        # IVF scan plane (index/tpu.py, ROADMAP item 3): same
-        # process-wide toggle shape — the index layer reads Config.ivf
-        # without plumbing, and the token scopes the revert to THIS App
         self._ivf_token = tpu_index.set_ivf_config(self.config.ivf)
 
         # end-to-end request tracing (monitoring/tracing.py): the tracer is
@@ -499,12 +493,11 @@ class App:
         # shards they would dispatch to go away
         if self.coalescer is not None:
             self.coalescer.shutdown()
-        # the fused-dispatch toggle reverts to the env default, but only
-        # if OUR override is still the current one (a newer App's setting
+        # the IVF toggle reverts to the env default, but only if OUR
+        # override is still the current one (a newer App's setting
         # survives) — the same still-ours discipline as the tracer below
         from weaviate_tpu.index import tpu as tpu_index
 
-        tpu_index.unset_fused_enabled(getattr(self, "_fused_token", None))
         tpu_index.unset_ivf_config(getattr(self, "_ivf_token", None))
         if self.tracer is not None:
             from weaviate_tpu.monitoring import tracing
