@@ -3,10 +3,27 @@
 // The serving hot path hydrates thousands of winners per batch with two
 // point lookups each (docid -> uuid, uuid -> object image). In Python that
 // is a bisect over per-segment key lists under the bucket lock WITH the GIL
-// held — it both costs ~5us/key and serializes concurrent batches. Here the
-// whole batch is one C call: ctypes releases the GIL for its duration, the
-// per-key cost is a bytewise binary search over the mmap'd footer
-// (~0.3us), and concurrent hydrations genuinely overlap.
+// held. Here a batch is one C call with the GIL released (ctypes), so
+// concurrent hydrations overlap:
+//
+//   lsm_multi_get  finds every key ONCE: the key is hashed once, then each
+//               segment (newest first) is asked with one probe of its
+//               hash table until one holds the key. It writes where each
+//               value lives and the prefix sums of their lengths, and
+//               then, if the caller's arena holds them all, copies them.
+//   lsm_copy    the copy alone, for the caller whose arena was too small:
+//               it comes back with a larger one and nothing is searched
+//               again.
+//
+// A segment's table is built once, in lsm_seg_open: open addressing,
+// linear probing, at most half full, one uint32 a slot (8 bytes a key). A
+// slot holds the entry's index plus one in its low bits and the high bits
+// of the key's hash above them, so a probe that meets another key's slot
+// almost never touches that key's bytes; a slot whose tag matches is
+// confirmed with a full length-and-bytes compare, so a collision can never
+// return another key's value. What a batch costs is then the number of
+// segments a key has to ask (about half of them: no bloom filter, no
+// fence) and the copy; lsm_multi_get counts both probes and compares.
 //
 // Reference analog: the batched hydration seam of
 // entities/storobj/storage_object.go:211 (ObjectsByDocID) over lsmkv's
@@ -21,7 +38,8 @@
 //   - the caller snapshots the segment handle list under the bucket lock
 //     and bumps an in-flight counter;
 //   - compaction retires (never closes) segments while calls are in
-//     flight, so every handle passed in stays valid for the whole call;
+//     flight, so every handle passed in, and every value address
+//     lsm_multi_get wrote, stays valid until the caller leaves;
 //   - handles are immutable after open — no locking needed here.
 
 #include <cstdint>
@@ -52,28 +70,77 @@ struct Seg {
     int fd = -1;
     const uint8_t* base = nullptr;
     size_t size = 0;
-    std::vector<Entry> entries;  // sorted by key (the writer guarantees it)
+    std::vector<Entry> entries;  // footer order (sorted by key)
+    // key hash -> entry: slot = tag << idx_bits | (entry index + 1), 0 = empty
+    std::vector<uint32_t> table;
+    uint64_t mask = 0;      // table.size() - 1 (a power of two)
+    uint32_t idx_bits = 1;  // bits that hold entry index + 1
 };
 
-inline int cmp_keys(const uint8_t* a, uint64_t alen, const uint8_t* b,
-                    uint64_t blen) {
-    const uint64_t n = alen < blen ? alen : blen;
-    const int c = n ? std::memcmp(a, b, n) : 0;
-    if (c != 0) return c;
-    return alen < blen ? -1 : (alen > blen ? 1 : 0);
+// murmur3's 64-bit finalizer over 8-byte words, the length mixed in first
+// (so a key and the same key with a zero byte appended differ)
+inline uint64_t fmix64(uint64_t x) {
+    x ^= x >> 33;
+    x *= 0xff51afd7ed558ccdULL;
+    x ^= x >> 33;
+    x *= 0xc4ceb9fe1a85ec53ULL;
+    x ^= x >> 33;
+    return x;
 }
 
-// -> entry index or -1
-inline int64_t seg_find(const Seg& s, const uint8_t* key, uint64_t klen) {
-    int64_t lo = 0, hi = static_cast<int64_t>(s.entries.size()) - 1;
-    while (lo <= hi) {
-        const int64_t mid = (lo + hi) / 2;
-        const Entry& e = s.entries[static_cast<size_t>(mid)];
-        const int c = cmp_keys(e.key, e.key_len, key, klen);
-        if (c == 0) return mid;
-        if (c < 0) lo = mid + 1; else hi = mid - 1;
+inline uint64_t hash_key(const uint8_t* p, uint64_t n) {
+    uint64_t h = fmix64(n + 0x9e3779b97f4a7c15ULL);
+    for (; n >= 8; p += 8, n -= 8) {
+        uint64_t w;
+        std::memcpy(&w, p, 8);
+        h = fmix64(h ^ w);
     }
-    return -1;
+    if (n) {
+        uint64_t w = 0;
+        std::memcpy(&w, p, n);
+        h = fmix64(h ^ w);
+    }
+    return h;
+}
+
+// the hash's high bits, as many as a slot has left above the index
+inline uint32_t slot_tag(const Seg& s, uint64_t h) {
+    return static_cast<uint32_t>(h >> (32 + s.idx_bits));
+}
+
+// false when the segment holds more entries than a uint32 slot can name
+bool build_table(Seg& s) {
+    const uint64_t count = s.entries.size();
+    if (count >= (1ULL << 31)) return false;
+    while ((1ULL << s.idx_bits) <= count) s.idx_bits++;
+    uint64_t slots = 2;
+    while (slots < 2 * count) slots <<= 1;  // at most half full
+    s.mask = slots - 1;
+    s.table.assign(slots, 0);
+    for (uint64_t i = 0; i < count; i++) {
+        const uint64_t h = hash_key(s.entries[i].key, s.entries[i].key_len);
+        uint64_t at = h & s.mask;
+        while (s.table[at] != 0) at = (at + 1) & s.mask;
+        s.table[at] = (slot_tag(s, h) << s.idx_bits) |
+                      static_cast<uint32_t>(i + 1);
+    }
+    return true;
+}
+
+// One probe of one segment's table -> the entry or nullptr. `compares`
+// counts the slots whose tag matched and whose key bytes were read.
+inline const Entry* seg_find(const Seg& s, uint64_t h, const uint8_t* key,
+                             uint64_t klen, int64_t& compares) {
+    const uint32_t tag = slot_tag(s, h);
+    const uint32_t idx_mask = (1u << s.idx_bits) - 1;
+    for (uint64_t at = h & s.mask;; at = (at + 1) & s.mask) {
+        const uint32_t v = s.table[at];
+        if (v == 0) return nullptr;
+        if ((v >> s.idx_bits) != tag) continue;
+        const Entry& e = s.entries[(v & idx_mask) - 1];
+        compares++;
+        if (e.key_len == klen && std::memcmp(e.key, key, klen) == 0) return &e;
+    }
 }
 
 }  // namespace
@@ -135,6 +202,7 @@ void* lsm_seg_open(const char* path) {
             }
         }
     }
+    ok = ok && build_table(*s);
     if (!ok) {
         ::munmap(const_cast<uint8_t*>(s->base), s->size);
         ::close(s->fd);
@@ -156,50 +224,70 @@ int64_t lsm_seg_count(void* h) {
     return h ? static_cast<int64_t>(static_cast<Seg*>(h)->entries.size()) : 0;
 }
 
+// Copy what lsm_multi_get located into `out` (at least out_offs[n_keys]
+// bytes), while the segments it read are still protected by the same
+// in-flight hold.
+void lsm_copy(const uint8_t* const* srcs, const int64_t* out_offs,
+              int64_t n_keys, uint8_t* out) {
+    for (int64_t i = 0; i < n_keys; i++) {
+        const int64_t len = out_offs[i + 1] - out_offs[i];
+        if (len > 0) std::memcpy(out + out_offs[i], srcs[i], len);
+    }
+}
+
 // Batched replace-strategy point gets over a NEWEST-FIRST segment list.
 //   keys/key_offs: concatenated key bytes, n_keys+1 prefix offsets; a
 //     zero-length key means "missing upstream" and stays missing.
-//   out/out_cap:   value arena; values of found keys are appended in order.
-//   out_offs:      n_keys+1 prefix offsets into out (equal offsets = miss).
-//   flags:         per key: 1 found, 0 missing (absent OR tombstoned).
-// -> total value bytes required. If > out_cap nothing useful was written
-// and the caller retries with a larger arena; the search work is the cheap
-// part, the copy is what is skipped.
+//   srcs:     per key: where its value lives in a segment's mapping
+//             (undefined for a miss).
+//   out_offs: n_keys+1 prefix sums of the found values' lengths: where
+//             each value goes in the arena (equal offsets = miss or empty
+//             value).
+//   flags:    per key: 1 found, 0 missing (absent OR tombstoned).
+//   stats:    {segment probes, key compares} of this call.
+//   out/out_cap: the caller's arena. Every key is located first; the
+//             values are copied only if all of them fit.
+// -> total value bytes (out_offs[n_keys]). If > out_cap nothing was copied:
+// the caller brings an arena that large to lsm_copy, which needs no search.
 int64_t lsm_multi_get(void** segs, int64_t n_segs, const uint8_t* keys,
-                      const int64_t* key_offs, int64_t n_keys, uint8_t* out,
-                      int64_t out_cap, int64_t* out_offs, int8_t* flags) {
-    int64_t need = 0;
-    int64_t wrote = 0;
-    bool fits = true;
+                      const int64_t* key_offs, int64_t n_keys,
+                      const uint8_t** srcs, int64_t* out_offs, int8_t* flags,
+                      int64_t* stats, uint8_t* out, int64_t out_cap) {
+    int64_t total = 0, probes = 0, compares = 0;
     out_offs[0] = 0;
     for (int64_t i = 0; i < n_keys; i++) {
         const uint8_t* key = keys + key_offs[i];
         const uint64_t klen = static_cast<uint64_t>(key_offs[i + 1] - key_offs[i]);
         flags[i] = 0;
+        srcs[i] = nullptr;
         if (klen > 0) {
+            const uint64_t h = hash_key(key, klen);
             for (int64_t si = 0; si < n_segs; si++) {
                 const Seg& s = *static_cast<Seg*>(segs[si]);
-                const int64_t e = seg_find(s, key, klen);
-                if (e < 0) continue;
-                const Entry& ent = s.entries[static_cast<size_t>(e)];
+                probes++;
+                const Entry* ent = seg_find(s, h, key, klen, compares);
+                if (ent == nullptr) continue;
                 // a tombstone in a newer segment shadows older values
-                if (ent.len == static_cast<uint64_t>(kTombLen) &&
-                    std::memcmp(s.base + ent.off, kTomb, kTombLen) == 0)
+                if (ent->len == static_cast<uint64_t>(kTombLen) &&
+                    std::memcmp(s.base + ent->off, kTomb, kTombLen) == 0)
                     break;
-                need += static_cast<int64_t>(ent.len);
-                if (fits && wrote + static_cast<int64_t>(ent.len) <= out_cap) {
-                    std::memcpy(out + wrote, s.base + ent.off, ent.len);
-                    wrote += static_cast<int64_t>(ent.len);
-                    flags[i] = 1;
-                } else {
-                    fits = false;
-                }
+                srcs[i] = s.base + ent->off;
+                total += static_cast<int64_t>(ent->len);
+                flags[i] = 1;
                 break;
             }
         }
-        out_offs[i + 1] = wrote;
+        out_offs[i + 1] = total;
     }
-    return need;
+    stats[0] = probes;
+    stats[1] = compares;
+    if (total <= out_cap) lsm_copy(srcs, out_offs, n_keys, out);
+    return total;
+}
+
+// The table's hash of a key (tests search it for colliding keys).
+uint64_t lsm_key_hash(const uint8_t* key, int64_t len) {
+    return hash_key(key, static_cast<uint64_t>(len));
 }
 
 }  // extern "C"

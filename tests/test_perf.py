@@ -242,7 +242,12 @@ def test_perf_window_summary_and_clear():
         w.record_dispatch(_stamped_shape(), rows=16)
     w.note_phase("queue_wait", 1.2)
     w.note_phase("scatter", 0.3)
+    assert "point_get" not in w.summary()  # no native point-get call yet
+    w.note_point_get(2560, 2560, 2560, 1)
+    w.note_point_get(2560, 19000, 2570, 0)
     s = w.summary()
+    assert s["point_get"] == {"keys": 5120, "segment_probes": 21560,
+                              "key_compares": 5130, "arena_grows": 1}
     assert s["dispatches"] == 4
     assert s["rows"] == 64
     assert 0.0 < s["duty_cycle"] <= 1.0
@@ -259,6 +264,7 @@ def test_perf_window_summary_and_clear():
     w.clear()
     s2 = w.summary()
     assert s2["dispatches"] == 0 and s2["duty_cycle"] == 0.0
+    assert "point_get" not in s2
     assert s2["dispatches_lifetime"] == 4  # lifetime survives clear
 
 
@@ -584,6 +590,8 @@ def test_disabled_serving_path_constructs_no_perf_objects(tmp_path,
     # the capture log and the profiler's annotations ride the same switch
     monkeypatch.setattr(perf.PerfWindow, "note_interval",
                         spy("PerfWindow.note_interval"))
+    monkeypatch.setattr(perf.PerfWindow, "note_point_get",
+                        spy("PerfWindow.note_point_get"))
     monkeypatch.setattr(tracing, "Phase", spy("Phase"))
     monkeypatch.setattr(tracing, "_TraceMe", spy("TraceAnnotation"))
     try:

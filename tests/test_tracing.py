@@ -563,6 +563,11 @@ def test_grpc_batch_trace_is_a_waterfall_with_decode_and_encode(tmp_path):
                 "entry.decode", "dispatch", "entry.encode"]
             assert app.perf_window.summary()["phases"]["decode"][
                 "samples"] == 2
+            # the hydrate was two native point-get calls of 4 x K keys
+            # (doc id -> uuid, uuid -> image), every one a hit
+            pg = app.perf_window.summary()["point_get"]
+            assert pg["keys"] == 2 * 4 * K
+            assert pg["keys"] <= min(pg["key_compares"], pg["segment_probes"])
             # one slot with an offset: the raw lane reads that off the
             # request's bytes and declines, the general path decodes and
             # serves, and the ledger takes that one sample, not two

@@ -1135,7 +1135,10 @@ class Shard:
 
     def hydrate_raw_packed(self, ids, dists):
         """Packed twin of _hydrate_batch: docid -> uuid -> image entirely in
-        buffer space; one call's value arena IS the next call's key buffer."""
+        buffer space; one call's value arena IS the next call's key buffer.
+        The images are a view of this thread's arena: build the reply from
+        them before this thread hydrates again
+        (lsm_native.multi_get_packed has the rule)."""
         dists = np.asarray(dists, dtype=np.float32)
         ids = np.asarray(ids)
         valid = ~np.isinf(dists)

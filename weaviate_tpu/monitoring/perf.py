@@ -13,6 +13,9 @@ What the device does is read from a profiler capture and nowhere else
   device dispatch, integrated from [enqueue-start, fetch-end] intervals.
   It is the lane controller's sensor (``control_signals``), not a device
   measurement: the chip's own busy share comes from a capture;
+- the **point-get counters**: what the native LSM point-get plane did
+  (storage/lsm_native.py; `hydrate` is two such calls a batch): keys asked,
+  segment tables probed, key bytes compared, arenas grown;
 - the **capture log**: while ``profiling.device_trace`` has a profiler
   session open, every closed host phase is kept as ``(name, thread id,
   start_ns, end_ns)`` on ``time.perf_counter_ns``, anchored at the stamp
@@ -152,6 +155,9 @@ class PerfWindow:
         # _PHASE_SAMPLES_MAX) on top of the time-horizon eviction
         self._phase: dict[str, deque] = {
             p: deque(maxlen=_PHASE_SAMPLES_MAX) for p in PHASES}
+        # (t_mono, keys, segment_probes, key_compares, arena_grows) per
+        # native point-get call, count-capped like the phases
+        self._point_get: deque = deque(maxlen=_PHASE_SAMPLES_MAX)
         self._duty = DutyCycle(self.window_s)
         self._rows = 0  # running sum over the live window
         self._first_entry: Optional[float] = None
@@ -235,6 +241,17 @@ class PerfWindow:
             while d and d[0][0] < horizon:
                 d.popleft()
 
+    def note_point_get(self, keys: int, segment_probes: int,
+                       key_compares: int, arena_grows: int) -> None:
+        """One call of the native point-get plane, as counted in C."""
+        now = time.monotonic()
+        with self._lock:
+            d = self._point_get
+            d.append((now, keys, segment_probes, key_compares, arena_grows))
+            horizon = now - self.window_s
+            while d[0][0] < horizon:
+                d.popleft()
+
     def note_interval(self, name: str, start_ns: int, end_ns: int,
                       tid: Optional[int] = None) -> None:
         """One closed host phase on ``time.perf_counter_ns``; `tid` is the
@@ -307,7 +324,7 @@ class PerfWindow:
         horizon = now - self.window_s
         while self._entries and self._entries[0][0] < horizon:
             self._rows -= self._entries.popleft()[2]
-        for d in self._phase.values():
+        for d in (*self._phase.values(), self._point_get):
             while d and d[0][0] < horizon:
                 d.popleft()
 
@@ -350,6 +367,7 @@ class PerfWindow:
             self._entries.clear()
             for d in self._phase.values():
                 d.clear()
+            self._point_get.clear()
             self._duty = DutyCycle(self.window_s)
             self._rows = 0
             self._first_entry = None
@@ -372,6 +390,7 @@ class PerfWindow:
                 tiers[tier] = tiers.get(tier, 0) + 1
                 violations += viol
             total_dispatches = self._total_dispatches
+            point_get = [sum(c) for c in list(zip(*self._point_get))[1:]]
         out: dict = {
             "window_s": self.window_s,
             "observed_s": round(span, 3),
@@ -397,6 +416,16 @@ class PerfWindow:
                 if total_accounted > 0.0 else None,
             }
         out["phases"] = phases
+        if point_get:
+            # the native LSM point-get plane over the window: probes a key
+            # is how many segments a lookup had to ask (what a bloom filter
+            # or a fence would cut), compares a probe is about 1 for the
+            # probe that hits and 0 for one that meets an empty slot, and
+            # arena_grows is 0 once every serving thread has seen its
+            # largest batch
+            out["point_get"] = dict(zip(
+                ("keys", "segment_probes", "key_compares", "arena_grows"),
+                point_get))
         out["tiers"] = dict(sorted(tiers.items(), key=lambda kv: -kv[1]))
         # invariant violations over the window
         # (costmodel.fused_invariant_ok): every dispatch translates on the
@@ -460,6 +489,15 @@ def note_phase(name: str, ms: float) -> None:
     w = _window
     if w is not None:
         w.note_phase(name, ms)
+
+
+def note_point_get(keys: int, segment_probes: int, key_compares: int,
+                   arena_grows: int) -> None:
+    """`PerfWindow.note_point_get` on the installed window; one comparison
+    while the plane is down."""
+    w = _window
+    if w is not None:
+        w.note_point_get(keys, segment_probes, key_compares, arena_grows)
 
 
 def note_interval(name: str, start_ns: int, end_ns: int,

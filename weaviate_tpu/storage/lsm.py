@@ -982,7 +982,9 @@ class Bucket:
         array; zero-length = missing upstream) -> (value arena, offsets,
         flags) straight from the native plane. None whenever the packed
         path cannot serve EXACTLY (memtable non-empty, no segments, native
-        unavailable) — the caller falls back to the general path."""
+        unavailable) — the caller falls back to the general path. The
+        values live in the calling thread's arena: valid until that
+        thread's next packed call (lsm_native.multi_get_packed)."""
         assert self.strategy == STRATEGY_REPLACE
         from weaviate_tpu.storage import lsm_native
 

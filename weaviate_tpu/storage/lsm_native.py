@@ -1,9 +1,18 @@
 """ctypes bridge to the native LSM point-get plane (native/lsm_get.cpp).
 
-Batched replace-strategy point lookups over the mmap'd segment files in ONE
-C call: the GIL is released for its duration (ctypes semantics), so
-concurrent request hydrations overlap instead of serializing, and the
-per-key cost drops from a Python bisect to a bytewise binary search.
+Batched replace-strategy point lookups over the mmap'd segment files with
+the GIL released (ctypes semantics), so concurrent request hydrations
+overlap instead of serializing. A batch is one C call (`lsm_multi_get`):
+every key is located once (hashed once, one probe of each segment's hash
+table, newest first), the values' total size is then known, and they are
+copied once into an arena this thread already holds. Only when that arena
+is too small does the bridge grow it and ask for the copy alone
+(`lsm_copy`): nothing is searched twice and no multi-megabyte buffer is
+mapped afresh a call.
+
+What a call did is counted in C and handed to the perf window
+(`/debug/perf` `point_get`: `keys`, `segment_probes`, `key_compares`,
+`arena_grows`) while the tracer is up.
 
 Reference analog: the compiled lsmkv segment readers under the batched
 hydration seam entities/storobj/storage_object.go:211.
@@ -22,6 +31,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 from weaviate_tpu import _native
+from weaviate_tpu.monitoring import perf
 
 _lib = None
 _lib_failed = False
@@ -46,9 +56,17 @@ def _load() -> Optional[ctypes.CDLL]:
                 ctypes.POINTER(ctypes.c_void_p), ctypes.c_int64,
                 ctypes.POINTER(ctypes.c_ubyte), ctypes.POINTER(ctypes.c_int64),
                 ctypes.c_int64,
+                ctypes.POINTER(ctypes.c_void_p), ctypes.POINTER(ctypes.c_int64),
+                ctypes.POINTER(ctypes.c_int8), ctypes.POINTER(ctypes.c_int64),
                 ctypes.POINTER(ctypes.c_ubyte), ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int8),
             ]
+            lib.lsm_copy.restype = None
+            lib.lsm_copy.argtypes = [
+                ctypes.POINTER(ctypes.c_void_p), ctypes.POINTER(ctypes.c_int64),
+                ctypes.c_int64, ctypes.POINTER(ctypes.c_ubyte),
+            ]
+            lib.lsm_key_hash.restype = ctypes.c_uint64
+            lib.lsm_key_hash.argtypes = [ctypes.c_char_p, ctypes.c_int64]
             _lib = lib
         except Exception as e:  # noqa: BLE001 — the Python reader serves
             _lib_failed = True
@@ -100,15 +118,44 @@ def seg_close(segment) -> None:
     segment._native_handle = None
 
 
+_ARENA_MIN = 1 << 16
+
+
+class _Arena(threading.local):
+    """This thread's value arena: it grows to the largest batch the thread
+    has served and is then reused. A fresh array a call costs the served
+    path a fifth of its rate (PERF.md, PR 28): megabytes of pages mapped,
+    faulted in and unmapped again by every serving thread at once."""
+
+    def __init__(self):
+        self.buf = np.empty(0, dtype=np.uint8)
+
+    def grow(self, need: int) -> np.ndarray:
+        cap = _ARENA_MIN
+        while cap < need:
+            cap *= 2
+        self.buf = np.empty(cap, dtype=np.uint8)
+        return self.buf
+
+
+_arena = _Arena()
+
+
 def multi_get_packed(
-    segments_newest_first: Sequence, key_buf: bytes, key_offs: np.ndarray
+    segments_newest_first: Sequence, key_buf, key_offs: np.ndarray
 ) -> Optional[tuple[np.ndarray, np.ndarray, np.ndarray]]:
     """Packed-buffer batched gets: keys at key_offs[i]..key_offs[i+1] in
-    key_buf (zero-length = missing upstream). -> (value arena uint8 array,
-    offsets int64 [n+1], flags int8 [n]), or None => Python fallback. The
-    arena layout feeds the packed reply builder and call-chaining (one
-    call's values are the next call's keys) without any per-value Python
-    objects. Caller owns segment lifetime."""
+    key_buf (bytes or uint8 array; zero-length = missing upstream). ->
+    (values uint8 array, offsets int64 [n+1], flags int8 [n]), or None =>
+    Python fallback. The layout feeds the packed reply builder and
+    call-chaining (one call's values are the next call's keys) without any
+    per-value Python objects. Caller owns segment lifetime.
+
+    LIFETIME: the values are a view of an arena this thread keeps, valid
+    until this thread's next packed call. That call may take them as its
+    key buffer (every key is located before the first value is copied
+    over them); whoever else needs them uses them, or copies them out,
+    before it, and never hands them to another thread."""
     lib = _load()
     if lib is None:
         return None
@@ -122,21 +169,23 @@ def multi_get_packed(
     key_offs = np.ascontiguousarray(key_offs, dtype=np.int64)
     out_offs = np.empty(n + 1, dtype=np.int64)
     flags = np.empty(n, dtype=np.int8)
+    srcs = np.empty(n, dtype=np.uintp)
+    stats = np.empty(2, dtype=np.int64)
     seg_arr = (ctypes.c_void_p * len(handles))(*handles)
-    cap = max(1 << 16, n * 1024)
-    key_ptr = _as_u8_ptr(key_buf)
-    for _ in range(2):
-        out = np.empty(cap, dtype=np.uint8)
-        need = lib.lsm_multi_get(
-            seg_arr, len(handles), key_ptr,
-            key_offs.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)), n,
-            out.ctypes.data_as(ctypes.POINTER(ctypes.c_ubyte)), cap,
-            out_offs.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            flags.ctypes.data_as(ctypes.POINTER(ctypes.c_int8)))
-        if need <= cap:
-            break
-        cap = int(need)
-    return out, out_offs, flags
+    p_u8, p_i64 = ctypes.POINTER(ctypes.c_ubyte), ctypes.POINTER(ctypes.c_int64)
+    srcs_ptr = srcs.ctypes.data_as(ctypes.POINTER(ctypes.c_void_p))
+    offs_ptr = out_offs.ctypes.data_as(p_i64)
+    arena, grew = _arena.buf, 0
+    need = lib.lsm_multi_get(
+        seg_arr, len(handles), _as_u8_ptr(key_buf),
+        key_offs.ctypes.data_as(p_i64), n, srcs_ptr, offs_ptr,
+        flags.ctypes.data_as(ctypes.POINTER(ctypes.c_int8)),
+        stats.ctypes.data_as(p_i64), arena.ctypes.data_as(p_u8), arena.size)
+    if need > arena.size:   # located, not copied: the copy alone, no search
+        arena, grew = _arena.grow(need), 1
+        lib.lsm_copy(srcs_ptr, offs_ptr, n, arena.ctypes.data_as(p_u8))
+    perf.note_point_get(n, int(stats[0]), int(stats[1]), grew)
+    return arena[:need], out_offs, flags
 
 
 def multi_get(segments_newest_first: Sequence,
