@@ -5,6 +5,13 @@ every reply against the stored exact ground truth, every returned distance
 against the reference's distance of the row that was returned, every reply
 with k results. The rows come from the state directory's own copy of the
 corpus (written by the build from the seed), never from the server.
+
+Under a filter the ground truth is the exact top k of the rows the query's
+filter allows (-1 pads it where fewer than k are allowed): recall counts the
+entries that exist, a reply is short only with fewer than min(k, allowed)
+results, and a returned row that the filter does not allow, by the
+dataset's own reading, is counted on its own: a filter is a guarantee, not
+an approximation. Without filters every number is what it was.
 """
 
 from __future__ import annotations
@@ -24,29 +31,37 @@ DIST_ATOL = {"cosine": 1e-5}
 
 
 def recall_at_k(got_ids: np.ndarray, want_ids: np.ndarray) -> float:
-    """Mean over replies of |got ∩ want| / k. got_ids, want_ids: [R, k]
-    (-1 pads a short reply and never matches)."""
-    if len(got_ids) == 0:
+    """|got ∩ want| over the ground-truth entries that exist, all replies
+    together: the mean over replies of |got ∩ want| / k where every query
+    has k. got_ids, want_ids: [R, k] (-1 pads a short reply or a ground
+    truth of fewer than k allowed rows, and never matches)."""
+    wanted = int((want_ids >= 0).sum())
+    if len(got_ids) == 0 or wanted == 0:
         return 0.0
     hits = (got_ids[:, :, None] == want_ids[:, None, :]) & \
         (got_ids[:, :, None] >= 0)
-    return float(hits.any(2).sum() / want_ids.size)
+    return float(hits.any(2).sum() / wanted)
 
 
 def check_window(reference, metric: str, k: int, rows, pool: np.ndarray,
                  gt_ids: np.ndarray, qidx: np.ndarray, got_ids: np.ndarray,
-                 got_dists: np.ndarray) -> dict:
+                 got_dists: np.ndarray, allowed_pairs=None) -> dict:
     """qidx [R]: which pool query each reply answers; got_ids/got_dists
     [R, k], padded with -1 / nan where a reply was short. `rows` is the
-    corpus (an array or memmap [N, dim]). -> recall and what failed."""
+    corpus (an array or memmap [N, dim]). `allowed_pairs(queries, rows)`
+    -> bool per (pool query, row id) pair, the dataset's reading of each
+    query's filter; None where no query has one. -> recall and what
+    failed."""
     n = len(qidx)
     out = {"replies": int(n), "recall": 0.0, "short_replies": 0,
-           "bad_distances": 0, "unknown_rows": 0, "first_bad": None}
+           "bad_distances": 0, "unknown_rows": 0, "disallowed_rows": 0,
+           "first_bad": None, "first_disallowed": None}
     if n == 0:
         return out
-    short = (got_ids < 0).any(1)
+    want_ids = gt_ids[qidx]
+    short = (got_ids >= 0).sum(1) < (want_ids >= 0).sum(1)
     out["short_replies"] = int(short.sum())
-    out["recall"] = recall_at_k(got_ids, gt_ids[qidx])
+    out["recall"] = recall_at_k(got_ids, want_ids)
     # each distinct (query, row) pair is held to the reference once
     valid = (got_ids >= 0) & (got_ids < rows.shape[0])
     out["unknown_rows"] = int(((got_ids >= rows.shape[0])).sum())
@@ -57,6 +72,12 @@ def check_window(reference, metric: str, k: int, rows, pool: np.ndarray,
     _, first, inverse = np.unique(key, return_index=True,
                                   return_inverse=True)
     uq, ur = qq[first], rr[first]
+    if allowed_pairs is not None and len(first):
+        off = ~np.asarray(allowed_pairs(uq, ur), bool)[inverse]
+        out["disallowed_rows"] = int(off.sum())
+        if off.any():
+            j = int(np.argmax(off))
+            out["first_disallowed"] = {"query": int(qq[j]), "row": int(rr[j])}
     order = np.argsort(ur, kind="stable")          # read the corpus in order
     true_u = np.empty(len(first), np.float32)
     step = 65_536

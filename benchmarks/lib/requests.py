@@ -1,11 +1,16 @@
 """Requests as a client makes them, from a traffic file's parameters.
 
 One builder serves every mix: `request` (Search | BatchSearch), `width`
-(queries in a BatchSearch), `limit`, `where` (a GraphQL-grammar filter,
-sent as `where_json`), `class` (another class than the configuration's),
-`write_share` (that share of requests is a REST batch write that puts
-`write_batch` stored rows again, unchanged: the write path runs and the
+(queries in a BatchSearch), `limit`, `class` (another class than the
+configuration's), `write_share` (that share of requests is a REST batch
+write that puts `write_batch` stored rows again, with the properties the
+configuration's dataset gives them, unchanged: the write path runs and the
 exact answers stay what they were), `timeout_s` (the request's deadline).
+Every pool query goes out with its own filter (`filters`, one GraphQL-grammar
+`where` or None a pool query, sent as `where_json` in a Search and in every
+slot of a BatchSearch): the filter plan the traffic names (`where`, one
+constant filter; `filter_plan`, a plan of the dataset's), resolved by
+benchmarks/build.py `plan_filters`.
 """
 
 from __future__ import annotations
@@ -32,7 +37,8 @@ class Request:
 
 
 class RequestBuilder:
-    def __init__(self, cfg: dict, traffic: dict, pool: np.ndarray, rows=None):
+    def __init__(self, cfg: dict, traffic: dict, pool: np.ndarray,
+                 filters: list, rows=None, dataset=None):
         from weaviate_tpu.grpcapi import weaviate_pb2 as pb
 
         self.pb = pb
@@ -43,11 +49,12 @@ class RequestBuilder:
             traffic.get("request", "Search")]
         if self.kind == "search" and self.width != 1:
             raise ValueError("a Search carries one query: width must be 1")
-        where = traffic.get("where")
-        self.where_json = json.dumps(where) if where else ""
+        if len(filters) != len(pool):
+            raise ValueError(f"{len(filters)} filters for {len(pool)} queries")
+        self.where_json = [json.dumps(w) if w else "" for w in filters]
         self.write_share = float(traffic.get("write_share", 0.0))
         self.write_batch = int(traffic.get("write_batch", 100))
-        self.buckets = int(cfg["filter_buckets"])
+        self.cfg, self.dataset = cfg, dataset
         self.pool, self.rows = pool, rows
         self._pool_lists = None
 
@@ -57,18 +64,20 @@ class RequestBuilder:
         req = self.pb.SearchRequest(
             class_name=self.cls, limit=self.limit,
             near_vector=self.pb.NearVectorParams(vector=self._pool_lists[q]))
-        if self.where_json:
-            req.where_json = self.where_json
+        if self.where_json[q]:
+            req.where_json = self.where_json[q]
         return req
 
     def draw(self, rng: np.random.Generator) -> Request:
         """The next request of the mix, from the seed's stream."""
         if self.write_share > 0.0 and rng.random() < self.write_share:
-            ids = rng.integers(0, self.rows.shape[0], self.write_batch)
+            ids = np.unique(rng.integers(0, self.rows.shape[0],
+                                         self.write_batch))
+            props = self.dataset.properties(self.cfg, ids)
             objs = [{"class": self.cls, "id": gen.uuid_of(int(i)),
-                     "properties": {"bucket": int(i) % self.buckets},
+                     "properties": p,
                      "vector": np.asarray(self.rows[int(i)]).tolist()}
-                    for i in ids]
+                    for i, p in zip(ids, props)]
             return Request("write", {"objects": objs}, np.empty(0, np.int64))
         qidx = rng.integers(0, len(self.pool), self.width)
         if self.kind == "search":

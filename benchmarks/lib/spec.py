@@ -11,6 +11,12 @@ adds one by adding files and one entry, and edits nothing that is here.
     benchmarks/generators/<generator>.py    run(ctx) -> window record
     benchmarks/readers/<reader>.py          read(sources, **args) -> number
     benchmarks/references/<reference>.py    the plain reference of a config
+    benchmarks/datasets/<dataset>.py        what a deployment's rows and queries
+                                            carry beside their vectors: the
+                                            rows' properties, each pool query's
+                                            filter, and which rows a filter
+                                            allows (datasets/buckets.py says
+                                            what a dataset is)
 """
 
 from __future__ import annotations
@@ -27,6 +33,9 @@ ROOT = os.path.dirname(HERE)                                        # the checko
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+# a configuration without a `dataset` key has the rows every configuration
+# had before datasets were files: one int property, no filter a query
+DEFAULT_DATASET = "buckets"
 
 
 class SpecError(Exception):
@@ -145,6 +154,10 @@ class Spec:
     def reference(self, name: str):
         return self._module("references", name)
 
+    def dataset(self, cfg: dict):
+        """The dataset module of a configuration (its `dataset` key)."""
+        return self._module("datasets", cfg.get("dataset", DEFAULT_DATASET))
+
     # -- the whole set loads and every name passes the rule ------------------
 
     def validate(self) -> None:
@@ -157,6 +170,10 @@ class Spec:
                 raise SpecError(f"{w['name']}: cell asks {w['chips']} chips, "
                                 f"config {cfg['chips']}")
             self.reference(cfg["reference"])
+            dataset = self.dataset(cfg)
+            for fn in ("properties", "filter_plan", "allowed"):
+                if not hasattr(dataset, fn):
+                    raise SpecError(f"dataset of {cfg['name']} has no {fn}()")
             traffic = self.traffic(check_name(w["traffic"]))
             self.generator(traffic["generator"])
             names = {m["name"] for m in self.metrics_for(w["name"], "end_to_end")}

@@ -3,7 +3,8 @@
 Independent of the program under test: numpy only, the textbook distance of
 every (query, row) pair that can matter. `pair_distances` is the distance
 the comparison holds a reply to; `TopK` is the ground truth, fed one chunk of
-rows at a time so the corpus is never held twice.
+rows at a time so the corpus is never held twice, each chunk with the mask
+of the rows every query's filter allows.
 
 Metrics as the program names them: `l2-squared` = |r - q|^2, `cosine` =
 1 - r.q / (|r||q|), `dot` = -r.q.
@@ -56,23 +57,38 @@ class TopK:
         nq = len(self.q)
         self.ids = np.full((nq, k), -1, np.int64)
         self.dists = np.full((nq, k), np.inf, np.float32)
+        self.allowed = np.zeros(nq, np.int64)   # rows each query could take
 
-    def update(self, first_id: int, rows: np.ndarray) -> None:
+    def update(self, first_id: int, rows: np.ndarray,
+               allowed: np.ndarray | None = None) -> None:
+        """One chunk of the corpus. `allowed` ([Q, rows] bool, or None for
+        no filter): the rows each query's filter lets through; a row that
+        is not allowed is never a neighbour of that query."""
         rows = np.ascontiguousarray(rows, np.float32)
         coarse = _coarse(self.metric, rows, self.q)
+        if allowed is None:
+            self.allowed += rows.shape[0]
+        else:
+            self.allowed += allowed.sum(1)
+            coarse = np.where(allowed, coarse, np.inf)
         kth = self.dists[:, -1]
-        if np.isinf(kth).any():
+        open_q = np.flatnonzero(np.isinf(kth))
+        # every pair that could beat the current k-th best, with slack for
+        # the coarse distance's rounding; a query with no k-th best yet
+        # matches nothing here and is served below
+        with np.errstate(invalid="ignore"):
+            bar = np.where(np.isinf(kth), -np.inf,
+                           kth + 1e-3 * np.abs(kth) + 1e-3)
+        qi, ri = np.nonzero(coarse <= bar[:, None])
+        if open_q.size:
             # nothing to prune with yet: the k best of this chunk by the
             # coarse distance, with room for its rounding
             take = min(4 * self.k, rows.shape[0])
-            part = np.argpartition(coarse, take - 1, axis=1)[:, :take]
-            qi = np.repeat(np.arange(len(self.q)), take)
-            ri = part.ravel()
-        else:
-            # every pair that could beat the current k-th best, with slack
-            # for the coarse distance's rounding
-            slack = 1e-3 * np.abs(kth) + 1e-3
-            qi, ri = np.nonzero(coarse <= (kth + slack)[:, None])
+            part = np.argpartition(coarse[open_q], take - 1, axis=1)[:, :take]
+            oq, orow = np.repeat(open_q, take), part.ravel()
+            keep = np.isfinite(coarse[oq, orow])
+            qi = np.concatenate([qi, oq[keep]])
+            ri = np.concatenate([ri, orow[keep]])
         if qi.size == 0:
             return
         exact = pair_distances(self.metric, rows[ri], self.q[qi])
@@ -89,5 +105,7 @@ class TopK:
             self.dists[q], self.ids[q] = d[keep], i[keep]
 
     def result(self) -> tuple[np.ndarray, np.ndarray]:
-        """([Q, k] row ids, [Q, k] exact distances), nearest first."""
+        """([Q, k] row ids, [Q, k] exact distances), nearest first; where a
+        query was allowed fewer than k rows, padded with -1 and inf
+        (`self.allowed` has how many it was allowed)."""
         return self.ids, self.dists

@@ -9,10 +9,12 @@ program's defaults; this process is a client and never initialises a JAX
 backend. Set-up (recovery, backend start, cache load, warm-up of the cell's own
 shapes) is timed as `setup_s`; then the cell's traffic runs for `--seconds`;
 then the server is stopped with SIGTERM and every reply of the window is held
-to the configuration's plain reference. The last line of stdout is the result
-object; everything else the run saw is on the `observations:` line before it
-and in `chiprun_out/bench/`. No accelerator, or fewer chips than the cell
-asks for: exit code 1 and no result line.
+to the configuration's plain reference, under the filter its query carried.
+The last line of stdout is the result object, whose last key `compared` has
+every number `correct` was decided from beside its limit (the same lines end
+standard error); everything else the run saw is on the `observations:` line
+before it and in `chiprun_out/bench/`. No accelerator, or fewer chips than
+the cell asks for: exit code 1 and no result line.
 """
 
 from __future__ import annotations
@@ -38,6 +40,7 @@ from benchmarks.lib import check, costs, stats, xplane  # noqa: E402
 from benchmarks.lib import server as srv  # noqa: E402
 from benchmarks.lib.requests import Caller, RequestBuilder, parse_reply  # noqa: E402
 from benchmarks.lib.spec import ROOT, Spec  # noqa: E402
+from benchmarks.readers import host_gaps  # noqa: E402
 
 STATE_ROOT = os.path.join(ROOT, "data", "bench")     # /data/ is git-ignored
 OUT_DIR = os.path.join(ROOT, "chiprun_out", "bench")
@@ -110,6 +113,29 @@ def ensure_state(spec: Spec, cfg: dict, state: str,
     if not builder.state_matches(state, cfg):
         raise NoResult(f"build left no matching manifest in {state}")
     return m, time.monotonic() - t0
+
+
+def ensure_plan(spec: Spec, cfg: dict, traffic: dict, state: str,
+                filters: list):
+    """The ground truth of the traffic's filter plan, computed once a state
+    directory by the build's numpy child (no chip, no JAX) and kept there.
+    -> (gt_ids, rows each query is allowed or None)."""
+    truth = builder.load_truth(state, filters)
+    if truth is None:
+        log(f"[state] {state}: no ground truth of {traffic['name']}'s "
+            "filter plan yet, computing")
+        t0 = time.monotonic()
+        rc = subprocess.call(
+            [sys.executable, "-m", "benchmarks.build", "--config",
+             cfg["name"], "--state", state, "--ground-truth", "--traffic",
+             traffic["name"]] + spec.as_args(),
+            cwd=ROOT, env=srv.child_env(), timeout=BUILD_LIMIT_S)
+        truth = builder.load_truth(state, filters)
+        if rc != 0 or truth is None:
+            raise NoResult(f"ground-truth child exited {rc}")
+        log(f"[state] filter plan's ground truth in "
+            f"{time.monotonic() - t0:.0f}s")
+    return truth
 
 
 def check_identity(meta: dict, chips: int, expect_platform: str) -> dict:
@@ -233,34 +259,58 @@ def reduce_window(window: dict, k: int) -> dict:
             "dists": cat(dists, (0, k), np.float32)}
 
 
-def why_not_correct(answers: dict, w: dict, k: int, prom, health: dict,
-                    clean: bool, live_ok: bool, live_note: str) -> list[str]:
-    """Every reason the run is not `correct`; empty when it is."""
+def comparisons(answers: dict, w: dict, k: int, prom, health: dict,
+                clean: bool, live: int, acknowledged: int, rows: int,
+                tail_samples: tuple[int, int] | None) -> list[tuple]:
+    """Every number `correct` is decided from, beside its limit:
+    (name, value, limit as text, whether it holds, the reason if not)."""
     falls = {json.dumps(lab, sort_keys=True): v for name, lab, v in prom
              if name == "weaviate_device_fallback_total" and v > 0}
     breaker = [v for name, _, v in prom if name == "weaviate_breaker_state"]
     rejected = {name: kern["rejected_shapes"]
                 for name, kern in (health.get("kernels") or {}).items()
                 if kern.get("rejected") or kern.get("broken")}
-    checks = (
-        (not live_ok, f"not durable: {live_note}"),
-        (answers["recall"] < check.RECALL_BAR,
+    rows_ok = live == acknowledged == rows
+    out = [
+        ("live_rows", live, f"== {rows}", rows_ok,
+         f"not durable: live {live}, acknowledged {acknowledged}, "
+         f"rows {rows}"),
+        ("recall", answers["recall"], f">= {check.RECALL_BAR}",
+         answers["recall"] >= check.RECALL_BAR,
          f"recall {answers['recall']:.4f} < {check.RECALL_BAR}"),
-        (answers["bad_distances"] > 0,
+        ("bad_distances", answers["bad_distances"], "== 0",
+         answers["bad_distances"] == 0,
          f"{answers['bad_distances']} distances off the reference: "
          f"{answers['first_bad']}"),
-        (answers["unknown_rows"] > 0,
+        ("unknown_rows", answers["unknown_rows"], "== 0",
+         answers["unknown_rows"] == 0,
          f"{answers['unknown_rows']} results name rows that do not exist"),
-        (answers["short_replies"] > 0,
-         f"{answers['short_replies']} replies with fewer than {k}"),
-        (bool(falls), f"fallback plane answered: {falls}"),
-        (breaker != [0.0], f"breaker state {breaker}"),
-        (bool(rejected), f"rejected kernel shapes: {rejected}"),
-        (not clean, "server did not exit 0 with 'shutdown complete'"),
-        (w["failed"] > 0,
+        ("short_replies", answers["short_replies"], "== 0",
+         answers["short_replies"] == 0,
+         f"{answers['short_replies']} replies with fewer than "
+         f"min({k}, rows the filter allows)"),
+        ("disallowed_rows", answers["disallowed_rows"], "== 0",
+         answers["disallowed_rows"] == 0,
+         f"{answers['disallowed_rows']} returned rows are outside their "
+         f"query's filter: {answers['first_disallowed']}"),
+        ("fallback_answers", sum(falls.values()), "== 0", not falls,
+         f"fallback plane answered: {falls}"),
+        ("breaker_state", max(breaker, default=-1.0), "== 0",
+         breaker == [0.0], f"breaker state {breaker}"),
+        ("rejected_kernel_shapes", len(rejected), "== 0", not rejected,
+         f"rejected kernel shapes: {rejected}"),
+        ("clean_shutdown", int(clean), "== 1", clean,
+         "server did not exit 0 with 'shutdown complete'"),
+        ("failed_requests", w["failed"], "== 0", w["failed"] == 0,
          f"{w['failed']} failed requests, first: {w['first_error']}"),
-        (not w["latency_s"], "no request completed"))
-    return [why for bad, why in checks if bad]
+        ("completed_requests", len(w["latency_s"]), ">= 1",
+         bool(w["latency_s"]), "no request completed"),
+    ]
+    if tail_samples is not None:
+        have, need = tail_samples
+        out.append(("tail_samples", have, f">= {need}", have >= need,
+                    f"tail: {have} samples, the percentile needs {need}"))
+    return out
 
 
 # -- one run ------------------------------------------------------------------
@@ -277,6 +327,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     cfg = spec.config(cell["config"])
     traffic = spec.traffic(cell["traffic"])
     reference = spec.reference(cfg["reference"])
+    dataset = spec.dataset(cfg)
     generator = spec.generator(traffic["generator"])
     chips, k = int(cell["chips"]), int(cfg["k"])
     cls = cfg["class"]["class"]
@@ -290,6 +341,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     log(f"[state] {state}: {manifest['disk_bytes'] / 1e9:.2f} GB on disk, "
         f"{manifest['acknowledged']} rows acknowledged"
         + (f", built in {build_s:.0f}s" if build_s else ", found"))
+    filters = builder.plan_filters(cfg, traffic, dataset)
+    gt_ids, gt_allowed = ensure_plan(spec, cfg, traffic, state, filters)
 
     os.makedirs(OUT_DIR, exist_ok=True)
     tag = f"{workload}-seed{seed}-trace{int(trace)}"
@@ -314,13 +367,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
 
         # durability: what the build acknowledged is there after a restart
         health = index_health(server, cls)
-        live_ok = health["live"] == manifest["acknowledged"] == int(cfg["rows"])
         obs["live"], obs["capacity"] = health["live"], health.get("capacity")
 
         pool = np.load(os.path.join(state, "pool.npy"))
-        gt_ids = np.load(os.path.join(state, "gt_ids.npy"))
         rows = builder.open_rows(state, int(cfg["rows"]), int(cfg["dim"]))
-        req_builder = RequestBuilder(cfg, traffic, pool, rows)
+        req_builder = RequestBuilder(cfg, traffic, pool, filters, rows,
+                                     dataset)
         ctx = Ctx(server, traffic, req_builder, seed, float(seconds))
 
         obs["warm_up"] = warm_up(ctx, spec, cache_dir)
@@ -364,12 +416,15 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
             "meta": meta,
             "cell": {"device_kind": dev["device_kind"], "chips": chips,
                      "rows": int(cfg["rows"]), "dim": int(cfg["dim"]),
-                     "batch": req_builder.width},
+                     "batch": req_builder.width,
+                     "pq_segments": ((cfg["class"].get("vectorIndexConfig")
+                                      or {}).get("pq") or {}).get("segments")},
         }
         if trace:
             sources["perf"] = server.get("/debug/perf")
             sources["traces"] = server.get("/debug/traces")
         health = index_health(server, cls)
+        sources["index"] = health
         t_stop = time.monotonic()
         rc = server.stop(STOP_LIMIT_S)
         obs["stop_s"] = time.monotonic() - t_stop
@@ -382,24 +437,30 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
 
     # -- the replies against the reference ------------------------------------
     w = reduce_window(window, k)
+    filtered = any(f is not None for f in filters)
     answers = check.check_window(
         reference, cfg["distance"], k, rows, pool, gt_ids, w["qidx"],
-        w["ids"], w["dists"])
+        w["ids"], w["dists"],
+        allowed_pairs=(lambda qq, rr: builder.allowed_pairs(
+            dataset, cfg, filters, qq, rr)) if filtered else None)
     lat_ms = [x * 1e3 for x in w["latency_s"]]
     late_ms = [x * 1e3 for x in w["late_s"]]
     tail_q = float(traffic.get("tail_percentile", 99))
-    reasons = []
-    try:
-        tail_ms = stats.percentile(lat_ms, tail_q)
-    except stats.TooFewSamples as e:
-        tail_ms = stats.percentile(lat_ms, tail_q, strict=False) \
-            if lat_ms else None
-        if window["loop"] == "open":
-            reasons.append(f"tail: {e}")
-    reasons += why_not_correct(
+    tail_ms = stats.percentile(lat_ms, tail_q, strict=False) \
+        if lat_ms else None
+    compared = comparisons(
         answers, w, k, sources["prom"], health, clean=clean,
-        live_ok=live_ok, live_note=f"live {obs['live']}, acknowledged "
-        f"{manifest['acknowledged']}, rows {cfg['rows']}")
+        live=obs["live"], acknowledged=manifest["acknowledged"],
+        rows=int(cfg["rows"]),
+        tail_samples=(len(lat_ms), stats.min_samples(tail_q))
+        if window["loop"] == "open" else None)
+    reasons = [why for _, _, _, ok, why in compared if not ok]
+    if filtered:
+        obs["filter_plan"] = {
+            "filtered_queries": sum(f is not None for f in filters),
+            "allowed_rows_min": int(gt_allowed.min()),
+            "allowed_rows_median": float(np.median(gt_allowed)),
+            "allowed_rows_max": int(gt_allowed.max())}
 
     values = {
         "setup_s": setup_s,
@@ -462,10 +523,17 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                     "xplane": found.get("summary"),
                     "notes": sources.get("notes"),
                     "perf": sources.get("perf")})
+    # beside its limit, every number `correct` was decided from: the last
+    # key of the result line and the last lines of standard error
+    result["compared"] = {name: {"value": value, "limit": limit}
+                          for name, value, limit, _, _ in compared}
     with open(os.path.join(OUT_DIR, tag + ".json"), "w") as f:
         json.dump({"observations": obs, "result": result,
                    "latency_ms": lat_ms, "late_ms": late_ms}, f)
     log("observations: " + json.dumps(obs, default=str))
+    for name, value, limit, ok, _ in compared:
+        print(f"compared: {name} {value} (limit {limit})"
+              f"{'' if ok else ' FAILS'}", file=sys.stderr, flush=True)
     return result
 
 
@@ -532,16 +600,38 @@ def reduce_trace(sources: dict, traced: dict, state: str,
             # cut); ops nest: a while's seconds include its body's
             "device_ops": [[n[:160], s] for n, s in sorted(
                 ops.items(), key=lambda kv: -kv[1])[:10]],
-            # what the host was doing in a gap is not known until the
-            # program writes TraceAnnotations: every gap is unattributed
-            "idle_gaps": [["unattributed: all gaps together",
-                           summary["window_s"] - devs[median]["busy_s"]]] + [
-                [f"unattributed: gap at +{at:.3f}s", g]
-                for g, at in devs[median]["gaps_s"][:5]]},
+            "idle_gaps": name_idle_gaps(
+                trace, (sources.get("perf") or {}).get("capture"),
+                summary["window_s"] - devs[median]["busy_s"],
+                devs[median]["gaps_s"])},
         "summary": {"window_s": summary["window_s"], "devices": {
             p: {"busy_s": d["busy_s"], "idle_pct": d["idle_pct"],
                 "op_events": d["op_events"]} for p, d in devs.items()}},
     }
+
+
+def name_idle_gaps(trace: dict, capture: dict | None, idle_s: float,
+                   longest: list) -> list:
+    """`breakdown.idle_gaps`: the median device's idle seconds by the host
+    interval of the program that was open in them (readers/host_gaps.py:
+    the innermost a thread, split over threads), largest first, then its
+    longest single gaps, each named by the interval that held most of it.
+    Without the program's capture log nothing is known of the host."""
+    if not capture or not capture.get("intervals"):
+        return [["unattributed (no capture log): all gaps together",
+                 idle_s]] + [
+            [f"unattributed: gap at +{at:.3f}s", g] for g, at in longest[:5]]
+    label = (lambda name: "no interval open" if name is None else str(name))
+    w0, _, gaps = host_gaps.idle_gaps(trace)
+    given = host_gaps.attribute(gaps, capture["intervals"])
+    out = [[label(name), ns / 1e9] for name, ns in sorted(
+        given.items(), key=lambda kv: -kv[1])[:5]]
+    for g0, g1 in sorted(gaps, key=lambda g: g[0] - g[1])[:5]:
+        inside = host_gaps.attribute([(g0, g1)], capture["intervals"])
+        most = max(inside, key=inside.get)
+        out.append([f"gap at +{(g0 - w0) / 1e9:.3f}s, mostly {label(most)}",
+                    (g1 - g0) / 1e9])
+    return out
 
 
 def run_sweep(server, spec, traffic, req_builder, generator, seed, rates,
@@ -587,13 +677,20 @@ def main(argv=None) -> int:
                     help="directory to copy the xplane and its device "
                          "events into: to read a trace by hand, or to cut "
                          "a fixture for the tests (PERF.md section 3)")
+    ap.add_argument("--benchmark-json", default=None,
+                    help="another BENCHMARK.json than the checkout's: a "
+                         "throw-away set of cells that is no part of the "
+                         "benchmark (with --extra-root)")
+    ap.add_argument("--extra-root", default=None,
+                    help="a directory whose configs/, traffic/, datasets/, "
+                         "... are looked in before benchmarks/")
     args = ap.parse_args(argv)
     try:
-        spec = Spec()
+        spec = Spec(args.benchmark_json, args.extra_root)
         result = run(
             args.workload, args.seed,
             args.seconds if args.seconds is not None else spec.run_seconds,
-            bool(args.trace),
+            bool(args.trace), spec=spec,
             sweep=[float(x) for x in args.sweep.split(",")] if args.sweep
             else None, keep_trace=args.keep_trace)
     except NoResult as e:

@@ -125,3 +125,27 @@ def test_the_new_metrics_validate_and_read_nothing_from_the_parent():
     assert len(flat) == len(set(flat))
     assert sorted(flat) == sorted(
         spec.layer_metric("idle_other_pct")["params"]["phases"])
+
+
+def test_the_breakdowns_idle_gaps_are_named_by_the_host_interval():
+    """`breakdown.idle_gaps` of a traced run: the idle seconds by the
+    interval that was open in them, then the longest gaps, each by the
+    interval that held most of it; without a capture log, unattributed."""
+    from benchmarks import run as bench_run
+
+    devs = xplane.device_summary(TRACE)["devices"][DEV]
+    named = bench_run.name_idle_gaps(
+        TRACE, {"intervals": INTERVALS}, 500e-9, devs["gaps_s"])
+    assert named[:5] == [
+        ["request", pytest.approx(275e-9)], ["enqueue", pytest.approx(100e-9)],
+        ["hydrate", pytest.approx(75e-9)], ["scatter", pytest.approx(40e-9)],
+        ["no interval open", pytest.approx(10e-9)]]
+    # gap [400, 700): request 200, enqueue 50; gap [100, 300): request 75
+    assert named[5:] == [
+        ["gap at +0.000s, mostly request", pytest.approx(300e-9)],
+        ["gap at +0.000s, mostly request", pytest.approx(200e-9)]]
+    assert len(named) <= 10
+    bare = bench_run.name_idle_gaps(TRACE, None, 500e-9, devs["gaps_s"])
+    assert [n.split(":")[0] for n, _ in bare] == [
+        "unattributed (no capture log)", "unattributed", "unattributed"]
+    assert bare[0][1] == 500e-9

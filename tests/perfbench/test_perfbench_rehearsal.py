@@ -13,7 +13,10 @@ from benchmarks.lib.spec import Spec
 
 THROWAWAY = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                          "throwaway")
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+FILTERED = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "throwaway_filtered")
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+             "compared"}
 
 
 @pytest.fixture(scope="module")
@@ -36,9 +39,12 @@ def _run(spec, state_root, cell, trace):
 
 def test_closed_loop_cell_builds_recovers_and_measures(spec, state_root):
     res = _run(spec, state_root, "tiny-128-l2.batch256", trace=False)
-    assert set(res) == LINE_KEYS
+    assert set(res) == LINE_KEYS and list(res)[-1] == "compared"
     assert res["correct"] is True and res["failed"] == 0
     assert res["attempted"] >= 3
+    assert res["compared"]["recall"] == {
+        "value": res["metrics"]["recall"]["value"], "limit": ">= 0.95"}
+    assert res["compared"]["disallowed_rows"] == {"value": 0, "limit": "== 0"}
     m = res["metrics"]
     assert set(m) == {"qps", "p50_ms", "recall", "setup_s"}
     assert m["recall"]["value"] >= 0.95 and m["qps"]["unit"] == "queries/s"
@@ -78,3 +84,23 @@ def test_traced_open_loop_cell_reads_the_programs_pages(spec, state_root):
     for name in ("device_idle_pct", "scan_device_ms", "scan_roofline"):
         assert name not in m
     assert "busy_s" not in res["device"]
+
+
+def test_a_filter_plan_is_traffic_on_a_state_directory_that_is_there(
+        state_root):
+    """Runs third: the state directory the first test built serves a mix
+    that gives every query its own 10% filter. Nothing is rebuilt; the
+    plan's ground truth is computed once and kept beside the unfiltered
+    one."""
+    spec = Spec(os.path.join(FILTERED, "BENCHMARK.json"), FILTERED)
+    state = os.path.join(state_root, "tiny-128-l2")
+    before = os.path.getmtime(os.path.join(state, "manifest.json"))
+    res = _run(spec, state_root, "tiny-128-l2.bucket-each", trace=False)
+    assert os.path.getmtime(os.path.join(state, "manifest.json")) == before
+    plans = [f for f in os.listdir(state) if f.startswith("plan-")]
+    assert sorted(f[-4:] for f in plans) == [".npz", "json"]
+    assert os.path.isfile(os.path.join(state, "gt_ids.npy"))
+    assert res["correct"] is True, res["compared"]
+    assert res["metrics"]["recall"]["value"] >= 0.95
+    assert res["compared"]["disallowed_rows"]["value"] == 0
+    assert res["compared"]["short_replies"]["value"] == 0

@@ -103,28 +103,138 @@ def test_recorded_v5e_trace_reduces_to_the_numbers_computed_by_hand():
         [10762188, 10774456, 10771253]
 
 
+MESH_FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            "fixtures_xplane_v5e_mesh4.json")
+
+
+def _pages(rows, dim, chips=1, batch=256, tier="exact_scan",
+           operand="store", bytes_a_component=4, queries=None,
+           dispatches=100, **perf):
+    """The server's pages as a run collects them, for a slab of
+    `capacity` = the next power of two of `rows`, held as `operand`."""
+    capacity = 1 << (rows - 1).bit_length()
+    queries = batch if queries is None else queries
+    return {
+        "cell": {"device_kind": "TPU v5 lite", "chips": chips, "rows": rows,
+                 "dim": dim, "batch": batch, "pq_segments": dim // 8},
+        "perf": dict({"rows": queries * dispatches, "dispatches": dispatches,
+                      "tiers": {tier: dispatches}}, **perf),
+        "index": {"live": rows, "capacity": capacity},
+        "debug_memory": {"device": {"components": {
+            operand: capacity * dim * bytes_a_component,
+            "tombs": capacity}}},
+    }
+
+
+def _value(sources, name):
+    spec = Spec()
+    m = spec.layer_metric(name)
+    return spec.reader(m["reader"]).read(sources, **m["params"])
+
+
 def test_the_readers_turn_the_recorded_trace_into_the_cells_metrics():
     spec = Spec()
     with open(FIXTURE) as f:
-        sources = {"xplane": xplane.from_json(f.read()),
-                   "cell": {"device_kind": "TPU v5 lite", "chips": 1,
-                            "rows": 1_000_000, "dim": 768, "batch": 256}}
-
-    def value(name):
-        m = spec.layer_metric(name)
-        return spec.reader(m["reader"]).read(sources, **m["params"])
-
-    assert value("scan_device_ms") == pytest.approx(10.771253)
-    assert value("device_idle_pct") == pytest.approx(37.88987062)
+        sources = dict(_pages(1_000_000, 768),
+                       xplane=xplane.from_json(f.read()))
+    assert _value(sources, "scan_device_ms") == pytest.approx(10.771253)
+    assert _value(sources, "device_idle_pct") == pytest.approx(37.88987062)
     # 1M x 768 f32 is 3.072 GB: 3.7509 ms at 819 GB/s, more than the 2.0 ms
     # its 393 GFLOP take at 197 TFLOP/s, so HBM bounds it
-    assert value("scan_roofline") == pytest.approx(
+    assert _value(sources, "scan_roofline") == pytest.approx(
         100 * (3.072e9 / 819e9) / 10.771253e-3)
-    assert sources["notes"]["roofline_bound"] == "hbm"
+    assert sources["notes"] == {
+        "roofline_program": "jit__search_full_fused(10375372082987777793)",
+        "roofline_tier": "exact_scan", "roofline_operand": "store",
+        "roofline_bytes_a_row": 3072.0, "roofline_rows": 1_000_000.0,
+        "roofline_queries": 256.0, "roofline_bound": "hbm"}
     assert spec.reader("xplane_ops").read({}, what="idle_pct") is None
     with pytest.raises(KeyError):     # a chip that is not in the table
         sources["cell"]["device_kind"] = "TPU v9"
-        value("scan_roofline")
+        _value(sources, "scan_roofline")
+
+
+def test_the_four_chip_trace_is_charged_one_chips_share_of_the_slab():
+    """3M x 768 f32 over four chips: 750,000 rows, 2.304 GB a chip; the
+    components and the capacity /debug/memory and /debug/index report are
+    the whole mesh's."""
+    with open(MESH_FIXTURE) as f:
+        sources = dict(_pages(3_000_000, 768, chips=4),
+                       xplane=xplane.from_json(f.read()))
+    assert _value(sources, "scan_roofline") == pytest.approx(
+        100 * (750_000 * 3072 / 819e9) / 13.918968e-3)
+    assert sources["notes"]["roofline_rows"] == 750_000
+    assert sources["notes"]["roofline_program"].startswith("jit_mesh_search")
+
+
+# what one execution of the recorded 10.771253 ms program would have been
+# given under other accounts of the server's: (pages, least ms or None, what
+# the notes must say)
+_F32_MS = 1e3 * 1_000_000 * 3072 / 819e9
+YARDSTICK = {
+    "a 2 B operand (pq with rescore scans the bf16 copy)": (
+        _pages(1_000_000, 768, tier="pq_rescore_bf16",
+               operand="rescore_store", bytes_a_component=2, queries=16),
+        _F32_MS / 2, {"roofline_operand": "rescore_store",
+                      "roofline_bytes_a_row": 1536.0,
+                      "roofline_bound": "hbm"}),
+    "at 2 B and 256 wide the multiply-adds bound it, not the bytes": (
+        _pages(2_000_000, 768, tier="pq_rescore_bf16",
+               operand="rescore_store", bytes_a_component=2),
+        1e3 * 2 * 256 * 2_000_000 * 768 / 197e12,
+        {"roofline_bound": "flops"}),
+    "one query a dispatch under a 256-wide traffic": (
+        _pages(2_000_000, 768, queries=1), 2 * _F32_MS,
+        {"roofline_queries": 1.0, "roofline_bound": "hbm"}),
+    "a part scan: the tier's own account says fewer rows": (
+        _pages(1_000_000, 768, tier="gather", tier_rows={"gather": 100 * 5000}),
+        _F32_MS / 200, {"roofline_rows": 5000.0, "roofline_tier": "gather"}),
+    "codes: segments bytes a row": (
+        _pages(1_000_000, 768, tier="pq_codes", operand="pq_codes",
+               bytes_a_component=0.125),
+        1e3 * 1_000_000 * 96 / 819e9, {"roofline_bytes_a_row": 96.0}),
+    "a part scan without the account": (
+        _pages(1_000_000, 768, tier="gather"), None,
+        {"roofline_null": "tier gather scans a part of the rows and "
+                          "/debug/perf has no tier_rows to say how many"}),
+    "the operand named is not resident": (
+        _pages(1_000_000, 768, tier="pq_rescore_bf16"), None,
+        {"roofline_null": "tier pq_rescore_bf16 scans rescore_store, which "
+                          "/debug/memory does not hold on the device "
+                          "(['store', 'tombs'])"}),
+    "bytes a component that are no type's": (
+        _pages(1_000_000, 768, bytes_a_component=3), None, {}),
+    "an account of more rows than the configuration has": (
+        _pages(1_000_000, 768, tier_rows={"exact_scan": 100 * 2_000_000}),
+        None, {}),
+    "live is not the configuration's rows": (
+        dict(_pages(1_000_000, 768), index={"live": 999_999,
+                                            "capacity": 1 << 20}), None, {}),
+    "no /debug/perf": (dict(_pages(1_000_000, 768), perf=None), None, {}),
+    "a tier the benchmark does not know": (
+        _pages(1_000_000, 768, tier="bm25_matmul"), None, {}),
+    "two tiers, and the program's name gives it to one": (
+        dict(_pages(1_000_000, 768), perf={
+            "rows": 300, "dispatches": 300,
+            "tiers": {"gather": 200, "exact_scan": 100}}),
+        _F32_MS, {"roofline_tier": "exact_scan", "roofline_queries": 1.0}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(YARDSTICK))
+def test_the_yardstick_counts_what_one_execution_was_given(case):
+    pages, least_ms, notes = YARDSTICK[case]
+    with open(FIXTURE) as f:
+        sources = dict(pages, xplane=xplane.from_json(f.read()))
+    value = _value(sources, "scan_roofline")
+    if least_ms is None:      # no reading, and the note says which account
+        assert value is None
+        assert sources["notes"]["roofline_null"]
+        assert "roofline_bound" not in sources["notes"]
+    else:
+        assert value == pytest.approx(100 * least_ms / 10.771253)
+    for key, want in notes.items():
+        assert sources["notes"][key] == want, sources["notes"]
 
 
 def test_to_json_round_trips():

@@ -15,13 +15,16 @@ import time
 import numpy as np
 import pytest
 
-from benchmarks.lib import check, stats
+from benchmarks import build as builder
+from benchmarks.lib import check, stats, where
 from benchmarks.lib.requests import Request, RequestBuilder
 from benchmarks.lib.spec import HERE, ROOT, NAME_RE, Spec, SpecError, check_unit
 from benchmarks.references import exact_f32
 
 THROWAWAY = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                          "throwaway")
+FILTERED = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "throwaway_filtered")
 
 
 def _tiny_cfg():
@@ -44,10 +47,13 @@ def test_open_loop_schedule_is_a_function_of_the_seed():
 def test_requests_are_a_function_of_the_seed():
     pool = np.random.default_rng(0).standard_normal((64, 128),
                                                     dtype=np.float32)
-    b = RequestBuilder(_tiny_cfg(), {"request": "BatchSearch", "width": 8,
-                                     "where": {"path": ["bucket"],
-                                               "operator": "Equal",
-                                               "valueInt": 3}}, pool)
+    cfg = _tiny_cfg()
+    traffic = {"request": "BatchSearch", "width": 8,
+               "where": {"path": ["bucket"], "operator": "Equal",
+                         "valueInt": 3}}
+    cfg["pool"] = len(pool)
+    b = RequestBuilder(cfg, traffic, pool, builder.plan_filters(
+        cfg, traffic, Spec().dataset(cfg)))
     one = [b.draw(np.random.default_rng(5)).qidx for _ in range(2)]
     other = b.draw(np.random.default_rng(6)).qidx
     assert np.array_equal(one[0], one[1]) and not np.array_equal(one[0], other)
@@ -57,6 +63,110 @@ def test_requests_are_a_function_of_the_seed():
     assert req.msg.requests[0].class_name == "Bench"
     assert list(req.msg.requests[2].near_vector.vector) == \
         pool[req.qidx[2]].tolist()
+
+
+def test_every_pool_query_goes_out_with_its_own_filter():
+    """In a Search and in every slot of a BatchSearch; a query without a
+    filter carries none; the draw is still a function of the seed alone."""
+    spec = Spec(os.path.join(FILTERED, "BENCHMARK.json"), FILTERED)
+    cfg = spec.config("tiny-128-l2-tags")
+    filters = builder.plan_filters(cfg, {}, spec.dataset(cfg))
+    assert len(filters) == 1024 == cfg["pool"]
+    assert filters == builder.plan_filters(cfg, None, spec.dataset(cfg))
+    filters[5] = None
+    pool = np.zeros((1024, 128), np.float32)
+    for traffic in ({"request": "BatchSearch", "width": 64},
+                    {"request": "Search"}):
+        b = RequestBuilder(cfg, traffic, pool, filters)
+        req = b.draw(np.random.default_rng(9))
+        again = b.draw(np.random.default_rng(9))
+        assert np.array_equal(req.qidx, again.qidx)
+        assert req.msg.SerializeToString() == again.msg.SerializeToString()
+        slots = req.msg.requests if req.kind == "batch" else [req.msg]
+        for q, slot in zip(req.qidx, slots):
+            assert slot.where_json == (json.dumps(filters[q])
+                                       if filters[q] else "")
+    assert b._search(5).where_json == ""
+    with pytest.raises(ValueError):
+        RequestBuilder(cfg, {}, pool, filters[:10])
+
+
+def test_a_traffic_mix_names_its_filter_plan():
+    """A constant `where`, a plan of the dataset's, no filter at all, or
+    what the dataset's queries carry by themselves; the state directory
+    keeps each plan's ground truth under the hash of its filters."""
+    spec = Spec(os.path.join(FILTERED, "BENCHMARK.json"), FILTERED)
+    tiny, tags = spec.config("tiny-128-l2"), spec.config("tiny-128-l2-tags")
+    buckets = spec.dataset(tiny)
+    assert spec.dataset(tiny).__name__.endswith("datasets_buckets")
+    none = builder.plan_filters(tiny, spec.traffic("batch256"), buckets)
+    assert none == [None] * 1024
+    assert builder.plan_files("/s", none) == ("/s/gt_ids.npy",
+                                              "/s/gt_dists.npy")
+    each = builder.plan_filters(
+        tiny, spec.traffic("batch256-bucket-each"), buckets)
+    assert [w["valueInt"] for w in each[:12]] == [0, 1, 2, 3, 4, 5, 6, 7, 8,
+                                                  9, 0, 1]
+    const = builder.plan_filters(tiny, {"where": each[3]}, buckets)
+    assert const == [each[3]] * 1024
+    files = {builder.plan_files("/s", p) for p in (each, const)}
+    assert len(files) == 2
+    for npz, text in files:
+        assert re.match(r"^/s/plan-[0-9a-f]{16}\.npz$", npz)
+        assert text == npz[:-4] + ".json"
+    assert builder.plan_filters(tags, spec.traffic("batch256-nofilter"),
+                                spec.dataset(tags)) == none
+    with pytest.raises(ValueError):
+        builder.plan_filters(tiny, {"filter_plan": "no_such"}, buckets)
+    rows = np.array([3, 13, 14, 20003])
+    assert buckets.properties(tiny, rows) == [
+        {"bucket": 3}, {"bucket": 3}, {"bucket": 4}, {"bucket": 3}]
+    assert buckets.allowed(tiny, [each[3], None, each[4]], rows).tolist() == [
+        [True, True, False, True], [True] * 4, [False, False, True, False]]
+
+
+WHERE_CASES = {
+    "Equal on a scalar": (
+        {"path": ["n"], "operator": "Equal", "valueInt": 2}, [0, 0, 1, 0]),
+    "Equal on a bag is any entry": (
+        {"path": ["bag"], "operator": "Equal", "valueInt": 7}, [1, 0, 1, 0]),
+    "the padding is no entry": (
+        {"path": ["bag"], "operator": "Equal", "valueInt": -1}, [0, 0, 0, 0]),
+    "And": ({"operator": "And", "operands": [
+        {"path": ["bag"], "operator": "Equal", "valueInt": 7},
+        {"path": ["bag"], "operator": "Equal", "valueInt": 9}]}, [0, 0, 1, 0]),
+    "Or and Not": ({"operator": "Or", "operands": [
+        {"path": ["n"], "operator": "LessThan", "valueInt": 1},
+        {"operator": "Not", "operands": [
+            {"path": ["n"], "operator": "LessThanEqual", "valueInt": 2}]}]},
+        [1, 0, 0, 1]),
+    "ContainsAll": ({"path": ["bag"], "operator": "ContainsAll",
+                     "valueInt": [7, 9]}, [0, 0, 1, 0]),
+    "ContainsAny": ({"path": ["bag"], "operator": "ContainsAny",
+                     "valueInt": [4, 9]}, [0, 1, 1, 0]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WHERE_CASES))
+def test_a_where_is_read_in_numpy(case):
+    clause, want = WHERE_CASES[case]
+    columns = {"n": np.array([0, 1, 2, 3]),
+               "bag": np.array([[7, -1, -1], [4, 5, -1], [9, 7, 3],
+                                [-1, -1, -1]])}
+    assert where.evaluate(clause, columns).tolist() == [bool(x) for x in want]
+    assert where.properties(columns, 4)[2] == {"n": 2, "bag": [9, 7, 3]}
+
+
+@pytest.mark.parametrize("clause", [
+    {"path": ["other"], "operator": "Equal", "valueInt": 1},
+    {"path": ["n"], "operator": "Like", "valueInt": 1},
+    {"path": ["n"], "operator": "Equal", "valueText": "a"},
+    {"path": ["bag"], "operator": "LessThan", "valueInt": 1},
+    {"operator": "And", "operands": []}])
+def test_a_where_the_reference_cannot_read_is_an_error(clause):
+    with pytest.raises(where.WhereError):
+        where.evaluate(clause, {"n": np.arange(3),
+                                "bag": np.zeros((3, 2), int)})
 
 
 class _OneWorkerServer:
@@ -214,6 +324,128 @@ def test_a_distance_off_the_reference_is_caught_as_chip_smoke_catches_it():
     assert ours["short_replies"] == 1 and ours["recall"] == 157 / 160
 
 
+def _filtered_case():
+    """The fixed case under a filter a query: queries 0-3 may take one row
+    in five, queries 4-7 seven rows in all, query 8 none, the rest all."""
+    vecs, queries, _ = _fixed_case()
+    allowed = np.ones((16, 2000), bool)
+    allowed[:4] = (np.arange(2000) % 5 == 2)[None, :]
+    allowed[4:8] = False
+    allowed[4:8, [3, 77, 512, 513, 1400, 1999, 1024]] = True
+    allowed[8] = False
+    topk = exact_f32.TopK("l2-squared", queries, 10)
+    for lo in range(0, 2000, 512):
+        topk.update(lo, vecs[lo:lo + 512], allowed[:, lo:lo + 512])
+    return vecs, queries, allowed, topk
+
+
+def test_filtered_ground_truth_agrees_with_chip_smoke_under_the_same_mask():
+    import chip_smoke
+
+    vecs, queries, allowed, topk = _filtered_case()
+    ids, dists = topk.result()
+    assert topk.allowed.tolist() == [400] * 4 + [7] * 4 + [0] + [2000] * 7
+    for q in range(16):
+        allow = np.flatnonzero(allowed[q])
+        n = min(10, len(allow))
+        assert (ids[q] >= 0).sum() == n and np.all(ids[q, n:] == -1)
+        assert np.all(np.isinf(dists[q, n:]))
+        if n == 0:
+            continue
+        # chip_smoke's brute force wants more rows than k: pad the short
+        # allow lists with far-away copies it ranks last
+        if len(allow) <= 10:
+            far = np.vstack([vecs, np.full((11, 128), 1e3, np.float32)])
+            theirs = chip_smoke.exact_topk(
+                far, queries[q:q + 1], 10,
+                np.concatenate([allow, 2000 + np.arange(11)]))[0][:n]
+        else:
+            theirs = chip_smoke.exact_topk(vecs, queries[q:q + 1], 10,
+                                           allow)[0]
+        assert np.array_equal(ids[q, :n], theirs)
+        assert allowed[q, ids[q, :n]].all()
+
+
+def _check_filtered(got_ids, got_dists=None):
+    vecs, queries, allowed, topk = _filtered_case()
+    want_ids, _ = topk.result()
+    if got_ids is None:
+        got_ids = want_ids.copy()
+    else:
+        got_ids = got_ids(want_ids.copy(), allowed)
+    safe = np.where(got_ids >= 0, got_ids, 0)
+    dists = exact_f32.pair_distances("l2-squared", vecs[safe],
+                                     queries[:, None, :])
+    dists[got_ids < 0] = np.nan
+    return check.check_window(
+        exact_f32, "l2-squared", 10, vecs, queries, want_ids, np.arange(16),
+        got_ids, dists, allowed_pairs=lambda qq, rr: allowed[qq, rr])
+
+
+def _one_row_outside(ids, allowed):
+    ids[0, 9] = np.flatnonzero(~allowed[0])[0]
+    return ids
+
+
+def _drop_one_of_seven(ids, allowed):
+    ids[5, 6] = -1
+    return ids
+
+
+def _a_wrong_neighbour(ids, allowed):
+    ids[1, 9] = next(r for r in np.flatnonzero(allowed[1])
+                     if r not in ids[1])
+    return ids
+
+
+def _answer_the_empty_filter(ids, allowed):
+    ids[8, 0] = 17
+    return ids
+
+
+FILTERED_CHECKS = {
+    # what the replies did -> (recall, short, disallowed)
+    "the exact answers: short ground truth counts for what it has":
+        (None, 1.0, 0, 0),
+    "a row outside its query's filter, whatever the recall":
+        (_one_row_outside, 137 / 138, 0, 1),
+    "six results where the filter allows seven":
+        (_drop_one_of_seven, 137 / 138, 1, 0),
+    "an allowed row that is not a neighbour":
+        (_a_wrong_neighbour, 137 / 138, 0, 0),
+    "an answer where the filter allows nothing":
+        (_answer_the_empty_filter, 1.0, 0, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FILTERED_CHECKS))
+def test_under_a_filter_the_check_holds_each_clause(case):
+    """138 ground-truth entries exist: 4 x 10 + 4 x 7 + 0 + 7 x 10."""
+    change, recall, short, disallowed = FILTERED_CHECKS[case]
+    out = _check_filtered(change)
+    assert out["recall"] == pytest.approx(recall)
+    assert out["short_replies"] == short
+    assert out["disallowed_rows"] == disallowed
+    assert out["bad_distances"] == 0 and out["unknown_rows"] == 0
+    assert (out["first_disallowed"] is None) == (disallowed == 0)
+
+
+def test_without_filters_the_check_reads_what_it_read():
+    vecs, queries, (want_ids, want_d) = _fixed_case()
+    a = check.check_window(exact_f32, "l2-squared", 10, vecs, queries,
+                           want_ids, np.arange(16), want_ids, want_d)
+    b = check.check_window(exact_f32, "l2-squared", 10, vecs, queries,
+                           want_ids, np.arange(16), want_ids, want_d,
+                           allowed_pairs=lambda qq, rr: np.ones(len(qq), bool))
+    assert a == b and a["recall"] == 1.0 and a["disallowed_rows"] == 0
+    topk = exact_f32.TopK("l2-squared", queries, 10)
+    for lo in range(0, 2000, 512):
+        topk.update(lo, vecs[lo:lo + 512], np.ones((16, len(vecs[lo:lo + 512])),
+                                                   bool))
+    assert np.array_equal(topk.result()[0], want_ids)
+    assert topk.allowed.tolist() == [2000] * 16
+
+
 @pytest.mark.parametrize("metric", ["cosine", "dot"])
 def test_reference_top_k_is_exact_for_the_other_metrics(metric):
     rng = np.random.default_rng(2)
@@ -296,11 +528,36 @@ def test_a_cell_a_config_a_mix_and_a_metric_are_added_by_files(tmp_path):
     traffic mix, new per-layer metric with a reader of its own, new cell;
     nothing under benchmarks/ is touched."""
     root = tmp_path / "extra"
-    for d in ("configs", "traffic", "layer_metrics", "readers"):
+    for d in ("configs", "traffic", "layer_metrics", "readers", "datasets"):
         (root / d).mkdir(parents=True)
     cfg = _tiny_cfg()
     cfg["name"], cfg["rows"] = "toy-128-l2", 1000
     (root / "configs" / "toy-128-l2.json").write_text(json.dumps(cfg))
+    # a dataset of its own (rows carry a tenant, every query asks for its
+    # own), a configuration that names it, and a traffic mix that puts a
+    # filter plan of the buckets dataset on a configuration that is there
+    (root / "datasets" / "tenants.py").write_text(
+        "import numpy as np\n"
+        "from benchmarks.lib import where\n"
+        "def _cols(cfg, rows):\n"
+        "    return {'tenant': np.asarray(rows) % cfg['tenants']}\n"
+        "def properties(cfg, rows):\n"
+        "    return where.properties(_cols(cfg, rows), len(rows))\n"
+        "def filter_plan(cfg, plan):\n"
+        "    return [{'path': ['tenant'], 'operator': 'Equal',\n"
+        "             'valueInt': i % cfg['tenants']}\n"
+        "            for i in range(cfg['pool'])]\n"
+        "def allowed(cfg, wheres, rows):\n"
+        "    return where.allowed(wheres, _cols(cfg, rows), len(rows))\n")
+    tenants = dict(cfg, name="toy-128-l2-tenants", dataset="tenants",
+                   tenants=7)
+    tenants["class"] = dict(cfg["class"], properties=[
+        {"name": "tenant", "dataType": ["int"]}])
+    (root / "configs" / "toy-128-l2-tenants.json").write_text(
+        json.dumps(tenants))
+    (root / "traffic" / "bucket-each.json").write_text(json.dumps({
+        "generator": "closed", "callers": 2, "request": "BatchSearch",
+        "width": 64, "filter_plan": "bucket_each"}))
     (root / "traffic" / "filtered10.json").write_text(json.dumps({
         "generator": "closed", "callers": 2, "request": "BatchSearch",
         "width": 64, "where": {"path": ["bucket"], "operator": "Equal",
@@ -321,6 +578,18 @@ def test_a_cell_a_config_a_mix_and_a_metric_are_added_by_files(tmp_path):
     doc["workloads"].append({"name": "toy-128-l2.filtered10",
                              "config": "toy-128-l2", "traffic": "filtered10",
                              "chips": 1, "why": "throw-away"})
+    doc["configs"].append({"name": "toy-128-l2-tenants", "source": "test",
+                           "file": "extra/configs/toy-128-l2-tenants.json",
+                           "reduced": [], "why": "throw-away"})
+    doc["workloads"].append({"name": "toy-128-l2-tenants.batch256",
+                             "config": "toy-128-l2-tenants",
+                             "traffic": "batch256", "chips": 1,
+                             "why": "throw-away"})
+    a_config = doc["configs"][0]["name"]      # one that is there
+    doc["workloads"].append({"name": a_config + ".bucket-each",
+                             "config": a_config, "traffic": "bucket-each",
+                             "chips": doc["workloads"][0]["chips"],
+                             "why": "throw-away"})
     doc["per_layer"].append({"name": "requests_seen", "unit": "count",
                              "better": "higher", "source": "host_clock",
                              "layer": "Client (benchmark)",
@@ -329,7 +598,9 @@ def test_a_cell_a_config_a_mix_and_a_metric_are_added_by_files(tmp_path):
     # the new cell needs its share of the end-to-end metrics
     for m in doc["end_to_end"]:
         if "workloads" in m and m["name"] == "qps":
-            m["workloads"].append("toy-128-l2.filtered10")
+            m["workloads"] += ["toy-128-l2.filtered10",
+                               "toy-128-l2-tenants.batch256",
+                               a_config + ".bucket-each"]
     bj = tmp_path / "BENCHMARK.json"
     bj.write_text(json.dumps(doc))
     spec = Spec(str(bj), str(root))
@@ -342,6 +613,24 @@ def test_a_cell_a_config_a_mix_and_a_metric_are_added_by_files(tmp_path):
     f = spec.layer_metric("requests_seen")
     assert spec.reader(f["reader"]).read({"client": {"requests": 41}},
                                          **f["params"]) == 42
+    # the configuration with a dataset of its own: rows, filters, reading
+    tcfg = spec.config("toy-128-l2-tenants")
+    dataset = spec.dataset(tcfg)
+    assert dataset.properties(tcfg, np.array([8])) == [{"tenant": 1}]
+    filters = builder.plan_filters(tcfg, spec.traffic("batch256"), dataset)
+    assert filters[9]["valueInt"] == 2 and len(filters) == tcfg["pool"]
+    assert dataset.allowed(tcfg, filters[:2], np.arange(14)).sum(1).tolist() \
+        == [2, 2]
+    # the filter plan as traffic on a configuration that is there: its own
+    # file, byte for byte, and the dataset it always had
+    there = spec.config(a_config)
+    with open(os.path.join(ROOT, Spec().configs[a_config]["file"]), "rb") as f:
+        assert there["_sha256"] == __import__("hashlib").sha256(
+            f.read()).hexdigest()
+    plan = builder.plan_filters(there, spec.traffic("bucket-each"),
+                                spec.dataset(there))
+    assert plan[13] == {"path": ["bucket"], "operator": "Equal",
+                        "valueInt": 13 % there["filter_buckets"]}
     # and the shipped ones are still found beside them
     assert hasattr(spec.reader("perf_phase"), "read")
     assert spec.generator("closed").run
