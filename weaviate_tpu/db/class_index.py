@@ -337,6 +337,19 @@ class ClassIndex:
 
         return done
 
+    def object_vector_search_multi_async(
+        self, vectors: np.ndarray, k: int, flts, include_vector: bool = False
+    ):
+        """A group of kNN slots, each under its own filter (or none), on the
+        single-local-shard layout (Shard.object_vector_search_multi_async).
+        None -> the caller searches slot by slot: other layouts fan one
+        filter out to every shard."""
+        shard = self.single_local_shard()
+        if shard is None:
+            return None
+        return shard.object_vector_search_multi_async(
+            vectors, k, flts, include_vector)
+
     def is_consistent(self, uuid: str, update_time: int) -> bool:
         """_additional.isConsistent: replicated shards digest-compare every
         replica; unreplicated objects are trivially consistent."""

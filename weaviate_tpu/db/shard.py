@@ -1049,6 +1049,125 @@ class Shard:
 
         return done
 
+    def object_vector_search_multi_async(
+        self, vectors: np.ndarray, k: int,
+        flts: Sequence[Optional[LocalFilter]], include_vector: bool = False,
+    ):
+        """A GROUP of kNN slots, slot i under its own filter `flts[i]` (None:
+        no filter), in a bounded number of device dispatches (index/tpu.py
+        search_by_vectors_multi_async) and one hydration. All the group's
+        filters resolve in ONE `filter` phase on the submitting thread
+        (`filters` and `distinct` in its stats): equal filters (one
+        signature) are evaluated once, through the same allowList cache a
+        single search uses. -> finalize() -> a list with, for each slot,
+        its hydrated results or the Exception its own filter raised (the
+        other slots are served); or None where this shard serves one
+        filter a dispatch (an index without the per-slot programs, the
+        breaker open): the caller then searches slot by slot, through the
+        path that has the host fallback. A device error feeds the breaker
+        and propagates, for the same reason."""
+        q = np.asarray(vectors, dtype=np.float32)
+        if q.ndim == 1:
+            q = q[None, :]
+        robustness.check_deadline("shard.search")
+        # the group's own point: a failure here sends every slot to the
+        # single path (the traverser), which fires db.shard.search itself
+        faults.fire("db.shard.search_group")
+        vidx = self.vector_index
+        dispatch = getattr(vidx, "search_by_vectors_multi_async", None)
+        br = robustness.get_breaker()
+        if dispatch is None or (
+                br is not None and self._has_host_plane() and not br.allow()):
+            return None
+        m = self.metrics
+        cls = self.class_def.name
+        failed: dict[int, Exception] = {}
+        allows: list = [None] * len(flts)
+        with tracing.Stopwatch("filter") as sw:
+            by_sig: dict = {}
+            for i, flt in enumerate(flts):
+                if flt is None:
+                    continue
+                sig = filter_signature(flt) or id(flt)
+                got = by_sig.get(sig)
+                if got is None:
+                    try:
+                        got = self.build_allow_list(flt)
+                    except Exception as e:  # noqa: BLE001 — this slot's alone
+                        got = e
+                    by_sig[sig] = got
+                if isinstance(got, Exception):
+                    failed[i] = got
+                else:
+                    allows[i] = got
+            sw.note(filters=sum(f is not None for f in flts),
+                    distinct=len(by_sig))
+        filter_ms = sw.ms
+        if m is not None:
+            m.filtered_vector_filter.labels(cls, self.name).observe(filter_ms)
+        served = [i for i in range(len(flts)) if i not in failed]
+        try:
+            finalize = dispatch(q[served], k, [allows[i] for i in served])
+        except Exception as e:
+            if br is not None and robustness.is_device_error(e):
+                br.record_failure(e)
+            raise
+        if finalize is None:
+            return None
+        lock_wait = self._pop_lock_wait()
+        shapes = finalize.shapes
+
+        def done() -> list:
+            rec = None
+            try:
+                rec = tracing.dispatch_record(len(served))
+                if rec is not None:
+                    rec.phase("filter", filter_ms)
+                t0 = time.perf_counter()
+                try:
+                    ids, dists = finalize()
+                except Exception as e:
+                    if br is not None and robustness.is_device_error(e):
+                        br.record_failure(e)
+                    raise
+                if br is not None and shapes and self._has_host_plane():
+                    self._record_device_success(br)
+                t1 = time.perf_counter()
+                with tracing.Stopwatch("hydrate", rows=len(dists)) as hyd:
+                    hydrated = self._hydrate_batch(ids, dists, include_vector)
+                if rec is not None:
+                    rec.phase("device_search", (t1 - t0) * 1000.0)
+                    rec.phase("hydrate", hyd.ms)
+                # the group's filter and hydrate are one sample each in the
+                # ledger, on its first dispatch; every dispatch is counted
+                for j, shape in enumerate(shapes):
+                    if j == 0:
+                        shape.filter_ms = filter_ms
+                        shape.hydrate_ms = hyd.ms
+                    self._trace_dispatch_facts(
+                        rec if j == 0 else None, shape.batch, k, lock_wait,
+                        shape)
+                if m is not None:
+                    m.filtered_vector_search.labels(cls, self.name).observe(
+                        (t1 - t0) * 1000.0)
+                    m.filtered_vector_objects.labels(cls, self.name).observe(
+                        hyd.ms)
+                    m.vector_index_ops.labels("search", cls, self.name).inc(
+                        len(served))
+                    m.query_dimensions.labels("nearVector", "search", cls).inc(
+                        int(len(served) * q.shape[1]))
+                out: list = [None] * len(flts)
+                for i, res in zip(served, hydrated):
+                    out[i] = res
+                for i, e in failed.items():
+                    out[i] = e
+                return out
+            finally:
+                if rec is not None and rec.owned:
+                    rec.finish()
+
+        return done
+
     def debug_health(self) -> dict:
         """Per-shard introspection for ``GET /debug/index``: object count,
         allowList-cache occupancy, and the vector index's health snapshot

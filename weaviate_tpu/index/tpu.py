@@ -19,10 +19,15 @@ device do what it is good at:
   target — comparable to HNSW's >=0.99 fixture bar, recall_test.go:137);
   config exactTopK=true forces lax.top_k for guaranteed recall 1.0;
 - tombstones (delete.go semantics) are a device bool mask, filters
-  (helpers/allow_list.go) become packed bitmaps expanded on device;
-- filtered searches below flat_search_cutoff take a gather path: only the
-  allowed rows are gathered and scored (flat_search.go:19 semantics,
-  vectorized);
+  (helpers/allow_list.go) become packed bitmaps expanded on device; an
+  allowList's docs are LOOKED UP in the snapshot's slot table (a cost that
+  follows the posting), never matched against every live row;
+- one filtered search below flat_search_cutoff takes a gather path: only
+  the allowed rows are gathered and scored (flat_search.go:19 semantics,
+  vectorized); a GROUP of slots, each under its own filter, is a bounded
+  number of dispatches (search_by_vectors_multi_async: one per-slot gather
+  program a row bucket, one scan whose queries carry their own masks), the
+  hand-over priced for the whole group (docs/filters.md);
 - mutation is staged host-side and flushed to the device in fixed-size
   chunks via dynamic_update_slice (no reallocation until capacity
   doubles — maintainance.go:31 geometric growth parity);
@@ -99,7 +104,8 @@ from weaviate_tpu.config.config import (IVF_TOP_P_BUCKETS, IvfConfig,
 # IVF_ENABLED is off
 from weaviate_tpu.ops import ivf as ivf_ops
 from weaviate_tpu.ops.topk import (bitmap_to_mask, merge_top_k,
-                                   retranslate_packed, translate_pack,
+                                   rescore_distances, retranslate_packed,
+                                   translate_pack, translate_pack_split,
                                    unpack_fused)
 
 _CHUNK = 8192          # rows staged per device write (fixed => no recompiles)
@@ -379,7 +385,14 @@ def _scan_full(
     store_c = store.reshape(nchunks, chunk, dim)
     tombs_c = tombs.reshape(nchunks, chunk)
     norms_c = sq_norms.reshape(nchunks, chunk) if sq_norms is not None else None
-    allow_c = allow_words.reshape(nchunks, chunk // 32) if use_allow else None
+    # one [capacity / 32] word vector masks every query alike; a
+    # [B, capacity / 32] block gives each query its own mask (a group of
+    # filtered slots in one scan: search_by_vectors_multi_async)
+    per_query = use_allow and allow_words.ndim == 2
+    if per_query:
+        allow_c = allow_words.reshape(allow_words.shape[0], nchunks, chunk // 32)
+    else:
+        allow_c = allow_words.reshape(nchunks, chunk // 32) if use_allow else None
     # scan only the chunks that hold live rows (capacity may be up to 2x n
     # after geometric growth; scanning the empty tail would halve throughput)
     if active_chunks is not None:
@@ -412,15 +425,22 @@ def _scan_full(
         norms_l = take(norms_c, ci) if norms_c is not None else None
         base = ci * chunk
         valid = jnp.logical_and(jnp.arange(chunk) + base < n, jnp.logical_not(tombs_l))
-        if use_allow:
-            valid = jnp.logical_and(valid, bitmap_to_mask(take(allow_c, ci), chunk))
+        if per_query:
+            words = jax.lax.dynamic_index_in_dim(allow_c, ci, 1, keepdims=False)
+            bits = (words[:, :, None] >> jnp.arange(32, dtype=jnp.uint32)) & jnp.uint32(1)
+            valid = jnp.logical_and(valid[None, :],
+                                    bits.reshape(b, chunk).astype(jnp.bool_))
+        else:
+            if use_allow:
+                valid = jnp.logical_and(valid, bitmap_to_mask(take(allow_c, ci), chunk))
+            valid = valid[None, :]
         if rescore_r and metric in (vi.DISTANCE_L2, vi.DISTANCE_DOT, vi.DISTANCE_COSINE):
             d = fast_dists(qd, store_l, norms_l)
-            d = jnp.where(valid[None, :], d, jnp.inf)
+            d = jnp.where(valid, d, jnp.inf)
             td, li = jax.lax.approx_min_k(d, kk, recall_target=0.95)
         else:
             d = DISTANCE_FNS[metric](qd, store_l, norms_l)
-            d = jnp.where(valid[None, :], d, jnp.inf)
+            d = jnp.where(valid, d, jnp.inf)
             if exact:
                 neg, li = jax.lax.top_k(-d, kk)
                 td = -neg
@@ -435,8 +455,6 @@ def _scan_full(
         # exact f32 rescoring of the R merged candidates, fully on device:
         # gather [B, R, D] rows and score elementwise (VPU work, one HBM
         # gather — no host round trip)
-        from weaviate_tpu.ops.topk import rescore_distances
-
         safe = jnp.clip(idx, 0, cap - 1)
         cand = jnp.take(store, safe, axis=0)  # [B, R, D]
         ed = rescore_distances(cand, q, metric)
@@ -699,6 +717,133 @@ def _search_gathered_fused(store, q, rows, row_valid, tombs, s2d, k, metric):
     ids in the same program."""
     top, slots = _gathered_topk(store, q, rows, row_valid, tombs, k, metric)
     return translate_pack(top, slots, s2d)
+
+
+# -- per-slot gather: a group of filtered slots in one program ---------------
+# A row-count bucket is four times the one below it (128, 512, ... ): one
+# program a bucket, so a group of any mix of selectivities is at most a
+# handful of gather dispatches, and the compiled shapes stay few.
+_GATHER_MIN_ROWS = 128
+# bytes of the [slots, rows, dim] block one loop step gathers (rows are
+# lane-padded in HBM: 192 components occupy 256 lanes). The program loops
+# over its slots in steps of this size, so a bucket's temporary is bounded
+# whatever the group's width; a bucket whose ONE slot passes it is not
+# served by the gather at all (the masked scan takes those slots).
+_GATHER_BLOCK_BYTES = 64 << 20
+# row indices one gather dispatch uploads (int32: 2 MB)
+_GATHER_INDEX_ROWS = 1 << 19
+
+
+def _scan_group_bucket(n: int, bb: int) -> int:
+    """Queries of the group's one masked scan after padding: half the
+    group's width bucket `bb`, or all of it. Two shapes a width, so that
+    which one a request compiles does not follow the draw of its filters
+    (how many of a group's slots the plan sends to the scan varies by tens
+    from one request to the next); a padded query costs its zero words'
+    upload."""
+    half = max(bb // 2, _B_BUCKETS[0])
+    return half if n <= half else bb
+
+
+def _gather_row_bucket(n: int) -> int:
+    r = _GATHER_MIN_ROWS
+    while r < n:
+        r *= 4
+    return r
+
+
+def _gather_step_slots(r: int, dim: int) -> int:
+    """Slots one loop step of the bucket-`r` gather program scores (a power
+    of two; 0: one slot's block alone passes _GATHER_BLOCK_BYTES)."""
+    row_bytes = -(-dim // 128) * 128 * 4
+    g = _GATHER_BLOCK_BYTES // (r * row_bytes)
+    return 1 << (g.bit_length() - 1) if g else 0
+
+
+def _gather_slots(bb: int, r: int) -> int:
+    """Slots of a group of width bucket `bb` that one bucket-`r` gather
+    dispatch takes: its slot dimension (the program loops over the real
+    ones alone)."""
+    return min(bb, max(_GATHER_INDEX_ROWS // r, 1))
+
+
+def gather_max_rows(dim: int, capacity: int) -> int:
+    """The largest row bucket the per-slot gather serves at this width."""
+    r = _GATHER_MIN_ROWS
+    while r * 4 <= capacity and _gather_step_slots(r * 4, dim):
+        r *= 4
+    return r
+
+
+@jax.jit
+def _pad_rows(store):
+    """The store with its rows padded to whole lanes (zero columns)."""
+    return jnp.pad(store, ((0, 0), (0, -store.shape[1] % 128)))
+
+
+@jax.jit
+def _split_doc_pairs(s2d):
+    """[capacity, 2] doc-id words -> (lo [capacity], hi [capacity]): the
+    layout a per-winner lookup reads without re-laying the table out."""
+    return s2d[:, 0], s2d[:, 1]
+
+
+@functools.partial(jax.jit, static_argnames=("k", "metric", "step"))
+def _search_gathered_multi(store, q, rows, counts, n_slots, tombs, s2d_lo,
+                           s2d_hi, k, metric, step):
+    """The gather tier for a GROUP of filtered slots: slot s scores only its
+    own rows. q [S, D], rows [S, R] int32 (slot s's store slots, padded),
+    counts [S] (how many of them are real), n_slots (how many of the S are
+    real: the loop runs over those alone, `step` slots at a time, so a
+    padded slot costs nothing and the gathered block is bounded). Distances
+    are elementwise float32 (exact), the snapshot's tombs mask rides the
+    program (_gather_live's contract), winners leave as doc ids."""
+    s, r = rows.shape
+    pos = jnp.arange(r, dtype=jnp.int32)
+
+    def body(c, carry):
+        top, idx = carry
+        at = c * step
+        rows_c = jax.lax.dynamic_slice_in_dim(rows, at, step, 0)
+        q_c = jax.lax.dynamic_slice_in_dim(q, at, step, 0)
+        cnt_c = jax.lax.dynamic_slice_in_dim(counts, at, step, 0)
+        sub = jnp.take(store, rows_c, axis=0)           # [step, R, D]
+        d = rescore_distances(sub, q_c, metric)         # [step, R] exact f32
+        live = jnp.logical_and(pos[None, :] < cnt_c[:, None],
+                               jnp.logical_not(jnp.take(tombs, rows_c)))
+        neg, p = jax.lax.top_k(-jnp.where(live, d, jnp.inf), k)
+        slots = jnp.where(jnp.isinf(neg), -1,
+                          jnp.take_along_axis(rows_c, p, axis=1))
+        return (jax.lax.dynamic_update_slice_in_dim(top, -neg, at, 0),
+                jax.lax.dynamic_update_slice_in_dim(idx, slots, at, 0))
+
+    init = (jnp.full((s, k), jnp.inf, jnp.float32),
+            jnp.full((s, k), -1, jnp.int32))
+    top, idx = jax.lax.fori_loop(0, (n_slots + step - 1) // step, body, init)
+    return translate_pack_split(top, idx, s2d_lo, s2d_hi)
+
+
+def _slot_words(slots: np.ndarray, capacity: int,
+                out: Optional[np.ndarray] = None) -> np.ndarray:
+    """Ascending store slots -> the packed uint32 filter words over
+    [capacity] slots every masked-scan kernel consumes (bit s % 32 of word
+    s // 32), set in `out` (all zero) when given. The bits of one word are
+    OR-ed in one reduceat over the slots, so the work follows the slots,
+    and the words themselves are the only thing capacity-sized."""
+    words = np.zeros(capacity // 32, np.uint32) if out is None else out
+    if slots.size * 32 > capacity:
+        # more than a slot a word: a bool mask packed whole is the cheaper
+        mask = np.zeros(capacity, bool)
+        mask[slots] = True
+        words[:] = np.packbits(mask.reshape(-1, 32), axis=1,
+                               bitorder="little").view(np.uint32).ravel()
+    elif slots.size:
+        s = slots.astype(np.uint32, copy=False)
+        word = s >> np.uint32(5)
+        starts = np.flatnonzero(np.r_[True, word[1:] != word[:-1]])
+        words[word[starts]] = np.bitwise_or.reduceat(
+            np.uint32(1) << (s & np.uint32(31)), starts)
+    return words
 
 
 def _prep_bulk_run(ids: np.ndarray, vecs: np.ndarray, metric: str, known_fn):
@@ -1178,7 +1323,8 @@ class IndexSnapshot:
                  "rescore_dev", "rescore_sq_norms", "host_vecs",
                  "pq4", "codes4", "recon_norms4", "opq_rot",
                  "ivf_centroids", "ivf_buckets", "ivf_pca_proj",
-                 "ivf_pca_rows", "ivf_meta")
+                 "ivf_pca_rows", "ivf_meta", "docs_ascending",
+                 "doc_order", "s2d_cols")
 
     def __init__(self, gen: int, idx: "TpuVectorIndex"):
         self.gen = gen
@@ -1191,6 +1337,15 @@ class IndexSnapshot:
         self.tombs = idx._tombs
         self.slot_to_doc = idx._slot_to_doc
         self.slot_to_doc_dev = idx._s2d_dev
+        # doc -> slot resolution of a filter (`_allow_slots`): whether the
+        # `[:n]` prefix of slot_to_doc ascends strictly (then it is its own
+        # index: a binary search a doc), else its sorted order, made on
+        # first use; and the device table's two columns as 1-D arrays for
+        # the per-slot gather program, split on first use. Both derive from
+        # what this snapshot pins and are the same whoever computes them.
+        self.docs_ascending = idx._docs_ascending
+        self.doc_order = None
+        self.s2d_cols = None
         self.host_tombs = idx._host_tombs
         self.allow_token = idx._allow_token
         self.compressed = idx.compressed
@@ -1274,6 +1429,11 @@ class TpuVectorIndex(VectorIndex):
         # live doc->slot map from it without a device fetch
         self._host_tombs = np.zeros(0, dtype=bool)
         self._doc_to_slot: dict[int, int] = {}
+        # does slot_to_doc[:n] ascend strictly? The shard hands out doc ids
+        # from a counter, so on the served path it always does; a library
+        # caller that re-adds or adds out of order clears it until the
+        # next compaction (`_note_docs_appended`)
+        self._docs_ascending = True
         # snapshot-isolated read plane: readers dispatch on the published
         # IndexSnapshot lock-free; writers republish under self._lock.
         # _staged_gen/_published_gen is the read-your-writes handshake: any
@@ -1347,6 +1507,10 @@ class TpuVectorIndex(VectorIndex):
         # generation), so object identity IS the write generation. Strong
         # refs keep ids stable.
         self._blk_cache: dict = {}
+        # (store array, its lane-padded twin): see _row_store
+        self._row_store_cache: Optional[tuple] = None
+        self._row_store_lock = sanitizers.register_lock(
+            threading.Lock(), "index.tpu.row_store")
         # -- IVF scan plane (ROADMAP item 3; ops/ivf.py) ----------------
         # device slabs (None until the write path trains a layout):
         # centroids [nlist, D] f32, padded partition buckets
@@ -1638,6 +1802,7 @@ class TpuVectorIndex(VectorIndex):
         self._ensure_capacity(self.n + count)
         self._cow_host_state()
         self._write_block(np.ascontiguousarray(vecs), self.n)
+        self._note_docs_appended(ids64)
         self._slot_to_doc[self.n : self.n + count] = ids64
         self._stage_doc_ids(ids64, self.n)
         d2s.update(zip(ids64.tolist(), range(self.n, self.n + count)))
@@ -1663,6 +1828,16 @@ class TpuVectorIndex(VectorIndex):
         self._mark_staged()
         if log and self._log is not None:
             self._log.append_delete(doc_id)
+
+    def _note_docs_appended(self, docs: np.ndarray) -> None:
+        """Keep `_docs_ascending` true to the run about to land at slot
+        `self.n` (called under the write lock, before the assignment)."""
+        if not self._docs_ascending or len(docs) == 0:
+            return
+        docs = np.asarray(docs)
+        if (self.n and docs[0] <= self._slot_to_doc[self.n - 1]) \
+                or not bool(np.all(docs[1:] > docs[:-1])):
+            self._docs_ascending = False
 
     def _stage_doc_ids(self, docs: np.ndarray, start: int) -> None:
         """Mirror a run of newly-assigned slot->doc entries onto the
@@ -1728,6 +1903,7 @@ class TpuVectorIndex(VectorIndex):
             # chunked writes pad the tail; capacity is padded in _CHUNK
             # multiples beyond need so padding only lands in unused slots
             self._write_block(rows, self.n)
+            self._note_docs_appended(docs)
             self._slot_to_doc[self.n : self.n + count] = docs
             self._stage_doc_ids(docs, self.n)
             for i, d in enumerate(docs):
@@ -2409,6 +2585,7 @@ class TpuVectorIndex(VectorIndex):
             self._ensure_capacity(self.n + count + _CHUNK)
             self._cow_host_state()
             self._write_block(vectors, self.n)
+            self._note_docs_appended(doc_arr)
             self._slot_to_doc[self.n : self.n + count] = doc_arr
             self._stage_doc_ids(doc_arr, self.n)
             new_slots = dict(zip(doc_arr.tolist(), range(self.n, self.n + count)))
@@ -2801,20 +2978,15 @@ class TpuVectorIndex(VectorIndex):
         to the allow token object, so identity can never be recycled; the
         (token, n, capacity) triple still uniquely identifies the layout
         under snapshots because slot assignment is append-only between
-        token refreshes (compact issues a fresh token)."""
-        from weaviate_tpu.storage.bitmap import (
-            Bitmap, allowed_mask, pack_allow_words)
-
+        token refreshes (compact issues a fresh token). The words are set
+        from the filter's slots (`_allow_slots`: a lookup a posting entry),
+        not from a membership pass over the live rows."""
         key = (snap.allow_token, snap.n, snap.capacity)
         cached = getattr(allow_list, "_words_cache", None)
         if cached is not None and cached[0] == key:
             return cached[1]
-        live_docs = snap.slot_to_doc[: snap.n]
-        if isinstance(allow_list, Bitmap):
-            allowed = allowed_mask(allow_list, live_docs)
-        else:
-            allowed = allow_list.contains_array(live_docs.astype(np.uint64))
-        words = jnp.asarray(pack_allow_words(allowed, snap.capacity))
+        words = jnp.asarray(_slot_words(self._allow_slots(snap, allow_list),
+                                        snap.capacity))
         try:
             allow_list._words_cache = (key, words)
         except AttributeError:
@@ -3234,27 +3406,25 @@ class TpuVectorIndex(VectorIndex):
         packed_dev = self._gmin_packed_or_none(snap, q, kk, allow_words,
                                                store, sq_norms)
         if packed_dev is None:
-            sq = snap.sq_norms if sq_norms is None else sq_norms
-            args = (
-                snap.store if store is None else store,
-                sq if self.metric == vi.DISTANCE_L2 else None,
-                snap.tombs,
-                snap.n,
-                jnp.asarray(q),
-                allow_words if allow_words is not None
-                else jnp.zeros((snap.capacity // 32,), jnp.uint32),
-            )
-            statics = (
-                kk,
-                self.metric,
-                allow_words is not None,
-                getattr(self.config, "exact_topk", False),
-                -(-snap.n // _SCAN_CHUNK),
-                self._rescore_r(kk, snap.n),
-            )
-            packed_dev = _search_full_fused(*args, snap.slot_to_doc_dev,
-                                            *statics)
+            packed_dev = self._scan_program(
+                snap, snap.store if store is None else store,
+                snap.sq_norms if sq_norms is None else sq_norms, q,
+                allow_words, kk)
         return self._finalize_fused(packed_dev, shape, b)
+
+    def _scan_program(self, snap: IndexSnapshot, store, sq_norms, q,
+                      allow_words, kk: int):
+        """Enqueue the lax.scan program over `store`: `allow_words` None, one
+        [capacity / 32] mask for every query, or a [queries, capacity / 32]
+        block, a mask a query."""
+        return _search_full_fused(
+            store, sq_norms if self.metric == vi.DISTANCE_L2 else None,
+            snap.tombs, snap.n, jnp.asarray(q),
+            allow_words if allow_words is not None
+            else jnp.zeros((snap.capacity // 32,), jnp.uint32),
+            snap.slot_to_doc_dev, kk, self.metric, allow_words is not None,
+            getattr(self.config, "exact_topk", False),
+            -(-snap.n // _SCAN_CHUNK), self._rescore_r(kk, snap.n))
 
     def _finalize_fused(self, packed_dev, shape, b: int,
                         k: Optional[int] = None):
@@ -3389,18 +3559,18 @@ class TpuVectorIndex(VectorIndex):
 
     def _allow_slots(self, snap: IndexSnapshot,
                      allow_list: AllowList) -> np.ndarray:
-        """Store slots of `allow_list`'s docs in this snapshot, the
-        gather path's input-side resolution: ONE vectorized membership
-        pass over the snapshot's slot->doc prefix (the same primitive the
-        packed-words filter path uses), cached on the (immutable)
-        allowList per slot layout exactly like `_allow_words` — repeated
-        queries with the same filter skip the pass entirely (the shard's
-        allowList cache reuses AllowList objects per filter signature,
-        and the coalescer only admits filters proven hot, so the serving
-        path hits this cache; a one-off filter pays one vectorized O(n)
-        pass, the cold-filter cost class `_allow_words` already set).
-        This replaced the per-snapshot lazily-sorted doc->slot binary-
-        search map, which died with host-side result translation.
+        """Store slots of `allow_list`'s docs in this snapshot, ascending:
+        the input-side resolution of both filtered tiers (the gather's row
+        list, the masked scan's words). Its cost follows the POSTING, not
+        the corpus: each allowed doc is looked up in the snapshot's own
+        `slot_to_doc[:n]` prefix by binary search, which is its own index
+        while doc ids ascend with the slots (they do on the served path:
+        the shard hands them out from a counter and slots are assigned in
+        order); a library caller that re-added a doc or added out of order
+        gets the prefix's sorted order instead, made once a snapshot
+        (`_doc_order`). No pass over the live rows a filter. Cached on the
+        (immutable) allowList per slot layout like `_allow_words`: the
+        shard's allowList cache (16 filters) reuses the object a filter.
 
         Staleness contract: the (allow_token, n, capacity) key changes on
         adds, re-adds, and compaction, but NOT on deletes — so the
@@ -3414,23 +3584,49 @@ class TpuVectorIndex(VectorIndex):
         dispatch hitting a cache computed after a delete still gathers
         (and keeps) the doc its own world holds live. Excluding
         host_tombs here would break that second direction."""
-        from weaviate_tpu.storage.bitmap import Bitmap, allowed_mask
-
         key = (snap.allow_token, snap.n, snap.capacity)
         cached = getattr(allow_list, "_slots_cache", None)
         if cached is not None and cached[0] == key:
             return cached[1]
-        live_docs = snap.slot_to_doc[: snap.n]
-        if isinstance(allow_list, Bitmap):
-            allowed = allowed_mask(allow_list, live_docs)
+        ids = np.asarray(allow_list.to_array()).astype(np.int64, copy=False)
+        docs = snap.slot_to_doc[: snap.n]
+        if snap.n == 0:
+            slots = ids[:0]
+        elif snap.docs_ascending and docs[-1] - docs[0] == snap.n - 1:
+            # consecutive doc ids (rows put once, from the shard's counter):
+            # the slot is the doc id less the first one
+            lo, hi = np.searchsorted(ids, (docs[0], docs[-1] + 1))
+            slots = ids[lo:hi] - docs[0]
+        elif snap.docs_ascending:
+            at = np.searchsorted(docs, ids)
+            slots = at[docs[np.minimum(at, snap.n - 1)] == ids]
         else:
-            allowed = allow_list.contains_array(live_docs.astype(np.uint64))
-        slots = np.flatnonzero(allowed).astype(np.int32)
+            order, sorted_docs = self._doc_order(snap)
+            lo = np.searchsorted(sorted_docs, ids, side="left")
+            hi = np.searchsorted(sorted_docs, ids, side="right")
+            runs = hi - lo   # a re-added doc holds two slots, one dead
+            first = np.repeat(lo, runs)
+            within = np.arange(first.size) - np.repeat(
+                np.cumsum(runs) - runs, runs)
+            slots = np.sort(order[first + within])
+        slots = slots.astype(np.int32)
         try:
             allow_list._slots_cache = (key, slots)
         except AttributeError:
             pass  # foreign AllowList impls without the cache slot
         return slots
+
+    @staticmethod
+    def _doc_order(snap: IndexSnapshot) -> tuple[np.ndarray, np.ndarray]:
+        """(slots in order of their doc id, those doc ids) of a snapshot
+        whose docs do not ascend with its slots: one sort a snapshot, on
+        the first filter that needs it."""
+        got = snap.doc_order
+        if got is None:
+            docs = snap.slot_to_doc[: snap.n]
+            order = np.argsort(docs, kind="stable")
+            got = snap.doc_order = (order, docs[order])
+        return got
 
     def _dispatch_small_allow(self, snap: IndexSnapshot, q: np.ndarray,
                               b: int, k: int, allow_list: AllowList, shape):
@@ -3808,6 +4004,227 @@ class TpuVectorIndex(VectorIndex):
         snap = self._read_snapshot()
         return self._dispatch_search(snap, vectors, k, allow_list)
 
+    # -- a group of slots, each with its own filter ---------------------------
+
+    def search_by_vectors_multi_async(self, vectors: np.ndarray, k: int,
+                                      allow_lists: Sequence[Optional[AllowList]]):
+        """kNN of a GROUP of slots, slot i under `allow_lists[i]` (None: no
+        filter), in a bounded number of device dispatches whatever the mix
+        of selectivities: at most one per-slot gather program a row bucket
+        (`_search_gathered_multi`: 128, 512, ... rows a slot), ONE masked
+        scan whose every query carries its own mask, and one plain scan for
+        the slots without a filter. Which filtered slots gather and which
+        share the scan is priced for the whole group
+        (costmodel.plan_filtered_group), not cut at a constant a slot.
+        Equal allowList OBJECTS are resolved once.
+
+        -> finalize() -> (ids [S, k'] uint64, dists [S, k'] float32, inf
+        where a slot has fewer than k' answers), with `finalize.shapes`,
+        the DispatchShapes of the dispatches made (empty while the tracer
+        is down); or None where this index state has no per-slot program
+        (compressed, or the IVF plane on): the caller then searches slot
+        by slot. Every slot's answer is exact over the rows its own filter
+        allows in the snapshot read here; tombstones are masked on the
+        device by that snapshot."""
+        snap = self._read_snapshot()
+        if snap.compressed or (snap.n and self._ivf_plan(snap, 1) is not None):
+            return None
+        q = np.array(vectors, dtype=np.float32, ndmin=2)
+        s = q.shape[0]
+        if len(allow_lists) != s:
+            raise ValueError(f"{len(allow_lists)} allowLists for {s} queries")
+        k_eff = min(k, snap.live)
+        if snap.n == 0 or k_eff <= 0:
+            empty = (np.zeros((s, 0), np.uint64), np.zeros((s, 0), np.float32))
+            fin = lambda: empty  # noqa: E731
+            fin.shapes = []
+            return fin
+        faults.fire("index.tpu.dispatch")
+        traced = tracing.get_tracer() is not None
+        # one `enqueue` interval a dispatch; the first also holds the
+        # resolution of the filters' slots and the plan
+        enqueue = tracing.Phase("enqueue") if traced else None
+        shapes: list = []
+        # (slot positions, finalize -> (ids, dists), the shape to stamp)
+        parts: list = []
+        try:
+            if self.metric == vi.DISTANCE_COSINE:
+                norms = np.linalg.norm(q, axis=1, keepdims=True)
+                norms[norms == 0] = 1.0
+                q /= norms
+            bb = _bucket_b(s)
+            slots, gathered, scanned, plain = self._plan_group(
+                snap, allow_lists, bb)
+            jobs = [(costmodel.TIER_GATHER, r, gathered[r])
+                    for r in sorted(gathered)]
+            if scanned:
+                jobs.append((costmodel.TIER_EXACT, snap.n, scanned))
+            for tier, rows, sel in jobs:
+                lists = [slots[i] for i in sel]
+                gather = tier == costmodel.TIER_GATHER
+                shape = None
+                if traced:
+                    enqueue = enqueue or tracing.Phase("enqueue")
+                    shape = costmodel.DispatchShape(
+                        tier, dim=snap.dim, batch=len(sel), k=int(k_eff),
+                        n=sum(sl.size for sl in lists) if gather else snap.n,
+                        batch_padded=(_gather_slots(bb, rows) if gather
+                                      else _scan_group_bucket(len(sel), bb)),
+                        bytes_per_row=snap.dim * snap.store.dtype.itemsize,
+                        extra={"row_bucket": rows})
+                parts.append((sel, (
+                    self._dispatch_gather_group(snap, q[sel], lists, rows,
+                                                bb, k_eff, shape) if gather
+                    else self._dispatch_scan_group(snap, q[sel], lists, bb,
+                                                   k_eff, shape)), shape))
+                if shape is not None:
+                    end_ns = enqueue.end(rows=len(sel), tier=tier,
+                                         row_bucket=rows)
+                    shape.t_start = enqueue.start_ns / 1e9
+                    shape.enqueue_ms = (end_ns - enqueue.start_ns) / 1e6
+                    shapes.append(shape)
+                    enqueue = None
+            if plain:
+                if enqueue is not None:
+                    enqueue.end()   # _dispatch_search opens its own
+                    enqueue = None
+                # the unfiltered dispatch as it always was; its own
+                # finalize stamps its shape
+                parts.append((plain, self._dispatch_search(
+                    snap, q[plain], k_eff, None), None))
+                shape = self.pop_dispatch_shape()
+                if shape is not None:
+                    shapes.append(shape)
+        finally:
+            if enqueue is not None:   # nothing dispatched, or a failure
+                enqueue.end()
+        # the audit pin belongs to single dispatches; leave none behind
+        self.pop_audit_snapshot()
+        self._track_inflight(1)
+        done = [False]
+
+        def finalize():
+            try:
+                faults.fire("index.tpu.finalize")
+                ids = np.zeros((s, k_eff), np.uint64)
+                dists = np.full((s, k_eff), np.inf, np.float32)
+                for sel, fin, shape in parts:  # graftlint: disable=JGL015 a loop over the group's DISPATCHES (a handful: one a row bucket, one scan), each consumed by unpack_fused; no per-row work
+                    t0 = time.perf_counter()
+                    try:
+                        pi, pd = fin()
+                    finally:
+                        if shape is not None:
+                            shape.t_end = shape.end_hop()
+                            shape.finalize_ms = (shape.t_end - t0) * 1000.0
+                    ids[sel, : pi.shape[1]] = pi
+                    dists[sel, : pd.shape[1]] = pd
+                return ids, dists
+            finally:
+                if not done[0]:
+                    done[0] = True
+                    self._track_inflight(-1)
+
+        finalize.shapes = shapes
+        return finalize
+
+    def _row_store(self, snap: IndexSnapshot):
+        """The store as the per-slot programs read it: rows in whole lanes.
+        A width that is a multiple of 128 is the store itself. Any other
+        (192 is one and a half lanes) the TPU keeps column-major, so that
+        nothing is padded, and every program that gathers rows from it
+        first copies the WHOLE slab into the row-major tiled layout (read
+        from the chip's compiler, tests/perfbench/test_perfbench_compile.py).
+        So the twin is made once a store generation (a write replaces the
+        store object, and with it the twin) and zero columns change no
+        distance; the queries are padded to match."""
+        store = snap.store
+        dim = store.shape[1]
+        if dim % 128 == 0:
+            return store
+        with self._row_store_lock:
+            hit = self._row_store_cache
+            if hit is None or hit[0] is not store:
+                hit = self._row_store_cache = (store, _pad_rows(store))  # graftflow: disable=JGL018 generation-keyed single-entry cache (the store object is the key: a write replaces it) with an explicit release in compact and drop
+            return hit[1]
+
+    def _plan_group(self, snap: IndexSnapshot, allow_lists, bb: int):
+        """Which program serves each slot of a group -> (each slot's store
+        slots, None without a filter; {row bucket: the slots that gather in
+        it}; the slots that share the scan; the slots without a filter). A
+        slot whose filter allows no row rides no dispatch. Equal allowList
+        OBJECTS are resolved once."""
+        by_list: dict[int, np.ndarray] = {}
+        slots: list = []
+        for a in allow_lists:
+            if a is not None and id(a) not in by_list:
+                by_list[id(a)] = self._allow_slots(snap, a)
+            slots.append(None if a is None else by_list[id(a)])
+        plain = [i for i, sl in enumerate(slots) if sl is None]
+        filtered = [i for i, sl in enumerate(slots)
+                    if sl is not None and sl.size]
+        sizes = [int(slots[i].size) for i in filtered]
+        buckets = [_gather_row_bucket(m) for m in sizes]
+        to_scan, _ = costmodel.plan_filtered_group(
+            sizes, buckets, snap.n, snap.capacity,
+            snap.dim * snap.store.dtype.itemsize,
+            gather_max_rows(snap.dim, snap.capacity))
+        gathered: dict[int, list[int]] = {}
+        scanned: list[int] = []
+        for i, r, scan in zip(filtered, buckets, to_scan):
+            (scanned if scan else gathered.setdefault(r, [])).append(i)
+        for r, sel in gathered.items():
+            # a bucket takes as many slots as its index upload allows; the
+            # rest share the scan
+            room = _gather_slots(bb, r)
+            scanned.extend(sel[room:])
+            del sel[room:]
+        return slots, gathered, sorted(scanned), plain
+
+    def _dispatch_gather_group(self, snap: IndexSnapshot, q: np.ndarray,
+                               slot_lists: list, r: int, bb: int, k: int,
+                               shape):
+        """One per-slot gather program: bucket `r`, the group's slots of
+        that bucket. The shapes compiled are one a (group-width bucket, row
+        bucket, k): the slot dimension is padded to what the bucket admits
+        and the program loops over the real slots alone."""
+        nsel = len(slot_lists)
+        s_pad = _gather_slots(bb, r)
+        step = min(_gather_step_slots(r, snap.dim), s_pad)
+        rows = np.zeros((s_pad, r), np.int32)
+        counts = np.zeros(s_pad, np.int32)
+        for j, sl in enumerate(slot_lists):
+            rows[j, : sl.size] = sl
+            counts[j] = sl.size
+        store = self._row_store(snap)
+        qp = np.zeros((s_pad, store.shape[1]), np.float32)
+        qp[:nsel, : snap.dim] = q
+        cols = snap.s2d_cols
+        if cols is None:
+            cols = snap.s2d_cols = _split_doc_pairs(snap.slot_to_doc_dev)
+        packed_dev = _search_gathered_multi(
+            store, jnp.asarray(qp), jnp.asarray(rows),
+            jnp.asarray(counts), np.int32(nsel), snap.tombs, cols[0], cols[1],
+            min(k, r), self.metric, step)
+        return self._finalize_fused(packed_dev, shape, nsel)
+
+    def _dispatch_scan_group(self, snap: IndexSnapshot, q: np.ndarray,
+                             slot_lists: list, bb: int, k: int, shape):
+        """ONE masked scan for the group's widest filters: query i is
+        masked by its own [capacity / 32] words. The lax.scan program
+        serves (the Pallas kernel takes one mask a dispatch)."""
+        nsel = len(slot_lists)
+        b_pad = _scan_group_bucket(nsel, bb)
+        words = np.zeros((b_pad, snap.capacity // 32), np.uint32)
+        for j, sl in enumerate(slot_lists):
+            _slot_words(sl, snap.capacity, out=words[j])
+        store = self._row_store(snap)
+        qp = np.zeros((b_pad, store.shape[1]), np.float32)
+        qp[:nsel, : snap.dim] = q
+        kk = min(max(k, 1), snap.n)
+        return self._finalize_fused(
+            self._scan_program(snap, store, snap.sq_norms, qp,
+                               jnp.asarray(words), kk), shape, nsel)
+
     def search_by_vector_distance(
         self,
         vector: np.ndarray,
@@ -3911,11 +4328,13 @@ class TpuVectorIndex(VectorIndex):
             self._doc_to_slot.clear()
             self._store = self._sq_norms = self._tombs = None
             self._s2d_dev = None
+            self._row_store_cache = None
             # the partition layout indexes the OLD slot space — drop it
             # wholesale; the post-rebuild retrain below is the
             # "recluster on compact" half of the IVF lifecycle
             self._ivf_reset()
             self._slot_to_doc = np.zeros(0, dtype=np.int64)
+            self._docs_ascending = True
             self._host_tombs = np.zeros(0, dtype=bool)
             # suppress the declarative compress trigger for the rebuild:
             # config.pq.enabled is true for ANY compressed index (compress
@@ -3961,12 +4380,14 @@ class TpuVectorIndex(VectorIndex):
                 self._log = None
             self._store = self._sq_norms = self._tombs = None
             self._s2d_dev = None
+            self._row_store_cache = None
             self._ivf_reset()
             self.dim = None
             self.capacity = 0
             self.n = 0
             self.live = 0
             self._slot_to_doc = np.zeros(0, dtype=np.int64)
+            self._docs_ascending = True
             self._host_tombs = np.zeros(0, dtype=bool)
             with self._stage_lock:
                 # parked staging buffers die with the data (a re-created

@@ -317,3 +317,63 @@ def roofline_from_qps(qps, n, dim, batch, bytes_per_row,
     batches_per_s = qps / batch
     return roofline(flops_per_batch * batches_per_s,
                     bytes_per_batch * batches_per_s, 1.0, backend)
+
+
+# -- a group of filtered slots: which slots gather, which share one scan ------
+# Modelled seconds of the two tiers that serve a filtered slot
+# (index/tpu.py search_by_vectors_multi_async). A gathered row is read at a
+# fraction of the streamed rate; a scanned slot pays the host for its
+# [capacity / 32] words (packed and uploaded) and shares ONE pass over the
+# slab with every other scanned slot; every program pays a launch. The
+# constants are orders of magnitude, set from the traced runs in PERF.md
+# (section 6, PR 30), not fitted: what matters is that the hand-over is a
+# comparison of whole dispatches, so a slot a row under and a row over it
+# cost about the same.
+# a gathered row against a streamed one: 31-42 ns a 768 B row in the
+# 2,048- and 8,192-row programs on a v5e (my chip run, PR 30), 0.94 streamed
+GATHER_ROW_SLOWDOWN = 32.0
+SCAN_EFFICIENCY = 0.7           # share of the HBM peak a masked scan reaches
+# packing and uploading words or row indices: about 1 ms for the 256 KB of
+# words of one slot at capacity 2^21 (profiled on the sandbox's CPU, PR 30)
+HOST_BYTES_PER_S = 2.5e8
+LAUNCH_S = 150e-6               # one more program: enqueue, fetch, unpack
+
+
+def plan_filtered_group(sizes, buckets, scan_rows: int, capacity: int,
+                        bytes_per_row: float, max_gather_rows: int,
+                        backend: Optional[str] = None
+                        ) -> tuple[list[bool], float]:
+    """-> (for each slot whether the masked scan serves it, else the gather;
+    the modelled seconds of the group served so).
+
+    `sizes[i]` rows slot i's filter allows (> 0), `buckets[i]` the rows its
+    gather would read (its row bucket). The cheapest partition sends the
+    slots above some size to the scan (a scanned slot's cost does not
+    depend on its size, a gathered slot's grows with it), so every cut of
+    the slots in order of size is priced and the cheapest kept. A slot
+    whose bucket passes `max_gather_rows` has no gather program."""
+    hbm = PEAKS[backend or detect_backend()]["hbm_gbs"] * 1e9
+    scan_once = scan_rows * bytes_per_row / (hbm * SCAN_EFFICIENCY) + LAUNCH_S
+    scan_slot = (capacity / 8.0) / HOST_BYTES_PER_S
+    gather_row = bytes_per_row * GATHER_ROW_SLOWDOWN / hbm \
+        + 4.0 / HOST_BYTES_PER_S
+    order = sorted(range(len(sizes)), key=lambda i: sizes[i])
+    # cost of gathering the `cut` smallest slots and scanning the rest
+    best_cut, best = 0, float("inf")
+    gathered, launched = 0.0, set()
+    for cut in range(len(order) + 1):
+        if cut:
+            i = order[cut - 1]
+            if buckets[i] > max_gather_rows:
+                break
+            gathered += buckets[i] * gather_row
+            launched.add(buckets[i])
+        scanned = len(order) - cut
+        cost = gathered + LAUNCH_S * len(launched) \
+            + (scan_once + scan_slot * scanned if scanned else 0.0)
+        if cost < best:
+            best_cut, best = cut, cost
+    to_scan = [True] * len(sizes)
+    for i in order[:best_cut]:
+        to_scan[i] = False
+    return to_scan, best

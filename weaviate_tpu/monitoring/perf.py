@@ -149,7 +149,8 @@ class PerfWindow:
         # up), but readers correlating with /debug/traces need the rate
         self.sample_hint = float(sample_hint)
         self._lock = threading.Lock()
-        # (t_end_mono, tier, rows, one-fetch invariant violated)
+        # (t_end_mono, tier, rows, one-fetch invariant violated, store
+        # rows the dispatch read: DispatchShape.n)
         self._entries: deque = deque()
         # phase name -> deque[(t_mono, ms)], count-capped (see
         # _PHASE_SAMPLES_MAX) on top of the time-horizon eviction
@@ -202,7 +203,8 @@ class PerfWindow:
         nrows = int(rows) or shape.batch
         with self._lock:
             self._evict(now)
-            self._entries.append((now, shape.tier, nrows, viol))
+            self._entries.append((now, shape.tier, nrows, viol,
+                                  int(shape.n)))
             self._rows += nrows
             self._total_dispatches += 1
             if self._first_entry is None:
@@ -385,9 +387,11 @@ class PerfWindow:
             phase_ms = {p: [ms for _, ms in d]
                         for p, d in self._phase.items() if d}
             tiers: dict[str, int] = {}
+            tier_rows: dict[str, int] = {}
             violations = 0
-            for _, tier, _, viol in self._entries:
+            for _, tier, _, viol, read in self._entries:
                 tiers[tier] = tiers.get(tier, 0) + 1
+                tier_rows[tier] = tier_rows.get(tier, 0) + read
                 violations += viol
             total_dispatches = self._total_dispatches
             point_get = [sum(c) for c in list(zip(*self._point_get))[1:]]
@@ -427,6 +431,11 @@ class PerfWindow:
                 ("keys", "segment_probes", "key_compares", "arena_grows"),
                 point_get))
         out["tiers"] = dict(sorted(tiers.items(), key=lambda kv: -kv[1]))
+        # the store rows each tier's dispatches read over the window: all
+        # live rows a scan, the probed rows an IVF dispatch, and for a
+        # per-slot gather the sum over its slots of the rows gathered. What
+        # a roofline may charge a program that reads a part of the rows.
+        out["tier_rows"] = {t: tier_rows[t] for t in out["tiers"]}
         # invariant violations over the window
         # (costmodel.fused_invariant_ok): every dispatch translates on the
         # device, so `dispatches` is all of them; violations > 0 means a

@@ -540,19 +540,26 @@ class Stopwatch:
     histogram read one interval; otherwise a bare ``perf_counter_ns``
     pair and nothing else."""
 
-    __slots__ = ("_phase", "start_ns", "ms")
+    __slots__ = ("_phase", "_late", "start_ns", "ms")
 
     def __init__(self, name: str, **stats):
         self._phase = Phase(name, **stats) if _tracer is not None else None
+        self._late: Optional[dict] = None
         self.start_ns = (self._phase.start_ns if self._phase is not None
                          else time.perf_counter_ns())
         self.ms = -1.0
+
+    def note(self, **stats) -> None:
+        """Stats known only while the stage runs (how many filters a group
+        resolved): they join the annotation when it closes."""
+        self._late = {**(self._late or {}), **stats}
 
     def __enter__(self) -> "Stopwatch":
         return self
 
     def __exit__(self, *exc) -> None:
-        end_ns = (self._phase.end() if self._phase is not None
+        end_ns = (self._phase.end(**(self._late or {}))
+                  if self._phase is not None
                   else time.perf_counter_ns())
         self.ms = (end_ns - self.start_ns) / 1e6
 

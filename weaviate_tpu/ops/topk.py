@@ -92,14 +92,25 @@ def translate_pack(top: Array, idx: Array, s2d: Array) -> Array:
     host-side slot->doc table reads (the JGL015 contract)."""
     safe = jnp.clip(idx, 0, s2d.shape[0] - 1)
     pair = jnp.take(s2d, safe, axis=0)  # [B, k, 2] u32
-    miss = idx < 0
+    return _pack_fused(top, idx < 0, pair[..., 0], pair[..., 1])
+
+
+def translate_pack_split(top: Array, idx: Array, s2d_lo: Array,
+                         s2d_hi: Array) -> Array:
+    """translate_pack over the table's two columns as 1-D arrays
+    ([capacity] each): the per-slot gather program reads them, because
+    there XLA re-lays the [capacity, 2] table out before every lookup."""
+    safe = jnp.clip(idx, 0, s2d_lo.shape[0] - 1)
+    return _pack_fused(top, idx < 0, jnp.take(s2d_lo, safe),
+                       jnp.take(s2d_hi, safe))
+
+
+def _pack_fused(top: Array, miss: Array, lo: Array, hi: Array) -> Array:
     sent = jnp.uint32(_MISS_WORD)
-    lo = jnp.where(miss, sent, pair[..., 0])
-    hi = jnp.where(miss, sent, pair[..., 1])
     return jnp.concatenate([
         jax.lax.bitcast_convert_type(top, jnp.int32),
-        jax.lax.bitcast_convert_type(lo, jnp.int32),
-        jax.lax.bitcast_convert_type(hi, jnp.int32),
+        jax.lax.bitcast_convert_type(jnp.where(miss, sent, lo), jnp.int32),
+        jax.lax.bitcast_convert_type(jnp.where(miss, sent, hi), jnp.int32),
     ], axis=1)
 
 

@@ -58,7 +58,9 @@ class Bitmap(AllowList):
     # bitmap for the index state it was built against (see _allow_words in
     # index/tpu.py + index/mesh.py). Bitmaps are immutable, so repeated
     # filtered queries with the same filter skip the whole host pack.
-    __slots__ = ("_ids", "_words_cache")
+    # _slots_cache: likewise one (token-tuple, store slots) pair: the
+    # filter's rows in that index state (index/tpu.py _allow_slots).
+    __slots__ = ("_ids", "_words_cache", "_slots_cache")
 
     def __init__(self, ids: Optional[Iterable[int] | np.ndarray] = None, _sorted: bool = False):
         if ids is None:
@@ -92,7 +94,13 @@ class Bitmap(AllowList):
     # -- set algebra (searcher_doc_bitmap.go:25-109 merge semantics) ---------
 
     def and_(self, other: "Bitmap") -> "Bitmap":
-        return Bitmap(np.intersect1d(self._ids, other._ids), _sorted=True)
+        # both sorted and unique: look the smaller up in the larger (a
+        # binary search an id) instead of sorting the two together
+        small, big = sorted((self._ids, other._ids), key=len)
+        if small.size == 0:
+            return Bitmap()
+        at = np.minimum(np.searchsorted(big, small), big.size - 1)
+        return Bitmap(small[big[at] == small], _sorted=True)
 
     def or_(self, other: "Bitmap") -> "Bitmap":
         return Bitmap(np.union1d(self._ids, other._ids), _sorted=True)

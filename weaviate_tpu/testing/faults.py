@@ -17,6 +17,10 @@ Injection points (the fault matrix; see docs/robustness.md):
                            allocator OOM on the write path
   db.shard.search          shard read entry (db/shard.py) — pre-dispatch
                            failure
+  db.shard.search_group    a group of slots, each under its own filter
+                           (db/shard.py object_vector_search_multi_async)
+                           — pre-dispatch failure of the group: the
+                           traverser serves every slot on the single path
   serving.coalescer.flush  the flush loop (serving/coalescer.py _run) —
                            flush-thread death (a BaseException that
                            escapes the loop's `except Exception` defense)

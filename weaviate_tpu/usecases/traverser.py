@@ -261,7 +261,6 @@ class Explorer:
                     p.near_vector is not None
                     and p.near_vector.get("vector") is not None
                     and not (p.hybrid or p.keyword_ranking or p.group_by or p.group or p.sort)
-                    and p.filters is None
                     and p.near_vector.get("distance") is None
                     and p.near_vector.get("certainty") is None
                 ):
@@ -304,6 +303,14 @@ class Explorer:
         # in-flight vector dispatches instead of serializing two round trips.
         pending: list[tuple] = []
         for (class_name, limit, offset, inc_vec), idxs in batchable.items():
+            if any(params_list[i].filters is not None for i in idxs):
+                # slots that carry a filter ride the group, each under its
+                # own; where the index takes one filter a dispatch they are
+                # searched one by one and the rest go on as a group
+                idxs = self._filtered_group(out, pending, params_list, idxs,
+                                            class_name, limit, offset, inc_vec)
+                if not idxs:
+                    continue
             try:
                 idx = self._index(class_name)
                 vecs = np.stack(
@@ -368,7 +375,9 @@ class Explorer:
             try:
                 res = done()
                 for j, i in enumerate(idxs):
-                    out[i] = self._postprocess(params_list[i], res[j][offset:])
+                    # a slot whose own filter failed holds its exception
+                    out[i] = res[j] if isinstance(res[j], Exception) else \
+                        self._postprocess(params_list[i], res[j][offset:])
             except (robustness.DeadlineExceededError,
                     robustness.OverloadedError) as e:
                 # fail fast per slot — no direct-path retry (see _get_one)
@@ -381,6 +390,51 @@ class Explorer:
                     except Exception as e2:
                         out[i] = e2
         return out  # type: ignore[return-value]
+
+    def _filtered_group(self, out: list, pending: list, params_list, idxs,
+                        class_name: str, limit: int, offset: int,
+                        inc_vec: bool) -> list[int]:
+        """A nearVector group in which some slot carries a filter: enqueue
+        it whole, one filter (or none) a slot, where the index serves that
+        (hnsw_tpu on a single local shard) -> []; else search the filtered
+        slots one by one here (the mesh index and the coalescer's lanes
+        take one filter a dispatch) -> the slots without a filter, which go
+        on as a group. A group of ONE slot stays on the single path, which
+        has the coalescer's per-filter lanes and the host fallback."""
+        done = None
+        if len(idxs) > 1 and self.coalescer is None:
+            try:
+                idx = self._index(class_name)
+                submit = getattr(idx, "object_vector_search_multi_async", None)
+                if submit is not None:
+                    vecs = np.stack([
+                        np.asarray(params_list[i].near_vector["vector"],
+                                   np.float32) for i in idxs])
+                    done = submit(vecs, limit + offset,
+                                  [params_list[i].filters for i in idxs],
+                                  inc_vec)
+            except (robustness.DeadlineExceededError,
+                    robustness.OverloadedError) as e:
+                for i in idxs:
+                    out[i] = e
+                return []
+            except Exception:
+                # ragged shapes, a bad class, a failure before the device
+                # (fault point db.shard.search_group): slot by slot
+                done = None
+        if done is not None:
+            pending.append((idxs, offset, done))
+            return []
+        rest = []
+        for i in idxs:
+            if params_list[i].filters is None:
+                rest.append(i)
+                continue
+            try:
+                out[i] = self._get_one(params_list[i])
+            except Exception as e:
+                out[i] = e
+        return rest
 
     def _index(self, class_name: str):
         resolved = self.schema.resolve_class_name(class_name)
