@@ -32,7 +32,25 @@
 // Segment layout (storage/lsm.py Segment):
 //   "WTSG" | strategy u8 | entries... | footer | footer_off u64
 //   footer: count u64, then per entry: klen u32 | key | off u64 | len u64
-// Only STRATEGY_REPLACE (index 0) segments are served here.
+// STRATEGY_REPLACE (index 0) segments serve the point gets,
+// STRATEGY_ROARINGSET (index 3) segments the posting walk:
+//
+//   lsm_posting_locate  finds ONE key in every segment of a roaring-set
+//               bucket, oldest first, and writes where each layer's
+//               additions live and how many ids they hold; it refuses
+//               a key one of whose layers deletes from what came
+//               before it, which the Python walk settles.
+//   lsm_posting_copy    copies the located layers into ONE uint64 buffer
+//               and says whether the joined ids ascend (they do where doc
+//               ids come from the counter: then the posting needs no sort).
+//   lsm_ids_gallop  the ids two ascending arrays share: the smaller
+//               gallops through the larger.
+//   lsm_bits_build, lsm_bits_probe  the same through a bitset of the
+//               larger, made once and asked by every filter that holds it.
+//
+//   roaring-set payload (storage/lsm.py _enc_roaring, storage/bitmap.py):
+//     la u32 | ld u32 | additions | deletions, each
+//     "WTBM" | n u64 | n ids u64 (ascending)
 //
 // Concurrency contract with the Python side (storage/lsm.py Bucket):
 //   - the caller snapshots the segment handle list under the bucket lock
@@ -59,6 +77,13 @@ constexpr unsigned char kMagic[4] = {'W', 'T', 'S', 'G'};
 constexpr unsigned char kTomb[] = "\x00__wt_tombstone__";
 constexpr int64_t kTombLen = 17;
 
+// storage/lsm.py STRATEGIES
+constexpr uint8_t kReplace = 0;
+constexpr uint8_t kRoaringSet = 3;
+
+// storage/bitmap.py _MAGIC
+constexpr unsigned char kBitmapMagic[4] = {'W', 'T', 'B', 'M'};
+
 struct Entry {
     const uint8_t* key;
     uint64_t key_len;
@@ -68,6 +93,7 @@ struct Entry {
 
 struct Seg {
     int fd = -1;
+    uint8_t strategy = 0;  // storage/lsm.py STRATEGIES index
     const uint8_t* base = nullptr;
     size_t size = 0;
     std::vector<Entry> entries;  // footer order (sorted by key)
@@ -143,6 +169,17 @@ inline const Entry* seg_find(const Seg& s, uint64_t h, const uint8_t* key,
     }
 }
 
+// One serialized Bitmap at p (`len` bytes) -> its ids and their count, or
+// false where the bytes are no bitmap.
+bool bitmap_at(const uint8_t* p, uint64_t len, const uint8_t** ids,
+               uint64_t* n) {
+    if (len < 12 || std::memcmp(p, kBitmapMagic, 4) != 0) return false;
+    std::memcpy(n, p + 4, 8);
+    if (*n > (len - 12) / 8) return false;
+    *ids = p + 12;
+    return true;
+}
+
 }  // namespace
 
 extern "C" {
@@ -173,7 +210,9 @@ void* lsm_seg_open(const char* path) {
     // the process — the contract here is nullptr-and-fallback, never a crash
     const uint8_t* p = s->base;
     const uint64_t size = s->size;
-    bool ok = std::memcmp(p, kMagic, 4) == 0 && p[4] == 0 /* replace */;
+    bool ok = std::memcmp(p, kMagic, 4) == 0 &&
+              (p[4] == kReplace || p[4] == kRoaringSet);
+    s->strategy = p[4];
     if (ok) {
         uint64_t footer_off;
         std::memcpy(&footer_off, p + size - 8, 8);
@@ -254,6 +293,9 @@ int64_t lsm_multi_get(void** segs, int64_t n_segs, const uint8_t* keys,
                       const uint8_t** srcs, int64_t* out_offs, int8_t* flags,
                       int64_t* stats, uint8_t* out, int64_t out_cap) {
     int64_t total = 0, probes = 0, compares = 0;
+    // a roaring-set segment's payloads are no values: the Python reader's
+    for (int64_t si = 0; si < n_segs; si++)
+        if (static_cast<Seg*>(segs[si])->strategy != kReplace) return -1;
     out_offs[0] = 0;
     for (int64_t i = 0; i < n_keys; i++) {
         const uint8_t* key = keys + key_offs[i];
@@ -283,6 +325,120 @@ int64_t lsm_multi_get(void** segs, int64_t n_segs, const uint8_t* keys,
     stats[1] = compares;
     if (total <= out_cap) lsm_copy(srcs, out_offs, n_keys, out);
     return total;
+}
+
+// One key's posting over an OLDEST-FIRST list of roaring-set segments.
+//   srcs/counts: per segment that holds additions for the key, in order:
+//             where its ids live in the mapping, and how many.
+//   stats:    {layers written to srcs/counts, segment probes}.
+// -> the ids of all layers together (what lsm_posting_copy will write).
+// Where the walk is not this plane's to serve, the Python walk serves the
+// key: -1 a layer deletes ids from what older layers added; -2 a payload
+// does not parse, or a segment is of another strategy.
+int64_t lsm_posting_locate(void** segs, int64_t n_segs, const uint8_t* key,
+                           int64_t klen, const uint8_t** srcs,
+                           int64_t* counts, int64_t* stats) {
+    const uint64_t h = hash_key(key, static_cast<uint64_t>(klen));
+    int64_t total = 0, layers = 0, probes = 0, compares = 0;
+    for (int64_t si = 0; si < n_segs; si++) {
+        const Seg& s = *static_cast<Seg*>(segs[si]);
+        if (s.strategy != kRoaringSet) return -2;
+        probes++;
+        const Entry* ent =
+            seg_find(s, h, key, static_cast<uint64_t>(klen), compares);
+        if (ent == nullptr) continue;
+        const uint8_t* p = s.base + ent->off;
+        if (ent->len < 8) return -2;
+        uint32_t la, ld;
+        std::memcpy(&la, p, 4);
+        std::memcpy(&ld, p + 4, 4);
+        if (ent->len - 8 < la || ent->len - 8 - la < ld) return -2;
+        const uint8_t *adds, *dels;
+        uint64_t n_adds, n_dels;
+        if (!bitmap_at(p + 8, la, &adds, &n_adds) ||
+            !bitmap_at(p + 8 + la, ld, &dels, &n_dels))
+            return -2;
+        if (n_dels > 0 && total > 0) return -1;
+        if (n_adds == 0) continue;
+        srcs[layers] = adds;
+        counts[layers] = static_cast<int64_t>(n_adds);
+        layers++;
+        total += static_cast<int64_t>(n_adds);
+    }
+    stats[0] = layers;
+    stats[1] = probes;
+    return total;
+}
+
+// The located layers, joined in `out` (the sum of `counts` ids), under the
+// in-flight hold lsm_posting_locate ran under. -> 1 where the joined ids
+// ascend strictly (sorted and unique as they stand), else 0.
+int64_t lsm_posting_copy(const uint8_t* const* srcs, const int64_t* counts,
+                         int64_t layers, uint64_t* out) {
+    int64_t at = 0, ascends = 1;
+    uint64_t last = 0;
+    for (int64_t i = 0; i < layers; i++) {
+        const int64_t n = counts[i];
+        std::memcpy(out + at, srcs[i], static_cast<size_t>(n) * 8);
+        // a layer ascends by construction (a Bitmap's ids): only the seam
+        // between two layers is in question
+        if (at > 0 && out[at] <= last) ascends = 0;
+        last = out[at + n - 1];
+        at += n;
+    }
+    return ascends;
+}
+
+// out (holds na) <- the ids in both a and b (each ascending and unique,
+// na <= nb) -> how many. The smaller gallops through the larger: doubling
+// steps from the last match, then a binary search.
+int64_t lsm_ids_gallop(const uint64_t* a, int64_t na, const uint64_t* b,
+                       int64_t nb, uint64_t* out) {
+    int64_t n = 0, lo = 0;
+    for (int64_t i = 0; i < na && lo < nb; i++) {
+        const uint64_t x = a[i];
+        int64_t step = 1, hi = lo;
+        while (hi < nb && b[hi] < x) {
+            lo = hi + 1;
+            hi += step;
+            step <<= 1;
+        }
+        if (hi > nb) hi = nb;
+        // first index in [lo, hi] whose id is not below x
+        while (lo < hi) {
+            const int64_t mid = lo + ((hi - lo) >> 1);
+            if (b[mid] < x) lo = mid + 1; else hi = mid;
+        }
+        if (lo < nb && b[lo] == x) out[n++] = x;
+    }
+    return n;
+}
+
+// bits (zeroed, ((b[nb-1] - base) >> 6) + 1 words) <- one bit an id of b,
+// id `base` (a multiple of 64, not above b[0]) the first: the look-up
+// table of a posting that several intersections ask (doc ids come from a
+// counter, so a popular posting's span is dense).
+void lsm_bits_build(const uint64_t* b, int64_t nb, uint64_t base,
+                    uint64_t* bits) {
+    for (int64_t j = 0; j < nb; j++) {
+        const uint64_t d = b[j] - base;
+        bits[d >> 6] |= 1ULL << (d & 63);
+    }
+}
+
+// out (holds na) <- the ids of a (ascending) whose bit is set -> how many.
+int64_t lsm_bits_probe(const uint64_t* a, int64_t na, const uint64_t* bits,
+                       uint64_t base, int64_t words, uint64_t* out) {
+    int64_t n = 0;
+    for (int64_t i = 0; i < na; i++) {
+        const uint64_t x = a[i];
+        if (x < base) continue;
+        const uint64_t d = x - base;
+        if ((d >> 6) >= static_cast<uint64_t>(words)) break;
+        out[n] = x;
+        n += (bits[d >> 6] >> (d & 63)) & 1;
+    }
+    return n;
 }
 
 // The table's hash of a key (tests search it for colliding keys).

@@ -402,7 +402,8 @@ def test_mixed_slots_equal_filters_and_one_bad_filter(tmp_path):
         shard = next(iter(app.db.get_index("Tagged").shards.values()))
         built = []
         real = shard.build_allow_list
-        shard.build_allow_list = lambda flt: built.append(flt) or real(flt)
+        shard.build_allow_list = \
+            lambda flt, memo=None: built.append(flt) or real(flt, memo)
         sv = SearchServicer(app)
         rows = rng.integers(0, ROWS, 24)
         reqs, asks = [], []

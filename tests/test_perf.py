@@ -592,6 +592,8 @@ def test_disabled_serving_path_constructs_no_perf_objects(tmp_path,
                         spy("PerfWindow.note_interval"))
     monkeypatch.setattr(perf.PerfWindow, "note_point_get",
                         spy("PerfWindow.note_point_get"))
+    monkeypatch.setattr(perf.PerfWindow, "note_posting",
+                        spy("PerfWindow.note_posting"))
     monkeypatch.setattr(tracing, "Phase", spy("Phase"))
     monkeypatch.setattr(tracing, "_TraceMe", spy("TraceAnnotation"))
     try:

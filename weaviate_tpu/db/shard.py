@@ -42,7 +42,7 @@ from weaviate_tpu.serving import robustness
 from weaviate_tpu.testing import faults, sanitizers
 from weaviate_tpu.inverted.bm25 import BM25Searcher
 from weaviate_tpu.inverted.index import InvertedIndex
-from weaviate_tpu.inverted.searcher import FilterSearcher
+from weaviate_tpu.inverted.searcher import FilterSearcher, PostingMemo
 from weaviate_tpu.storage.bitmap import Bitmap
 from weaviate_tpu.storage.docid import Counter
 from weaviate_tpu.storage.lsm import STRATEGY_REPLACE, Store
@@ -494,8 +494,12 @@ class Shard:
         with self._lock:
             return self._write_gen
 
-    def build_allow_list(self, flt: Optional[LocalFilter]) -> Optional[Bitmap]:
-        """filters -> allowList (shard_read.go:377 buildAllowList).
+    def build_allow_list(self, flt: Optional[LocalFilter],
+                         memo: Optional[PostingMemo] = None
+                         ) -> Optional[Bitmap]:
+        """filters -> allowList (shard_read.go:377 buildAllowList). `memo`:
+        the postings of the group's `filter` phase, where `flt` is one of a
+        group's filters (object_vector_search_multi_async).
 
         Cached per filter CONTENT for the current write generation: the
         serving path constructs a fresh LocalFilter/Bitmap per request, so
@@ -518,7 +522,7 @@ class Shard:
             return None
         key = filter_signature(flt)
         if key is None:  # unhashable filter: just evaluate
-            return self.searcher.doc_ids(flt)
+            return self.searcher.doc_ids(flt, memo)
         gen = self._locked_gen()
         hit = self._allow_cache.get(key)
         if hit is not None and hit[0] == gen:
@@ -529,7 +533,7 @@ class Shard:
             self._allow_cache.pop(key, None)
             self._allow_cache[key] = hit
             return hit[1]
-        allow = self.searcher.doc_ids(flt)
+        allow = self.searcher.doc_ids(flt, memo)
         if self._locked_gen() == gen:
             tenant = robustness.effective_tenant(self.class_def.name) or ""
             # small LRU: hot filters are few
@@ -1056,12 +1060,18 @@ class Shard:
         """A GROUP of kNN slots, slot i under its own filter `flts[i]` (None:
         no filter), in a bounded number of device dispatches (index/tpu.py
         search_by_vectors_multi_async) and one hydration. All the group's
-        filters resolve in ONE `filter` phase on the submitting thread
-        (`filters` and `distinct` in its stats): equal filters (one
-        signature) are evaluated once, through the same allowList cache a
-        single search uses. -> finalize() -> a list with, for each slot,
-        its hydrated results or the Exception its own filter raised (the
-        other slots are served); or None where this shard serves one
+        filters resolve in ONE `filter` phase on the submitting thread:
+        equal filters (one signature) are evaluated once, through the same
+        allowList cache a single search uses, and every distinct posting
+        the group's filters ask is read from its bucket once (a
+        PostingMemo that lives for this phase and no longer: a popular tag
+        that eighty two-tag filters hold is one read, and the next group
+        reads the bucket afresh). Its stats: `filters`, `distinct`
+        (signatures), `tags` (distinct postings read), `memo_hits` (leaf
+        reads the memo served), `ids` (ids read from the buckets). ->
+        finalize() -> a list with, for each slot, its hydrated results or
+        the Exception its own filter raised (the other slots are served);
+        or None where this shard serves one
         filter a dispatch (an index without the per-slot programs, the
         breaker open): the caller then searches slot by slot, through the
         path that has the host fallback. A device error feeds the breaker
@@ -1083,7 +1093,7 @@ class Shard:
         cls = self.class_def.name
         failed: dict[int, Exception] = {}
         allows: list = [None] * len(flts)
-        with tracing.Stopwatch("filter") as sw:
+        with tracing.Stopwatch("filter") as sw, PostingMemo() as memo:
             by_sig: dict = {}
             for i, flt in enumerate(flts):
                 if flt is None:
@@ -1092,7 +1102,7 @@ class Shard:
                 got = by_sig.get(sig)
                 if got is None:
                     try:
-                        got = self.build_allow_list(flt)
+                        got = self.build_allow_list(flt, memo)
                     except Exception as e:  # noqa: BLE001 — this slot's alone
                         got = e
                     by_sig[sig] = got
@@ -1101,7 +1111,8 @@ class Shard:
                 else:
                     allows[i] = got
             sw.note(filters=sum(f is not None for f in flts),
-                    distinct=len(by_sig))
+                    distinct=len(by_sig), tags=len(memo),
+                    memo_hits=memo.hits, ids=memo.ids)
         filter_ms = sw.ms
         if m is not None:
             m.filtered_vector_filter.labels(cls, self.name).observe(filter_ms)

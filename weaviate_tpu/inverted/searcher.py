@@ -27,11 +27,55 @@ from weaviate_tpu.entities.filters import (
 from weaviate_tpu.entities.schema import ClassDef, DataType
 from weaviate_tpu.inverted.analyzer import filter_value_token
 from weaviate_tpu.inverted.index import (
+    ALL_DOCS_KEY,
     NULL_TRUE,
     InvertedIndex,
     filterable_bucket,
 )
 from weaviate_tpu.storage.bitmap import Bitmap
+
+
+class PostingMemo:
+    """The postings read during ONE `filter` phase (db/shard.py opens it
+    for a group of filtered slots and drops it with the phase): a leaf that
+    asks a (bucket, token) another filter of the group already read gets
+    the same Bitmap, which is immutable. It sits below the tree, so every
+    filter shape gains and none is tested for; it outlives no phase, so a
+    write acknowledged before the next group is read by it. Leaving the
+    `with` block ends it: a posting that is also a slot's allowList lives
+    on as that, without the bitset its intersections made (Bitmap.and_)."""
+
+    __slots__ = ("_got", "hits", "ids")
+
+    def __init__(self):
+        self._got: dict[tuple[object, bytes], Bitmap] = {}
+        self.hits = 0   # leaf reads served from the memo
+        self.ids = 0    # ids read from the buckets
+
+    def __len__(self) -> int:
+        """Distinct postings read."""
+        return len(self._got)
+
+    def __enter__(self) -> "PostingMemo":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        for posting in self._got.values():
+            posting.drop_bits()
+        self._got.clear()
+
+    def get(self, bucket, token: bytes) -> Bitmap:
+        got = self._got.get((bucket, token))
+        if got is None:
+            got = self._got[(bucket, token)] = bucket.roaring_get(token)
+            self.ids += len(got)
+        else:
+            self.hits += 1
+        return got
+
+
+def _posting(bucket, token: bytes, memo: Optional[PostingMemo]) -> Bitmap:
+    return bucket.roaring_get(token) if memo is None else memo.get(bucket, token)
 
 
 class FilterSearcher:
@@ -47,32 +91,39 @@ class FilterSearcher:
         self.geo_search = geo_search
         self.ref_resolver = ref_resolver
 
-    def doc_ids(self, flt: LocalFilter) -> Bitmap:
-        return self._eval(flt.root)
+    def doc_ids(self, flt: LocalFilter,
+                memo: Optional[PostingMemo] = None) -> Bitmap:
+        """`memo`: the group's, where `flt` is one of a group of filters
+        resolved in one phase; every leaf read goes through it."""
+        return self._eval(flt.root, memo)
+
+    def _universe(self, memo: Optional[PostingMemo]) -> Bitmap:
+        """Every live doc id (InvertedIndex.all_doc_ids, through the memo)."""
+        return _posting(self.inverted._all, ALL_DOCS_KEY, memo)
 
     # -- tree ----------------------------------------------------------------
 
-    def _eval(self, c: Clause) -> Bitmap:
+    def _eval(self, c: Clause, memo: Optional[PostingMemo]) -> Bitmap:
         if c.operator is Operator.AND:
             out: Optional[Bitmap] = None
             for op in c.operands:
-                b = self._eval(op)
+                b = self._eval(op, memo)
                 out = b if out is None else out.and_(b)
             return out or Bitmap()
         if c.operator is Operator.OR:
             out = Bitmap()
             for op in c.operands:
-                out = out.or_(self._eval(op))
+                out = out.or_(self._eval(op, memo))
             return out
         if c.operator is Operator.NOT:
             # complement against the live universe (searcher uses the doc
             # universe the same way for NotEqual)
-            universe = self.inverted.all_doc_ids()
+            universe = self._universe(memo)
             out = Bitmap()
             for op in c.operands:
-                out = out.or_(self._eval(op))
+                out = out.or_(self._eval(op, memo))
             return universe.and_not(out)
-        return self._eval_value(c)
+        return self._eval_value(c, memo)
 
     # -- leaves --------------------------------------------------------------
 
@@ -91,7 +142,7 @@ class FilterSearcher:
             raise FilterValidationError(f"unknown property {name!r} in filter")
         return prop
 
-    def _eval_value(self, c: Clause) -> Bitmap:
+    def _eval_value(self, c: Clause, memo: Optional[PostingMemo]) -> Bitmap:
         if len(c.on) > 1:
             # cross-reference path: [RefProp, TargetClass, targetProp...]
             if self.ref_resolver is None:
@@ -120,9 +171,9 @@ class FilterSearcher:
             nb = self.inverted.store.bucket(null_bucket(name))
             if nb is None:
                 return Bitmap()
-            nulls = nb.roaring_get(NULL_TRUE)
+            nulls = _posting(nb, NULL_TRUE, memo)
             if c.value in (False, None) or (isinstance(c.value, bool) and not c.value):
-                return self.inverted.all_doc_ids().and_not(nulls)
+                return self._universe(memo).and_not(nulls)
             return nulls
         if not prop.index_filterable:
             raise FilterValidationError(f"property {name!r} is not indexFilterable")
@@ -135,7 +186,7 @@ class FilterSearcher:
             out: Optional[Bitmap] = None
             for v in values:
                 tok = filter_value_token(pt, prop.tokenization, v)
-                b = bucket.roaring_get(tok)
+                b = _posting(bucket, tok, memo)
                 if c.operator is Operator.CONTAINS_ANY:
                     out = b if out is None else out.or_(b)
                 else:
@@ -147,24 +198,25 @@ class FilterSearcher:
             out = Bitmap()
             for key in bucket.keys():
                 if rx.match(key):
-                    out = out.or_(bucket.roaring_get(key))
+                    out = out.or_(_posting(bucket, key, memo))
             return out
 
         tok = filter_value_token(pt, prop.tokenization, c.value)
         if c.operator is Operator.EQUAL:
-            return bucket.roaring_get(tok)
+            return _posting(bucket, tok, memo)
         if c.operator is Operator.NOT_EQUAL:
-            return self.inverted.all_doc_ids().and_not(bucket.roaring_get(tok))
+            return self._universe(memo).and_not(_posting(bucket, tok, memo))
         if c.operator in (
             Operator.GREATER_THAN,
             Operator.GREATER_THAN_EQUAL,
             Operator.LESS_THAN,
             Operator.LESS_THAN_EQUAL,
         ):
-            return self._range(bucket, tok, c.operator)
+            return self._range(bucket, tok, c.operator, memo)
         raise FilterValidationError(f"unsupported operator {c.operator}")
 
-    def _range(self, bucket, tok: bytes, op: Operator) -> Bitmap:
+    def _range(self, bucket, tok: bytes, op: Operator,
+               memo: Optional[PostingMemo]) -> Bitmap:
         keys = bucket.keys()
         lo = bisect.bisect_left(keys, tok)
         out = Bitmap()
@@ -178,7 +230,7 @@ class FilterSearcher:
         else:  # LESS_THAN_EQUAL
             sel = keys[: bisect.bisect_right(keys, tok)]
         for k in sel:
-            out = out.or_(bucket.roaring_get(k))
+            out = out.or_(_posting(bucket, k, memo))
         return out
 
     def _eval_id(self, c: Clause) -> Bitmap:

@@ -67,6 +67,12 @@ CAPTURE_LOG_MAX = 65536
 # within the window — plenty for p50/p99 at any realistic horizon.
 _PHASE_SAMPLES_MAX = 16384
 
+# which walk served a posting read (`note_posting`): the one-pass native
+# walk, none (a bucket with no segment: the memtable alone), or a reason
+# the Python walk had to (storage/lsm.py Bucket.roaring_get names them)
+POSTING_NATIVE = "native"
+POSTING_MEMTABLE = "memtable_only"
+
 
 class DutyCycle:
     """Busy-time integrator over [start, end) intervals within a rolling
@@ -159,6 +165,10 @@ class PerfWindow:
         # (t_mono, keys, segment_probes, key_compares, arena_grows) per
         # native point-get call, count-capped like the phases
         self._point_get: deque = deque(maxlen=_PHASE_SAMPLES_MAX)
+        # [second, keys, segment_probes, ids, {walk: calls}] per second of
+        # posting reads: a filtered group reads hundreds of postings, so
+        # the calls of one second share an entry
+        self._postings: deque = deque()
         self._duty = DutyCycle(self.window_s)
         self._rows = 0  # running sum over the live window
         self._first_entry: Optional[float] = None
@@ -254,6 +264,25 @@ class PerfWindow:
             while d[0][0] < horizon:
                 d.popleft()
 
+    def note_posting(self, segment_probes: int, ids: int, walk: str) -> None:
+        """One `Bucket.roaring_get`: the segments it asked, the ids it read,
+        and the walk that served it (`POSTING_NATIVE`, `POSTING_MEMTABLE`,
+        or the reason the Python walk had to)."""
+        now = time.monotonic()
+        sec = int(now)
+        with self._lock:
+            d = self._postings
+            if not d or d[-1][0] != sec:
+                d.append([sec, 0, 0, 0, {}])
+                horizon = now - self.window_s
+                while d[0][0] < horizon - 1.0:
+                    d.popleft()
+            e = d[-1]
+            e[1] += 1
+            e[2] += segment_probes
+            e[3] += ids
+            e[4][walk] = e[4].get(walk, 0) + 1
+
     def note_interval(self, name: str, start_ns: int, end_ns: int,
                       tid: Optional[int] = None) -> None:
         """One closed host phase on ``time.perf_counter_ns``; `tid` is the
@@ -329,6 +358,8 @@ class PerfWindow:
         for d in (*self._phase.values(), self._point_get):
             while d and d[0][0] < horizon:
                 d.popleft()
+        while self._postings and self._postings[0][0] < horizon - 1.0:
+            self._postings.popleft()
 
     def _observed_span(self, now: float) -> float:
         if self._first_entry is None:
@@ -370,6 +401,7 @@ class PerfWindow:
             for d in self._phase.values():
                 d.clear()
             self._point_get.clear()
+            self._postings.clear()
             self._duty = DutyCycle(self.window_s)
             self._rows = 0
             self._first_entry = None
@@ -395,6 +427,11 @@ class PerfWindow:
                 violations += viol
             total_dispatches = self._total_dispatches
             point_get = [sum(c) for c in list(zip(*self._point_get))[1:]]
+            postings = [sum(c) for c in list(zip(*self._postings))[1:4]]
+            walks: dict[str, int] = {}
+            for e in self._postings:
+                for walk, calls in e[4].items():
+                    walks[walk] = walks.get(walk, 0) + calls
         out: dict = {
             "window_s": self.window_s,
             "observed_s": round(span, 3),
@@ -430,6 +467,19 @@ class PerfWindow:
             out["point_get"] = dict(zip(
                 ("keys", "segment_probes", "key_compares", "arena_grows"),
                 point_get))
+        if postings:
+            # the roaring-set posting reads over the window (every filter
+            # leaf, BM25 and hybrid allowLists, `keys()`): `native` calls
+            # the one-pass C walk served, `fallback` calls the Python walk
+            # had to, by reason; a bucket with no segment is neither
+            reasons = {w: c for w, c in sorted(walks.items())
+                       if w not in (POSTING_NATIVE, POSTING_MEMTABLE)}
+            out["postings"] = {
+                **dict(zip(("keys", "segment_probes", "ids"), postings)),
+                "native": walks.get(POSTING_NATIVE, 0),
+                "fallback": sum(reasons.values()),
+                "fallback_reasons": reasons,
+            }
         out["tiers"] = dict(sorted(tiers.items(), key=lambda kv: -kv[1]))
         # the store rows each tier's dispatches read over the window: all
         # live rows a scan, the probed rows an IVF dispatch, and for a
@@ -507,6 +557,14 @@ def note_point_get(keys: int, segment_probes: int, key_compares: int,
     w = _window
     if w is not None:
         w.note_point_get(keys, segment_probes, key_compares, arena_grows)
+
+
+def note_posting(segment_probes: int, ids: int, walk: str) -> None:
+    """`PerfWindow.note_posting` on the installed window; one comparison
+    while the plane is down."""
+    w = _window
+    if w is not None:
+        w.note_posting(segment_probes, ids, walk)
 
 
 def note_interval(name: str, start_ns: int, end_ns: int,

@@ -60,9 +60,12 @@ class Bitmap(AllowList):
     # filtered queries with the same filter skip the whole host pack.
     # _slots_cache: likewise one (token-tuple, store slots) pair: the
     # filter's rows in that index state (index/tpu.py _allow_slots).
-    __slots__ = ("_ids", "_words_cache", "_slots_cache")
+    # _bits: (first id, bitset) of a posting that intersections ask again
+    # (and_), until drop_bits().
+    __slots__ = ("_ids", "_words_cache", "_slots_cache", "_bits")
 
     def __init__(self, ids: Optional[Iterable[int] | np.ndarray] = None, _sorted: bool = False):
+        self._bits = None
         if ids is None:
             self._ids = np.empty(0, dtype=np.uint64)
         elif isinstance(ids, np.ndarray) and _sorted:
@@ -94,13 +97,29 @@ class Bitmap(AllowList):
     # -- set algebra (searcher_doc_bitmap.go:25-109 merge semantics) ---------
 
     def and_(self, other: "Bitmap") -> "Bitmap":
-        # both sorted and unique: look the smaller up in the larger (a
-        # binary search an id) instead of sorting the two together
-        small, big = sorted((self._ids, other._ids), key=len)
-        if small.size == 0:
+        # both sorted and unique: one native pass (storage/lsm_native.py
+        # intersect_sorted: the smaller probes the larger's bitset, which
+        # stays on the larger for the next filter that holds the same
+        # posting, or gallops through it); without the library the smaller
+        # is looked up in the larger, a binary search an id. Neither sorts
+        # the two together.
+        from weaviate_tpu.storage import lsm_native
+
+        small, big = sorted((self, other), key=len)
+        if len(small) == 0:
             return Bitmap()
+        got = lsm_native.intersect_sorted(small._ids, big._ids, big._bits)
+        if got is not None:
+            both, big._bits = got
+            return Bitmap(both, _sorted=True)
+        small, big = small._ids, big._ids
         at = np.minimum(np.searchsorted(big, small), big.size - 1)
         return Bitmap(small[big[at] == small], _sorted=True)
+
+    def drop_bits(self) -> None:
+        """Forget the bitset intersections made of this posting (the
+        group's posting memo, when its `filter` phase ends)."""
+        self._bits = None
 
     def or_(self, other: "Bitmap") -> "Bitmap":
         return Bitmap(np.union1d(self._ids, other._ids), _sorted=True)
@@ -144,13 +163,17 @@ class Bitmap(AllowList):
     def to_bytes(self) -> bytes:
         return _MAGIC + struct.pack("<Q", self._ids.size) + self._ids.astype("<u8").tobytes()
 
-    @classmethod
-    def from_bytes(cls, data: bytes) -> "Bitmap":
+    @staticmethod
+    def ids_view(data) -> np.ndarray:
+        """The ids of a serialized bitmap as a read-only view of `data`."""
         if data[:4] != _MAGIC:
             raise ValueError("bad bitmap magic")
         (n,) = struct.unpack_from("<Q", data, 4)
-        ids = np.frombuffer(data, dtype="<u8", count=n, offset=12).copy()
-        return cls(ids, _sorted=True)
+        return np.frombuffer(data, dtype="<u8", count=n, offset=12)
+
+    @classmethod
+    def from_bytes(cls, data: bytes) -> "Bitmap":
+        return cls(cls.ids_view(data).copy(), _sorted=True)
 
     @classmethod
     def full_range(cls, start: int, stop: int) -> "Bitmap":

@@ -33,6 +33,7 @@ from typing import Iterable, Iterator, Optional
 
 import numpy as np
 
+from weaviate_tpu.monitoring import perf
 from weaviate_tpu.storage.bitmap import Bitmap
 
 STRATEGY_REPLACE = "replace"
@@ -290,6 +291,7 @@ class Segment:
 
     def __init__(self, path: str):
         self.path = path
+        self._native_handle: Optional[int] = None   # storage/lsm_native.py
         self._f = open(path, "rb")
         self._mm = mmap.mmap(self._f.fileno(), 0, access=mmap.ACCESS_READ)
         mv = memoryview(self._mm)
@@ -483,12 +485,19 @@ def _enc_roaring(adds: Bitmap, dels: Bitmap) -> bytes:
     return struct.pack("<II", len(a), len(d)) + a + d
 
 
-def _dec_roaring(payload) -> tuple[Bitmap, Bitmap]:
-    payload = memoryview(payload)   # slices of a view copy nothing
+def _dec_roaring_views(payload: memoryview) -> tuple[np.ndarray, np.ndarray]:
+    """(additions, deletions) of one layer as VIEWS of its payload
+    (slices of a view copy nothing): for the reader that joins the layers
+    of a posting, whose join is the copy. Valid while the segment is open
+    (the bucket's lock)."""
     la, ld = struct.unpack_from("<II", payload, 0)
-    a = Bitmap.from_bytes(payload[8 : 8 + la])
-    d = Bitmap.from_bytes(payload[8 + la : 8 + la + ld])
-    return a, d
+    return (Bitmap.ids_view(payload[8 : 8 + la]),
+            Bitmap.ids_view(payload[8 + la : 8 + la + ld]))
+
+
+def _dec_roaring(payload) -> tuple[Bitmap, Bitmap]:
+    adds, dels = _dec_roaring_views(memoryview(payload))
+    return Bitmap(adds.copy(), _sorted=True), Bitmap(dels.copy(), _sorted=True)
 
 
 # -- bucket ------------------------------------------------------------------
@@ -1109,43 +1118,88 @@ class Bucket:
         return ids[last], vals[last]
 
     def roaring_get(self, key: bytes) -> Bitmap:
+        """The key's posting: oldest layer first, out = (out - deletions) |
+        additions a layer, the memtable's on top. ONE read path for every
+        caller, chosen by what the bucket observes: the segments are walked
+        in one native pass (storage/lsm_native.py posting_get: outside the
+        lock, on a snapshot the retire-until-idle contract protects, and
+        keeping the GIL) where the library is loaded and no layer of the key
+        deletes from an older one; else by `_roaring_walk`, which settles
+        any layering. `/debug/perf` `postings` counts which served."""
         assert self.strategy == STRATEGY_ROARINGSET
-        # oldest segment first: out = (out - deletions) | additions a layer.
-        # The additions of consecutive layers without a deletion are merged
-        # in ONE pass (a posting spread over 30 segments is one sort, not 30
-        # unions); a layer that deletes settles what came before it first.
+        from weaviate_tpu.storage import lsm_native
+
         with self._lock:
-            layers: list[np.ndarray] = []
-
-            def merged() -> Bitmap:
-                if not layers:
-                    return Bitmap()
-                if len(layers) == 1:
-                    return Bitmap(layers[0], _sorted=True)
-                # doc ids come from a counter, so the layers of a posting
-                # follow each other: joined they ascend already, and the
-                # check is one pass where the sort would be the whole cost
-                ids = np.concatenate(layers)
-                return Bitmap(ids, _sorted=bool(np.all(ids[1:] > ids[:-1])))
-
-            def settle(dels: Bitmap) -> None:
-                layers[:] = [merged().and_not(dels).to_array()]
-
-            for seg in self._segments:
-                raw = seg.view_raw(key)
-                if raw is not None:
-                    adds, dels = _dec_roaring(raw)
-                    if len(dels) and layers:
-                        settle(dels)
-                    if len(adds):
-                        layers.append(adds.to_array())
+            snapshot = list(self._segments)
+            walk = perf.POSTING_MEMTABLE
+            if snapshot:
+                walk = (perf.POSTING_NATIVE if lsm_native.available()
+                        else "no_library")
+            if walk != perf.POSTING_NATIVE:
+                out = self._roaring_walk(key)
+                perf.note_posting(len(snapshot), len(out), walk)
+                return out
             madds = self._mem.adds.get(key)
             mdels = self._mem.dels.get(key)
-            if mdels and layers:
-                settle(Bitmap(mdels))
-            if madds:
-                layers.append(Bitmap(madds).to_array())
-            return merged()
+            madds = Bitmap(madds) if madds else None
+            mdels = Bitmap(mdels) if mdels else None
+            self._native_inflight += 1
+        try:
+            got = lsm_native.posting_get(snapshot, key)
+        finally:
+            with self._lock:
+                self._native_exit()
+        if isinstance(got, str):    # the reason this key is not the plane's
+            with self._lock:
+                out = self._roaring_walk(key)
+                perf.note_posting(len(self._segments), len(out), got)
+            return out
+        ids, ascends, probes = got
+        perf.note_posting(probes, len(ids), walk)
+        out = Bitmap(ids, _sorted=ascends)
+        if mdels is not None and len(out):
+            out = out.and_not(mdels)
+        if madds is not None:
+            out = out.or_(madds) if len(out) else madds
+        return out
+
+    def _roaring_walk(self, key: bytes) -> Bitmap:
+        """`roaring_get` in Python (caller holds the lock). The additions
+        of consecutive layers without a deletion are merged in ONE pass (a
+        posting spread over 30 segments is one sort, not 30 unions); a
+        layer that deletes settles what came before it first."""
+        layers: list[np.ndarray] = []
+
+        def merged() -> Bitmap:
+            if not layers:
+                return Bitmap()
+            # doc ids come from a counter, so the layers of a posting
+            # follow each other: joined they ascend already, and the
+            # check is one pass where the sort would be the whole cost.
+            # The layers are views of the mappings; joining them is the
+            # one copy (np.concatenate copies a single layer too)
+            ids = np.concatenate(layers)
+            return Bitmap(ids, _sorted=len(layers) == 1
+                          or bool(np.all(ids[1:] > ids[:-1])))
+
+        def settle(dels: Bitmap) -> None:
+            layers[:] = [merged().and_not(dels).to_array()]
+
+        for seg in self._segments:
+            raw = seg.view_raw(key)
+            if raw is not None:
+                adds, dels = _dec_roaring_views(raw)
+                if len(dels) and layers:
+                    settle(Bitmap(dels, _sorted=True))
+                if len(adds):
+                    layers.append(adds)
+        madds = self._mem.adds.get(key)
+        mdels = self._mem.dels.get(key)
+        if mdels and layers:
+            settle(Bitmap(mdels))
+        if madds:
+            layers.append(Bitmap(madds).to_array())
+        return merged()
 
     def keys(self) -> list[bytes]:
         """Sorted live keys across memtable + segments."""

@@ -1,4 +1,5 @@
-"""ctypes bridge to the native LSM point-get plane (native/lsm_get.cpp).
+"""ctypes bridge to the native LSM read plane (native/lsm_get.cpp): the
+replace-strategy point gets, and the roaring-set posting walk below them.
 
 Batched replace-strategy point lookups over the mmap'd segment files with
 the GIL released (ctypes semantics), so concurrent request hydrations
@@ -17,8 +18,17 @@ What a call did is counted in C and handed to the perf window
 Reference analog: the compiled lsmkv segment readers under the batched
 hydration seam entities/storobj/storage_object.go:211.
 
-Falls back cleanly: `multi_get` returns None whenever the library or a
-segment handle is unavailable, and callers use the Python reader.
+A posting (`posting_get`) is two C calls a key: the first finds the key in
+every segment's hash table, oldest first, and says how many ids its layers
+hold; the second copies them into one fresh uint64 array, the Bitmap's own,
+and says whether they ascend as they stand. `intersect_sorted` is
+`Bitmap.and_`'s pass over two postings. All of these keep the GIL
+(`_load`). What the walk did reaches `/debug/perf` `postings`
+through the bucket (`Bucket.roaring_get`).
+
+Falls back cleanly: `multi_get` returns None, and `posting_get` the
+reason, whenever the library or a segment handle is unavailable, and
+callers use the Python reader.
 """
 
 from __future__ import annotations
@@ -67,6 +77,24 @@ def _load() -> Optional[ctypes.CDLL]:
             ]
             lib.lsm_key_hash.restype = ctypes.c_uint64
             lib.lsm_key_hash.argtypes = [ctypes.c_char_p, ctypes.c_int64]
+            # the calls on postings KEEP the GIL (a PYFUNCTYPE prototype,
+            # where the library's own attributes release it): most take
+            # microseconds, and a thread that lets go of the GIL gets it
+            # back only when whichever thread took it gives it up, up to a
+            # switch interval later (PERF.md section 6, PR 31: the same
+            # copies through numpy, which lets go, cost the filtered cell
+            # 65 ms a request). Addresses go as integers.
+            ptr, i64 = ctypes.c_void_p, ctypes.c_int64
+            for name, res, args in (
+                    ("posting_locate", i64,
+                     (ptr, i64, ctypes.c_char_p, i64, ptr, ptr, ptr)),
+                    ("posting_copy", i64, (ptr, ptr, i64, ptr)),
+                    ("ids_gallop", i64, (ptr, i64, ptr, i64, ptr)),
+                    ("bits_build", None, (ptr, i64, ctypes.c_uint64, ptr)),
+                    ("bits_probe", i64,
+                     (ptr, i64, ptr, ctypes.c_uint64, i64, ptr))):
+                setattr(lib, name, ctypes.PYFUNCTYPE(res, *args)(
+                    ("lsm_" + name, lib)))
             _lib = lib
         except Exception as e:  # noqa: BLE001 — the Python reader serves
             _lib_failed = True
@@ -181,6 +209,8 @@ def multi_get_packed(
         key_offs.ctypes.data_as(p_i64), n, srcs_ptr, offs_ptr,
         flags.ctypes.data_as(ctypes.POINTER(ctypes.c_int8)),
         stats.ctypes.data_as(p_i64), arena.ctypes.data_as(p_u8), arena.size)
+    if need < 0:            # a segment that is no replace segment
+        return None
     if need > arena.size:   # located, not copied: the copy alone, no search
         arena, grew = _arena.grow(need), 1
         lib.lsm_copy(srcs_ptr, offs_ptr, n, arena.ctypes.data_as(p_u8))
@@ -211,3 +241,95 @@ def multi_get(segments_newest_first: Sequence,
         if f:
             res[i] = data[offs[i]:offs[i + 1]]
     return res
+
+
+class _PostingScratch(threading.local):
+    """This thread's per-segment outputs of `lsm_posting_locate` (where a
+    layer's ids live, how many) and its two counters, with their
+    addresses: grown to the deepest bucket the thread has read."""
+
+    def __init__(self):
+        self.cap = 0
+
+    def ensure(self, n_segs: int) -> None:
+        if n_segs <= self.cap:
+            return
+        self.cap = max(64, 2 * n_segs)
+        self.srcs = np.empty(self.cap, dtype=np.uintp)
+        self.counts = np.empty(self.cap, dtype=np.int64)
+        self.stats = np.empty(2, dtype=np.int64)
+        self.addrs = (self.srcs.ctypes.data, self.counts.ctypes.data,
+                      self.stats.ctypes.data)
+
+
+_posting_scratch = _PostingScratch()
+
+def posting_get(segments_oldest_first: Sequence, key: bytes
+                ) -> tuple[np.ndarray, bool, int] | str:
+    """One key's additions over a snapshot of roaring-set segments (OLDEST
+    first), joined in one fresh uint64 array -> (ids, whether they ascend
+    strictly as they stand, segments probed). A string => the Python walk,
+    and why: "no_library"; "deleting_layer", a layer deletes from what
+    older layers added; "unreadable", a segment or a payload this plane
+    could not parse. Caller owns segment lifetime
+    (`Bucket._native_inflight`)."""
+    lib = _load()
+    if lib is None:
+        return "no_library"
+    handles = [s._native_handle for s in segments_oldest_first]
+    if None in handles:
+        handles = [seg_handle(s) for s in segments_oldest_first]
+    if 0 in handles:
+        return "unreadable"
+    n = len(handles)
+    sc = _posting_scratch
+    sc.ensure(n)
+    srcs, counts, stats = sc.addrs
+    seg_arr = (ctypes.c_void_p * n)(*handles)
+    total = lib.posting_locate(ctypes.addressof(seg_arr), n, key, len(key),
+                               srcs, counts, stats)
+    if total < 0:
+        return "deleting_layer" if total == -1 else "unreadable"
+    ids = np.empty(total, dtype=np.uint64)
+    layers = int(sc.stats[0])
+    ascends = not layers or bool(lib.posting_copy(
+        srcs, counts, layers, ids.ctypes.data))
+    return ids, ascends, int(sc.stats[1])
+
+
+def intersect_sorted(small: np.ndarray, big: np.ndarray,
+                     bits: Optional[tuple[int, np.ndarray]] = None
+                     ) -> Optional[tuple[np.ndarray, Optional[tuple]]]:
+    """The ids two ascending, unique uint64 arrays share (`small` the
+    shorter, not empty), as a fresh array: the smaller probes a bitset
+    over the larger's span, or gallops through the larger. `bits`: the
+    larger's bitset (first id, words) where an earlier call made it. ->
+    (ids, the larger's bitset where one was given or made, for the next
+    filter that asks the same posting); None => the caller's numpy (no
+    library).
+
+    A bitset is made where that is the cheaper way for this call alone:
+    a word-octet cleared or an id set costs one, a gallop's step (a
+    dependent load and a branch) four, and an id of the smaller takes a
+    step up and a step down for each doubling of the sizes' ratio."""
+    lib = _load()
+    if lib is None:
+        return None
+    small, big = np.ascontiguousarray(small), np.ascontiguousarray(big)
+    na, nb = small.size, big.size
+    if bits is None:
+        base = int(big[0]) & ~63
+        words = ((int(big[-1]) - base) >> 6) + 1
+        if words // 8 + nb < 4 * na * (1 + (nb // na).bit_length()):
+            bits = (base, np.zeros(words, dtype=np.uint64))
+            lib.bits_build(big.ctypes.data, nb, base, bits[1].ctypes.data)
+    out = np.empty(na, dtype=np.uint64)
+    if bits is not None:
+        n = lib.bits_probe(small.ctypes.data, na, bits[1].ctypes.data,
+                           bits[0], bits[1].size, out.ctypes.data)
+    else:
+        n = lib.ids_gallop(small.ctypes.data, na, big.ctypes.data, nb,
+                           out.ctypes.data)
+    # the room the result did not need goes back, in place
+    out.resize(n, refcheck=False)
+    return out, bits
