@@ -67,7 +67,7 @@ def _mk_index(tmp_path, n=500, pq=None, seed=0, name="fx", ids=None,
 
 TIERS = ("exact", "filtered_scan", "gather", "pq_rescore", "pq_codes",
          "pq_gather")
-_PQ = {"enabled": True, "segments": 4, "centroids": 16}
+_PQ = {"enabled": True, "trainingLimit": 256, "segments": 4, "centroids": 16}
 
 
 def _tier(tmp_path, tier, n=500, ids=None):
@@ -142,17 +142,29 @@ def _spy_programs(monkeypatch):
     return calls
 
 
-def _host_translated(call, snap, b, k):
+def _host_translated(call, snap, b, k, q=None):
     """The reference: the tier's slot-returning body under jax.jit, its
-    slots translated on the host through the snapshot's slot_to_doc."""
+    slots translated on the host through the snapshot's slot_to_doc. A
+    compressed index's scan of its bf16 rows returns CANDIDATES (`q`
+    given): their float32 distances from the snapshot's host rows, stably
+    sorted, are the answer."""
     body, statics, args, kwargs = call
+    kwargs = {kw: v for kw, v in kwargs.items() if kw != "with_slots"}
     out = jax.jit(body, static_argnames=statics)(*args, **kwargs)
     if isinstance(out, tuple):
         top, slots = (np.asarray(x) for x in out)
     else:  # _scan_full packs (dists | slots)
         top, slots = unpack_topk(np.asarray(out))
+    top, slots = top[:b], slots[:b]
+    if q is not None:
+        rows = snap.host_vecs[np.clip(slots, 0, None)]
+        top = tpu._host_distances(rows, q.astype(np.float32), "l2-squared")
+        top[slots < 0] = np.inf
+        order = np.argsort(top, axis=1, kind="stable")
+        top = np.take_along_axis(top, order, axis=1)
+        slots = np.take_along_axis(slots, order, axis=1)
     ids = np.where(slots >= 0, snap.slot_to_doc[np.clip(slots, 0, None)], -1)
-    return (ids.astype(np.uint64)[:b, :k], top.astype(np.float32)[:b, :k])
+    return (ids.astype(np.uint64)[:, :k], top.astype(np.float32)[:, :k])
 
 
 # a batch under 8 rows is refused by the Pallas group-min kernels, so the
@@ -174,7 +186,8 @@ def test_fused_legacy_bit_identity_all_tiers_sync_and_async(
               "pq_rescore": "_scan_full", "pq_codes": "_pq_recon_topk"}
     if batch < 8 and tier in served:
         assert calls[0][0].__name__ == served[tier]
-    want = _host_translated(calls[0], snap, batch, got_sync[0].shape[1])
+    want = _host_translated(calls[0], snap, batch, got_sync[0].shape[1],
+                            q if tier == "pq_rescore" else None)
     for got in (got_sync, got_async):
         np.testing.assert_array_equal(got[0], want[0])
         np.testing.assert_array_equal(got[1], want[1])
@@ -342,7 +355,7 @@ def test_gather_cached_allowlist_never_returns_deleted_docs(tmp_path):
     gather kernels must mask tombstones on device with the dispatching
     snapshot's own tombs (both tiers)."""
     for compress in (False, True):
-        pq = ({"enabled": True, "segments": 4, "centroids": 16}
+        pq = ({"enabled": True, "trainingLimit": 256, "segments": 4, "centroids": 16}
               if compress else None)
         idx, vecs = _mk_index(tmp_path, pq=pq,
                               name=f"stale{int(compress)}")

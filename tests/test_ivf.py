@@ -93,7 +93,7 @@ def _tier(tmp_path, tier, n=600):
     """(index, vectors, allowList) of one read tier."""
     pq = None
     if tier.startswith("pq"):
-        pq = {"enabled": True, "segments": 4, "centroids": 16,
+        pq = {"enabled": True, "trainingLimit": 256, "segments": 4, "centroids": 16,
               "rescore": tier == "pq_rescore"}
     idx, vecs = _mk_index(tmp_path, n=n, name=tier, exactTopK=True, pq=pq)
     if pq is not None:

@@ -83,7 +83,7 @@ def test_exact_tier_components_equal_snapshot_nbytes(tmp_path):
 def test_pq_rescore_tier_components_and_no_stale_store(tmp_path):
     led = _mk_ledger()
     idx, _ = _mk_index(
-        tmp_path, pq={"enabled": True, "segments": 4, "centroids": 16},
+        tmp_path, pq={"enabled": True, "trainingLimit": 256, "segments": 4, "centroids": 16},
         n=512)
     assert idx.compressed
     snap = idx._snap
@@ -103,7 +103,7 @@ def test_pq_codes_only_tier_has_no_rescore_components(tmp_path):
     led = _mk_ledger()
     idx, _ = _mk_index(
         tmp_path,
-        pq={"enabled": True, "segments": 4, "centroids": 16,
+        pq={"enabled": True, "trainingLimit": 256, "segments": 4, "centroids": 16,
             "rescore": False},
         n=512)
     assert idx.compressed and idx._rescore_dev is None

@@ -62,7 +62,7 @@ def _mk_app(tmp_path, tracing_on=True, coalesce=True, window_ms=200.0,
            "properties": [{"name": "tag", "dataType": ["text"]}]}
     if pq:
         cls["vectorIndexConfig"]["pq"] = {
-            "enabled": True, "segments": 4, "centroids": 16}
+            "enabled": True, "trainingLimit": 256, "segments": 4, "centroids": 16}
     app.schema.add_class(cls)
     rng = np.random.default_rng(11)
     vecs = rng.integers(-8, 8, (n, DIM)).astype(np.float32)

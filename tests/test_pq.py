@@ -17,7 +17,9 @@ from weaviate_tpu.index.tpu import TpuVectorIndex
 def _cfg(**pq_kwargs):
     d = {"distance": "l2-squared"}
     if pq_kwargs:
-        d["pq"] = pq_kwargs
+        # a class that declares pq compresses at its trainingLimit; these
+        # tests import a few hundred rows
+        d["pq"] = {"trainingLimit": 256, **pq_kwargs}
     return vi.HnswUserConfig.from_dict(d)
 
 
@@ -240,7 +242,7 @@ def test_pq_manhattan_rides_store_scan(tmp_path):
     base = rng.standard_normal((600, 32)).astype(np.float32)
     cfg = vi.HnswUserConfig.from_dict(
         {"distance": "manhattan",
-         "pq": {"enabled": True, "segments": 8, "centroids": 32}}, "hnsw_tpu")
+         "pq": {"enabled": True, "trainingLimit": 256, "segments": 8, "centroids": 32}}, "hnsw_tpu")
     idx = TpuVectorIndex(cfg, str(tmp_path / "man"), persist=False)
     idx.add_batch(np.arange(600), base)
     idx.flush()
@@ -258,7 +260,7 @@ def test_pq_hamming_rejected(tmp_path):
     exact-equality test) — compress must refuse, not mis-rank."""
     cfg = vi.HnswUserConfig.from_dict(
         {"distance": "hamming",
-         "pq": {"enabled": True, "segments": 8, "centroids": 32}}, "hnsw_tpu")
+         "pq": {"enabled": True, "trainingLimit": 256, "segments": 8, "centroids": 32}}, "hnsw_tpu")
     idx = TpuVectorIndex(cfg, str(tmp_path / "ham"), persist=False)
     rng = np.random.default_rng(5)
     idx.add_batch(np.arange(600), rng.integers(0, 4, (600, 32)).astype(np.float32))

@@ -59,7 +59,7 @@ def _reset_globals():
     memory.configure(None)
 
 
-PQ4 = {"enabled": True, "segments": 4, "centroids": 32, "bits": 4,
+PQ4 = {"enabled": True, "trainingLimit": 256, "segments": 4, "centroids": 32, "bits": 4,
        "rescore": True, "rotation": "opq"}
 
 
@@ -241,7 +241,7 @@ def test_bits8_mode_never_touches_the_funnel(tmp_path, monkeypatch):
     for name in ("search_pq4_funnel_fused", "search_ivf_pq4_fused",
                  "pq4_funnel_topk", "ivf_pq4_topk", "plan_funnel"):
         monkeypatch.setattr(pq4_ops, name, boom)
-    pq8 = {"enabled": True, "segments": 4, "centroids": 32, "rescore": True}
+    pq8 = {"enabled": True, "trainingLimit": 256, "segments": 4, "centroids": 32, "rescore": True}
     idx, vecs = _mk_index(tmp_path, pq=pq8, name="no4")
     assert idx._codes4 is None and idx._pq4 is None
     assert idx._opq_rot_dev is None

@@ -20,7 +20,7 @@ def _mk_pq_index(tmp_path, metric=vi.DISTANCE_L2, n=2000, d=32, segments=8,
         vecs /= np.linalg.norm(vecs, axis=1, keepdims=True)
     cfg = vi.HnswUserConfig.from_dict(
         {"distance": metric,
-         "pq": {"enabled": True, "segments": segments,
+         "pq": {"enabled": True, "trainingLimit": 256, "segments": segments,
                 "centroids": centroids, "rescore": False}}, "hnsw_tpu")
     idx = TpuVectorIndex(cfg, str(tmp_path / "pqg"), persist=False)
     idx.add_batch(np.arange(n), vecs)

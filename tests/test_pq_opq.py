@@ -73,7 +73,7 @@ def test_opq_validation():
 def _codes_only_recall(tmp_path, name, rotation, data, queries):
     cfg = vi.HnswUserConfig.from_dict(
         {"distance": "l2-squared",
-         "pq": {"enabled": True, "segments": 8, "centroids": 16,
+         "pq": {"enabled": True, "trainingLimit": 256, "segments": 8, "centroids": 16,
                 "rescore": False, "rotation": rotation}}, "hnsw_tpu")
     idx = TpuVectorIndex(cfg, str(tmp_path / name), persist=False)
     idx.add_batch(np.arange(len(data)), data)
@@ -104,7 +104,7 @@ def test_opq_restart_serves_from_persisted_rotation(tmp_path, rng):
     data = correlated_data(seed=11, n=1500)
     cfg = vi.HnswUserConfig.from_dict(
         {"distance": "l2-squared",
-         "pq": {"enabled": True, "segments": 8, "centroids": 16,
+         "pq": {"enabled": True, "trainingLimit": 256, "segments": 8, "centroids": 16,
                 "rescore": False, "rotation": "opq"}}, "hnsw_tpu")
     idx = TpuVectorIndex(cfg, str(tmp_path / "r"), persist=True)
     idx.add_batch(np.arange(len(data)), data)
@@ -136,7 +136,7 @@ def test_opq_mesh_codes_only(tmp_path, rng):
     idx.update_user_config(parse_and_validate_config(
         "hnsw_tpu_mesh",
         {"distance": "l2-squared",
-         "pq": {"enabled": True, "segments": 8, "centroids": 16,
+         "pq": {"enabled": True, "trainingLimit": 256, "segments": 8, "centroids": 16,
                 "rescore": False, "rotation": "opq"}}))
     assert idx.compressed and idx._pq.rotation_matrix is not None
     q = data[:8] + 0.001 * rng.standard_normal((8, 16)).astype(np.float32)

@@ -61,7 +61,7 @@ def _mk_app(tmp_path, sample_rate=1.0, coalesce=False, pq=False, n=N,
            "properties": [{"name": "tag", "dataType": ["text"]}]}
     if pq:
         cls["vectorIndexConfig"]["pq"] = {
-            "enabled": True, "segments": 4, "centroids": 16}
+            "enabled": True, "trainingLimit": 256, "segments": 4, "centroids": 16}
     app.schema.add_class(cls)
     rng = np.random.default_rng(11)
     vecs = rng.integers(-8, 8, (n, DIM)).astype(np.float32)

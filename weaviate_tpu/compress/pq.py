@@ -229,24 +229,29 @@ class ProductQuantizer:
         self.rotation_matrix: Optional[np.ndarray] = None  # [D, D] orthogonal
         self.code_dtype = np.uint8 if centroids <= 256 else np.uint16
         self.codebook: Optional[np.ndarray] = None  # [M, C, ds] float32
+        self.trained_rows: Optional[int] = None  # rows the codebook fit on
         self._codebook_dev: Optional[Array] = None
         self._rot_dev: Optional[Array] = None
 
     # fit ---------------------------------------------------------------
 
     def fit(self, vectors: np.ndarray, seed: int = 0,
-            rotation_matrix: Optional[np.ndarray] = None) -> None:
+            rotation_matrix: Optional[np.ndarray] = None,
+            sample_max: int = _FIT_SAMPLE_MAX) -> None:
         """Fit codebooks (and the OPQ rotation when configured). Passing
         ``rotation_matrix`` pins a PRE-FITTED orthogonal rotation instead of
         learning one — the 4-bit funnel quantizer reuses the 8-bit
         quantizer's OPQ rotation this way, so both ladders of the funnel
         rank in the SAME rotated space and the Procrustes alternation runs
-        once per compress, not once per bit depth."""
+        once per compress, not once per bit depth. At most `sample_max`
+        rows fit (a uniform sample of more); `trained_rows` keeps how many
+        did, and is persisted with the codebook."""
         vectors = np.asarray(vectors, dtype=np.float32)
-        if vectors.shape[0] > _FIT_SAMPLE_MAX:
+        if vectors.shape[0] > sample_max:
             rng = np.random.default_rng(seed)
-            sel = rng.choice(vectors.shape[0], _FIT_SAMPLE_MAX, replace=False)
+            sel = rng.choice(vectors.shape[0], sample_max, replace=False)
             vectors = vectors[sel]
+        self.trained_rows = int(vectors.shape[0])
         if rotation_matrix is not None:
             if self.encoder == vi.PQ_ENCODER_TILE:
                 raise vi.ConfigValidationError(
@@ -390,6 +395,8 @@ class ProductQuantizer:
         extra = {}
         if self.rotation_matrix is not None:
             extra["rotation_matrix"] = self.rotation_matrix
+        if self.trained_rows is not None:
+            extra["trained_rows"] = self.trained_rows
         np.savez(
             path,
             codebook=self.codebook,
@@ -419,4 +426,6 @@ class ProductQuantizer:
         pq.codebook = z["codebook"].astype(np.float32)
         if "rotation_matrix" in z:
             pq.rotation_matrix = z["rotation_matrix"].astype(np.float32)
+        if "trained_rows" in z:  # files from before it was kept have none
+            pq.trained_rows = int(z["trained_rows"])
         return pq

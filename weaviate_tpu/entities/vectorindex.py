@@ -54,6 +54,7 @@ DEFAULT_FLAT_SEARCH_CUTOFF = 40_000
 
 # PQ defaults (pq_config.go:21-26)
 DEFAULT_PQ_CENTROIDS = 256
+DEFAULT_PQ_TRAINING_LIMIT = 100_000  # the reference's documented default
 PQ_ENCODER_KMEANS = "kmeans"
 PQ_ENCODER_TILE = "tile"
 PQ_DISTRIBUTION_LOG_NORMAL = "log-normal"
@@ -91,6 +92,12 @@ class PQConfig:
     # of top-c; ops/pq4.py) — half the scanned bytes per row at matched
     # recall through the funnel
     bits: int = 8
+    # the reference's `trainingLimit`: at most this many rows fit the
+    # codebook. A class that DECLARES pq compresses when its row count
+    # reaches it (the documented procedure enables pq after that many
+    # objects are imported); an explicit enable fits on a sample of at
+    # most this many of the rows that are there
+    training_limit: int = DEFAULT_PQ_TRAINING_LIMIT
 
     @classmethod
     def from_dict(cls, d: dict) -> "PQConfig":
@@ -108,6 +115,8 @@ class PQConfig:
             rescore_limit=int(d.get("rescoreLimit", 0)),
             rotation=str(d.get("rotation", PQ_ROTATION_NONE)),
             bits=int(d.get("bits", 8)),
+            training_limit=int(d.get("trainingLimit",
+                                     DEFAULT_PQ_TRAINING_LIMIT)),
         )
 
     def to_dict(self) -> dict:
@@ -121,6 +130,7 @@ class PQConfig:
             "rescoreLimit": self.rescore_limit,
             "rotation": self.rotation,
             "bits": self.bits,
+            "trainingLimit": self.training_limit,
         }
 
 
@@ -232,6 +242,9 @@ class HnswUserConfig:
                     f"invalid pq rotation {self.pq.rotation!r} (none|opq)")
             if self.pq.bits not in (4, 8):
                 raise ConfigValidationError("pq.bits must be 4 or 8")
+            if self.pq.training_limit < 1:
+                raise ConfigValidationError(
+                    "pq.trainingLimit must be at least 1")
             if self.pq.bits == 4:
                 if self.distance not in (DISTANCE_L2, DISTANCE_DOT,
                                          DISTANCE_COSINE):

@@ -63,7 +63,7 @@ from weaviate_tpu.index.interface import AllowList, VectorIndex
 # dispatch-shape recording for the perf-attribution plane: a
 # costmodel.DispatchShape is built per dispatch ONLY while the tracer is
 # up (tracing.get_tracer() gate — the zero-cost-when-disabled contract)
-from weaviate_tpu.monitoring import costmodel, tracing
+from weaviate_tpu.monitoring import costmodel, perf, tracing
 # memory ledger (monitoring/memory.py): device components are stamped
 # analytically (shapes x dtypes, zero syncs) at snapshot publish and at
 # every buffer-mutating method; unconfigured => one comparison, nothing
@@ -105,8 +105,9 @@ from weaviate_tpu.config.config import (IVF_TOP_P_BUCKETS, IvfConfig,
 from weaviate_tpu.ops import ivf as ivf_ops
 from weaviate_tpu.ops.topk import (bitmap_to_mask, merge_top_k,
                                    rescore_distances, retranslate_packed,
-                                   translate_pack, translate_pack_split,
-                                   unpack_fused)
+                                   translate_pack, translate_pack_slots,
+                                   translate_pack_split, unpack_fused,
+                                   unpack_fused_slots)
 
 _CHUNK = 8192          # rows staged per device write (fixed => no recompiles)
 _MIN_CAPACITY = 16384
@@ -114,6 +115,12 @@ _LOG_ADD = 1
 _LOG_DELETE = 2
 _LOG_MAGIC = b"WTVL"
 _LOG_VERSION = 2  # v2 = per-record checksums + skip-ahead corrupt-region replay
+# add records a run holds at most when a COMPRESSED shard replays its log: a
+# run is copied out of the log (and again where cosine normalises it), and the
+# rows' last home is the host too (`host_vecs`), so a log of one long run
+# would hold the corpus four times over on its way in. An uncompressed replay
+# lands its one long run as it always did.
+_REPLAY_RUN_MAX = 8 * _CHUNK
 
 # query-batch padding buckets (limit distinct compiled shapes)
 _B_BUCKETS = (1, 4, 16, 64, 256, 1024)
@@ -348,12 +355,13 @@ class _ScanProgram:
         return self._for(store).lower(store, *args, **kwargs)
 
 
-_SCAN_STATICS = ("k", "metric", "use_allow", "exact", "active_chunks", "rescore_r")
+_SCAN_STATICS = ("k", "metric", "use_allow", "exact", "active_chunks",
+                 "rescore_r", "candidates")
 
 
 def _scan_full(
     store, sq_norms, tombs, n, q, allow_words, k, metric, use_allow, exact=False,
-    active_chunks=None, rescore_r=0,
+    active_chunks=None, rescore_r=0, candidates=False,
 ):
     """Full-store masked kNN: a loop over HBM chunks, each step one
     [B, chunk] MXU distance block + per-chunk k-selection, exact merge.
@@ -377,7 +385,12 @@ def _scan_full(
     the R winners per query are gathered from the store ON DEVICE and
     re-scored elementwise at exact f32 — selection errors from the fast
     pass sit within R, so the final top-k matches HIGHEST-precision quality
-    at DEFAULT-precision cost."""
+    at DEFAULT-precision cost.
+
+    candidates=True is the program of a compressed index, whose `store` is
+    the bf16 copy of rows the HOST keeps in float32: the selection is all
+    that runs here, and the max(k, rescore_r) columns returned are what it
+    selected, in the scan's order; the host scores them from its rows."""
     cap, dim = store.shape
     chunk = min(cap, _SCAN_CHUNK)
     nchunks = cap // chunk  # cap is a power of two >= 16384, so this divides
@@ -451,7 +464,7 @@ def _scan_full(
 
     init = (jnp.full((b, kk), jnp.inf, jnp.float32), jnp.full((b, kk), -1, jnp.int32))
     (top, idx), _ = jax.lax.scan(step, init, jnp.arange(nchunks, dtype=jnp.int32))
-    if rescore_r:
+    if rescore_r and not candidates:
         # exact f32 rescoring of the R merged candidates, fully on device:
         # gather [B, R, D] rows and score elementwise (VPU work, one HBM
         # gather — no host round trip)
@@ -468,14 +481,21 @@ def _scan_full(
 
 def _search_full_fused(
     store, sq_norms, tombs, n, q, allow_words, s2d, k, metric, use_allow,
-    exact=False, active_chunks=None, rescore_r=0,
+    exact=False, active_chunks=None, rescore_r=0, candidates=False,
 ):
     """_scan_full as the top-level program: the slot->doc translation
     runs in the SAME XLA program, so the one packed fetch carries final doc
-    ids (ops/topk FUSED layout)."""
+    ids (ops/topk FUSED layout; with `candidates` the layout that keeps
+    the slots beside them, translate_pack_slots)."""
     packed = _scan_full(store, sq_norms, tombs, n, q, allow_words, k,
-                        metric, use_allow, exact, active_chunks, rescore_r)
-    return retranslate_packed(packed, s2d)
+                        metric, use_allow, exact, active_chunks, rescore_r,
+                        candidates)
+    if not candidates:
+        return retranslate_packed(packed, s2d)
+    kc = packed.shape[1] // 2
+    return translate_pack_slots(
+        jax.lax.bitcast_convert_type(packed[:, :kc], jnp.float32),
+        packed[:, kc:], s2d)
 
 
 _search_full_fused = _ScanProgram(
@@ -846,6 +866,20 @@ def _slot_words(slots: np.ndarray, capacity: int,
     return words
 
 
+def _host_distances(cand: np.ndarray, q: np.ndarray, metric: str) -> np.ndarray:
+    """Float32 distances of gathered float32 rows on the host: cand
+    [B, R, D] (scratch: overwritten), q [B, D] -> [B, R]. The arithmetic of
+    ops/topk.rescore_distances (cosine: rows and queries arrive
+    normalized), in numpy calls that let go of the GIL."""
+    if metric in (vi.DISTANCE_L2, vi.DISTANCE_MANHATTAN):
+        np.subtract(cand, q[:, None, :], out=cand)
+        if metric == vi.DISTANCE_L2:
+            return np.einsum("brd,brd->br", cand, cand)
+        return np.abs(cand, out=cand).sum(axis=-1)
+    dots = np.matmul(cand, q[:, :, None])[..., 0]
+    return -dots if metric == vi.DISTANCE_DOT else np.float32(1.0) - dots
+
+
 def _prep_bulk_run(ids: np.ndarray, vecs: np.ndarray, metric: str, known_fn):
     """Shared restore-run preparation for the single-chip and mesh indexes:
     f32 cast, cosine normalization, keep-last dedup of in-run duplicate
@@ -1154,11 +1188,13 @@ class VectorLog:
                 return
 
     @staticmethod
-    def replay_batches(path: str, stats: Optional[dict] = None):
+    def replay_batches(path: str, stats: Optional[dict] = None,
+                       run_max: Optional[int] = None):
         """Vectorized replay: maximal runs of same-dim add records parse as
         ONE numpy view — ('add', ids [n] u64, vecs [n, dim] f32) — with
         ('delete', doc_id, None) singles in order. Same corruption tolerance
-        as replay(); restores parse the log ~10x faster this way."""
+        as replay(); restores parse the log ~10x faster this way. `run_max`
+        cuts a v2 log's runs to that many records."""
         if not os.path.exists(path):
             return
         with open(path, "rb") as f:
@@ -1166,7 +1202,8 @@ class VectorLog:
         if data[:4] != _LOG_MAGIC or len(data) < 6:
             return
         if struct.unpack_from("<H", data, 4)[0] >= 2:
-            yield from VectorLog._replay_v2(data, stats, batched=True)
+            yield from VectorLog._replay_v2(data, stats, batched=True,
+                                            run_max=run_max)
             return
         buf = np.frombuffer(data, np.uint8)
         off = 6
@@ -1204,7 +1241,8 @@ class VectorLog:
                 return
 
     @staticmethod
-    def _replay_v2(data: bytes, stats: Optional[dict], batched: bool):
+    def _replay_v2(data: bytes, stats: Optional[dict], batched: bool,
+                   run_max: Optional[int] = None):
         """Shared v2 walk. Valid add-runs still parse as one numpy view (the
         checksum column verifies vectorized, two row-sums per run); any
         record that fails validation starts a skip-ahead scan, and the
@@ -1228,6 +1266,8 @@ class VectorLog:
                 dim, ck0 = struct.unpack_from("<II", data, off + 9)
                 rec = 17 + 4 * dim
                 max_run = (n - off) // rec if 0 < dim <= 65536 else 0
+                if run_max:
+                    max_run = min(max_run, run_max)
                 if max_run == 0:
                     off = _skip(off)
                     if off is None:
@@ -1422,7 +1462,10 @@ class TpuVectorIndex(VectorIndex):
         # one blocking fetch: by then the program has consumed its inputs,
         # so reuse is safe even where device_put aliases host memory
         # (the cpu backend).
-        self._stage_free: dict[tuple[int, int], list[np.ndarray]] = {}
+        # A compressed dispatch's [queries, candidates, dim] gather of
+        # float32 rows (`_rescore_f32`) checks out of the same pool: the
+        # key is the buffer's shape.
+        self._stage_free: dict[tuple, list[np.ndarray]] = {}
         self._stage_lock = sanitizers.register_lock(
             threading.Lock(), "index.tpu.stage_pool")
         # host mirror of the device tombstone mask: snapshots derive the
@@ -1475,6 +1518,14 @@ class TpuVectorIndex(VectorIndex):
         self._opq_rot_dev = None            # device f32 [D, D] (or None)
         self._pq4_path = os.path.join(shard_path, "pq4.npz")
         self._restoring = False
+        # (pq, pq4) the next `_init_device` enters the compressed form
+        # with: set by a restore that found a codebook and by the
+        # compaction of a compressed index, consumed by the first row
+        self._pending_pq: Optional[tuple] = None
+        # what the last restore did (health(); the `restore` incident)
+        self.last_restore: Optional[dict] = None
+        # chunks that went through the codebook on their way in, lifetime
+        self._chunks_encoded = 0
         # flips true on a Mosaic compile failure of the fused gmin kernel;
         # searches then stay on the lax.scan kernel permanently
         self._gmin_broken = False
@@ -1566,14 +1617,23 @@ class TpuVectorIndex(VectorIndex):
     # -- lifecycle -----------------------------------------------------------
 
     def _restore(self) -> None:
-        """Replay the vector log (startup.go:56 restoreFromDisk analog); if a
-        persisted PQ codebook exists, re-enter compressed mode (the analog of
-        commit-log AddPQ replay, deserializer.go) — codes are re-derived on
+        """Replay the vector log (startup.go:56 restoreFromDisk analog). A
+        shard that was compressed (a persisted PQ codebook: the analog of
+        commit-log AddPQ replay, deserializer.go) is replayed STRAIGHT into
+        the compressed form: the codebook is loaded before the log, and
+        every run lands through `_write_block`'s compressed branch — codes,
+        bf16 rows and norms on the device, the float32 rows in
+        `host_vecs`, chunk by chunk. The float32 slab is never allocated,
+        nothing is fetched back, and the codes are re-derived on the
         device, which beats persisting them."""
         self._restoring = True
+        t0 = time.perf_counter()
         try:
+            self._pending_pq = self._load_persisted_pq()
             replay_stats: dict = {}
-            for op, ids, vecs in VectorLog.replay_batches(self._log.path, stats=replay_stats):
+            for op, ids, vecs in VectorLog.replay_batches(
+                    self._log.path, stats=replay_stats,
+                    run_max=_REPLAY_RUN_MAX if self._pending_pq else None):
                 if op == "add":
                     self._bulk_stage_add(ids, vecs)
                 else:
@@ -1581,32 +1641,76 @@ class TpuVectorIndex(VectorIndex):
             VectorLog.report_replay_stats(self._log.path, replay_stats)
             self.last_replay_stats = replay_stats
             if os.path.exists(self._pq_path):
-                from weaviate_tpu.compress.pq import ProductQuantizer
-
                 self._flush_pending()
-                if self.n > 0:
-                    try:
-                        pq = ProductQuantizer.load(self._pq_path)
-                        vecs = np.asarray(self._store[: self.n], dtype=np.float32)
-                        self._enable_pq(pq, vecs, save=False)
-                    except Exception as e:  # noqa: BLE001 — see below
-                        # a pq.npz this build cannot use — rejected config
-                        # (hamming), corrupt zip, missing key, dim mismatch —
-                        # must not make the shard unloadable: serve
-                        # uncompressed with a warning AND a fallback count
-                        # (a fleet of shards quietly serving uncompressed is
-                        # a capacity incident, not a log line)
-                        import logging
-
-                        self.config.pq.enabled = False
-                        record_device_fallback(
-                            "index.tpu.restore", "pq_codebook_rejected", e,
-                            log=False)
-                        logging.getLogger(__name__).warning(
-                            "persisted pq codebook rejected (%s: %s); "
-                            "serving uncompressed", type(e).__name__, e)
+                if self.compressed and self.config.pq.bits == 4 \
+                        and self._pq4 is None and self.n:
+                    # no usable pq4.npz: refit the funnel's ladder from
+                    # the rows just replayed (never costs the shard)
+                    vecs_n = self._host_vecs[: self.n]
+                    self._set_pq4(self._fit_pq4(self._pq, vecs_n))
+                    self._encode_pq4(vecs_n)
         finally:
             self._restoring = False
+            self._pending_pq = None
+        if self.compressed:
+            self._publish_snapshot()
+        self.last_restore = {
+            "mode": "compressed" if self.compressed else "uncompressed",
+            "rows": self.n,
+            "seconds": round(time.perf_counter() - t0, 3),
+            # restore runs in the constructor: the lifetime count is its own
+            "chunks_encoded": self._chunks_encoded,
+        }
+        if self.n:
+            incidents.emit("write_phase", scope="restore",
+                           **self.last_restore)
+
+    def _load_persisted_pq(self):
+        """(pq, pq4 or None) of a shard that was compressed when it shut
+        down, None of one that was not. A pq.npz this build cannot use —
+        rejected config (hamming), corrupt zip, missing key, a codebook
+        that is not its own shape — must not make the shard unloadable:
+        it serves uncompressed with a warning AND a fallback count (a
+        fleet of shards quietly serving uncompressed is a capacity
+        incident, not a log line)."""
+        if not os.path.exists(self._pq_path):
+            return None
+        from weaviate_tpu.compress.pq import ProductQuantizer
+
+        try:
+            pq = ProductQuantizer.load(self._pq_path)
+            if pq.codebook.shape != (pq.segments, pq.centroids, pq.ds):
+                raise ValueError(
+                    f"codebook {pq.codebook.shape} is not "
+                    f"{(pq.segments, pq.centroids, pq.ds)}")
+        except Exception as e:  # noqa: BLE001 — see above
+            record_device_fallback(
+                "index.tpu.restore", "pq_codebook_rejected", e, log=False)
+            self._serve_uncompressed(e)
+            return None
+        pq4 = None
+        if self.config.pq.bits == 4 and os.path.exists(self._pq4_path):
+            try:
+                pq4 = ProductQuantizer.load(self._pq4_path)
+                if pq4.segments != pq.segments or pq4.centroids != 16:
+                    pq4 = None  # stale: refit after the replay
+            except Exception as e:  # noqa: BLE001 — refit is always safe
+                import logging
+
+                logging.getLogger(__name__).warning(
+                    "persisted pq4 codebook rejected (%s: %s); refitting",
+                    type(e).__name__, e)
+        return pq, pq4
+
+    def _serve_uncompressed(self, e: Exception) -> None:
+        """The rest of a rejected codebook (its fallback is counted where
+        it was rejected)."""
+        import logging
+
+        self.config.pq.enabled = False
+        logging.getLogger(__name__).warning(
+            "persisted pq codebook rejected (%s: %s); "
+            "serving uncompressed", type(e).__name__, e)
 
     def post_startup(self) -> None:
         self._flush_pending()
@@ -1617,8 +1721,20 @@ class TpuVectorIndex(VectorIndex):
         self.dim = dim
         self.capacity = _MIN_CAPACITY
         dev = self.device
-        self._store = jax.device_put(jnp.zeros((self.capacity, dim), self.dtype), dev)
-        self._sq_norms = jax.device_put(jnp.zeros((self.capacity,), jnp.float32), dev)
+        held, self._pending_pq = self._pending_pq, None
+        if held is not None and held[0].dim != dim:
+            e = ValueError(f"codebook of {held[0].dim} dims, rows of {dim}")
+            record_device_fallback(
+                "index.tpu.restore", "pq_codebook_rejected", e, log=False)
+            self._serve_uncompressed(e)
+            held = None
+        if held is not None:
+            # a restore or a compaction of a compressed shard: the rows
+            # about to land go straight into the compressed form
+            self._alloc_compressed(*held)
+        else:
+            self._store = jax.device_put(jnp.zeros((self.capacity, dim), self.dtype), dev)
+            self._sq_norms = jax.device_put(jnp.zeros((self.capacity,), jnp.float32), dev)
         self._tombs = jax.device_put(jnp.zeros((self.capacity,), jnp.bool_), dev)
         self._slot_to_doc = np.full(self.capacity, -1, dtype=np.int64)
         self._s2d_dev = jax.device_put(
@@ -1675,10 +1791,20 @@ class TpuVectorIndex(VectorIndex):
             self._stamp_memory()
 
     def _write_block(self, rows: np.ndarray, start: int) -> None:
-        """Land [count, D] float32 rows at slots [start, start+count) in
-        fixed-size chunks (one compiled shape). In compressed mode the chunk
-        is PQ-encoded on device and only the codes hit HBM; the float rows go
-        to the host-side rescoring store."""
+        """Land [count, D] float32 rows at slots [start, start+count): every
+        write path's one way in (flush, bulk import, restore, compact)."""
+        self._land_rows(rows, start)
+        self._ivf_on_rows_written(rows, start)
+        led = memory.get_ledger()
+        if led is not None:
+            led.note_write_shape(
+                ("write_rows", self.capacity, self.dim, self.compressed))
+
+    def _land_rows(self, rows: np.ndarray, start: int) -> None:
+        """The device half of `_write_block`, in fixed-size chunks (one
+        compiled shape). In compressed mode a chunk is PQ-encoded on the
+        device and the codes, its bf16 copy and the norms hit HBM; the
+        float rows go to the host-side rescoring store."""
         count = rows.shape[0]
         off = 0
         while off < count:
@@ -1695,17 +1821,7 @@ class TpuVectorIndex(VectorIndex):
                     start + off,
                 )
                 if self._pq4 is not None:
-                    from weaviate_tpu.compress import pq as pq_mod
-
-                    codes4 = self._pq4.encode(chunk)  # [_CHUNK, M] 0..15
-                    self._codes4 = _write_rows(
-                        self._codes4,
-                        jnp.asarray(pq_mod.pack_codes4(codes4)),
-                        start + off)
-                    self._recon_norms4 = _write_norms(
-                        self._recon_norms4,
-                        jnp.asarray(self._pq4.recon_sq_norms(codes4)),
-                        start + off)
+                    self._land_chunk4(chunk, start + off)
                 if self._rescore_dev is not None:
                     self._rescore_dev = _write_rows(
                         self._rescore_dev, jnp.asarray(chunk, jnp.bfloat16), start + off
@@ -1726,11 +1842,7 @@ class TpuVectorIndex(VectorIndex):
             off += take
         if self.compressed:
             self._host_vecs[start : start + count] = rows
-        self._ivf_on_rows_written(rows, start)
-        led = memory.get_ledger()
-        if led is not None:
-            led.note_write_shape(
-                ("write_rows", self.capacity, self.dim, self.compressed))
+            self._chunks_encoded += -(-count // _CHUNK)
         self._stamp_memory()
 
     def _stage_add(self, doc_id: int, vector: np.ndarray, log: bool = True) -> None:
@@ -1938,22 +2050,29 @@ class TpuVectorIndex(VectorIndex):
             self._update_index_gauges()
         self._maybe_declared_compress()
         self._maybe_ivf_train()
-        if flushed or self._published_gen != self._staged_gen:
+        if (flushed or self._published_gen != self._staged_gen) \
+                and not self._restoring:
             # publication is the LAST step: readers grabbing the new
-            # reference must see every staged mutation already applied
+            # reference must see every staged mutation already applied.
+            # Not inside a restore or a compaction's rebuild: nothing reads
+            # then, and a snapshot published between two replayed runs pins
+            # a third generation of the slab beside the two a write holds
+            # (its end publishes)
             self._publish_snapshot()
 
     def _maybe_declared_compress(self) -> None:
-        # pq.enabled set at class creation: compress once enough data exists
-        # to fit codebooks (the reference requires an explicit post-import
-        # config update; we also honor the declarative form). Evaluated on
-        # every flush AND every direct batch write — the snapshot read path
-        # no longer flushes on each search, so writes must carry the trigger
+        # pq.enabled set at class creation: compress once the rows the
+        # documented procedure imports before it enables pq are there, the
+        # class's `trainingLimit` (the reference requires an explicit
+        # post-import config update; we also honor the declarative form,
+        # and fit on those rows as it does). Evaluated on every flush AND
+        # every direct batch write — the snapshot read path no longer
+        # flushes on each search, so writes must carry the trigger
         if (
             self.config.pq.enabled
             and not self.compressed
             and not self._restoring
-            and self.n >= max(256, self.config.pq.centroids)
+            and self.n >= self.config.pq.training_limit
         ):
             try:
                 self._compress_locked()
@@ -2403,7 +2522,7 @@ class TpuVectorIndex(VectorIndex):
             rotation=self.config.pq.rotation,
         )
         vecs = np.asarray(self._store[: self.n], dtype=np.float32)
-        pq.fit(vecs)
+        pq.fit(vecs, sample_max=self.config.pq.training_limit)
         self._enable_pq(pq, vecs, save=True)
 
     def _fit_pq4(self, pq, vecs_n: np.ndarray):
@@ -2423,109 +2542,92 @@ class TpuVectorIndex(VectorIndex):
             distribution=self.config.pq.encoder.distribution,
             rotation=vi.PQ_ROTATION_NONE,
         )
-        pq4.fit(vecs_n, rotation_matrix=pq.rotation_matrix)
+        pq4.fit(vecs_n, rotation_matrix=pq.rotation_matrix,
+                sample_max=self.config.pq.training_limit)
         return pq4
 
-    def _obtain_pq4(self, pq, vecs_n: np.ndarray):
-        """The funnel quantizer for _enable_pq: a restore prefers the
-        persisted pq4.npz (deterministic across restarts, skips the kmeans
-        refit); anything else — fresh compress, missing/stale/corrupt file
-        — fits from scratch with the pinned rotation. A rejected pq4.npz
-        only costs the refit, never the shard."""
-        if self._restoring and os.path.exists(self._pq4_path):
-            from weaviate_tpu.compress.pq import ProductQuantizer
-
-            try:
-                pq4 = ProductQuantizer.load(self._pq4_path)
-                if pq4.segments == pq.segments and pq4.centroids == 16:
-                    return pq4
-            except Exception as e:  # noqa: BLE001 — refit is always safe
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "persisted pq4 codebook rejected (%s: %s); refitting",
-                    type(e).__name__, e)
-        return self._fit_pq4(pq, vecs_n)
-
-    def _enable_pq(self, pq, vecs_n: np.ndarray, save: bool,
-                   pq4=None) -> None:
-        from weaviate_tpu.compress import pq as pq_mod
-
-        t0 = time.perf_counter()
-        codes = pq.encode(vecs_n)  # [n, M]
-        full = np.zeros((self.capacity, pq.segments), dtype=pq.code_dtype)
-        full[: self.n] = codes
-        self._codes = jax.device_put(jnp.asarray(full), self.device)
-        hv = np.zeros((self.capacity, self.dim), np.float32)
-        hv[: self.n] = vecs_n
-        self._host_vecs = hv
+    def _alloc_compressed(self, pq, pq4) -> None:
+        """Enter the compressed form at the current capacity with no row
+        in it: zeroed codes, reconstruction norms, the bf16 copy the fast
+        scan reads (unless pq.rescore is off: the memory-tightest tier)
+        and the host's float32 rows; the float32 slab, if there is one, is
+        let go. Rows then land through `_land_rows`."""
+        dev, cap = self.device, self.capacity
+        self._pq = pq
+        self._codes = jax.device_put(
+            jnp.zeros((cap, pq.segments), pq.code_dtype), dev)
         self._recon_norms = jax.device_put(
-            jnp.asarray(
-                np.concatenate([
-                    pq.recon_sq_norms(codes),
-                    np.zeros(self.capacity - self.n, np.float32),
-                ])
-            ),
-            self.device,
-        )
-        # bf16 rescore copy stays in HBM: the candidate rescoring pass then
-        # never crosses the host boundary (half the f32 footprint the codes
-        # just replaced; disable via pq.rescore=false for memory-tightest)
-        if self.config.pq.rescore:
-            # hv already holds the zero-padded [capacity, D] rows
-            self._rescore_dev = jax.device_put(
-                jnp.asarray(hv, jnp.bfloat16), self.device
-            )
-            # the fast scan runs straight over this copy; only l2 reads the
-            # norms (einsum: f64 accumulation without a full f64 temp)
-            self._rescore_sq_norms = (
-                jax.device_put(jnp.asarray(np.einsum(
-                    "ij,ij->i", hv, hv, dtype=np.float64).astype(np.float32)),
-                    self.device)
-                if self.metric == vi.DISTANCE_L2 else None)
-        else:
-            self._rescore_dev = None
-            self._rescore_sq_norms = None
-        # 4-bit funnel ladder (pq.bits=4): a SECOND 16-centroid quantizer
-        # fit in the 8-bit quantizer's rotated space (its OPQ rotation is
-        # PINNED via fit(rotation_matrix=...), so the Procrustes
-        # alternation runs once per compress, not once per bit depth) —
-        # nibble-packed codes halve the code bytes again and serve as the
-        # funnel's stage-1 scan plane, with the 8-bit codes as stage 2
-        if self.config.pq.bits == 4:
-            if pq4 is None:
-                pq4 = self._obtain_pq4(pq, vecs_n)
-            codes4 = pq4.encode(vecs_n)  # [n, M] values 0..15
-            packed = pq_mod.pack_codes4(codes4)  # [n, M/2]
-            full4 = np.zeros((self.capacity, pq4.segments // 2), np.uint8)
-            full4[: self.n] = packed
-            self._codes4 = jax.device_put(jnp.asarray(full4), self.device)
-            self._recon_norms4 = jax.device_put(
-                jnp.asarray(np.concatenate([
-                    pq4.recon_sq_norms(codes4),
-                    np.zeros(self.capacity - self.n, np.float32),
-                ])),
-                self.device,
-            )
-            self._opq_rot_dev = (
-                jax.device_put(
-                    jnp.asarray(pq4.rotation_matrix, jnp.float32),
-                    self.device)
-                if pq4.rotation_matrix is not None else None)
-            self._pq4 = pq4
-            self._pq4_cb = None
-        else:
-            self._pq4 = None
-            self._codes4 = None
-            self._recon_norms4 = None
-            self._opq_rot_dev = None
-            self._pq4_cb = None
+            jnp.zeros((cap,), jnp.float32), dev)
+        self._host_vecs = np.zeros((cap, self.dim), np.float32)
+        # the bf16 copy stays in HBM: half the f32 footprint the codes
+        # replace, and the scan that selects the candidates reads it
+        rescore = self.config.pq.rescore
+        self._rescore_dev = (jax.device_put(
+            jnp.zeros((cap, self.dim), jnp.bfloat16), dev)
+            if rescore else None)
+        # only l2 reads the norms
+        self._rescore_sq_norms = (jax.device_put(
+            jnp.zeros((cap,), jnp.float32), dev)
+            if rescore and self.metric == vi.DISTANCE_L2 else None)
+        self._set_pq4(pq4)
         self._store = None
         self._sq_norms = None
-        self._pq = pq
         self.compressed = True
         if not self.config.pq.enabled:
             self.config.pq.enabled = True
+        self._stamp_memory()
+
+    def _set_pq4(self, pq4) -> None:
+        """The 4-bit funnel ladder (pq.bits=4), empty: a SECOND
+        16-centroid quantizer fit in the 8-bit quantizer's rotated space
+        (its OPQ rotation is PINNED via fit(rotation_matrix=...), so the
+        Procrustes alternation runs once per compress, not once per bit
+        depth) — nibble-packed codes halve the code bytes again and serve
+        as the funnel's stage-1 scan plane, with the 8-bit codes as stage
+        2. None: no ladder."""
+        dev, cap = self.device, self.capacity
+        self._pq4 = pq4
+        self._pq4_cb = None
+        self._codes4 = self._recon_norms4 = self._opq_rot_dev = None
+        if pq4 is not None:
+            self._codes4 = jax.device_put(
+                jnp.zeros((cap, pq4.segments // 2), jnp.uint8), dev)
+            self._recon_norms4 = jax.device_put(
+                jnp.zeros((cap,), jnp.float32), dev)
+            if pq4.rotation_matrix is not None:
+                self._opq_rot_dev = jax.device_put(
+                    jnp.asarray(pq4.rotation_matrix, jnp.float32), dev)
+        self._stamp_memory()
+
+    def _land_chunk4(self, chunk: np.ndarray, at: int) -> None:
+        """One [_CHUNK, D] chunk into the funnel's ladder at slot `at`:
+        its nibble-packed 4-bit codes and their reconstruction norms."""
+        from weaviate_tpu.compress import pq as pq_mod
+
+        codes4 = self._pq4.encode(chunk)  # [_CHUNK, M] 0..15
+        self._codes4 = _write_rows(
+            self._codes4, jnp.asarray(pq_mod.pack_codes4(codes4)), at)
+        self._recon_norms4 = _write_norms(
+            self._recon_norms4,
+            jnp.asarray(self._pq4.recon_sq_norms(codes4)), at)
+        self._stamp_memory()
+
+    def _encode_pq4(self, vecs_n: np.ndarray) -> None:
+        """Fill the funnel's ladder alone for rows [0, n) (a restore whose
+        pq4.npz was missing or stale, after the replay)."""
+        for off in range(0, len(vecs_n), _CHUNK):
+            chunk = np.zeros((_CHUNK, self.dim), np.float32)
+            chunk[: len(vecs_n) - off] = vecs_n[off : off + _CHUNK]
+            self._land_chunk4(chunk, off)
+
+    def _enable_pq(self, pq, vecs_n: np.ndarray, save: bool) -> None:
+        """Compress an index that holds its rows uncompressed (an explicit
+        enable, the declared form at its `trainingLimit`): `vecs_n` [n, D]
+        are those rows."""
+        t0 = time.perf_counter()
+        pq4 = self._fit_pq4(pq, vecs_n) if self.config.pq.bits == 4 else None
+        self._alloc_compressed(pq, pq4)
+        self._land_rows(vecs_n, 0)
         if save and self._log is not None:
             pq.save(self._pq_path)
             if self._pq4 is not None:
@@ -2691,6 +2793,9 @@ class TpuVectorIndex(VectorIndex):
 
     def _search_full_gmin(self, snap: IndexSnapshot, q: np.ndarray, kk: int,
                           allow_words, store=None, sq_norms=None):
+        """`store` given: a compressed index's bf16 rows, and the program
+        returns its `_candidate_depth` best by them, slots kept (the groups
+        kept are still sized by kk)."""
         from weaviate_tpu.ops import gmin_scan
 
         interpret = device.pallas_interpret()
@@ -2707,7 +2812,7 @@ class TpuVectorIndex(VectorIndex):
         )
         statics = (
             allow_words is not None,
-            kk,
+            kk if store is None else self._candidate_depth(kk, snap.n),
             self.metric,
             self._gmin_rg(kk, snap.capacity),
             -(-snap.n // ncols),  # live store slices only
@@ -2715,7 +2820,8 @@ class TpuVectorIndex(VectorIndex):
             self._gen_blocks(s, gmin_scan.build_rescore_blocks),
         )
         return gmin_scan.search_gmin_fused(*args, snap.slot_to_doc_dev,
-                                           *statics)
+                                           *statics,
+                                           with_slots=store is not None)
 
     def _gmin_packed_or_none(self, snap: IndexSnapshot, q: np.ndarray,
                              kk: int, allow_words, store=None, sq_norms=None):
@@ -2916,6 +3022,12 @@ class TpuVectorIndex(VectorIndex):
         # at reduced precision; fall back to the HIGHEST-precision scan
         return r if r >= 2 * k else 0
 
+    def _candidate_depth(self, k: int, n: int) -> int:
+        """Candidates a query that a compressed index's scan of its bf16
+        rows hands the host for float32 scoring: the fast scan's own depth
+        (`_rescore_r`), or k where it does not apply."""
+        return max(self._rescore_r(k, n), k)
+
     # bound per-bucket free-list length: buffers parked beyond the live
     # pipeline depth are dead weight (a burst of concurrent dispatches can
     # momentarily check out more; the extras just get collected)
@@ -2937,12 +3049,7 @@ class TpuVectorIndex(VectorIndex):
             q = q[None, :]
         b = q.shape[0]
         bb = _bucket_b(b)
-        key = (bb, q.shape[1])
-        with self._stage_lock:
-            lst = self._stage_free.get(key)
-            buf = lst.pop() if lst else None
-        if buf is None:
-            buf = np.empty(key, np.float32)
+        buf = self._checkout_stage((bb, q.shape[1]))
         np.copyto(buf[:b], q)
         if self.metric == vi.DISTANCE_COSINE:
             norms = np.linalg.norm(buf[:b], axis=1, keepdims=True)
@@ -2952,10 +3059,18 @@ class TpuVectorIndex(VectorIndex):
             buf[b:] = 0.0
         return buf, b
 
+    def _checkout_stage(self, shape: tuple) -> np.ndarray:
+        """A float32 buffer of `shape` from the pool (contents undefined),
+        a fresh one where none is parked."""
+        with self._stage_lock:
+            lst = self._stage_free.get(shape)
+            buf = lst.pop() if lst else None
+        return np.empty(shape, np.float32) if buf is None else buf
+
     def _release_stage(self, buf: Optional[np.ndarray]) -> None:
         if buf is None:
             return
-        key = (buf.shape[0], buf.shape[1])
+        key = buf.shape
         with self._stage_lock:
             # dim is None once drop() ran (or mid-compact teardown): an
             # in-flight dispatch finalizing after drop must NOT re-park
@@ -3401,7 +3516,9 @@ class TpuVectorIndex(VectorIndex):
         rescore copy under PQ-with-rescore (scanning codes first would read
         MORE HBM than the copy the rescore pass consults anyway). The
         slot->doc translation runs in the same program, against the
-        snapshot's device table, and finalize is a reshape."""
+        snapshot's device table, and finalize is a reshape; over the bf16
+        copy the program's columns are candidates and finalize scores them
+        from the float32 rows the host keeps (`_rescore_f32`)."""
         kk = min(max(k_eff, 1), snap.n)
         packed_dev = self._gmin_packed_or_none(snap, q, kk, allow_words,
                                                store, sq_norms)
@@ -3409,11 +3526,18 @@ class TpuVectorIndex(VectorIndex):
             packed_dev = self._scan_program(
                 snap, snap.store if store is None else store,
                 snap.sq_norms if sq_norms is None else sq_norms, q,
-                allow_words, kk)
-        return self._finalize_fused(packed_dev, shape, b)
+                allow_words, kk, candidates=store is not None)
+        if store is None:
+            return self._finalize_fused(packed_dev, shape, b)
+
+        def finalize():
+            return self._rescore_f32(
+                snap, q, _fetch_packed(packed_dev, shape), b, kk, shape)
+
+        return finalize
 
     def _scan_program(self, snap: IndexSnapshot, store, sq_norms, q,
-                      allow_words, kk: int):
+                      allow_words, kk: int, candidates: bool = False):
         """Enqueue the lax.scan program over `store`: `allow_words` None, one
         [capacity / 32] mask for every query, or a [queries, capacity / 32]
         block, a mask a query."""
@@ -3424,7 +3548,47 @@ class TpuVectorIndex(VectorIndex):
             else jnp.zeros((snap.capacity // 32,), jnp.uint32),
             snap.slot_to_doc_dev, kk, self.metric, allow_words is not None,
             getattr(self.config, "exact_topk", False),
-            -(-snap.n // _SCAN_CHUNK), self._rescore_r(kk, snap.n))
+            -(-snap.n // _SCAN_CHUNK), self._rescore_r(kk, snap.n),
+            candidates)
+
+    def _rescore_f32(self, snap: IndexSnapshot, q: np.ndarray,
+                     packed: np.ndarray, b: int, k: int, shape):
+        """The last step of a compressed dispatch under pq.rescore: the
+        program selected R candidates a query by the bf16 copy of the rows
+        (`packed`: the translate_pack_slots layout, in the scan's order);
+        their distances are computed here in float32 from the rows the
+        host keeps (`host_vecs`), the top k is taken from THOSE, and the
+        reply carries those. Compression may cost recall, never a
+        distance. One gather into a pooled buffer and one contraction,
+        both numpy calls that let go of the GIL; no loop over rows."""
+        ids, _, slots = unpack_fused_slots(packed[:b])
+        r = slots.shape[1]
+        phase = tracing.Phase("rescore") if shape is not None else None
+        # pooled: fresh pages a dispatch cost more than the copy (PERF.md,
+        # PR 28)
+        buf = self._checkout_stage((b, r, snap.dim))
+        try:
+            np.take(snap.host_vecs, np.maximum(slots, 0).ravel(), axis=0,
+                    out=buf.reshape(b * r, snap.dim), mode="clip")
+            d = _host_distances(buf, q[:b], self.metric)
+        finally:
+            self._release_stage(buf)
+        d[slots < 0] = np.inf
+        # stable: candidates that tie keep the scan's order
+        order = np.argsort(d, axis=1, kind="stable")[:, :k]
+        dists = np.take_along_axis(d, order, axis=1).astype(
+            np.float32, copy=False)
+        ids = np.take_along_axis(ids, order, axis=1)
+        rows, nbytes = b * r, b * r * snap.dim * 4
+        # winners the float32 distances moved from the rank the bf16
+        # rows gave them: 0 says R is deeper than the rounding needs
+        promoted = int(np.count_nonzero(
+            (order != np.arange(order.shape[1])) & np.isfinite(dists)))
+        perf.note_rescore(rows, nbytes, promoted)
+        if phase is not None:
+            end_ns = phase.end(rows=rows, bytes=nbytes)
+            shape.rescore_ms = (end_ns - phase.start_ns) / 1e6
+        return ids, dists
 
     def _finalize_fused(self, packed_dev, shape, b: int,
                         k: Optional[int] = None):
@@ -3917,6 +4081,11 @@ class TpuVectorIndex(VectorIndex):
             # read-your-writes flush debt the next read pays)
             "staged_lag": max(self._staged_gen - self._published_gen, 0),
             "compressed": self.compressed,
+            # what the last restart replayed into: `mode` compressed (a
+            # persisted codebook: rows went straight into codes, bf16 rows
+            # and host_vecs) or uncompressed, rows, seconds, chunks that
+            # went through the codebook; None for an index never restored
+            "restore": self.last_restore,
             "pq": None,
             # the IVF partition layout's health: a skewed or
             # padding-wasteful layout is visible HERE before it costs
@@ -3951,6 +4120,10 @@ class TpuVectorIndex(VectorIndex):
                 "rescore": bool(self.config.pq.rescore
                                 and self._rescore_dev is not None),
                 "code_dtype": str(getattr(pq, "code_dtype", "")),
+                # rows the codebook was fitted on (a declared class: its
+                # `trainingLimit`); None for a codebook persisted before
+                # the count was kept
+                "trained_rows": getattr(pq, "trained_rows", None),
                 # quantization-ladder state (the /debug/index satellite):
                 # which bit depth serves, whether an OPQ rotation is
                 # pinned, the controller-capped funnel budgets, and the
@@ -4305,9 +4478,10 @@ class TpuVectorIndex(VectorIndex):
             # packed-words cache keyed on the old mapping (same n/capacity
             # possible after re-adds) must never be served again
             self._allow_token = object()
-            # rebuild device state (uncompressed rebuild, then re-encode);
-            # the pq4 quantizer rides along with the 8-bit one so the
-            # post-rebuild re-encode preserves BOTH ladders' codebooks
+            # rebuild device state; a compressed index is rebuilt straight
+            # into its compressed form (`_init_device` takes the codebooks
+            # from `_pending_pq`), the pq4 quantizer riding along with the
+            # 8-bit one so the re-encode preserves BOTH ladders' codebooks
             pq, pq4, was_compressed = self._pq, self._pq4, self.compressed
             self.compressed = False
             self._pq = None
@@ -4344,15 +4518,14 @@ class TpuVectorIndex(VectorIndex):
             # for it (the auditor's ground-truth parity test caught this)
             prev_restoring = self._restoring
             self._restoring = True
+            self._pending_pq = (pq, pq4) if was_compressed else None
             try:
                 for d, v in zip(docs.tolist(), vecs):
                     self._stage_add(int(d), v, log=False)
                 self._flush_pending()
             finally:
                 self._restoring = prev_restoring
-            if was_compressed and self.n > 0:
-                fresh = np.asarray(self._store[: self.n], dtype=np.float32)  # graftlint: disable=JGL008 compact is a stop-the-world rebuild: the lock must cover it and the materialized store IS the rebuild's input
-                self._enable_pq(pq, fresh, save=False, pq4=pq4)
+                self._pending_pq = None
             # recluster on the compacted slot space (fresh k-means — the
             # densified layout is a different distribution than the
             # tombstone-riddled one); publish so readers see it

@@ -129,6 +129,8 @@ class DispatchShape:
                      (query prep, allowList pack, host gather)
       device_ms      the ONE blocking device->host fetch (finalize)
       finalize_ms    whole finalize() wall — device_ms + the host hop
+      rescore_ms     float32 rescoring of a compressed dispatch's
+                     candidates from the host's rows (inside finalize)
       filter_ms      allowList build (shard, filtered dispatches)
       hydrate_ms     LSM result hydration (shard)
     and the monotonic interval [t_start, t_end] from enqueue start to
@@ -137,7 +139,7 @@ class DispatchShape:
 
     __slots__ = ("tier", "n", "dim", "batch", "batch_padded",
                  "bytes_per_row", "k", "extra", "ndev",
-                 "enqueue_ms", "device_ms", "finalize_ms",
+                 "enqueue_ms", "device_ms", "finalize_ms", "rescore_ms",
                  "filter_ms", "hydrate_ms", "t_start", "t_end",
                  "t_fetch", "t_fetch_mono", "fetches", "hop")
 
@@ -160,6 +162,7 @@ class DispatchShape:
         self.enqueue_ms = -1.0
         self.device_ms = -1.0
         self.finalize_ms = -1.0
+        self.rescore_ms = -1.0
         self.filter_ms = -1.0
         self.hydrate_ms = -1.0
         self.t_start = 0.0
@@ -203,13 +206,15 @@ class DispatchShape:
 
     def hop_ms(self) -> float:
         """The host hop between the device fetch and hydration — finalize
-        wall minus the blocking fetch. The slot->doc translation runs ON
-        DEVICE inside the search program, so the hop is the unpack: dtype
-        views + two word copies (docs/performance.md "anatomy of a
-        dispatch"). -1 when the split was not measured."""
+        wall minus the blocking fetch (and minus a compressed dispatch's
+        float32 rescoring, a phase of its own). The slot->doc translation
+        runs ON DEVICE inside the search program, so the hop is the
+        unpack: dtype views + two word copies (docs/performance.md
+        "anatomy of a dispatch"). -1 when the split was not measured."""
         if self.finalize_ms < 0.0 or self.device_ms < 0.0:
             return -1.0
-        return max(self.finalize_ms - self.device_ms, 0.0)
+        return max(self.finalize_ms - self.device_ms
+                   - max(self.rescore_ms, 0.0), 0.0)
 
     def end_hop(self) -> float:
         """Close the open `gather_hop` interval (finalize, on the thread
@@ -229,6 +234,8 @@ class DispatchShape:
         hop = self.hop_ms()
         if hop >= 0.0:
             out["gather_hop"] = hop
+        if self.rescore_ms >= 0.0:
+            out["rescore"] = self.rescore_ms
         if self.hydrate_ms >= 0.0:
             out["hydrate"] = self.hydrate_ms
         return out

@@ -5,7 +5,8 @@ What the device does is read from a profiler capture and nowhere else
 
 - the **ledger**: per-phase durations over the last ``window_s`` seconds
   (``decode`` / ``queue_wait`` / ``filter`` / ``enqueue`` / ``device`` /
-  ``gather_hop`` / ``hydrate`` / ``scatter`` / ``encode``), fed by
+  ``gather_hop`` / ``rescore`` / ``hydrate`` / ``scatter`` / ``encode``),
+  fed by
   db/shard.py for EVERY dispatch while the tracer is up (full coverage,
   independent of trace sampling), by the coalescer per admitted request
   and by the gRPC entry per sampled request;
@@ -16,6 +17,10 @@ What the device does is read from a profiler capture and nowhere else
 - the **point-get counters**: what the native LSM point-get plane did
   (storage/lsm_native.py; `hydrate` is two such calls a batch): keys asked,
   segment tables probed, key bytes compared, arenas grown;
+- the **rescore counters**: what the float32 rescoring of a compressed
+  index's candidates read from the host's rows (index/tpu.py
+  ``_rescore_f32``): dispatches, candidate rows scored, bytes gathered,
+  winners the float32 distances moved from their bf16 rank;
 - the **capture log**: while ``profiling.device_trace`` has a profiler
   session open, every closed host phase is kept as ``(name, thread id,
   start_ns, end_ns)`` on ``time.perf_counter_ns``, anchored at the stamp
@@ -54,7 +59,7 @@ from weaviate_tpu.monitoring import costmodel
 # encode are fed by the gRPC entry per sampled request, queue_wait and
 # scatter by the coalescer, the rest by the shard per dispatch)
 PHASES = ("decode", "queue_wait", "filter", "enqueue", "device",
-          "gather_hop", "hydrate", "scatter", "encode")
+          "gather_hop", "rescore", "hydrate", "scatter", "encode")
 
 # intervals one capture keeps; beyond it they are counted as `dropped`
 # (a 5 s capture of the busiest cell closes about 3,000)
@@ -165,6 +170,9 @@ class PerfWindow:
         # (t_mono, keys, segment_probes, key_compares, arena_grows) per
         # native point-get call, count-capped like the phases
         self._point_get: deque = deque(maxlen=_PHASE_SAMPLES_MAX)
+        # (t_mono, rows, bytes, promoted) per float32 rescoring of a
+        # compressed dispatch's candidates, count-capped likewise
+        self._rescore: deque = deque(maxlen=_PHASE_SAMPLES_MAX)
         # [second, keys, segment_probes, ids, {walk: calls}] per second of
         # posting reads: a filtered group reads hundreds of postings, so
         # the calls of one second share an entry
@@ -264,6 +272,16 @@ class PerfWindow:
             while d[0][0] < horizon:
                 d.popleft()
 
+    def note_rescore(self, rows: int, nbytes: int, promoted: int) -> None:
+        """One compressed dispatch's float32 rescoring on the host."""
+        now = time.monotonic()
+        with self._lock:
+            d = self._rescore
+            d.append((now, rows, nbytes, promoted))
+            horizon = now - self.window_s
+            while d[0][0] < horizon:
+                d.popleft()
+
     def note_posting(self, segment_probes: int, ids: int, walk: str) -> None:
         """One `Bucket.roaring_get`: the segments it asked, the ids it read,
         and the walk that served it (`POSTING_NATIVE`, `POSTING_MEMTABLE`,
@@ -355,7 +373,7 @@ class PerfWindow:
         horizon = now - self.window_s
         while self._entries and self._entries[0][0] < horizon:
             self._rows -= self._entries.popleft()[2]
-        for d in (*self._phase.values(), self._point_get):
+        for d in (*self._phase.values(), self._point_get, self._rescore):
             while d and d[0][0] < horizon:
                 d.popleft()
         while self._postings and self._postings[0][0] < horizon - 1.0:
@@ -401,6 +419,7 @@ class PerfWindow:
             for d in self._phase.values():
                 d.clear()
             self._point_get.clear()
+            self._rescore.clear()
             self._postings.clear()
             self._duty = DutyCycle(self.window_s)
             self._rows = 0
@@ -427,6 +446,8 @@ class PerfWindow:
                 violations += viol
             total_dispatches = self._total_dispatches
             point_get = [sum(c) for c in list(zip(*self._point_get))[1:]]
+            rescore = [sum(c) for c in list(zip(*self._rescore))[1:]]
+            rescores = len(self._rescore)
             postings = [sum(c) for c in list(zip(*self._postings))[1:4]]
             walks: dict[str, int] = {}
             for e in self._postings:
@@ -467,6 +488,14 @@ class PerfWindow:
             out["point_get"] = dict(zip(
                 ("keys", "segment_probes", "key_compares", "arena_grows"),
                 point_get))
+        if rescore:
+            # the float32 rescoring of compressed dispatches over the
+            # window: `rows / dispatches` is the candidates a dispatch
+            # scored from the host's rows (queries x R), `promoted` the
+            # winners whose rank the float32 distances changed: near 0
+            # says R is deeper than the bf16 rounding needs
+            out["rescore"] = {"dispatches": rescores, **dict(zip(
+                ("rows", "bytes", "promoted"), rescore))}
         if postings:
             # the roaring-set posting reads over the window (every filter
             # leaf, BM25 and hybrid allowLists, `keys()`): `native` calls
@@ -557,6 +586,14 @@ def note_point_get(keys: int, segment_probes: int, key_compares: int,
     w = _window
     if w is not None:
         w.note_point_get(keys, segment_probes, key_compares, arena_grows)
+
+
+def note_rescore(rows: int, nbytes: int, promoted: int) -> None:
+    """`PerfWindow.note_rescore` on the installed window; one comparison
+    while the plane is down."""
+    w = _window
+    if w is not None:
+        w.note_rescore(rows, nbytes, promoted)
 
 
 def note_posting(segment_probes: int, ids: int, walk: str) -> None:

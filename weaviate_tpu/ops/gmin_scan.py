@@ -259,11 +259,12 @@ def build_rescore_blocks(store):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("use_allow", "k", "metric", "rg", "active_g", "interpret"),
+    static_argnames=("use_allow", "k", "metric", "rg", "active_g", "interpret",
+                     "with_slots"),
 )
 def search_gmin_fused(store, sq_norms, tombs, n, q, allow_words, s2d,
                       use_allow, k, metric, rg, active_g=G, interpret=False,
-                      rescore_blk=None):
+                      rescore_blk=None, with_slots=False):
     """The full-store search as one program: group-min fast scan -> top-RG
     groups -> exact rescore of RG*G members -> top-k (gmin_topk) -> doc
     ids. The matmul metrics' fast twin of index/tpu.py _search_full_fused.
@@ -275,12 +276,15 @@ def search_gmin_fused(store, sq_norms, tombs, n, q, allow_words, s2d,
     device-resident [capacity, 2] uint32 doc-id word table (index/tpu.py
     IndexSnapshot.slot_to_doc_dev) and the return is the FUSED [B, 3k]
     layout (ops/topk.translate_pack): final doc ids leave the device in the
-    one packed fetch."""
-    from weaviate_tpu.ops.topk import translate_pack
+    one packed fetch. with_slots: the [B, 4k] layout that keeps the slots
+    (ops/topk.translate_pack_slots): the k columns of a compressed index's
+    bf16 rows are candidates the host scores again."""
+    from weaviate_tpu.ops.topk import translate_pack, translate_pack_slots
 
     top, idx = gmin_topk(store, sq_norms, tombs, n, q, allow_words, use_allow,
                          k, metric, rg, active_g, interpret, rescore_blk)
-    return translate_pack(top, idx, s2d)
+    return (translate_pack_slots if with_slots else translate_pack)(
+        top, idx, s2d)
 
 
 def gmin_topk(store, sq_norms, tombs, n, q, allow_words, use_allow,

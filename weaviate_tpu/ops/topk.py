@@ -138,6 +138,26 @@ def unpack_fused(packed) -> tuple:
     return ids, dists
 
 
+def translate_pack_slots(top: Array, idx: Array, s2d: Array) -> Array:
+    """translate_pack with the slots kept beside the doc ids:
+
+        [B, 4k] int32 = [ dists (f32 bitcast) | id_lo | id_hi | slots ]
+
+    What a compressed index's program returns: its k columns are
+    CANDIDATES, which the host scores again from the float32 rows it
+    keeps (read by slot) and answers with their doc ids."""
+    return jnp.concatenate(
+        [translate_pack(top, idx, s2d), idx.astype(jnp.int32)], axis=1)
+
+
+def unpack_fused_slots(packed) -> tuple:
+    """Host-side inverse of translate_pack_slots: np [B, 4k] i32 ->
+    (ids u64 [B, k], dists f32 [B, k], slots i32 [B, k], -1 = missing)."""
+    k = packed.shape[1] // 4
+    ids, dists = unpack_fused(packed[:, : 3 * k])
+    return ids, dists, packed[:, 3 * k:]
+
+
 def rescore_distances(cand: Array, q: Array, metric: str) -> Array:
     """Exact f32 distances of gathered candidates: cand [B, R, D] vs
     q [B, D] -> [B, R]. The shared rescore core of the fast-scan kernels
