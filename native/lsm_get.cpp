@@ -48,6 +48,13 @@
 //   lsm_bits_build, lsm_bits_probe  the same through a bitset of the
 //               larger, made once and asked by every filter that holds it.
 //
+//   lsm_group_locate, lsm_group_fill  a filtered GROUP's device operands
+//               from its distinct allowLists (index/tpu.py
+//               search_by_vectors_multi_async): where each list lies in
+//               the snapshot's docs and how many slots it holds, then,
+//               once the plan is made, each gathered slot's int32 rows
+//               and each scanned slot's mask bits, written in place.
+//
 //   roaring-set payload (storage/lsm.py _enc_roaring, storage/bitmap.py):
 //     la u32 | ld u32 | additions | deletions, each
 //     "WTBM" | n u64 | n ids u64 (ascending)
@@ -439,6 +446,141 @@ int64_t lsm_bits_probe(const uint64_t* a, int64_t na, const uint64_t* bits,
         n += (bits[d >> 6] >> (d & 63)) & 1;
     }
     return n;
+}
+
+// -- a filtered group's device operands ------------------------------------
+//
+// A snapshot's doc -> slot rule is its `docs` (slot_to_doc[:n], ascending
+// strictly): slot s holds doc docs[s]. `consecutive` says docs[n-1] -
+// docs[0] == n - 1 (rows put once, from the shard's counter: the served
+// case), and then the slot of doc d is d - docs[0]; else a doc is looked up
+// in docs, galloping on from the last one found (both sides ascend). Ids
+// are the allowLists' uint64 arrays read as int64, as docs are.
+
+}  // extern "C"
+
+namespace {
+
+// first index in [lo, n) of ascending `a` whose value is not below x
+inline int64_t lower_bound_i64(const int64_t* a, int64_t lo, int64_t n,
+                               int64_t x) {
+    int64_t hi = n;
+    while (lo < hi) {
+        const int64_t mid = lo + ((hi - lo) >> 1);
+        if (a[mid] < x) lo = mid + 1; else hi = mid;
+    }
+    return lo;
+}
+
+// fn(slot) for every id of ids[lo, hi) that `docs` holds, ascending
+template <typename F>
+inline void each_slot(const int64_t* ids, int64_t lo, int64_t hi,
+                      const int64_t* docs, int64_t n, bool consecutive,
+                      F&& fn) {
+    if (consecutive) {
+        const int64_t first = docs[0];
+        for (int64_t i = lo; i < hi; i++) fn(ids[i] - first);
+        return;
+    }
+    int64_t at = 0;
+    for (int64_t i = lo; i < hi && at < n; i++) {
+        const int64_t x = ids[i];
+        int64_t step = 1, top = at;
+        while (top < n && docs[top] < x) {
+            at = top + 1;
+            top += step;
+            step <<= 1;
+        }
+        at = lower_bound_i64(docs, at, top < n ? top : n, x);
+        if (at < n && docs[at] == x) fn(at);
+    }
+}
+
+}  // namespace
+
+extern "C" {
+
+// Each list's ids that can be a doc of the snapshot, ids[l][lo[l], hi[l])
+// (two binary searches a list), and how many of them are: sizes[l], its
+// slots. Where docs are consecutive that is hi - lo; else the walk
+// lsm_group_fill will make again. -> the sum over the lists of hi - lo.
+int64_t lsm_group_locate(const int64_t* const* ids, const int64_t* lens,
+                         int64_t n_lists, const int64_t* docs, int64_t n,
+                         int64_t consecutive, int64_t* lo, int64_t* hi,
+                         int64_t* sizes) {
+    int64_t walked = 0;
+    for (int64_t l = 0; l < n_lists; l++) {
+        lo[l] = hi[l] = sizes[l] = 0;
+        if (n == 0 || lens[l] == 0) continue;
+        lo[l] = lower_bound_i64(ids[l], 0, lens[l], docs[0]);
+        // docs[n-1] + 1 would overflow at the top of the range
+        hi[l] = lower_bound_i64(ids[l], lo[l], lens[l], docs[n - 1]);
+        if (hi[l] < lens[l] && ids[l][hi[l]] == docs[n - 1]) hi[l]++;
+        walked += hi[l] - lo[l];
+        if (consecutive) {
+            sizes[l] = hi[l] - lo[l];
+        } else {
+            int64_t m = 0;
+            each_slot(ids[l], lo[l], hi[l], docs, n, false,
+                      [&](int64_t) { m++; });
+            sizes[l] = m;
+        }
+    }
+    return walked;
+}
+
+// The operands of a group's dispatches, written in place. A job is six
+// int64: {kind, dst, aux, height, width, nsel}, and takes its next `nsel`
+// entries of `sel` (indices into the lists), one a row of dst.
+//   kind 0, a gather bucket: dst int32 [height, width] rows, aux the
+//     address of its int32 [height] counts. Row j gets list sel[j]'s slots
+//     and counts[j] their number. counts is also what the buffer's LAST
+//     use left in each row: what lies between the new count and the old is
+//     zeroed, so a reused buffer reads as a fresh one.
+//   kind 1, the masked scan: dst uint32 [height, width] words, aux the
+//     number of rows its last use dirtied. Row j gets bit s % 32 of word
+//     s / 32 set for every slot s of list sel[j]; a dirty row is zeroed
+//     first, whether or not this use fills it.
+void lsm_group_fill(const int64_t* const* ids, const int64_t* lo,
+                    const int64_t* hi, const int64_t* docs, int64_t n,
+                    int64_t consecutive, const int64_t* jobs, int64_t n_jobs,
+                    const int64_t* sel) {
+    for (int64_t q = 0; q < n_jobs; q++, jobs += 6) {
+        const int64_t height = jobs[3], width = jobs[4], nsel = jobs[5];
+        if (jobs[0] == 0) {
+            auto* rows = reinterpret_cast<int32_t*>(jobs[1]);
+            auto* counts = reinterpret_cast<int32_t*>(jobs[2]);
+            for (int64_t j = 0; j < height; j++) {
+                int32_t* row = rows + j * width;
+                int64_t m = 0;
+                if (j < nsel) {
+                    const int64_t l = sel[j];
+                    each_slot(ids[l], lo[l], hi[l], docs, n, consecutive != 0,
+                              [&](int64_t s) {
+                                  if (m < width)
+                                      row[m++] = static_cast<int32_t>(s);
+                              });
+                }
+                if (counts[j] > m)
+                    std::memset(row + m, 0,
+                                static_cast<size_t>(counts[j] - m) * 4);
+                counts[j] = static_cast<int32_t>(m);
+            }
+        } else {
+            auto* words = reinterpret_cast<uint32_t*>(jobs[1]);
+            const int64_t dirty = jobs[2];
+            for (int64_t j = 0; j < height && (j < nsel || j < dirty); j++) {
+                uint32_t* row = words + j * width;
+                if (j < dirty)
+                    std::memset(row, 0, static_cast<size_t>(width) * 4);
+                if (j >= nsel) continue;
+                const int64_t l = sel[j];
+                each_slot(ids[l], lo[l], hi[l], docs, n, consecutive != 0,
+                          [&](int64_t s) { row[s >> 5] |= 1u << (s & 31); });
+            }
+        }
+        sel += nsel;
+    }
 }
 
 // The table's hash of a key (tests search it for colliding keys).

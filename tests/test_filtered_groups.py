@@ -59,6 +59,18 @@ def small_launch(monkeypatch):
     monkeypatch.setattr(costmodel, "GATHER_ROW_SLOWDOWN", 1.0)
 
 
+@pytest.fixture(params=["native", "no_library"])
+def library(request, monkeypatch):
+    """A group's device operands by the native pass, and by numpy where
+    the library is absent (index/group_inputs.py): the same answers."""
+    from weaviate_tpu.storage import lsm_native
+
+    assert lsm_native.available()
+    if request.param == "no_library":
+        monkeypatch.setattr(lsm_native, "_load", lambda: None)
+    return request.param
+
+
 def _allow(rows):
     return Bitmap(np.asarray(rows, np.int64) + DOC0)
 
@@ -97,13 +109,15 @@ def _check(idx, vecs, q, allows, masks, k=K):
     return fin
 
 
-def test_every_size_of_filter_in_one_group(corpus, small_launch):
+def test_every_size_of_filter_in_one_group(corpus, small_launch, library):
     """Allowed rows 0, 1, under k, around the row buckets, one under and one
     over the hand-over point, every row; the same filter twice; a slot
     without a filter."""
     idx, vecs, rng = corpus
     edge = _hand_over(idx)
     assert 128 < edge < N, edge      # both tiers serve at this size
+    tracing.configure(tracing.Tracer(sample_rate=1.0))
+    window = perf.configure(perf.PerfWindow(window_s=60.0))
     sizes = [0, 1, K - 3, 127, 128, 129, 512, 513, edge - 1, edge,
              edge + 1, min(edge + 64, N), N]
     allows, masks = [], []
@@ -117,7 +131,14 @@ def test_every_size_of_filter_in_one_group(corpus, small_launch):
     masks += [masks[4], np.ones(N, bool)]
     q = vecs[rng.integers(0, N, len(allows))] \
         + 0.05 * rng.standard_normal((len(allows), DIM)).astype(np.float32)
-    _check(idx, vecs, q, allows, masks)
+    fin = _check(idx, vecs, q, allows, masks)
+    tiers = {s.tier for s in fin.shapes}
+    assert {costmodel.TIER_GATHER, costmodel.TIER_EXACT} <= tiers
+    built = window.summary()["group_inputs"]
+    assert built["groups"] == 1 and built["lists"] == len(sizes)
+    assert built["native"] == (library == "native")
+    assert built["fallback_reasons"] == (
+        {} if library == "native" else {"no_library": 1})
 
 
 def test_no_cliff_at_the_old_cut_off_nor_at_the_hand_over():
@@ -148,7 +169,7 @@ def test_no_cliff_at_the_old_cut_off_nor_at_the_hand_over():
 
 
 def test_a_tombstoned_row_inside_a_filter_and_a_write_between_searches(
-        tmp_path):
+        tmp_path, library):
     rng = np.random.default_rng(31)
     vecs = rng.standard_normal((N, DIM)).astype(np.float32)
     cfg = parse_and_validate_config("hnsw_tpu", {"distance": "l2-squared"})

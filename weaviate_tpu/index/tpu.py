@@ -59,6 +59,7 @@ import numpy as np
 
 from weaviate_tpu import device
 from weaviate_tpu.entities import vectorindex as vi
+from weaviate_tpu.index import group_inputs
 from weaviate_tpu.index.interface import AllowList, VectorIndex
 # dispatch-shape recording for the perf-attribution plane: a
 # costmodel.DispatchShape is built per dispatch ONLY while the tracer is
@@ -1468,6 +1469,11 @@ class TpuVectorIndex(VectorIndex):
         self._stage_free: dict[tuple, list[np.ndarray]] = {}
         self._stage_lock = sanitizers.register_lock(
             threading.Lock(), "index.tpu.stage_pool")
+        # a filtered group's device operands (a gather bucket's rows and
+        # counts, the masked scan's 32 MB of words), pooled by the same
+        # rule: back in finalize, after the fetch (index/group_inputs.py)
+        self._group_pool = group_inputs.OperandPool(self._STAGE_POOL_CAP,
+                                                    self._stage_lock)
         # host mirror of the device tombstone mask: snapshots derive the
         # live doc->slot map from it without a device fetch
         self._host_tombs = np.zeros(0, dtype=bool)
@@ -4226,33 +4232,43 @@ class TpuVectorIndex(VectorIndex):
                 norms[norms == 0] = 1.0
                 q /= norms
             bb = _bucket_b(s)
-            slots, gathered, scanned, plain = self._plan_group(
+            inputs, gathered, scanned, plain = self._plan_group(
                 snap, allow_lists, bb)
             jobs = [(costmodel.TIER_GATHER, r, gathered[r])
                     for r in sorted(gathered)]
             if scanned:
                 jobs.append((costmodel.TIER_EXACT, snap.n, scanned))
-            for tier, rows, sel in jobs:
-                lists = [slots[i] for i in sel]
+            # every dispatch's operands in one pass over the lists, before
+            # the first upload
+            operands = inputs.fill(self._group_pool, [
+                (True, sel, _gather_slots(bb, rows), rows)
+                if tier == costmodel.TIER_GATHER else
+                (False, sel, _scan_group_bucket(len(sel), bb),
+                 snap.capacity // 32) for tier, rows, sel in jobs])
+            # what the group's first interval resolved
+            resolved = {"lists": inputs.lists, "ids": inputs.ids}
+            for (tier, rows, sel), operand in zip(jobs, operands):
                 gather = tier == costmodel.TIER_GATHER
                 shape = None
                 if traced:
                     enqueue = enqueue or tracing.Phase("enqueue")
                     shape = costmodel.DispatchShape(
                         tier, dim=snap.dim, batch=len(sel), k=int(k_eff),
-                        n=sum(sl.size for sl in lists) if gather else snap.n,
-                        batch_padded=(_gather_slots(bb, rows) if gather
-                                      else _scan_group_bucket(len(sel), bb)),
+                        n=(int(operand.counts.sum(dtype=np.int64)) if gather
+                           else snap.n),
+                        batch_padded=operand.arr.shape[0],
                         bytes_per_row=snap.dim * snap.store.dtype.itemsize,
                         extra={"row_bucket": rows})
                 parts.append((sel, (
-                    self._dispatch_gather_group(snap, q[sel], lists, rows,
+                    self._dispatch_gather_group(snap, q[sel], operand, rows,
                                                 bb, k_eff, shape) if gather
-                    else self._dispatch_scan_group(snap, q[sel], lists, bb,
-                                                   k_eff, shape)), shape))
+                    else self._dispatch_scan_group(snap, q[sel], operand,
+                                                   k_eff, shape)),
+                    shape, operand))
                 if shape is not None:
                     end_ns = enqueue.end(rows=len(sel), tier=tier,
-                                         row_bucket=rows)
+                                         row_bucket=rows, **resolved)
+                    resolved = {}
                     shape.t_start = enqueue.start_ns / 1e9
                     shape.enqueue_ms = (end_ns - enqueue.start_ns) / 1e6
                     shapes.append(shape)
@@ -4264,7 +4280,7 @@ class TpuVectorIndex(VectorIndex):
                 # the unfiltered dispatch as it always was; its own
                 # finalize stamps its shape
                 parts.append((plain, self._dispatch_search(
-                    snap, q[plain], k_eff, None), None))
+                    snap, q[plain], k_eff, None), None, None))
                 shape = self.pop_dispatch_shape()
                 if shape is not None:
                     shapes.append(shape)
@@ -4281,7 +4297,7 @@ class TpuVectorIndex(VectorIndex):
                 faults.fire("index.tpu.finalize")
                 ids = np.zeros((s, k_eff), np.uint64)
                 dists = np.full((s, k_eff), np.inf, np.float32)
-                for sel, fin, shape in parts:  # graftlint: disable=JGL015 a loop over the group's DISPATCHES (a handful: one a row bucket, one scan), each consumed by unpack_fused; no per-row work
+                for sel, fin, shape, operand in parts:  # graftlint: disable=JGL015 a loop over the group's DISPATCHES (a handful: one a row bucket, one scan), each consumed by unpack_fused; no per-row work
                     t0 = time.perf_counter()
                     try:
                         pi, pd = fin()
@@ -4289,6 +4305,14 @@ class TpuVectorIndex(VectorIndex):
                         if shape is not None:
                             shape.t_end = shape.end_hop()
                             shape.finalize_ms = (shape.t_end - t0) * 1000.0
+                    # fetched: the program has consumed its operands, the
+                    # next group may write them (a fetch that failed
+                    # strands its buffer, as the staging pool's does)
+                    if operand is not None:
+                        # `_release_stage`'s rule: never into the pool
+                        # drop() cleared
+                        self._group_pool.give(
+                            operand, lambda: self.dim is not None)
                     ids[sel, : pi.shape[1]] = pi
                     dists[sel, : pd.shape[1]] = pd
                 return ids, dists
@@ -4321,21 +4345,18 @@ class TpuVectorIndex(VectorIndex):
             return hit[1]
 
     def _plan_group(self, snap: IndexSnapshot, allow_lists, bb: int):
-        """Which program serves each slot of a group -> (each slot's store
-        slots, None without a filter; {row bucket: the slots that gather in
-        it}; the slots that share the scan; the slots without a filter). A
-        slot whose filter allows no row rides no dispatch. Equal allowList
-        OBJECTS are resolved once."""
-        by_list: dict[int, np.ndarray] = {}
-        slots: list = []
-        for a in allow_lists:
-            if a is not None and id(a) not in by_list:
-                by_list[id(a)] = self._allow_slots(snap, a)
-            slots.append(None if a is None else by_list[id(a)])
-        plain = [i for i, sl in enumerate(slots) if sl is None]
-        filtered = [i for i, sl in enumerate(slots)
-                    if sl is not None and sl.size]
-        sizes = [int(slots[i].size) for i in filtered]
+        """Which program serves each slot of a group -> (the group's lists
+        resolved in this snapshot: group_inputs.GroupInputs; {row bucket:
+        the slots that gather in it}; the slots that share the scan; the
+        slots without a filter). A slot whose filter allows no row rides no
+        dispatch. Equal allowList OBJECTS are resolved once."""
+        inputs = group_inputs.GroupInputs(snap, allow_lists,
+                                          self._allow_slots, _slot_words)
+        size_of = inputs.sizes.tolist()
+        plain = [i for i, at in enumerate(inputs.list_of) if at < 0]
+        filtered = [i for i, at in enumerate(inputs.list_of)
+                    if at >= 0 and size_of[at]]
+        sizes = [size_of[inputs.list_of[i]] for i in filtered]
         buckets = [_gather_row_bucket(m) for m in sizes]
         to_scan, _ = costmodel.plan_filtered_group(
             sizes, buckets, snap.n, snap.capacity,
@@ -4351,23 +4372,20 @@ class TpuVectorIndex(VectorIndex):
             room = _gather_slots(bb, r)
             scanned.extend(sel[room:])
             del sel[room:]
-        return slots, gathered, sorted(scanned), plain
+        return inputs, gathered, sorted(scanned), plain
 
     def _dispatch_gather_group(self, snap: IndexSnapshot, q: np.ndarray,
-                               slot_lists: list, r: int, bb: int, k: int,
-                               shape):
+                               operand: group_inputs.Operand, r: int,
+                               bb: int, k: int, shape):
         """One per-slot gather program: bucket `r`, the group's slots of
-        that bucket. The shapes compiled are one a (group-width bucket, row
+        that bucket, their store slots in `operand` (rows [s_pad, r],
+        counts). The shapes compiled are one a (group-width bucket, row
         bucket, k): the slot dimension is padded to what the bucket admits
         and the program loops over the real slots alone."""
-        nsel = len(slot_lists)
+        nsel = q.shape[0]
+        rows, counts = operand.arr, operand.counts
         s_pad = _gather_slots(bb, r)
         step = min(_gather_step_slots(r, snap.dim), s_pad)
-        rows = np.zeros((s_pad, r), np.int32)
-        counts = np.zeros(s_pad, np.int32)
-        for j, sl in enumerate(slot_lists):
-            rows[j, : sl.size] = sl
-            counts[j] = sl.size
         store = self._row_store(snap)
         qp = np.zeros((s_pad, store.shape[1]), np.float32)
         qp[:nsel, : snap.dim] = q
@@ -4381,15 +4399,14 @@ class TpuVectorIndex(VectorIndex):
         return self._finalize_fused(packed_dev, shape, nsel)
 
     def _dispatch_scan_group(self, snap: IndexSnapshot, q: np.ndarray,
-                             slot_lists: list, bb: int, k: int, shape):
+                             operand: group_inputs.Operand, k: int, shape):
         """ONE masked scan for the group's widest filters: query i is
-        masked by its own [capacity / 32] words. The lax.scan program
-        serves (the Pallas kernel takes one mask a dispatch)."""
-        nsel = len(slot_lists)
-        b_pad = _scan_group_bucket(nsel, bb)
-        words = np.zeros((b_pad, snap.capacity // 32), np.uint32)
-        for j, sl in enumerate(slot_lists):
-            _slot_words(sl, snap.capacity, out=words[j])
+        masked by its own row of `operand`'s [b_pad, capacity / 32] words.
+        The lax.scan program serves (the Pallas kernel takes one mask a
+        dispatch)."""
+        nsel = q.shape[0]
+        words = operand.arr
+        b_pad = words.shape[0]
         store = self._row_store(snap)
         qp = np.zeros((b_pad, store.shape[1]), np.float32)
         qp[:nsel, : snap.dim] = q
@@ -4567,6 +4584,7 @@ class TpuVectorIndex(VectorIndex):
                 # class may use a different dim; the ledger's
                 # stage_buffers component must read 0 after drop)
                 self._stage_free.clear()
+            self._group_pool.clear()
             self._doc_to_slot.clear()
             self._pending.clear()
             self._pending_tombs.clear()

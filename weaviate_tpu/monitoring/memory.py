@@ -258,6 +258,12 @@ def index_host_components(vidx) -> dict:
             b += sum(int(x.nbytes) for x in list(bufs))
         if b:
             out["stage_buffers"] = b
+    # a filtered group's parked operands (index/group_inputs.py): the same
+    # kind of buffer, the same component
+    pool = getattr(vidx, "_group_pool", None)
+    parked = pool.nbytes() if pool is not None else 0
+    if parked:
+        out["stage_buffers"] = out.get("stage_buffers", 0) + parked
     return out
 
 

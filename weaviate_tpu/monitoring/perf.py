@@ -177,6 +177,9 @@ class PerfWindow:
         # posting reads: a filtered group reads hundreds of postings, so
         # the calls of one second share an entry
         self._postings: deque = deque()
+        # (t_mono, lists, ids, why numpy built them or None, host ms, pool
+        # hits, pool grows) per filtered group's device operands
+        self._group_inputs: deque = deque(maxlen=_PHASE_SAMPLES_MAX)
         self._duty = DutyCycle(self.window_s)
         self._rows = 0  # running sum over the live window
         self._first_entry: Optional[float] = None
@@ -301,6 +304,23 @@ class PerfWindow:
             e[3] += ids
             e[4][walk] = e[4].get(walk, 0) + 1
 
+    def note_group_inputs(self, lists: int, ids: int, reason: Optional[str],
+                          host_ms: float, pool_hits: int,
+                          pool_grows: int) -> None:
+        """One filtered group's device operands (index/group_inputs.py):
+        the distinct allowLists resolved, the ids walked, `reason` why
+        numpy built them (None: the native pass), the host's wall time for
+        the resolution and the fills, and how many operands the pool had
+        parked or had to make."""
+        now = time.monotonic()
+        with self._lock:
+            d = self._group_inputs
+            d.append((now, lists, ids, reason, host_ms, pool_hits,
+                      pool_grows))
+            horizon = now - self.window_s
+            while d[0][0] < horizon:
+                d.popleft()
+
     def note_interval(self, name: str, start_ns: int, end_ns: int,
                       tid: Optional[int] = None) -> None:
         """One closed host phase on ``time.perf_counter_ns``; `tid` is the
@@ -373,7 +393,8 @@ class PerfWindow:
         horizon = now - self.window_s
         while self._entries and self._entries[0][0] < horizon:
             self._rows -= self._entries.popleft()[2]
-        for d in (*self._phase.values(), self._point_get, self._rescore):
+        for d in (*self._phase.values(), self._point_get, self._rescore,
+                  self._group_inputs):
             while d and d[0][0] < horizon:
                 d.popleft()
         while self._postings and self._postings[0][0] < horizon - 1.0:
@@ -421,6 +442,7 @@ class PerfWindow:
             self._point_get.clear()
             self._rescore.clear()
             self._postings.clear()
+            self._group_inputs.clear()
             self._duty = DutyCycle(self.window_s)
             self._rows = 0
             self._first_entry = None
@@ -453,6 +475,7 @@ class PerfWindow:
             for e in self._postings:
                 for walk, calls in e[4].items():
                     walks[walk] = walks.get(walk, 0) + calls
+            groups = list(self._group_inputs)
         out: dict = {
             "window_s": self.window_s,
             "observed_s": round(span, 3),
@@ -508,6 +531,28 @@ class PerfWindow:
                 "native": walks.get(POSTING_NATIVE, 0),
                 "fallback": sum(reasons.values()),
                 "fallback_reasons": reasons,
+            }
+        if groups:
+            # the device operands of the filtered groups over the window
+            # (index/group_inputs.py): `native` groups the one C pass
+            # served, `fallback` those numpy had to, by reason; `host_ms`
+            # their wall time all told (resolution and fills, no upload);
+            # `pool_grows` is 0 once the pool holds the pipeline's depth
+            reasons: dict[str, int] = {}
+            for g in groups:
+                if g[3] is not None:
+                    reasons[g[3]] = reasons.get(g[3], 0) + 1
+            fallback = sum(reasons.values())
+            out["group_inputs"] = {
+                "groups": len(groups),
+                "lists": sum(g[1] for g in groups),
+                "ids": sum(g[2] for g in groups),
+                "native": len(groups) - fallback,
+                "fallback": fallback,
+                "fallback_reasons": dict(sorted(reasons.items())),
+                "host_ms": round(sum(g[4] for g in groups), 3),
+                "pool_hits": sum(g[5] for g in groups),
+                "pool_grows": sum(g[6] for g in groups),
             }
         out["tiers"] = dict(sorted(tiers.items(), key=lambda kv: -kv[1]))
         # the store rows each tier's dispatches read over the window: all
@@ -594,6 +639,17 @@ def note_rescore(rows: int, nbytes: int, promoted: int) -> None:
     w = _window
     if w is not None:
         w.note_rescore(rows, nbytes, promoted)
+
+
+def note_group_inputs(lists: int, ids: int, reason: Optional[str],
+                      host_ms: float, pool_hits: int,
+                      pool_grows: int) -> None:
+    """`PerfWindow.note_group_inputs` on the installed window; one
+    comparison while the plane is down."""
+    w = _window
+    if w is not None:
+        w.note_group_inputs(lists, ids, reason, host_ms, pool_hits,
+                            pool_grows)
 
 
 def note_posting(segment_probes: int, ids: int, walk: str) -> None:

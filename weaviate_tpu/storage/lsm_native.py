@@ -18,6 +18,12 @@ What a call did is counted in C and handed to the perf window
 Reference analog: the compiled lsmkv segment readers under the batched
 hydration seam entities/storobj/storage_object.go:211.
 
+A filtered group's device operands (`GroupLists`) are two C calls a
+group: `locate` (where each distinct allowList lies in the snapshot's docs,
+how many slots it holds; keeps the GIL) and, once the plan is made, `fill`
+(every gathered slot's int32 rows, every scanned slot's mask bits, written
+in place into the caller's buffers; lets go of the GIL).
+
 A posting (`posting_get`) is two C calls a key: the first finds the key in
 every segment's hash table, oldest first, and says how many ids its layers
 hold; the second copies them into one fresh uint64 array, the Bitmap's own,
@@ -92,9 +98,17 @@ def _load() -> Optional[ctypes.CDLL]:
                     ("ids_gallop", i64, (ptr, i64, ptr, i64, ptr)),
                     ("bits_build", None, (ptr, i64, ctypes.c_uint64, ptr)),
                     ("bits_probe", i64,
-                     (ptr, i64, ptr, ctypes.c_uint64, i64, ptr))):
+                     (ptr, i64, ptr, ctypes.c_uint64, i64, ptr)),
+                    ("group_locate", i64,
+                     (ptr, ptr, i64, ptr, i64, i64, ptr, ptr, ptr))):
                 setattr(lib, name, ctypes.PYFUNCTYPE(res, *args)(
                     ("lsm_" + name, lib)))
+            # a group's fill is ONE call of milliseconds (megabytes of
+            # ids walked, of rows and words written): it lets go of the
+            # GIL, as the point gets do
+            lib.lsm_group_fill.restype = None
+            lib.lsm_group_fill.argtypes = [ptr, ptr, ptr, ptr, i64, i64,
+                                           ptr, i64, ptr]
             _lib = lib
         except Exception as e:  # noqa: BLE001 — the Python reader serves
             _lib_failed = True
@@ -333,3 +347,67 @@ def intersect_sorted(small: np.ndarray, big: np.ndarray,
     # the room the result did not need goes back, in place
     out.resize(n, refcheck=False)
     return out, bits
+
+
+class GroupLists:
+    """The distinct allowLists of a filtered group, located in a
+    snapshot's `docs` (`slot_to_doc[:n]`, ascending strictly; `consecutive`
+    where they have no gap): `sizes[l]` is how many store slots list l
+    holds, `walked` the ids of all lists that lie in the docs' range. The
+    plan needs no more; `fill` then writes the operands of the group's
+    dispatches in one call. Holds what the addresses it passes point into."""
+
+    __slots__ = ("_lib", "_ids", "_addrs", "_spans", "_docs", "_consecutive",
+                 "sizes", "walked")
+
+    def __init__(self, lib, ids: Sequence[np.ndarray], docs: np.ndarray,
+                 consecutive: bool):
+        n = len(ids)
+        self._lib, self._ids, self._docs = lib, ids, docs
+        self._consecutive = int(consecutive)
+        self._addrs = np.fromiter((a.ctypes.data for a in ids), np.uintp, n)
+        lens = np.fromiter((a.size for a in ids), np.int64, n)
+        # lo, hi, sizes: one a list each
+        self._spans = np.empty((3, n), dtype=np.int64)
+        at = self._spans.ctypes.data
+        self.walked = int(lib.group_locate(
+            self._addrs.ctypes.data, lens.ctypes.data, n, docs.ctypes.data,
+            docs.size, self._consecutive, at, at + 8 * n, at + 16 * n))
+        self.sizes = self._spans[2]
+
+    def fill(self, jobs: np.ndarray, sel: np.ndarray) -> None:
+        """`jobs` int64 [n, 6] and `sel` int64, as native/lsm_get.cpp
+        lsm_group_fill reads them: a gather bucket's rows and counts, or
+        the masked scan's words, a job."""
+        kind, height, width, nsel = jobs[:, 0], jobs[:, 3], jobs[:, 4], \
+            jobs[:, 5]
+        if (jobs.dtype != np.int64 or sel.dtype != np.int64
+                or not jobs.flags.c_contiguous or nsel.sum() != sel.size
+                or (nsel > height).any()
+                or (sel.size and not 0 <= sel.min() <= sel.max()
+                    < len(self._ids))
+                # a row of words holds a bit for every slot of the docs
+                or (width[kind == 1] * 32 < self._docs.size).any()):
+            raise ValueError("group fill jobs do not fit their operands")
+        at = self._spans.ctypes.data
+        self._lib.lsm_group_fill(
+            self._addrs.ctypes.data, at, at + 8 * len(self._ids),
+            self._docs.ctypes.data, self._docs.size, self._consecutive,
+            jobs.ctypes.data, len(jobs), sel.ctypes.data)
+
+
+def group_locate(ids: Sequence, docs: np.ndarray,
+                 consecutive: bool) -> GroupLists | str:
+    """`GroupLists` over the allowLists' ascending id arrays and a
+    snapshot's ascending int64 docs. A string => the caller's numpy, and
+    why: "no_library"; "foreign_list", an allowList whose ids are no
+    contiguous uint64 array."""
+    lib = _load()
+    if lib is None:
+        return "no_library"
+    if not all(isinstance(a, np.ndarray) and a.dtype == np.uint64
+               and a.flags.c_contiguous for a in ids):
+        return "foreign_list"
+    if docs.dtype != np.int64 or not docs.flags.c_contiguous:
+        raise ValueError("docs are a contiguous int64 array")
+    return GroupLists(lib, ids, docs, consecutive)
