@@ -24,6 +24,7 @@ signal/atexit device-trace teardown.
 
 import json
 import threading
+import time
 import urllib.request
 import uuid as uuidlib
 
@@ -652,6 +653,50 @@ def test_debug_perf_endpoint_and_metrics(tmp_path):
         app.shutdown()
 
 
+def test_debug_perf_serves_the_restart_timeline_beside_the_window(tmp_path):
+    """`startup` and `compiles` are on the page the traced run collects,
+    after the window's own keys, and the first readiness probe closes the
+    timeline."""
+    from weaviate_tpu.server import RestServer
+
+    perf.timeline_reset()
+    tl = perf.startup_begin()
+    try:
+        with tracing.stage("app"):
+            app, idx, vecs = _mk_app(tmp_path)
+        srv = RestServer(app, port=0)
+        with tracing.stage("listen"):
+            srv.start()
+        tl.ready(app.metrics)
+        try:
+            base = f"http://127.0.0.1:{srv.port}"
+            with urllib.request.urlopen(base + "/v1/.well-known/ready",
+                                        timeout=30) as r:
+                assert r.status == 200
+            for _ in range(50):   # the stage lands after the probe's reply
+                if tl.sealed and "first_ready" in tl.summary()["stages"]:
+                    break
+                time.sleep(0.05)
+            with urllib.request.urlopen(base + "/debug/perf",
+                                        timeout=30) as r:
+                body = json.loads(r.read())
+            assert body["enabled"] is True and "phases" in body
+            assert list(body)[-2:] == ["startup", "compiles"]
+            st = body["startup"]
+            assert {"app", "listen", "first_ready"} <= set(st["stages"])
+            assert st["seconds"]["ready"] is not None
+            assert st["stages"]["first_ready"]["seconds"] >= 0
+            assert body["compiles"]["count"] >= st["compiles"]["count"]
+            text = app.metrics.expose().decode()
+            assert ('weaviate_startup_durations_ms_count{operation='
+                    '"first_ready"} 1.0') in text
+        finally:
+            srv.stop()
+            app.shutdown()
+    finally:
+        perf.timeline_reset()
+
+
 def test_debug_perf_disabled_reports_disabled(tmp_path):
     from weaviate_tpu.server import App, RestServer
 
@@ -661,7 +706,14 @@ def test_debug_perf_disabled_reports_disabled(tmp_path):
     try:
         with urllib.request.urlopen(
                 f"http://127.0.0.1:{srv.port}/debug/perf", timeout=30) as r:
-            assert json.loads(r.read()) == {"enabled": False}
+            body = json.loads(r.read())
+        # the window is down; the restart's timeline and the compile tally
+        # are the process's own and are served all the same
+        assert body["enabled"] is False
+        assert set(body) == {"enabled", "startup", "compiles"}
+        assert body["startup"] is None      # nobody started this process
+        assert set(body["compiles"]) == {"count", "seconds", "cache_hits",
+                                         "cache_misses", "last"}
     finally:
         srv.stop()
         app.shutdown()

@@ -686,3 +686,50 @@ def test_traceparent_parsing_rejects_malformed():
                 "00-" + "0" * 32 + "-" + "cd" * 8 + "-01",   # zero trace id
                 "00-" + "ab" * 16 + "-" + "0" * 16 + "-01"):  # zero parent
         assert tracing.parse_traceparent(bad) is None
+
+
+def test_a_stage_is_a_phase_only_while_the_tracer_is_up(monkeypatch):
+    """`tracing.stage` and a restore's `StageSums` are `wv/startup.*`
+    annotations with the tracer up and bare stamps with it down; either
+    way they measure."""
+    from weaviate_tpu.monitoring import perf
+
+    made = []
+    real = tracing.Phase.__init__
+
+    def spy(self, name, **stats):
+        made.append((name, stats))
+        real(self, name, **stats)
+
+    monkeypatch.setattr(tracing.Phase, "__init__", spy)
+
+    def one_restart():
+        with tracing.stage("vector.restore", shard="s0") as st:
+            sums = tracing.StageSums()
+            sums.enter("stage")
+            for _ in sums.timed(iter(range(3)), "log.parse"):
+                with tracing.piece_of(sums, "land", 16384, rows=8):
+                    sums.enter("grow", capacity=32768)
+                    sums.leave(32768)
+            sums.leave()
+            sums.publish()
+        assert st.seconds > 0 and sums.seconds("land") > 0
+        return sums
+
+    assert tracing.get_tracer() is None
+    one_restart()
+    assert made == []
+    t = tracing.configure(tracing.Tracer())
+    try:
+        sums = one_restart()
+    finally:
+        tracing.unconfigure(t)
+    names = [n for n, _ in made]
+    assert names[0] == "startup.vector.restore"
+    assert names.count("startup.land") == 3 == names.count("startup.grow")
+    assert names.count("startup.stage") == 1
+    assert "startup.log.parse" not in names    # a pair of stamps a step
+    assert dict(made)["startup.grow"] == {"capacity": 32768}
+    assert sums._pieces == {"stage": 1, "log.parse": 4, "land": 3, "grow": 3}
+    assert perf.startup() is None              # nobody began a timeline
+

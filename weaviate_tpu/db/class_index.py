@@ -23,6 +23,7 @@ from weaviate_tpu.db.shard import SearchResult, Shard
 from weaviate_tpu.entities.filters import LocalFilter
 from weaviate_tpu.entities.schema import ClassDef
 from weaviate_tpu.entities.storobj import StorObj
+from weaviate_tpu.monitoring import tracing
 
 
 def _merge_shard_results(
@@ -78,15 +79,16 @@ class ClassIndex:
                 self._load_shard(name)
 
     def _load_shard(self, name: str) -> Shard:
-        s = Shard(
-            name,
-            os.path.join(self.path, name),
-            self.class_def,
-            self.vector_config,
-            metrics=self.metrics,
-            invert_cfg=self.invert_cfg,
-            store_opts=self.store_opts,
-        )
+        with tracing.stage("shard.open", cls=self.class_name, shard=name):
+            s = Shard(
+                name,
+                os.path.join(self.path, name),
+                self.class_def,
+                self.vector_config,
+                metrics=self.metrics,
+                invert_cfg=self.invert_cfg,
+                store_opts=self.store_opts,
+            )
         self.shards[name] = s
         return s
 

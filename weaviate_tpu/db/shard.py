@@ -164,25 +164,32 @@ class Shard:
         self.class_def = class_def
         self.metrics = metrics
         os.makedirs(path, exist_ok=True)
-        self.store = Store(os.path.join(path, "lsm"), **(store_opts or {}))
-        # objects bucket keyed by uuid bytes; docid bucket docID -> uuid bytes
-        # (reference: helpers.ObjectsBucketLSM + docid lookup)
-        self.objects = self.store.create_or_load_bucket("objects", STRATEGY_REPLACE)
-        self.docid_lookup = self.store.create_or_load_bucket("docid_lookup", STRATEGY_REPLACE)
-        self.counter = Counter(os.path.join(path, "indexcount"))
+        # the stages of a restart's timeline (monitoring/perf.py): the
+        # store and its two point-get buckets (WAL replay, segment maps),
+        # then the inverted index's buckets, and below them the vector
+        # index's own `vector.restore`
+        with tracing.stage("lsm.open", shard=name):
+            self.store = Store(os.path.join(path, "lsm"), **(store_opts or {}))
+            # objects bucket keyed by uuid bytes; docid bucket docID -> uuid
+            # bytes (reference: helpers.ObjectsBucketLSM + docid lookup)
+            self.objects = self.store.create_or_load_bucket("objects", STRATEGY_REPLACE)
+            self.docid_lookup = self.store.create_or_load_bucket("docid_lookup", STRATEGY_REPLACE)
+            self.counter = Counter(os.path.join(path, "indexcount"))
         self.invert_cfg = invert_cfg
-        self.inverted = InvertedIndex(self.store, class_def)
+        with tracing.stage("inverted.open", shard=name):
+            self.inverted = InvertedIndex(self.store, class_def)
         self.vector_index = new_vector_index(
             vector_config, path, name, metrics=metrics,
             class_name=self.class_def.name)
-        self._geo_indexes: dict[str, object] = {}
-        self._init_geo_indexes()
-        self.searcher = FilterSearcher(
-            self.inverted, class_def, geo_search=self._geo_search
-        )
-        self.bm25 = BM25Searcher(self.inverted, class_def, invert_cfg,
-                                 gen_fn=self._locked_gen)
-        self.bm25_device = self._maybe_device_bm25()
+        with tracing.stage("inverted.open", shard=name):
+            self._geo_indexes: dict[str, object] = {}
+            self._init_geo_indexes()
+            self.searcher = FilterSearcher(
+                self.inverted, class_def, geo_search=self._geo_search
+            )
+            self.bm25 = BM25Searcher(self.inverted, class_def, invert_cfg,
+                                     gen_fn=self._locked_gen)
+            self.bm25_device = self._maybe_device_bm25()
         # background per-bucket pair compaction (segment_group_compaction.go)
         self.store.start_compaction_cycle()
         self.status = STATUS_READY
@@ -1205,6 +1212,7 @@ class Shard:
         out["vector_index"] = vh() if vh is not None else {
             "type": type(self.vector_index).__name__,
             "live": len(self.vector_index),
+            "restore": getattr(self.vector_index, "last_restore", None),
         }
         return out
 
@@ -1492,10 +1500,18 @@ class Shard:
             g.flush()
 
     def shutdown(self) -> None:
-        self.store.shutdown()
-        self.vector_index.shutdown()
-        for g in self._geo_indexes.values():
-            g.shutdown()
+        # the way down's stages (printed by `python -m weaviate_tpu` before
+        # it exits): memtables to segments and the WALs closed, then the
+        # vector index's last flush and the close of its log. The store's
+        # compaction cycle is a daemon nobody joins: `sweep_in_flight` says
+        # whether a merge shared the interpreter with the flush
+        with tracing.stage("lsm.close", shard=self.name,
+                           sweep_in_flight=self.store.sweep_in_flight()):
+            self.store.shutdown()
+        with tracing.stage("vector.close", shard=self.name):
+            self.vector_index.shutdown()
+            for g in self._geo_indexes.values():
+                g.shutdown()
 
     def drop(self) -> None:
         self.vector_index.drop()

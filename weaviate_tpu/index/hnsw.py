@@ -25,7 +25,8 @@ import numpy as np
 from weaviate_tpu import _native
 from weaviate_tpu.entities import vectorindex as vi
 from weaviate_tpu.index.interface import AllowList, VectorIndex
-from weaviate_tpu.index.tpu import VectorLog
+from weaviate_tpu.index.tpu import VectorLog, restore_record
+from weaviate_tpu.monitoring import tracing
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -114,6 +115,8 @@ class HnswIndex(VectorIndex):
         self._cleanup_running = threading.Semaphore(1)  # one cycle at a time
         self._snapshot_path = os.path.join(shard_path, "hnsw.snapshot")
         self._log = VectorLog(os.path.join(shard_path, "hnsw.log")) if persist else None
+        # what the restore did (`/debug/index` `restore`)
+        self.last_restore: Optional[dict] = None
         if persist:
             self._restore()
 
@@ -142,26 +145,41 @@ class HnswIndex(VectorIndex):
         return v
 
     def _restore(self) -> None:
-        if os.path.exists(self._snapshot_path):
-            h = self._lib.hnsw_load(self._snapshot_path.encode())
-            if h:
-                self._h = h
-                # dim is embedded in the snapshot; probe via a search no-op is
-                # overkill — store alongside
-                dim_file = self._snapshot_path + ".dim"
-                if os.path.exists(dim_file):
-                    self.dim = int(open(dim_file).read().strip())
-        if self._log is not None:
-            replay_stats: dict = {}
-            for op, doc_id, vec in VectorLog.replay(self._log.path, stats=replay_stats):
+        """Load the snapshot, replay the delta log into the graph. The
+        stages carry the device indexes' names (index/tpu.py `_restore`):
+        the graph's load and inserts are `land`; nothing is staged, grown,
+        flushed or owed by a device here."""
+        replay_stats: dict = {}
+        rows = 0
+        with tracing.stage("vector.restore", shard=self.shard_name) as st:
+            sums = tracing.StageSums()
+            sums.enter("land")
+            if os.path.exists(self._snapshot_path):
+                h = self._lib.hnsw_load(self._snapshot_path.encode())
+                if h:
+                    self._h = h
+                    # dim is embedded in the snapshot; probe via a search no-op is
+                    # overkill — store alongside
+                    dim_file = self._snapshot_path + ".dim"
+                    if os.path.exists(dim_file):
+                        self.dim = int(open(dim_file).read().strip())
+            for op, doc_id, vec in sums.timed(VectorLog.replay(
+                    self._log.path, stats=replay_stats, sums=sums),
+                    "log.parse"):
+                rows += 1
                 if op == "add":
                     v = np.asarray(vec, dtype=np.float32)  # already normalized at log time
                     self._ensure_handle(v.shape[0])
                     self._lib.hnsw_add(self._h, doc_id, _f32p(np.ascontiguousarray(v)))
                 elif self._h is not None:
                     self._lib.hnsw_delete(self._h, doc_id)
+            sums.leave()
             VectorLog.report_replay_stats(self._log.path, replay_stats)
-            self.last_replay_stats = replay_stats
+            sums.publish()
+            st.note(rows=rows)
+        # `rows`: the delta's records (the snapshot's are the library's)
+        self.last_restore = restore_record("graph", rows, st, sums,
+                                           replay_stats)
 
     def _ef(self, k: int) -> int:
         ef = self.config.ef

@@ -70,6 +70,7 @@ from weaviate_tpu.entities import vectorindex as vi
 from weaviate_tpu.index.interface import AllowList, VectorIndex
 from weaviate_tpu.index.tpu import (
     VectorLog,
+    restore_record,
     _S2D_FILL,
     _bucket_b,
     _bucket_rows,
@@ -305,6 +306,10 @@ class MeshVectorIndex(VectorIndex):
         self._pq4_path = (os.path.join(shard_path, "pq4.npz")
                           if shard_path else "")
         self._restoring = False
+        # the running restore's stage sums and what the last one did: the
+        # single-chip index's two fields (tpu.py _restore)
+        self._restore_sums: Optional[tracing.StageSums] = None
+        self.last_restore: Optional[dict] = None
         self._gmin_broken = False  # fused mesh kernel failed: use the scan
         # identity token for the per-allowList packed-words cache
         self._allow_token = object()
@@ -332,27 +337,48 @@ class MeshVectorIndex(VectorIndex):
         """Replay the vector log (startup.go:56 analog). Placement is
         recomputed at replay time, so the same log restores onto any mesh."""
         self._restoring = True
-        try:
-            replay_stats: dict = {}
-            for op, ids, vecs in VectorLog.replay_batches(self._log.path, stats=replay_stats):
-                if op == "add":
-                    self._bulk_stage_add(ids, vecs)
-                else:
-                    self._stage_delete(int(ids), log=False)
-            VectorLog.report_replay_stats(self._log.path, replay_stats)
-            self.last_replay_stats = replay_stats
-            if self._pq_path and os.path.exists(self._pq_path):
-                from weaviate_tpu.compress.pq import ProductQuantizer
+        replay_stats: dict = {}
+        with tracing.stage("vector.restore", shard=self.shard_name) as st:
+            sums = self._restore_sums = tracing.StageSums()
+            try:
+                sums.enter("stage")
+                for op, ids, vecs in sums.timed(VectorLog.replay_batches(
+                        self._log.path, stats=replay_stats, sums=sums),
+                        "log.parse"):
+                    if op == "add":
+                        self._bulk_stage_add(ids, vecs)
+                    else:
+                        self._stage_delete(int(ids), log=False)
+                sums.leave(self._capacity())
+                VectorLog.report_replay_stats(self._log.path, replay_stats)
+                if self._pq_path and os.path.exists(self._pq_path):
+                    from weaviate_tpu.compress.pq import ProductQuantizer
 
-                self._flush_pending()
-                if self.live > 0:
-                    self._enable_pq(
-                        ProductQuantizer.load(self._pq_path),
-                        np.asarray(self._store, dtype=np.float32),
-                        save=False,
-                    )
-        finally:
-            self._restoring = False
+                    with tracing.piece_of(sums, "flush", self._capacity()):
+                        self._flush_pending()
+                        if self.live > 0:
+                            self._enable_pq(
+                                ProductQuantizer.load(self._pq_path),
+                                np.asarray(self._store, dtype=np.float32),
+                                save=False,
+                            )
+                with tracing.piece_of(sums, "drain", self._capacity()):
+                    jax.block_until_ready([a for a in (  # graftlint: disable=JGL001 a restore runs in the constructor, before the index serves: the wait is the `drain` stage (tpu.py _restore)
+                        self._store, self._sq_norms, self._tombs,
+                        self._s2d_dev, self._codes, self._recon_norms,
+                        self._codes4, self._recon_norms4) if a is not None])
+            finally:
+                self._restoring = False
+                self._restore_sums = None
+            sums.publish()
+            st.note(rows=self.live, capacity=self._capacity())
+        self.last_restore = restore_record(
+            "compressed" if self.compressed else "uncompressed",
+            int(self._counts.sum()), st, sums, replay_stats)
+
+    def _capacity(self) -> int:
+        """Slots over all chips (0 before the first row sizes the slabs)."""
+        return self.n_dev * self.n_loc if self.dim is not None else 0
 
     def post_startup(self) -> None:
         self.flush()
@@ -429,6 +455,9 @@ class MeshVectorIndex(VectorIndex):
             new_loc *= 2
         if new_loc == self.n_loc:
             return
+        sums = self._restore_sums
+        if sums is not None:
+            sums.enter("grow", capacity=self.n_dev * new_loc)
         old_loc = self.n_loc
         self._store = mesh_grow_2d(self._store, new_loc, self.mesh)
         self._sq_norms = mesh_grow_1d(self._sq_norms, new_loc, self.mesh)
@@ -489,6 +518,8 @@ class MeshVectorIndex(VectorIndex):
                 ("mesh_grow", self.n_dev, new_loc, self.dim or 0,
                  self.compressed))
         self._stamp_memory()
+        if sums is not None:
+            sums.leave(self.n_dev * new_loc)
 
     # -- staging -------------------------------------------------------------
 
@@ -525,7 +556,9 @@ class MeshVectorIndex(VectorIndex):
         if log and self._log is not None:
             self._log.append_add(doc_id, vector)
         if len(self._pending) >= _FLUSH_CHUNK:
-            self._flush_pending()
+            with tracing.piece_of(self._restore_sums, "flush",
+                                  self._capacity()):
+                self._flush_pending()
 
     def _bulk_stage_add(self, ids: np.ndarray, vecs: np.ndarray) -> None:
         """Restore-path bulk staging (single-chip twin in tpu.py): a run of
@@ -561,14 +594,19 @@ class MeshVectorIndex(VectorIndex):
         if len(ids64) < _FLUSH_CHUNK:
             self._pending.update(zip(ids64.tolist(), vecs))
             if len(self._pending) >= _FLUSH_CHUNK:
-                self._flush_pending()
+                with tracing.piece_of(self._restore_sums, "flush",
+                                      self._capacity()):
+                    self._flush_pending()
             return
         # a long run (a whole import's log is one) lands as it is: the
         # slabs are sized once from its row count and the rows go down in
         # insert steps, never through a dict entry a row and one stacked
         # copy of the log (single-chip twin: tpu.py _write_block)
-        self._flush_pending()  # earlier staged singles keep their slots
-        self._write_balanced(ids64, vecs)
+        with tracing.piece_of(self._restore_sums, "flush", self._capacity()):
+            self._flush_pending()  # earlier staged singles keep their slots
+        with tracing.piece_of(self._restore_sums, "land", self._capacity(),
+                              rows=len(ids64)):
+            self._write_balanced(ids64, vecs)
 
     def _stage_delete(self, doc_id: int, log: bool = True) -> None:
         row = self._doc_to_row.pop(doc_id, None)
@@ -624,7 +662,9 @@ class MeshVectorIndex(VectorIndex):
             t0 = time.perf_counter()
             rows = np.stack(list(self._pending.values()))
             docs = np.array(list(self._pending.keys()), dtype=np.int64)
-            self._write_balanced(docs, rows)
+            with tracing.piece_of(self._restore_sums, "land",
+                                  self._capacity(), rows=len(docs)):
+                self._write_balanced(docs, rows)
             self._pending.clear()
             if led is not None:
                 led.note_write(
@@ -683,7 +723,10 @@ class MeshVectorIndex(VectorIndex):
         self._grow(needed)
         first = [int(a[0]) if len(a) else 0 for a in assign]
         left = [len(a) for a in assign]
+        sums = self._restore_sums
         while any(left):
+            if sums is not None:
+                sums.tick(self.n_dev * self.n_loc)
             max_off = max(
                 int(self._counts[s]) for s in range(self.n_dev) if left[s]
             )
@@ -1896,6 +1939,9 @@ class MeshVectorIndex(VectorIndex):
                 "published_gen": self._published_gen,
                 "staged_lag": self._staged_gen - max(self._published_gen, 0),
                 "per_device": per_device,
+                # what the restore did (None for an index that began
+                # empty): the single-chip index's block
+                "restore": self.last_restore,
                 "compressed": self.compressed,
                 # rescore=false is a footgun: raw ADC
                 # distances at recall ~0.24 — surfaced, not just documented

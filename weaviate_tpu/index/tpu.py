@@ -902,6 +902,26 @@ def _prep_bulk_run(ids: np.ndarray, vecs: np.ndarray, metric: str, known_fn):
     return ids64, vecs, known
 
 
+def restore_record(mode: str, rows: int, st, sums, replay_stats: dict,
+                   **extra) -> dict:
+    """What one restore did, for `/debug/index` `restore`, the
+    `write_phase` incident of scope `restore` and nobody else: `seconds` is
+    the `vector.restore` stage `st` itself (`tracing.stage`), `stages` the
+    flat parts of the restart timeline that lie inside it (`sums`, the
+    restore's `tracing.StageSums`; monitoring/perf.py STARTUP_PARTS),
+    `replay` what the log's replay skipped. One shape for every index
+    type."""
+    return {
+        "mode": mode,
+        "rows": rows,
+        "seconds": round(st.seconds, 3),
+        "stages": {part: sums.seconds(*perf.STARTUP_PARTS[part])
+                   for part in ("log", "land", "drain")},
+        "replay": dict(replay_stats),
+        **extra,
+    }
+
+
 class VectorLog:
     """Append-only durability log for the device store (commit-log analog).
 
@@ -933,25 +953,27 @@ class VectorLog:
             # silent data loss on the next restart. For v2 logs the cut point
             # is the end of the LAST valid record (mid-file damage stays in
             # place for skip-ahead replay to route around); for v1 logs it is
-            # the first bad record, as before.
+            # the first bad record, as before. A stage of a restart's
+            # timeline: the walk is a seek and a read a record.
             size = os.path.getsize(path)
-            valid = self._valid_prefix_len(path)
-            if valid < size:
-                cut = valid
-                if self._version(path) >= 2:
-                    cut = max(valid, self._last_valid_end(path))
-                with open(path, "r+b") as f:
-                    f.truncate(cut)
-                fresh = cut == 0
-            else:
-                fresh = valid == 0
-            if not fresh and self._version(path) < 2:
-                # one-time in-place upgrade: appends always write v2
-                # checksummed records, and mixing formats within one file
-                # would make v1 replay mis-parse every appended vector
-                # (checksum bytes read as payload) — rewrite the whole log
-                # as v2 before reusing it.
-                self._upgrade_v1(path)
+            with tracing.stage("log.check", bytes=size):
+                valid = self._valid_prefix_len(path)
+                if valid < size:
+                    cut = valid
+                    if self._version(path) >= 2:
+                        cut = max(valid, self._last_valid_end(path))
+                    with open(path, "r+b") as f:
+                        f.truncate(cut)
+                    fresh = cut == 0
+                else:
+                    fresh = valid == 0
+                if not fresh and self._version(path) < 2:
+                    # one-time in-place upgrade: appends always write v2
+                    # checksummed records, and mixing formats within one
+                    # file would make v1 replay mis-parse every appended
+                    # vector (checksum bytes read as payload) — rewrite the
+                    # whole log as v2 before reusing it.
+                    self._upgrade_v1(path)
         self._f = open(path, "ab")
         if fresh:
             self._f.write(_LOG_MAGIC + struct.pack("<H", _LOG_VERSION))
@@ -1150,16 +1172,23 @@ class VectorLog:
             self._f.close()
 
     @staticmethod
-    def replay(path: str, stats: Optional[dict] = None):
+    def _read(path: str, sums=None) -> bytes:
+        """The whole log as one `bytes`: a restore's `log.read` stage."""
+        with tracing.piece_of(sums, "log.read"), open(path, "rb") as f:
+            return f.read()
+
+    @staticmethod
+    def replay(path: str, stats: Optional[dict] = None, sums=None):
         """Yield ('add', doc_id, vec) / ('delete', doc_id, None). v2 logs
         verify per-record checksums and SKIP corrupt regions (resuming at the
         next valid record, with the loss counted in `stats`); v1 logs keep
         the old stop-at-first-bad-record behavior. A torn tail is tolerated
-        either way (corrupt_commit_logs_fixer.go behavior)."""
+        either way (corrupt_commit_logs_fixer.go behavior). `sums`: the
+        restore's stage sums (`tracing.StageSums`), to which the file's
+        read is `log.read`."""
         if not os.path.exists(path):
             return
-        with open(path, "rb") as f:
-            data = f.read()
+        data = VectorLog._read(path, sums)
         if data[:4] != _LOG_MAGIC or len(data) < 6:
             return
         if struct.unpack_from("<H", data, 4)[0] >= 2:
@@ -1190,16 +1219,15 @@ class VectorLog:
 
     @staticmethod
     def replay_batches(path: str, stats: Optional[dict] = None,
-                       run_max: Optional[int] = None):
+                       run_max: Optional[int] = None, sums=None):
         """Vectorized replay: maximal runs of same-dim add records parse as
         ONE numpy view — ('add', ids [n] u64, vecs [n, dim] f32) — with
         ('delete', doc_id, None) singles in order. Same corruption tolerance
         as replay(); restores parse the log ~10x faster this way. `run_max`
-        cuts a v2 log's runs to that many records."""
+        cuts a v2 log's runs to that many records; `sums` as in replay()."""
         if not os.path.exists(path):
             return
-        with open(path, "rb") as f:
-            data = f.read()
+        data = VectorLog._read(path, sums)
         if data[:4] != _LOG_MAGIC or len(data) < 6:
             return
         if struct.unpack_from("<H", data, 4)[0] >= 2:
@@ -1524,6 +1552,9 @@ class TpuVectorIndex(VectorIndex):
         self._opq_rot_dev = None            # device f32 [D, D] (or None)
         self._pq4_path = os.path.join(shard_path, "pq4.npz")
         self._restoring = False
+        # the stage sums of the restore that is running (tracing.StageSums),
+        # None at every other time: the write path's pieces gate on it
+        self._restore_sums: Optional[tracing.StageSums] = None
         # (pq, pq4) the next `_init_device` enters the compressed form
         # with: set by a restore that found a codebook and by the
         # compaction of a compressed index, consumed by the first row
@@ -1633,43 +1664,70 @@ class TpuVectorIndex(VectorIndex):
         nothing is fetched back, and the codes are re-derived on the
         device, which beats persisting them."""
         self._restoring = True
-        t0 = time.perf_counter()
+        replay_stats: dict = {}
+        with tracing.stage("vector.restore", shard=self.shard_name) as st:
+            sums = self._restore_sums = tracing.StageSums()
+            try:
+                self._replay_log(sums, replay_stats)
+                if self.compressed:
+                    with tracing.piece_of(sums, "flush", self.capacity):
+                        self._publish_snapshot()
+                # what the device still owed when the host was done: the
+                # write programs only queue, and a server that says ready
+                # before they ran answers its first request after them
+                with tracing.piece_of(sums, "drain", self.capacity):
+                    jax.block_until_ready(self._restored_arrays())  # graftlint: disable=JGL001 a restore runs in the constructor, before the index serves: the wait is the `drain` stage, what the device still owed when the host was done
+            finally:
+                self._restore_sums = None
+            sums.publish()
+            st.note(rows=self.n, capacity=self.capacity)
+        self.last_restore = restore_record(
+            "compressed" if self.compressed else "uncompressed", self.n, st,
+            sums, replay_stats,
+            # restore runs in the constructor: the lifetime count is its own
+            chunks_encoded=self._chunks_encoded)
+        if self.n:
+            incidents.emit("write_phase", scope="restore",
+                           **self.last_restore)
+
+    def _replay_log(self, sums, replay_stats: dict) -> None:
+        """The replay half of `_restore`: the codebook, the log's runs, the
+        last flush. `_restoring` holds from the caller until this
+        returns."""
         try:
             self._pending_pq = self._load_persisted_pq()
-            replay_stats: dict = {}
-            for op, ids, vecs in VectorLog.replay_batches(
+            sums.enter("stage")
+            for op, ids, vecs in sums.timed(VectorLog.replay_batches(
                     self._log.path, stats=replay_stats,
-                    run_max=_REPLAY_RUN_MAX if self._pending_pq else None):
+                    run_max=_REPLAY_RUN_MAX if self._pending_pq else None,
+                    sums=sums), "log.parse"):
                 if op == "add":
                     self._bulk_stage_add(ids, vecs)
                 else:
                     self._stage_delete(int(ids), log=False)
+            sums.leave(self.capacity)
             VectorLog.report_replay_stats(self._log.path, replay_stats)
-            self.last_replay_stats = replay_stats
             if os.path.exists(self._pq_path):
-                self._flush_pending()
-                if self.compressed and self.config.pq.bits == 4 \
-                        and self._pq4 is None and self.n:
-                    # no usable pq4.npz: refit the funnel's ladder from
-                    # the rows just replayed (never costs the shard)
-                    vecs_n = self._host_vecs[: self.n]
-                    self._set_pq4(self._fit_pq4(self._pq, vecs_n))
-                    self._encode_pq4(vecs_n)
+                with tracing.piece_of(sums, "flush", self.capacity):
+                    self._flush_pending()
+                    if self.compressed and self.config.pq.bits == 4 \
+                            and self._pq4 is None and self.n:
+                        # no usable pq4.npz: refit the funnel's ladder from
+                        # the rows just replayed (never costs the shard)
+                        vecs_n = self._host_vecs[: self.n]
+                        self._set_pq4(self._fit_pq4(self._pq, vecs_n))
+                        self._encode_pq4(vecs_n)
         finally:
             self._restoring = False
             self._pending_pq = None
-        if self.compressed:
-            self._publish_snapshot()
-        self.last_restore = {
-            "mode": "compressed" if self.compressed else "uncompressed",
-            "rows": self.n,
-            "seconds": round(time.perf_counter() - t0, 3),
-            # restore runs in the constructor: the lifetime count is its own
-            "chunks_encoded": self._chunks_encoded,
-        }
-        if self.n:
-            incidents.emit("write_phase", scope="restore",
-                           **self.last_restore)
+
+    def _restored_arrays(self) -> list:
+        """The device arrays a restore's write programs produce."""
+        return [a for a in (
+            self._store, self._sq_norms, self._tombs, self._s2d_dev,
+            self._codes, self._recon_norms, self._rescore_dev,
+            self._rescore_sq_norms, self._codes4, self._recon_norms4)
+            if a is not None]
 
     def _load_persisted_pq(self):
         """(pq, pq4 or None) of a shard that was compressed when it shut
@@ -1756,6 +1814,9 @@ class TpuVectorIndex(VectorIndex):
             cap *= 2  # geometric growth (maintainance.go:31)
         if cap != self.capacity:
             faults.fire("index.tpu.alloc")
+            sums = self._restore_sums
+            if sums is not None:
+                sums.enter("grow", capacity=cap)
             if self.compressed:
                 self._codes = _grow_store(self._codes, cap)
                 hv = np.zeros((cap, self.dim), np.float32)
@@ -1795,12 +1856,16 @@ class TpuVectorIndex(VectorIndex):
                 led.note_write_shape(
                     ("grow", cap, self.dim or 0, self.compressed))
             self._stamp_memory()
+            if sums is not None:
+                sums.leave(cap)
 
     def _write_block(self, rows: np.ndarray, start: int) -> None:
         """Land [count, D] float32 rows at slots [start, start+count): every
         write path's one way in (flush, bulk import, restore, compact)."""
-        self._land_rows(rows, start)
-        self._ivf_on_rows_written(rows, start)
+        with tracing.piece_of(self._restore_sums, "land", self.capacity,
+                              rows=rows.shape[0]):
+            self._land_rows(rows, start)
+            self._ivf_on_rows_written(rows, start)
         led = memory.get_ledger()
         if led is not None:
             led.note_write_shape(
@@ -1812,12 +1877,15 @@ class TpuVectorIndex(VectorIndex):
         device and the codes, its bf16 copy and the norms hit HBM; the
         float rows go to the host-side rescoring store."""
         count = rows.shape[0]
+        sums = self._restore_sums
         off = 0
         while off < count:
             take = min(_CHUNK, count - off)
             chunk = np.zeros((_CHUNK, self.dim), dtype=np.float32)
             chunk[:take] = rows[off : off + take]
             self._ensure_capacity(start + off + _CHUNK)
+            if sums is not None:
+                sums.tick(self.capacity)
             if self.compressed:
                 codes = self._pq.encode(chunk)  # [_CHUNK, M]
                 self._codes = _write_rows(self._codes, jnp.asarray(codes), start + off)
@@ -1876,7 +1944,8 @@ class TpuVectorIndex(VectorIndex):
         if log and self._log is not None:
             self._log.append_add(doc_id, vector)
         if len(self._pending) >= _CHUNK:
-            self._flush_pending()
+            with tracing.piece_of(self._restore_sums, "flush", self.capacity):
+                self._flush_pending()
 
     def _bulk_stage_add(self, ids: np.ndarray, vecs: np.ndarray) -> None:
         """Restore-path bulk staging with _stage_add's exact semantics
@@ -1911,9 +1980,12 @@ class TpuVectorIndex(VectorIndex):
             self._pending.update(zip(ids64.tolist(), vecs))
             self.live += len(ids64)
             if len(self._pending) >= _CHUNK:
-                self._flush_pending()
+                with tracing.piece_of(self._restore_sums, "flush",
+                                      self.capacity):
+                    self._flush_pending()
             return
-        self._flush_pending()  # earlier staged singles keep their slots
+        with tracing.piece_of(self._restore_sums, "flush", self.capacity):
+            self._flush_pending()  # earlier staged singles keep their slots
         count = len(ids64)
         self._staged_gen += 1
         self._mark_staged()

@@ -114,6 +114,32 @@ _WRITE_SAMPLES_MAX = 8192
 _SHAPES_MAX = 128
 
 
+def allocator_stats() -> Optional[list]:
+    """``memory_stats()`` of every local device (``{}`` for one that keeps
+    none), None where jax is not imported yet: a reading must never be
+    what brings the backend up."""
+    import sys
+
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        return [d.memory_stats() or {} for d in jax.local_devices()]
+    except Exception:  # noqa: BLE001 — absent backend support
+        return None
+
+
+def fullest_allocator() -> Optional[tuple[int, int]]:
+    """(bytes_in_use, peak_bytes_in_use) of the fullest local device, None
+    where the backend keeps no allocator statistics (cpu). What the
+    restart timeline samples (monitoring/perf.py ``Timeline.memory``)."""
+    every = [s for s in allocator_stats() or () if "bytes_in_use" in s]
+    if not every:
+        return None
+    return (max(int(s["bytes_in_use"]) for s in every),
+            max(int(s.get("peak_bytes_in_use", 0)) for s in every))
+
+
 def array_bytes(arr) -> int:
     """Analytic byte size of a (device or host) array: shape x itemsize.
     Never touches device data — the zero-sync contract — and equals the
@@ -802,15 +828,10 @@ class MemoryLedger:
         reports in use (includes XLA workspace/executable overhead the
         analytic ledger deliberately does not model — a gauge to watch,
         never the primary). Summary-time only."""
-        try:
-            import jax
-
-            every = [d.memory_stats() or {} for d in jax.local_devices()]
-        except Exception:  # noqa: BLE001 — absent backend support
+        every = allocator_stats()
+        if not every or "bytes_in_use" not in every[0]:
             return None
         stats = every[0]
-        if "bytes_in_use" not in stats:
-            return None
         in_use = int(stats["bytes_in_use"])
         pulled = device_provider_components()
         with self._lock:

@@ -30,6 +30,10 @@ from prometheus_client import (
 
 _MS_BUCKETS = (0.1, 0.5, 1, 2, 5, 10, 25, 50, 100, 250, 500, 1000, 2500, 10000)
 
+# a restart's stages run from milliseconds to minutes
+_STARTUP_MS_BUCKETS = (10, 100, 500, 1000, 2500, 5000, 10000, 25000, 50000,
+                       100000, 250000, 600000)
+
 # occupancy buckets (requests/rows per coalesced dispatch): powers of two to
 # mirror the index's query-padding buckets
 _COUNT_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024)
@@ -125,26 +129,14 @@ class Metrics:
         def c(name, doc, labels=()):
             return Counter(name, doc, labels, registry=r)
 
-        # batch / object write path (prometheus.go batch metrics)
-        self.batch_durations = h(
-            "weaviate_batch_durations_ms", "Batch import phase durations",
-            ("operation", "class_name", "shard_name"))
-        self.batch_delete_durations = h(
-            "weaviate_batch_delete_durations_ms", "Batch delete durations",
-            ("class_name", "shard_name"))
-        self.objects_durations = h(
-            "weaviate_objects_durations_ms", "Single-object op durations",
-            ("operation", "step", "class_name", "shard_name"))
+        # Every series here is observed by some line of the program: one
+        # that nothing sets reads as a healthy zero on a dashboard (PR 35
+        # took fifteen such out; the reference's names for them are in
+        # CHANGES.md).
         self.object_count = g(
             "weaviate_object_count", "Objects per shard", ("class_name", "shard_name"))
 
         # queries
-        self.queries_count = g(
-            "weaviate_concurrent_queries_count", "In-flight queries",
-            ("class_name", "query_type"))
-        self.query_durations = h(
-            "weaviate_queries_durations_ms", "Query durations",
-            ("class_name", "query_type"))
         self.query_dimensions = c(
             "weaviate_query_dimensions_total", "Vector dimensions searched",
             ("query_type", "operation", "class_name"))
@@ -155,9 +147,6 @@ class Metrics:
         self.filtered_vector_search = h(
             "weaviate_filtered_vector_search_durations_ms",
             "device search dispatch (upload+scan+topk)", ("class_name", "shard_name"))
-        self.filtered_vector_rescore = h(
-            "weaviate_filtered_vector_rescore_durations_ms", "PQ rescoring pass",
-            ("class_name", "shard_name"))
         self.filtered_vector_objects = h(
             "weaviate_filtered_vector_objects_durations_ms", "result hydration",
             ("class_name", "shard_name"))
@@ -188,40 +177,14 @@ class Metrics:
             "weaviate_vector_segments_sum", "tracked PQ segments",
             ("class_name", "shard_name"))
 
-        # LSM (prometheus.go lsm metrics)
-        self.lsm_active_segments = g(
-            "weaviate_lsm_active_segments", "segments per bucket",
-            ("strategy", "class_name", "shard_name", "path"))
-        self.lsm_segment_objects = g(
-            "weaviate_lsm_segment_objects", "entries per segment level",
-            ("strategy", "class_name", "shard_name", "path", "level"))
-        self.lsm_compactions = c(
-            "weaviate_lsm_compactions_total", "compactions run",
-            ("strategy", "path"))
-        self.lsm_memtable_durations = h(
-            "weaviate_lsm_memtable_durations_ms", "memtable op durations",
-            ("strategy", "operation"))
-
-        # startup (prometheus.go startup metrics)
-        self.startup_durations = h(
-            "weaviate_startup_durations_ms", "startup phase durations", ("operation",))
-        self.startup_progress = g(
-            "weaviate_startup_progress", "0..1 progress", ("operation",))
-
-        # backup
-        self.backup_store_durations = h(
-            "weaviate_backup_store_ms", "backup store durations",
-            ("backend", "class_name"))
-        self.backup_restore_durations = h(
-            "weaviate_backup_restore_ms", "restore durations",
-            ("backend", "class_name"))
-
-        # schema / cluster
-        self.schema_tx = c(
-            "weaviate_schema_tx_total", "schema transactions", ("type", "status"))
-        self.replication_ops = c(
-            "weaviate_replication_operations_total", "replication coordinator ops",
-            ("operation", "status"))
+        # startup (prometheus.go startup metrics): one sample a stage of
+        # the restart's timeline (monitoring/perf.py Timeline.ready), shards
+        # summed; `operation` is the stage's name, a fixed set
+        self.startup_durations = Histogram(
+            "weaviate_startup_durations_ms",
+            "seconds of each stage from process start to the first "
+            "answered readiness probe, in ms", ("operation",), registry=r,
+            buckets=_STARTUP_MS_BUCKETS)
 
         # cross-request query coalescer (serving/coalescer.py). Registered
         # here, once, at Metrics construction — the same pattern as

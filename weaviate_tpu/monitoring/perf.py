@@ -31,9 +31,25 @@ What the device does is read from a profiler capture and nowhere else
   (benchmarks/readers/host_gaps.py gives each device gap to the phase
   that was open in it).
 
+Beside the window, and whether or not the tracer is up, the process keeps
+two small records of its own (no request's path touches either):
+
+- the **timeline** of a restart (``Timeline``): every stage from process
+  start to the first answered readiness probe as ``(name, thread, start_ns,
+  seconds)`` on ``time.perf_counter_ns`` (stamped by ``tracing.stage`` and
+  a restore's ``tracing.StageSums``), the fullest device's allocator
+  reading at every stage end, every ``grow`` and at most once a second
+  inside a replay, and the compiles that fell inside it. ``/debug/perf``
+  serves it as ``startup``; the way down is a second timeline that
+  ``python -m weaviate_tpu`` prints before it exits;
+- the **compile tally** (``CompileTally``): listeners on ``jax.monitoring``
+  count every backend compile (or load from the persistent cache) where it
+  happens: ``/debug/perf`` ``compiles``.
+
 Exposure: ``GET /debug/perf`` (server/rest.py, same authorizer as pprof),
-the gauge ``weaviate_device_duty_cycle`` and the per-dispatch phase-share
-histogram ``weaviate_perf_phase_share``.
+the gauge ``weaviate_device_duty_cycle``, the per-dispatch phase-share
+histogram ``weaviate_perf_phase_share`` and, from the timeline,
+``weaviate_startup_durations_ms{operation=<stage>}``.
 
 Lifecycle mirrors the tracer (monitoring/tracing.py): a process-wide
 module global installed by App when TRACING_ENABLED is set, None
@@ -48,6 +64,8 @@ capture log.
 
 from __future__ import annotations
 
+import json
+import os
 import threading
 import time
 from collections import deque
@@ -577,9 +595,360 @@ def _pct(sorted_vals: list, q: float) -> float:
     return float(sorted_vals[i])
 
 
+# -- the restart timeline and the compile tally -------------------------------
+
+# `startup.seconds`: the flat partition of process start -> listeners up that
+# the benchmark's metric files read, and the stages each part sums. `other`
+# is what is left of `app`, `post_startup` and `listen`; `unaccounted` what
+# is left of `ready` (the gaps between the stages `python -m weaviate_tpu`
+# opens one after another)
+STARTUP_PARTS = {
+    "boot": ("process", "backend"),
+    "lsm": ("lsm.open", "inverted.open"),
+    "log": ("log.check", "log.read", "log.parse"),
+    "land": ("stage", "grow", "land", "flush"),
+    "drain": ("drain",),
+}
+
+# the jax.monitoring keys the tally listens to (jax 0.9: _src/dispatch.py
+# BACKEND_COMPILE_EVENT wraps compiler.compile_or_get_cached, so a load from
+# the persistent cache is an event too, with the load's seconds; the two
+# cache events fire inside it, on the compiling thread, before it ends)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+
+def process_start_ns() -> Optional[int]:
+    """When the OS started this process, on the ``perf_counter_ns`` clock:
+    field 22 of ``/proc/self/stat`` (clock ticks after boot) against
+    ``CLOCK_BOOTTIME``. None where the OS gives none."""
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            stat = f.read()
+        # the command (field 2) may hold blanks: count from its last ')'
+        ticks = int(stat[stat.rindex(b")") + 2:].split()[19])
+        age_ns = (time.clock_gettime_ns(time.CLOCK_BOOTTIME)
+                  - ticks * 1_000_000_000 // os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return time.perf_counter_ns() - age_ns if age_ns >= 0 else None
+
+
+class Timeline:
+    """Stages on ``time.perf_counter_ns`` from an anchor on: the restart
+    (process start -> listeners up -> first readiness probe) and, as a
+    second instance, the way down. A stage is ``(name, thread, start_ns,
+    length_ns, stats)``; one whose stats carry ``pieces`` is a SUM of that
+    many pieces from `start_ns` on (a restore's inner stages: exclusive
+    seconds, so siblings add up to at most their parent). Bounded, and off
+    every request's path."""
+
+    MEMORY_ROWS_MAX = 512
+    INTERVALS_MAX = 1024
+    MEMORY_EVERY_NS = 1_000_000_000
+
+    def __init__(self, anchor_ns: int, anchor: str, memory: bool = True,
+                 prefix: str = "startup."):
+        self.anchor_ns = int(anchor_ns)
+        self.anchor = anchor
+        # of the stages' `wv/<prefix><name>` annotations
+        self.prefix = prefix
+        self._lock = threading.Lock()
+        self._intervals: list[tuple] = []
+        self._dropped = 0
+        self._sample_memory = memory
+        self._memory: list[list] = []
+        self._memory_dropped = 0
+        self._memory_last_ns = 0
+        # names of the stages open on the calling thread, innermost last:
+        # the stage a compile is charged to
+        self._open = threading.local()
+        # the process's compile tally when this timeline began and when it
+        # was sealed: the compiles inside it are the difference
+        self._compiles_from = compiles.counts()
+        self._compiles_to: Optional[tuple] = None
+        self.peak_at_restore_end: Optional[int] = None
+        # stamped when the listeners are up: from then on the timeline
+        # takes `first_ready` and nothing else (a class made at run time
+        # opens its shard outside any restart)
+        self.ready_ns: Optional[int] = None
+        self.sealed = False
+
+    # -- recording -----------------------------------------------------------
+
+    def recording(self) -> bool:
+        return self.ready_ns is None
+
+    def push(self, name: str) -> None:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        stack.append(name)
+
+    def pop(self) -> None:
+        stack = getattr(self._open, "stack", None)
+        if stack:
+            stack.pop()
+
+    def current(self) -> Optional[str]:
+        stack = getattr(self._open, "stack", None)
+        return stack[-1] if stack else None
+
+    def note(self, name: str, start_ns: int, length_ns: int,
+             stats: Optional[dict] = None, capacity: Optional[int] = None,
+             sample: bool = True) -> None:
+        """One closed stage, and the device's memory at its end."""
+        tid = threading.get_native_id()
+        with self._lock:
+            if len(self._intervals) < self.INTERVALS_MAX:
+                self._intervals.append((name, tid, int(start_ns),
+                                        int(length_ns), dict(stats or {})))
+            else:
+                self._dropped += 1
+        if sample:
+            row = self.memory(name, capacity, force=True)
+            if name == "vector.restore" and row is not None:
+                self.peak_at_restore_end = row[4]
+
+    def memory(self, event: str, capacity: Optional[int] = None,
+               force: bool = False) -> Optional[list]:
+        """``[t_ms, event, capacity, bytes_in_use, peak_bytes_in_use]`` of
+        the fullest local device; unforced at most once a second. The two
+        readings are None where the backend keeps none (cpu) or is not up
+        yet."""
+        if not self._sample_memory:
+            return None
+        now = time.perf_counter_ns()
+        if not force and now - self._memory_last_ns < self.MEMORY_EVERY_NS:
+            return None
+        from weaviate_tpu.monitoring import memory as memledger
+
+        in_use, peak = memledger.fullest_allocator() or (None, None)
+        row = [round((now - self.anchor_ns) / 1e6, 3), event, capacity,
+               in_use, peak]
+        with self._lock:
+            self._memory_last_ns = now
+            if len(self._memory) < self.MEMORY_ROWS_MAX:
+                self._memory.append(row)
+            else:
+                self._memory_dropped += 1
+        return row
+
+    def ready(self, metrics=None) -> dict:
+        """The listeners are up: close the timeline to everything but
+        `first_ready`, feed ``weaviate_startup_durations_ms`` one sample a
+        stage and -> `seconds`, the `startup` log line's body."""
+        self.ready_ns = time.perf_counter_ns()
+        doc = self.summary()
+        for name, st in doc["stages"].items():
+            _observe_stage(metrics, name, st["seconds"])
+        return doc["seconds"]
+
+    def first_ready(self, metrics=None) -> None:
+        """The first readiness probe was answered: the last stage."""
+        with self._lock:
+            if self.sealed:
+                return
+            self.sealed = True
+            self._compiles_to = compiles.counts()
+        now = time.perf_counter_ns()
+        start = self.ready_ns if self.ready_ns is not None else now
+        self.ready_ns = start
+        self.note("first_ready", start, now - start)
+        _observe_stage(metrics, "first_ready", (now - start) / 1e9)
+
+    # -- the page ------------------------------------------------------------
+
+    def summary(self) -> dict:
+        with self._lock:
+            intervals = list(self._intervals)
+            memory = [list(r) for r in self._memory]
+            dropped, memory_dropped = self._dropped, self._memory_dropped
+            inside = [b - a for a, b in zip(
+                self._compiles_from, self._compiles_to or compiles.counts())]
+        a = self.anchor_ns
+        stages: dict = {}
+        tot: dict[str, int] = {}
+        for name, _, start, length, stats in intervals:
+            tot[name] = tot.get(name, 0) + length
+            st = stages.get(name)
+            if st is None:
+                stages[name] = {"start_ms": round((start - a) / 1e6, 3),
+                                "seconds": 0.0, "stats": dict(stats)}
+                continue
+            # a stage several shards opened: the first start, the summed
+            # seconds, numbers added up, `count` of them
+            merged = st["stats"]
+            merged["count"] = merged.get("count", 1) + 1
+            for k, v in stats.items():
+                if isinstance(v, (int, float)) and not isinstance(v, bool) \
+                        and isinstance(merged.get(k), (int, float)):
+                    merged[k] += v
+                else:
+                    merged[k] = v
+        for name, st in stages.items():
+            st["seconds"] = round(tot[name] / 1e9, 6)
+        # shards that opened on two threads at once: the page says so, and
+        # the parts inside them are cut to the wall clock the opens took
+        # together, in proportion
+        opens = [(s, s + n) for name, _, s, n, _ in intervals
+                 if name == "shard.open"]
+        tids = {t for name, t, _, _, _ in intervals if name == "shard.open"}
+        union = _union_ns(opens)
+        summed = sum(e - s for s, e in opens)
+        parallel = len(tids) > 1 and union < summed
+        cut = union / summed if parallel else 1.0
+        parts = {k: int(sum(tot.get(n, 0) for n in names)
+                        * (1.0 if k == "boot" else cut))
+                 for k, names in STARTUP_PARTS.items()}
+        in_app = sum(v for k, v in parts.items() if k != "boot")
+        parts["other"] = sum(tot.get(n, 0) for n in
+                             ("app", "post_startup", "listen")) - in_app
+        listen = [s + n for name, _, s, n, _ in intervals if name == "listen"]
+        if listen:
+            parts["ready"] = max(listen) - a
+            parts["unaccounted"] = parts["ready"] - (
+                parts["boot"] + in_app + parts["other"])
+        seconds = {k: round(v / 1e9, 6) for k, v in parts.items()}
+        seconds.setdefault("ready", None)
+        seconds.setdefault("unaccounted", None)
+        return {
+            "anchor": self.anchor,
+            "stages": stages,
+            "seconds": seconds,
+            "parallel": parallel,
+            "intervals": [[name, tid, round((s - a) / 1e6, 3),
+                           round(n / 1e9, 6), stats]
+                          for name, tid, s, n, stats in intervals],
+            "dropped": dropped,
+            "memory": memory,
+            "memory_dropped": memory_dropped,
+            "compiles": dict(zip(
+                ("count", "seconds", "cache_hits", "cache_misses"),
+                (inside[0], round(inside[1], 6), *inside[2:]))),
+            "peak_at_restore_end_bytes": self.peak_at_restore_end,
+        }
+
+    def line(self) -> str:
+        """The stages as one JSON line (the way down: no page outlives the
+        process, the server's log does)."""
+        doc = self.summary()
+        return json.dumps({
+            "anchor": doc["anchor"],
+            "seconds": round((time.perf_counter_ns() - self.anchor_ns) / 1e9,
+                             6),
+            "stages": doc["stages"]}, default=str)
+
+
+def _observe_stage(metrics, name: str, seconds: float) -> None:
+    """One sample of ``weaviate_startup_durations_ms{operation=<name>}``."""
+    if metrics is None:
+        return
+    try:
+        metrics.startup_durations.labels(name).observe(seconds * 1e3)
+    except Exception:  # noqa: BLE001 -- metrics must not break start-up
+        pass
+
+
+def _union_ns(spans: list) -> int:
+    """Length of the union of `(start, end)` spans."""
+    total, reach = 0, None
+    for s, e in sorted(spans):
+        if reach is None or s > reach:
+            total += e - s
+            reach = e
+        elif e > reach:
+            total += e - reach
+            reach = e
+    return total
+
+
+class CompileTally:
+    """Every backend compile of the process, counted where it happens: the
+    listeners `install()` hangs on ``jax.monitoring``. `count` and
+    `seconds` are the ``backend_compile_duration`` events (a program
+    compiled, or loaded from the persistent cache: the seconds are what the
+    caller waited either way); `cache_hits` / `cache_misses` the
+    compilation cache's own events (a miss is only an event where the
+    cache would keep the program: with ``JAX_COMPILATION_CACHE_DIR`` set
+    and jax's one-second floor, a short compile is neither)."""
+
+    KEPT = 32
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._t0_ns = time.perf_counter_ns()
+        self.count = 0
+        self.seconds = 0.0
+        self.cache_hits = 0
+        self.cache_misses = 0
+        self._last: deque = deque(maxlen=self.KEPT)
+        # what the cache said about the compile this thread is inside
+        self._outcome = threading.local()
+        self._installed = False
+
+    def install(self) -> None:
+        """Hang the listeners on ``jax.monitoring`` (imports jax; once a
+        process: jax has no public way to take one listener off again)."""
+        with self._lock:
+            if self._installed:
+                return
+            self._installed = True
+        from jax import monitoring
+
+        monitoring.register_event_listener(self._on_event)
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+
+    def _on_event(self, event: str, **kw) -> None:
+        if event == CACHE_HIT_EVENT:
+            self._outcome.hit = True
+        elif event == CACHE_MISS_EVENT:
+            self._outcome.hit = False
+
+    def _on_duration(self, event: str, duration: float, **kw) -> None:
+        if event != COMPILE_EVENT:
+            return
+        hit = getattr(self._outcome, "hit", None)
+        self._outcome.hit = None
+        now = time.perf_counter_ns()
+        tl = _startup
+        stage = tl.current() if tl is not None and not tl.sealed else None
+        t0 = tl.anchor_ns if tl is not None else self._t0_ns
+        with self._lock:
+            self.count += 1
+            self.seconds += float(duration)
+            self.cache_hits += hit is True
+            self.cache_misses += hit is False
+            self._last.append([round((now - t0) / 1e6, 3),
+                               round(float(duration), 6), hit, stage,
+                               kw.get("fun_name")])
+
+    def counts(self) -> tuple:
+        """(count, seconds, cache_hits, cache_misses) so far."""
+        with self._lock:
+            return (self.count, self.seconds, self.cache_hits,
+                    self.cache_misses)
+
+    def summary(self) -> dict:
+        with self._lock:
+            return {"count": self.count,
+                    "seconds": round(self.seconds, 6),
+                    "cache_hits": self.cache_hits,
+                    "cache_misses": self.cache_misses,
+                    "last": [list(r) for r in self._last]}
+
+
 # -- module state + zero-hop accessors ----------------------------------------
 
 _window: Optional[PerfWindow] = None
+
+# the restart's timeline (kept for the page after it closed), the one that
+# is recording now (the restart's until the listeners are up, the way
+# down's from the signal on; None between the two), and the compile tally
+_startup: Optional[Timeline] = None
+_recording: Optional[Timeline] = None
+compiles = CompileTally()
 
 # final summaries of recently-unconfigured windows (CI failure artifact:
 # tests/conftest.py dumps these so a red run's bundle carries the perf
@@ -614,6 +983,51 @@ def unconfigure(window: PerfWindow) -> None:
 
 def get_window() -> Optional[PerfWindow]:
     return _window
+
+
+def startup_begin(main_ns: Optional[int] = None) -> Timeline:
+    """Open the restart's timeline: anchored at the OS's start time of the
+    process where it gives one (then `process` is the stage from there to
+    `main_ns`, the first line of ``main()``), else at `main_ns`."""
+    global _startup, _recording
+    main_ns = time.perf_counter_ns() if main_ns is None else main_ns
+    os_ns = process_start_ns()
+    if os_ns is not None and os_ns <= main_ns:
+        tl = Timeline(os_ns, "os")
+        tl.note("process", os_ns, main_ns - os_ns)
+    else:
+        tl = Timeline(main_ns, "main")
+    _startup = _recording = tl
+    return tl
+
+
+def shutdown_begin() -> Timeline:
+    """Open the way down's timeline, anchored now (the signal)."""
+    global _recording
+    _recording = Timeline(time.perf_counter_ns(), "signal", memory=False,
+                          prefix="shutdown.")
+    return _recording
+
+
+def timeline() -> Optional[Timeline]:
+    """The timeline that takes stages now, None outside a restart and a
+    shutdown: `tracing.stage` and a restore's sums are stamps alone then."""
+    tl = _recording
+    if tl is not None and tl.recording():
+        return tl
+    return None
+
+
+def startup() -> Optional[Timeline]:
+    """The restart's timeline (``/debug/perf`` `startup`), recording or
+    closed; None in a process nobody started as a server."""
+    return _startup
+
+
+def timeline_reset() -> None:
+    """Forget both timelines (tests)."""
+    global _startup, _recording
+    _startup = _recording = None
 
 
 def note_phase(name: str, ms: float) -> None:

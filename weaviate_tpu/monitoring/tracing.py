@@ -564,6 +564,175 @@ class Stopwatch:
         self.ms = (end_ns - self.start_ns) / 1e6
 
 
+class stage:
+    """``with tracing.stage("lsm.open", shard=name) as st: ...``: one stage
+    of a restart (or of the way down) on the timeline the process keeps
+    (monitoring/perf.py ``Timeline``). A ``Stopwatch`` named
+    ``startup.<name>`` (``shutdown.<name>`` on the way down): while the
+    tracer is up a ``wv/startup.<name>`` annotation and an entry of the
+    capture log, otherwise two stamps;
+    either way the timeline gets the interval and the device's memory at
+    its end, if one is recording. ``st.seconds`` afterwards; ``st.note()``
+    for stats known only at the end."""
+
+    __slots__ = ("name", "stats", "seconds", "_sw", "_tl")
+
+    def __init__(self, name: str, **stats):
+        self.name = name
+        self.stats = stats
+        self.seconds = -1.0
+        tl = self._tl = perf.timeline()
+        if tl is not None:
+            tl.push(name)
+        self._sw = Stopwatch(
+            ("startup." if tl is None else tl.prefix) + name, **stats)
+
+    def note(self, **stats) -> None:
+        self.stats.update(stats)
+        self._sw.note(**stats)
+
+    def __enter__(self) -> "stage":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        sw = self._sw
+        sw.__exit__(*exc)
+        self.seconds = sw.ms / 1e3
+        tl = self._tl
+        if tl is not None:
+            tl.pop()
+            tl.note(self.name, sw.start_ns, round(sw.ms * 1e6), self.stats,
+                    capacity=self.stats.get("capacity"))
+
+
+class StageSums:
+    """The inner stages of one restore as EXCLUSIVE sums: a piece is a pair
+    of stamps added to its stage's sum (``log.read``, ``log.parse``,
+    ``stage``, ``grow``, ``land``, ``flush``, ``drain``), never an object a
+    record. Pieces nest (`flush` calls `land` calls `grow`): entering one
+    stops its caller's clock, so the stages add up to at most the restore
+    that holds them. One thread, the restoring one. `publish()` hands each
+    stage to the recording timeline as one interval: first start, summed
+    seconds, ``pieces`` and the span to its last end in the stats."""
+
+    __slots__ = ("sums", "_first", "_last", "_pieces", "_stack", "_t",
+                 "_tl", "_phases")
+
+    def __init__(self):
+        self.sums: dict[str, int] = {}
+        self._first: dict[str, int] = {}
+        self._last: dict[str, int] = {}
+        self._pieces: dict[str, int] = {}
+        self._stack: list[str] = []
+        # a piece's annotation, None without the tracer, False for a
+        # piece that asked for none
+        self._phases: list = []
+        self._t = 0
+        self._tl = perf.timeline()
+
+    def enter(self, name: str, annotate: bool = True, **stats) -> None:
+        """Open a piece of `name`. `annotate` False: the stamps alone, for
+        a piece as short and as frequent as a step of the log's generator
+        (no annotation, no memory row, not the stage a compile is charged
+        to)."""
+        ph = None
+        if annotate:
+            # the annotation first, the stamp last: its cost is the caller's
+            if _tracer is not None:
+                ph = Phase("startup." + name, **stats)
+            if self._tl is not None:
+                self._tl.push(name)
+        self._phases.append(ph if annotate else False)
+        now = time.perf_counter_ns()
+        if self._stack:
+            self.sums[self._stack[-1]] += now - self._t
+        if name not in self.sums:
+            self.sums[name] = 0
+            self._first[name] = now
+        self._pieces[name] = self._pieces.get(name, 0) + 1
+        self._stack.append(name)
+        self._t = now
+
+    def leave(self, capacity: Optional[int] = None) -> None:
+        now = time.perf_counter_ns()
+        name = self._stack.pop()
+        self.sums[name] += now - self._t
+        self._last[name] = now
+        self._t = now
+        ph = self._phases.pop()
+        if ph is False:
+            return
+        if ph is not None:
+            ph.end()
+        tl = self._tl
+        if tl is not None:
+            tl.pop()
+            # the device's memory: at every grow, else once a second
+            tl.memory(name, capacity, force=name == "grow")
+
+    def tick(self, capacity: Optional[int] = None) -> None:
+        """Inside a long piece (a run's chunks): the device's memory, at
+        most once a second."""
+        if self._tl is not None and self._stack:
+            self._tl.memory(self._stack[-1], capacity)
+
+    def timed(self, it, name: str):
+        """`it`, with the time inside it charged to `name` (the log's
+        generator: what it reads and parses between two yields)."""
+        it = iter(it)
+        while True:
+            self.enter(name, annotate=False)
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+            finally:
+                self.leave()
+            yield item
+
+    def seconds(self, *names: str) -> float:
+        return round(sum(self.sums.get(n, 0) for n in names) / 1e9, 6)
+
+    def publish(self) -> None:
+        tl = self._tl
+        if tl is None:
+            return
+        for name, total in self.sums.items():
+            first = self._first[name]
+            tl.note(name, first, total, {
+                "pieces": self._pieces[name],
+                "span_s": round((self._last[name] - first) / 1e9, 6)},
+                sample=False)
+
+
+class _Piece:
+    __slots__ = ("_sums", "_name", "_capacity", "_stats")
+
+    def __init__(self, sums, name, capacity, stats):
+        self._sums, self._name = sums, name
+        self._capacity, self._stats = capacity, stats
+
+    def __enter__(self):
+        self._sums.enter(self._name, **self._stats)
+
+    def __exit__(self, *exc):
+        self._sums.leave(self._capacity)
+
+
+# what `piece_of` hands out outside a restore: nothing, and the same nothing
+_NO_PIECE = contextlib.nullcontext()
+
+
+def piece_of(sums: Optional[StageSums], name: str,
+             capacity: Optional[int] = None, **stats):
+    """``with tracing.piece_of(self._restore_sums, "land"): ...`` on a
+    write path a restore shares with serving: a piece of the restore's
+    `name` stage while one runs, nothing at all otherwise."""
+    if sums is None:
+        return _NO_PIECE
+    return _Piece(sums, name, capacity, stats)
+
+
 def current_span() -> Optional[Span]:
     """The active span, or None. First check is the disabled fast path."""
     if _tracer is None:

@@ -53,6 +53,13 @@ class App:
 
         self._ivf_token = tpu_index.set_ivf_config(self.config.ivf)
 
+        # every compile of the process is counted where it happens
+        # (monitoring/perf.py CompileTally: `/debug/perf` `compiles`);
+        # once a process, whoever builds the first App
+        from weaviate_tpu.monitoring import perf
+
+        perf.compiles.install()
+
         # end-to-end request tracing (monitoring/tracing.py): the tracer is
         # a process-wide module global — shards and the coalescer reach it
         # without plumbing — installed here and cleared on shutdown.
@@ -545,7 +552,13 @@ class App:
         if self.serving_pool is not None:
             self.serving_pool.shutdown(wait=False)
         self.disk_monitor.shutdown()
-        if self.cluster_node is not None:
-            self.cluster_node.shutdown()
-        else:
-            self.db.shutdown()
+        from weaviate_tpu.monitoring import profiling, tracing
+
+        # a capture still open would outlive its server
+        with tracing.stage("profiler.stop"):
+            profiling.stop_active_trace()
+        with tracing.stage("db.shutdown"):
+            if self.cluster_node is not None:
+                self.cluster_node.shutdown()
+            else:
+                self.db.shutdown()

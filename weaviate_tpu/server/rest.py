@@ -25,7 +25,7 @@ from typing import Optional
 from urllib.parse import parse_qs, urlparse
 
 from weaviate_tpu.auth import ForbiddenError, UnauthorizedError
-from weaviate_tpu.monitoring import incidents, tracing
+from weaviate_tpu.monitoring import incidents, perf, tracing
 from weaviate_tpu.serving import robustness
 from weaviate_tpu.schema.manager import SchemaError
 from weaviate_tpu.usecases.objects import NotFoundError, ObjectsError
@@ -408,6 +408,10 @@ class Handler(BaseHTTPRequestHandler):
 
     def h_ready(self):
         self._reply(200, raw=b"")
+        # the restart's last stage: the first probe answered
+        tl = perf.startup()
+        if tl is not None and not tl.sealed:
+            tl.first_ready(self.app.metrics)
 
     def h_metrics(self):
         self._reply(200, raw=self.app.metrics.expose(),
@@ -431,14 +435,17 @@ class Handler(BaseHTTPRequestHandler):
                           "traces": traces})
 
     def h_debug_perf(self):
-        from weaviate_tpu.monitoring import perf
-
+        # the restart's timeline and the compile tally are the process's
+        # own: served whether or not the window is up
+        tl = perf.startup()
+        own = {"startup": tl.summary() if tl is not None else None,
+               "compiles": perf.compiles.summary()}
         w = perf.get_window()
         if w is None:
-            self._reply(200, {"enabled": False})
+            self._reply(200, {"enabled": False, **own})
             return
         self._reply(200, {"enabled": True, **w.summary(),
-                          "capture": w.last_capture()})
+                          "capture": w.last_capture(), **own})
 
     def h_debug_quality(self):
         from weaviate_tpu.monitoring import quality

@@ -1496,6 +1496,11 @@ class Store:
         )
         self._cycle_thread.start()
 
+    def sweep_in_flight(self) -> bool:
+        """Is a compaction sweep (or a backup's pause) holding the gate
+        right now? Racy by nature: a fact for a log line."""
+        return self._compaction_gate.locked()
+
     def compact_once(self, max_segments: Optional[int] = None) -> int:
         """One compaction sweep (also the test/CLI entry): -> merges done."""
         max_segs = max_segments if max_segments is not None else self.MAX_SEGMENTS
