@@ -418,6 +418,43 @@ def test_rescore_r_cap_steers_index_budget(tmp_path):
     assert idx._rescore_r(10, 100_000) == 40           # reverted
 
 
+def test_rescore_r_cap_steers_the_mesh_scan_step_too(tmp_path):
+    """The one rule (index/tpu.py rescore_depth) serves both indexes: the
+    depth every chip of the mesh's exact tier scans at follows the
+    controller's cap, read off the dispatch's shape."""
+    from weaviate_tpu.entities import vectorindex as vi
+    from weaviate_tpu.index.mesh import MeshVectorIndex
+    from weaviate_tpu.monitoring import tracing
+    from weaviate_tpu.parallel.mesh_search import make_mesh
+
+    cfg = vi.HnswUserConfig.from_dict(
+        {"distance": vi.DISTANCE_L2}, "hnsw_tpu_mesh")
+    idx = MeshVectorIndex(cfg, str(tmp_path), persist=False,
+                          mesh=make_mesh(4), initial_capacity_per_shard=64)
+    vecs = np.random.default_rng(5).standard_normal((200, 8)).astype(
+        np.float32)
+    idx.add_batch(np.arange(200), vecs)
+
+    def depth():
+        idx.search_by_vectors(vecs[:2], 10)
+        return idx.pop_dispatch_shape().extra["rescore_r"]
+
+    prev = tracing.get_tracer()
+    tracing.configure(tracing.Tracer(sample_rate=1.0))
+    try:
+        assert depth() == 40                            # static: 4k
+        p = controller.configure(_plane())
+        p._set_knob(KNOB_RESCORE_CAP, 32, "budget")
+        try:
+            assert depth() == 32                        # capped
+        finally:
+            controller.unconfigure(p)
+        assert depth() == 40                            # reverted
+    finally:
+        tracing.configure(prev)
+        idx.shutdown()
+
+
 # -- controller 3: coalescer window / pipeline depth --------------------------
 
 

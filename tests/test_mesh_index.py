@@ -495,3 +495,86 @@ def test_pq_mesh_compact_keeps_f32_log(tmp_path, rng):
         str(tmp_path / "pqc" / "vector.log")) if op == "add"}
     np.testing.assert_array_equal(got[42], vecs[42])
     assert 0 not in got and 1 not in got
+
+
+def _f32_reference(rows, q, metric):
+    """The float32 distance of every row, summed in float64 so that the
+    reference's own rounding stays under the tolerance it is used at."""
+    rows, q = rows.astype(np.float64), q.astype(np.float64)
+    if metric == "l2-squared":
+        return ((rows - q) ** 2).sum(1)
+    if metric == "dot":
+        return -(rows @ q)
+    return 1.0 - rows @ q
+
+
+@pytest.mark.parametrize("exact_topk", [False, True],
+                         ids=["rescored", "exactTopK"])
+@pytest.mark.parametrize("tombstones", [False, True],
+                         ids=["all-live", "tombstones"])
+@pytest.mark.parametrize("use_allow", [False, True],
+                         ids=["unfiltered", "allowList"])
+@pytest.mark.parametrize("metric", ["cosine", "l2-squared", "dot"])
+def test_exact_tier_answers_are_the_f32_brute_force(
+        tmp_path, metric, use_allow, tombstones, exact_topk):
+    """The mesh's exact tier on four virtual chips is the one-chip scan step
+    on each slab: the ids are exact float32 brute force's over the rows that
+    are live and allowed, every returned distance is the float32 distance of
+    the row returned, and the dispatch's shape says at which depth the step
+    ran (0 under exactTopK: the HIGHEST-precision scan). The allowList
+    leaves every chip fewer rows than the depth, so the (+inf, -1) filler of
+    a short slab goes through the rescore and the merge."""
+    from weaviate_tpu.monitoring import tracing
+    from weaviate_tpu.parallel.mesh_search import make_mesh
+
+    rng = np.random.default_rng(36)
+    n, dim, k = 600, 32, 5
+    config = parse_and_validate_config(
+        "hnsw_tpu_mesh", {"distance": metric, "exactTopK": exact_topk})
+    idx = MeshVectorIndex(config, str(tmp_path), persist=False,
+                          mesh=make_mesh(4), initial_capacity_per_shard=64)
+    vecs = rng.standard_normal((n, dim)).astype(np.float32)
+    vecs /= np.linalg.norm(vecs, axis=1, keepdims=True)
+    ids = np.arange(100, 100 + n)
+    idx.add_batch(ids, vecs)
+    live = np.ones(n, bool)
+    if tombstones:
+        dead = np.arange(0, n, 3)
+        idx.delete(*(int(i) for i in ids[dead]))
+        live[dead] = False
+    allow = None
+    if use_allow:
+        allowed = np.arange(0, n, 7)          # 86 rows: ~21 a chip, under R
+        allow = Bitmap(ids[allowed].astype(np.uint64))
+        mask = np.zeros(n, bool)
+        mask[allowed] = True
+        live &= mask
+    qs = vecs[rng.integers(0, n, 6)] + 0.05 * rng.standard_normal(
+        (6, dim)).astype(np.float32)
+    qs = (qs / np.linalg.norm(qs, axis=1, keepdims=True)).astype(np.float32)
+
+    prev = tracing.get_tracer()
+    tracing.configure(tracing.Tracer(sample_rate=1.0))
+    try:
+        got_ids, got_d = idx.search_by_vectors(qs, k, allow_list=allow)
+        shape = idx.pop_dispatch_shape()
+    finally:
+        tracing.configure(prev)
+    assert shape.tier == "exact_scan" and shape.ndev == 4
+    assert shape.extra["rescore_r"] == (0 if exact_topk else 32)
+    assert shape.describe()["rescore_r"] == shape.extra["rescore_r"]
+    # and /debug/perf tallies the window's dispatches by that depth
+    from weaviate_tpu.monitoring import perf
+    window = perf.PerfWindow()
+    shape.t_end = shape.t_start + 1e-3
+    window.record_dispatch(shape)
+    assert window.summary()["rescore_r"] == {
+        str(shape.extra["rescore_r"]): 1}
+
+    assert got_ids.shape == (6, k)
+    for bi in range(6):
+        ref = _f32_reference(vecs, qs[bi], metric)
+        want = np.argsort(np.where(live, ref, np.inf), kind="stable")[:k]
+        assert got_ids[bi].tolist() == ids[want].tolist()
+        np.testing.assert_allclose(got_d[bi], ref[want], rtol=0, atol=1e-6)
+    idx.shutdown()

@@ -1,13 +1,16 @@
-"""The legacy scan's program makes no slab-sized temporary: compiled by the
-real TPU compiler for a described v5e, at the 768-d deployment's real shapes,
+"""The scan step's programs make no slab-sized temporary: compiled by the
+real TPU compiler for a described v5e, at the 768-d deployments' real shapes,
 every step takes its chunk from the f32 slab in place and the rounding to bf16
 happens inside the step's matmul. Two things hold that together and neither
-does alone (index/tpu.py _scan_full, _TPU_SCAN_OPTIONS): the loop indexes the
+does alone (ops/scan.py scan_topk, TPU_SCAN_OPTIONS): the loop indexes the
 whole slab (a static `store[:ext]` prefix is copied on every dispatch once the
 slab is part full), and XLA's bf16 propagation is off for the program (it
 narrows the whole slab at its source, outside the loop, at every batch of 8
-and more). Nothing runs here, so this says nothing about answers or times;
-tests/test_tpu_index.py holds the answers. The topology is described inside a
+and more). The option binds to a top-level jit only, so each program that calls
+the step carries it itself: the one-chip `_search_full_fused` and the mesh's
+`mesh_search_step` (four chips, 2^19 rows a chip), both held here. Nothing
+runs, so this says nothing about answers or times; tests/test_tpu_index.py and
+tests/test_mesh_index.py hold the answers. The topology is described inside a
 module-scoped fixture, never at import (on-chip-measurement guide, 2)."""
 
 import re
@@ -22,13 +25,17 @@ TEMP_LIMIT = 64 * 2 ** 20   # the slab is 3.2 GB, a chunk of it 403 MB
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -46,17 +53,36 @@ def _no_compile_cache():
     cc.reset_cache()
 
 
-def _slab_wide_bf16_converts(text: str) -> list:
+def _slab_wide_bf16_converts(text: str, slab_rows: int = CAP) -> list:
     """`convert` instructions whose result is bf16 and holds a quarter of the
-    slab's elements or more (the hoisted one is bf16[8,131072,768])."""
+    slab's elements or more (the hoisted one is bf16[8,131072,768]; on the
+    mesh, of a chip's slab, bf16[4,131072,768])."""
     found = []
     for m in re.finditer(r"= bf16\[([\d,]+)\]\S* convert\(", text):
         elems = 1
         for d in m.group(1).split(","):
             elems *= int(d)
-        if elems >= CAP * DIM // 4:
+        if elems >= slab_rows * DIM // 4:
             found.append(m.group(0))
     return found
+
+
+def _one_chip_program(one_chip, program, batch, rows, use_allow):
+    """`program` (`_search_full_fused`, or one of its two jits) compiled for
+    one described chip over cohere-768-cos's slab, at the depth the index
+    runs."""
+    from weaviate_tpu.config.config import RESCORE_R_BUCKETS
+    from weaviate_tpu.index import tpu
+
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    return program.lower(
+        S((CAP, DIM), jnp.float32), None, S((CAP,), jnp.bool_),
+        S((), jnp.int32), S((batch, DIM), jnp.float32),
+        S((CAP // 32,), jnp.uint32), S((CAP, 2), jnp.uint32),
+        k=K, metric="cosine", use_allow=use_allow, exact=False,
+        active_chunks=-(-rows // tpu._SCAN_CHUNK),
+        rescore_r=min(max(4 * K, RESCORE_R_BUCKETS[0]),
+                      RESCORE_R_BUCKETS[-1])).compile()
 
 
 @pytest.mark.parametrize("batch,rows,use_allow", [
@@ -70,17 +96,83 @@ def test_scan_program_has_no_slab_sized_temporary(one_chip, batch, rows,
     widths Search and BatchSearch dispatch: the program the index picks for
     a TPU device (the platform of the slab's sharding decides, from a CPU
     process too)."""
-    from weaviate_tpu.config.config import RESCORE_R_BUCKETS
     from weaviate_tpu.index import tpu
 
-    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    compiled = tpu._search_full_fused.lower(
-        S((CAP, DIM), jnp.float32), None, S((CAP,), jnp.bool_),
-        S((), jnp.int32), S((batch, DIM), jnp.float32),
-        S((CAP // 32,), jnp.uint32), S((CAP, 2), jnp.uint32),
-        k=K, metric="cosine", use_allow=use_allow, exact=False,
-        active_chunks=-(-rows // tpu._SCAN_CHUNK),
-        rescore_r=min(max(4 * K, RESCORE_R_BUCKETS[0]),
-                      RESCORE_R_BUCKETS[-1])).compile()
+    compiled = _one_chip_program(one_chip, tpu._search_full_fused, batch,
+                                 rows, use_allow)
     assert compiled.memory_analysis().temp_size_in_bytes < TEMP_LIMIT
     assert _slab_wide_bf16_converts(compiled.as_text()) == []
+
+
+def test_the_compiler_option_is_what_holds_the_one_chip_program(one_chip):
+    """The same function jitted WITHOUT TPU_SCAN_OPTIONS (the program a CPU
+    device gets), compiled for the chip at batch 256 over the full slab:
+    bf16 propagation narrows the whole slab ahead of the loop."""
+    from weaviate_tpu.index import tpu
+
+    compiled = _one_chip_program(one_chip, tpu._search_full_fused._plain,
+                                 256, 1_000_000, False)
+    assert compiled.memory_analysis().temp_size_in_bytes > CAP * DIM * 2 // 2
+    assert _slab_wide_bf16_converts(compiled.as_text()) != []
+
+
+MESH_DEV, MESH_LOC, MESH_BATCH = 4, 2 ** 19, 256   # cohere-768-cos-mesh4
+
+
+def _mesh_program(topo, program, **statics):
+    """`program` (mesh_search_step, or one of its two jits) compiled for the
+    four described chips at the mesh cell's shapes; the bytes are a chip's."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from weaviate_tpu.parallel import mesh_search as ms
+
+    mesh = Mesh(topo.devices[:MESH_DEV], (ms.SHARD_AXIS,))
+    sharded = lambda *rest: NamedSharding(mesh, P(ms.SHARD_AXIS, *rest))  # noqa: E731
+    rep = NamedSharding(mesh, P())
+    S = jax.ShapeDtypeStruct
+    cap = MESH_DEV * MESH_LOC
+    return program.lower(
+        S((cap, DIM), jnp.float32, sharding=sharded(None)),
+        S((cap,), jnp.float32, sharding=sharded()),
+        S((cap,), jnp.bool_, sharding=sharded()),
+        S((MESH_DEV,), jnp.int32, sharding=rep),
+        S((cap // 32,), jnp.uint32, sharding=sharded()),
+        S((MESH_BATCH, DIM), jnp.float32, sharding=rep),
+        S((cap, 2), jnp.uint32, sharding=sharded(None)),
+        k=K, metric="cosine", use_norms=False, exact=False, fused=True,
+        mesh=mesh, **statics).compile()
+
+
+@pytest.mark.parametrize("use_allow,rescore_r", [
+    (False, 40), (True, 40), (False, 0),
+])
+def test_mesh_scan_program_has_no_slab_sized_temporary(topo, use_allow,
+                                                       rescore_r):
+    """cohere-768-cos-mesh4's slab a chip (2^19 x 768 f32), batch 256, k 10:
+    the program `mesh_search_step` picks for TPU devices (the platform of
+    the store's sharding decides), at the depth the index runs (40), under a
+    filter, and as the HIGHEST-precision scan that exactTopK keeps (0)."""
+    from weaviate_tpu.parallel import mesh_search as ms
+
+    compiled = _mesh_program(topo, ms.mesh_search_step, use_allow=use_allow,
+                             rescore_r=rescore_r)
+    assert compiled.memory_analysis().temp_size_in_bytes < TEMP_LIMIT
+    text = compiled.as_text()
+    assert _slab_wide_bf16_converts(text, MESH_LOC) == []
+    assert "all-gather" in text
+    # the name the benchmark finds the program by (scan_roofline.json)
+    assert "HloModule jit_mesh_search_step" in text
+
+
+def test_the_compiler_option_is_what_holds_the_mesh_program(topo):
+    """The mesh's twin of the one-chip case above: without the option every
+    chip's whole slab is narrowed ahead of the loop, an 806 MB temporary a
+    chip a dispatch. The shared step does not bring the option; the program
+    must."""
+    from weaviate_tpu.parallel import mesh_search as ms
+
+    compiled = _mesh_program(topo, ms.mesh_search_step._plain,
+                             use_allow=False, rescore_r=40)
+    assert compiled.memory_analysis().temp_size_in_bytes > \
+        MESH_LOC * DIM * 2 // 2
+    assert _slab_wide_bf16_converts(compiled.as_text(), MESH_LOC) != []

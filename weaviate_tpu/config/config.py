@@ -21,7 +21,7 @@ class ConfigError(ValueError):
 # import it and may never drift apart (the fused-dispatch satellite):
 #   - serving/controller.py's recall-guarded budget controller steps the
 #     rescore_r cap DOWN this ladder (and snaps operator overrides to it);
-#   - index/tpu.py's `_rescore_r` / codes-tier pool sizing treat the top
+#   - index/tpu.py's `rescore_depth` / codes-tier pool sizing treat the top
 #     bucket as the static maximum and clamp against the controller cap.
 # Because every cap value is a bucket and the index's own static choices
 # are {max(4k, 32)} ∪ buckets, a controller cut can never mint a jit

@@ -8,7 +8,10 @@ weaviate_tpu/parallel/mesh_search.py):
 - insert: staged host-side, flushed as ONE sharded [n_dev, C, D] write —
   each chip lands its own chunk at its own offset (no per-shard dispatch
   loop);
-- search: chunked masked scan per slab + local top-k, cross-chip merge over
+- search: on every chip the one-chip scan step itself over its own slab
+  (ops/scan.py scan_topk: one bf16 MXU pass, R candidates a query rescored
+  in f32 from the chip's own rows; the HIGHEST-precision scan under
+  exactTopK) + local top-k, cross-chip merge over
   ICI (all_gather + reselect) inside the same jit, then on-device slot→doc
   translation against the sharded pair table — the program returns
   the packed [B, 3k] buffer, so finalize is ONE fetch and dtype views
@@ -77,6 +80,7 @@ from weaviate_tpu.index.tpu import (
     _fetch_packed,
     _snap_top_p,
     ivf_settings,
+    rescore_depth,
 )
 # dispatch-shape recording for the perf-attribution plane: a
 # costmodel.DispatchShape is built per dispatch ONLY while the tracer is
@@ -100,13 +104,13 @@ from weaviate_tpu.monitoring.costmodel import (
 )
 from weaviate_tpu.monitoring.metrics import record_device_fallback
 from weaviate_tpu.ops import ivf as ivf_ops
+from weaviate_tpu.ops.scan import SCAN_CHUNK
 from weaviate_tpu.ops.topk import unpack_fused
 # the recall-guarded probe-depth cap shares the single-chip controller;
 # controller imports nothing from the index layer, so no cycle
 from weaviate_tpu.serving import controller
 from weaviate_tpu.testing import faults, sanitizers
 from weaviate_tpu.parallel.mesh_search import (
-    _MESH_SCAN_CHUNK,
     make_mesh,
     mesh_delete_step,
     mesh_grow_1d,
@@ -1343,7 +1347,7 @@ class MeshVectorIndex(VectorIndex):
         caps as the single-chip index (index/tpu.py _funnel_budgets), but
         planned against the PER-SHARD slab (n = n_loc) — each chip funnels
         its own rows, so the whole-mesh candidate pool is n_dev x rg4*16.
-        The no-starvation floors mirror _rescore_r: the controller may
+        The no-starvation floors are rescore_depth's: the controller may
         only cut work, never break top-k coverage."""
         from weaviate_tpu.ops import pq4 as pq4_ops
 
@@ -1384,6 +1388,7 @@ class MeshVectorIndex(VectorIndex):
             return lambda: empty
         faults.fire("index.mesh.dispatch")
         shape = None
+        depth = {}  # {"rescore_r": R} where the scan step serves
         t_enq0 = 0.0
         enqueue = None
         if tracing.get_tracer() is not None:
@@ -1391,7 +1396,7 @@ class MeshVectorIndex(VectorIndex):
             t_enq0 = enqueue.start_ns / 1e9
         try:
             q, b = self._prep_queries(vectors)
-            chunk = min(snap.n_loc, _MESH_SCAN_CHUNK)
+            chunk = min(snap.n_loc, SCAN_CHUNK)
             kk = max(1, min(k, snap.live, chunk))
             use_allow = allow_list is not None
             words = self._allow_words(snap, allow_list) if use_allow else snap.zero_words
@@ -1533,6 +1538,13 @@ class MeshVectorIndex(VectorIndex):
                     packed_dev = self._gmin_step_or_none(
                         snap, q, kk, words, use_allow)
                     if packed_dev is None:
+                        # the scan step's depth a chip: the one-chip rule,
+                        # planned against one slab like the funnel's
+                        # budgets; on the shape and in the `enqueue`
+                        # interval's stats (0: the HIGHEST-precision scan)
+                        rescore_r = rescore_depth(self.config, self.metric,
+                                                  kk, snap.n_loc)
+                        depth = {"rescore_r": rescore_r}
                         packed_dev = mesh_search_step(
                             snap.store,
                             snap.sq_norms,
@@ -1548,20 +1560,22 @@ class MeshVectorIndex(VectorIndex):
                             exact,
                             True,  # fused: the only epilogue there is
                             self.mesh,
+                            rescore_r,
                         )
                     if t_enq0:
                         shape = DispatchShape(
                             TIER_EXACT, n=snap.n_total, dim=snap.dim, batch=b,
                             batch_padded=q.shape[0],
                             bytes_per_row=snap.dim * snap.store.dtype.itemsize,
-                            k=int(kk), ndev=snap.n_dev)
+                            k=int(kk), ndev=snap.n_dev, extra=depth or None)
         except BaseException:
             if enqueue is not None:  # a dispatch that failed being built
                 enqueue.end()
             raise
 
         if shape is not None:
-            now_ns = enqueue.end(rows=b, tier=shape.tier, ndev=shape.ndev)
+            now_ns = enqueue.end(rows=b, tier=shape.tier, ndev=shape.ndev,
+                                 **depth)
             shape.t_start = t_enq0
             shape.enqueue_ms = (now_ns - enqueue.start_ns) / 1e6
             self._read_local.dispatch_shape = shape

@@ -179,7 +179,8 @@ class PerfWindow:
         self.sample_hint = float(sample_hint)
         self._lock = threading.Lock()
         # (t_end_mono, tier, rows, one-fetch invariant violated, store
-        # rows the dispatch read: DispatchShape.n)
+        # rows the dispatch read: DispatchShape.n, the depth its scan step
+        # ran at where the shape carries one: extra["rescore_r"])
         self._entries: deque = deque()
         # phase name -> deque[(t_mono, ms)], count-capped (see
         # _PHASE_SAMPLES_MAX) on top of the time-horizon eviction
@@ -243,7 +244,8 @@ class PerfWindow:
         with self._lock:
             self._evict(now)
             self._entries.append((now, shape.tier, nrows, viol,
-                                  int(shape.n)))
+                                  int(shape.n),
+                                  (shape.extra or {}).get("rescore_r")))
             self._rows += nrows
             self._total_dispatches += 1
             if self._first_entry is None:
@@ -480,10 +482,13 @@ class PerfWindow:
             tiers: dict[str, int] = {}
             tier_rows: dict[str, int] = {}
             violations = 0
-            for _, tier, _, viol, read in self._entries:
+            depths: dict[str, int] = {}
+            for _, tier, _, viol, read, depth in self._entries:
                 tiers[tier] = tiers.get(tier, 0) + 1
                 tier_rows[tier] = tier_rows.get(tier, 0) + read
                 violations += viol
+                if depth is not None:
+                    depths[str(depth)] = depths.get(str(depth), 0) + 1
             total_dispatches = self._total_dispatches
             point_get = [sum(c) for c in list(zip(*self._point_get))[1:]]
             rescore = [sum(c) for c in list(zip(*self._rescore))[1:]]
@@ -578,6 +583,10 @@ class PerfWindow:
         # per-slot gather the sum over its slots of the rows gathered. What
         # a roofline may charge a program that reads a part of the rows.
         out["tier_rows"] = {t: tier_rows[t] for t in out["tiers"]}
+        if depths:
+            # dispatches by the depth R their scan step ran at, where the
+            # shape says (the mesh's exact tier: "0" is the HIGHEST scan)
+            out["rescore_r"] = depths
         # invariant violations over the window
         # (costmodel.fused_invariant_ok): every dispatch translates on the
         # device, so `dispatches` is all of them; violations > 0 means a

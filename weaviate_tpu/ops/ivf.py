@@ -343,7 +343,7 @@ def _grouped_topk(slots_g: Array, valid_g: Array, score_fn, keep: int,
 
     Selection discipline mirrors the flat fast scan: each group's
     approx_min_k keeps 4x``keep`` SLACK candidates (selection errors of
-    the approximate pass sit well within 4k — index/tpu.py _rescore_r's
+    the approximate pass sit well within 4k — index/tpu.py rescore_depth's
     rationale), the cross-step merge is an exact top-k over the widened
     set, and the final [:, :keep] slice of the sorted merge is the exact
     best of everything any group surfaced. The PCA prefilter stage

@@ -9,7 +9,8 @@ sets over ICI followed by a k-selection — all inside one jit.
 Every kernel here is a whole-mesh step:
 
 - mesh_search_step:  chunked masked kNN per slab (tombstones + allowList
-  bitmap, same semantics as the single-chip scan in index/tpu.py) with the
+  bitmap): the single-chip scan step itself (ops/scan.py scan_topk, fast
+  scan and f32 rescore included), called on each chip's slab, with the
   cross-chip merge riding ICI. Every search kernel translates its LOCAL
   winners through its slab of the sharded slot->doc word table BEFORE the
   collective, so the gathered candidates already carry final doc ids and
@@ -37,9 +38,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from weaviate_tpu.ops.distances import DISTANCE_FNS
+from weaviate_tpu.ops.scan import (
+    SCAN_CHUNK, TPU_SCAN_OPTIONS, ScanProgram, scan_topk,
+)
 from weaviate_tpu.ops.topk import (
-    bitmap_to_mask, merge_top_k, rescore_distances, translate_pack,
+    bitmap_to_mask, rescore_distances, translate_pack,
 )
 
 SHARD_AXIS = "shard"
@@ -51,10 +54,6 @@ def _shard_map(f, *, mesh, in_specs, out_specs):
     checker cannot see through pallas_call."""
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-
-# rows of a slab scored per scan step (bounds the [B, chunk] block in HBM,
-# same rationale as index/tpu.py _SCAN_CHUNK)
-_MESH_SCAN_CHUNK = 131072
 
 
 def _merge_across_shards_fused(d_top, i_loc, s2d_l, k):
@@ -111,16 +110,12 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("k", "metric", "use_allow", "use_norms", "exact",
-                     "fused", "mesh"),
-)
 def mesh_search_step(
     store, sq_norms, tombs, n_per_shard, allow_words, queries, s2d,
-    k, metric, use_allow, use_norms, exact, fused, mesh,
+    k, metric, use_allow, use_norms, exact, fused, mesh, rescore_r=0,
 ):
-    """Fully-sharded masked kNN.
+    """Fully-sharded masked kNN: every chip runs the one-chip scan step
+    (ops/scan.py scan_topk) over its own slab, then the cross-chip merge.
 
     store:       [n_dev * n_loc, D] sharded P('shard', None) — HBM slabs
     sq_norms:    [n_dev * n_loc] f32 sharded (l2 only; pass zeros otherwise)
@@ -134,63 +129,35 @@ def mesh_search_step(
                  nothing else. The argument stays because the benchmark's
                  compile tests pass it (ROADMAP.md Queue 3); anything else
                  is refused.
+    rescore_r:   the fast scan's depth R a chip (index/tpu.py
+                 rescore_depth, planned against one slab). R > 0: each chip
+                 scans its slab in ONE bf16 MXU pass (DEFAULT precision),
+                 keeps R candidates a query, gathers those R rows from its
+                 own slab and scores them elementwise in f32, so what
+                 crosses ICI is still k (f32 distance, doc id) a chip and
+                 every returned distance is the f32 distance of the row
+                 returned. 0 (exactTopK, a non-matmul metric, k too deep
+                 for R): six passes at HIGHEST precision select k directly,
+                 with lax.top_k a chunk if `exact`, else approx_min_k.
     -> FUSED packed [B, 3k] i32 (translate_pack layout, doc ids already
        resolved on device), replicated.
 
-    Per-chunk selection is lax.approx_min_k (the TPU PartialReduce primitive)
-    unless exact; the cross-chunk and cross-chip merges are exact, mirroring
-    the single-chip scan in index/tpu.py.
+    The cross-chunk and cross-chip merges are exact. The single-pass
+    matmul needs TPU_SCAN_OPTIONS or XLA narrows every chip's whole f32
+    slab to bf16 ahead of the loop, and the option binds to a top-level
+    jit only: hence a ScanProgram (the platform of the store's devices
+    picks the program), not a plain jit.
     """
     if not isinstance(fused, bool) or not fused:
         raise ValueError(
             "mesh_search_step translates on the device and nothing else: "
             f"fused must be True, got {fused!r}")
-    n_dev = mesh.devices.size
-    n_loc = store.shape[0] // n_dev
-    dim = store.shape[1]
-    chunk = min(n_loc, _MESH_SCAN_CHUNK)
-    nchunks = n_loc // chunk  # n_loc is a power of two, so this divides
 
     def shard_fn(store_l, norms_l, tombs_l, n_all, allow_l, q, s2d_l):
-        my = jax.lax.axis_index(SHARD_AXIS)
-        n_mine = n_all[my]
-        b = q.shape[0]
-        store_c = store_l.reshape(nchunks, chunk, dim)
-        tombs_c = tombs_l.reshape(nchunks, chunk)
-        norms_c = norms_l.reshape(nchunks, chunk) if use_norms else None
-        allow_c = allow_l.reshape(nchunks, chunk // 32) if use_allow else None
-
-        def step(carry, xs):
-            best_d, best_i = carry
-            ci, st, tb = xs[0], xs[1], xs[2]
-            j = 3
-            nm = None
-            if use_norms:
-                nm = xs[j]
-                j += 1
-            al = xs[j] if use_allow else None
-            base = ci * chunk
-            valid = jnp.logical_and(
-                jnp.arange(chunk) + base < n_mine, jnp.logical_not(tb)
-            )
-            if use_allow:
-                valid = jnp.logical_and(valid, bitmap_to_mask(al, chunk))
-            d = DISTANCE_FNS[metric](q.astype(st.dtype), st, nm)
-            d = jnp.where(valid[None, :], d, jnp.inf)
-            if exact:
-                neg, li = jax.lax.top_k(-d, k)
-                td = -neg
-            else:
-                td, li = jax.lax.approx_min_k(d, k, recall_target=0.95)
-            return merge_top_k(best_d, best_i, td, li + base, k), None
-
-        init = (jnp.full((b, k), jnp.inf, jnp.float32), jnp.full((b, k), -1, jnp.int32))
-        xs = [jnp.arange(nchunks), store_c, tombs_c]
-        if use_norms:
-            xs.append(norms_c)
-        if use_allow:
-            xs.append(allow_c)
-        (d_top, i_top), _ = jax.lax.scan(step, init, tuple(xs))
+        n_mine = n_all[jax.lax.axis_index(SHARD_AXIS)]
+        d_top, i_top = scan_topk(
+            store_l, norms_l if use_norms else None, tombs_l, n_mine, q,
+            allow_l, k, metric, use_allow, exact, rescore_r=rescore_r)
         return _merge_across_shards_fused(d_top, i_top, s2d_l, k)
 
     return _shard_map(
@@ -202,6 +169,14 @@ def mesh_search_step(
         ),
         out_specs=P(),
     )(store, sq_norms, tombs, n_per_shard, allow_words, queries, s2d)
+
+
+_STEP_STATICS = ("k", "metric", "use_allow", "use_norms", "exact", "fused",
+                 "mesh", "rescore_r")
+mesh_search_step = ScanProgram(
+    jax.jit(mesh_search_step, static_argnames=_STEP_STATICS),
+    jax.jit(mesh_search_step, static_argnames=_STEP_STATICS,
+            compiler_options=TPU_SCAN_OPTIONS))
 
 
 @functools.partial(
@@ -317,7 +292,7 @@ def mesh_search_pq_step(
     n_loc = codes.shape[0] // n_dev
     m = codes.shape[1]
     _, c, ds = codebook.shape
-    chunk = min(n_loc, _MESH_SCAN_CHUNK)
+    chunk = min(n_loc, SCAN_CHUNK)
     nchunks = n_loc // chunk
 
     def shard_fn(codes_l, norms_l, tombs_l, n_all, allow_l, cb, rs_l, q, r,

@@ -47,7 +47,7 @@ Fail-static safety — the control plane may never degrade serving:
   writes a controller-owned knob) and journaled as a
   ``controller_actuation`` ops event;
 - knob values carry a LEASE: readers (coalescer admission, the index's
-  ``_rescore_r``) fall back to the configured default once a value goes
+  ``rescore_depth``) fall back to the configured default once a value goes
   ``lease_s`` stale, so a STALLED tick thread reverts the module-read
   knobs in bounded time without any watchdog;
 - a DYING tick thread (``serving.controller.tick`` fault point, action
@@ -988,7 +988,7 @@ def retry_after_scale() -> float:
 
 def rescore_r_cap(default: int) -> int:
     """Cap on the PQ fast-scan candidate budget (index/tpu.py
-    ``_rescore_r``); the recall-guarded budget controller steps it down
+    ``rescore_depth``); the recall-guarded budget controller steps it down
     bucket-by-bucket while measured recall slack exists. Never exceeds
     `default` (the index's own maximum)."""
     p = _plane
