@@ -59,10 +59,10 @@ def indexes(tmp_path_factory):
         idx = new_vector_index(cfg, str(tmp_path_factory.mktemp(layout)), "s")
         idx.add_batch(docs, vecs)
         if layout == "out_of_order":
-            # a re-added doc holds two slots, the first one dead
-            vecs = np.concatenate([vecs, vecs[7:8] + 1.0])
-            idx.add(int(docs[7]), vecs[-1])
-            docs = np.concatenate([docs, docs[7:8]])
+            # a re-added doc takes its old slot again (index/tpu.py
+            # `_place_rows`): one slot, the new row in it
+            vecs[7] += 1.0
+            idx.add(int(docs[7]), vecs[7])
         made[layout] = (idx, vecs, docs)
     yield made
     for idx, _, _ in made.values():
@@ -119,8 +119,7 @@ def test_a_list_of_every_size_as_rows_and_as_words(indexes, library, layout,
     want, want_words = _oracle(idx, snap, allow)
 
     def slots_of(a: Bitmap, picked: int) -> int:
-        # a re-added doc's dead slot stays in the list: the device masks it
-        return picked + (layout == "out_of_order" and a.contains(int(docs[7])))
+        return picked
 
     assert inputs.sizes.tolist() == [slots_of(allow, m), slots_of(other, 40)]
     assert want.size == slots_of(allow, m)

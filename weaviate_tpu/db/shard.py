@@ -45,6 +45,7 @@ from weaviate_tpu.inverted.index import InvertedIndex
 from weaviate_tpu.inverted.searcher import FilterSearcher, PostingMemo
 from weaviate_tpu.storage.bitmap import Bitmap
 from weaviate_tpu.storage.docid import Counter
+from weaviate_tpu.storage import lsm
 from weaviate_tpu.storage.lsm import STRATEGY_REPLACE, Store
 
 # shard status (entities/storagestate)
@@ -148,6 +149,10 @@ class Shard:
     # allowList-cache LRU capacity (build_allow_list; surfaced by
     # debug_health so /debug/index can report occupancy vs the bound)
     _ALLOW_CACHE_CAP = 16
+    # how long a replaced doc id still resolves to its object: the longest
+    # a search may lie between its dispatch and its hydration (a request's
+    # default deadline is 30 s)
+    _REPLACED_KEEP_S = 60.0
 
     def __init__(
         self,
@@ -194,6 +199,10 @@ class Shard:
         self.store.start_compaction_cycle()
         self.status = STATUS_READY
         self._deleted: dict[str, int] = {}  # uuid -> deletion ms (digests)
+        # doc id a re-put replaced -> (uuid key, monotonic stamp): a search
+        # dispatched before the re-put holds the old doc id and hydrates
+        # after the lookup entry is gone (`_uuid_keys`)
+        self._replaced: dict[int, tuple[bytes, float]] = {}
         # allowList cache: filter-content key -> (write generation, Bitmap,
         # inserting tenant) — the tenant bounds each tenant's share at
         # eviction time (see build_allow_list)
@@ -291,7 +300,7 @@ class Shard:
                 obj.creation_time_unix = prev.creation_time_unix
                 if not preserve_times:
                     obj.last_update_time_unix = int(time.time() * 1000)
-                self._cleanup_previous(prev)
+                self._cleanup_previous(prev, key)
             doc_id = self.counter.get_and_inc()
             obj.doc_id = doc_id
             self.objects.put(key, obj.to_binary())
@@ -302,11 +311,52 @@ class Shard:
                 self.vector_index.add(doc_id, obj.vector)
             return obj
 
-    def _cleanup_previous(self, prev: StorObj) -> None:
+    def _cleanup_previous(self, prev: StorObj, key: bytes = b"",
+                          replaced: Optional[list] = None) -> None:
+        """Take the previous version of an object out of the inverted
+        index, the geo indexes, the doc-id lookup and the vector index.
+        `replaced`: a batch's list of doc ids whose vectors leave the
+        index together with the batch's add (`put_batch`), in place of
+        the delete here. `key`: the object's uuid key where it is being
+        put again, so that a search that was dispatched on the old doc id
+        still finds the object (`_replaced`)."""
         self.inverted.delete_object(prev.doc_id, prev.properties)
         self._geo_delete(prev.doc_id, prev.properties)
         self.docid_lookup.delete(struct.pack("<Q", prev.doc_id))
-        self.vector_index.delete(prev.doc_id)
+        if key:
+            self._replaced[prev.doc_id] = (key, time.monotonic())
+        if replaced is not None:
+            replaced.append(prev.doc_id)
+        else:
+            self.vector_index.delete(prev.doc_id)
+
+    def _prune_replaced(self) -> None:
+        """Forget the re-put doc ids no search can still hold: those older
+        than `_REPLACED_KEEP_S` (dicts keep insertion order, which is the
+        order of the stamps)."""
+        horizon = time.monotonic() - self._REPLACED_KEEP_S
+        drop = []
+        for doc_id, (_, at) in self._replaced.items():
+            if at >= horizon:
+                break
+            drop.append(doc_id)
+        for doc_id in drop:
+            del self._replaced[doc_id]
+
+    def _uuid_keys(self, doc_ids) -> list:
+        """The uuid key of each doc id (None: no such object). A doc id
+        that a re-put has replaced since the search that found it was
+        dispatched resolves to the object it was a version of: the reply
+        names the row, in its new version, instead of coming back short."""
+        keys = self.docid_lookup.multi_get(
+            [struct.pack("<Q", int(d)) for d in doc_ids])
+        if self._replaced:
+            for i, key in enumerate(keys):
+                if key is None:
+                    hit = self._replaced.get(int(doc_ids[i]))
+                    if hit is not None:
+                        keys[i] = hit[0]
+        return keys
 
     def _geo_add(self, doc_id: int, props: dict) -> None:
         for name, idx in self._geo_indexes.items():
@@ -328,6 +378,14 @@ class Shard:
         with self._lock:
             self._check_writable()
             self._write_gen += 1
+            self._prune_replaced()
+            # the stage `lsm` of /debug/perf `writes`: everything up to
+            # the vector index's own stages (objects, doc-id lookup,
+            # inverted index)
+            lsm = tracing.Stopwatch("write.lsm", rows=len(objs))
+            # doc ids of the previous versions: their vectors leave the
+            # index in the same step as the batch's rows arrive
+            replaced: list[int] = []
             errs: list[Optional[Exception]] = [None] * len(objs)
             fresh_ids: list[int] = []
             fresh_vecs: list[np.ndarray] = []
@@ -353,7 +411,7 @@ class Shard:
                         obj.creation_time_unix = prev.creation_time_unix
                         if not preserve_times:
                             obj.last_update_time_unix = int(time.time() * 1000)
-                        self._cleanup_previous(prev)
+                        self._cleanup_previous(prev, key, replaced)
                         inv_items.pop(prev.doc_id, None)
                         doc_puts.pop(prev.doc_id, None)
                         # the earlier version's vector was never device-added,
@@ -390,6 +448,9 @@ class Shard:
                 for _, i in inv_items.values():
                     if errs[i] is None:
                         errs[i] = e
+                lsm.stop()
+                if replaced:
+                    self.vector_index.delete(*replaced)
                 return errs
             for d, (_, i) in inv_items.items():
                 e = inv_errs.get(d)
@@ -398,21 +459,30 @@ class Shard:
                     pos = staged_pos.pop(d, None)
                     if pos is not None:
                         fresh_ids[pos] = -1  # match add_object-failure semantics
-            if any(d >= 0 for d in fresh_ids):
-                keep = [j for j, d in enumerate(fresh_ids) if d >= 0]
-                fresh_ids = [fresh_ids[j] for j in keep]
-                fresh_vecs = [fresh_vecs[j] for j in keep]
-                try:
-                    self.vector_index.add_batch(fresh_ids, np.stack(fresh_vecs))
-                except Exception:
-                    # keep per-object error isolation: retry row-by-row so one
-                    # bad vector doesn't fail the whole batch post-LSM-write
-                    by_doc = {o.doc_id: i for i, o in enumerate(objs)}
-                    for d, v in zip(fresh_ids, fresh_vecs):
-                        try:
-                            self.vector_index.add(d, v)
-                        except Exception as e:
-                            errs[by_doc[d]] = e
+            keep = [j for j, d in enumerate(fresh_ids) if d >= 0]
+            fresh_ids = [fresh_ids[j] for j in keep]
+            fresh_vecs = [fresh_vecs[j] for j in keep]
+            tracing.write_stage("lsm", lsm.stop())
+            if not replaced and not fresh_ids:
+                return errs
+            try:
+                # ONE step of the index: no reader sees the previous
+                # versions gone and the new ones not yet there
+                self.vector_index.replace_batch(
+                    replaced, fresh_ids,
+                    np.stack(fresh_vecs) if fresh_vecs
+                    else np.zeros((0, 0), np.float32))
+            except Exception:
+                # keep per-object error isolation: retry row-by-row so one
+                # bad vector doesn't fail the whole batch post-LSM-write
+                by_doc = {o.doc_id: i for i, o in enumerate(objs)}
+                if replaced:
+                    self.vector_index.delete(*replaced)
+                for d, v in zip(fresh_ids, fresh_vecs):
+                    try:
+                        self.vector_index.add(d, v)
+                    except Exception as e:
+                        errs[by_doc[d]] = e
             return errs
 
     def delete_object(self, uuid: str, deletion_time: Optional[int] = None) -> bool:
@@ -486,9 +556,7 @@ class Shard:
         one multi-get per store (single lock acquisition each), lazy
         decode — the same batched plane the vector path's _hydrate_batch
         uses, shared by BM25 / listing / aggregation hydration."""
-        keys = self.docid_lookup.multi_get(
-            [struct.pack("<Q", int(d)) for d in doc_ids])
-        raws = self.objects.multi_get(keys)
+        raws = self.objects.multi_get(self._uuid_keys(doc_ids))
         return [StorObj.from_binary(r, include_vector) if r is not None else None
                 for r in raws]
 
@@ -1218,17 +1286,19 @@ class Shard:
 
     def raw_plane_ready(self) -> bool:
         """Cheap pre-check for the raw serving lane, BEFORE any device work:
-        the packed native plane serves exactly only when both point-get
-        buckets are segment-resident (empty memtables) and the native
-        library loads — checked first so an ineligible batch never runs the
-        device kNN twice (once here, once on the general path)."""
+        the packed native plane serves when both point-get buckets have
+        segments and the native library loads — checked first so an
+        ineligible batch never runs the device kNN twice (once here, once
+        on the general path). A memtable that a writer keeps non-empty
+        does not close the lane: `Bucket.multi_get_packed` lays the
+        memtable's word over the segments' answer."""
         from weaviate_tpu.storage import lsm_native
 
         if not lsm_native.available():
             return False
         for b in (self.docid_lookup, self.objects):
             with b._lock:
-                if len(b._mem) or not b._segments:
+                if not b._segments:
                     return False
         return True
 
@@ -1286,7 +1356,17 @@ class Shard:
         r1 = self.docid_lookup.multi_get_packed(flat_ids.tobytes(), key_offs)
         if r1 is None:
             return None
-        ubuf, uoffs, _ = r1
+        ubuf, uoffs, uflags = r1
+        if self._replaced and not uflags.all():
+            # a doc id that a re-put replaced after the search was
+            # dispatched: the object it was a version of (`_uuid_keys`)
+            newer = {}
+            for i in np.flatnonzero(uflags == 0).tolist():
+                hit = self._replaced.get(int(flat_ids[i]))
+                if hit is not None:
+                    newer[i] = hit[0]
+            if newer:
+                ubuf, uoffs, _ = lsm.overlay_packed(r1, newer)
         r2 = self.objects.multi_get_packed(ubuf, uoffs)
         if r2 is None:
             return None
@@ -1310,9 +1390,7 @@ class Shard:
         counts = valid.sum(axis=1)
         flat_ids = ids[valid]
         flat_d = dists[valid].tolist()
-        keys = [struct.pack("<Q", int(d)) for d in flat_ids]
-        ukeys = self.docid_lookup.multi_get(keys)
-        raws = self.objects.multi_get(ukeys)
+        raws = self.objects.multi_get(self._uuid_keys(flat_ids))
         name = self.name
         out_all: list[list[SearchResult]] = []
         pos = 0

@@ -71,6 +71,18 @@ class VectorIndex(abc.ABC):
         for d, v in zip(doc_ids, vectors):
             self.add(int(d), v)
 
+    def replace_batch(self, old_doc_ids: Sequence[int],
+                      doc_ids: Sequence[int], vectors: np.ndarray) -> None:
+        """An upsert's two halves in one call: drop `old_doc_ids` (the
+        previous versions, under the doc ids they were stored with) and add
+        the batch. An index whose readers could see the gap between the two
+        overrides it to make them one step (index/tpu.py); here they are
+        the two calls they always were."""
+        if len(old_doc_ids):
+            self.delete(*old_doc_ids)
+        if len(doc_ids):
+            self.add_batch(doc_ids, vectors)
+
     @abc.abstractmethod
     def delete(self, *doc_ids: int) -> None: ...
 

@@ -126,6 +126,12 @@ _LOG_VERSION = 2  # v2 = per-record checksums + skip-ahead corrupt-region replay
 # would hold the corpus four times over on its way in. An uncompressed replay
 # lands its one long run as it always did.
 _REPLAY_RUN_MAX = 8 * _CHUNK
+# a restart rewrites the vector log from its live records when the dead ones
+# (superseded adds, delete records) hold more than this share of the live
+# records' bytes: the reference's commit-log condensor, run where the log is
+# read anyway. Log bytes, and with them the time to restart, then follow the
+# live rows: under 1.25 of them after every restart.
+_LOG_CONDENSE_DEAD = 0.25
 
 # query-batch padding buckets (limit distinct compiled shapes)
 _B_BUCKETS = (1, 4, 16, 64, 256, 1024)
@@ -244,6 +250,27 @@ def _write_doc_pairs(s2d, idx, pairs):
 
 
 @jax.jit
+def _write_slots(store, sq_norms, s2d, tombs, slots, rows, norms, pairs,
+                 dead):
+    """One small write as ONE program: `rows` into store slots `slots`
+    (free slots handed out again and slots past the high-water mark
+    alike), their squared norms (l2; None otherwise), their doc-id words
+    into the slot->doc table, the slots' tombstone bits cleared and the
+    bits of `dead` set. Every index array is padded to a `_bucket_rows`
+    width with an out-of-range sentinel that mode="drop" ignores, so a
+    batch of 100 uploads 128 rows, not a `_CHUNK`. Non-donating like every
+    write kernel: a snapshot that pins the previous generation keeps
+    reading the rows, docs and tombstones it was published with."""
+    store = store.at[slots].set(rows.astype(store.dtype), mode="drop")
+    if sq_norms is not None:
+        sq_norms = sq_norms.at[slots].set(norms, mode="drop")
+    s2d = s2d.at[slots].set(pairs, mode="drop")
+    tombs = tombs.at[dead].set(True, mode="drop")
+    tombs = tombs.at[slots].set(False, mode="drop")
+    return store, sq_norms, s2d, tombs
+
+
+@jax.jit
 def _scatter_rows(arr, idx, rows):
     """Scatter padded row runs into a [capacity, d] device table (the
     IVF plane's low-dim PCA rows); idx padded with an out-of-range
@@ -283,6 +310,15 @@ def _grow_store(store, new_cap):
 def _grow_1d(arr, new_cap, fill):
     out = jnp.full((new_cap,), fill, arr.dtype)
     return jax.lax.dynamic_update_slice(out, arr, (0,))
+
+
+def _doc_pairs(docs: np.ndarray, pad: int) -> np.ndarray:
+    """[pad, 2] uint32: the lo/hi words of each int64 doc id, as the device
+    slot->doc table holds them; rows past `len(docs)` are padding."""
+    pairs = np.zeros((pad, 2), dtype=np.uint32)
+    pairs[: len(docs)] = np.ascontiguousarray(
+        docs.astype("<i8")).view("<u4").reshape(len(docs), 2)
+    return pairs
 
 
 def _pack(top: jax.Array, idx: jax.Array) -> jax.Array:
@@ -780,7 +816,12 @@ def _prep_bulk_run(ids: np.ndarray, vecs: np.ndarray, metric: str, known_fn):
     if metric == vi.DISTANCE_COSINE:
         nrm = np.linalg.norm(vecs, axis=1, keepdims=True)
         nrm[nrm == 0] = 1.0
-        vecs = vecs / nrm
+        if vecs.flags.writeable:
+            # the replay's own copy of the run (`_replay_v2`): divided where
+            # it lies, not into a second array the size of the corpus
+            vecs /= nrm
+        else:
+            vecs = vecs / nrm
     ids64 = ids.astype(np.int64)
     if len(np.unique(ids64)) != len(ids64):
         # keep-last within the run (later records overwrite earlier)
@@ -867,6 +908,10 @@ class VectorLog:
         if fresh:
             self._f.write(_LOG_MAGIC + struct.pack("<H", _LOG_VERSION))
             self._f.flush()
+        # the file's bytes, and the records in it (a restore that replays
+        # the file says how many it found; appends count themselves)
+        self.bytes = os.path.getsize(path)
+        self.records = 0
 
     @staticmethod
     def report_replay_stats(path: str, stats: dict) -> None:
@@ -1028,13 +1073,19 @@ class VectorLog:
 
     # -- appends -------------------------------------------------------------
 
+    def _write(self, data: bytes, records: int) -> None:
+        self._f.write(data)
+        self.bytes += len(data)
+        self.records += records
+
     def append_add(self, doc_id: int, vector: np.ndarray) -> None:
         v = np.ascontiguousarray(vector, dtype=np.float32)
-        self._f.write(self._enc_add(doc_id, v))
+        self._write(self._enc_add(doc_id, v), 1)
 
-    def append_add_batch(self, doc_ids: np.ndarray, vectors: np.ndarray) -> None:
-        """Vectorized bulk append: one write() for the whole batch, with the
-        per-record checksums computed as two numpy row-sums."""
+    @staticmethod
+    def _enc_add_batch(doc_ids: np.ndarray, vectors: np.ndarray) -> bytes:
+        """A run of add records, the per-record checksums computed as two
+        numpy row-sums."""
         n, dim = vectors.shape
         rec_len = 17 + 4 * dim
         buf = np.zeros((n, rec_len), np.uint8)
@@ -1044,11 +1095,15 @@ class VectorLog:
         buf[:, 17:] = np.ascontiguousarray(vectors, dtype="<f4").view(np.uint8).reshape(n, 4 * dim)
         sums = buf[:, :13].sum(axis=1, dtype=np.uint64) + buf[:, 17:].sum(axis=1, dtype=np.uint64)
         buf[:, 13:17] = (sums & 0xFFFFFFFF).astype("<u4").view(np.uint8).reshape(n, 4)
-        self._f.write(buf.tobytes())
+        return buf.tobytes()
+
+    def append_add_batch(self, doc_ids: np.ndarray, vectors: np.ndarray) -> None:
+        """Vectorized bulk append: one write() for the whole batch."""
+        self._write(self._enc_add_batch(doc_ids, vectors), len(doc_ids))
 
     def append_delete(self, doc_id: int) -> None:
         head = struct.pack("<BQ", _LOG_DELETE, doc_id)
-        self._f.write(head + struct.pack("<I", self._sum32(head)))
+        self._write(head + struct.pack("<I", self._sum32(head)), 1)
 
     def flush(self) -> None:
         self._f.flush()
@@ -1191,16 +1246,28 @@ class VectorLog:
                     if off is None:
                         return
                     continue
-                view = buf[off : off + max_run * rec].reshape(max_run, rec)
-                ok = view[:, 0] == _LOG_ADD
+                # how far the run of same-width add records goes, by their
+                # op and dim bytes alone and in growing probes: a log of
+                # re-puts is thousands of short runs between deletes, and a
+                # checksum of everything that FOLLOWS each of them made its
+                # replay quadratic in the writes
                 dim_b = np.frombuffer(struct.pack("<I", dim), np.uint8)
-                ok &= (view[:, 9:13] == dim_b).all(axis=1)
+                probe = min(max_run, 4096)
+                while True:
+                    view = buf[off : off + probe * rec].reshape(probe, rec)
+                    ok = view[:, 0] == _LOG_ADD
+                    ok &= (view[:, 9:13] == dim_b).all(axis=1)
+                    if probe == max_run or not bool(ok.all()):
+                        break
+                    probe = min(max_run, probe * 8)
+                if not bool(ok.all()):
+                    view = view[: int(np.argmin(ok))]
                 sums = view[:, :13].sum(axis=1, dtype=np.uint64) + view[:, 17:].sum(
                     axis=1, dtype=np.uint64
                 )
                 stored = np.ascontiguousarray(view[:, 13:17]).view("<u4").ravel()
-                ok &= (sums & 0xFFFFFFFF) == stored
-                run = max_run if bool(ok.all()) else int(np.argmin(ok))
+                ok = (sums & 0xFFFFFFFF) == stored
+                run = len(view) if bool(ok.all()) else int(np.argmin(ok))
                 if run == 0:  # first record is corrupt — resync
                     off = _skip(off)
                     if off is None:
@@ -1231,17 +1298,74 @@ class VectorLog:
 
     def rewrite(self, entries) -> None:
         """Condense: atomically rewrite the log with only live entries."""
+        self._rewrite(
+            (self._enc_add(d, np.ascontiguousarray(v, np.float32)), 1)
+            for d, v in entries)
+
+    def rewrite_runs(self, runs) -> None:
+        """`rewrite` from runs of (doc ids [n], vectors [n, dim]), encoded a
+        `_REPLAY_RUN_MAX` of records at a time."""
+        self._rewrite(
+            (self._enc_add_batch(ids[off : off + _REPLAY_RUN_MAX],
+                                 vecs[off : off + _REPLAY_RUN_MAX]),
+             min(_REPLAY_RUN_MAX, len(ids) - off))
+            for ids, vecs in runs
+            for off in range(0, len(ids), _REPLAY_RUN_MAX))
+
+    def _rewrite(self, encoded) -> None:
+        """`encoded`: (bytes of whole records, how many) pairs."""
         tmp = self.path + ".tmp"
+        size, records = 6, 0
         with open(tmp, "wb") as f:
             f.write(_LOG_MAGIC + struct.pack("<H", _LOG_VERSION))
-            for doc_id, vec in entries:
-                v = np.ascontiguousarray(vec, dtype=np.float32)
-                f.write(self._enc_add(doc_id, v))
+            for data, count in encoded:
+                f.write(data)
+                size += len(data)
+                records += count
             f.flush()
             os.fsync(f.fileno())
         self._f.close()
         os.replace(tmp, self.path)
         self._f = open(self.path, "ab")
+        self.bytes, self.records = size, records
+
+
+def _live_runs(events, stats: dict):
+    """A replay (`VectorLog.replay_batches`) -> its add runs less every
+    record that is dead at the log's end: an add whose doc a later record
+    deletes or adds again. No delete is yielded: what it deleted is not.
+    A restore then lands the live rows and nothing else, however many
+    writes the log has seen. `stats` gets `records` and `dead_records`.
+    The replay is read to its end first (the runs are views and copies of
+    a file that is in memory whole either way)."""
+    events = list(events)
+    docs, adds, pos = [], [], 0
+    for op, ids, _ in events:
+        if op == "add":
+            docs.append(np.asarray(ids).astype(np.int64))
+            adds.append(np.ones(len(ids), bool))
+        else:
+            docs.append(np.array([ids], np.uint64).astype(np.int64))
+            adds.append(np.zeros(1, bool))
+    stats["records"] = stats["dead_records"] = 0
+    if not docs:
+        return []
+    doc, is_add = np.concatenate(docs), np.concatenate(adds)
+    # the last record of every doc, by a stable sort on the doc id
+    order = np.argsort(doc, kind="stable")
+    last = order[np.r_[doc[order][1:] != doc[order][:-1], True]]
+    live = np.zeros(len(doc), bool)
+    live[last[is_add[last]]] = True
+    stats["records"] = int(len(doc))
+    stats["dead_records"] = int(len(doc) - live.sum())
+    out = []
+    for op, ids, vecs in events:
+        width = len(ids) if op == "add" else 1
+        keep, pos = live[pos : pos + width], pos + width
+        if op != "add" or not keep.any():
+            continue
+        out.append((ids, vecs) if keep.all() else (ids[keep], vecs[keep]))
+    return out
 
 
 class IndexSnapshot:
@@ -1420,6 +1544,16 @@ class TpuVectorIndex(VectorIndex):
         # staging buffer keyed by doc_id: a re-add of a staged doc replaces it
         self._pending: dict[int, np.ndarray] = {}
         self._pending_tombs: list[int] = []
+        # dead slots whose tombstone bit is set on the device, handed to
+        # the next rows to land before `n` grows (`_place_rows`): a flat
+        # slab has no graph edges to repair, so the reference's tombstone
+        # clean-up reduces to giving the slot away. Slots, capacity and the
+        # scan's length then follow the live rows, not the writes ever made.
+        self._free_slots: list[int] = []
+        # what the write path did, lifetime (health(); the perf window
+        # keeps the same counts a window: monitoring/perf.py `writes`)
+        self._wstats = {"slots_reused": 0, "slots_appended": 0,
+                        "tombstones_applied": 0, "grows": 0}
         # PQ state (compress.go analog): when compressed, the device holds
         # [cap, M] uint8/16 codes instead of floats; full-precision rows move
         # to host RAM for the rescoring pass
@@ -1554,10 +1688,13 @@ class TpuVectorIndex(VectorIndex):
         device, which beats persisting them."""
         self._restoring = True
         replay_stats: dict = {}
+        # the log's records, those of them dead at its end, and the bytes
+        # the condensor took out (`_live_events`)
+        log_stats: dict = {}
         with tracing.stage("vector.restore", shard=self.shard_name) as st:
             sums = self._restore_sums = tracing.StageSums()
             try:
-                self._replay_log(sums, replay_stats)
+                self._replay_log(sums, replay_stats, log_stats)
                 if self.compressed:
                     with tracing.piece_of(sums, "flush", self.capacity):
                         self._publish_snapshot()
@@ -1574,26 +1711,36 @@ class TpuVectorIndex(VectorIndex):
             "compressed" if self.compressed else "uncompressed", self.n, st,
             sums, replay_stats,
             # restore runs in the constructor: the lifetime count is its own
-            chunks_encoded=self._chunks_encoded)
+            chunks_encoded=self._chunks_encoded, log=log_stats)
         if self.n:
             incidents.emit("write_phase", scope="restore",
                            **self.last_restore)
 
-    def _replay_log(self, sums, replay_stats: dict) -> None:
+    def _replay_log(self, sums, replay_stats: dict, log_stats: dict) -> None:
         """The replay half of `_restore`: the codebook, the log's runs, the
         last flush. `_restoring` holds from the caller until this
         returns."""
         try:
             self._pending_pq = self._load_persisted_pq()
             sums.enter("stage")
-            for op, ids, vecs in sums.timed(VectorLog.replay_batches(
-                    self._log.path, stats=replay_stats,
-                    run_max=_REPLAY_RUN_MAX if self._pending_pq else None,
-                    sums=sums), "log.parse"):
+            events = VectorLog.replay_batches(
+                self._log.path, stats=replay_stats,
+                run_max=_REPLAY_RUN_MAX if self._pending_pq else None,
+                sums=sums)
+            if self._pending_pq is None:
+                # the log's live records alone, and a log that holds much
+                # else rewritten from them before they land
+                events = self._live_events(events, log_stats)
+            records = 0
+            for op, ids, vecs in sums.timed(events, "log.parse"):
                 if op == "add":
+                    records += len(ids)
                     self._bulk_stage_add(ids, vecs)
                 else:
+                    records += 1
                     self._stage_delete(int(ids), log=False)
+            if "records" not in log_stats:
+                self._log.records = log_stats["records"] = records
             sums.leave(self.capacity)
             VectorLog.report_replay_stats(self._log.path, replay_stats)
             if os.path.exists(self._pq_path):
@@ -1609,6 +1756,22 @@ class TpuVectorIndex(VectorIndex):
         finally:
             self._restoring = False
             self._pending_pq = None
+
+    def _live_events(self, events, stats: dict):
+        """The replay's live add runs (`_live_runs`), after the condensor:
+        a log whose dead records hold more than `_LOG_CONDENSE_DEAD` of
+        its live records' bytes is rewritten from the live runs first."""
+        runs = _live_runs(events, stats)
+        log = self._log
+        log.records = stats["records"]
+        live_bytes = sum(len(ids) * (17 + 4 * vecs.shape[1])
+                         for ids, vecs in runs)
+        dead_bytes = log.bytes - 6 - live_bytes
+        if dead_bytes > _LOG_CONDENSE_DEAD * live_bytes:
+            log.rewrite_runs(runs)
+            stats["condensed_bytes"] = int(dead_bytes)
+        for ids, vecs in runs:
+            yield "add", ids, vecs
 
     def _restored_arrays(self) -> list:
         """The device arrays a restore's write programs produce."""
@@ -1740,6 +1903,8 @@ class TpuVectorIndex(VectorIndex):
             ht[: self.capacity] = self._host_tombs
             self._host_tombs = ht
             self.capacity = cap
+            self._wstats["grows"] += 1
+            self._note_write(grows=1, slab_bytes_copied=self._slab_bytes())
             led = memory.get_ledger()
             if led is not None:
                 led.note_write_shape(
@@ -1755,6 +1920,11 @@ class TpuVectorIndex(VectorIndex):
                               rows=rows.shape[0]):
             self._land_rows(rows, start)
             self._ivf_on_rows_written(rows, start)
+        chunks = -(-rows.shape[0] // _CHUNK)
+        # every chunk is a whole `_CHUNK` uploaded and a new generation of
+        # each array it lands in
+        self._note_write(upload_bytes=chunks * _CHUNK * self.dim * 4,
+                        slab_bytes_copied=chunks * self._slab_bytes())
         led = memory.get_ledger()
         if led is not None:
             led.note_write_shape(
@@ -1932,25 +2102,24 @@ class TpuVectorIndex(VectorIndex):
         pad = _bucket_rows(count)
         idx = np.full(pad, self.capacity + 1, dtype=np.int32)
         idx[:count] = np.arange(start, start + count, dtype=np.int32)
-        pairs = np.zeros((pad, 2), dtype=np.uint32)
-        pairs[:count] = np.ascontiguousarray(
-            docs.astype("<i8")).view("<u4").reshape(count, 2)
         self._s2d_dev = _write_doc_pairs(
-            self._s2d_dev, jnp.asarray(idx), jnp.asarray(pairs))
+            self._s2d_dev, jnp.asarray(idx),
+            jnp.asarray(_doc_pairs(docs, pad)))
         led = memory.get_ledger()
         if led is not None:
             led.note_write_shape(("write_docs", self.capacity, pad))
         self._stamp_memory()
 
-    def _cow_host_state(self) -> None:
+    def _cow_host_state(self, rewrites_slots: bool = False) -> None:
         """Copy-on-write the host mirrors a published snapshot still pins,
         so in-place writer mutation can never tear a lock-free reader.
-        Only `host_tombs` needs the copy (deletes flip bits at arbitrary
-        live slots); `slot_to_doc` is append-only between compactions —
-        writers assign only at indices >= every published snapshot's `n`,
-        so the `[:n]` prefix a snapshot reads is immutable in place and
-        the per-flush O(capacity) copy the fused-dispatch PR deleted was
-        pure overhead."""
+        `host_tombs` always (deletes flip bits at arbitrary live slots).
+        `slot_to_doc` only where this write hands out a slot again
+        (`rewrites_slots`): an append assigns at indices >= every published
+        snapshot's `n`, so the `[:n]` prefix a snapshot reads is immutable
+        in place and the per-flush O(capacity) copy would be pure overhead;
+        a reused slot lies INSIDE that prefix, and the snapshot that still
+        holds the old row there must keep reading the old doc id."""
         snap = self._snap
         if snap is None:
             return
@@ -1958,59 +2127,200 @@ class TpuVectorIndex(VectorIndex):
         if snap.host_tombs is self._host_tombs:
             self._host_tombs = self._host_tombs.copy()
             copied += int(self._host_tombs.nbytes)
+        if rewrites_slots and snap.slot_to_doc is self._slot_to_doc:
+            self._slot_to_doc = self._slot_to_doc.copy()
+            copied += int(self._slot_to_doc.nbytes)
         if copied:
             led = memory.get_ledger()
             if led is not None:
                 led.note_cow(copied)
 
+    # -- landing rows: reused slots first, then the high-water mark ----------
+
+    def _note_write(self, **counts) -> None:
+        """Add to `/debug/perf` `writes` (monitoring/perf.py
+        WRITE_COUNTERS): what SERVING writes did. A restore lands the
+        corpus through the same code and is the restart timeline's."""
+        if not self._restoring:
+            perf.note_write(**counts)
+
+    def _reuse_refused(self) -> Optional[str]:
+        """Why this index never hands a dead slot to the next row (None: it
+        does). The compressed branch lands rows through the codebook in
+        whole chunks at an offset, and an IVF layout's buckets name the
+        partition of the slot's OLD row: both keep appending, tombstones
+        staying until `compact()`, and say so in `health()`."""
+        if self.compressed:
+            return "compressed"
+        if self._ivf_centroids_host is not None:
+            return "ivf_layout"
+        return None
+
+    def _slab_bytes(self) -> int:
+        """Bytes of the arrays a non-donating row write makes anew."""
+        if self.compressed:
+            return (memory.array_bytes(self._codes)
+                    + memory.array_bytes(self._recon_norms)
+                    + memory.array_bytes(self._codes4)
+                    + memory.array_bytes(self._recon_norms4)
+                    + memory.array_bytes(self._rescore_dev)
+                    + memory.array_bytes(self._rescore_sq_norms))
+        return memory.array_bytes(self._store) + (
+            memory.array_bytes(self._sq_norms)
+            if self.metric == vi.DISTANCE_L2 else 0)
+
+    def _place_rows(self, docs: np.ndarray, rows: np.ndarray) -> None:
+        """Give [count, D] rows (normalised, logged, none of them known to
+        the index) their slots and land them: the one way in for a flush
+        and for a fresh batch; the caller holds the lock. Dead slots go
+        first: those of the tombstones staged WITH this write (an upsert's
+        old version: the new row overwrites it in place and the tombstone
+        bit is never set), then the free list; the rest is appended at `n`.
+        Rows for handed-out slots, and an appended run shorter than a
+        `_CHUNK`, are ONE `_write_slots` program a `_CHUNK` of rows, with
+        whatever tombstones are still staged; a longer appended run takes
+        the chunked `_write_block` (the build's and the restore's programs,
+        unchanged). What a published snapshot pins is never written: the
+        device arrays are functional updates and the host mirrors are
+        copied first where the snapshot shares them."""
+        count = len(docs)
+        dead, free = self._pending_tombs, self._free_slots
+        take_dead = take_free = 0
+        if self._reuse_refused() is None:
+            take_dead = min(count, len(dead))
+            take_free = min(count - take_dead, len(free))
+        reused = take_dead + take_free
+        slots = np.empty(reused, np.int64)
+        if take_dead:
+            slots[:take_dead] = dead[len(dead) - take_dead:]
+            del dead[len(dead) - take_dead:]
+        if take_free:
+            slots[take_dead:] = free[len(free) - take_free:]
+            del free[len(free) - take_free:]
+        rest = count - reused
+        small = rest if (not self.compressed and rest < _CHUNK) else 0
+        self._cow_host_state(rewrites_slots=reused > 0)
+        if reused:
+            # the slot layout under a cached filter changed without `n`
+            # moving: no packed-words or slot-list cache keyed on the old
+            # layout may be served again
+            self._allow_token = object()
+            self._docs_ascending = False
+            self._wstats["slots_reused"] += reused
+        if reused + small:
+            self._ensure_capacity(self.n + small)
+            head = reused + small
+            at = np.concatenate(
+                [slots, np.arange(self.n, self.n + small, dtype=np.int64)])
+            if small:
+                if not reused:
+                    self._note_docs_appended(docs[:head])
+                self._ivf_on_rows_written(rows[reused:head], self.n)
+            self._write_small(docs[:head], rows[:head], at,
+                              cleared=slots[take_dead:])
+            self.n += small
+        if rest - small:
+            tail_docs, tail = docs[reused + small:], rows[reused + small:]
+            self._ensure_capacity(self.n + len(tail_docs) + _CHUNK)
+            self._write_block(np.ascontiguousarray(tail), self.n)
+            self._note_docs_appended(tail_docs)
+            self._slot_to_doc[self.n : self.n + len(tail_docs)] = tail_docs
+            self._stage_doc_ids(tail_docs, self.n)
+            self._doc_to_slot.update(zip(
+                tail_docs.tolist(),
+                range(self.n, self.n + len(tail_docs))))
+            self.n += len(tail_docs)
+        self._wstats["slots_appended"] += rest
+        self._note_write(slots_reused=reused, slots_appended=rest)
+
+    def _write_small(self, docs: np.ndarray, rows: np.ndarray,
+                     slots: np.ndarray, cleared: np.ndarray) -> None:
+        """`_write_slots` over rows for arbitrary slots, a `_CHUNK` at a
+        time, padded to the `_bucket_rows` widths; the tombstones still
+        staged ride the first program. `cleared`: the slots among them
+        that come from the free list (their tombstone bit is set)."""
+        t0 = time.perf_counter()
+        l2 = self.metric == vi.DISTANCE_L2
+        sentinel = self.capacity + 1     # out of range: mode="drop"
+        dead = np.asarray(self._pending_tombs, np.int64)
+        self._pending_tombs.clear()
+        for off in range(0, len(slots), _CHUNK):
+            sl = slots[off : off + _CHUNK]
+            count, pad = len(sl), _bucket_rows(len(sl))
+            idx = np.full(pad, sentinel, np.int32)
+            idx[:count] = sl
+            buf = np.zeros((pad, self.dim), np.float32)
+            buf[:count] = rows[off : off + count]
+            pairs = _doc_pairs(docs[off : off + count], pad)
+            norms = None
+            if l2:
+                norms = np.zeros(pad, np.float32)
+                norms[:count] = np.einsum(
+                    "ij,ij->i", buf[:count], buf[:count], dtype=np.float64)  # graftlint: disable=JGL006 host-side numpy norms: f64 accumulation without a full f64 temp, cast to f32 before the upload (the einsum idiom of `_land_rows`)
+            d = dead if off == 0 else dead[:0]
+            didx = np.full(_bucket_rows(len(d)), sentinel, np.int32)
+            didx[: len(d)] = d
+            store, sq_norms, self._s2d_dev, self._tombs = _write_slots(
+                self._store, self._sq_norms if l2 else None, self._s2d_dev,
+                self._tombs, jnp.asarray(idx), jnp.asarray(buf),
+                None if norms is None else jnp.asarray(norms),
+                jnp.asarray(pairs), jnp.asarray(didx))
+            self._store = store
+            if l2:
+                self._sq_norms = sq_norms
+            self._note_write(
+                upload_bytes=buf.nbytes + pairs.nbytes + idx.nbytes
+                + didx.nbytes + (norms.nbytes if l2 else 0),
+                slab_bytes_copied=self._slab_bytes()
+                + memory.array_bytes(self._s2d_dev)
+                + memory.array_bytes(self._tombs))
+            led = memory.get_ledger()
+            if led is not None:
+                led.note_write_shape(
+                    ("write_slots", self.capacity, self.dim, pad, len(didx)))
+        self._slot_to_doc[slots] = docs
+        self._host_tombs[cleared] = False
+        self._doc_to_slot.update(zip(docs.tolist(), slots.tolist()))
+        self._tombstones_applied(dead, t0)
+        self._stamp_memory()
+
+    def _tombstones_applied(self, dead: np.ndarray, t0: float) -> None:
+        """Tombstones whose device bit was just set by a program enqueued
+        since `t0`: the host mirror, the free list, the counts."""
+        if len(dead) == 0:
+            return
+        self._host_tombs[dead] = True
+        self._free_slots.extend(dead.tolist())
+        self._wstats["tombstones_applied"] += len(dead)
+        self._note_write(tombstones_applied=len(dead))
+        self._obs_index("delete", "apply_tombstones", t0, ops=len(dead))
+        led = memory.get_ledger()
+        if led is not None:
+            led.note_write(
+                "delete", "apply_tombstones",
+                (time.perf_counter() - t0) * 1000.0, rows=len(dead))
+
     def _flush_pending(self) -> None:
         flushed = bool(self._pending or self._pending_tombs)
         led = memory.get_ledger()
-        if flushed:
-            self._cow_host_state()
         if self._pending:
             t0 = time.perf_counter()
             rows = np.stack(list(self._pending.values()))
             docs = np.array(list(self._pending.keys()), dtype=np.int64)
             count = rows.shape[0]
-            self._ensure_capacity(self.n + count)
             if led is not None:
                 # the non-donating write pass transiently holds BOTH the
                 # old and new buffer generations (the snapshot-isolation
                 # trade) — record the per-flush peak
                 led.note_cow(0, transient_peak=self._write_transient_bytes())
-            # chunked writes pad the tail; capacity is padded in _CHUNK
-            # multiples beyond need so padding only lands in unused slots
-            self._write_block(rows, self.n)
-            self._note_docs_appended(docs)
-            self._slot_to_doc[self.n : self.n + count] = docs
-            self._stage_doc_ids(docs, self.n)
-            for i, d in enumerate(docs):
-                self._doc_to_slot[int(d)] = self.n + i
-            self.n += count
             self._pending.clear()
+            self._place_rows(docs, rows)
             self._obs_index("add", "flush", t0, ops=count)
             if led is not None:
                 led.note_write(
                     "add", "flush", (time.perf_counter() - t0) * 1000.0,
                     rows=count, bytes_moved=count * (self.dim or 0) * 4)
-        if self._pending_tombs:
-            t0 = time.perf_counter()
-            idx = np.array(self._pending_tombs, dtype=np.int32)
-            pad = _bucket_rows(len(idx))
-            padded = np.full(pad, self.capacity + 1, dtype=np.int32)
-            padded[: len(idx)] = idx
-            self._tombs = _set_tombstones(self._tombs, jnp.asarray(padded))
-            self._host_tombs[idx] = True
-            self._obs_index("delete", "apply_tombstones", t0,
-                            ops=len(self._pending_tombs))
-            if led is not None:
-                led.note_write(
-                    "delete", "apply_tombstones",
-                    (time.perf_counter() - t0) * 1000.0,
-                    rows=len(self._pending_tombs))
-                led.note_write_shape(("set_tombstones", self.capacity, pad))
-            self._pending_tombs.clear()
+        self._apply_pending_tombs()
         if flushed:
             # gauges refresh only when state changed: _flush_pending runs at
             # the top of every search and must stay free on the hot path
@@ -2026,6 +2336,26 @@ class TpuVectorIndex(VectorIndex):
             # a third generation of the slab beside the two a write holds
             # (its end publishes)
             self._publish_snapshot()
+
+    def _apply_pending_tombs(self) -> None:
+        """Set the device bit of the tombstones no row of this write took
+        the slot of (a delete without a re-put, an index that refuses
+        reuse, a row run that went the chunked way)."""
+        if not self._pending_tombs:
+            return
+        t0 = time.perf_counter()
+        self._cow_host_state()
+        idx = np.array(self._pending_tombs, dtype=np.int32)
+        self._pending_tombs.clear()
+        pad = _bucket_rows(len(idx))
+        padded = np.full(pad, self.capacity + 1, dtype=np.int32)
+        padded[: len(idx)] = idx
+        self._tombs = _set_tombstones(self._tombs, jnp.asarray(padded))
+        self._tombstones_applied(idx.astype(np.int64), t0)
+        led = memory.get_ledger()
+        if led is not None:
+            led.note_write_shape(("set_tombstones", self.capacity, pad))
+        self._stamp_memory()
 
     def _maybe_declared_compress(self) -> None:
         # pq.enabled set at class creation: compress once the rows the
@@ -2364,17 +2694,12 @@ class TpuVectorIndex(VectorIndex):
                + memory.array_bytes(self._ivf_buckets)
                + memory.array_bytes(self._ivf_centroids)
                + memory.array_bytes(self._ivf_pca_proj))
-        if self.compressed:
-            return (memory.array_bytes(self._codes)
-                    + memory.array_bytes(self._recon_norms)
-                    + memory.array_bytes(self._codes4)
-                    + memory.array_bytes(self._recon_norms4)
-                    + memory.array_bytes(self._rescore_dev)
-                    + memory.array_bytes(self._rescore_sq_norms)
-                    + memory.array_bytes(self._s2d_dev) + ivf)
-        return (memory.array_bytes(self._store)
-                + memory.array_bytes(self._sq_norms)
-                + memory.array_bytes(self._s2d_dev) + ivf)
+        # the rows' own arrays (`_slab_bytes`: the float slab and, for l2,
+        # its norms; compressed: codes, norms and the bf16 copy), the
+        # slot->doc table and the tombstone mask: what `_write_slots` (or
+        # the chunked write and its doc-id scatter) makes anew
+        return (self._slab_bytes() + memory.array_bytes(self._s2d_dev)
+                + memory.array_bytes(self._tombs) + ivf)
 
     # -- snapshot publication / lock-free reads ------------------------------
 
@@ -2392,6 +2717,7 @@ class TpuVectorIndex(VectorIndex):
         self._snap_gen += 1
         self._snap = IndexSnapshot(self._snap_gen, self)
         self._published_gen = self._staged_gen
+        self._note_write(snapshots_published=1)
         m = self.metrics
         if m is not None:
             cls, shard = self._metric_labels()
@@ -2422,6 +2748,7 @@ class TpuVectorIndex(VectorIndex):
                 self._publish_snapshot()
             snap = self._snap
         self._read_local.lock_wait_ms = wait_ms
+        perf.note_read_lock_wait(wait_ms)
         m = self.metrics
         if m is not None:
             cls, shard = self._metric_labels()
@@ -2620,58 +2947,84 @@ class TpuVectorIndex(VectorIndex):
         """Bulk import. Fresh doc_ids take a fully-vectorized path (the common
         batch-import case, shard_write_batch_objects.go); doc_ids that collide
         with existing/staged entries fall back to per-row staging."""
+        self.replace_batch((), doc_ids, vectors)
+
+    def replace_batch(self, old_doc_ids: Sequence[int],
+                      doc_ids: Sequence[int], vectors: np.ndarray) -> None:
+        """Delete `old_doc_ids` and add the batch under ONE hold of the
+        index lock, published as ONE snapshot: a reader sees every row of
+        an upsert in its old version or in its new one, never neither and
+        never both (`db/shard.py put_batch` gives a re-put a fresh doc id
+        and hands the old ones over here). The old slots are the first the
+        new rows take (`_place_rows`). The write's phases are those of
+        `/debug/perf` `writes`: `index_lock_wait`, `index` (collision
+        check, log append, slots), `device_write` (the write programs
+        enqueued), `publish`."""
         doc_arr = np.asarray(doc_ids, dtype=np.int64)
         vectors = np.asarray(vectors, dtype=np.float32)
-        with self._lock:
-            if self._doc_to_slot:
-                existing = np.fromiter(self._doc_to_slot.keys(), dtype=np.int64)
-                collides = bool(np.isin(doc_arr, existing).any())
-            else:
-                collides = False
-            fresh = (
-                not self._pending
-                and not collides
-                and np.unique(doc_arr).size == doc_arr.size
-            )
-            if not fresh or vectors.ndim != 2:
-                for d, v in zip(doc_arr, vectors):
-                    self._stage_add(int(d), v)
-                return
-            if self.metric == vi.DISTANCE_COSINE:
-                norms = np.linalg.norm(vectors, axis=1, keepdims=True)
-                norms[norms == 0] = 1.0
-                vectors = vectors / norms
-            if self.dim is None:
-                self._init_device(int(vectors.shape[1]))
-            elif vectors.shape[1] != self.dim:
-                raise ValueError(f"dim mismatch: index has {self.dim}, got {vectors.shape[1]}")
-            if self._log is not None:
-                self._log.append_add_batch(doc_arr, vectors)
-            t0 = time.perf_counter()
-            count = vectors.shape[0]
-            self._staged_gen += 1
-            self._mark_staged()
-            self._ensure_capacity(self.n + count + _CHUNK)
-            self._cow_host_state()
-            self._write_block(vectors, self.n)
-            self._note_docs_appended(doc_arr)
-            self._slot_to_doc[self.n : self.n + count] = doc_arr
-            self._stage_doc_ids(doc_arr, self.n)
-            new_slots = dict(zip(doc_arr.tolist(), range(self.n, self.n + count)))
-            self._doc_to_slot.update(new_slots)
-            self.n += count
-            self.live += count
-            self._obs_index("add", "device_write", t0, ops=count)
-            led = memory.get_ledger()
-            if led is not None:
-                led.note_write(
-                    "add", "device_write",
-                    (time.perf_counter() - t0) * 1000.0,
-                    rows=count, bytes_moved=count * self.dim * 4)
-            self._update_index_gauges()
-            self._maybe_declared_compress()
-            self._maybe_ivf_train()
+        wait = tracing.Stopwatch("write.index_lock_wait")
+        self._lock.acquire()
+        wait.stop()
+        try:
+            with tracing.Stopwatch("write.index") as held:
+                dev, pub = self._replace_locked(old_doc_ids, doc_arr, vectors)
+        finally:
+            self._lock.release()
+        self._note_write(rows=len(doc_arr), batches=1)
+        perf.note_write_phase("index_held", held.ms)
+        tracing.write_stage("index_lock_wait", wait.ms)
+        tracing.write_stage("index", max(held.ms - dev - pub, 0.0))
+        tracing.write_stage("device_write", dev)
+        tracing.write_stage("publish", pub)
+
+    def _replace_locked(self, old_doc_ids, doc_arr: np.ndarray,
+                        vectors: np.ndarray) -> tuple[float, float]:
+        """-> (ms in `device_write`, ms in `publish`)."""
+        for d in old_doc_ids:
+            self._stage_delete(int(d))
+        if len(doc_arr) == 0:
+            return 0.0, 0.0
+        ids = doc_arr.tolist()
+        d2s = self._doc_to_slot
+        # O(batch): dict membership, never a pass over the live docs
+        fresh = (not self._pending and len(set(ids)) == len(ids)
+                 and not any(d in d2s for d in ids))
+        if not fresh or vectors.ndim != 2:
+            for d, v in zip(doc_arr, vectors):
+                self._stage_add(int(d), v)
+            return 0.0, 0.0
+        if self.metric == vi.DISTANCE_COSINE:
+            norms = np.linalg.norm(vectors, axis=1, keepdims=True)
+            norms[norms == 0] = 1.0
+            vectors = vectors / norms
+        if self.dim is None:
+            self._init_device(int(vectors.shape[1]))
+        elif vectors.shape[1] != self.dim:
+            raise ValueError(f"dim mismatch: index has {self.dim}, got {vectors.shape[1]}")
+        if self._log is not None:
+            self._log.append_add_batch(doc_arr, vectors)
+        t0 = time.perf_counter()
+        count = vectors.shape[0]
+        self._staged_gen += 1
+        self._mark_staged()
+        with tracing.Stopwatch("write.device_write", rows=count) as dev:
+            self._place_rows(doc_arr, vectors)
+            self._apply_pending_tombs()
+        self.live += count
+        self._obs_index("add", "device_write", t0, ops=count)
+        led = memory.get_ledger()
+        if led is not None:
+            led.note_cow(0, transient_peak=self._write_transient_bytes())
+            led.note_write(
+                "add", "device_write",
+                (time.perf_counter() - t0) * 1000.0,
+                rows=count, bytes_moved=count * self.dim * 4)
+        self._update_index_gauges()
+        self._maybe_declared_compress()
+        self._maybe_ivf_train()
+        with tracing.Stopwatch("write.publish") as pub:
             self._publish_snapshot()
+        return dev.ms, pub.ms
 
     def delete(self, *doc_ids: int) -> None:
         with self._lock:
@@ -4010,7 +4363,15 @@ class TpuVectorIndex(VectorIndex):
             "slots": n,
             "live": live,
             "tombstones": tombs,
+            # free slots count as tombstones: their bit is set and the
+            # scan still walks them, until the next rows take them
             "tombstone_fraction": round(tombs / n, 4) if n > 0 else 0.0,
+            # tombstoned slots the next rows to land take before `slots`
+            # grows, and why this index hands none out (None: it does)
+            "free_slots": len(self._free_slots),
+            "slot_reuse_refused": self._reuse_refused(),
+            "writes": dict(self._wstats),
+            "log": self._log_health(),
             "pending_adds": len(self._pending),
             "pending_tombstones": len(self._pending_tombs),
             "snapshot_gen": snap.gen if snap is not None else 0,
@@ -4092,6 +4453,18 @@ class TpuVectorIndex(VectorIndex):
                         st["stage3_survivors"] / d, 1),
                 }
         return out
+
+    def _log_health(self) -> Optional[dict]:
+        """The vector log on disk: its bytes, and the records in it that a
+        replay would no longer land (superseded adds, their deletes): what
+        the condensor at restart reads (`_maybe_condense_log`)."""
+        log = self._log
+        if log is None:
+            return None
+        return {"bytes": log.bytes, "records": log.records,
+                "dead_records": max(
+                    log.records - len(self._doc_to_slot) - len(self._pending),
+                    0)}
 
     def search_by_vector(
         self, vector: np.ndarray, k: int, allow_list: Optional[AllowList] = None
@@ -4450,6 +4823,7 @@ class TpuVectorIndex(VectorIndex):
             self.n = 0
             self.live = 0
             self._doc_to_slot.clear()
+            self._free_slots.clear()
             self._store = self._sq_norms = self._tombs = None
             self._s2d_dev = None
             self._row_store_cache = None
@@ -4521,6 +4895,7 @@ class TpuVectorIndex(VectorIndex):
             self._doc_to_slot.clear()
             self._pending.clear()
             self._pending_tombs.clear()
+            self._free_slots.clear()
             self.compressed = False
             self._pq = None
             self._codes = None

@@ -79,6 +79,27 @@ from weaviate_tpu.monitoring import costmodel
 PHASES = ("decode", "queue_wait", "filter", "enqueue", "device",
           "gather_hop", "rescore", "hydrate", "scatter", "encode")
 
+# the write path's own stages, in order (`/debug/perf` `writes.phases`; the
+# ten above and their meaning are the read path's and stay so): `decode`
+# (server/rest.py: JSON to objects), `lsm` (db/shard.py put_batch: objects,
+# doc-id lookup, inverted index), then index/tpu.py replace_batch:
+# `index_lock_wait`, `index` (everything under the index lock but the next
+# two: collision check, log append, slots), `device_write` (the write
+# programs enqueued: the device runs them after) and `publish`. Each is a
+# capture interval too, as `write.<stage>`.
+WRITE_PHASES = ("decode", "lsm", "index_lock_wait", "index", "device_write",
+                "publish")
+# two spans over those stages, a sample a batch each: the whole request
+# (`batch_ms`) and the hold of the index lock, which is what a search that
+# meets the write waits for (`index_held_ms`: index + device_write + publish)
+WRITE_SPANS = ("batch", "index_held")
+# what a write did, summed over the window (`writes`): `slab_bytes_copied`
+# is the bytes of whole-array generations the write programs made (0 for a
+# donated or in-place write), `upload_bytes` what the host handed them
+WRITE_COUNTERS = ("rows", "batches", "slots_reused", "slots_appended",
+                  "tombstones_applied", "slab_bytes_copied", "upload_bytes",
+                  "snapshots_published", "grows")
+
 # intervals one capture keeps; beyond it they are counted as `dropped`
 # (a 5 s capture of the busiest cell closes about 3,000)
 CAPTURE_LOG_MAX = 65536
@@ -199,6 +220,15 @@ class PerfWindow:
         # (t_mono, lists, ids, why numpy built them or None, host ms, pool
         # hits, pool grows) per filtered group's device operands
         self._group_inputs: deque = deque(maxlen=_PHASE_SAMPLES_MAX)
+        # the write path: stage -> deque[(t_mono, ms)], and (t_mono,
+        # {counter: amount}) a call of `note_write`, count-capped likewise
+        self._write_phase: dict[str, deque] = {
+            p: deque(maxlen=_PHASE_SAMPLES_MAX)
+            for p in WRITE_PHASES + WRITE_SPANS}
+        self._write_counts: deque = deque(maxlen=_PHASE_SAMPLES_MAX)
+        # (t_mono, ms) a search that met staged writes and took the index
+        # lock (index/tpu.py `_read_snapshot`'s slow path)
+        self._read_lock_waits: deque = deque(maxlen=_PHASE_SAMPLES_MAX)
         self._duty = DutyCycle(self.window_s)
         self._rows = 0  # running sum over the live window
         self._first_entry: Optional[float] = None
@@ -341,6 +371,29 @@ class PerfWindow:
             while d[0][0] < horizon:
                 d.popleft()
 
+    def _keep(self, d: deque, item) -> None:
+        """Append `(now, item)` to a window deque and drop what left the
+        window."""
+        now = time.monotonic()
+        with self._lock:
+            d.append((now, item))
+            while d[0][0] < now - self.window_s:
+                d.popleft()
+
+    def note_write_phase(self, name: str, ms: float) -> None:
+        """One sample of a stage of the write path (`WRITE_PHASES`) or of
+        a span over them (`WRITE_SPANS`)."""
+        self._keep(self._write_phase[name], float(ms))
+
+    def note_write(self, counts: dict) -> None:
+        """Amounts to add to the window's write counters
+        (`WRITE_COUNTERS`)."""
+        self._keep(self._write_counts, counts)
+
+    def note_read_lock_wait(self, ms: float) -> None:
+        """One search that took the index lock to see staged writes."""
+        self._keep(self._read_lock_waits, float(ms))
+
     def note_interval(self, name: str, start_ns: int, end_ns: int,
                       tid: Optional[int] = None) -> None:
         """One closed host phase on ``time.perf_counter_ns``; `tid` is the
@@ -414,7 +467,8 @@ class PerfWindow:
         while self._entries and self._entries[0][0] < horizon:
             self._rows -= self._entries.popleft()[2]
         for d in (*self._phase.values(), self._point_get, self._rescore,
-                  self._group_inputs):
+                  self._group_inputs, *self._write_phase.values(),
+                  self._write_counts, self._read_lock_waits):
             while d and d[0][0] < horizon:
                 d.popleft()
         while self._postings and self._postings[0][0] < horizon - 1.0:
@@ -463,6 +517,10 @@ class PerfWindow:
             self._rescore.clear()
             self._postings.clear()
             self._group_inputs.clear()
+            for d in self._write_phase.values():
+                d.clear()
+            self._write_counts.clear()
+            self._read_lock_waits.clear()
             self._duty = DutyCycle(self.window_s)
             self._rows = 0
             self._first_entry = None
@@ -499,6 +557,10 @@ class PerfWindow:
                 for walk, calls in e[4].items():
                     walks[walk] = walks.get(walk, 0) + calls
             groups = list(self._group_inputs)
+            write_ms = {p: sorted(ms for _, ms in d)
+                        for p, d in self._write_phase.items() if d}
+            write_counts = [c for _, c in self._write_counts]
+            lock_waits = [ms for _, ms in self._read_lock_waits]
         out: dict = {
             "window_s": self.window_s,
             "observed_s": round(span, 3),
@@ -577,6 +639,27 @@ class PerfWindow:
                 "pool_hits": sum(g[5] for g in groups),
                 "pool_grows": sum(g[6] for g in groups),
             }
+        if write_ms or write_counts:
+            # the write path over the window: its stages a batch, and what
+            # the batches did to the slots, the slab and the log
+            counts = dict.fromkeys(WRITE_COUNTERS, 0)
+            for c in write_counts:
+                for name, amount in c.items():
+                    counts[name] += amount
+            stat = (lambda v: {
+                "samples": len(v), "p50_ms": round(_pct(v, 50.0), 3),
+                "p99_ms": round(_pct(v, 99.0), 3),
+                "mean_ms": round(sum(v) / len(v), 3)})
+            out["writes"] = {
+                **counts,
+                "phases": {p: stat(write_ms[p]) for p in WRITE_PHASES
+                           if p in write_ms},
+                **{p + "_ms": stat(write_ms[p]) for p in WRITE_SPANS
+                   if p in write_ms}}
+        # searches that found staged writes and took the index lock to see
+        # them (the first read after a write), and what they waited there
+        out["read_lock_waits"] = len(lock_waits)
+        out["read_lock_wait_ms_sum"] = round(sum(lock_waits), 3)
         out["tiers"] = dict(sorted(tiers.items(), key=lambda kv: -kv[1]))
         # the store rows each tier's dispatches read over the window: all
         # live rows a scan, the probed rows an IVF dispatch, and for a
@@ -1045,6 +1128,27 @@ def note_phase(name: str, ms: float) -> None:
     w = _window
     if w is not None:
         w.note_phase(name, ms)
+
+
+def note_write_phase(name: str, ms: float) -> None:
+    """One sample of a write-path stage; no-op when the perf plane is
+    disabled."""
+    w = _window
+    if w is not None:
+        w.note_write_phase(name, ms)
+
+
+def note_write(**counts) -> None:
+    """Add to the window's write counters; no-op when disabled."""
+    w = _window
+    if w is not None:
+        w.note_write(counts)
+
+
+def note_read_lock_wait(ms: float) -> None:
+    w = _window
+    if w is not None:
+        w.note_read_lock_wait(ms)
 
 
 def note_point_get(keys: int, segment_probes: int, key_compares: int,

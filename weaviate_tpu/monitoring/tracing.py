@@ -558,10 +558,16 @@ class Stopwatch:
         return self
 
     def __exit__(self, *exc) -> None:
+        self.stop()
+
+    def stop(self) -> float:
+        """Close the stage -> its ms (for a stage that does not end where
+        a block does)."""
         end_ns = (self._phase.end(**(self._late or {}))
                   if self._phase is not None
                   else time.perf_counter_ns())
         self.ms = (end_ns - self.start_ns) / 1e6
+        return self.ms
 
 
 class stage:
@@ -731,6 +737,16 @@ def piece_of(sums: Optional[StageSums], name: str,
     if sums is None:
         return _NO_PIECE
     return _Piece(sums, name, capacity, stats)
+
+
+def write_stage(name: str, ms: float) -> None:
+    """One closed stage of a write (monitoring/perf.py WRITE_PHASES): a
+    sample of `/debug/perf` `writes` and, in a sampled request, a child
+    `write.<name>` of the span that is open (`batch_objects`)."""
+    perf.note_write_phase(name, ms)
+    s = current_span()
+    if s is not None:
+        s.child_done("write." + name, ms)
 
 
 def current_span() -> Optional[Span]:

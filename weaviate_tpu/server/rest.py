@@ -744,9 +744,21 @@ class Handler(BaseHTTPRequestHandler):
     # -- batch ---------------------------------------------------------------
 
     def h_batch_objects(self):
-        body = self._json_body() or {}
-        payloads = body.get("objects") or []
-        results = self.app.batch.add_objects(payloads, cl=self._cl())
+        # a sampled request like BatchSearch: the span `batch_objects`
+        # under the request's root, its stages as children and as the
+        # phases of /debug/perf `writes` (`decode` here: JSON to objects;
+        # `lsm` in db/shard.py; the index's four in index/tpu.py)
+        whole = tracing.Stopwatch("write.batch")
+        with tracing.span("batch_objects") as sp:
+            with tracing.Stopwatch("write.decode") as decode:
+                body = self._json_body() or {}
+                prepared = self.app.batch.prepare_objects(
+                    body.get("objects") or [])
+            tracing.write_stage("decode", decode.ms)
+            if sp is not None:
+                sp.annotate("objects", len(prepared))
+            results = self.app.batch.put_prepared(prepared, cl=self._cl())
+        perf.note_write_phase("batch", whole.stop())
         out = []
         for r in results:
             if r.err:

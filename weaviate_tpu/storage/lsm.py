@@ -999,25 +999,51 @@ class Bucket:
     def multi_get_packed(self, key_buf, key_offs):
         """Packed-buffer batched point gets for the raw serving lane:
         keys live at key_offs[i]..key_offs[i+1] in key_buf (bytes or uint8
-        array; zero-length = missing upstream) -> (value arena, offsets,
-        flags) straight from the native plane. None whenever the packed
-        path cannot serve EXACTLY (memtable non-empty, no segments, native
-        unavailable) — the caller falls back to the general path. The
+        array; zero-length = missing upstream) -> (value buffer, offsets,
+        flags) straight from the native plane. The memtable's newer word
+        on a key (a value put, or a delete, since the last flush) is laid
+        over the segments' answer (`overlay_packed`), so a bucket that is
+        being written serves exactly too: the lane does not close on every
+        reader while ONE writer keeps a memtable non-empty. None whenever
+        the packed path cannot serve (no segments, native unavailable) —
+        the caller falls back to the general path. Without an overlay the
         values live in the calling thread's arena: valid until that
         thread's next packed call (lsm_native.multi_get_packed)."""
         assert self.strategy == STRATEGY_REPLACE
         from weaviate_tpu.storage import lsm_native
 
         with self._lock:
-            if len(self._mem) or not self._segments or not lsm_native.available():
+            if not self._segments or not lsm_native.available():
                 return None
+            newer = self._mem_words(key_buf, key_offs) if len(self._mem) \
+                else None
             snapshot = list(reversed(self._segments))
             self._native_inflight += 1
         try:
-            return lsm_native.multi_get_packed(snapshot, key_buf, key_offs)
+            packed = lsm_native.multi_get_packed(snapshot, key_buf, key_offs)
         finally:
             with self._lock:
                 self._native_exit()
+        if packed is None or not newer:
+            return packed
+        return overlay_packed(packed, newer)
+
+    def _mem_words(self, key_buf, key_offs) -> dict[int, bytes]:
+        """{position: the memtable's value or tombstone} of the packed keys
+        the memtable holds (the caller holds the lock): a dict look-up a
+        key, a few thousand a request."""
+        data = self._mem.data
+        kb = key_buf if isinstance(key_buf, bytes) else \
+            np.ascontiguousarray(key_buf).tobytes()
+        offs = np.asarray(key_offs).tolist()
+        out = {}
+        for i in range(len(offs) - 1):
+            a, b = offs[i], offs[i + 1]
+            if b > a:
+                v = data.get(kb[a:b])
+                if v is not None:
+                    out[i] = v
+        return out
 
     def set_get(self, key: bytes) -> set[bytes]:
         assert self.strategy == STRATEGY_SET
@@ -1424,6 +1450,30 @@ class Bucket:
                 if os.path.exists(seg.path + ".bloom"):
                     out.append(seg.path + ".bloom")
             return out
+
+
+def overlay_packed(packed, newer: dict[int, bytes]):
+    """(values, offsets, flags) of a packed point get with the values at
+    the positions of `newer` replaced (`_TOMBSTONE`: that key is gone) ->
+    a packed triple of its own buffer. The spans between the replaced
+    positions are copied whole: a loop over `newer`, not over the keys."""
+    vbuf, voffs, flags = packed
+    n = len(flags)
+    lens, flags = np.diff(voffs), flags.copy()
+    for i, v in newer.items():
+        gone = v == _TOMBSTONE
+        lens[i], flags[i] = (0, 0) if gone else (len(v), 1)
+    offs = np.zeros(n + 1, np.int64)
+    np.cumsum(lens, out=offs[1:])
+    out = np.empty(int(offs[n]), np.uint8)
+    prev = 0
+    for i in sorted(newer):
+        out[offs[prev]:offs[i]] = vbuf[voffs[prev]:voffs[i]]
+        if flags[i]:
+            out[offs[i]:offs[i + 1]] = np.frombuffer(newer[i], np.uint8)
+        prev = i + 1
+    out[offs[prev]:] = vbuf[voffs[prev]:voffs[n]]
+    return out, offs, flags
 
 
 class Store:

@@ -339,15 +339,25 @@ class BatchManager:
 
     def add_objects(self, payloads: Sequence[dict],
                     cl: Optional[str] = None) -> list[BatchResult]:
+        return self.put_prepared(self.prepare_objects(payloads), cl=cl)
+
+    def prepare_objects(self, payloads: Sequence[dict]) -> list[BatchResult]:
+        """Payloads -> objects (validated, vectorised), one result each:
+        the half of `add_objects` that touches no shard."""
         results = [BatchResult(original=p) for p in payloads]
-        by_class: dict[str, list[int]] = {}
         for i, p in enumerate(payloads):
             try:
-                obj = self.om._prepare(p)
-                results[i].obj = obj
-                by_class.setdefault(obj.class_name, []).append(i)
+                results[i].obj = self.om._prepare(p)
             except Exception as e:
                 results[i].err = str(e)
+        return results
+
+    def put_prepared(self, results: list[BatchResult],
+                     cl: Optional[str] = None) -> list[BatchResult]:
+        by_class: dict[str, list[int]] = {}
+        for i, r in enumerate(results):
+            if r.obj is not None:
+                by_class.setdefault(r.obj.class_name, []).append(i)
         for class_name, idxs in by_class.items():
             index = self.om.db.get_index(class_name)
             if index is None:
