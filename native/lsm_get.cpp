@@ -59,21 +59,50 @@
 //     la u32 | ld u32 | additions | deletions, each
 //     "WTBM" | n u64 | n ids u64 (ascending)
 //
+//   lsm_mem_open, lsm_mem_put, lsm_mem_close  a REPLACE bucket's memtable
+//               as a layer lsm_multi_get asks BEFORE the segments: a hash
+//               table over the same hash_key whose records (key, then
+//               value or tombstone) are appended to chunks the handle owns
+//               and never move. The mirror holds its own copy of the bytes
+//               (the memtable's Python objects are not referenced), so what
+//               a probe located stays readable whatever the dict does next.
+//
 // Concurrency contract with the Python side (storage/lsm.py Bucket):
-//   - the caller snapshots the segment handle list under the bucket lock
-//     and bumps an in-flight counter;
-//   - compaction retires (never closes) segments while calls are in
+//   - the caller snapshots the segment handle list, and the memtable
+//     handle where the memtable has one, under the bucket lock and bumps
+//     an in-flight counter;
+//   - compaction retires (never closes) segments, and a memtable flush
+//     retires (never closes) the memtable handle, while calls are in
 //     flight, so every handle passed in, and every value address
 //     lsm_multi_get wrote, stays valid until the caller leaves;
-//   - handles are immutable after open — no locking needed here.
+//   - segment handles are immutable after open — no locking needed;
+//   - a memtable handle has ONE inserting thread at a time (lsm_mem_put is
+//     called under the bucket lock, by put / delete, in the same hold that
+//     changes the dict) beside any number of probing threads, which hold no
+//     lock at all: a record is written whole before the slot that names it
+//     is stored (release), a probe loads slots with acquire, an
+//     overwritten key's slot is re-pointed at the newer record and the
+//     older one stays where it is, a table that grows is built aside and
+//     published by one pointer store while the older table stays readable
+//     until lsm_mem_close. So a probe sees, key by key, a whole value that
+//     was the key's newest at some instant of the call, and a get that
+//     starts after a put returned sees that put. No inserter ever waits
+//     for a reader: it holds the interpreter's lock. lsm_mem_race.cpp
+//     runs exactly this (one inserter, four probers, a growing table)
+//     under ThreadSanitizer and AddressSanitizer
+//     (tests/test_property_lsm_native.py).
 
+#include <atomic>
 #include <cstdint>
+#include <cstdlib>
 #include <cstring>
 #include <fcntl.h>
 #include <sys/mman.h>
 #include <sys/stat.h>
 #include <unistd.h>
 
+#include <memory>
+#include <new>
 #include <vector>
 
 namespace {
@@ -176,6 +205,118 @@ inline const Entry* seg_find(const Seg& s, uint64_t h, const uint8_t* key,
     }
 }
 
+inline bool is_tombstone(const uint8_t* p, uint64_t len) {
+    return len == static_cast<uint64_t>(kTombLen) &&
+           std::memcmp(p, kTomb, kTombLen) == 0;
+}
+
+// -- a memtable's mirror -------------------------------------------------------
+
+// One put, appended to a chunk and never moved: this header, the key's
+// bytes, the value's bytes (none for a tombstone).
+struct MemRec {
+    uint64_t hash;
+    uint64_t val_len;
+    uint32_t key_len;
+    uint32_t tomb;
+    const uint8_t* key() const {
+        return reinterpret_cast<const uint8_t*>(this + 1);
+    }
+    const uint8_t* val() const { return key() + key_len; }
+};
+
+// Open addressing, linear probing, at most half full; a slot is a record's
+// address or null. Slots go from null to a record, and from a record to a
+// newer record of the same key: a probe never meets a hole it must skip.
+struct MemTable {
+    uint64_t mask;
+    std::unique_ptr<std::atomic<const MemRec*>[]> slots;
+    explicit MemTable(uint64_t n)
+        : mask(n - 1), slots(new std::atomic<const MemRec*>[n]) {
+        for (uint64_t i = 0; i < n; i++)
+            slots[i].store(nullptr, std::memory_order_relaxed);
+    }
+};
+
+constexpr uint64_t kMemChunk = 1 << 20;
+constexpr uint64_t kMemSlots = 1 << 10;
+
+struct Mem {
+    std::atomic<MemTable*> table{nullptr};
+    // every table this handle has published, the live one last: a probe
+    // that loaded an older one may still be reading it
+    std::vector<std::unique_ptr<MemTable>> tables;
+    std::vector<uint8_t*> chunks;
+    uint64_t chunk_used = 0, chunk_cap = 0;
+    uint64_t keys = 0;   // distinct keys
+    int64_t held = 0;    // bytes of all chunks and tables
+    int64_t dead = 0;    // bytes of the records a newer put superseded
+
+    ~Mem() {
+        for (uint8_t* c : chunks) std::free(c);
+    }
+
+    // room for a record of `need` bytes, 8-aligned; nullptr = no memory
+    uint8_t* room(uint64_t need) {
+        need = (need + 7) & ~7ULL;
+        if (chunk_cap - chunk_used < need) {
+            const uint64_t cap = need > kMemChunk ? need : kMemChunk;
+            auto* c = static_cast<uint8_t*>(std::malloc(cap));
+            if (c == nullptr) return nullptr;
+            chunks.push_back(c);
+            chunk_used = 0;
+            chunk_cap = cap;
+            held += static_cast<int64_t>(cap);
+        }
+        uint8_t* at = chunks.back() + chunk_used;
+        chunk_used += need;
+        return at;
+    }
+
+    // the slot that holds `key`, or the empty one where it would go
+    static std::atomic<const MemRec*>& slot_of(MemTable& t, uint64_t h,
+                                               const uint8_t* key,
+                                               uint64_t klen) {
+        for (uint64_t at = h & t.mask;; at = (at + 1) & t.mask) {
+            const MemRec* r = t.slots[at].load(std::memory_order_relaxed);
+            if (r == nullptr ||
+                (r->hash == h && r->key_len == klen &&
+                 std::memcmp(r->key(), key, klen) == 0))
+                return t.slots[at];
+        }
+    }
+
+    // a table of `n` slots holding what `old` holds, published
+    void publish(uint64_t n, const MemTable* old) {
+        auto t = std::make_unique<MemTable>(n);
+        if (old != nullptr)
+            for (uint64_t i = 0; i <= old->mask; i++) {
+                const MemRec* r = old->slots[i].load(std::memory_order_relaxed);
+                if (r != nullptr)
+                    slot_of(*t, r->hash, r->key(), r->key_len)
+                        .store(r, std::memory_order_relaxed);
+            }
+        // kept before it is published: a push_back that throws must not
+        // free a table a probe can already load
+        tables.push_back(std::move(t));
+        held += static_cast<int64_t>(n * sizeof(const MemRec*));
+        table.store(tables.back().get(), std::memory_order_release);
+    }
+};
+
+// A probing thread's look-up: the key's newest record, or nullptr.
+inline const MemRec* mem_find(const Mem& m, uint64_t h, const uint8_t* key,
+                              uint64_t klen) {
+    const MemTable* t = m.table.load(std::memory_order_acquire);
+    for (uint64_t at = h & t->mask;; at = (at + 1) & t->mask) {
+        const MemRec* r = t->slots[at].load(std::memory_order_acquire);
+        if (r == nullptr) return nullptr;
+        if (r->hash == h && r->key_len == klen &&
+            std::memcmp(r->key(), key, klen) == 0)
+            return r;
+    }
+}
+
 // One serialized Bitmap at p (`len` bytes) -> its ids and their count, or
 // false where the bytes are no bitmap.
 bool bitmap_at(const uint8_t* p, uint64_t len, const uint8_t** ids,
@@ -270,6 +411,69 @@ int64_t lsm_seg_count(void* h) {
     return h ? static_cast<int64_t>(static_cast<Seg*>(h)->entries.size()) : 0;
 }
 
+// -> a memtable mirror with nothing in it, or nullptr (no memory).
+void* lsm_mem_open() {
+    try {
+        auto m = std::make_unique<Mem>();
+        m->publish(kMemSlots, nullptr);
+        return m.release();
+    } catch (const std::bad_alloc&) {
+        return nullptr;
+    }
+}
+
+void lsm_mem_close(void* h) { delete static_cast<Mem*>(h); }
+
+// The memtable's word on `key` from now on: `val`, or, where `val` is the
+// tombstone marker, "deleted". -> the bytes of the records that newer puts
+// of their keys have superseded (they stay held until lsm_mem_close: the
+// caller decides when a fresh mirror is the cheaper one), or -1 where
+// memory ran out and the handle no longer mirrors its memtable. The ONE
+// inserting thread's call (the header has the contract).
+int64_t lsm_mem_put(void* h, const uint8_t* key, int64_t klen,
+                    const uint8_t* val, int64_t vlen) {
+    auto& m = *static_cast<Mem*>(h);
+    const bool tomb = is_tombstone(val, static_cast<uint64_t>(vlen));
+    if (tomb) vlen = 0;
+    try {
+        uint8_t* at = m.room(sizeof(MemRec) + klen + vlen);
+        if (at == nullptr) return -1;
+        auto* r = reinterpret_cast<MemRec*>(at);
+        r->hash = hash_key(key, static_cast<uint64_t>(klen));
+        r->val_len = static_cast<uint64_t>(vlen);
+        r->key_len = static_cast<uint32_t>(klen);
+        r->tomb = tomb;
+        std::memcpy(at + sizeof(MemRec), key, klen);
+        std::memcpy(at + sizeof(MemRec) + klen, val, vlen);
+        MemTable* t = m.table.load(std::memory_order_relaxed);
+        auto* slot = &Mem::slot_of(*t, r->hash, key, klen);
+        const MemRec* was = slot->load(std::memory_order_relaxed);
+        if (was != nullptr) {
+            m.dead += static_cast<int64_t>(sizeof(MemRec) + was->key_len +
+                                           was->val_len);
+        } else {
+            if (2 * (m.keys + 1) > t->mask + 1) {
+                m.publish(2 * (t->mask + 1), t);
+                t = m.table.load(std::memory_order_relaxed);
+                slot = &Mem::slot_of(*t, r->hash, key, klen);
+            }
+            m.keys++;
+        }
+        slot->store(r, std::memory_order_release);
+    } catch (const std::bad_alloc&) {
+        return -1;
+    }
+    return m.dead;
+}
+
+// stats <- {distinct keys, bytes held (chunks and tables), dead bytes}
+void lsm_mem_stats(void* h, int64_t* stats) {
+    const auto& m = *static_cast<Mem*>(h);
+    stats[0] = static_cast<int64_t>(m.keys);
+    stats[1] = m.held;
+    stats[2] = m.dead;
+}
+
 // Copy what lsm_multi_get located into `out` (at least out_offs[n_keys]
 // bytes), while the segments it read are still protected by the same
 // in-flight hold.
@@ -282,24 +486,30 @@ void lsm_copy(const uint8_t* const* srcs, const int64_t* out_offs,
 }
 
 // Batched replace-strategy point gets over a NEWEST-FIRST segment list.
+//   mem:      the memtable's mirror (lsm_mem_open), or nullptr: the layer
+//             asked first. A value found there is the answer, a tombstone
+//             found there is a miss, and either ends the key's search.
 //   keys/key_offs: concatenated key bytes, n_keys+1 prefix offsets; a
 //     zero-length key means "missing upstream" and stays missing.
-//   srcs:     per key: where its value lives in a segment's mapping
-//             (undefined for a miss).
+//   srcs:     per key: where its value lives, in a segment's mapping or
+//             in the mirror's chunks (undefined for a miss).
 //   out_offs: n_keys+1 prefix sums of the found values' lengths: where
 //             each value goes in the arena (equal offsets = miss or empty
 //             value).
 //   flags:    per key: 1 found, 0 missing (absent OR tombstoned).
-//   stats:    {segment probes, key compares} of this call.
+//   stats:    {segment probes, key compares, keys the memtable answered}
+//             of this call.
 //   out/out_cap: the caller's arena. Every key is located first; the
 //             values are copied only if all of them fit.
 // -> total value bytes (out_offs[n_keys]). If > out_cap nothing was copied:
 // the caller brings an arena that large to lsm_copy, which needs no search.
-int64_t lsm_multi_get(void** segs, int64_t n_segs, const uint8_t* keys,
-                      const int64_t* key_offs, int64_t n_keys,
-                      const uint8_t** srcs, int64_t* out_offs, int8_t* flags,
-                      int64_t* stats, uint8_t* out, int64_t out_cap) {
-    int64_t total = 0, probes = 0, compares = 0;
+int64_t lsm_multi_get(void** segs, int64_t n_segs, void* mem,
+                      const uint8_t* keys, const int64_t* key_offs,
+                      int64_t n_keys, const uint8_t** srcs, int64_t* out_offs,
+                      int8_t* flags, int64_t* stats, uint8_t* out,
+                      int64_t out_cap) {
+    int64_t total = 0, probes = 0, compares = 0, mem_keys = 0;
+    const Mem* m = static_cast<const Mem*>(mem);
     // a roaring-set segment's payloads are no values: the Python reader's
     for (int64_t si = 0; si < n_segs; si++)
         if (static_cast<Seg*>(segs[si])->strategy != kReplace) return -1;
@@ -311,15 +521,22 @@ int64_t lsm_multi_get(void** segs, int64_t n_segs, const uint8_t* keys,
         srcs[i] = nullptr;
         if (klen > 0) {
             const uint64_t h = hash_key(key, klen);
-            for (int64_t si = 0; si < n_segs; si++) {
+            const MemRec* r = m ? mem_find(*m, h, key, klen) : nullptr;
+            if (r != nullptr) {
+                mem_keys++;
+                if (!r->tomb) {
+                    srcs[i] = r->val();
+                    total += static_cast<int64_t>(r->val_len);
+                    flags[i] = 1;
+                }
+            }
+            for (int64_t si = 0; r == nullptr && si < n_segs; si++) {
                 const Seg& s = *static_cast<Seg*>(segs[si]);
                 probes++;
                 const Entry* ent = seg_find(s, h, key, klen, compares);
                 if (ent == nullptr) continue;
                 // a tombstone in a newer segment shadows older values
-                if (ent->len == static_cast<uint64_t>(kTombLen) &&
-                    std::memcmp(s.base + ent->off, kTomb, kTombLen) == 0)
-                    break;
+                if (is_tombstone(s.base + ent->off, ent->len)) break;
                 srcs[i] = s.base + ent->off;
                 total += static_cast<int64_t>(ent->len);
                 flags[i] = 1;
@@ -330,6 +547,7 @@ int64_t lsm_multi_get(void** segs, int64_t n_segs, const uint8_t* keys,
     }
     stats[0] = probes;
     stats[1] = compares;
+    stats[2] = mem_keys;
     if (total <= out_cap) lsm_copy(srcs, out_offs, n_keys, out);
     return total;
 }

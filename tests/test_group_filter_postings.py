@@ -439,7 +439,7 @@ def test_postings_read_while_compaction_retires_segments(tmp_path):
         sys.setswitchinterval(interval)
     assert not any(t.is_alive() for t in threads)
     assert not errors
-    assert b._native_inflight == 0 and not b._retired_segments
+    assert b._native_inflight == 0 and not b._retired
     for key, ids in want.items():
         assert np.array_equal(b.roaring_get(key).to_array(),
                               ids.astype(np.uint64))

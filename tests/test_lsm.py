@@ -218,7 +218,7 @@ def test_native_multi_get_races_compaction(tmp_path):
     assert not errors, errors[:3]
     with b._lock:
         assert b._native_inflight == 0
-        assert not b._retired_segments  # all retired segments were closed
+        assert not b._retired  # all retired segments were closed
 
 
 def test_reserved_tombstone_value_refused(tmp_path):

@@ -248,7 +248,18 @@ def test_perf_window_summary_and_clear():
     w.note_point_get(2560, 19000, 2570, 0)
     s = w.summary()
     assert s["point_get"] == {"keys": 5120, "segment_probes": 21560,
-                              "key_compares": 5130, "arena_grows": 1}
+                              "key_compares": 5130, "arena_grows": 1,
+                              "mem_layer_calls": 0, "mem_keys": 0,
+                              "mirror_builds": 0, "overlay_fallbacks": 0}
+    # a written bucket's memtable layer: a call that asked it, a mirror
+    # built, a packed get left to the general path
+    w.note_point_get(2560, 18000, 2500, 0, True, 31)
+    w.note_point_get(mirror_builds=1)
+    w.note_point_get(overlay_fallbacks=1)
+    assert w.summary()["point_get"] == {
+        "keys": 7680, "segment_probes": 39560, "key_compares": 7630,
+        "arena_grows": 1, "mem_layer_calls": 1, "mem_keys": 31,
+        "mirror_builds": 1, "overlay_fallbacks": 1}
     assert s["dispatches"] == 4
     assert s["rows"] == 64
     assert 0.0 < s["duty_cycle"] <= 1.0

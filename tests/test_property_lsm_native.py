@@ -73,13 +73,17 @@ def _python_multi_get(b, probe):
         lsm_native._lib, lsm_native._lib_failed = orig
 
 
+def _offs(keys):
+    """keys (None = missing upstream) -> (key buffer, n + 1 offsets)."""
+    offs = np.zeros(len(keys) + 1, dtype=np.int64)
+    np.cumsum([len(k or b"") for k in keys], out=offs[1:])
+    return b"".join(k or b"" for k in keys), offs
+
+
 def _packed(b, keys):
     """keys (None = missing upstream) through the packed plane -> values
     list, copied out of the arena at once."""
-    lens = np.array([len(k or b"") for k in keys], dtype=np.int64)
-    offs = np.zeros(len(keys) + 1, dtype=np.int64)
-    np.cumsum(lens, out=offs[1:])
-    got = b.multi_get_packed(b"".join(k or b"" for k in keys), offs)
+    got = b.multi_get_packed(*_offs(keys))
     assert got is not None
     vbuf, voffs, flags = got
     assert voffs[-1] == len(vbuf)
@@ -342,8 +346,276 @@ def test_chained_hydrate_equals_general_under_compaction(tmp_path):
     assert merges >= nseg - 1
     with shard.objects._lock:
         assert shard.objects._native_inflight == 0
-        assert not shard.objects._retired_segments
+        assert not shard.objects._retired
     shard.shutdown()
+
+
+# -- the memtable as the native call's newest layer --------------------------
+
+
+def _overlay_oracle(b, key_buf, key_offs):
+    """The packed get as plain Python serves it: the memtable's words read
+    from its dict under the lock, a look-up a key, laid over the segments'
+    answer in a buffer of its own (`overlay_packed`) -> copies of the
+    triple."""
+    from weaviate_tpu.storage.lsm import overlay_packed
+
+    with b._lock:
+        data, offs = b._mem.data, key_offs.tolist()
+        newer = {i: data[key_buf[lo:hi]]
+                 for i, (lo, hi) in enumerate(zip(offs, offs[1:]))
+                 if hi > lo and key_buf[lo:hi] in data}
+        packed = lsm_native.multi_get_packed(
+            list(reversed(b._segments)), key_buf, key_offs)
+        vbuf, voffs, flags = overlay_packed(packed, newer) if newer \
+            else packed
+        return vbuf.tobytes(), voffs.tolist(), flags.tolist()
+
+
+def _held_to_the_oracle(b, keys):
+    """One packed get of `keys` through the mirror, held to the overlay
+    (values, offsets, flags) and to `Bucket.get` key for key."""
+    key_buf, key_offs = _offs(keys)
+    want = _overlay_oracle(b, key_buf, key_offs)
+    vbuf, voffs, flags = b.multi_get_packed(key_buf, key_offs)
+    assert (vbuf.tobytes(), voffs.tolist(), flags.tolist()) == want
+    data = want[0]
+    for i, k in enumerate(keys):
+        v = b.get(k) if k else None
+        assert (data[voffs[i]:voffs[i + 1]] if flags[i] else None) == v, k
+    return flags
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_memtable_layer_equals_python_overlay(tmp_path, seed):
+    """Random puts, overwrites, deletes, flushes and compactions, a packed
+    get after every few of them: the ONE native call that asks the
+    memtable's mirror first answers what the Python overlay answers
+    (values, offsets, flags) and what `Bucket.get` answers, zero-length
+    keys and absent ones in the batch, values of 0 B to 8 KB so that the
+    thread's arena has to grow under the memtable's values too."""
+    rng = np.random.default_rng(1000 + seed)
+    sizes = [0, 1, 17, 700, 3300, 8192] if seed % 2 else [0, 3, 16, 64]
+    b = Bucket(str(tmp_path / "b"), STRATEGY_REPLACE)
+    for i in range(0, 50, 3):   # something on disk: the packed path serves
+        b.put(_key(i), b"first-%d" % i)
+    b.flush_memtable()
+    done: list = []
+
+    def run():   # a thread of its own: its arena starts empty
+        asked = mem_hits = 0
+        for step in range(160):
+            op = rng.choice(["put", "put", "put", "del", "flush",
+                             "compact_pair", "compact"],
+                            p=[.3, .3, .2, .12, .04, .02, .02])
+            i = int(rng.integers(0, 50))
+            if op == "put":
+                b.put(_key(i), hashlib.shake_128(b"%d" % step).digest(
+                    int(rng.choice(sizes))))
+            elif op == "del":
+                b.delete(_key(i))
+            elif op == "flush":
+                b.flush_memtable()
+            elif op == "compact_pair":
+                b.compact_pair()
+            else:
+                b.compact()
+            if step % 4:
+                continue
+            keys = [None if j >= 54 else b"" if j >= 52 else _key(j)
+                    for j in rng.integers(0, 56, 300).tolist()]
+            had_mem = len(b._mem) > 0
+            _, pg = _counted(lambda: _held_to_the_oracle(b, keys))
+            # the oracle's segments-only call, then the bucket's own
+            assert pg["mem_layer_calls"] == int(had_mem)
+            assert pg["overlay_fallbacks"] == 0
+            asked += had_mem
+            mem_hits += pg["mem_keys"]
+        done.append((asked, mem_hits))
+
+    t = threading.Thread(target=run)
+    t.start()
+    t.join(timeout=300)
+    assert not t.is_alive() and done
+    assert done[0][0] > 10 and done[0][1] > 100   # the layer was exercised
+    b.shutdown()
+    assert b._mem.mirror is None and not b._retired
+
+
+def test_memtable_layer_chains_one_arena_through_two_written_buckets(
+        tmp_path):
+    """`hydrate_raw_packed`'s shape: r1's values, a view of the thread's
+    arena, are r2's keys, and r2's values overwrite them in that arena,
+    with both buckets' memtables holding the newest word on some keys, a
+    tombstone among them, and an arena that has to grow in the second call."""
+    ids = [struct.pack("<Q", i) for i in range(400)]
+    uuids = [uuidlib.UUID(int=i + 1).bytes for i in range(400)]
+    lookup = Bucket(str(tmp_path / "lookup"), STRATEGY_REPLACE)
+    objects = Bucket(str(tmp_path / "objects"), STRATEGY_REPLACE)
+    image = {u: hashlib.shake_128(u).digest(3300) for u in uuids}
+    lookup.put_many(zip(ids[:300], uuids[:300]))
+    objects.put_many((u, image[u]) for u in uuids[:300])
+    for b in (lookup, objects):
+        b.flush_memtable()
+    # since the flush: new rows, re-put rows (a new image), a deleted doc
+    # id, a deleted object
+    lookup.put_many(zip(ids[300:], uuids[300:]))
+    for u in uuids[250:]:
+        image[u] = hashlib.shake_128(u + b"2").digest(4000)
+        objects.put(u, image[u])
+    lookup.delete(ids[7])
+    objects.delete(uuids[9])
+    out: list = []
+
+    def chain():
+        key_buf, key_offs = _offs(ids)
+        ubuf, uoffs, uflags = lookup.multi_get_packed(key_buf, key_offs)
+        assert ubuf.base is lsm_native._arena.buf
+        vbuf, voffs, vflags = objects.multi_get_packed(ubuf, uoffs)
+        out.append((uflags.tolist(), vflags.tolist(), vbuf.tobytes(),
+                    voffs.tolist()))
+
+    _, pg = _counted(lambda: (t := threading.Thread(target=chain),
+                              t.start(), t.join(timeout=60)))
+    uflags, vflags, data, voffs = out[0]
+    assert uflags == [int(i != 7) for i in range(400)]
+    assert vflags == [int(i not in (7, 9)) for i in range(400)]
+    for i, u in enumerate(uuids):
+        if vflags[i]:
+            assert data[voffs[i]:voffs[i + 1]] == image[u], i
+    assert pg["mem_layer_calls"] == 2 and pg["mirror_builds"] == 2
+    assert pg["mem_keys"] == (100 + 1) + (150 + 1)
+    assert pg["arena_grows"] == 2   # 6.4 KB of uuids, then 1.4 MB of images
+    for b in (lookup, objects):
+        b.shutdown()
+
+
+def test_no_mirror_for_an_empty_memtable_or_a_bucket_nobody_reads_packed(
+        tmp_path, monkeypatch):
+    """The cells that write nothing make today's native call, with a null
+    layer, and a bucket that is only written (an import, a build, a
+    restart's WAL replay) never makes a mirror: one comparison a put."""
+    calls: list = []
+    real = lsm_native.multi_get_packed
+    monkeypatch.setattr(
+        lsm_native, "multi_get_packed",
+        lambda segs, kb, ko, mem=None: calls.append(mem) or real(
+            segs, kb, ko, mem))
+    made: list = []
+    real_mirror = lsm_native.mem_mirror
+    monkeypatch.setattr(lsm_native, "mem_mirror",
+                        lambda data: made.append(1) or real_mirror(data))
+    b = _segments_of(tmp_path, {b"a": b"1", b"b": b"2"})
+
+    def quiet():
+        assert _packed(b, [b"a", b"b", b"c"]) == [b"1", b"2", None]
+
+    _, pg = _counted(quiet)
+    assert calls == [None] and not made and b._mem.mirror is None
+    assert {k: pg[k] for k in ("mem_layer_calls", "mem_keys", "mirror_builds",
+                               "overlay_fallbacks")} == dict.fromkeys(
+        ("mem_layer_calls", "mem_keys", "mirror_builds", "overlay_fallbacks"),
+        0)
+    # only written: 10,000 puts, deletes among them, a batch, a reopen
+    for i in range(10_000):
+        b.put(b"w%d" % i, b"v")
+    b.put_many((b"m%d" % i, b"v") for i in range(100))
+    b.delete(b"w5")
+    assert b.get(b"w6") == b"v" and b.multi_get([b"w5", b"w7"] * 8) == [
+        None, b"v"] * 8
+    assert not made and b._mem.mirror is None
+    b._wal.flush()
+    again = Bucket(b.path + "-copy", STRATEGY_REPLACE)
+    again.shutdown()
+    shutil.copy(b._wal_path, again._wal_path)
+    again = Bucket(again.path, STRATEGY_REPLACE)   # replays the WAL
+    assert len(again._mem) == 10_100 and again._mem.mirror is None
+    assert not made
+    # the first packed reader makes ONE, later ones and the writes between
+    # them none
+    assert _packed(b, [b"w5", b"w6", b"a"]) == [None, b"v", b"1"]
+    b.put(b"w6", b"v2")
+    assert _packed(b, [b"w6"]) == [b"v2"]
+    assert made == [1] and calls[-1] is b._mem.mirror is not None
+    assert b._mem.mirror.stats()["keys"] == 10_100
+    for x in (b, again):
+        x.shutdown()
+
+
+def test_a_mirror_that_cannot_be_made_leaves_the_general_path_serving(
+        tmp_path, monkeypatch):
+    """No handle (no memory): the packed plane declines that memtable
+    generation's gets (asking once), the counter says so and the general
+    reader answers; the next generation tries again."""
+    b = _segments_of(tmp_path, {b"a": b"1", b"b": b"2", b"c": b"3"})
+    b.put(b"a", b"new")
+    b.delete(b"b")
+    asked: list = []
+    monkeypatch.setattr(lsm_native, "mem_mirror",
+                        lambda data: asked.append(1))
+    got, pg = _counted(lambda: [b.multi_get_packed(*_offs(keys))
+                                for keys in ([b"a", b"b", b"c"], [b"a"])])
+    assert got == [None, None] and asked == [1]
+    assert pg["overlay_fallbacks"] == 2 and pg["mem_layer_calls"] == 0
+    assert pg["mirror_builds"] == 0 and b._mem.mirror is None
+    assert b.multi_get([b"a", b"b", b"c"]) == [b"new", None, b"3"]
+    monkeypatch.undo()
+    b.flush_memtable()
+    b.put(b"c", b"newer")
+    got, pg = _counted(lambda: _packed(b, [b"a", b"b", b"c"]))
+    assert got == [b"new", None, b"newer"]
+    assert pg["overlay_fallbacks"] == 0 and pg["mirror_builds"] == 1
+    b.shutdown()
+
+
+def test_a_hot_key_does_not_grow_its_mirror_for_ever(tmp_path):
+    """A key re-put again and again grows no memtable, so no flush would
+    ever free what its older versions hold in the mirror: the bucket
+    retires a mirror whose dead bytes pass its memtable's live ones (plus
+    the slack), and the next packed get makes a fresh one."""
+    b = _segments_of(tmp_path, {b"cold": b"c"})
+    b.put(b"hot", b"0")
+    assert _packed(b, [b"hot"]) == [b"0"]
+    first, value = b._mem.mirror, b"x" * 65_536
+    rounds = Bucket._MIRROR_DEAD_SLACK // len(value) + 2
+    for i in range(rounds):
+        b.put(b"hot", value)
+        if b._mem.mirror is not first:
+            break
+    assert b._mem.mirror is None and first._h == 0 and i >= rounds - 3
+    assert _packed(b, [b"hot", b"cold"]) == [value, b"c"]
+    assert b._mem.mirror.stats() == {
+        "keys": 1, "held_bytes": b._mem.mirror.stats()["held_bytes"],
+        "dead_bytes": 0}
+    b.shutdown()
+
+
+@pytest.mark.parametrize("sanitizer", ["thread", "address,undefined"])
+def test_the_mirror_is_clean_under_a_sanitizer(tmp_path, sanitizer):
+    """`native/lsm_mem_race.cpp`: one inserting thread beside four that
+    probe with no lock while the table grows five times, built with the
+    sanitizer: no report, no torn, missing or stale value. Skipped where
+    the compiler has no such runtime, or the sanitizer cannot map its
+    shadow memory on this kernel."""
+    import os
+    import subprocess
+
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "native", "lsm_mem_race.cpp")
+    exe = str(tmp_path / "race")
+    built = subprocess.run(
+        ["g++", "-std=c++17", "-O1", "-g", f"-fsanitize={sanitizer}",
+         "-fno-sanitize-recover=all", "-o", exe, src, "-lpthread"],
+        capture_output=True, text=True)
+    if built.returncode:
+        pytest.skip(f"no -fsanitize={sanitizer} here: {built.stderr[-200:]}")
+    ran = subprocess.run([exe, "1.5"], capture_output=True, text=True,
+                         timeout=120)
+    if "unexpected memory mapping" in ran.stderr:
+        pytest.skip("the sanitizer cannot map its shadow memory here")
+    assert ran.returncode == 0 and ran.stdout.endswith("ok\n"), (
+        ran.stdout[-300:], ran.stderr[-2000:])
+    assert "Sanitizer" not in ran.stderr and "runtime error" not in ran.stderr
 
 
 # the reserved-tombstone-value guard test lives in test_lsm.py: it has no

@@ -501,6 +501,188 @@ def test_the_raw_lane_serves_exactly_while_a_writer_keeps_memtables_busy(
     c.db.shutdown()
 
 
+def _version_value(version: int, key: bytes) -> bytes:
+    """3.3 KB that are one version's or nobody's: a torn copy shows."""
+    return bytes([version % 251]) * 3300 + key
+
+
+def test_four_packed_readers_beside_one_writer_read_whole_values(tmp_path):
+    """The memtable's mirror is probed with no lock while the writer puts:
+    every value a packed get returns is a whole old or a whole new one,
+    never torn, never missing; a get that starts after a put was
+    acknowledged returns that put or a later one; a key that is deleted
+    and put again reads as gone or whole; and the flushes and compactions
+    the writer makes on the way retire segments and mirrors under the
+    readers without freeing one that is still being read."""
+    import struct
+    import sys
+
+    from weaviate_tpu.storage import lsm_native
+    from weaviate_tpu.storage.lsm import STRATEGY_REPLACE, Bucket
+
+    if not lsm_native.available():
+        pytest.skip("the native point-get library did not build here")
+    keys = [struct.pack("<Q", i) * 2 for i in range(300)]
+    steady, flicker = keys[:250], keys[250:]
+    b = Bucket(str(tmp_path / "b"), STRATEGY_REPLACE)
+    b.put_many((k, _version_value(0, k)) for k in keys)
+    b.flush_memtable()
+    acked = dict.fromkeys(steady, 0)     # the last version put() returned
+    offs = np.arange(len(keys) + 1, dtype=np.int64) * 16
+    key_buf = b"".join(keys)
+    errors: list = []
+    stop = threading.Event()
+    reads = [0] * 4
+
+    def reader(slot):
+        try:
+            while not stop.is_set():
+                floor = [acked[k] for k in steady]
+                packed = b.multi_get_packed(key_buf, offs)
+                if packed is None:
+                    # a segment of the snapshot was compacted away before
+                    # its first native open (`lsm_native.seg_handle` opens
+                    # by path): the general path's turn, not this test's
+                    continue
+                vbuf, voffs, flags = packed
+                data, voffs = vbuf.tobytes(), voffs.tolist()
+                for i, k in enumerate(keys):
+                    v = data[voffs[i]:voffs[i + 1]]
+                    if not flags[i]:
+                        assert i >= 250 and not v, ("missing", i)
+                        continue
+                    assert len(v) == 3316 and v[3300:] == k and \
+                        v[:3300] == v[:1] * 3300, ("torn", i)
+                    if i < 250:
+                        # versions only rise, so % 251 is compared over
+                        # a window the writer cannot lap within one call
+                        ahead = (v[0] - floor[i]) % 251
+                        assert ahead < 125, ("stale", i, v[0], floor[i])
+                reads[slot] += 1
+        except Exception as e:  # noqa: BLE001 — reported by the assert below
+            errors.append(repr(e))
+            stop.set()
+
+    threads = [threading.Thread(target=reader, args=(i,)) for i in range(4)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    try:
+        for t in threads:
+            t.start()
+        rng = np.random.default_rng(7)
+        deadline = time.monotonic() + 6.0
+        mirrors, puts = set(), 0
+        while time.monotonic() < deadline and not stop.is_set():
+            k = steady[int(rng.integers(0, 250))]
+            b.put(k, _version_value(acked[k] + 1, k))
+            acked[k] += 1
+            puts += 1
+            f = flicker[int(rng.integers(0, 50))]
+            if rng.random() < 0.5:
+                b.delete(f)
+            else:
+                b.put(f, _version_value(puts, f))
+            if b._mem.mirror is not None:
+                mirrors.add(id(b._mem.mirror))
+            if puts % 400 == 0:
+                b.flush_memtable()
+            if puts % 1500 == 0:
+                b.compact_pair()
+        stop.set()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+        stop.set()
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors[:3]
+    assert min(reads) >= 3 and puts >= 800 and len(mirrors) >= 2
+    with b._lock:
+        assert b._native_inflight == 0 and not b._retired
+    b.shutdown()
+
+
+def test_a_flush_under_a_packed_get_retires_the_mirror_until_it_leaves(
+        tmp_path, monkeypatch):
+    """The memtable's mirror has the segments' contract: a flush while a
+    packed get that holds it is in flight parks it, open, and the last
+    reader to leave frees it."""
+    from weaviate_tpu.storage import lsm_native
+    from weaviate_tpu.storage.lsm import STRATEGY_REPLACE, Bucket
+
+    if not lsm_native.available():
+        pytest.skip("the native point-get library did not build here")
+    b = Bucket(str(tmp_path / "b"), STRATEGY_REPLACE)
+    b.put(b"on-disk", b"old")
+    b.flush_memtable()
+    b.put(b"on-disk", b"new")
+    b.put(b"mem-only", b"m")
+    b.delete(b"gone")
+    offs = np.array([0, 7, 15, 19], dtype=np.int64)
+    assert b.multi_get_packed(b"on-diskmem-onlygone", offs) is not None
+    mirror = b._mem.mirror
+    seen = {}
+    real = lsm_native.multi_get_packed
+
+    def flush_inside(segs, key_buf, key_offs, mem=None):
+        # after the snapshot, outside the lock, before the native call
+        b.flush_memtable()
+        b.put(b"mem-only", b"after the snapshot")
+        seen.update(parked=list(b._retired), handle=mem._h, mem=mem,
+                    segments=len(b._segments) - len(segs))
+        return real(segs, key_buf, key_offs, mem)
+
+    monkeypatch.setattr(lsm_native, "multi_get_packed", flush_inside)
+    vbuf, voffs, flags = b.multi_get_packed(b"on-diskmem-onlygone", offs)
+    assert seen["mem"] is mirror and seen["parked"] == [mirror]
+    assert seen["handle"] != 0 and seen["segments"] == 1
+    # the snapshot's answer: the retired memtable over the older segments
+    assert vbuf.tobytes() == b"newm" and flags.tolist() == [1, 1, 0]
+    assert mirror._h == 0 and not b._retired and b._native_inflight == 0
+    monkeypatch.undo()
+    vbuf, voffs, flags = b.multi_get_packed(b"on-diskmem-onlygone", offs)
+    assert vbuf.tobytes() == b"newafter the snapshot"
+    assert b._mem.mirror is not mirror
+    b.shutdown()
+
+
+def test_debug_perf_point_get_counts_the_memtable_layer(tmp_path):
+    """`/debug/perf` `point_get`: the four counters of the memtable layer
+    are there and read 0 while nothing is written; beside a writer a
+    hydrate is two calls that asked a memtable, the first of each bucket's
+    generation built its mirror, and no call was left to the general path."""
+    from weaviate_tpu.monitoring import perf
+    from weaviate_tpu.storage import lsm_native
+
+    if not lsm_native.available():
+        pytest.skip("the native point-get library did not build here")
+    c = Corpus(tmp_path / "data", "l2-squared", 2_000, 16)
+    shard = c.cls.single_local_shard()
+    for b in (shard.objects, shard.docid_lookup):
+        b.flush_memtable()
+    ids, dists = shard.vector_index.search_by_vectors(c.queries, K)
+    window = perf.configure(perf.PerfWindow(window_s=60.0))
+    layer = ("mem_layer_calls", "mem_keys", "mirror_builds",
+             "overlay_fallbacks")
+    try:
+        assert shard.hydrate_raw_packed(ids, dists) is not None
+        pg = window.summary()["point_get"]
+        assert pg["keys"] == 2 * ids.size
+        assert [pg[k] for k in layer] == [0, 0, 0, 0]
+        c.re_put()
+        ids, dists = shard.vector_index.search_by_vectors(c.queries, K)
+        window.clear()
+        for _ in range(3):
+            assert shard.hydrate_raw_packed(ids, dists) is not None
+        pg = window.summary()["point_get"]
+        assert pg["mem_layer_calls"] == 6 and pg["mirror_builds"] == 2
+        assert pg["mem_keys"] > 0 and pg["overlay_fallbacks"] == 0
+        c.held_to_truth(shard._hydrate_batch(ids, dists, False))
+    finally:
+        perf.unconfigure(window)
+    c.db.shutdown()
+
+
 def test_a_log_of_short_runs_replays_without_reading_what_follows(tmp_path):
     """A log of re-puts is thousands of short add runs between deletes:
     each run's checksums are taken over the run, not over every byte that

@@ -1290,8 +1290,8 @@ class Shard:
         segments and the native library loads — checked first so an
         ineligible batch never runs the device kNN twice (once here, once
         on the general path). A memtable that a writer keeps non-empty
-        does not close the lane: `Bucket.multi_get_packed` lays the
-        memtable's word over the segments' answer."""
+        does not close the lane: `Bucket.multi_get_packed` hands the native
+        call the memtable as its newest layer."""
         from weaviate_tpu.storage import lsm_native
 
         if not lsm_native.available():

@@ -207,8 +207,10 @@ class PerfWindow:
         # _PHASE_SAMPLES_MAX) on top of the time-horizon eviction
         self._phase: dict[str, deque] = {
             p: deque(maxlen=_PHASE_SAMPLES_MAX) for p in PHASES}
-        # (t_mono, keys, segment_probes, key_compares, arena_grows) per
-        # native point-get call, count-capped like the phases
+        # (t_mono, keys, segment_probes, key_compares, arena_grows,
+        # mem_layer, mem_keys, mirror_builds, overlay_fallbacks) per native
+        # point-get call or memtable-layer event, count-capped like the
+        # phases
         self._point_get: deque = deque(maxlen=_PHASE_SAMPLES_MAX)
         # (t_mono, rows, bytes, promoted) per float32 rescoring of a
         # compressed dispatch's candidates, count-capped likewise
@@ -314,13 +316,23 @@ class PerfWindow:
             while d and d[0][0] < horizon:
                 d.popleft()
 
-    def note_point_get(self, keys: int, segment_probes: int,
-                       key_compares: int, arena_grows: int) -> None:
-        """One call of the native point-get plane, as counted in C."""
+    def note_point_get(self, keys: int = 0, segment_probes: int = 0,
+                       key_compares: int = 0, arena_grows: int = 0,
+                       mem_layer: bool = False, mem_keys: int = 0,
+                       mirror_builds: int = 0,
+                       overlay_fallbacks: int = 0) -> None:
+        """One call of the native point-get plane, as counted in C
+        (`mem_layer`: it was handed a memtable's mirror, which answered
+        `mem_keys` of the keys), or one event of a written bucket's
+        memtable layer (`storage/lsm.py Bucket.multi_get_packed`): a mirror
+        built, or a packed get that found none could be made and left the
+        request to the general path."""
         now = time.monotonic()
         with self._lock:
             d = self._point_get
-            d.append((now, keys, segment_probes, key_compares, arena_grows))
+            d.append((now, keys, segment_probes, key_compares, arena_grows,
+                      int(mem_layer), mem_keys, mirror_builds,
+                      overlay_fallbacks))
             horizon = now - self.window_s
             while d[0][0] < horizon:
                 d.popleft()
@@ -592,10 +604,18 @@ class PerfWindow:
             # or a fence would cut), compares a probe is about 1 for the
             # probe that hits and 0 for one that meets an empty slot, and
             # arena_grows is 0 once every serving thread has seen its
-            # largest batch
+            # largest batch. A bucket that is being written hands the call
+            # its memtable as one more layer: mem_layer_calls the calls
+            # that asked one (two a BatchSearch beside a writer, 0 where
+            # nothing is written), mem_keys the keys it answered
+            # (tombstones too), mirror_builds the native mirrors made (one
+            # a memtable generation a packed reader met), overlay_fallbacks
+            # the packed gets that found no mirror could be made (no
+            # memory) and left their request to the general path
             out["point_get"] = dict(zip(
-                ("keys", "segment_probes", "key_compares", "arena_grows"),
-                point_get))
+                ("keys", "segment_probes", "key_compares", "arena_grows",
+                 "mem_layer_calls", "mem_keys", "mirror_builds",
+                 "overlay_fallbacks"), point_get))
         if rescore:
             # the float32 rescoring of compressed dispatches over the
             # window: `rows / dispatches` is the candidates a dispatch
@@ -1151,13 +1171,12 @@ def note_read_lock_wait(ms: float) -> None:
         w.note_read_lock_wait(ms)
 
 
-def note_point_get(keys: int, segment_probes: int, key_compares: int,
-                   arena_grows: int) -> None:
+def note_point_get(*counts, **events) -> None:
     """`PerfWindow.note_point_get` on the installed window; one comparison
     while the plane is down."""
     w = _window
     if w is not None:
-        w.note_point_get(keys, segment_probes, key_compares, arena_grows)
+        w.note_point_get(*counts, **events)
 
 
 def note_rescore(rows: int, nbytes: int, promoted: int) -> None:

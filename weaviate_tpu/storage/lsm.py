@@ -140,6 +140,13 @@ class _MemReplace:
     def __init__(self):
         self.data: dict[bytes, bytes] = {}  # value or _TOMBSTONE
         self._bytes = 0
+        # the native copy of `data` that packed point gets ask
+        # (lsm_native.MemMirror), made by the first one that finds this
+        # generation non-empty (Bucket._mem_layer); `mirror_refused`: none
+        # could be made (no memory), and the general path serves this
+        # generation's point gets
+        self.mirror = None
+        self.mirror_refused = False
 
     def put(self, k, v):
         old = self.data.get(k)
@@ -547,26 +554,28 @@ class Bucket:
             with open(self._wal_path, "rb") as f:
                 self._wal_v2 = f.read(4) == _WAL_MAGIC2
         # native multi_get lifetime protection: calls run OUTSIDE the bucket
-        # lock on a segment snapshot, so compaction must retire (not close)
-        # segments while any call is in flight
+        # lock on a snapshot of the segments (and of the memtable's mirror),
+        # so compaction must retire (not close) segments, and a flush the
+        # mirror, while any call is in flight
         self._native_inflight = 0
-        self._retired_segments: list[Segment] = []
+        self._retired: list = []  # Segment | lsm_native.MemMirror
 
-    def _retire_segment(self, seg: "Segment") -> None:
-        """Close a replaced segment, or park it until in-flight native
-        reads drain (caller holds the bucket lock)."""
+    def _retire(self, handle) -> None:
+        """Close a replaced segment or a flushed memtable's mirror, or park
+        it until in-flight native reads drain (caller holds the bucket
+        lock)."""
         if self._native_inflight > 0:
-            self._retired_segments.append(seg)
+            self._retired.append(handle)
         else:
-            seg.close()
+            handle.close()
 
     def _native_exit(self) -> None:
         """Leave the native-read critical section (caller holds the lock)."""
         self._native_inflight -= 1
-        if self._native_inflight == 0 and self._retired_segments:
-            for s in self._retired_segments:
-                s.close()
-            self._retired_segments.clear()
+        if self._native_inflight == 0 and self._retired:
+            for h in self._retired:
+                h.close()
+            self._retired.clear()
 
     def _new_memtable(self):
         return {
@@ -797,6 +806,8 @@ class Bucket:
         with self._lock:
             self._wal_append(_W_PUT, key, value)
             self._mem.put(key, value)
+            if self._mem.mirror is not None:
+                self._mirror_put(key, value)
             self._maybe_flush()
 
     def put_many(self, pairs) -> None:
@@ -812,6 +823,10 @@ class Bucket:
             mput = self._mem.put
             for k, v in pairs:
                 mput(k, v)
+            for k, v in pairs:
+                if self._mem.mirror is None:
+                    break
+                self._mirror_put(k, v)
             self._maybe_flush()
 
     def delete(self, key: bytes) -> None:
@@ -819,7 +834,44 @@ class Bucket:
         with self._lock:
             self._wal_append(_W_DELETE, key)
             self._mem.delete(key)
+            if self._mem.mirror is not None:
+                self._mirror_put(key, _TOMBSTONE)
             self._maybe_flush()
+
+    # what a memtable's mirror may hold in superseded records, over the
+    # memtable's own bytes, before a fresh mirror is the cheaper one: a hot
+    # key re-put for ever grows no memtable, so nothing else bounds it
+    _MIRROR_DEAD_SLACK = 4 << 20
+
+    def _mirror_put(self, key: bytes, value: bytes) -> None:
+        """Keep the memtable's mirror in step with a put or a delete (the
+        caller holds the lock and has changed the dict): a packed get that
+        follows sees it. A mirror that ran out of memory, or holds more
+        dead bytes than its memtable live ones, is retired; the next packed
+        get makes a fresh one."""
+        mem = self._mem
+        m = mem.mirror
+        if not m.put(key, value) or \
+                m.dead > mem.approx_bytes() + self._MIRROR_DEAD_SLACK:
+            mem.mirror = None
+            self._retire(m)
+
+    def _mem_layer(self):
+        """The memtable's mirror for a packed get, made at the first one
+        that finds this generation non-empty (the caller holds the lock and
+        has seen `len(self._mem)`): a bucket nobody reads packed, every
+        import, every build, a restart's WAL replay, never makes one. None
+        where none can be made (no memory): asked once a generation."""
+        from weaviate_tpu.storage import lsm_native
+
+        mem = self._mem
+        if mem.mirror is None and not mem.mirror_refused:
+            mem.mirror = lsm_native.mem_mirror(mem.data)
+            if mem.mirror is None:
+                mem.mirror_refused = True
+            else:
+                perf.note_point_get(mirror_builds=1)
+        return mem.mirror
 
     def set_add(self, key: bytes, value: bytes) -> None:
         assert self.strategy == STRATEGY_SET
@@ -1000,50 +1052,41 @@ class Bucket:
         """Packed-buffer batched point gets for the raw serving lane:
         keys live at key_offs[i]..key_offs[i+1] in key_buf (bytes or uint8
         array; zero-length = missing upstream) -> (value buffer, offsets,
-        flags) straight from the native plane. The memtable's newer word
-        on a key (a value put, or a delete, since the last flush) is laid
-        over the segments' answer (`overlay_packed`), so a bucket that is
-        being written serves exactly too: the lane does not close on every
-        reader while ONE writer keeps a memtable non-empty. None whenever
-        the packed path cannot serve (no segments, native unavailable) —
-        the caller falls back to the general path. Without an overlay the
-        values live in the calling thread's arena: valid until that
-        thread's next packed call (lsm_native.multi_get_packed)."""
+        flags) straight from the native plane. A bucket that is being
+        written serves exactly too, and as a quiet one does: the memtable
+        (a value put, or a delete, since the last flush) is a layer the
+        ONE native call asks before the segments, through a native mirror
+        that `put` / `delete` keep in step (`_mem_layer`). The lock is held
+        for the snapshot alone (the segments and the mirror, retired and
+        never closed while this call is in flight) and no Python statement
+        runs a key; the lane does not close on every reader while ONE
+        writer keeps a memtable non-empty. None whenever the packed path
+        cannot serve (no segments, native unavailable, or no memory for a
+        written memtable's mirror: `/debug/perf` `point_get`
+        `overlay_fallbacks` counts those) — the caller falls back to the
+        general path. The values live in the calling thread's arena: valid
+        until that thread's next packed call
+        (lsm_native.multi_get_packed)."""
         assert self.strategy == STRATEGY_REPLACE
         from weaviate_tpu.storage import lsm_native
 
         with self._lock:
             if not self._segments or not lsm_native.available():
                 return None
-            newer = self._mem_words(key_buf, key_offs) if len(self._mem) \
-                else None
+            mirror = None
+            if len(self._mem):
+                mirror = self._mem_layer()
+                if mirror is None:
+                    perf.note_point_get(overlay_fallbacks=1)
+                    return None
             snapshot = list(reversed(self._segments))
             self._native_inflight += 1
         try:
-            packed = lsm_native.multi_get_packed(snapshot, key_buf, key_offs)
+            return lsm_native.multi_get_packed(snapshot, key_buf, key_offs,
+                                               mirror)
         finally:
             with self._lock:
                 self._native_exit()
-        if packed is None or not newer:
-            return packed
-        return overlay_packed(packed, newer)
-
-    def _mem_words(self, key_buf, key_offs) -> dict[int, bytes]:
-        """{position: the memtable's value or tombstone} of the packed keys
-        the memtable holds (the caller holds the lock): a dict look-up a
-        key, a few thousand a request."""
-        data = self._mem.data
-        kb = key_buf if isinstance(key_buf, bytes) else \
-            np.ascontiguousarray(key_buf).tobytes()
-        offs = np.asarray(key_offs).tolist()
-        out = {}
-        for i in range(len(offs) - 1):
-            a, b = offs[i], offs[i + 1]
-            if b > a:
-                v = data.get(kb[a:b])
-                if v is not None:
-                    out[i] = v
-        return out
 
     def set_get(self, key: bytes) -> set[bytes]:
         assert self.strategy == STRATEGY_SET
@@ -1293,6 +1336,7 @@ class Bucket:
             Segment.write(seg_path, self.strategy, items)
             self._seg_counter += 1
             self._segments.append(Segment(seg_path))
+            self._drop_mirror()
             self._mem = self._new_memtable()
             # truncate WAL (always rotates to the v2 crc-framed format)
             self._wal.close()
@@ -1301,6 +1345,15 @@ class Bucket:
             self._wal.flush()
             os.fsync(self._wal.fileno())
             self._wal_v2 = True
+
+    def _drop_mirror(self) -> None:
+        """Retire the memtable's mirror with its generation (the caller
+        holds the lock): freed once no packed get that holds it is in
+        flight."""
+        m = getattr(self._mem, "mirror", None)
+        if m is not None:
+            self._mem.mirror = None
+            self._retire(m)
 
     def segment_count(self) -> int:
         with self._lock:
@@ -1339,7 +1392,7 @@ class Bucket:
                 return False
             keep_path = pair[0].path
             for seg in pair:
-                self._retire_segment(seg)
+                self._retire(seg)
             # bloom BEFORE segment: a crash in between pairs the old segment
             # with a new bloom (false positives only — harmless); the other
             # order pairs the merged segment with a stale bloom, turning
@@ -1406,7 +1459,7 @@ class Bucket:
             old = self._segments
             self._segments = [Segment(seg_path)]
             for seg in old:
-                self._retire_segment(seg)
+                self._retire(seg)
                 os.remove(seg.path)
                 try:
                     os.remove(seg.path + ".bloom")
@@ -1426,7 +1479,7 @@ class Bucket:
             self.flush_memtable()
             self._wal.close()
             for seg in self._segments:
-                self._retire_segment(seg)  # never munmap under an in-flight read
+                self._retire(seg)  # never munmap under an in-flight read
             self._segments = []
 
     def drop(self) -> None:
@@ -1436,8 +1489,9 @@ class Bucket:
             except Exception:
                 pass
             for seg in self._segments:
-                self._retire_segment(seg)
+                self._retire(seg)
             self._segments = []
+            self._drop_mirror()
             import shutil
 
             shutil.rmtree(self.path, ignore_errors=True)

@@ -11,9 +11,14 @@ is too small does the bridge grow it and ask for the copy alone
 (`lsm_copy`): nothing is searched twice and no multi-megabyte buffer is
 mapped afresh a call.
 
+A bucket that is being written hands the call its memtable as one more
+layer, the newest (`MemMirror`: a native copy of the memtable's keys and
+values, kept in step by the bucket's puts), so the call's answer is exact
+without a Python statement a key and the values are still written once.
+
 What a call did is counted in C and handed to the perf window
 (`/debug/perf` `point_get`: `keys`, `segment_probes`, `key_compares`,
-`arena_grows`) while the tracer is up.
+`arena_grows`, `mem_layer_calls`, `mem_keys`) while the tracer is up.
 
 Reference analog: the compiled lsmkv segment readers under the batched
 hydration seam entities/storobj/storage_object.go:211.
@@ -70,6 +75,7 @@ def _load() -> Optional[ctypes.CDLL]:
             lib.lsm_multi_get.restype = ctypes.c_int64
             lib.lsm_multi_get.argtypes = [
                 ctypes.POINTER(ctypes.c_void_p), ctypes.c_int64,
+                ctypes.c_void_p,
                 ctypes.POINTER(ctypes.c_ubyte), ctypes.POINTER(ctypes.c_int64),
                 ctypes.c_int64,
                 ctypes.POINTER(ctypes.c_void_p), ctypes.POINTER(ctypes.c_int64),
@@ -83,15 +89,23 @@ def _load() -> Optional[ctypes.CDLL]:
             ]
             lib.lsm_key_hash.restype = ctypes.c_uint64
             lib.lsm_key_hash.argtypes = [ctypes.c_char_p, ctypes.c_int64]
-            # the calls on postings KEEP the GIL (a PYFUNCTYPE prototype,
-            # where the library's own attributes release it): most take
-            # microseconds, and a thread that lets go of the GIL gets it
-            # back only when whichever thread took it gives it up, up to a
-            # switch interval later (PERF.md section 6, PR 31: the same
-            # copies through numpy, which lets go, cost the filtered cell
-            # 65 ms a request). Addresses go as integers.
             ptr, i64 = ctypes.c_void_p, ctypes.c_int64
+            lib.lsm_mem_open.restype = ptr
+            lib.lsm_mem_open.argtypes = []
+            lib.lsm_mem_close.restype = None
+            lib.lsm_mem_close.argtypes = [ptr]
+            # the calls on postings, and a put into a memtable's mirror
+            # (the writer's, under the bucket's lock), KEEP the GIL (a
+            # PYFUNCTYPE prototype, where the library's own attributes
+            # release it): most take microseconds, and a thread that lets
+            # go of the GIL gets it back only when whichever thread took it
+            # gives it up, up to a switch interval later (PERF.md section
+            # 6, PR 31: the same copies through numpy, which lets go, cost
+            # the filtered cell 65 ms a request). Addresses go as integers.
             for name, res, args in (
+                    ("mem_put", i64,
+                     (ptr, ctypes.c_char_p, i64, ctypes.c_char_p, i64)),
+                    ("mem_stats", None, (ptr, ptr)),
                     ("posting_locate", i64,
                      (ptr, i64, ctypes.c_char_p, i64, ptr, ptr, ptr)),
                     ("posting_copy", i64, (ptr, ptr, i64, ptr)),
@@ -160,6 +174,58 @@ def seg_close(segment) -> None:
     segment._native_handle = None
 
 
+class MemMirror:
+    """A REPLACE memtable as a layer `multi_get_packed` asks before the
+    segments (native/lsm_get.cpp `lsm_mem_*`): the handle holds its OWN
+    copy of every key and value put since it was made, in chunks that never
+    move, so a packed get probes it outside the bucket's lock while the
+    writer goes on. One thread puts at a time (the bucket's lock is held);
+    `close` only once no call that was handed the mirror is in flight
+    (`Bucket._native_inflight`). `dead` is the bytes of records that newer
+    puts of their keys superseded: they stay held until `close`."""
+
+    __slots__ = ("_lib", "_h", "dead")
+
+    def __init__(self, lib, handle: int):
+        self._lib, self._h, self.dead = lib, handle, 0
+
+    def put(self, key: bytes, value: bytes) -> bool:
+        """The memtable's word on `key` from now on (`_TOMBSTONE`: gone).
+        False where memory ran out: the mirror no longer holds what its
+        memtable holds and must not be asked again."""
+        self.dead = self._lib.mem_put(self._h, key, len(key), value,
+                                      len(value))
+        return self.dead >= 0
+
+    def stats(self) -> dict:
+        out = np.empty(3, dtype=np.int64)
+        self._lib.mem_stats(self._h, out.ctypes.data)
+        return dict(zip(("keys", "held_bytes", "dead_bytes"), out.tolist()))
+
+    def close(self) -> None:
+        if self._h:
+            self._lib.lsm_mem_close(self._h)
+            self._h = 0
+
+    __del__ = close   # a bucket dropped without `shutdown` (tests)
+
+
+def mem_mirror(data: dict) -> Optional[MemMirror]:
+    """A mirror of a REPLACE memtable's `data` (key -> value or
+    `_TOMBSTONE`); None where the library or the memory for it is missing.
+    The caller holds the bucket's lock: nothing is put meanwhile."""
+    lib = _load()
+    handle = lib.lsm_mem_open() if lib is not None else None
+    if not handle:
+        return None
+    m = MemMirror(lib, handle)
+    for k, v in data.items():
+        if not m.put(k, v):
+            m.close()
+            return None
+    return m
+
+
 _ARENA_MIN = 1 << 16
 
 
@@ -184,14 +250,17 @@ _arena = _Arena()
 
 
 def multi_get_packed(
-    segments_newest_first: Sequence, key_buf, key_offs: np.ndarray
+    segments_newest_first: Sequence, key_buf, key_offs: np.ndarray,
+    mem: Optional[MemMirror] = None,
 ) -> Optional[tuple[np.ndarray, np.ndarray, np.ndarray]]:
     """Packed-buffer batched gets: keys at key_offs[i]..key_offs[i+1] in
     key_buf (bytes or uint8 array; zero-length = missing upstream). ->
     (values uint8 array, offsets int64 [n+1], flags int8 [n]), or None =>
     Python fallback. The layout feeds the packed reply builder and
     call-chaining (one call's values are the next call's keys) without any
-    per-value Python objects. Caller owns segment lifetime.
+    per-value Python objects. `mem`: the memtable's mirror, asked before
+    the segments inside the same call (its value is the answer, its
+    tombstone a miss). Caller owns the segments' and the mirror's lifetime.
 
     LIFETIME: the values are a view of an arena this thread keeps, valid
     until this thread's next packed call. That call may take them as its
@@ -212,14 +281,15 @@ def multi_get_packed(
     out_offs = np.empty(n + 1, dtype=np.int64)
     flags = np.empty(n, dtype=np.int8)
     srcs = np.empty(n, dtype=np.uintp)
-    stats = np.empty(2, dtype=np.int64)
+    stats = np.empty(3, dtype=np.int64)
     seg_arr = (ctypes.c_void_p * len(handles))(*handles)
     p_u8, p_i64 = ctypes.POINTER(ctypes.c_ubyte), ctypes.POINTER(ctypes.c_int64)
     srcs_ptr = srcs.ctypes.data_as(ctypes.POINTER(ctypes.c_void_p))
     offs_ptr = out_offs.ctypes.data_as(p_i64)
     arena, grew = _arena.buf, 0
     need = lib.lsm_multi_get(
-        seg_arr, len(handles), _as_u8_ptr(key_buf),
+        seg_arr, len(handles), mem._h if mem is not None else None,
+        _as_u8_ptr(key_buf),
         key_offs.ctypes.data_as(p_i64), n, srcs_ptr, offs_ptr,
         flags.ctypes.data_as(ctypes.POINTER(ctypes.c_int8)),
         stats.ctypes.data_as(p_i64), arena.ctypes.data_as(p_u8), arena.size)
@@ -228,7 +298,8 @@ def multi_get_packed(
     if need > arena.size:   # located, not copied: the copy alone, no search
         arena, grew = _arena.grow(need), 1
         lib.lsm_copy(srcs_ptr, offs_ptr, n, arena.ctypes.data_as(p_u8))
-    perf.note_point_get(n, int(stats[0]), int(stats[1]), grew)
+    perf.note_point_get(n, int(stats[0]), int(stats[1]), grew,
+                        mem is not None, int(stats[2]))
     return arena[:need], out_offs, flags
 
 
