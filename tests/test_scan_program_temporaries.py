@@ -176,3 +176,57 @@ def test_the_compiler_option_is_what_holds_the_mesh_program(topo):
     assert compiled.memory_analysis().temp_size_in_bytes > \
         MESH_LOC * DIM * 2 // 2
     assert _slab_wide_bf16_converts(compiled.as_text(), MESH_LOC) != []
+
+
+# -- cohere-768-cos-10m-share: a slab the chip holds once, not twice ----------
+
+SHARE_CAP, SHARE_ROWS = 20 * 131072, 2_500_000      # 8.05 GB of 16.9
+
+
+def test_the_share_scan_program_fits_beside_its_slab(one_chip):
+    """cohere-768-cos-10m-share.batch256's program: 20 scan chunks over an
+    8.05 GB slab, and no temporary worth naming beside it."""
+    from weaviate_tpu.config.config import RESCORE_R_BUCKETS
+    from weaviate_tpu.index import tpu
+
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    compiled = tpu._search_full_fused.lower(
+        S((SHARE_CAP, DIM), jnp.float32), None, S((SHARE_CAP,), jnp.bool_),
+        S((), jnp.int32), S((256, DIM), jnp.float32),
+        S((SHARE_CAP // 32,), jnp.uint32), S((SHARE_CAP, 2), jnp.uint32),
+        k=K, metric="cosine", use_allow=False, exact=False,
+        active_chunks=-(-SHARE_ROWS // tpu._SCAN_CHUNK),
+        rescore_r=min(max(4 * K, RESCORE_R_BUCKETS[0]),
+                      RESCORE_R_BUCKETS[-1])).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < TEMP_LIMIT
+    assert _slab_wide_bf16_converts(compiled.as_text(), SHARE_CAP) == []
+
+
+@pytest.mark.parametrize("kernel", ["_write_rows", "_write_slots",
+                                    "_set_tombstones", "_write_doc_pairs"])
+def test_a_donating_write_kernel_overwrites_every_array_it_writes(
+        one_chip, kernel):
+    """The in-place twins at the share's shapes: the compiler aliases every
+    written array to its input (no second slab, no temporary), where the
+    functional kernels' outputs are new arrays as large as their inputs."""
+    from weaviate_tpu.index import tpu
+
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    slab = S((SHARE_CAP, DIM), jnp.float32)
+    s2d, tombs = S((SHARE_CAP, 2), jnp.uint32), S((SHARE_CAP,), jnp.bool_)
+    idx = S((128,), jnp.int32)
+    args = {
+        "_write_rows": (slab, S((8192, DIM), jnp.float32), S((), jnp.int32)),
+        "_write_slots": (slab, None, s2d, tombs, idx,
+                         S((128, DIM), jnp.float32), None,
+                         S((128, 2), jnp.uint32), idx),
+        "_set_tombstones": (tombs, idx),
+        "_write_doc_pairs": (s2d, idx, S((128, 2), jnp.uint32)),
+    }[kernel]
+    plain = getattr(tpu, kernel)
+    given = tpu._IN_PLACE[plain].lower(*args).compile().memory_analysis()
+    made = plain.lower(*args).compile().memory_analysis()
+    assert given.alias_size_in_bytes >= given.output_size_in_bytes - 1024
+    assert given.temp_size_in_bytes < TEMP_LIMIT
+    assert made.alias_size_in_bytes == 0
+    assert made.output_size_in_bytes >= given.alias_size_in_bytes

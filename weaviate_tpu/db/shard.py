@@ -322,9 +322,11 @@ class Shard:
         still finds the object (`_replaced`)."""
         self.inverted.delete_object(prev.doc_id, prev.properties)
         self._geo_delete(prev.doc_id, prev.properties)
-        self.docid_lookup.delete(struct.pack("<Q", prev.doc_id))
         if key:
+            # before the lookup forgets the doc id: a reader that misses
+            # there (`_uuid_keys`, `_hydrate_packed`) must find it here
             self._replaced[prev.doc_id] = (key, time.monotonic())
+        self.docid_lookup.delete(struct.pack("<Q", prev.doc_id))
         if replaced is not None:
             replaced.append(prev.doc_id)
         else:

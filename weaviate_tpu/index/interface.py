@@ -15,6 +15,13 @@ from typing import Optional, Sequence
 import numpy as np
 
 
+class SnapshotRetired(RuntimeError):
+    """A caller that kept an index snapshot asked for its device rows after
+    a writer had overwritten that generation in place (index/tpu.py
+    `_retire_snapshot`). Searches never see it: they dispatch on a snapshot
+    only while they hold a pin on it."""
+
+
 class AllowList(abc.ABC):
     """Filter result container (reference helpers/allow_list.go:19-29)."""
 

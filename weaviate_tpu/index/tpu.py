@@ -60,7 +60,8 @@ import numpy as np
 from weaviate_tpu import device
 from weaviate_tpu.entities import vectorindex as vi
 from weaviate_tpu.index import group_inputs
-from weaviate_tpu.index.interface import AllowList, VectorIndex
+from weaviate_tpu.index.interface import (AllowList, SnapshotRetired,
+                                          VectorIndex)
 # dispatch-shape recording for the perf-attribution plane: a
 # costmodel.DispatchShape is built per dispatch ONLY while the tracer is
 # up (tracing.get_tracer() gate — the zero-cost-when-disabled contract)
@@ -218,11 +219,34 @@ def _bucket_rows(n: int) -> int:
     return b
 
 
-# the write kernels deliberately do NOT donate their input buffers:
-# snapshot-isolated readers (IndexSnapshot) may still be dispatching on the
-# previous array generation, and donation would invalidate the buffer under
-# an in-flight search. Copy-on-write costs one transient extra copy per
-# flush on the WRITE path — the trade that makes the read path lock-free.
+# Every write kernel exists twice: as written here, a functional update that
+# leaves the arrays it was given valid, and DONATING (`_IN_PLACE`, below),
+# which overwrites them where they lie. Which one a write runs is
+# `TpuVectorIndex._in_place`'s to say:
+#
+# - arrays no published IndexSnapshot holds (a restore's and a compaction's
+#   rebuild, every chunk of a write after its first) are donated: nobody can
+#   still be handed them, and a slab that is landed 8,192 rows a program
+#   stays ONE generation instead of as many as the launch queue holds;
+# - arrays the published snapshot holds are copied (copy-on-write: lock-free
+#   readers may still dispatch on them) as long as the memory ledger says a
+#   second generation fits the device beside the first;
+# - where it does not fit (a slab over about 45% of the chip), the writer
+#   retires the snapshot first: searches that are inside their enqueue on it
+#   finish it (the writer waits for them: microseconds to a few ms of host
+#   work, `/debug/perf` `writes.reader_wait_ms`), searches that come after
+#   take the index lock and wait for the write's snapshot, and the write
+#   goes in place. A search that was ENQUEUED before the write still gets
+#   the rows it was dispatched on: the device runs its programs in order,
+#   and a donated buffer is overwritten only after every program that was
+#   enqueued on it has read it. What a reader is never handed is a deleted
+#   array: it dispatches on a snapshot only between `_pin` and `_unpin`.
+#
+# What is retired and pinned is not one snapshot but the `ArrayLease` every
+# snapshot published since the last donation shares: a delete that copies
+# the tombstone bits publishes a snapshot whose slab is still its
+# predecessor's, and a reader that kept the predecessor must be waited for
+# and turned away exactly like one that holds the newest.
 @jax.jit
 def _write_rows(store, chunk, offset):
     return jax.lax.dynamic_update_slice(store, chunk, (offset, 0))
@@ -243,9 +267,7 @@ def _set_tombstones(tombs, idx):
 def _write_doc_pairs(s2d, idx, pairs):
     """Scatter doc-id word pairs into the device slot->doc table. idx is
     padded (to a _bucket_rows width, bounding jit shapes) with an
-    out-of-range sentinel; mode="drop" ignores the padding rows. Like
-    every write kernel: non-donating, so snapshots pinning the previous
-    table generation can never tear."""
+    out-of-range sentinel; mode="drop" ignores the padding rows."""
     return s2d.at[idx].set(pairs, mode="drop")
 
 
@@ -258,9 +280,7 @@ def _write_slots(store, sq_norms, s2d, tombs, slots, rows, norms, pairs,
     into the slot->doc table, the slots' tombstone bits cleared and the
     bits of `dead` set. Every index array is padded to a `_bucket_rows`
     width with an out-of-range sentinel that mode="drop" ignores, so a
-    batch of 100 uploads 128 rows, not a `_CHUNK`. Non-donating like every
-    write kernel: a snapshot that pins the previous generation keeps
-    reading the rows, docs and tombstones it was published with."""
+    batch of 100 uploads 128 rows, not a `_CHUNK`."""
     store = store.at[slots].set(rows.astype(store.dtype), mode="drop")
     if sq_norms is not None:
         sq_norms = sq_norms.at[slots].set(norms, mode="drop")
@@ -268,6 +288,20 @@ def _write_slots(store, sq_norms, s2d, tombs, slots, rows, norms, pairs,
     tombs = tombs.at[dead].set(True, mode="drop")
     tombs = tombs.at[slots].set(False, mode="drop")
     return store, sq_norms, s2d, tombs
+
+
+# the donating twins: same function, same program name, the written arrays
+# given away (docs/concurrency.md "When a write copies and when it goes in
+# place")
+_IN_PLACE = {
+    _write_rows: jax.jit(_write_rows.__wrapped__, donate_argnums=0),
+    _write_norms: jax.jit(_write_norms.__wrapped__, donate_argnums=0),
+    _set_tombstones: jax.jit(_set_tombstones.__wrapped__, donate_argnums=0),
+    _write_doc_pairs: jax.jit(_write_doc_pairs.__wrapped__,
+                              donate_argnums=0),
+    _write_slots: jax.jit(_write_slots.__wrapped__,
+                          donate_argnums=(0, 1, 2, 3)),
+}
 
 
 @jax.jit
@@ -292,6 +326,19 @@ def _scatter_bucket(buckets, parts, cols, slots):
 # an unwritten slot reassembles to 2**64-1 — the same "missing" id the
 # kernels' idx -1 sentinel produces, never a plausible doc id
 _S2D_FILL = 0xFFFFFFFF
+
+
+# rows a host fetch of the slab asks for at a time (`_read_rows`)
+_HOST_PIECE = 65536
+
+
+@jax.jit
+def _read_rows(store, offset):
+    """`_HOST_PIECE` rows of the slab from `offset` (all of a slab that
+    holds fewer): what a host fetch asks for a piece at a time
+    (`TpuVectorIndex._fetch_rows`)."""
+    return jax.lax.dynamic_slice_in_dim(
+        store, offset, min(store.shape[0], _HOST_PIECE), 0)
 
 
 @functools.partial(jax.jit, static_argnames=("new_cap",))
@@ -1368,6 +1415,22 @@ def _live_runs(events, stats: dict):
     return out
 
 
+class ArrayLease:
+    """What the snapshots published between two donations have in common:
+    device arrays that may be the same objects from one snapshot to the
+    next (a delete copies the tombstone bits and publishes the slab it
+    found). So the searches inside their enqueue are counted here, on all of
+    those snapshots together (`pins`), and a writer that takes the arrays
+    back takes them from all of them at once (`retired`). Both under the
+    index's `_inflight_lock`: `_pin`, `_unpin`, `_retire_snapshot`."""
+
+    __slots__ = ("pins", "retired")
+
+    def __init__(self):
+        self.pins = 0
+        self.retired = False
+
+
 class IndexSnapshot:
     """One immutable published generation of the device state a search
     dispatch reads.
@@ -1379,9 +1442,13 @@ class IndexSnapshot:
     the index's attributes to new arrays but can never tear an in-flight
     dispatch, because
 
-      - the device write kernels do not donate (every update REPLACES the
-        array object, the old buffer stays valid until the last snapshot
-        holding it drops), and
+      - a write program is given the arrays of the PUBLISHED snapshot to
+        overwrite only after its `lease` was retired (`ArrayLease`: no
+        search can pin a snapshot of that lease any more, and none is
+        still between `_pin` and `_unpin` on one); until then every update
+        REPLACES the array object and the old buffer stays valid until the
+        last snapshot holding it drops (module comment above
+        `_write_rows`), and
       - the host-side `host_tombs` mirror is copy-on-written by any
         writer that would mutate an array a published snapshot still
         references; `slot_to_doc` needs NO copy — writers only assign
@@ -1406,10 +1473,13 @@ class IndexSnapshot:
                  "pq4", "codes4", "recon_norms4", "opq_rot",
                  "ivf_centroids", "ivf_buckets", "ivf_pca_proj",
                  "ivf_pca_rows", "ivf_meta", "docs_ascending",
-                 "doc_order", "s2d_cols")
+                 "doc_order", "s2d_cols", "lease")
 
     def __init__(self, gen: int, idx: "TpuVectorIndex"):
         self.gen = gen
+        # shared with every snapshot published since the last donation:
+        # their device arrays may be the same objects as this one's
+        self.lease = idx._lease
         self.dim = idx.dim
         self.capacity = idx.capacity
         self.n = idx.n
@@ -1541,6 +1611,12 @@ class TpuVectorIndex(VectorIndex):
         self._inflight_lock = sanitizers.register_lock(
             threading.Lock(), "index.tpu.inflight")  # ...and finalize
         self._inflight_gauge = None  # resolved lazily (None) / broken (False)
+        # a writer that overwrites the published generation waits here for
+        # the searches enqueueing on it (`_retire_snapshot`)
+        self._pin_cv = threading.Condition(self._inflight_lock)
+        # the lease the next published snapshot takes; replaced at every
+        # retirement (under the index lock)
+        self._lease = ArrayLease()
         # staging buffer keyed by doc_id: a re-add of a staged doc replaces it
         self._pending: dict[int, np.ndarray] = {}
         self._pending_tombs: list[int] = []
@@ -1553,7 +1629,8 @@ class TpuVectorIndex(VectorIndex):
         # what the write path did, lifetime (health(); the perf window
         # keeps the same counts a window: monitoring/perf.py `writes`)
         self._wstats = {"slots_reused": 0, "slots_appended": 0,
-                        "tombstones_applied": 0, "grows": 0}
+                        "tombstones_applied": 0, "grows": 0,
+                        "writes_in_place": 0, "writes_copied": 0}
         # PQ state (compress.go analog): when compressed, the device holds
         # [cap, M] uint8/16 codes instead of floats; full-precision rows move
         # to host RAM for the rescoring pass
@@ -1574,6 +1651,11 @@ class TpuVectorIndex(VectorIndex):
         self._recon_norms4 = None           # device f32 [capacity]
         self._opq_rot_dev = None            # device f32 [D, D] (or None)
         self._pq4_path = os.path.join(shard_path, "pq4.npz")
+        # the capacity the shard last grew to, for the restart to come back
+        # to whatever the device's budget reads then (`_record_capacity`);
+        # and that number while a restore runs, 0 at every other time
+        self._capacity_path = os.path.join(shard_path, "capacity")
+        self._recorded = 0
         self._restoring = False
         # the stage sums of the restore that is running (tracing.StageSums),
         # None at every other time: the write path's pieces gate on it
@@ -1707,6 +1789,10 @@ class TpuVectorIndex(VectorIndex):
                 self._restore_sums = None
             sums.publish()
             st.note(rows=self.n, capacity=self.capacity)
+            tl = perf.timeline()
+            if tl is not None:
+                # what `slab_bytes_copied` is held against
+                tl.count(slab_bytes=self._slab_bytes())
         self.last_restore = restore_record(
             "compressed" if self.compressed else "uncompressed", self.n, st,
             sums, replay_stats,
@@ -1722,6 +1808,7 @@ class TpuVectorIndex(VectorIndex):
         returns."""
         try:
             self._pending_pq = self._load_persisted_pq()
+            self._recorded = self._recorded_capacity()
             sums.enter("stage")
             events = VectorLog.replay_batches(
                 self._log.path, stats=replay_stats,
@@ -1756,6 +1843,7 @@ class TpuVectorIndex(VectorIndex):
         finally:
             self._restoring = False
             self._pending_pq = None
+            self._recorded = 0
 
     def _live_events(self, events, stats: dict):
         """The replay's live add runs (`_live_runs`), after the condensor:
@@ -1861,9 +1949,7 @@ class TpuVectorIndex(VectorIndex):
     def _ensure_capacity(self, needed: int) -> None:
         if self._store is None and self._codes is None:
             raise RuntimeError("store not initialised")
-        cap = self.capacity
-        while cap < needed:
-            cap *= 2  # geometric growth (maintainance.go:31)
+        cap = self._ladder_capacity(needed)
         if cap != self.capacity:
             faults.fire("index.tpu.alloc")
             sums = self._restore_sums
@@ -1885,7 +1971,7 @@ class TpuVectorIndex(VectorIndex):
                     self._recon_norms4 = _grow_1d(
                         self._recon_norms4, cap, jnp.float32(0))
             else:
-                self._store = _grow_store(self._store, cap)
+                self._grow_slab(cap)
                 self._sq_norms = _grow_1d(self._sq_norms, cap, jnp.float32(0))
             self._tombs = _grow_1d(self._tombs, cap, False)
             if self._s2d_dev is not None:
@@ -1903,6 +1989,7 @@ class TpuVectorIndex(VectorIndex):
             ht[: self.capacity] = self._host_tombs
             self._host_tombs = ht
             self.capacity = cap
+            self._record_capacity()
             self._wstats["grows"] += 1
             self._note_write(grows=1, slab_bytes_copied=self._slab_bytes())
             led = memory.get_ledger()
@@ -1913,6 +2000,216 @@ class TpuVectorIndex(VectorIndex):
             if sums is not None:
                 sums.leave(cap)
 
+    def _ladder_capacity(self, needed: int) -> int:
+        """The capacity that holds `needed` slots: the least rung of a
+        ladder that depends on the rows' width and the device's budget
+        alone, so an import that climbs it rung by rung and the restart
+        that asks for all its rows at once end on the same one. The rungs
+        double (maintainance.go:31) while the doubled slab still fits the
+        device BESIDE the one it grows from; past that a doubling would
+        ask for memory no chip of this kind has (from 2^21 rows of 768-d
+        float32, 6.4 GB, to 12.9 GB beside them), and the rungs go up a
+        quarter at a time, in whole scan chunks (docs/memory.md "Growth").
+        The budget is the memory ledger's; where it is unknown (cpu, no
+        ledger) every rung doubles, as it always did. A compressed index
+        keeps its doubling (`ROADMAP.md` Queue 2 A1).
+
+        The budget may read otherwise after a restart (another
+        `MEMORY_DEVICE_BUDGET_BYTES`, another alert reserve), and the ladder
+        with it: a restore comes back to the capacity the shard recorded
+        when it last grew (`_recorded`), where that holds the rows and
+        today's budget holds it."""
+        cap = self.capacity
+        usable = None
+        if not self.compressed:
+            led = memory.get_ledger()
+            usable = led.device_usable_bytes() if led is not None else None
+        row = self._row_bytes()
+        rec = 0 if self.compressed else self._recorded
+        if rec >= max(needed, cap) and (
+                usable is None or rec * row <= usable):
+            return rec
+        while cap < needed:
+            if usable is None or 3 * cap * row <= usable:
+                cap *= 2
+            else:
+                cap = -(-(cap + cap // 4) // _SCAN_CHUNK) * _SCAN_CHUNK
+        return cap
+
+    def _record_capacity(self) -> None:
+        """Write the capacity beside the vector log, whole or not at all: a
+        restart reads it before it replays (`_recorded_capacity`)."""
+        if self._log is None:
+            return
+        tmp = self._capacity_path + ".tmp"
+        with open(tmp, "w") as f:
+            f.write(f"{self.capacity}\n")
+        os.replace(tmp, self._capacity_path)
+
+    def _recorded_capacity(self) -> int:
+        """The capacity the shard recorded last, 0 where it recorded none
+        (it never grew, or an earlier build wrote its state) or what is
+        there cannot be read: the ladder alone decides then."""
+        try:
+            with open(self._capacity_path) as f:
+                return max(int(f.read()), 0)
+        except (OSError, ValueError):
+            return 0
+
+    def _row_bytes(self) -> int:
+        """Device bytes a slot of the uncompressed form holds: its row, its
+        squared norm (l2), its doc-id words, its tombstone bit."""
+        return ((self.dim or 0) * jnp.dtype(self.dtype).itemsize
+                + (4 if self.metric == vi.DISTANCE_L2 else 0) + 8 + 1)
+
+    def _grow_slab(self, cap: int) -> None:
+        """The float slab at capacity `cap`, the rows it holds kept. Beside
+        the old slab where the new one fits whichever way the old one lies
+        in the device's address space (twice its bytes are free under the
+        ledger's line: a free half on either side is enough); else THROUGH
+        THE HOST: the rows are fetched, the old slab is given back, the new
+        one is made in the memory that frees and the rows are landed again.
+        The allocator does not move what lives, so after a few doublings
+        the free memory is two holes around the slab and a larger slab can
+        fit neither, however much is free in all. Minutes apart in a
+        shard's life and seconds long; searches wait for it at the index
+        lock like for any write."""
+        new_bytes = cap * (self.dim or 0) * self._store.dtype.itemsize
+        if self._copy_fits(2 * new_bytes):
+            self._store = _grow_store(self._store, cap)
+            self._stamp_memory()
+            return
+        # nobody may be handed the old slab from here on
+        self._retire_snapshot()
+        rows = -(-self.n // _CHUNK) * _CHUNK
+        old = self._store
+        # the whole array, not a slice of it (a slice is a second array on
+        # the device); the fetch returns when the slab's last writer has run
+        host = np.asarray(old) if rows else None  # graftlint: disable=JGL001 a slab that cannot grow beside itself moves through the host, under the lock like compact's rebuild: nothing may read or write it meanwhile
+        self._store = None
+        self._row_store_cache = None
+        self._blk_cache.clear()
+        old.delete()
+        try:
+            self._store = self._land_slab(cap, host, rows, old.dtype)
+        except Exception:
+            # the larger slab could not be made or filled: the rows go back
+            # into one of the size they came from, which the device held a
+            # moment ago, and the write that asked for more fails alone
+            self._store = self._land_slab(old.shape[0], host, rows, old.dtype)
+            raise
+        finally:
+            self._stamp_memory()
+
+    def _land_slab(self, cap: int, host: Optional[np.ndarray], rows: int,
+                   dtype):
+        """A new slab of `cap` rows that nobody else holds yet, with the
+        first `rows` of `host` landed in it."""
+        store = jax.device_put(jnp.zeros((cap, self.dim), dtype), self.device)
+        write = _IN_PLACE[_write_rows]
+        for off in range(0, rows, _CHUNK):
+            store = write(store, jnp.asarray(host[off : off + _CHUNK]), off)
+        return store
+
+    def _copy_fits(self, nbytes: int) -> bool:
+        """Does the memory ledger leave room for `nbytes` more on the
+        device (`MemoryLedger.device_room`: the budget less its alert
+        reserve less every stamped component)? Yes where nobody knows the
+        budget (cpu, no ledger): the allocator is then the only judge, as
+        it always was."""
+        led = memory.get_ledger()
+        if led is None:
+            return True
+        room = led.device_room()
+        return room is None or nbytes <= room
+
+    def _in_place(self, *arrays) -> bool:
+        """May the next write program be GIVEN `arrays` (donated, to
+        overwrite where they lie), or must it leave them valid and make new
+        ones? The module comment above `_write_rows` has the rule. The
+        caller holds the lock; where this returns True for arrays the
+        published snapshot held, that snapshot has been retired and the
+        write must end in `_publish_snapshot`, as every write does."""
+        if self.compressed or self._ivf_centroids_host is not None:
+            return False
+        snap = self._snap
+        if snap is None or snap.lease.retired:
+            return True
+        held = (snap.store, snap.sq_norms, snap.tombs, snap.slot_to_doc_dev)
+        if not any(a is h for a in arrays if a is not None for h in held):
+            return True
+        # the functional kernel makes every array it writes anew
+        if self._copy_fits(sum(memory.array_bytes(a) for a in arrays)):
+            return False
+        self._retire_snapshot()
+        return True
+
+    def _run_write(self, kernel, *args, written: int = 1):
+        """Run a write kernel over its first `written` arguments, the arrays
+        it writes: its donating twin where `_in_place` gives them away,
+        else the functional one; counted either way."""
+        arrays = args[:written]
+        if self._in_place(*arrays):
+            self._wstats["writes_in_place"] += 1
+            self._note_write(writes_in_place=1)
+            return _IN_PLACE[kernel](*args)
+        copied = sum(memory.array_bytes(a) for a in arrays)
+        self._wstats["writes_copied"] += 1
+        self._note_write(writes_copied=1, slab_bytes_copied=copied)
+        led = memory.get_ledger()
+        if led is not None:
+            # old and new generation are both alive while the copy runs
+            led.note_cow(0, transient_peak=copied)
+        return kernel(*args)
+
+    def _retire_snapshot(self) -> None:
+        """Take the published device arrays back (the caller holds the lock
+        and will publish): from here no search can pin the published
+        snapshot NOR any earlier one of its lease, which may hold the same
+        arrays (`_pin` sends them to `_read_snapshot`'s slow path, where
+        they wait at the lock for this write's snapshot), and the searches
+        that are inside their enqueue on any of them are waited for. They
+        hold no lock there, so the wait is their host work: microseconds to
+        a few ms. What is published next takes a lease of its own."""
+        snap = self._snap
+        if snap is None or snap.lease.retired:
+            return
+        lease = snap.lease
+        self._lease = ArrayLease()
+        t0 = time.perf_counter()
+        with self._pin_cv:
+            lease.retired = True
+            while lease.pins:
+                self._pin_cv.wait()
+        self._note_write(
+            reader_wait_ms=(time.perf_counter() - t0) * 1000.0)
+
+    def _pin(self, snap: IndexSnapshot,
+             follow: bool = True) -> Optional[IndexSnapshot]:
+        """-> the snapshot to enqueue on: `snap`, pinned, or where a writer
+        has retired its lease the next published one, pinned (`follow`
+        False: None). Until `_unpin` no write program is given its device
+        arrays. A retired lease that is still pinned may be pinned again (a
+        group's dispatches share one snapshot): its writer is still
+        waiting, and proceeds only once it has seen no pin under the
+        lock."""
+        while True:
+            lease = snap.lease
+            with self._pin_cv:
+                if not lease.retired or lease.pins:
+                    lease.pins += 1
+                    return snap
+            if not follow:
+                return None
+            snap = self._read_snapshot()
+
+    def _unpin(self, snap: IndexSnapshot) -> None:
+        lease = snap.lease
+        with self._pin_cv:
+            lease.pins -= 1
+            if lease.retired and not lease.pins:
+                self._pin_cv.notify_all()
+
     def _write_block(self, rows: np.ndarray, start: int) -> None:
         """Land [count, D] float32 rows at slots [start, start+count): every
         write path's one way in (flush, bulk import, restore, compact)."""
@@ -1921,10 +2218,13 @@ class TpuVectorIndex(VectorIndex):
             self._land_rows(rows, start)
             self._ivf_on_rows_written(rows, start)
         chunks = -(-rows.shape[0] // _CHUNK)
-        # every chunk is a whole `_CHUNK` uploaded and a new generation of
-        # each array it lands in
-        self._note_write(upload_bytes=chunks * _CHUNK * self.dim * 4,
-                        slab_bytes_copied=chunks * self._slab_bytes())
+        # every chunk is a whole `_CHUNK` uploaded and, compressed, a new
+        # generation of each array it lands in (the float slab's chunks are
+        # counted where they run: `_run_write`)
+        self._note_write(
+            upload_bytes=chunks * _CHUNK * self.dim * 4,
+            slab_bytes_copied=(chunks * self._slab_bytes()
+                               if self.compressed else 0))
         led = memory.get_ledger()
         if led is not None:
             led.note_write_shape(
@@ -1968,10 +2268,13 @@ class TpuVectorIndex(VectorIndex):
                             start + off,
                         )
             else:
-                self._store = _write_rows(self._store, jnp.asarray(chunk, self.dtype), start + off)
+                self._store = self._run_write(
+                    _write_rows, self._store, jnp.asarray(chunk, self.dtype),
+                    start + off)
                 if self.metric == vi.DISTANCE_L2:
                     nchunk = jnp.asarray((chunk.astype(np.float64) ** 2).sum(1).astype(np.float32))
-                    self._sq_norms = _write_norms(self._sq_norms, nchunk, start + off)
+                    self._sq_norms = self._run_write(
+                        _write_norms, self._sq_norms, nchunk, start + off)
             off += take
         if self.compressed:
             self._host_vecs[start : start + count] = rows
@@ -2102,8 +2405,8 @@ class TpuVectorIndex(VectorIndex):
         pad = _bucket_rows(count)
         idx = np.full(pad, self.capacity + 1, dtype=np.int32)
         idx[:count] = np.arange(start, start + count, dtype=np.int32)
-        self._s2d_dev = _write_doc_pairs(
-            self._s2d_dev, jnp.asarray(idx),
+        self._s2d_dev = self._run_write(
+            _write_doc_pairs, self._s2d_dev, jnp.asarray(idx),
             jnp.asarray(_doc_pairs(docs, pad)))
         led = memory.get_ledger()
         if led is not None:
@@ -2140,9 +2443,18 @@ class TpuVectorIndex(VectorIndex):
     def _note_write(self, **counts) -> None:
         """Add to `/debug/perf` `writes` (monitoring/perf.py
         WRITE_COUNTERS): what SERVING writes did. A restore lands the
-        corpus through the same code and is the restart timeline's."""
+        corpus through the same code and is the restart timeline's: its
+        whole-array copies and its grows are `startup`'s counters
+        (perf.RESTORE_COUNTERS)."""
         if not self._restoring:
             perf.note_write(**counts)
+            return
+        tl = perf.timeline()
+        if tl is not None:
+            kept = {k: v for k, v in counts.items()
+                    if k in perf.RESTORE_COUNTERS}
+            if kept:
+                tl.count(**kept)
 
     def _reuse_refused(self) -> Optional[str]:
         """Why this index never hands a dead slot to the next row (None: it
@@ -2221,7 +2533,10 @@ class TpuVectorIndex(VectorIndex):
             self.n += small
         if rest - small:
             tail_docs, tail = docs[reused + small:], rows[reused + small:]
-            self._ensure_capacity(self.n + len(tail_docs) + _CHUNK)
+            # to the end of the last padded chunk: what `_land_rows` needs
+            # and what a restart that lands the same rows asks for
+            self._ensure_capacity(
+                self.n + -(-len(tail_docs) // _CHUNK) * _CHUNK)
             self._write_block(np.ascontiguousarray(tail), self.n)
             self._note_docs_appended(tail_docs)
             self._slot_to_doc[self.n : self.n + len(tail_docs)] = tail_docs
@@ -2260,20 +2575,18 @@ class TpuVectorIndex(VectorIndex):
             d = dead if off == 0 else dead[:0]
             didx = np.full(_bucket_rows(len(d)), sentinel, np.int32)
             didx[: len(d)] = d
-            store, sq_norms, self._s2d_dev, self._tombs = _write_slots(
+            store, sq_norms, self._s2d_dev, self._tombs = self._run_write(
+                _write_slots,
                 self._store, self._sq_norms if l2 else None, self._s2d_dev,
                 self._tombs, jnp.asarray(idx), jnp.asarray(buf),
                 None if norms is None else jnp.asarray(norms),
-                jnp.asarray(pairs), jnp.asarray(didx))
+                jnp.asarray(pairs), jnp.asarray(didx), written=4)
             self._store = store
             if l2:
                 self._sq_norms = sq_norms
             self._note_write(
                 upload_bytes=buf.nbytes + pairs.nbytes + idx.nbytes
-                + didx.nbytes + (norms.nbytes if l2 else 0),
-                slab_bytes_copied=self._slab_bytes()
-                + memory.array_bytes(self._s2d_dev)
-                + memory.array_bytes(self._tombs))
+                + didx.nbytes + (norms.nbytes if l2 else 0))
             led = memory.get_ledger()
             if led is not None:
                 led.note_write_shape(
@@ -2308,11 +2621,6 @@ class TpuVectorIndex(VectorIndex):
             rows = np.stack(list(self._pending.values()))
             docs = np.array(list(self._pending.keys()), dtype=np.int64)
             count = rows.shape[0]
-            if led is not None:
-                # the non-donating write pass transiently holds BOTH the
-                # old and new buffer generations (the snapshot-isolation
-                # trade) — record the per-flush peak
-                led.note_cow(0, transient_peak=self._write_transient_bytes())
             self._pending.clear()
             self._place_rows(docs, rows)
             self._obs_index("add", "flush", t0, ops=count)
@@ -2350,7 +2658,8 @@ class TpuVectorIndex(VectorIndex):
         pad = _bucket_rows(len(idx))
         padded = np.full(pad, self.capacity + 1, dtype=np.int32)
         padded[: len(idx)] = idx
-        self._tombs = _set_tombstones(self._tombs, jnp.asarray(padded))
+        self._tombs = self._run_write(
+            _set_tombstones, self._tombs, jnp.asarray(padded))
         self._tombstones_applied(idx.astype(np.int64), t0)
         led = memory.get_ledger()
         if led is not None:
@@ -2683,24 +2992,6 @@ class TpuVectorIndex(VectorIndex):
         if self._staged_t0 is None and memory.get_ledger() is not None:
             self._staged_t0 = time.perf_counter()
 
-    def _write_transient_bytes(self) -> int:
-        """Device bytes transiently DOUBLED by one non-donating write
-        pass: the replaced buffer generations stay alive (pinned by
-        snapshots / the functional update) while the new ones build."""
-        # every IVF slab is functionally replaced by its write/fold
-        # kernel (pca scatter, bucket fold) or wholesale on recluster —
-        # the old generation stays snapshot-pinned while the new builds
-        ivf = (memory.array_bytes(self._ivf_pca_rows)
-               + memory.array_bytes(self._ivf_buckets)
-               + memory.array_bytes(self._ivf_centroids)
-               + memory.array_bytes(self._ivf_pca_proj))
-        # the rows' own arrays (`_slab_bytes`: the float slab and, for l2,
-        # its norms; compressed: codes, norms and the bf16 copy), the
-        # slot->doc table and the tombstone mask: what `_write_slots` (or
-        # the chunked write and its doc-id scatter) makes anew
-        return (self._slab_bytes() + memory.array_bytes(self._s2d_dev)
-                + memory.array_bytes(self._tombs) + ivf)
-
     # -- snapshot publication / lock-free reads ------------------------------
 
     def _publish_snapshot(self) -> None:
@@ -2737,14 +3028,18 @@ class TpuVectorIndex(VectorIndex):
         observe the wait — this is the read-your-writes pre-read check,
         paid only by the first read after a write."""
         snap = self._snap
-        if snap is not None and self._published_gen == self._staged_gen:
+        if snap is not None and not snap.lease.retired \
+                and self._published_gen == self._staged_gen:
             self._read_local.lock_wait_ms = 0.0
             return snap
         t0 = time.perf_counter()
         with self._lock:
             wait_ms = (time.perf_counter() - t0) * 1000.0
             self._flush_pending()
-            if self._snap is None or self._published_gen != self._staged_gen:
+            # retired and never replaced: a write that overwrote the
+            # published arrays failed before its publish
+            if self._snap is None or self._snap.lease.retired \
+                    or self._published_gen != self._staged_gen:
                 self._publish_snapshot()
             snap = self._snap
         self._read_local.lock_wait_ms = wait_ms
@@ -3014,7 +3309,6 @@ class TpuVectorIndex(VectorIndex):
         self._obs_index("add", "device_write", t0, ops=count)
         led = memory.get_ledger()
         if led is not None:
-            led.note_cow(0, transient_peak=self._write_transient_bytes())
             led.note_write(
                 "add", "device_write",
                 (time.perf_counter() - t0) * 1000.0,
@@ -3424,7 +3718,19 @@ class TpuVectorIndex(VectorIndex):
         lock. Every read-path case — full scan, both PQ tiers, filtered
         scans, the small-allowList gather — dispatches through here, so
         sync and async searches run the same kernels with the same
-        arguments (the bit-identical contract)."""
+        arguments (the bit-identical contract). The snapshot is pinned for
+        the enqueue (`_pin`: a snapshot a writer has taken back meanwhile
+        is replaced by the one that writer published); finalize fetches
+        the program's own output and needs no pin."""
+        snap = self._pin(snap)
+        try:
+            return self._enqueue_search(snap, vectors, k, allow_list)
+        finally:
+            self._unpin(snap)
+
+    def _enqueue_search(self, snap: IndexSnapshot, vectors: np.ndarray,
+                        k: int, allow_list: Optional[AllowList]):
+        """`_dispatch_search` on a snapshot its caller has pinned."""
         if snap.n == 0 or snap.live == 0:
             b = 1 if np.asarray(vectors).ndim == 1 else len(vectors)
             empty = (np.zeros((b, 0), dtype=np.uint64),
@@ -4140,12 +4446,32 @@ class TpuVectorIndex(VectorIndex):
         already live host-side (host_vecs); only the norms are derived."""
         if snap.compressed and snap.host_vecs is not None:
             rows = snap.host_vecs[: snap.n]  # a view — no extra memory
+        elif self._pin(snap, follow=False) is None:
+            # a writer overwrote this generation in place: its rows are
+            # no longer anybody's to read
+            raise SnapshotRetired(f"snapshot {snap.gen} was retired")
         else:
-            rows = np.asarray(snap.store[: snap.n]).astype(
-                np.float32, copy=False)
+            try:
+                rows = self._fetch_rows(snap.store, snap.n)
+            finally:
+                self._unpin(snap)
         # einsum: the norms pass must not transiently duplicate the rows
         sq = np.einsum("ij,ij->i", rows, rows, dtype=np.float32)
         return rows, sq
+
+    def _fetch_rows(self, store, n: int) -> np.ndarray:
+        """Host float32 copy of `store`'s first `n` rows, a piece at a time:
+        `store[:n]` is a second slab on the device for as long as the fetch
+        takes, and a slab that fills half the chip has no room for one. A
+        piece is `_HOST_PIECE` rows (a capacity is a power of two from
+        16,384 up or whole scan chunks: whole pieces either way), 200 MB at
+        768-d."""
+        piece = min(store.shape[0], _HOST_PIECE)
+        out = np.empty((n, store.shape[1]), np.float32)
+        for lo in range(0, n, piece):
+            hi = min(lo + piece, n)
+            out[lo:hi] = np.asarray(_read_rows(store, lo))[: hi - lo]  # graftlint: disable=JGL001 the host plane's one bulk fetch (breaker open, an audit, compact), a piece at a time because a slice of the whole is a second slab on the device
+        return out
 
     def _host_fallback_rows(
             self, snap: IndexSnapshot) -> tuple[np.ndarray, np.ndarray]:
@@ -4180,14 +4506,18 @@ class TpuVectorIndex(VectorIndex):
         probes' riders). Same contract as search_by_vectors ([B, k] ids +
         dists, inf-padded absent slots); selection is exact, so recall can
         only go UP while degraded — latency and throughput pay instead."""
-        snap = self._read_snapshot()
-        if snap.n == 0 or snap.live == 0:
-            b = 1 if np.asarray(vectors).ndim == 1 else len(vectors)
-            return (np.zeros((b, 0), np.uint64),
-                    np.zeros((b, 0), np.float32))
-        rows, row_sq = self._host_fallback_rows(snap)
-        return self._host_search_snap(snap, vectors, k, allow_list,
-                                      rows, row_sq)
+        while True:
+            snap = self._read_snapshot()
+            if snap.n == 0 or snap.live == 0:
+                b = 1 if np.asarray(vectors).ndim == 1 else len(vectors)
+                return (np.zeros((b, 0), np.uint64),
+                        np.zeros((b, 0), np.float32))
+            try:
+                rows, row_sq = self._host_fallback_rows(snap)
+            except SnapshotRetired:
+                continue    # a writer took it back: the one it published
+            return self._host_search_snap(snap, vectors, k, allow_list,
+                                          rows, row_sq)
 
     def search_by_vectors_host_pinned(
         self, snap: IndexSnapshot, vectors: np.ndarray, k: int,
@@ -4511,7 +4841,15 @@ class TpuVectorIndex(VectorIndex):
         by slot. Every slot's answer is exact over the rows its own filter
         allows in the snapshot read here; tombstones are masked on the
         device by that snapshot."""
-        snap = self._read_snapshot()
+        snap = self._pin(self._read_snapshot())
+        try:
+            return self._enqueue_group(snap, vectors, k, allow_lists)
+        finally:
+            self._unpin(snap)
+
+    def _enqueue_group(self, snap: IndexSnapshot, vectors: np.ndarray,
+                       k: int, allow_lists):
+        """`search_by_vectors_multi_async` on the snapshot it pinned."""
         if snap.compressed or (snap.n and self._ivf_plan(snap, 1) is not None):
             return None
         q = np.array(vectors, dtype=np.float32, ndmin=2)
@@ -4792,7 +5130,7 @@ class TpuVectorIndex(VectorIndex):
             if self.compressed:
                 store_host = self._host_vecs[: self.n]
             else:
-                store_host = np.asarray(self._store[: self.n]).astype(np.float32)  # graftlint: disable=JGL008 compact is a stop-the-world rebuild: the lock must cover it and the materialized store IS the rebuild's input
+                store_host = self._fetch_rows(self._store, self.n)  # graftlint: disable=JGL008 compact is a stop-the-world rebuild: the lock must cover it and the materialized store IS the rebuild's input  # graftflow: disable=JGL016 the same stop-the-world fetch, one call deep
             docs = self._slot_to_doc[live_slots]
             vecs = store_host[live_slots]
             if self._log is not None:
@@ -4850,6 +5188,8 @@ class TpuVectorIndex(VectorIndex):
             finally:
                 self._restoring = prev_restoring
                 self._pending_pq = None
+            # what a restart comes back to is what the rebuild ended on
+            self._record_capacity()
             # recluster on the compacted slot space (fresh k-means — the
             # densified layout is a different distribution than the
             # tombstone-riddled one); publish so readers see it
@@ -4910,7 +5250,7 @@ class TpuVectorIndex(VectorIndex):
             self._host_vecs = None
             self._staged_gen += 1
             self._publish_snapshot()
-            for path in (self._pq_path, self._pq4_path):
+            for path in (self._pq_path, self._pq4_path, self._capacity_path):
                 try:
                     os.remove(path)
                 except FileNotFoundError:
@@ -4925,7 +5265,7 @@ class TpuVectorIndex(VectorIndex):
 
     def list_files(self) -> list[str]:
         files = [self._log.path] if self._log is not None else []
-        for path in (self._pq_path, self._pq4_path):
+        for path in (self._pq_path, self._pq4_path, self._capacity_path):
             if os.path.exists(path):
                 files.append(path)
         return files

@@ -599,6 +599,31 @@ class MemoryLedger:
             self._auto_device_budget = budget
         return self._auto_device_budget
 
+    def device_room(self) -> Optional[int]:
+        """Bytes one device can still take before the device scope crosses
+        its own degradation line (``headroom_alert_pct`` of the budget):
+        the budget less that reserve less what the stamped components
+        hold a device. None where the budget is unknown (cpu: no
+        ``bytes_limit``, no override). What an index asks before it makes
+        a second generation of its slab (index/tpu.py `_copy_fits`):
+        analytic, like every stamp, so a write's choice costs no sync."""
+        usable = self.device_usable_bytes()
+        if usable is None:
+            return None
+        pulled = device_provider_components()
+        with self._lock:
+            self._prune_device_locked()
+            _, per_dev = self._device_totals_locked(pulled)
+        return usable - per_dev
+
+    def device_usable_bytes(self) -> Optional[int]:
+        """The device budget less the reserve under which the scope reads
+        degraded; None where the budget is unknown."""
+        budget = self._device_budget()
+        if budget <= 0:
+            return None
+        return int(budget * (1.0 - self.headroom_alert_pct / 100.0))
+
     def _host_budget(self) -> int:
         if self.host_budget_bytes:
             return self.host_budget_bytes

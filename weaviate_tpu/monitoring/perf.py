@@ -95,10 +95,20 @@ WRITE_PHASES = ("decode", "lsm", "index_lock_wait", "index", "device_write",
 WRITE_SPANS = ("batch", "index_held")
 # what a write did, summed over the window (`writes`): `slab_bytes_copied`
 # is the bytes of whole-array generations the write programs made (0 for a
-# donated or in-place write), `upload_bytes` what the host handed them
+# donated or in-place write), `upload_bytes` what the host handed them;
+# `writes_in_place` / `writes_copied` count the write programs by whether
+# they were given their arrays to overwrite or made new generations of them,
+# and `reader_wait_ms` is what writers waited for searches to finish their
+# enqueue on a generation before it was overwritten (index/tpu.py
+# `_retire_snapshot`)
 WRITE_COUNTERS = ("rows", "batches", "slots_reused", "slots_appended",
                   "tombstones_applied", "slab_bytes_copied", "upload_bytes",
-                  "snapshots_published", "grows")
+                  "snapshots_published", "grows", "writes_in_place",
+                  "writes_copied", "reader_wait_ms")
+# what a restore's landing did to the slab, summed over the restart's shards
+# (`startup`): bytes of whole-array generations its writes and grows made,
+# the grows, and the bytes of the slab(s) at its end to hold them against
+RESTORE_COUNTERS = ("slab_bytes_copied", "grows", "slab_bytes")
 
 # intervals one capture keeps; beyond it they are counted as `dropped`
 # (a 5 s capture of the busiest cell closes about 3,000)
@@ -666,6 +676,7 @@ class PerfWindow:
             for c in write_counts:
                 for name, amount in c.items():
                     counts[name] += amount
+            counts["reader_wait_ms"] = round(counts["reader_wait_ms"], 3)
             stat = (lambda v: {
                 "samples": len(v), "p50_ms": round(_pct(v, 50.0), 3),
                 "p99_ms": round(_pct(v, 99.0), 3),
@@ -781,6 +792,7 @@ class Timeline:
         self._compiles_from = compiles.counts()
         self._compiles_to: Optional[tuple] = None
         self.peak_at_restore_end: Optional[int] = None
+        self._counts = dict.fromkeys(RESTORE_COUNTERS, 0)
         # stamped when the listeners are up: from then on the timeline
         # takes `first_ready` and nothing else (a class made at run time
         # opens its shard outside any restart)
@@ -822,6 +834,12 @@ class Timeline:
             row = self.memory(name, capacity, force=True)
             if name == "vector.restore" and row is not None:
                 self.peak_at_restore_end = row[4]
+
+    def count(self, **counts) -> None:
+        """Add to the restore's counters (`RESTORE_COUNTERS`)."""
+        with self._lock:
+            for name, amount in counts.items():
+                self._counts[name] += amount
 
     def memory(self, event: str, capacity: Optional[int] = None,
                force: bool = False) -> Optional[list]:
@@ -877,6 +895,7 @@ class Timeline:
             intervals = list(self._intervals)
             memory = [list(r) for r in self._memory]
             dropped, memory_dropped = self._dropped, self._memory_dropped
+            counts = dict(self._counts)
             inside = [b - a for a, b in zip(
                 self._compiles_from, self._compiles_to or compiles.counts())]
         a = self.anchor_ns
@@ -940,6 +959,7 @@ class Timeline:
                 ("count", "seconds", "cache_hits", "cache_misses"),
                 (inside[0], round(inside[1], 6), *inside[2:]))),
             "peak_at_restore_end_bytes": self.peak_at_restore_end,
+            **counts,
         }
 
     def line(self) -> str:

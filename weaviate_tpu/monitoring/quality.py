@@ -65,6 +65,7 @@ from typing import Optional
 
 import numpy as np
 
+from weaviate_tpu.index.interface import SnapshotRetired
 from weaviate_tpu.testing import sanitizers
 
 _LOG = logging.getLogger(__name__)
@@ -465,6 +466,12 @@ class QualityAuditor:
             except AuditDeadlineExceeded:
                 self.window.count("deadline")
                 self._count_metric("deadline")
+            except SnapshotRetired:
+                # a write overwrote the audited generation in place before
+                # its rows were read (index/tpu.py `_retire_snapshot`):
+                # nothing to compare against, like an audit that was shed
+                self.window.count("shed")
+                self._count_metric("shed")
             except Exception:  # noqa: BLE001 — the audit loop must survive
                 self.window.count("error")
                 self._count_metric("error")
