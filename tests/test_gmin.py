@@ -157,9 +157,15 @@ def test_vmem_tile_plan():
         assert scg >= 128 and qb >= 64  # lane-width / sublane floors hold
 
 
-def test_gmin_wide_vectors_adaptive_tiles(tmp_path):
+def test_gmin_wide_vectors_adaptive_tiles(tmp_path, monkeypatch):
     """d=768 forces a reduced store tile; the kernel must stay correct
-    (interpret mode) at the adapted tiling."""
+    (interpret mode) at the adapted tiling. Since PR 40 the index runs the
+    lax.scan program at this width (gmin_scan.kernel_serves: the kernel is
+    the slower one there), so the test hands the kernel every shape that
+    compiles, as the index did before."""
+    from weaviate_tpu.ops import gmin_scan as gs
+
+    monkeypatch.setattr(gs, "kernel_serves", gs.fits_vmem)
     idx, vecs, rng = _mk_index(tmp_path, vi.DISTANCE_L2, n=700, d=768)
     q = vecs[:16] + 0.001 * rng.standard_normal((16, 768)).astype(np.float32)
     ids, dists = idx.search_by_vectors(q, 5)

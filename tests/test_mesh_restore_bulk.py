@@ -138,9 +138,12 @@ def test_the_mesh_dispatch_says_how_many_chips_it_spans(tmp_path,
     idx.flush()
     idx.search_by_vectors(vecs[:8], 3)
     by_name = {a.name: a.stats for a in annotations}
-    # since PR 36 also the depth the scan step ran at on every chip
+    # since PR 36 also the depth the scan step ran at on every chip, since
+    # PR 40 which of the two full-store programs ran (8 rows a chip's slab
+    # of 64: under the kernel's smallest slab)
     assert by_name["wv/enqueue"] == {"rows": 8, "tier": "exact_scan",
-                                     "ndev": 4, "rescore_r": 32}
+                                     "ndev": 4, "rescore_r": 32,
+                                     "program": "scan"}
     assert by_name["wv/device_wait"] == {"rows": 8, "tier": "exact_scan",
                                          "ndev": 4}
     assert by_name["wv/gather_hop"] == {"rows": 8}

@@ -628,7 +628,116 @@ def test_disabled_serving_path_constructs_no_perf_objects(tmp_path,
         app.shutdown()
 
 
+def test_counting_scan_programs_constructs_nothing_while_disabled(
+        tmp_path, monkeypatch):
+    """The index counts its full-store dispatches by program as plain
+    integers, tracer up or down; naming the program on the `enqueue`
+    interval and on the shape lives inside the tracer's gate."""
+    app, idx, vecs = _mk_app(tmp_path, tracing_on=False, coalesce=False)
+    calls = []
+
+    def spy(name):
+        def boom(*a, **kw):
+            calls.append(name)
+            raise AssertionError(f"{name} touched while disabled")
+        return boom
+
+    monkeypatch.setattr(costmodel, "DispatchShape", spy("DispatchShape"))
+    monkeypatch.setattr(tracing, "Phase", spy("Phase"))
+    monkeypatch.setattr(tracing, "_TraceMe", spy("TraceAnnotation"))
+    try:
+        vidx = idx.shards[next(iter(idx.shards))].vector_index
+        before = vidx.scan_programs.as_dict()
+        out = app.traverser.get_class_batched([
+            GetParams(class_name="Pf",
+                      near_vector={"vector": (vecs[i] + 0.5).tolist()},
+                      limit=K)
+            for i in range(20)])
+        assert not any(isinstance(r, Exception) for r in out)
+        after = vidx.scan_programs.as_dict()
+        assert after["gmin"] + after["scan"] > before["gmin"] + before["scan"]
+        assert after["declined_slower"] == 0
+        assert vidx.pop_dispatch_shape() is None
+        assert calls == []
+    finally:
+        app.shutdown()
+
+
+def test_enqueue_interval_and_shape_name_the_program(tmp_path, monkeypatch):
+    """`program` joins `rows` and `tier` on the `enqueue` interval of a
+    full-store scan (the kernel for a batch of 8 or more, the lax.scan
+    program under it); a gather dispatch runs neither and says nothing."""
+    from weaviate_tpu.storage.bitmap import Bitmap
+
+    seen = []
+
+    class Ann:
+        def __init__(self, name, **stats):
+            self.name, self.stats = name, dict(stats)
+            seen.append(self)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            pass
+
+        def set_metadata(self, **stats):
+            self.stats.update(stats)
+
+    app, idx, vecs = _mk_app(tmp_path, coalesce=False)
+    monkeypatch.setattr(tracing, "_TraceMe", Ann)
+    try:
+        vidx = idx.shards[next(iter(idx.shards))].vector_index
+        for rows, allow in ((16, None), (3, None), (16, Bitmap([1, 2, 3]))):
+            vidx.search_by_vectors(vecs[:rows] + 0.5, K, allow)
+        stats = [a.stats for a in seen if a.name == "wv/enqueue"]
+        assert stats == [
+            {"rows": 16, "tier": costmodel.TIER_EXACT, "program": "gmin"},
+            {"rows": 3, "tier": costmodel.TIER_EXACT, "program": "scan"},
+            {"rows": 16, "tier": costmodel.TIER_GATHER}]
+        vidx.search_by_vectors(vecs[:16] + 0.5, K)
+        shape = vidx.pop_dispatch_shape()
+        assert shape.extra["program"] == "gmin"
+        assert shape.describe()["program"] == "gmin"
+    finally:
+        app.shutdown()
+
+
 # -- exposition ---------------------------------------------------------------
+
+def test_debug_perf_and_health_count_dispatches_by_program(tmp_path):
+    """`/debug/perf` `programs` sums what every shard's index counted;
+    `/debug/index` `kernels.gmin.dispatches` has one shard's."""
+    from weaviate_tpu.server import RestServer
+
+    app, idx, vecs = _mk_app(tmp_path, coalesce=False)
+    srv = RestServer(app, port=0)
+    srv.start()
+    try:
+        shard = idx.shards[next(iter(idx.shards))]
+        shard.vector_index.search_by_vectors(vecs[:16] + 0.5, K)   # kernel
+        shard.vector_index.search_by_vectors(vecs[:2] + 0.5, K)    # b < 8
+        base = f"http://127.0.0.1:{srv.port}"
+        with urllib.request.urlopen(base + "/debug/perf", timeout=30) as r:
+            body = json.loads(r.read())
+        assert body["programs"] == {"gmin": 1, "scan": 1,
+                                    "declined_slower": 0}
+        assert list(body)[-3:] == ["programs", "startup", "compiles"]
+        with urllib.request.urlopen(base + "/debug/index", timeout=30) as r:
+            page = json.loads(r.read())
+        (health,) = page["indexes"]["Pf"].values()
+        gmin = health["vector_index"]["kernels"]["gmin"]
+        assert gmin["dispatches"] == body["programs"]
+        assert gmin["validated"] == 1 and gmin["rejected"] == 0
+        # a decline is the index's own count too
+        shard.vector_index.scan_programs.declined()
+        with urllib.request.urlopen(base + "/debug/perf", timeout=30) as r:
+            assert json.loads(r.read())["programs"]["declined_slower"] == 1
+    finally:
+        srv.stop()
+        app.shutdown()
+
 
 def test_debug_perf_endpoint_and_metrics(tmp_path):
     from weaviate_tpu.server import App, RestServer

@@ -318,12 +318,15 @@ class MeshVectorIndex(VectorIndex):
         # identity token for the per-allowList packed-words cache
         self._allow_token = object()
         # separate failure domain + codebook cache for the PQ codes kernel
-        from weaviate_tpu.ops.gmin_scan import KernelState
+        from weaviate_tpu.ops.gmin_scan import KernelState, ProgramCounts
 
         self._pqg_state = KernelState()
         self._pqg_cb = None
         self._gmin_validated: set = set()     # shapes that served correctly
         self._gmin_shape_broken: set = set()  # shapes Mosaic rejected
+        # the exact tier's full-store dispatches by the program that ran
+        # them (tpu.py's `scan_programs`)
+        self.scan_programs = ProgramCounts()
         # host-memory provider (monitoring/memory.py): slot map, PQ host
         # rows, and staged rows become /debug/memory host components
         memory.register_host_provider(self, memory.index_host_components)
@@ -1388,7 +1391,10 @@ class MeshVectorIndex(VectorIndex):
             return lambda: empty
         faults.fire("index.mesh.dispatch")
         shape = None
-        depth = {}  # {"rescore_r": R} where the scan step serves
+        # the exact tier's `enqueue` stats: {"program": which of the two
+        # full-store programs ran} and, where the scan step did,
+        # {"rescore_r": R}
+        depth = {}
         t_enq0 = 0.0
         enqueue = None
         if tracing.get_tracer() is not None:
@@ -1535,8 +1541,12 @@ class MeshVectorIndex(VectorIndex):
                                    "probed_fraction": round(
                                        min(probed / max(snap.n_total, 1), 1.0), 4)})
                 else:
+                    from weaviate_tpu.ops.gmin_scan import (PROGRAM_GMIN,
+                                                            PROGRAM_SCAN)
+
                     packed_dev = self._gmin_step_or_none(
                         snap, q, kk, words, use_allow)
+                    depth = {"program": PROGRAM_GMIN}
                     if packed_dev is None:
                         # the scan step's depth a chip: the one-chip rule,
                         # planned against one slab like the funnel's
@@ -1544,7 +1554,8 @@ class MeshVectorIndex(VectorIndex):
                         # interval's stats (0: the HIGHEST-precision scan)
                         rescore_r = rescore_depth(self.config, self.metric,
                                                   kk, snap.n_loc)
-                        depth = {"rescore_r": rescore_r}
+                        depth = {"program": PROGRAM_SCAN,
+                                 "rescore_r": rescore_r}
                         packed_dev = mesh_search_step(
                             snap.store,
                             snap.sq_norms,
@@ -1562,12 +1573,13 @@ class MeshVectorIndex(VectorIndex):
                             self.mesh,
                             rescore_r,
                         )
+                    self.scan_programs.count(depth["program"])
                     if t_enq0:
                         shape = DispatchShape(
                             TIER_EXACT, n=snap.n_total, dim=snap.dim, batch=b,
                             batch_padded=q.shape[0],
                             bytes_per_row=snap.dim * snap.store.dtype.itemsize,
-                            k=int(kk), ndev=snap.n_dev, extra=depth or None)
+                            k=int(kk), ndev=snap.n_dev, extra=depth)
         except BaseException:
             if enqueue is not None:  # a dispatch that failed being built
                 enqueue.end()
@@ -1631,9 +1643,12 @@ class MeshVectorIndex(VectorIndex):
     # -- fused group-min kernels (guarded; separate failure domains) ---------
 
     def _gmin_plan(self, b: int, kk: int, snap: Optional[MeshSnapshot] = None):
-        """-> (rg, active_g) when the fused mesh kernel is eligible for this
-        shape (metric, slab size, VMEM budget), else None. Pure gate — no
-        kernel execution — so tests can assert eligibility directly."""
+        """-> (rg, active_g) when the fused mesh kernel is the program to run
+        for this shape (metric, slab size, and `gmin_scan.kernel_serves`:
+        it compiles and is the faster program at this width, the one-chip
+        index's own question against one chip's slab), else None. Pure
+        gate — no kernel execution — so tests can assert eligibility
+        directly."""
         from weaviate_tpu.ops import gmin_scan
 
         n_loc = snap.n_loc if snap is not None else self.n_loc
@@ -1654,9 +1669,9 @@ class MeshVectorIndex(VectorIndex):
         if rg < kk:
             return None
         active_g = max(1, -(-int(counts.max()) // ncols_l))
-        if not gmin_scan.fits_vmem(b, dim, ncols_l, active_g,
-                                   store.dtype.itemsize):
-            return None
+        shape = (b, dim, ncols_l, active_g, store.dtype.itemsize)
+        if not self.scan_programs.kernel_serves(*shape):
+            return None  # a counted choice where it compiles, no degradation
         return rg, active_g
 
     def _pq_gmin_step_or_none(self, snap: MeshSnapshot, q: np.ndarray,

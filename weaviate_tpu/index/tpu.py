@@ -1676,7 +1676,7 @@ class TpuVectorIndex(VectorIndex):
         self._allow_token = object()
         # separate failure domain + codebook-constant cache for the PQ
         # codes-only fused kernel (ops/pq_gmin.py)
-        from weaviate_tpu.ops.gmin_scan import KernelState
+        from weaviate_tpu.ops.gmin_scan import KernelState, ProgramCounts
 
         self._pqg_state = KernelState()
         self._pqg_cb = None  # (pq identity, cb_chunks dev, flat_cb dev)
@@ -1747,6 +1747,9 @@ class TpuVectorIndex(VectorIndex):
         # small-shape success must not vouch for a larger VMEM footprint
         self._gmin_validated: set = set()
         self._gmin_shape_broken: set = set()  # keys Mosaic rejected
+        # full-store dispatches by the program that ran them (health()
+        # kernels.gmin.dispatches, /debug/perf `programs`)
+        self.scan_programs = ProgramCounts()
         # host-memory provider (monitoring/memory.py): the slot/tombstone
         # mirrors, PQ host rows, staged rows, and the breaker's fallback
         # cache become /debug/memory host components. Weakref-held — the
@@ -3439,23 +3442,31 @@ class TpuVectorIndex(VectorIndex):
 
     def _gmin_packed_or_none(self, snap: IndexSnapshot, q: np.ndarray,
                              kk: int, allow_words, store=None, sq_norms=None):
-        """Run the fused scan, or None to use the legacy kernel. Validation
-        is per compiled shape: each distinct (b, k, rg, active_g, use_allow)
-        is a separate Mosaic compilation with its own VMEM footprint
-        (active_g grows as the slab fills), so a failure on a NEW shape falls
-        back for that shape only, while a failure on a shape that already
-        completed a materialized search is a real runtime fault and
-        propagates instead of silently halving throughput."""
+        """Run the fused scan, or None to run the lax.scan program. Which of
+        the two a shape the kernel is eligible for gets is
+        `gmin_scan.kernel_serves`'s answer (the mesh asks the same function):
+        the kernel where it compiles and is the faster program at this width.
+        A no is a choice, not a degradation: no block copy of the store is
+        built, nothing is compiled or validated, no fallback is counted;
+        `scan_programs.declined_slower` counts the dispatches the kernel would
+        have fitted. Validation of a kernel that serves is per compiled
+        shape: each distinct (b, k, rg, active_g, use_allow) is a separate
+        Mosaic compilation with its own VMEM footprint (active_g grows as
+        the slab fills), so a failure on a NEW shape falls back for that
+        shape only, while a failure on a shape that already completed a
+        materialized search is a real runtime fault and propagates instead
+        of silently halving throughput."""
         if not self._use_gmin(snap, q.shape[0], kk):
             return None
         from weaviate_tpu.ops import gmin_scan
 
         ncols = snap.capacity // gmin_scan.G
         active_g = -(-snap.n // ncols)
-        sb = (store if store is not None else snap.store).dtype.itemsize
-        if not gmin_scan.fits_vmem(q.shape[0], snap.dim, ncols, active_g, sb):
-            # even the smallest tiling exceeds the VMEM budget (very wide
-            # vectors): never hand Mosaic a kernel that can wedge the chip
+        shape = (q.shape[0], snap.dim, ncols, active_g,
+                 (store if store is not None else snap.store).dtype.itemsize)
+        if not self.scan_programs.kernel_serves(*shape):
+            # never hand Mosaic a kernel over its VMEM budget (it can wedge
+            # the chip), nor the chip the slower of its two programs
             return None
         # capacity is part of the key: the compilation is parameterized by
         # the [capacity, D] store, so growth invalidates prior validation
@@ -3827,7 +3838,10 @@ class TpuVectorIndex(VectorIndex):
                 enqueue.end()
             raise
         if shape is not None:
-            now_ns = enqueue.end(rows=b, tier=shape.tier)
+            # a full-store scan names the program that ran it
+            program = (shape.extra or {}).get("program")
+            now_ns = enqueue.end(rows=b, tier=shape.tier,
+                                 **({"program": program} if program else {}))
             shape.t_start = t_enq0
             shape.enqueue_ms = (now_ns - enqueue.start_ns) / 1e6
             self._read_local.dispatch_shape = shape
@@ -4109,22 +4123,33 @@ class TpuVectorIndex(VectorIndex):
     def _dispatch_scan(self, snap: IndexSnapshot, q: np.ndarray, b: int,
                        k_eff: int, allow_words, store=None, sq_norms=None,
                        shape=None):
-        """Full-store scan (fused gmin when eligible, legacy lax.scan kernel
-        otherwise) over `store` — the f32 store uncompressed, or the bf16
-        rescore copy under PQ-with-rescore (scanning codes first would read
-        MORE HBM than the copy the rescore pass consults anyway). The
-        slot->doc translation runs in the same program, against the
-        snapshot's device table, and finalize is a reshape; over the bf16
-        copy the program's columns are candidates and finalize scores them
-        from the float32 rows the host keeps (`_rescore_f32`)."""
+        """Full-store scan over `store` — the f32 store uncompressed, or the
+        bf16 rescore copy under PQ-with-rescore (scanning codes first would
+        read MORE HBM than the copy the rescore pass consults anyway) — by
+        one of two programs: the fused gmin kernel where it is eligible,
+        compiles and is the faster at this width (`_gmin_packed_or_none`,
+        `gmin_scan.kernel_serves`), the lax.scan program otherwise. Which
+        one ran is counted (`scan_programs`) and, while the tracer is up, named
+        on the shape (`extra["program"]`) and in the `enqueue` interval's
+        stats. The slot->doc translation runs in the same program, against
+        the snapshot's device table, and finalize is a reshape; over the
+        bf16 copy the program's columns are candidates and finalize scores
+        them from the float32 rows the host keeps (`_rescore_f32`)."""
+        from weaviate_tpu.ops.gmin_scan import PROGRAM_GMIN, PROGRAM_SCAN
+
         kk = min(max(k_eff, 1), snap.n)
         packed_dev = self._gmin_packed_or_none(snap, q, kk, allow_words,
                                                store, sq_norms)
+        program = PROGRAM_GMIN
         if packed_dev is None:
+            program = PROGRAM_SCAN
             packed_dev = self._scan_program(
                 snap, snap.store if store is None else store,
                 snap.sq_norms if sq_norms is None else sq_norms, q,
                 allow_words, kk, candidates=store is not None)
+        self.scan_programs.count(program)
+        if shape is not None:
+            shape.extra = {**(shape.extra or {}), "program": program}
         if store is None:
             return self._finalize_fused(packed_dev, shape, b)
 
@@ -4210,10 +4235,13 @@ class TpuVectorIndex(VectorIndex):
 
         With rescore enabled a full bf16 copy of the rows already lives in
         HBM for the rescoring pass — so the fast scan reads THAT copy
-        directly (fused gmin kernel / legacy scan), which is strictly less
-        HBM traffic and strictly more accurate than scanning the codes
-        first; the codes then only serve writes and restarts. The reference
-        has no such copy, hence its LUT scan (product_quantization.go:56-75).
+        directly (`_dispatch_scan`: the fused gmin kernel where
+        `gmin_scan.kernel_serves` says it is the faster program at 2 B a
+        component, the lax.scan program otherwise, as at 768-d), which is
+        strictly less HBM traffic and strictly more accurate than scanning
+        the codes first; the codes then only serve writes and restarts. The
+        reference has no such copy, hence its LUT scan
+        (product_quantization.go:56-75).
 
         With rescore disabled (memory-tightest tier) the scan really runs
         over the codes: reconstruction-matmul ADC for matmul metrics, LUT

@@ -109,7 +109,71 @@ def plan_tiles(b: int, d: int, ncols: int, ag: int,
 
 
 def fits_vmem(b: int, d: int, ncols: int, ag: int, store_bytes: int = 4) -> bool:
+    """Whether the kernel COMPILES at this shape. Not whether it should run:
+    `kernel_serves` says that."""
     return plan_tiles(b, d, ncols, ag, store_bytes)[2] <= _VMEM_BUDGET
+
+
+# The narrowest width at which the lax.scan program was measured no slower
+# than the kernel, whatever a component weighs: fitted on a v5e at b = 256
+# over nine (width, bytes a component) points (PERF.md section 6, PR 40 has
+# the table). The kernel's time follows rows x width, the scan's the bytes
+# plus a cost a row, so the width orders them and the bytes of a row do not:
+# at 768 B a row the kernel wins as 192-d f32 and loses as 384-d bf16.
+KERNEL_LOSES_FROM_DIM = 256
+
+
+def kernel_serves(b: int, d: int, ncols: int, ag: int,
+                  store_bytes: int = 4) -> bool:
+    """Whether the group-min kernel is the program to run a full-store scan
+    with: it compiles (`fits_vmem`) AND it is the faster of the two at this
+    width. The one choice of both indexes (index/tpu.py _gmin_packed_or_none,
+    index/mesh.py _gmin_plan). False is a choice, not a degradation: the
+    caller runs ops/scan.py's program and builds nothing of the kernel's."""
+    return (d < KERNEL_LOSES_FROM_DIM
+            and fits_vmem(b, d, ncols, ag, store_bytes))
+
+
+PROGRAM_GMIN, PROGRAM_SCAN = "gmin", "scan"
+
+
+class ProgramCounts:
+    """Full-store dispatches of one index by the program that ran them, and
+    how many of the `scan` ones the kernel would have fitted and
+    `kernel_serves` declined. Plain integers behind a leaf lock (four
+    callers dispatch at once), kept whether or not the tracer is up."""
+
+    __slots__ = ("_lock", "gmin", "scan", "declined_slower")
+
+    def __init__(self):
+        import threading
+
+        self._lock = threading.Lock()
+        self.gmin = self.scan = self.declined_slower = 0
+
+    def count(self, program: str) -> None:
+        with self._lock:
+            if program == PROGRAM_GMIN:
+                self.gmin += 1
+            else:
+                self.scan += 1
+
+    def declined(self) -> None:
+        with self._lock:
+            self.declined_slower += 1
+
+    def kernel_serves(self, *shape) -> bool:
+        """`kernel_serves(*shape)` as both indexes ask it: a no at a shape
+        the kernel would have compiled for is counted as a decline."""
+        if kernel_serves(*shape):
+            return True
+        if fits_vmem(*shape):
+            self.declined()
+        return False
+
+    def as_dict(self) -> dict:
+        return {PROGRAM_GMIN: self.gmin, PROGRAM_SCAN: self.scan,
+                "declined_slower": self.declined_slower}
 
 
 class KernelState:
@@ -133,7 +197,9 @@ def kernel_health(state) -> dict:
     the shape keys themselves. "No fallback counted" cannot prove a kernel
     ran — an ineligible shape counts nothing — so this is the positive
     proof: validated >= 1 and rejected == 0."""
+    programs = getattr(state, "scan_programs", None)
     return {
+        **({"dispatches": programs.as_dict()} if programs is not None else {}),
         "validated": len(state._gmin_validated),
         "rejected": len(state._gmin_shape_broken),
         "broken": bool(state._gmin_broken),

@@ -445,7 +445,26 @@ class Handler(BaseHTTPRequestHandler):
             self._reply(200, {"enabled": False, **own})
             return
         self._reply(200, {"enabled": True, **w.summary(),
-                          "capture": w.last_capture(), **own})
+                          "capture": w.last_capture(),
+                          "programs": self._scan_program_counts(), **own})
+
+    def _scan_program_counts(self) -> dict:
+        """Full-store scan dispatches by the program that ran them, over
+        every shard's vector index since it opened: `gmin` (the Pallas
+        group-min kernel), `scan` (the lax.scan program) and, of the `scan`
+        ones, `declined_slower`: the kernel would have compiled and
+        `ops/gmin_scan.kernel_serves` chose the scan as the faster program.
+        The indexes' own integers, lifetime and not the window's: with the
+        tracer down `/debug/index` `kernels.gmin.dispatches` has them a
+        shard."""
+        total = {"gmin": 0, "scan": 0, "declined_slower": 0}
+        for idx in list(self.app.db.indexes.values()):
+            for shard in list(idx.shards.values()):
+                counts = getattr(shard.vector_index, "scan_programs", None)
+                if counts is not None:
+                    for name, n in counts.as_dict().items():
+                        total[name] += n
+        return total
 
     def h_debug_quality(self):
         from weaviate_tpu.monitoring import quality
