@@ -10,8 +10,11 @@ and more). The option binds to a top-level jit only, so each program that calls
 the step carries it itself: the one-chip `_search_full_fused` and the mesh's
 `mesh_search_step` (four chips, 2^19 rows a chip), both held here. Nothing
 runs, so this says nothing about answers or times; tests/test_tpu_index.py and
-tests/test_mesh_index.py hold the answers. The topology is described inside a
-module-scoped fixture, never at import (on-chip-measurement guide, 2)."""
+tests/test_mesh_index.py hold the answers. The same compiled texts hold one
+more thing since PR 41: no `gather` of the merged candidates' slots runs inside
+the loop (ops/topk.py merge_top_k carries them through its sort). The topology
+is described inside a module-scoped fixture, never at import
+(on-chip-measurement guide, 2)."""
 
 import re
 
@@ -67,22 +70,24 @@ def _slab_wide_bf16_converts(text: str, slab_rows: int = CAP) -> list:
     return found
 
 
-def _one_chip_program(one_chip, program, batch, rows, use_allow):
+def _one_chip_program(one_chip, program, batch, rows, use_allow, cap=CAP,
+                      store=jnp.float32, candidates=False):
     """`program` (`_search_full_fused`, or one of its two jits) compiled for
-    one described chip over cohere-768-cos's slab, at the depth the index
-    runs."""
+    one described chip over a 768-d slab of `cap` slots (cohere-768-cos's
+    unless the caller says), at the depth the index runs."""
     from weaviate_tpu.config.config import RESCORE_R_BUCKETS
     from weaviate_tpu.index import tpu
 
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     return program.lower(
-        S((CAP, DIM), jnp.float32), None, S((CAP,), jnp.bool_),
+        S((cap, DIM), store), None, S((cap,), jnp.bool_),
         S((), jnp.int32), S((batch, DIM), jnp.float32),
-        S((CAP // 32,), jnp.uint32), S((CAP, 2), jnp.uint32),
+        S((cap // 32,), jnp.uint32), S((cap, 2), jnp.uint32),
         k=K, metric="cosine", use_allow=use_allow, exact=False,
         active_chunks=-(-rows // tpu._SCAN_CHUNK),
         rescore_r=min(max(4 * K, RESCORE_R_BUCKETS[0]),
-                      RESCORE_R_BUCKETS[-1])).compile()
+                      RESCORE_R_BUCKETS[-1]),
+        candidates=candidates).compile()
 
 
 @pytest.mark.parametrize("batch,rows,use_allow", [
@@ -183,23 +188,96 @@ def test_the_compiler_option_is_what_holds_the_mesh_program(topo):
 SHARE_CAP, SHARE_ROWS = 20 * 131072, 2_500_000      # 8.05 GB of 16.9
 
 
+def _share_program(one_chip):
+    from weaviate_tpu.index import tpu
+
+    return _one_chip_program(one_chip, tpu._search_full_fused, 256,
+                             SHARE_ROWS, False, cap=SHARE_CAP)
+
+
 def test_the_share_scan_program_fits_beside_its_slab(one_chip):
     """cohere-768-cos-10m-share.batch256's program: 20 scan chunks over an
     8.05 GB slab, and no temporary worth naming beside it."""
-    from weaviate_tpu.config.config import RESCORE_R_BUCKETS
-    from weaviate_tpu.index import tpu
-
-    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    compiled = tpu._search_full_fused.lower(
-        S((SHARE_CAP, DIM), jnp.float32), None, S((SHARE_CAP,), jnp.bool_),
-        S((), jnp.int32), S((256, DIM), jnp.float32),
-        S((SHARE_CAP // 32,), jnp.uint32), S((SHARE_CAP, 2), jnp.uint32),
-        k=K, metric="cosine", use_allow=False, exact=False,
-        active_chunks=-(-SHARE_ROWS // tpu._SCAN_CHUNK),
-        rescore_r=min(max(4 * K, RESCORE_R_BUCKETS[0]),
-                      RESCORE_R_BUCKETS[-1])).compile()
+    compiled = _share_program(one_chip)
     assert compiled.memory_analysis().temp_size_in_bytes < TEMP_LIMIT
     assert _slab_wide_bf16_converts(compiled.as_text(), SHARE_CAP) == []
+
+
+# -- the loop's merge carries its slots: no gather a chunk ---------------------
+
+def _computations(text: str) -> dict:
+    """Compiled HLO text -> {computation: its instruction lines}."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def _gathers_in_loops(text: str) -> list:
+    """The result types of every `gather` that runs inside a `while`: in
+    its body or in a computation the body calls (a fusion, a comparator), at
+    any depth."""
+    comps = _computations(text)
+    todo = [m.group(1) for lines in comps.values() for line in lines
+            if " while(" in line
+            for m in [re.search(r"body=%([\w.\-]+)", line)] if m]
+    assert todo, "the program has no while loop"
+    seen, found = set(), []
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            m = re.match(r"\s*(?:ROOT )?%\S+ = (\S+) gather\(", line)
+            if m:
+                found.append(re.sub(r"\{.*", "", m.group(1)))
+            todo += re.findall(
+                r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", line)
+    return found
+
+
+MERGED = {f"s32[256,{4 * K}]", f"s32[{256 * 4 * K}]"}
+
+
+@pytest.mark.parametrize("program", ["share", "compressed", "mesh"])
+def test_no_gather_of_the_merged_slots_is_left_in_the_scan_loop(
+        topo, one_chip, program):
+    """The cross-chunk merge of every step (ops/topk.py merge_top_k) moves
+    the slots with their distances, through one sort. The form before it
+    selected the distances and then read the slots by position, which the
+    TPU compiler makes a `gather` with slice_sizes={1,1} from the
+    s32[256,80] block to s32[256,40], in a fusion of the loop's body whose
+    result is s32[10240]: 10,240 single look-ups, 82 us a step on a v5e, an
+    eighth of cohere-768-cos-10m-share.batch256's program (the parent of
+    PR 41 fails here with ['s32[256,40]']). The gathers that stay (the
+    [B, R, D] rescore rows, the slot->doc words, the final k of R) run once
+    a program, after the loop. Three programs: the share's, the compressed
+    cell's (a bf16 slab, `candidates`) and a chip of the mesh's."""
+    if program == "mesh":
+        from weaviate_tpu.parallel import mesh_search as ms
+
+        compiled = _mesh_program(topo, ms.mesh_search_step, use_allow=False,
+                                 rescore_r=4 * K)
+    elif program == "compressed":
+        from weaviate_tpu.index import tpu
+
+        compiled = _one_chip_program(
+            one_chip, tpu._search_full_fused, 256, 2_000_000, False,
+            cap=2 ** 21, store=jnp.bfloat16, candidates=True)
+    else:
+        compiled = _share_program(one_chip)
+    text = compiled.as_text()
+    assert [g for g in _gathers_in_loops(text) if g in MERGED] == []
+    # what the parser can see: the program still gathers, outside the loop
+    assert " gather(" in text
 
 
 @pytest.mark.parametrize("kernel", ["_write_rows", "_write_slots",

@@ -81,7 +81,10 @@ def scan_topk(
 
     Per-chunk selection uses lax.approx_min_k — the TPU PartialReduce op
     (the ScaNN primitive) — which is ~2-4x faster than lax.top_k at
-    measured recall 1.0 on real workloads; the cross-chunk merge is exact.
+    measured recall 1.0 on real workloads; the cross-chunk merge is exact
+    (ops/topk.py merge_top_k: one stable sort a step that moves the slots
+    with their distances, the earlier chunk first among equals, and no
+    gather in the loop; tests/test_scan_program_temporaries.py holds that).
     Set exact=True (config exactTopK) to force lax.top_k per chunk.
 
     rescore_r > 0 enables the fast-scan-then-exact-rescore shape (the ScaNN
