@@ -436,8 +436,9 @@ def test_rescore_r_cap_steers_the_mesh_scan_step_too(tmp_path):
     idx.add_batch(np.arange(200), vecs)
 
     def depth():
-        idx.search_by_vectors(vecs[:2], 10)
-        return idx.pop_dispatch_shape().extra["rescore_r"]
+        handle = idx.search_by_vectors_async(vecs[:2], 10)
+        handle()
+        return handle.shape.extra["rescore_r"]
 
     prev = tracing.get_tracer()
     tracing.configure(tracing.Tracer(sample_rate=1.0))

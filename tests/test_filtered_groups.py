@@ -78,7 +78,7 @@ def _allow(rows):
 def _hand_over(idx) -> int:
     """The largest filter the plan still gathers when it is alone in a
     group of small filters (the hand-over point of this index's size)."""
-    snap = idx._read_snapshot()
+    snap = idx._read_snapshot()[0]
     top = tpu_index.gather_max_rows(snap.dim, snap.capacity)
     best = 0
     for m in range(64, N + 1, 64):
@@ -231,7 +231,7 @@ def test_docs_that_do_not_ascend_with_their_slots(tmp_path):
         idx.add(int(docs[7]), moved)          # a second slot for docs[7]
         vecs = vecs.copy()
         vecs[7] = moved
-        snap = idx._read_snapshot()
+        snap = idx._read_snapshot()[0]
         assert not snap.docs_ascending
         by_doc = {int(d): i for i, d in enumerate(docs)}
         for m in (1, 9, 300, n):
@@ -349,7 +349,7 @@ def _batch(sv, reqs):
 # scan, one plain scan for slots without a filter
 def _dispatch_bound(app) -> int:
     snap = next(iter(app.db.get_index("Tagged").shards.values())) \
-        .vector_index._read_snapshot()
+        .vector_index._read_snapshot()[0]
     top, buckets, r = tpu_index.gather_max_rows(snap.dim, snap.capacity), 0, 128
     while r <= top:
         buckets, r = buckets + 1, r * 4

@@ -178,7 +178,7 @@ def test_fused_legacy_bit_identity_all_tiers_sync_and_async(
     idx, vecs, allow = _tier(tmp_path, tier, n, doc_ids)
     calls = _spy_programs(monkeypatch)
     q = vecs[:batch] + 0.01
-    snap = idx._read_snapshot()
+    snap = idx._read_snapshot()[0]
     got_sync = idx.search_by_vectors(q, 10, allow)
     got_async = idx.search_by_vectors_async(q, 10, allow)()
     assert len(calls) == 2 and calls[0][0] is calls[1][0], calls
@@ -288,17 +288,18 @@ def _with_perf_window():
     return perf.configure(perf.PerfWindow(window_s=60.0))
 
 
-def _pop_shape(idx):
-    s = idx.pop_dispatch_shape()
-    assert s is not None
-    return s
+def _search_shape(idx, *args):
+    """-> (ids, dists, the dispatch's shape off its handle)."""
+    handle = idx.search_by_vectors_async(*args)
+    ids, dists = handle()
+    assert handle.shape is not None
+    return ids, dists, handle.shape
 
 
 def test_fused_invariant_one_fetch_zero_translation(tmp_path):
     win = _with_perf_window()
     for name, idx, vecs, allow in _tiers(tmp_path):
-        ids, dists = idx.search_by_vectors(vecs[:4] + 0.01, 5, allow)
-        shape = _pop_shape(idx)
+        ids, dists, shape = _search_shape(idx, vecs[:4] + 0.01, 5, allow)
         assert shape.fetches == 1, name
         assert costmodel.fused_invariant_ok(shape), name
         win.record_dispatch(shape, rows=4)
@@ -323,9 +324,8 @@ def test_fused_empty_gather_owes_no_fetch(tmp_path):
     _with_perf_window()
     idx, vecs = _mk_index(tmp_path)
     allow = Bitmap(np.array([10**7, 10**7 + 1], dtype=np.uint64))
-    ids, dists = idx.search_by_vectors(vecs[:2], 5, allow)
+    ids, dists, shape = _search_shape(idx, vecs[:2], 5, allow)
     assert ids.shape == (2, 0)
-    shape = _pop_shape(idx)
     assert shape.fetches == 0 and shape.n == 0
     assert costmodel.fused_invariant_ok(shape)
 
@@ -335,7 +335,7 @@ def test_fused_empty_gather_owes_no_fetch(tmp_path):
 
 def test_sorted_map_is_gone_and_gather_slots_cache_on_allowlist(tmp_path):
     idx, vecs = _mk_index(tmp_path)
-    snap = idx._read_snapshot()
+    snap = idx._read_snapshot()[0]
     assert not hasattr(snap, "_sorted_map")
     assert not hasattr(snap, "sorted_doc_slots")
     allow = Bitmap(np.array([3, 7, 11], dtype=np.uint64))
@@ -380,12 +380,10 @@ def test_gather_fully_deleted_filter_short_circuits_empty(tmp_path):
     allow = Bitmap(np.array([3, 7], dtype=np.uint64))
     q = vecs[:2] + 0.01
     idx.search_by_vectors(q, 3, allow)  # warm the slot cache
-    idx.pop_dispatch_shape()
     idx.delete(3, 7)
     idx.flush()
-    ids, dists = idx.search_by_vectors(q, 3, allow)
+    ids, dists, shape = _search_shape(idx, q, 3, allow)
     assert ids.shape == (2, 0) and dists.shape == (2, 0)
-    shape = _pop_shape(idx)
     assert shape.n == 0 and shape.fetches == 0
 
 
@@ -413,7 +411,7 @@ def test_gather_old_pinned_snapshot_keeps_its_predelete_world(tmp_path):
     idx, vecs = _mk_index(tmp_path)
     allow = Bitmap(np.array([3, 7, 11], dtype=np.uint64))
     q = vecs[:2] + 0.01
-    snap_a = idx._read_snapshot()
+    snap_a = idx._read_snapshot()[0]
     idx.delete(3)
     idx.flush()  # publishes B; (allow_token, n, capacity) unchanged
     # warm the cache from B's world
@@ -426,7 +424,7 @@ def test_gather_old_pinned_snapshot_keeps_its_predelete_world(tmp_path):
 
 def test_slot_to_doc_cow_copy_dropped_host_tombs_kept(tmp_path):
     idx, vecs = _mk_index(tmp_path)
-    snap = idx._read_snapshot()
+    snap = idx._read_snapshot()[0]
     s2d_obj = snap.slot_to_doc
     # append within capacity: slot_to_doc mutates in place past snap.n —
     # NO copy (the append-only invariant), and the snapshot's prefix is

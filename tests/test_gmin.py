@@ -23,6 +23,15 @@ def _mk_index(tmp_path, metric, n=600, d=32, seed=0):
     return idx, vecs, rng
 
 
+def _planned(idx, b, k):
+    """The plan of a dispatch of `b` queries at depth `k` (index/plan.py)."""
+    from weaviate_tpu.index.plan import plan_search
+
+    snap = idx._read_snapshot()[0]
+    return plan_search(idx._plan_view(snap), b, idx.padded_width(b),
+                       min(k, snap.live))
+
+
 def _exact(vecs, q, k, metric):
     if metric == vi.DISTANCE_L2:
         d = ((q[:, None, :] - vecs[None, :, :]) ** 2).sum(-1)
@@ -38,7 +47,7 @@ def _exact(vecs, q, k, metric):
 def test_gmin_matches_exact(tmp_path, metric):
     idx, vecs, rng = _mk_index(tmp_path, metric)
     q = rng.standard_normal((16, vecs.shape[1])).astype(np.float32)
-    assert idx._use_gmin(idx._read_snapshot(), 16, 10)
+    assert _planned(idx, 16, 10).program == "gmin"
     ids, dists = idx.search_by_vectors(q, 10)
     assert not idx._gmin_broken  # the fused path actually ran
     gt_ids, gt_d = _exact(vecs, q, 10, metric)
@@ -75,7 +84,7 @@ def test_gmin_tombstones_and_filter(tmp_path):
 
 def test_gmin_small_batch_uses_legacy(tmp_path):
     idx, vecs, _ = _mk_index(tmp_path, vi.DISTANCE_L2, n=50)
-    assert not idx._use_gmin(idx._read_snapshot(), 4, 10)  # b < 8 -> legacy
+    assert _planned(idx, 4, 10).program == "scan"  # b < 8 -> legacy
     ids, _ = idx.search_by_vectors(vecs[:2], 3)
     assert ids.shape == (2, 3)
 

@@ -70,7 +70,7 @@ def indexes(tmp_path_factory):
 
 
 def _inputs(idx, allows):
-    snap = idx._read_snapshot()
+    snap = idx._read_snapshot()[0]
     return snap, group_inputs.GroupInputs(
         snap, allows, idx._allow_slots, tpu_index._slot_words)
 

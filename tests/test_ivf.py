@@ -143,7 +143,7 @@ def test_ivf_disabled_is_true_noop(tmp_path):
     idx, vecs = _mk_index(tmp_path)  # no settings anywhere
     assert idx._ivf_centroids is None
     assert idx._ivf_buckets is None
-    snap = idx._read_snapshot()
+    snap = idx._read_snapshot()[0]
     assert snap.ivf_buckets is None
     assert idx._ivf_plan(snap, 10) is None
     comps = idx._memory_components()
@@ -181,7 +181,7 @@ def test_ivf_skips_non_matmul_metrics(tmp_path):
 def test_training_publishes_a_complete_layout(tmp_path):
     tpu.set_ivf_config(_ivf())
     idx, vecs = _mk_index(tmp_path)
-    snap = idx._read_snapshot()
+    snap = idx._read_snapshot()[0]
     assert snap.ivf_centroids is not None and snap.ivf_buckets is not None
     nlist, cap_p, gen = snap.ivf_meta
     assert nlist == 8 and gen == 1
@@ -208,7 +208,7 @@ def test_bucket_shapes_stay_stable_across_small_inserts(tmp_path):
     assert idx._ivf_meta[1] == cap_p0
     # and the O(batch) incremental fold kept the bucket table COMPLETE:
     # every slot (old and new) bucketed exactly once
-    buckets = np.asarray(idx._read_snapshot().ivf_buckets)
+    buckets = np.asarray(idx._read_snapshot()[0].ivf_buckets)
     assert sorted(buckets[buckets >= 0].tolist()) == list(range(616))
     # ...so the new rows are immediately findable through the probe
     ids, _ = idx.search_by_vectors(extra[:3], 1)
@@ -287,7 +287,7 @@ def test_probe_prunes_and_keeps_recall_on_clustered_data(tmp_path):
 def test_pca_prefilter_cuts_candidates_and_keeps_recall(tmp_path):
     tpu.set_ivf_config(_ivf(pca_dim=8))
     idx, vecs = _mk_index(tmp_path, n=1200, name="pca")
-    snap = idx._read_snapshot()
+    snap = idx._read_snapshot()[0]
     assert snap.ivf_pca_proj is not None and snap.ivf_pca_rows is not None
     plan = idx._ivf_plan(snap, 10)
     assert plan is not None and plan[1] > 0  # prefilter active
@@ -332,7 +332,7 @@ def test_compact_reclusters_on_the_dense_slot_space(tmp_path):
     idx.delete(*range(0, 200))
     idx.compact()
     assert idx._ivf_gen == gen0 + 1
-    snap = idx._read_snapshot()
+    snap = idx._read_snapshot()[0]
     buckets = np.asarray(snap.ivf_buckets)
     slots = buckets[buckets >= 0]
     assert sorted(slots.tolist()) == list(range(400))  # dense, complete
@@ -408,8 +408,9 @@ def test_dispatch_shape_carries_probed_aware_flops(tmp_path):
     idx, vecs = _mk_index(tmp_path, n=2000, name="shape")
     tracing.configure(tracing.Tracer(sample_rate=1.0))
     try:
-        idx.search_by_vectors(vecs[:4], 10)
-        shape = idx.pop_dispatch_shape()
+        handle = idx.search_by_vectors_async(vecs[:4], 10)
+        handle()
+        shape = handle.shape
         assert shape is not None
         nlist, cap_p, _ = idx._ivf_meta
         probed = 2 * cap_p + nlist
@@ -472,7 +473,7 @@ def test_deep_k_widens_the_probe_for_coverage(tmp_path):
     covered, no matter what the config or controller cap says."""
     tpu.set_ivf_config(_ivf(nlist=8, top_p=1))
     idx, vecs = _mk_index(tmp_path, name="deepk")
-    snap = idx._read_snapshot()
+    snap = idx._read_snapshot()[0]
     cap_p = snap.ivf_meta[1]
     assert idx._ivf_plan(snap, 10)[0] == 1          # shallow k: as asked
     deep_k = cap_p  # 4k = 4*cap_p > 1*cap_p: must widen
@@ -497,7 +498,7 @@ def test_ivf_top_p_cap_reader_is_clamped_and_bucket_snapped():
 def test_controller_cap_steers_the_live_probe_count(tmp_path):
     tpu.set_ivf_config(_ivf(nlist=8, top_p=8))
     idx, vecs = _mk_index(tmp_path, name="steer")
-    snap = idx._read_snapshot()
+    snap = idx._read_snapshot()[0]
     assert idx._ivf_plan(snap, 10)[0] == 8
     p = _plane()
     controller.configure(p)

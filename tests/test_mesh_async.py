@@ -136,7 +136,7 @@ def test_mesh_async_read_takes_zero_index_locks_one_fetch(tmp_path):
         tracing.configure(prev)
     assert ids.shape == (6, 5)
     assert spy.count == 0, "mesh async dispatch took the index lock"
-    shape = idx.pop_dispatch_shape()
+    shape = fin.shape
     assert shape is not None
     assert shape.ndev == 8
     assert shape.fetches == 1
@@ -151,7 +151,7 @@ def test_mesh_search_step_refuses_a_host_translation(tmp_path):
 
     idx, vecs, _ = _mk_index(tmp_path)
     idx.search_by_vectors(vecs[:4], 3)  # publish
-    snap = idx._read_snapshot()
+    snap = idx._read_snapshot()[0]
 
     def step(fused):
         return mesh_search_step(
@@ -180,7 +180,8 @@ def test_mesh_reader_never_blocks_on_writer_held_lock(tmp_path):
     w.start()
     assert holding.wait(5.0)
     t0 = time.perf_counter()
-    ids, _ = idx.search_by_vectors(vecs[:4], 3)
+    handle = idx.search_by_vectors_async(vecs[:4], 3)
+    ids, _ = handle()
     elapsed = time.perf_counter() - t0
     release.set()
     w.join(timeout=10)
@@ -188,7 +189,7 @@ def test_mesh_reader_never_blocks_on_writer_held_lock(tmp_path):
     assert elapsed < 1.0, (
         f"reader took {elapsed:.2f}s while a writer held the lock — "
         "the mesh snapshot fast path must not touch it")
-    assert idx.pop_read_lock_wait() == 0.0
+    assert handle.lock_wait_ms == 0.0
 
 
 # -- 3. snapshot pinning across delete + compact -----------------------------

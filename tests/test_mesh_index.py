@@ -426,7 +426,9 @@ def test_mesh_gmin_fused_kernel_matches_exact(tmp_path, rng):
     ids, dists = idx.search_by_vectors(q, 5)
     # the fused path was eligible AND actually served (validated shape)
     assert not idx._gmin_broken and idx._gmin_validated
-    assert idx._gmin_plan(16, 5) is not None
+    from weaviate_tpu.index.plan import plan_search
+    assert plan_search(idx._plan_view(idx._read_snapshot()[0]), 16, 16,
+                       5).program == "gmin"
     live = np.array([d for d in range(n) if not (d < 30 and d % 2 == 0)])
     dd = ((q[:, None, :] - vecs[live][None, :, :]) ** 2).sum(-1)
     want = live[np.argsort(dd, axis=1)[:, :5]]
@@ -556,8 +558,9 @@ def test_exact_tier_answers_are_the_f32_brute_force(
     prev = tracing.get_tracer()
     tracing.configure(tracing.Tracer(sample_rate=1.0))
     try:
-        got_ids, got_d = idx.search_by_vectors(qs, k, allow_list=allow)
-        shape = idx.pop_dispatch_shape()
+        handle = idx.search_by_vectors_async(qs, k, allow_list=allow)
+        got_ids, got_d = handle()
+        shape = handle.shape
     finally:
         tracing.configure(prev)
     assert shape.tier == "exact_scan" and shape.ndev == 4

@@ -136,7 +136,8 @@ def test_the_mesh_dispatch_says_how_many_chips_it_spans(tmp_path,
     idx = MeshVectorIndex(_cfg(meshDevices=4), str(tmp_path), persist=False)
     idx.add_batch(np.arange(600), vecs)
     idx.flush()
-    idx.search_by_vectors(vecs[:8], 3)
+    handle = idx.search_by_vectors_async(vecs[:8], 3)
+    handle()
     by_name = {a.name: a.stats for a in annotations}
     # since PR 36 also the depth the scan step ran at on every chip, since
     # PR 40 which of the two full-store programs ran (8 rows a chip's slab
@@ -147,7 +148,7 @@ def test_the_mesh_dispatch_says_how_many_chips_it_spans(tmp_path,
     assert by_name["wv/device_wait"] == {"rows": 8, "tier": "exact_scan",
                                          "ndev": 4}
     assert by_name["wv/gather_hop"] == {"rows": 8}
-    assert idx.pop_dispatch_shape().ndev == 4
+    assert handle.shape.ndev == 4
 
 
 def test_a_one_chip_dispatch_keeps_its_annotations_as_they_were(
@@ -173,5 +174,6 @@ def test_with_tracing_off_the_mesh_dispatch_opens_nothing(tmp_path,
     vecs = _rows(600)
     idx = MeshVectorIndex(_cfg(meshDevices=4), str(tmp_path), persist=False)
     idx.add_batch(np.arange(600), vecs)
-    idx.search_by_vectors(vecs[:8], 3)
-    assert opened == [] and idx.pop_dispatch_shape() is None
+    handle = idx.search_by_vectors_async(vecs[:8], 3)
+    handle()
+    assert opened == [] and handle.shape is None

@@ -326,9 +326,10 @@ def test_gather_empty_shard_records_zero_cost(tmp_path):
     try:
         vidx = idx.single_local_shard().vector_index
         absent = Bitmap(np.array([10**9], dtype=np.uint64))
-        ids, dists = vidx.search_by_vectors(vecs[:1], K, absent)
+        handle = vidx.search_by_vectors_async(vecs[:1], K, absent)
+        ids, dists = handle()
         assert ids.shape[1] == 0
-        shape = vidx.pop_dispatch_shape()
+        shape = handle.shape
         assert shape is not None and shape.tier == costmodel.TIER_GATHER
         assert shape.n == 0 and shape.flops() == 0 and shape.bytes() == 0
         assert shape.t_fetch == 0.0  # no device call ran
@@ -572,8 +573,9 @@ def test_pq_tiers_report_their_bytes(tmp_path):
                 near_vector={"vector": (vecs[0] + 0.5).tolist()}, limit=K))
         a = _dispatch_spans(app.tracer.snapshot())[0]["attrs"]
         assert a["tier"] == costmodel.TIER_PQ_RESCORE
-        vidx.search_by_vectors(vecs[:1] + 0.5, K)
-        shape = vidx.pop_dispatch_shape()
+        handle = vidx.search_by_vectors_async(vecs[:1] + 0.5, K)
+        handle()
+        shape = handle.shape
         assert shape.tier == costmodel.TIER_PQ_RESCORE
         assert shape.bytes() == shape.n * 2 * DIM == a["n_live"] * 2 * DIM
     finally:
@@ -657,7 +659,7 @@ def test_counting_scan_programs_constructs_nothing_while_disabled(
         after = vidx.scan_programs.as_dict()
         assert after["gmin"] + after["scan"] > before["gmin"] + before["scan"]
         assert after["declined_slower"] == 0
-        assert vidx.pop_dispatch_shape() is None
+        assert vidx.search_by_vectors_async(vecs[:2] + 0.5, K).shape is None
         assert calls == []
     finally:
         app.shutdown()
@@ -696,8 +698,9 @@ def test_enqueue_interval_and_shape_name_the_program(tmp_path, monkeypatch):
             {"rows": 16, "tier": costmodel.TIER_EXACT, "program": "gmin"},
             {"rows": 3, "tier": costmodel.TIER_EXACT, "program": "scan"},
             {"rows": 16, "tier": costmodel.TIER_GATHER}]
-        vidx.search_by_vectors(vecs[:16] + 0.5, K)
-        shape = vidx.pop_dispatch_shape()
+        handle = vidx.search_by_vectors_async(vecs[:16] + 0.5, K)
+        handle()
+        shape = handle.shape
         assert shape.extra["program"] == "gmin"
         assert shape.describe()["program"] == "gmin"
     finally:

@@ -32,6 +32,7 @@ from weaviate_tpu.config.config import (
 )
 from weaviate_tpu.entities.vectorindex import parse_and_validate_config
 from weaviate_tpu.index import tpu
+from weaviate_tpu.index.plan import funnel_budgets
 from weaviate_tpu.index.tpu import TpuVectorIndex
 from weaviate_tpu.monitoring import costmodel, memory, perf, quality, tracing
 from weaviate_tpu.ops import pq4 as pq4_ops
@@ -119,11 +120,12 @@ def test_funnel_matches_exact_fused_legacy_sync_async(tmp_path, lane):
 
 def test_funnel_dispatches_on_the_pq_adc4_tier(tmp_path):
     idx, vecs = _mk_index(tmp_path)
-    assert idx.dispatch_tier(idx._read_snapshot()) == costmodel.TIER_PQ_ADC4
+    assert idx.dispatch_tier(idx._read_snapshot()[0]) == costmodel.TIER_PQ_ADC4
     tracing.configure(tracing.Tracer(sample_rate=1.0))
     win = perf.configure(perf.PerfWindow(window_s=60.0))
-    idx.search_by_vectors(vecs[:8] + 0.25, 5)
-    shape = idx.pop_dispatch_shape()
+    handle = idx.search_by_vectors_async(vecs[:8] + 0.25, 5)
+    handle()
+    shape = handle.shape
     assert shape is not None and shape.tier == costmodel.TIER_PQ_ADC4
     assert shape.bytes_per_row == idx._pq4.segments // 2
     assert shape.extra["funnel_c"] >= shape.extra["funnel_rescore"] >= 5
@@ -456,10 +458,10 @@ def test_index_budget_floor_ignores_starving_caps(tmp_path):
     p = controller.configure(_plane())
     p._set_knob(KNOB_FUNNEL_C, PQ4_FUNNEL_C_BUCKETS[0], "t")      # 256
     p._set_knob(KNOB_FUNNEL_RESCORE, PQ4_FUNNEL_RESCORE_BUCKETS[0], "t")
-    rg4, rc = idx._funnel_budgets(100, 100000)  # 4k > 256, 2k > 32
+    rg4, rc = funnel_budgets(100, 100000)  # 4k > 256, 2k > 32
     assert rg4 * 16 == PQ4_FUNNEL_C_BUCKETS[-1]
     assert rc == PQ4_FUNNEL_RESCORE_BUCKETS[-1]
-    rg4, rc = idx._funnel_budgets(10, 100000)   # caps respected when sane
+    rg4, rc = funnel_budgets(10, 100000)   # caps respected when sane
     assert rg4 * 16 == PQ4_FUNNEL_C_BUCKETS[0]
     assert rc == PQ4_FUNNEL_RESCORE_BUCKETS[0]
 

@@ -360,8 +360,9 @@ def test_rescore_phase_and_counters_once_a_compressed_dispatch(tmp_path):
     plain.add_batch(np.arange(3000), rows[:3000])
     plain.flush()
 
-    plain.search_by_vectors(pool[:8], K)
-    shape = plain.pop_dispatch_shape()
+    handle = plain.search_by_vectors_async(pool[:8], K)
+    handle()
+    shape = handle.shape
     assert shape.rescore_ms < 0 and "rescore" not in shape.ledger()
     win.record_dispatch(shape, rows=8)
     assert "rescore" not in win.summary()
@@ -369,8 +370,9 @@ def test_rescore_phase_and_counters_once_a_compressed_dispatch(tmp_path):
     r = idx._candidate_depth(K, idx.n)
     assert r == 40
     for _ in range(3):
-        idx.search_by_vectors(pool[:8], K)
-        shape = idx.pop_dispatch_shape()
+        handle = idx.search_by_vectors_async(pool[:8], K)
+        handle()
+        shape = handle.shape
         assert shape.fetches == 1 and shape.rescore_ms >= 0
         led = shape.ledger()
         assert led["rescore"] == shape.rescore_ms
@@ -396,8 +398,9 @@ def test_rescore_phase_and_counters_once_a_compressed_dispatch(tmp_path):
     raw.add_batch(np.arange(3000), rows[:3000])
     raw.flush()
     assert raw.compressed and raw._rescore_dev is None
-    raw.search_by_vectors(pool[:8], K)
-    assert raw.pop_dispatch_shape().rescore_ms < 0
+    handle = raw.search_by_vectors_async(pool[:8], K)
+    handle()
+    assert handle.shape.rescore_ms < 0
     assert win.summary()["rescore"]["dispatches"] == 3
 
 

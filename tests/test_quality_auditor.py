@@ -303,9 +303,9 @@ def test_disabled_serving_path_constructs_no_audit_objects(tmp_path,
             class_name="Ql",
             near_vector={"vector": (vecs[0] + 0.5).tolist()}, limit=K))
         assert len(res) == K
-        # the index pinned nothing either (the TLS gate)
+        # the index pinned nothing either (the handle's gate)
         vidx = idx.single_local_shard().vector_index
-        assert getattr(vidx._read_local, "audit_snap", None) is None
+        assert vidx.search_by_vectors_async(vecs[:1] + 0.5, K).snapshot is None
         assert calls == []
     finally:
         app.shutdown()

@@ -8,13 +8,14 @@ is a choice, not a degradation: nothing of the kernel's is built, compiled,
 validated or counted as a fallback; what ran is counted by program.
 """
 
-import types
+import dataclasses
 
 import numpy as np
 import pytest
 
 from weaviate_tpu.entities import vectorindex as vi
 from weaviate_tpu.index import tpu
+from weaviate_tpu.index.plan import plan_search
 from weaviate_tpu.index.tpu import TpuVectorIndex
 from weaviate_tpu.monitoring import incidents, memory, perf, tracing
 from weaviate_tpu.ops import gmin_scan
@@ -114,7 +115,7 @@ def test_the_one_chip_index_asks_the_shared_function(tmp_path, monkeypatch):
     idx, vecs = _mk_index(tmp_path / "a")
     asked = _spy_choice(monkeypatch)
     idx.search_by_vectors(vecs[:16], 5)
-    snap = idx._read_snapshot()
+    snap = idx._read_snapshot()[0]
     ncols = snap.capacity // gmin_scan.G
     assert asked == [(16, 32, ncols, -(-snap.n // ncols), 4)]
     assert idx.scan_programs.as_dict() == {
@@ -131,17 +132,18 @@ def test_the_mesh_gate_answers_as_the_shared_function(
     idx = MeshVectorIndex(
         parse_and_validate_config("hnsw_tpu_mesh", {"distance": "cosine"}),
         str(tmp_path / "m"), persist=False, initial_capacity_per_shard=64)
+    idx.add_batch(np.arange(8), np.eye(8, dtype=np.float32))
     asked = _spy_choice(monkeypatch)
-    # a chip's slab of the mesh cell's size; the gate reads these four
+    # a chip's slab of the mesh cell's size; the plan's gate reads these four
     n_loc = 1 << 19
-    snap = types.SimpleNamespace(
-        n_loc=n_loc, dim=dim, counts=np.full(4, 500_000),
-        store=np.zeros(1, np.float32 if store_bytes == 4 else np.float16))
-    plan = idx._gmin_plan(256, 10, snap)
+    view = dataclasses.replace(
+        idx._plan_view(idx._read_snapshot()[0]), slab=n_loc, fill=500_000,
+        dim=dim, itemsize=store_bytes)
+    plan = plan_search(view, 256, 256, 10)
     ncols = n_loc // gmin_scan.G
     assert asked == [(256, dim, ncols, 16, store_bytes)]
-    assert (plan is not None) is serves
-    assert plan == ((32, 16) if serves else None)
+    assert (plan.program == "gmin") is serves
+    assert plan.gmin == ((32, 16) if serves else None)
     # declined where it would have compiled: counted, as on one chip
     fits = gmin_scan.fits_vmem(256, dim, ncols, 16, store_bytes)
     assert idx.scan_programs.declined_slower == int(fits and not serves)
@@ -161,7 +163,7 @@ def test_a_declined_compressed_store_runs_the_scan_and_builds_nothing(
     led = memory.configure(memory.MemoryLedger())
     idx, vecs = _mk_index(tmp_path / "pq", n=700, d=768, pq=_PQ)
     assert idx.compressed and idx._rescore_dev is not None
-    snap = idx._read_snapshot()
+    snap = idx._read_snapshot()[0]
     ncols = snap.capacity // gmin_scan.G
     shape = (16, 768, ncols, -(-snap.n // ncols), 2)
     assert gmin_scan.fits_vmem(*shape) and not gmin_scan.kernel_serves(*shape)
@@ -267,14 +269,13 @@ def test_a_store_the_kernel_serves_answers_bit_for_bit_as_the_kernel_alone(
     q = vecs[:16] + 0.01
     got_ids, got_d = idx.search_by_vectors(q, 5)
     assert idx.scan_programs.gmin == 1
-    snap = idx._read_snapshot()
+    snap = idx._read_snapshot()[0]
     qp, b = idx._prep_queries_staged(q)
-    ncols = snap.capacity // gmin_scan.G
     packed = gmin_scan.search_gmin_fused(
         snap.store, snap.sq_norms, snap.tombs, snap.n, np.array(qp),
         np.zeros((snap.capacity // 32,), np.uint32), snap.slot_to_doc_dev,
-        False, 5, metric, idx._gmin_rg(5, snap.capacity),
-        -(-snap.n // ncols), device.pallas_interpret(),
+        False, 5, metric, *plan_search(idx._plan_view(snap), 16, 16, 5).gmin,
+        device.pallas_interpret(),
         gmin_scan.build_rescore_blocks(snap.store))
     want_ids, want_d = unpack_fused(np.asarray(packed))
     np.testing.assert_array_equal(got_ids, want_ids[:b])

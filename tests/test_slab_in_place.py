@@ -254,7 +254,7 @@ def test_a_search_enqueued_before_an_in_place_write_reads_the_old_rows(
     nxt = 1000
     for _ in range(20):
         before = idx.search_by_vectors(q, K)
-        snap = idx._read_snapshot()
+        snap = idx._read_snapshot()[0]
         finalize = idx.search_by_vectors_async(q, K)
         # every row the queries found is re-put far away, in its own slot
         hit = np.unique(before[0].astype(np.int64))
@@ -276,7 +276,7 @@ def test_a_search_enqueued_before_an_in_place_write_reads_the_old_rows(
         idx.replace_batch(far.tolist(), back,
                           np.stack([vec_of.pop(int(d)) for d in hit]))
         vec_of.update(zip(back.tolist(), idx.host_rows(
-            idx._read_snapshot())[0][[idx._doc_to_slot[int(d)]
+            idx._read_snapshot()[0])[0][[idx._doc_to_slot[int(d)]
                                       for d in back]]))
         nxt += 2 * len(hit)
     assert idx.n == 500 and idx.health()["writes"]["writes_copied"] == 0
@@ -302,9 +302,9 @@ def _two_snapshots_on_one_slab(idx):
     """-> (S1, S2): S1 published, then a delete whose tombstone bits are
     COPIED (they fit) and published by a reader's slow path, so that S2 is
     the published snapshot and holds S1's slab."""
-    s1 = idx._read_snapshot()
+    s1 = idx._read_snapshot()[0]
     idx.delete(499)
-    s2 = idx._read_snapshot()
+    s2 = idx._read_snapshot()[0]
     assert s2 is not s1 and s2.store is s1.store
     assert s2.tombs is not s1.tombs and not s1.tombs.is_deleted()
     assert idx.health()["writes"]["writes_copied"] >= 1
@@ -332,7 +332,7 @@ def test_a_snapshot_kept_from_before_a_copying_delete_is_retired_with_the_slab(
         with pytest.raises(SnapshotRetired):
             idx.host_rows(kept)                   # the auditor's shed
     assert s1.lease is s2.lease and s1.lease.retired
-    assert idx._read_snapshot().lease is not s1.lease
+    assert idx._read_snapshot()[0].lease is not s1.lease
     np.testing.assert_array_equal(
         idx.search_by_vectors(q, K)[0], idx._dispatch_search(s1, q, K)()[0])
 

@@ -166,7 +166,8 @@ def test_reader_never_blocks_on_writer_held_lock(tmp_path):
     w.start()
     assert holding.wait(5.0)
     t0 = time.perf_counter()
-    ids, dists = idx.search_by_vectors(vecs[:4], 3)
+    handle = idx.search_by_vectors_async(vecs[:4], 3)
+    ids, dists = handle()
     elapsed = time.perf_counter() - t0
     release.set()
     w.join(timeout=10)
@@ -175,7 +176,7 @@ def test_reader_never_blocks_on_writer_held_lock(tmp_path):
         f"reader took {elapsed:.2f}s while a writer held the lock — "
         "the snapshot fast path must not touch it")
     # the fast path reports zero lock wait
-    assert idx.pop_read_lock_wait() == 0.0
+    assert handle.lock_wait_ms == 0.0
 
 
 # -- 3. bit-identical: snapshot/async reads == quiesced sync reads -----------

@@ -100,20 +100,22 @@ def restarted(tmp_path_factory):
     base = f"http://127.0.0.1:{port}"
     try:
         deadline = time.monotonic() + 120
+        # probe until the page shows `first_ready`: REST answers from the
+        # moment it listens, the timeline takes the first probe answered
+        # after every listener is up (`Timeline.first_ready`), and the stage
+        # lands after that probe's reply. Under load the gRPC server is
+        # still starting when an early probe succeeds: the wait is on the
+        # stage, not on a probe's reply
         while True:
             try:
                 _get(base + "/v1/.well-known/ready")
-                break
+                ready_s = time.monotonic() - t_spawn
+                page = json.loads(_get(base + "/debug/perf"))
+                if "first_ready" in page["startup"]["stages"]:
+                    break
             except OSError:
-                assert proc.poll() is None and time.monotonic() < deadline
-                time.sleep(0.25)
-        ready_s = time.monotonic() - t_spawn
-        # `first_ready` lands after the probe's reply: give it a moment
-        for _ in range(50):
-            page = json.loads(_get(base + "/debug/perf"))
-            if "first_ready" in page["startup"]["stages"] \
-                    and page["startup"]["seconds"]["ready"] is not None:
-                break
+                pass
+            assert proc.poll() is None and time.monotonic() < deadline
             time.sleep(0.1)
         index = json.loads(_get(base + "/debug/index"))
         prom = _get(f"http://127.0.0.1:{metrics_port}/metrics").decode()
@@ -203,8 +205,14 @@ def test_the_flat_partition_adds_up_to_ready(restarted):
                                  ("stage", "grow", "land", "flush"))) < 1e-3
     # what is left are the gaps between main()'s stages
     assert sec["unaccounted"] < 0.05 * sec["ready"] + 0.05
-    # under what a client that polls for readiness saw, and close to it
-    assert sec["ready"] < restarted["ready_s"]
+    # under what a client that polls for readiness saw, and close to it. The
+    # OS anchor is the process's start in whole clock ticks, rounded down
+    # (`perf.process_start_ns`: 10 ms a tick), so the page's `ready` may read
+    # up to one tick over the client's own clock when the probe that sealed
+    # the timeline came right behind the listeners
+    tick = (1.0 / os.sysconf("SC_CLK_TCK")
+            if restarted["page"]["startup"]["anchor"] == "os" else 0.0)
+    assert sec["ready"] < restarted["ready_s"] + tick
     assert restarted["ready_s"] - sec["ready"] < 2.0
 
 

@@ -251,7 +251,7 @@ def test_a_search_dispatched_before_a_reuse_returns_the_old_rows(tmp_path):
     idx.add_batch(np.arange(500), vecs)
     q = vecs[:8] + 0.25
     before = idx.search_by_vectors(q, K)
-    snap = idx._read_snapshot()
+    snap = idx._read_snapshot()[0]
     finalize = idx.search_by_vectors_async(q, K)     # pins `snap`
     # every row the queries found is re-put under a new doc id with a
     # vector far away: each takes its old slot, in place

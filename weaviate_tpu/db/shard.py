@@ -742,18 +742,10 @@ class Shard:
             return [[] for _ in range(q.shape[0])]
         t1 = time.perf_counter()
         if target_distance is not None:
-            row_ids, row_dists = self._search_by_vectors_distance(
+            # widening runs several dispatches; the handle (and so the
+            # ledger facts) is the LAST round's
+            row_ids, row_dists, handle = self._search_by_vectors_distance(
                 q, target_distance, k, allow)
-            if dispatched is not None:
-                dispatched[0] = True
-            lock_wait = self._pop_lock_wait()
-            # widening runs several dispatches; the popped shape (and so
-            # the ledger facts) describes the LAST round
-            shape = self._pop_dispatch_shape()
-            # target-distance rounds are ragged re-dispatches of the same
-            # rows — not a representative recall sample; drop the pin
-            self._pop_audit_snap()
-            t2 = time.perf_counter()
             # pad the ragged per-row results back to one rectangle so the
             # winners hydrate in ONE batched pass (inf marks absent slots,
             # exactly the device kernels' padding convention)
@@ -763,31 +755,16 @@ class Shard:
             for i, (ri, rd) in enumerate(zip(row_ids, row_dists)):
                 ids[i, : len(ri)] = ri
                 dists[i, : len(ri)] = rd
-            with tracing.Stopwatch("hydrate", rows=len(dists)) as hyd:
-                hydrated = self._hydrate_batch(ids, dists, include_vector)
-            if rec is not None:
-                rec.phase("device_search", (t2 - t1) * 1000.0)
-                rec.phase("hydrate", hyd.ms)
-            if shape is not None:
-                if filter_ms is not None:
-                    shape.filter_ms = filter_ms
-                shape.hydrate_ms = hyd.ms
-            self._trace_dispatch_facts(rec, q.shape[0], k, lock_wait, shape)
-            if m is not None:
-                m.filtered_vector_search.labels(cls, self.name).observe(
-                    (t2 - t1) * 1000.0)
-                m.filtered_vector_objects.labels(cls, self.name).observe(
-                    hyd.ms)
-                m.vector_index_ops.labels("search", cls, self.name).inc(q.shape[0])
-                m.query_dimensions.labels("nearVector", "search", cls).inc(
-                    int(q.shape[0] * q.shape[1]))
-            return hydrated
-        ids, dists = self.vector_index.search_by_vectors(q, k, allow)
+        else:
+            handle = self._dispatch(q, k, allow)
+            ids, dists = handle()
         if dispatched is not None:
             dispatched[0] = True
-        lock_wait = self._pop_lock_wait()
-        shape = self._pop_dispatch_shape()
-        self._maybe_audit(self._pop_audit_snap(), q, k, allow, ids, dists)
+        lock_wait, shape, snap = self._dispatch_facts(handle)
+        if target_distance is None:
+            # target-distance rounds are ragged re-dispatches of the same
+            # rows — not a representative recall sample
+            self._maybe_audit(snap, q, k, allow, ids, dists)
         t2 = time.perf_counter()
         with tracing.Stopwatch("hydrate", rows=len(dists)) as hyd:
             hydrated = self._hydrate_batch(ids, dists, include_vector)
@@ -854,16 +831,29 @@ class Shard:
         with tracing.Stopwatch("hydrate", rows=len(dists)):
             return self._hydrate_batch(ids, dists, include_vector)
 
-    def _pop_audit_snap(self):
-        """The pinned IndexSnapshot this thread's last dispatch read —
-        None unless an auditor was configured at dispatch time. Popped
-        UNCONDITIONALLY (a TLS getattr, the _pop_lock_wait cost class) so
-        an auditor torn down between dispatch and finalize cannot leave a
-        stale pin for a LATER request to pop — that would audit query B
-        against query A's snapshot. Must run on the DISPATCHING thread,
-        like the lock wait and the dispatch shape."""
-        pop = getattr(self.vector_index, "pop_audit_snapshot", None)
-        return pop() if pop is not None else None
+    def _dispatch(self, q: np.ndarray, k: int, allow=None):
+        """Enqueue a kNN on the vector index -> its handle: `handle()` is
+        (ids, dists). An index without the two-phase plane (hnsw, noop)
+        searches here and hands back a bare callable."""
+        vidx = self.vector_index
+        dispatch = getattr(vidx, "search_by_vectors_async", None)
+        if dispatch is None:
+            out = vidx.search_by_vectors(q, k, allow)
+            return lambda: out
+        return dispatch(q, k, allow)
+
+    @staticmethod
+    def _dispatch_facts(handle):
+        """What a dispatch learned, off its handle (index/plan.py
+        DispatchHandle), on whatever thread holds it -> (the ms its
+        snapshot read waited on the index write lock: 0.0 = the lock-free
+        fast path, None for an index without the snapshot plane; its
+        costmodel.DispatchShape, None while the tracer is down; the
+        snapshot it read, None unless an auditor was configured at
+        dispatch time). A bare callable (hnsw) has none of them."""
+        return (getattr(handle, "lock_wait_ms", None),
+                getattr(handle, "shape", None),
+                getattr(handle, "snapshot", None))
 
     def _maybe_audit(self, snap, q, k: int, allow, ids, dists) -> None:
         """Shadow-recall sample capture at finalize: offer this completed
@@ -880,21 +870,6 @@ class Shard:
                               shard=self.name)
         except Exception:  # noqa: BLE001 — auditing must never break serving
             pass
-
-    def _pop_lock_wait(self) -> Optional[float]:
-        """ms this thread's last snapshot read waited on the index write
-        lock (0.0 = the lock-free fast path), or None when the index has no
-        snapshot plane (hnsw)."""
-        pop = getattr(self.vector_index, "pop_read_lock_wait", None)
-        return pop() if pop is not None else None
-
-    def _pop_dispatch_shape(self):
-        """This thread's last dispatch's costmodel.DispatchShape (None
-        while the tracer is down, or for indexes without the perf plane —
-        hnsw). Must be popped on the DISPATCHING thread, like the
-        lock wait."""
-        pop = getattr(self.vector_index, "pop_dispatch_shape", None)
-        return pop() if pop is not None else None
 
     def _trace_dispatch_facts(self, rec, rows: int, k: int,
                               lock_wait_ms: Optional[float] = None,
@@ -949,7 +924,8 @@ class Shard:
         VectorIndex.search_by_vector_distance (search.go:90-157), except
         every round is ONE bucketed device dispatch over the rows that still
         need widening — B rows cost ~1 dispatch instead of B dispatch
-        chains. -> ragged ([ids...], [dists...]) per row, ascending."""
+        chains. -> ragged ([ids...], [dists...]) per row, ascending, and
+        the last round's dispatch handle."""
         b = q.shape[0]
         out_ids: list = [None] * b
         out_dists: list = [None] * b
@@ -957,9 +933,11 @@ class Shard:
         live = len(vidx)
         pending = list(range(b))
         limit = 64
+        handle = None
         while pending:
             kk = min(limit, max_limit)
-            ids, dists = vidx.search_by_vectors(q[pending], kk, allow)
+            handle = self._dispatch(q[pending], kk, allow)
+            ids, dists = handle()
             nxt: list[int] = []
             for j, row in enumerate(pending):
                 rd = np.asarray(dists[j], dtype=np.float32)
@@ -983,7 +961,7 @@ class Shard:
                     nxt.append(row)
             pending = nxt
             limit *= 2
-        return out_ids, out_dists
+        return out_ids, out_dists, handle
 
     def object_vector_search_async(
         self, vectors: np.ndarray, k: int, include_vector: bool = False,
@@ -1061,14 +1039,10 @@ class Shard:
                         q, k, flt, None, include_vector, "device_error",
                         cause=err)
             raise
-        lock_wait = self._pop_lock_wait()
-        # popped HERE, on the dispatching thread (the TLS does not follow
-        # the flusher/pool handoff); the closure carries it to done(),
-        # where finalize() will have stamped the device timings
-        shape = self._pop_dispatch_shape()
-        # audit-snapshot pin: same thread-handoff rule — popped at
-        # dispatch, carried into done() where the live answer exists
-        audit_snap = self._pop_audit_snap()
+        # the shape is shared with finalize(): done() reads the device
+        # timings it will have stamped; the audit's snapshot rides along to
+        # where the live answer exists
+        lock_wait, shape, audit_snap = self._dispatch_facts(finalize)
 
         def done() -> list[list[SearchResult]]:
             # observe only the time BLOCKED on the device result — wall time
@@ -1202,8 +1176,7 @@ class Shard:
             raise
         if finalize is None:
             return None
-        lock_wait = self._pop_lock_wait()
-        shapes = finalize.shapes
+        lock_wait, shapes = finalize.lock_wait_ms, finalize.shapes
 
         def done() -> list:
             rec = None
@@ -1318,11 +1291,10 @@ class Shard:
         try:
             rec = tracing.dispatch_record(q.shape[0])
             t1 = time.perf_counter()
-            ids, dists = self.vector_index.search_by_vectors(q, k)
-            lock_wait = self._pop_lock_wait()
-            shape = self._pop_dispatch_shape()
-            self._maybe_audit(self._pop_audit_snap(), q, k, None, ids,
-                              dists)
+            handle = self._dispatch(q, k)
+            ids, dists = handle()
+            lock_wait, shape, snap = self._dispatch_facts(handle)
+            self._maybe_audit(snap, q, k, None, ids, dists)
             t2 = time.perf_counter()
             with tracing.Stopwatch("hydrate", rows=len(dists)) as hyd:
                 out = self.hydrate_raw_packed(ids, dists)

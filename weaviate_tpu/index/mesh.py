@@ -58,6 +58,7 @@ the single-chip staged-clustering plane.
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 import threading
@@ -71,6 +72,10 @@ import numpy as np
 from weaviate_tpu import device
 from weaviate_tpu.entities import vectorindex as vi
 from weaviate_tpu.index.interface import AllowList, VectorIndex
+# the tier and the program of a dispatch are chosen in index/plan.py, once,
+# for both indexes
+from weaviate_tpu.index.plan import (KERNEL_GMIN, DispatchHandle, PlanView,
+                                     plan_search)
 from weaviate_tpu.index.tpu import (
     VectorLog,
     restore_record,
@@ -78,37 +83,29 @@ from weaviate_tpu.index.tpu import (
     _bucket_b,
     _bucket_rows,
     _fetch_packed,
-    _snap_top_p,
+    ivf_probe,
     ivf_settings,
-    rescore_depth,
 )
-# dispatch-shape recording for the perf-attribution plane: a
-# costmodel.DispatchShape is built per dispatch ONLY while the tracer is
-# up (tracing.get_tracer() gate — the zero-cost-when-disabled contract);
-# shapes carry ndev, the chips one SPMD program spans
-from weaviate_tpu.monitoring import costmodel, tracing
+# the `enqueue` interval of a dispatch (and its shape, index/plan.py) exist
+# ONLY while the tracer is up (tracing.get_tracer() gate — the
+# zero-cost-when-disabled contract)
+from weaviate_tpu.monitoring import tracing
 # memory ledger (monitoring/memory.py): per-device slab components are
 # stamped analytically at every buffer mutation; unconfigured => one
 # comparison, nothing constructed
 from weaviate_tpu.monitoring import memory
-# shadow recall auditing (monitoring/quality.py): the dispatch snapshot is
-# pinned in TLS ONLY while an auditor is configured, so the audit compares
-# against the exact mesh state the live answer saw
+# shadow recall auditing (monitoring/quality.py): the dispatch's handle
+# carries its snapshot ONLY while an auditor is configured, so the audit
+# compares against the exact mesh state the live answer saw
 from weaviate_tpu.monitoring import quality
 from weaviate_tpu.monitoring.costmodel import (
-    TIER_EXACT,
     TIER_PQ_ADC4,
     TIER_PQ_CODES,
     TIER_PQ_RESCORE,
-    DispatchShape,
 )
-from weaviate_tpu.monitoring.metrics import record_device_fallback
 from weaviate_tpu.ops import ivf as ivf_ops
 from weaviate_tpu.ops.scan import SCAN_CHUNK
 from weaviate_tpu.ops.topk import unpack_fused
-# the recall-guarded probe-depth cap shares the single-chip controller;
-# controller imports nothing from the index layer, so no cycle
-from weaviate_tpu.serving import controller
 from weaviate_tpu.testing import faults, sanitizers
 from weaviate_tpu.parallel.mesh_search import (
     make_mesh,
@@ -127,8 +124,6 @@ from weaviate_tpu.parallel.mesh_search import (
     shard_spec,
 )
 from weaviate_tpu.compress.pq import pack_codes4 as pq_pack_codes4
-from weaviate_tpu.config.config import (PQ4_FUNNEL_C_BUCKETS,
-                                        PQ4_FUNNEL_RESCORE_BUCKETS)
 
 _MIN_LOC = 1024       # minimum slab rows per chip (power of two, mult of 32)
 _FLUSH_CHUNK = 8192   # staged rows that trigger a flush
@@ -269,7 +264,6 @@ class MeshVectorIndex(VectorIndex):
         self._staged_gen = 0
         self._published_gen = -1  # != staged: the first read publishes
         self._staged_t0: Optional[float] = None
-        self._read_local = threading.local()
         self._inflight = 0
         self._inflight_lock = sanitizers.register_lock(
             threading.Lock(), "index.mesh.inflight")
@@ -1103,14 +1097,14 @@ class MeshVectorIndex(VectorIndex):
                 (time.perf_counter() - self._staged_t0) * 1000.0)
         self._staged_t0 = None
 
-    def _read_snapshot(self) -> MeshSnapshot:
-        """Current MeshSnapshot, lock-free when nothing is staged: one
-        reference load + one generation compare. Staged writes take the
+    def _read_snapshot(self) -> tuple[MeshSnapshot, float]:
+        """-> (the current MeshSnapshot, the ms this read waited on the
+        write lock). Lock-free when nothing is staged: one reference load +
+        one generation compare, and the wait is 0.0. Staged writes take the
         slow path — drain staging under the lock, republish, serve."""
         snap = self._snap
         if snap is not None and self._published_gen == self._staged_gen:
-            self._read_local.lock_wait_ms = 0.0
-            return snap
+            return snap, 0.0
         t0 = time.perf_counter()
         with self._lock:
             wait_ms = (time.perf_counter() - t0) * 1000.0
@@ -1118,17 +1112,10 @@ class MeshVectorIndex(VectorIndex):
             if self._snap is None or self._published_gen != self._staged_gen:
                 self._publish_snapshot()
             snap = self._snap
-        self._read_local.lock_wait_ms = wait_ms
         m = self.metrics
         if m is not None:
             m.index_lock_wait.labels(*self._metric_labels()).observe(wait_ms)
-        return snap
-
-    def pop_read_lock_wait(self) -> float:
-        """Lock wait of the calling thread's last snapshot read, then 0."""
-        w = getattr(self._read_local, "lock_wait_ms", 0.0)
-        self._read_local.lock_wait_ms = 0.0
-        return w
+        return snap, wait_ms
 
     @property
     def snapshot_gen(self) -> int:
@@ -1147,20 +1134,6 @@ class MeshVectorIndex(VectorIndex):
             g = m.index_inflight_dispatches.labels(*self._metric_labels())
             self._inflight_gauge = g
         g.set(n)
-
-    def pop_dispatch_shape(self):
-        """The DispatchShape of the calling thread's last dispatch (serving
-        layer hands it to the perf tracer), then None."""
-        shape = getattr(self._read_local, "dispatch_shape", None)
-        self._read_local.dispatch_shape = None
-        return shape
-
-    def pop_audit_snapshot(self):
-        """The snapshot the calling thread's last dispatch answered from
-        (set only while the quality auditor is up), then None."""
-        snap = getattr(self._read_local, "audit_snap", None)
-        self._read_local.audit_snap = None
-        return snap
 
     # -- IVF plane (per-device KScaNN buckets, shared codebook) --------------
 
@@ -1193,7 +1166,7 @@ class MeshVectorIndex(VectorIndex):
         """Off-lock (re)clustering: pin a snapshot, fetch + fit outside the
         lock while concurrent writes queue into _ivf_backlog, then install
         under the lock iff the device epoch is unchanged."""
-        snap = self._read_snapshot()
+        snap, _ = self._read_snapshot()
         if snap.dim is None or snap.n_total == 0:
             return
         epoch = self._device_epoch
@@ -1325,132 +1298,124 @@ class MeshVectorIndex(VectorIndex):
         )
         return st
 
-    def _ivf_plan(self, snap: MeshSnapshot, k: int) -> Optional[int]:
-        """-> effective top_p when the partition-pruned tier applies to
-        this snapshot, else None (full scan)."""
-        if (snap.ivf_buckets is None or snap.ivf_meta is None
-                or snap.compressed):
-            return None
-        s = ivf_settings()
-        if s is None or self.metric not in ivf_ops.MATMUL_METRICS:
-            return None
-        nlist, cap_p, _gen = snap.ivf_meta
-        req = s.top_p if s.top_p > 0 else max(1, nlist // 16)
-        req = min(req, nlist)
-        eff = max(1, min(req, controller.ivf_top_p_cap(req)))
-        if eff < nlist:
-            eff = min(_snap_top_p(eff), nlist)
-        while eff < nlist and eff * cap_p < 4 * k:
-            nxt = _snap_top_p(min(eff * 2, nlist))
-            eff = nlist if nxt <= eff else nxt
-        return eff
-
-    def _funnel_budgets(self, k: int, n: int):
-        """Controller-guarded funnel budgets, mesh-shaped: same ladder
-        caps as the single-chip index (index/tpu.py _funnel_budgets), but
-        planned against the PER-SHARD slab (n = n_loc) — each chip funnels
-        its own rows, so the whole-mesh candidate pool is n_dev x rg4*16.
-        The no-starvation floors are rescore_depth's: the controller may
-        only cut work, never break top-k coverage."""
-        from weaviate_tpu.ops import pq4 as pq4_ops
-
-        c_top = PQ4_FUNNEL_C_BUCKETS[-1]
-        rc_top = PQ4_FUNNEL_RESCORE_BUCKETS[-1]
-        c_cap = controller.funnel_c_cap(c_top)
-        rc_cap = controller.funnel_rescore_cap(rc_top)
-        if c_cap < 4 * k:
-            c_cap = c_top
-        if rc_cap < 2 * k:
-            rc_cap = rc_top
-        return pq4_ops.plan_funnel(k, n, c_cap, rc_cap)
-
     # -- search dispatch (two-phase: enqueue on the snapshot, fetch later) ---
 
+    def _plan_view(self, snap: MeshSnapshot) -> PlanView:
+        """What index/plan.py reads of `snap` across the mesh: one chip's
+        slab is `n_loc` rows, the fullest holds `counts.max()`."""
+        pq = snap.compressed
+        ivf = (not pq and snap.ivf_buckets is not None
+               and snap.ivf_meta is not None)
+        row_bytes = snap.dim * snap.store.dtype.itemsize
+        return PlanView(
+            config=self.config, metric=self.metric,
+            programs=self.scan_programs, kernels=self,
+            component="index.mesh.gmin", n=snap.n_total, live=snap.live,
+            dim=snap.dim, ndev=snap.n_dev, slab=snap.n_loc,
+            fill=int(snap.counts.max()), itemsize=snap.store.dtype.itemsize,
+            compressed=pq, pq_segments=snap.pq.segments if pq else 0,
+            pq4_segments=(snap.pq4.segments if pq and snap.codes4 is not None
+                          and snap.pq4 is not None else 0),
+            # the pq steps rescore against the chip's own store slab
+            rescore=bool(pq and self.config.pq.rescore),
+            rescore_bytes_per_row=row_bytes if pq else 0,
+            # the scan step's depth a chip: the one-chip rule, planned
+            # against one slab like the funnel's budgets
+            depth_rows=snap.n_loc,
+            ivf_meta=snap.ivf_meta[:2] if ivf else None,
+            ivf_probe=functools.partial(ivf_probe, self.metric, snap) if ivf
+            else None)
+
     def dispatch_tier(self, snap: MeshSnapshot,
-                      allow_list: Optional[AllowList] = None) -> str:
-        """The tier a dispatch against `snap` takes (quality auditor
-        attribution). The mesh has no gather tier — small filtered reads
-        still run the full sharded scan."""
-        if snap.compressed:
-            if snap.codes4 is not None and snap.pq4 is not None:
-                return TIER_PQ_ADC4
-            return TIER_PQ_RESCORE if self.config.pq.rescore else TIER_PQ_CODES
-        return TIER_EXACT
+                      allow_list: Optional[AllowList] = None,
+                      b: int = 1, k: int = 1) -> str:
+        """The tier a dispatch of `b` queries at depth `k` against `snap`
+        takes (quality auditor attribution): its plan's (index/plan.py).
+        The mesh has no gather tier — small filtered reads still run the
+        full sharded scan — so the plan is given no allowList; the program
+        is not asked, so nothing is counted."""
+        return plan_search(
+            self._plan_view(snap), b, _bucket_b(b), self._k_eff(snap, k),
+            refused=frozenset((KERNEL_GMIN,))).tier
+
+    @staticmethod
+    def _k_eff(snap: MeshSnapshot, k: int) -> int:
+        """The depth every chip selects at: no deeper than the live rows or
+        one scan chunk."""
+        return max(1, min(k, snap.live, snap.n_loc, SCAN_CHUNK))
 
     def _dispatch_search(self, snap: MeshSnapshot, vectors: np.ndarray,
                          k: int, allow_list: Optional[AllowList] = None):
-        """Enqueue ONE whole-mesh program against `snap` and return the
-        finalize closure. The program runs per-shard scan -> local top-k ->
-        all-gather -> final select -> on-device slot->doc translation, so
-        finalize is one packed fetch + dtype views (the JGL015 one-fetch /
-        zero-translation invariant, across chips). No locks anywhere."""
+        """Enqueue ONE whole-mesh program against `snap`, the one its plan
+        names (index/plan.py), and return its `DispatchHandle`. The program
+        runs per-shard scan -> local top-k -> all-gather -> final select ->
+        on-device slot->doc translation, so finalize is one packed fetch +
+        dtype views (the JGL015 one-fetch / zero-translation invariant,
+        across chips). No locks anywhere."""
         if snap.dim is None or snap.live == 0 or snap.n_total == 0:
             b = 1 if np.asarray(vectors).ndim == 1 else len(vectors)
-            empty = (np.zeros((b, 0), dtype=np.uint64),
-                     np.zeros((b, 0), dtype=np.float32))
-            return lambda: empty
+            return DispatchHandle.ready((np.zeros((b, 0), dtype=np.uint64),
+                                         np.zeros((b, 0), dtype=np.float32)))
         faults.fire("index.mesh.dispatch")
-        shape = None
-        # the exact tier's `enqueue` stats: {"program": which of the two
-        # full-store programs ran} and, where the scan step did,
-        # {"rescore_r": R}
-        depth = {}
-        t_enq0 = 0.0
-        enqueue = None
-        if tracing.get_tracer() is not None:
-            enqueue = tracing.Phase("enqueue")
-            t_enq0 = enqueue.start_ns / 1e9
+        # the `enqueue` interval and the shape exist ONLY while the tracer
+        # is up (the zero-cost-when-disabled contract). The interval's
+        # stats name the exact tier's program and, where the scan step
+        # ran, its depth ({"program", "rescore_r"}: `SearchPlan.stats`)
+        enqueue = (tracing.Phase("enqueue")
+                   if tracing.get_tracer() is not None else None)
         try:
             q, b = self._prep_queries(vectors)
-            chunk = min(snap.n_loc, SCAN_CHUNK)
-            kk = max(1, min(k, snap.live, chunk))
+            kk = self._k_eff(snap, k)
             use_allow = allow_list is not None
             words = self._allow_words(snap, allow_list) if use_allow else snap.zero_words
             exact = getattr(self.config, "exact_topk", False)
+            view = self._plan_view(snap)
+            plan = plan_search(view, b, q.shape[0], kk)
+            handle = DispatchHandle(
+                self, "index.mesh.finalize", plan,
+                None if enqueue is None
+                else plan.shape(enqueue.start_ns / 1e9))
+            packed_dev = None
+            if plan.tier == TIER_PQ_ADC4:
+                # the 4-bit rung: per-chip three-stage funnel (nibble scan
+                # -> 8-bit ADC re-rank -> exact rescore against the chip's
+                # own store slab), budgets recall-guarded per shard
+                # (planned against n_loc: the whole-mesh candidate pool is
+                # n_dev x rg4*16)
+                from weaviate_tpu.ops import pq_gmin
 
-            if snap.compressed:
-                rescore = self.config.pq.rescore
-                packed_dev = None
-                funnel_budgets = None
-                if snap.codes4 is not None and snap.pq4 is not None:
-                    # the 4-bit rung: per-chip three-stage funnel (nibble scan
-                    # -> 8-bit ADC re-rank -> exact rescore against the chip's
-                    # own store slab), budgets recall-guarded per shard
-                    from weaviate_tpu.ops import pq4 as pq4_ops
-                    from weaviate_tpu.ops import pq_gmin
-
-                    rg4, rc = self._funnel_budgets(kk, snap.n_loc)
-                    if rc >= kk:
-                        _, flat_cb8 = pq_gmin.cached_cb_constants(self)
-                        packed_dev = mesh_search_pq4_step(
-                            snap.codes4,
-                            snap.codes,
-                            snap.recon_norms4,
-                            snap.recon_norms,
-                            snap.tombs,
-                            snap.counts_dev,
-                            words,
-                            snap.pq4._dev_codebook(),
-                            flat_cb8,
-                            snap.store,
-                            jnp.asarray(q),
-                            snap.pq4.rotation_dev(),
-                            snap.slot_to_doc_dev,
-                            kk,
-                            self.metric,
-                            use_allow,
-                            rg4,
-                            rc,
-                            exact,
-                            self.mesh,
-                        )
-                        funnel_budgets = (rg4, rc)
-                if packed_dev is None and not rescore:
+                rg4, rc = plan.funnel
+                _, flat_cb8 = pq_gmin.cached_cb_constants(self)
+                packed_dev = mesh_search_pq4_step(
+                    snap.codes4,
+                    snap.codes,
+                    snap.recon_norms4,
+                    snap.recon_norms,
+                    snap.tombs,
+                    snap.counts_dev,
+                    words,
+                    snap.pq4._dev_codebook(),
+                    flat_cb8,
+                    snap.store,
+                    jnp.asarray(q),
+                    snap.pq4.rotation_dev(),
+                    snap.slot_to_doc_dev,
+                    kk,
+                    self.metric,
+                    use_allow,
+                    rg4,
+                    rc,
+                    exact,
+                    self.mesh,
+                )
+            elif snap.compressed:
+                if plan.tier == TIER_PQ_CODES:
                     # codes-only tier: try the fused per-shard ADC kernel
                     # (mesh twin of the single-chip pq_gmin dispatch)
                     packed_dev = self._pq_gmin_step_or_none(
                         snap, q, kk, words, use_allow)
                 if packed_dev is None:
+                    chunk = min(snap.n_loc, SCAN_CHUNK)
                     nchunks_eff = max(1, snap.n_loc // chunk)
                     pool_target = self.config.pq.rescore_limit or 1024
                     r_chunk = min(
@@ -1473,160 +1438,88 @@ class MeshVectorIndex(VectorIndex):
                         self.metric,
                         use_allow,
                         exact,
-                        rescore,
+                        plan.tier == TIER_PQ_RESCORE,
                         self.mesh,
                     )
-                if t_enq0:
-                    if funnel_budgets is not None:
-                        rg4_s, rc_s = funnel_budgets
-                        shape = DispatchShape(
-                            TIER_PQ_ADC4, n=snap.n_total, dim=snap.dim, batch=b,
-                            batch_padded=q.shape[0],
-                            bytes_per_row=snap.pq4.segments // 2,
-                            k=int(kk), ndev=snap.n_dev,
-                            extra={
-                                # per-shard budgets x n_dev: whole-dispatch
-                                # survivor counts (bytes() attributes stages
-                                # 2/3 per batch row, costmodel.py)
-                                "funnel_c": rg4_s * 16 * snap.n_dev,
-                                "funnel_rescore": rc_s * snap.n_dev,
-                                "funnel_stage2_bytes_per_row": snap.pq.segments,
-                                "funnel_stage3_bytes_per_row":
-                                    snap.dim * snap.store.dtype.itemsize,
-                            })
-                    else:
-                        shape = DispatchShape(
-                            TIER_PQ_RESCORE if rescore else TIER_PQ_CODES,
-                            n=snap.n_total, dim=snap.dim, batch=b,
-                            batch_padded=q.shape[0],
-                            bytes_per_row=(snap.dim * snap.store.dtype.itemsize
-                                           if rescore else snap.pq.segments),
-                            k=int(kk), ndev=snap.n_dev)
+            elif plan.ivf is not None:
+                top_p = plan.ivf[0]
+                _nlist, cap_p, _gen = snap.ivf_meta
+                gp = ivf_ops.group_steps(q.shape[0], cap_p, snap.dim, top_p)
+                packed_dev = mesh_search_ivf_step(
+                    snap.store,
+                    snap.tombs,
+                    snap.counts_dev,
+                    words,
+                    snap.ivf_centroids,
+                    snap.ivf_buckets,
+                    jnp.asarray(q),
+                    snap.slot_to_doc_dev,
+                    kk,
+                    self.metric,
+                    use_allow,
+                    top_p,
+                    exact,
+                    gp,
+                    self.mesh,
+                )
+                with self._ivf_lock:
+                    st = self._ivf_stats
+                    st["dispatches"] += 1
+                    st["probed_rows"] += snap.n_dev * top_p * cap_p
+                    st["base_rows"] += int(snap.n_total)
             else:
-                top_p = self._ivf_plan(snap, kk)
-                if top_p is not None:
-                    nlist, cap_p, _gen = snap.ivf_meta
-                    gp = ivf_ops.group_steps(q.shape[0], cap_p, snap.dim, top_p)
-                    packed_dev = mesh_search_ivf_step(
+                if plan.gmin is not None:
+                    packed_dev = self._gmin_step_or_none(
+                        snap, q, kk, words, use_allow, plan.gmin)
+                    if packed_dev is None:
+                        # Mosaic refused this shape (the one place that
+                        # falls back): planned again, the scan step serves
+                        plan = handle.refuse(view, KERNEL_GMIN)
+                if packed_dev is None:
+                    packed_dev = mesh_search_step(
                         snap.store,
+                        snap.sq_norms,
                         snap.tombs,
                         snap.counts_dev,
                         words,
-                        snap.ivf_centroids,
-                        snap.ivf_buckets,
                         jnp.asarray(q),
                         snap.slot_to_doc_dev,
                         kk,
                         self.metric,
                         use_allow,
-                        top_p,
+                        self.metric == vi.DISTANCE_L2,
                         exact,
-                        gp,
+                        True,  # fused: the only epilogue there is
                         self.mesh,
+                        plan.rescore_r,
                     )
-                    with self._ivf_lock:
-                        st = self._ivf_stats
-                        st["dispatches"] += 1
-                        st["probed_rows"] += snap.n_dev * top_p * cap_p
-                        st["base_rows"] += int(snap.n_total)
-                    if t_enq0:
-                        probed = snap.n_dev * top_p * cap_p + nlist
-                        shape = DispatchShape(
-                            TIER_EXACT, n=probed, dim=snap.dim, batch=b,
-                            batch_padded=q.shape[0],
-                            bytes_per_row=snap.dim * snap.store.dtype.itemsize,
-                            k=int(kk), ndev=snap.n_dev,
-                            extra={"ivf": True, "ivf_top_p": top_p,
-                                   "ivf_nlist": nlist,
-                                   "probed_fraction": round(
-                                       min(probed / max(snap.n_total, 1), 1.0), 4)})
-                else:
-                    from weaviate_tpu.ops.gmin_scan import (PROGRAM_GMIN,
-                                                            PROGRAM_SCAN)
-
-                    packed_dev = self._gmin_step_or_none(
-                        snap, q, kk, words, use_allow)
-                    depth = {"program": PROGRAM_GMIN}
-                    if packed_dev is None:
-                        # the scan step's depth a chip: the one-chip rule,
-                        # planned against one slab like the funnel's
-                        # budgets; on the shape and in the `enqueue`
-                        # interval's stats (0: the HIGHEST-precision scan)
-                        rescore_r = rescore_depth(self.config, self.metric,
-                                                  kk, snap.n_loc)
-                        depth = {"program": PROGRAM_SCAN,
-                                 "rescore_r": rescore_r}
-                        packed_dev = mesh_search_step(
-                            snap.store,
-                            snap.sq_norms,
-                            snap.tombs,
-                            snap.counts_dev,
-                            words,
-                            jnp.asarray(q),
-                            snap.slot_to_doc_dev,
-                            kk,
-                            self.metric,
-                            use_allow,
-                            self.metric == vi.DISTANCE_L2,
-                            exact,
-                            True,  # fused: the only epilogue there is
-                            self.mesh,
-                            rescore_r,
-                        )
-                    self.scan_programs.count(depth["program"])
-                    if t_enq0:
-                        shape = DispatchShape(
-                            TIER_EXACT, n=snap.n_total, dim=snap.dim, batch=b,
-                            batch_padded=q.shape[0],
-                            bytes_per_row=snap.dim * snap.store.dtype.itemsize,
-                            k=int(kk), ndev=snap.n_dev, extra=depth)
+                self.scan_programs.count(plan.program)
         except BaseException:
             if enqueue is not None:  # a dispatch that failed being built
                 enqueue.end()
             raise
 
-        if shape is not None:
-            now_ns = enqueue.end(rows=b, tier=shape.tier, ndev=shape.ndev,
-                                 **depth)
-            shape.t_start = t_enq0
+        shape = handle.shape
+        if enqueue is not None:
+            now_ns = enqueue.end(rows=b, tier=plan.tier, ndev=plan.ndev,
+                                 **plan.stats())
             shape.enqueue_ms = (now_ns - enqueue.start_ns) / 1e6
-            self._read_local.dispatch_shape = shape
+        # the shadow audit must re-read the SAME snapshot the live dispatch
+        # answered from: the handle carries it, only while an auditor is up
         if quality.get_auditor() is not None:
-            self._read_local.audit_snap = snap  # graftflow: disable=JGL018 TLS pin by design: at most one snapshot per serving thread, overwritten on the next sampled dispatch — the shadow audit must re-read the SAME snapshot the live dispatch answered from
-        self._track_inflight(1)
-        done = [False]
+            handle.snapshot = snap
 
         def finish():
             packed = _fetch_packed(packed_dev, shape)
             ids, dists = unpack_fused(packed)
             return ids[:b], dists[:b]
 
-        def finalize():
-            try:
-                faults.fire("index.mesh.finalize")
-                if shape is None:
-                    return finish()
-                if shape.fetches:
-                    shape.fetches = 0  # a retried finalize re-counts
-                t0 = time.perf_counter()
-                try:
-                    out = finish()
-                finally:  # also when the host half of finalize raised
-                    t1 = shape.end_hop()
-                shape.finalize_ms = (t1 - t0) * 1000.0
-                shape.t_end = t1
-                return out
-            finally:
-                if not done[0]:
-                    done[0] = True
-                    self._track_inflight(-1)
-
-        return finalize
+        return handle.launched(finish)
 
     def search_by_vectors(
         self, vectors: np.ndarray, k: int, allow_list: Optional[AllowList] = None
     ) -> tuple[np.ndarray, np.ndarray]:
-        snap = self._read_snapshot()
+        snap, _ = self._read_snapshot()
         return self._dispatch_search(snap, vectors, k, allow_list)()
 
     def search_by_vectors_async(
@@ -1637,42 +1530,12 @@ class MeshVectorIndex(VectorIndex):
         the finalize closure. The coalescer overlaps the next lane's
         enqueue with this lane's device time (pipeline depth 2); filtered
         lanes ride the same path (async_supports_filters)."""
-        snap = self._read_snapshot()
-        return self._dispatch_search(snap, vectors, k, allow_list)
+        snap, wait_ms = self._read_snapshot()
+        handle = self._dispatch_search(snap, vectors, k, allow_list)
+        handle.lock_wait_ms = wait_ms
+        return handle
 
     # -- fused group-min kernels (guarded; separate failure domains) ---------
-
-    def _gmin_plan(self, b: int, kk: int, snap: Optional[MeshSnapshot] = None):
-        """-> (rg, active_g) when the fused mesh kernel is the program to run
-        for this shape (metric, slab size, and `gmin_scan.kernel_serves`:
-        it compiles and is the faster program at this width, the one-chip
-        index's own question against one chip's slab), else None. Pure
-        gate — no kernel execution — so tests can assert eligibility
-        directly."""
-        from weaviate_tpu.ops import gmin_scan
-
-        n_loc = snap.n_loc if snap is not None else self.n_loc
-        dim = snap.dim if snap is not None else self.dim
-        counts = snap.counts if snap is not None else self._counts
-        store = snap.store if snap is not None else self._store
-        if getattr(self.config, "exact_topk", False):
-            return None  # config opt-out, not degradation
-        if self._gmin_broken:
-            record_device_fallback("index.mesh.gmin", "degraded", log=False)
-            return None
-        if self.metric not in (vi.DISTANCE_L2, vi.DISTANCE_DOT, vi.DISTANCE_COSINE):
-            return None
-        if n_loc < 16384 or b < 8:
-            return None
-        ncols_l = n_loc // gmin_scan.G
-        rg = min(max(32, 2 * kk), 128, ncols_l)
-        if rg < kk:
-            return None
-        active_g = max(1, -(-int(counts.max()) // ncols_l))
-        shape = (b, dim, ncols_l, active_g, store.dtype.itemsize)
-        if not self.scan_programs.kernel_serves(*shape):
-            return None  # a counted choice where it compiles, no degradation
-        return rg, active_g
 
     def _pq_gmin_step_or_none(self, snap: MeshSnapshot, q: np.ndarray,
                               kk: int, words, use_allow: bool):
@@ -1723,22 +1586,20 @@ class MeshVectorIndex(VectorIndex):
             "mesh pq codes kernel", component="index.mesh.pq_gmin")
 
     def _gmin_step_or_none(self, snap: MeshSnapshot, q: np.ndarray, kk: int,
-                           words, use_allow: bool):
-        """Enqueue the fused group-min mesh kernel, or None for the legacy
-        scan. Validation mirrors tpu.py's _gmin_packed_or_none: per
-        compiled shape — a Mosaic rejection on a NEW shape falls back for
-        that shape only, a failure on a shape that already served
-        propagates, and only repeated distinct-shape failures with zero
-        successes disable the path. Returns the guarded result RAW so the
-        async finalize defers the fetch."""
+                           words, use_allow: bool, gmin: tuple[int, int]):
+        """Enqueue the fused group-min mesh kernel at the plan's `gmin`
+        (groups kept, live store slices), or None where Mosaic refuses it:
+        the scan step then serves. Validation mirrors tpu.py's
+        _gmin_packed_or_none: per compiled shape — a Mosaic rejection on a
+        NEW shape falls back for that shape only, a failure on a shape that
+        already served propagates, and only repeated distinct-shape
+        failures with zero successes disable the path. Returns the guarded
+        result RAW so the async finalize defers the fetch."""
         from weaviate_tpu.parallel.mesh_search import mesh_search_gmin_step
 
         from weaviate_tpu.ops import gmin_scan
 
-        plan = self._gmin_plan(q.shape[0], kk, snap)
-        if plan is None:
-            return None
-        rg, active_g = plan
+        rg, active_g = gmin
         key = (q.shape[0], kk, rg, active_g, snap.n_loc, use_allow)
         interpret = device.pallas_interpret()
         return gmin_scan.guarded_kernel_call(
@@ -1808,7 +1669,7 @@ class MeshVectorIndex(VectorIndex):
     ) -> tuple[np.ndarray, np.ndarray]:
         """Pure-host scan over the current snapshot (the breaker's degraded
         serving path; bit-compatible contract with the device scan)."""
-        snap = self._read_snapshot()
+        snap, _ = self._read_snapshot()
         if snap.dim is None or snap.n_total == 0 or snap.live == 0:
             b = 1 if np.asarray(vectors).ndim == 1 else len(vectors)
             return (np.zeros((b, 0), dtype=np.uint64),

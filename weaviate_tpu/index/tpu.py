@@ -62,6 +62,11 @@ from weaviate_tpu.entities import vectorindex as vi
 from weaviate_tpu.index import group_inputs
 from weaviate_tpu.index.interface import (AllowList, SnapshotRetired,
                                           VectorIndex)
+# the tier and the program of a dispatch are chosen in index/plan.py, once
+from weaviate_tpu.index.plan import (KERNEL_FUNNEL, KERNEL_GMIN,
+                                     DispatchHandle, PlanView, fetch_stamped,
+                                     funnel_budgets, plan_search,
+                                     rescore_depth)
 # dispatch-shape recording for the perf-attribution plane: a
 # costmodel.DispatchShape is built per dispatch ONLY while the tracer is
 # up (tracing.get_tracer() gate — the zero-cost-when-disabled contract)
@@ -76,8 +81,8 @@ from weaviate_tpu.monitoring import memory
 # shows what the index was doing around a symptom; unconfigured => one
 # comparison, nothing constructed, emit() is exception-guarded internally
 from weaviate_tpu.monitoring import incidents
-# shadow recall auditing (monitoring/quality.py): the dispatch snapshot is
-# pinned in TLS ONLY while an auditor is configured (one comparison,
+# shadow recall auditing (monitoring/quality.py): the dispatch's handle
+# carries its snapshot ONLY while an auditor is configured (one comparison,
 # nothing constructed — the tracer's zero-cost contract), so the audit
 # compares against the exact index state the live answer saw
 from weaviate_tpu.monitoring import quality
@@ -441,40 +446,6 @@ _search_full_fused = ScanProgram(
     jax.jit(_search_full_fused, static_argnames=_SCAN_STATICS),
     jax.jit(_search_full_fused, static_argnames=_SCAN_STATICS,
             compiler_options=TPU_SCAN_OPTIONS))
-
-
-def rescore_depth(config, metric: str, k: int, n: int) -> int:
-    """Fast-scan candidate depth R of a scan over a slab of n rows (the
-    one rule of both indexes: the mesh plans it against one chip's slab):
-    0 disables (exactTopK config or non-matmul metrics); otherwise 4k
-    clamped to [32, r_max] — selection errors of the single-pass scan sit
-    well within 4k candidates. r_max is 128 statically; the control
-    plane's recall-guarded budget controller (serving/controller.py) may
-    lower it bucket-by-bucket while the shadow auditor's recall EWMA
-    holds measured slack over the configured floor — the cap is
-    clamped, jit-bucket-snapped, and lapses back to 128 when the
-    controller stalls or dies."""
-    if getattr(config, "exact_topk", False):
-        return 0
-    if metric not in (vi.DISTANCE_L2, vi.DISTANCE_DOT, vi.DISTANCE_COSINE):
-        return 0
-    # R_BUCKETS single source of truth (config.RESCORE_R_BUCKETS,
-    # aliased by serving/controller.py): cap values are buckets and
-    # the static choices are {max(4k, floor)} ∪ buckets, so a
-    # controller cut can never mint a jit shape the static path
-    # wouldn't also compile
-    r_top = RESCORE_R_BUCKETS[-1]
-    r_max = controller.rescore_r_cap(r_top)
-    if r_max < 2 * k:
-        # a cap below this query's slack threshold would zero r and
-        # force the full-precision exact scan — strictly MORE device
-        # work than the static path; the budget controller may only
-        # cut, so queries too deep for the cap keep the static max
-        r_max = r_top
-    r = int(min(max(4 * k, RESCORE_R_BUCKETS[0]), r_max, max(n, 1)))
-    # no candidate slack over k => the fast pass would pick the FINAL set
-    # at reduced precision; fall back to the HIGHEST-precision scan
-    return r if r >= 2 * k else 0
 
 
 # rows of the uint8 code matrix scored per PQ scan step ([B, chunk] f32
@@ -1431,6 +1402,52 @@ class ArrayLease:
         self.retired = False
 
 
+def ivf_probe(metric: str, snap, k: int) -> Optional[tuple[int, int]]:
+    """(top_p, prefilter_c) for an IVF dispatch on `snap` (either
+    index's: the mesh trains no prefilter and gets 0), or None to
+    take the flat path. None whenever the plane is disabled, the
+    snapshot carries no trained layout, or the metric has no
+    matmul/rescore form — the first two checks are one comparison
+    each (the zero-hop contract). The effective probe count is the
+    configured value capped by the controller's recall-guarded
+    budget (serving/controller.py ivf_top_p_cap) and snapped to the
+    bounded IVF_TOP_P_BUCKETS ladder (or to nlist exactly when the
+    request covers every partition), so top_p — a jit static — can
+    only take bounded values."""
+    if snap.ivf_buckets is None:
+        return None
+    s = ivf_settings()
+    if s is None:
+        return None
+    if metric not in ivf_ops.MATMUL_METRICS:
+        return None
+    nlist, cap_p, _gen = snap.ivf_meta
+    req = s.top_p if s.top_p > 0 else max(1, nlist // 16)
+    req = min(req, nlist)
+    eff = max(1, min(req, controller.ivf_top_p_cap(req)))
+    if eff < nlist:
+        eff = min(_snap_top_p(eff), nlist)
+    # deep-k coverage: a probe set under ~4k candidates starves the
+    # final selection (the flat fast-scan's slack rationale) — widen
+    # up the ladder before dispatching; neither the config nor the
+    # controller cap may shrink a query below its own k
+    while eff < nlist and eff * cap_p < 4 * k:
+        nxt = _snap_top_p(min(eff * 2, nlist))
+        eff = nlist if nxt <= eff else nxt
+    pre_c = 0
+    if getattr(snap, "ivf_pca_proj", None) is not None:
+        r = eff * cap_p
+        # auto: 8k floor for selection quality, r/8 cut, capped at
+        # 2048 — past that the full-dim pass stops being the
+        # bottleneck the prefilter exists to shrink
+        pc = s.prefilter_c if s.prefilter_c > 0 \
+            else max(8 * k, min(2048, r // 8))
+        pc = _bucket_rows(min(pc, r))  # pow2: bounded jit shapes
+        if pc < r:
+            pre_c = pc
+    return (eff, pre_c)
+
+
 class IndexSnapshot:
     """One immutable published generation of the device state a search
     dispatch reads.
@@ -1606,7 +1623,6 @@ class TpuVectorIndex(VectorIndex):
         # monotonic stamp of the OLDEST staged-but-unpublished mutation
         # (ledger staged-publish lag; None = nothing staged / ledger off)
         self._staged_t0: Optional[float] = None
-        self._read_local = threading.local()  # per-thread last lock wait
         self._inflight = 0                    # dispatches between enqueue
         self._inflight_lock = sanitizers.register_lock(
             threading.Lock(), "index.tpu.inflight")  # ...and finalize
@@ -2204,7 +2220,7 @@ class TpuVectorIndex(VectorIndex):
                     return snap
             if not follow:
                 return None
-            snap = self._read_snapshot()
+            snap, _ = self._read_snapshot()
 
     def _unpin(self, snap: IndexSnapshot) -> None:
         lease = snap.lease
@@ -3023,18 +3039,18 @@ class TpuVectorIndex(VectorIndex):
                 (time.perf_counter() - self._staged_t0) * 1000.0)
         self._staged_t0 = None
 
-    def _read_snapshot(self) -> IndexSnapshot:
-        """The snapshot a search dispatches on. Fast path: one reference
-        read and one generation compare, NO lock — concurrent writers
-        cannot block it. Slow path (staged writes not yet published, or
+    def _read_snapshot(self) -> tuple[IndexSnapshot, float]:
+        """-> (the snapshot a search dispatches on, the ms this read waited
+        on the write lock). Fast path: one reference read and one
+        generation compare, NO lock — concurrent writers cannot block it,
+        and the wait is 0.0. Slow path (staged writes not yet published, or
         never published): take the write lock once, flush + publish, and
         observe the wait — this is the read-your-writes pre-read check,
         paid only by the first read after a write."""
         snap = self._snap
         if snap is not None and not snap.lease.retired \
                 and self._published_gen == self._staged_gen:
-            self._read_local.lock_wait_ms = 0.0
-            return snap
+            return snap, 0.0
         t0 = time.perf_counter()
         with self._lock:
             wait_ms = (time.perf_counter() - t0) * 1000.0
@@ -3045,21 +3061,12 @@ class TpuVectorIndex(VectorIndex):
                     or self._published_gen != self._staged_gen:
                 self._publish_snapshot()
             snap = self._snap
-        self._read_local.lock_wait_ms = wait_ms
         perf.note_read_lock_wait(wait_ms)
         m = self.metrics
         if m is not None:
             cls, shard = self._metric_labels()
             m.index_lock_wait.labels(cls, shard).observe(wait_ms)
-        return snap
-
-    def pop_read_lock_wait(self) -> float:
-        """ms the CALLING thread's last snapshot read waited on the write
-        lock (0.0 on the lock-free fast path); reading clears it. The shard
-        layer attaches it as a dispatch trace fact."""
-        w = getattr(self._read_local, "lock_wait_ms", 0.0)
-        self._read_local.lock_wait_ms = 0.0
-        return w
+        return snap, wait_ms
 
     @property
     def snapshot_gen(self) -> int:
@@ -3361,32 +3368,6 @@ class TpuVectorIndex(VectorIndex):
 
     # -- fused group-min fast scan (ops/gmin_scan.py) ------------------------
 
-    def _gmin_rg(self, k: int, capacity: int) -> int:
-        """Groups kept by the fused scan: >= k guarantees exact selection
-        under exact arithmetic (at most k groups hold the true top-k);
-        2k..128 adds slack for bf16 fast-scan ranking error. 0 = shape
-        unsupported, use the legacy scan."""
-        from weaviate_tpu.ops import gmin_scan
-
-        ncols = capacity // gmin_scan.G
-        rg = min(max(32, 2 * k), 128, ncols)
-        return rg if rg >= k else 0
-
-    def _use_gmin(self, snap: IndexSnapshot, b: int, k: int) -> bool:
-        if getattr(self.config, "exact_topk", False):
-            return False  # config opt-out, not degradation
-        if self._gmin_broken:
-            record_device_fallback("index.tpu.gmin", "degraded", log=False)
-            incidents.emit("device_fallback", scope="index.tpu.gmin")
-            return False
-        if self.metric not in (vi.DISTANCE_L2, vi.DISTANCE_DOT, vi.DISTANCE_COSINE):
-            return False
-        # pallas tiling wants >= 8 query sublanes; tiny batches stay on the
-        # legacy scan (they're dispatch-latency-bound anyway)
-        if snap.capacity < _MIN_CAPACITY or b < 8:
-            return False
-        return self._gmin_rg(k, snap.capacity) > 0
-
     def _gen_blocks(self, arr, build_fn):
         """Generation-cached block layout for `arr` (the store, the bf16
         rescore store, or the PQ codes): rebuilt only when the underlying
@@ -3409,14 +3390,15 @@ class TpuVectorIndex(VectorIndex):
         return blk
 
     def _search_full_gmin(self, snap: IndexSnapshot, q: np.ndarray, kk: int,
-                          allow_words, store=None, sq_norms=None):
-        """`store` given: a compressed index's bf16 rows, and the program
-        returns its `_candidate_depth` best by them, slots kept (the groups
-        kept are still sized by kk)."""
+                          allow_words, gmin: tuple[int, int], store=None,
+                          sq_norms=None):
+        """`gmin`: the plan's (groups kept, live store slices). `store`
+        given: a compressed index's bf16 rows, and the program returns its
+        `_candidate_depth` best by them, slots kept (the groups kept are
+        still sized by kk)."""
         from weaviate_tpu.ops import gmin_scan
 
         interpret = device.pallas_interpret()
-        ncols = snap.capacity // gmin_scan.G
         s = snap.store if store is None else store
         args = (
             s,
@@ -3431,8 +3413,7 @@ class TpuVectorIndex(VectorIndex):
             allow_words is not None,
             kk if store is None else self._candidate_depth(kk, snap.n),
             self.metric,
-            self._gmin_rg(kk, snap.capacity),
-            -(-snap.n // ncols),  # live store slices only
+            *gmin,
             interpret,
             self._gen_blocks(s, gmin_scan.build_rescore_blocks),
         )
@@ -3441,41 +3422,27 @@ class TpuVectorIndex(VectorIndex):
                                            with_slots=store is not None)
 
     def _gmin_packed_or_none(self, snap: IndexSnapshot, q: np.ndarray,
-                             kk: int, allow_words, store=None, sq_norms=None):
-        """Run the fused scan, or None to run the lax.scan program. Which of
-        the two a shape the kernel is eligible for gets is
-        `gmin_scan.kernel_serves`'s answer (the mesh asks the same function):
-        the kernel where it compiles and is the faster program at this width.
-        A no is a choice, not a degradation: no block copy of the store is
-        built, nothing is compiled or validated, no fallback is counted;
-        `scan_programs.declined_slower` counts the dispatches the kernel would
-        have fitted. Validation of a kernel that serves is per compiled
-        shape: each distinct (b, k, rg, active_g, use_allow) is a separate
-        Mosaic compilation with its own VMEM footprint (active_g grows as
-        the slab fills), so a failure on a NEW shape falls back for that
-        shape only, while a failure on a shape that already completed a
-        materialized search is a real runtime fault and propagates instead
-        of silently halving throughput."""
-        if not self._use_gmin(snap, q.shape[0], kk):
-            return None
+                             kk: int, allow_words, gmin: tuple[int, int],
+                             store=None, sq_norms=None):
+        """Run the fused scan the plan chose (`index/plan.py`: eligible, and
+        by `gmin_scan.kernel_serves` the faster program at this width), or
+        None where Mosaic refuses it: the lax.scan program then runs.
+        Validation is per compiled shape: each distinct (b, k, rg,
+        active_g, use_allow) is a separate Mosaic compilation with its own
+        VMEM footprint (active_g grows as the slab fills), so a failure on a
+        NEW shape falls back for that shape only, while a failure on a shape
+        that already completed a materialized search is a real runtime
+        fault and propagates instead of silently halving throughput."""
         from weaviate_tpu.ops import gmin_scan
 
-        ncols = snap.capacity // gmin_scan.G
-        active_g = -(-snap.n // ncols)
-        shape = (q.shape[0], snap.dim, ncols, active_g,
-                 (store if store is not None else snap.store).dtype.itemsize)
-        if not self.scan_programs.kernel_serves(*shape):
-            # never hand Mosaic a kernel over its VMEM budget (it can wedge
-            # the chip), nor the chip the slower of its two programs
-            return None
         # capacity is part of the key: the compilation is parameterized by
         # the [capacity, D] store, so growth invalidates prior validation
-        key = (q.shape[0], kk, self._gmin_rg(kk, snap.capacity), active_g,
-               snap.capacity, allow_words is not None, store is not None)
+        key = (q.shape[0], kk, *gmin, snap.capacity, allow_words is not None,
+               store is not None)
         return gmin_scan.guarded_kernel_call(
             self, key,
-            lambda: self._search_full_gmin(snap, q, kk, allow_words, store,
-                                           sq_norms),
+            lambda: self._search_full_gmin(snap, q, kk, allow_words, gmin,
+                                           store, sq_norms),
             "fused gmin kernel", component="index.tpu.gmin")
 
     def _pq_gmin_packed_or_none(self, snap: IndexSnapshot, q: np.ndarray,
@@ -3517,58 +3484,28 @@ class TpuVectorIndex(VectorIndex):
             self._pqg_state, key, thunk,
             "fused pq codes kernel", component="index.tpu.pq_gmin")
 
-    def _funnel_budgets(self, k: int, n: int) -> tuple[int, int]:
-        """(rg4 stage-1 groups, rc stage-2 survivors) for a funnel whose
-        scan plane holds n rows — the SLAB capacity on the full-store
-        tier (dead slots mask to inf; the group-column count plan_funnel
-        clamps against is slab-derived), the probed candidate count on
-        the IVF tier. The two caps are the controller's recall-guarded
-        budgets (serving/controller.py), single-sourced from the
-        config.PQ4_FUNNEL_*_BUCKETS ladders exactly like rescore_r_cap —
-        bucket values in, so the jit shapes plan_funnel emits stay
-        bounded. The same no-starvation floor as rescore_depth: a cap too
-        shallow for this query's k lapses to the static max (the
-        controller may only cut work, never break coverage)."""
-        from weaviate_tpu.ops import pq4 as pq4_ops
-
-        c_top = PQ4_FUNNEL_C_BUCKETS[-1]
-        rc_top = PQ4_FUNNEL_RESCORE_BUCKETS[-1]
-        c_cap = controller.funnel_c_cap(c_top)
-        rc_cap = controller.funnel_rescore_cap(rc_top)
-        if c_cap < 4 * k:
-            c_cap = c_top
-        if rc_cap < 2 * k:
-            rc_cap = rc_top
-        return pq4_ops.plan_funnel(k, n, c_cap, rc_cap)
-
     def _pq4_funnel_packed_or_none(self, snap: IndexSnapshot, q: np.ndarray,
-                                   b: int, k: int, allow_list):
-        """Run the three-stage 4-bit funnel (ops/pq4.py), or None for the
-        8-bit fallback paths. Its own failure domain (self._pq4_state) and
+                                   b: int, k: int, allow_list,
+                                   budgets: tuple[int, int]):
+        """Run the three-stage 4-bit funnel (ops/pq4.py) at the plan's
+        `budgets` (rg4, rc: planned against the SLAB, capacity, not live n —
+        the scan plane's group-columns are capacity-derived, and on a
+        sparse slab the live rows spread across up to min(n, ncols)
+        columns, so a live-n clamp would keep far fewer columns than
+        actually carry data; dead slots already score inf, so capacity
+        never over-scans), or None where the kernel is refused: the 8-bit
+        paths then serve. Its own failure domain (self._pq4_state) and
         per-shape validation, like the other fused kernels — but unlike
         eligible_rg, Pallas ineligibility here only downgrades STAGE 1 to
         the traceable byte-LUT scan; the funnel itself still serves."""
         from weaviate_tpu.ops import gmin_scan, pq_gmin
         from weaviate_tpu.ops import pq4 as pq4_ops
 
-        if snap.codes4 is None or snap.pq4 is None:
-            return None
-        if self.metric not in (vi.DISTANCE_L2, vi.DISTANCE_DOT,
-                               vi.DISTANCE_COSINE):
-            return None
         kk = min(max(k, 1), snap.live)
         ncols = snap.capacity // gmin_scan.G
         active_g = max(1, -(-snap.n // ncols))
         mb = snap.pq4.segments // 2
-        # budgets plan against the SLAB (capacity), not live n: the scan
-        # plane's group-columns are capacity-derived, and on a sparse slab
-        # the live rows spread across up to min(n, ncols) columns — a
-        # live-n clamp would keep far fewer columns than actually carry
-        # data (dead slots already score inf, so capacity never
-        # over-scans)
-        rg4, rc = self._funnel_budgets(kk, snap.capacity)
-        if rc < kk:
-            return None  # candidate set too small to cover k: 8-bit paths
+        rg4, rc = budgets
         bq = q.shape[0]
         use_pallas = pq4_ops.pallas_eligible(
             self._pq4_state, self.metric, bq, ncols, snap.dim, mb, active_g,
@@ -3718,15 +3655,15 @@ class TpuVectorIndex(VectorIndex):
         reference (lock-free unless writes are pending), dispatch, fetch.
         Concurrent writers republish new snapshots but can never tear or
         block this dispatch — the snapshot pins its arrays."""
-        snap = self._read_snapshot()
+        snap, _ = self._read_snapshot()
         return self._dispatch_search(snap, vectors, k, allow_list)()
 
     def _dispatch_search(self, snap: IndexSnapshot, vectors: np.ndarray,
                          k: int, allow_list: Optional[AllowList] = None):
         """Two-phase search on `snap`: enqueue the device work NOW (query
-        upload + kernels — nothing blocks), return finalize() -> (ids,
-        dists) whose ONE blocking device->host fetch runs outside any
-        lock. Every read-path case — full scan, both PQ tiers, filtered
+        upload + kernels — nothing blocks), return its `DispatchHandle`:
+        handle() -> (ids, dists), whose ONE blocking device->host fetch runs
+        outside any lock. Every read-path case — full scan, both PQ tiers, filtered
         scans, the small-allowList gather — dispatches through here, so
         sync and async searches run the same kernels with the same
         arguments (the bit-identical contract). The snapshot is pinned for
@@ -3741,291 +3678,117 @@ class TpuVectorIndex(VectorIndex):
 
     def _enqueue_search(self, snap: IndexSnapshot, vectors: np.ndarray,
                         k: int, allow_list: Optional[AllowList]):
-        """`_dispatch_search` on a snapshot its caller has pinned."""
+        """`_dispatch_search` on a snapshot its caller has pinned: plan the
+        dispatch (index/plan.py), run the dispatcher of its tier, hand back
+        the `DispatchHandle`."""
         if snap.n == 0 or snap.live == 0:
             b = 1 if np.asarray(vectors).ndim == 1 else len(vectors)
-            empty = (np.zeros((b, 0), dtype=np.uint64),
-                     np.zeros((b, 0), dtype=np.float32))
-            return lambda: empty
+            return DispatchHandle.ready((np.zeros((b, 0), dtype=np.uint64),
+                                         np.zeros((b, 0), dtype=np.float32)))
         faults.fire("index.tpu.dispatch")
-        # perf-attribution shape (monitoring/costmodel.py): built ONLY
-        # while the tracer is up — the disabled serving path constructs
-        # nothing here (one comparison; spy-pinned in tests/test_perf.py).
-        # Stamped with the host-overhead ledger as the dispatch executes
-        # and popped by the shard on the dispatching thread
-        # (pop_dispatch_shape, the pop_read_lock_wait idiom).
-        shape = None
-        t_enq0 = 0.0
-        enqueue = None
-        if tracing.get_tracer() is not None:
-            enqueue = tracing.Phase("enqueue")
-            t_enq0 = enqueue.start_ns / 1e9
+        # the `enqueue` interval and the perf-attribution shape
+        # (monitoring/costmodel.py) exist ONLY while the tracer is up — the
+        # disabled serving path constructs nothing here (one comparison;
+        # spy-pinned in tests/test_perf.py). The shape is stamped with the
+        # host-overhead ledger as the dispatch executes
+        enqueue = (tracing.Phase("enqueue")
+                   if tracing.get_tracer() is not None else None)
         try:
             q, b = self._prep_queries_staged(vectors)
-            stage_buf = q  # returned to the pool by the finalize wrapper
-            k_eff = min(k, snap.live)
-            if allow_list is not None and len(allow_list) < self.config.flat_search_cutoff:
-                if t_enq0:
-                    shape = costmodel.DispatchShape(
-                        costmodel.TIER_GATHER,
-                        n=min(len(allow_list), snap.live), dim=snap.dim,
-                        batch=b, batch_padded=q.shape[0],
-                        bytes_per_row=snap.dim * 4, k=int(k_eff))
-                fin = self._dispatch_small_allow(snap, q, b, k_eff, allow_list,
-                                                 shape)
-            elif (ivf_plan := self._ivf_plan(snap, k_eff)) is not None:
+            plan = plan_search(
+                self._plan_view(snap), b, q.shape[0], min(k, snap.live),
+                None if allow_list is None else len(allow_list))
+            handle = DispatchHandle(
+                self, "index.tpu.finalize", plan,
+                None if enqueue is None
+                else plan.shape(enqueue.start_ns / 1e9))
+            if plan.tier == costmodel.TIER_GATHER:
+                fin = self._dispatch_small_allow(
+                    snap, q, b, plan.k_eff, allow_list, handle.shape)
+            elif plan.ivf is not None:
                 # partition-pruned path (ROADMAP item 3): scan only the
                 # probed buckets; large allowLists compose via the same
                 # packed words, small ones took the gather tier above
-                if t_enq0:
-                    shape = self._ivf_shape(snap, ivf_plan, b, q.shape[0],
-                                            k_eff)
-                fin = self._dispatch_ivf(snap, q, b, k_eff, allow_list,
-                                         ivf_plan, shape)
+                fin = self._dispatch_ivf(snap, q, b, allow_list, plan,
+                                         handle.shape)
             elif snap.compressed:
-                if t_enq0:
-                    rescore = (self.config.pq.rescore
-                               and snap.rescore_dev is not None)
-                    funnel = (snap.codes4 is not None
-                              and self.metric in (vi.DISTANCE_L2,
-                                                  vi.DISTANCE_DOT,
-                                                  vi.DISTANCE_COSINE))
-                    if funnel:
-                        # the 4-bit funnel tier: stage 1 reads M/2 packed
-                        # bytes per scanned row; the re-ranking stages are
-                        # attributed in extra (C/c rows at M and 2·D bytes)
-                        # — a mid-dispatch refusal re-labels this below
-                        rg4_s, rc_s = self._funnel_budgets(
-                            int(k_eff), snap.capacity)
-                        shape = costmodel.DispatchShape(
-                            costmodel.TIER_PQ_ADC4,
-                            n=snap.n, dim=snap.dim, batch=b,
-                            batch_padded=q.shape[0],
-                            bytes_per_row=snap.pq4.segments // 2,
-                            k=int(k_eff),
-                            extra={"funnel_c": rg4_s * 16,
-                                   "funnel_rescore": rc_s,
-                                   "funnel_stage2_bytes_per_row":
-                                       snap.pq.segments,
-                                   "funnel_stage3_bytes_per_row":
-                                       (2 * snap.dim if rescore else 0)})
-                    else:
-                        shape = costmodel.DispatchShape(
-                            costmodel.TIER_PQ_RESCORE if rescore
-                            else costmodel.TIER_PQ_CODES,
-                            n=snap.n, dim=snap.dim, batch=b,
-                            batch_padded=q.shape[0],
-                            # rescore scans the bf16 copy (2·D); codes-only
-                            # reads the uint8 codes (M = segments bytes/row)
-                            bytes_per_row=(2 * snap.dim if rescore
-                                           else snap.pq.segments),
-                            k=int(k_eff))
-                fin = self._dispatch_full_pq(snap, q, b, k_eff, allow_list,
-                                             shape)
+                fin = self._dispatch_full_pq(snap, q, b, allow_list, handle)
             else:
-                if t_enq0:
-                    shape = costmodel.DispatchShape(
-                        costmodel.TIER_EXACT, n=snap.n, dim=snap.dim,
-                        batch=b, batch_padded=q.shape[0],
-                        bytes_per_row=snap.dim * snap.store.dtype.itemsize,
-                        k=int(k_eff))
                 allow_words = (self._allow_words(snap, allow_list)
                                if allow_list is not None else None)
-                fin = self._dispatch_scan(snap, q, b, k_eff, allow_words,
-                                          shape=shape)
+                fin = self._dispatch_scan(snap, q, b, allow_words, handle)
         except BaseException:
             if enqueue is not None:  # a dispatch that failed being built
                 enqueue.end()
             raise
-        if shape is not None:
+        if enqueue is not None:
             # a full-store scan names the program that ran it
-            program = (shape.extra or {}).get("program")
-            now_ns = enqueue.end(rows=b, tier=shape.tier,
-                                 **({"program": program} if program else {}))
-            shape.t_start = t_enq0
-            shape.enqueue_ms = (now_ns - enqueue.start_ns) / 1e6
-            self._read_local.dispatch_shape = shape
+            now_ns = enqueue.end(rows=b, tier=handle.plan.tier,
+                                 **handle.plan.stats())
+            handle.shape.enqueue_ms = (now_ns - enqueue.start_ns) / 1e6
         # shadow-audit snapshot pin (monitoring/quality.py): record which
         # snapshot THIS dispatch read so a sampled audit re-executes
         # against the same index state — writers publishing between
-        # enqueue and finalize must not skew the comparison. TLS holds at
-        # most one snapshot per serving thread; gated so the disabled
-        # path stores nothing (one comparison, the tracer contract).
+        # enqueue and finalize must not skew the comparison. Gated so the
+        # disabled path stores nothing (one comparison, the tracer
+        # contract).
         if quality.get_auditor() is not None:
-            self._read_local.audit_snap = snap
-        self._track_inflight(1)
-        done = [False]
+            handle.snapshot = snap
+        # `q` is the staging buffer: the handle returns it to the pool
+        return handle.launched(fin, q)
 
-        def finalize():
-            fetched = False
-            try:
-                faults.fire("index.tpu.finalize")
-                if shape is None:
-                    out = fin()
-                    fetched = True
-                    return out
-                if shape.fetches:
-                    # a RETRIED finalize (permitted — see done[] below)
-                    # re-runs the fetch; the ledger invariant is per
-                    # attempt, and the recorded shape must describe the
-                    # attempt whose results the caller actually got — a
-                    # leftover count would read as a spurious double-
-                    # fetch violation in /debug/perf
-                    shape.fetches = 0
-                t0 = time.perf_counter()
-                try:
-                    out = fin()
-                    fetched = True
-                finally:  # also when the host half of finalize raised
-                    t1 = shape.end_hop()
-                shape.finalize_ms = (t1 - t0) * 1000.0
-                shape.t_end = t1
-                return out
-            finally:
-                if not done[0]:  # idempotent: finalize may be retried
-                    done[0] = True
-                    self._track_inflight(-1)
-                    if fetched:
-                        # the staging buffer goes back to the pool ONLY
-                        # after a completed fetch: by then the program has
-                        # consumed its inputs (cpu-backend device_put may
-                        # alias host memory). A pre-fetch failure strands
-                        # the buffer for the GC instead — a recycled
-                        # buffer could be overwritten under a still-
-                        # enqueued program and corrupt a permitted retry
-                        self._release_stage(stage_buf)
+    def _plan_view(self, snap: IndexSnapshot) -> PlanView:
+        """What index/plan.py reads of `snap` on one chip."""
+        pq = snap.compressed
+        rescore = bool(pq and self.config.pq.rescore
+                       and snap.rescore_dev is not None)
+        funnel = (pq and snap.codes4 is not None and snap.pq4 is not None
+                  and self.metric in ivf_ops.MATMUL_METRICS)
+        ivf = snap.ivf_buckets is not None
+        return PlanView(
+            config=self.config, metric=self.metric,
+            programs=self.scan_programs, kernels=self,
+            component="index.tpu.gmin", n=snap.n, live=snap.live,
+            dim=snap.dim, ndev=1, slab=snap.capacity, fill=snap.n,
+            itemsize=0 if pq else snap.store.dtype.itemsize, compressed=pq,
+            pq_segments=snap.pq.segments if pq else 0,
+            pq4_segments=snap.pq4.segments if funnel else 0,
+            rescore=rescore,
+            # the rescore tier scans the bf16 copy of the rows (2·D)
+            rescore_bytes_per_row=2 * snap.dim if rescore else 0,
+            rescore_scan_itemsize=(snap.rescore_dev.dtype.itemsize
+                                   if rescore else 0),
+            ivf_meta=snap.ivf_meta[:2] if ivf else None,
+            ivf_probe=functools.partial(self._ivf_plan, snap) if ivf
+            else None)
 
-        return finalize
-
-    def pop_dispatch_shape(self):
-        """The costmodel.DispatchShape of the CALLING thread's last
-        dispatch (None while the tracer is down); reading clears it. The
-        shard pops it on the dispatching thread — like the lock-wait fact
-        — and attaches it to the trace record / perf window after
-        finalize stamps the device timings (the shape object is shared
-        with the finalize closure, so a pop at enqueue time still
-        observes them)."""
-        s = getattr(self._read_local, "dispatch_shape", None)
-        if s is not None:
-            self._read_local.dispatch_shape = None
-        return s
-
-    def pop_audit_snapshot(self) -> Optional[IndexSnapshot]:
-        """The IndexSnapshot the CALLING thread's last dispatch read (None
-        unless an auditor was configured at dispatch time); reading clears
-        it. Popped by the shard on the dispatching thread — the
-        pop_read_lock_wait idiom — and handed to the quality auditor so
-        the shadow re-execution is generation-pinned."""
-        s = getattr(self._read_local, "audit_snap", None)
-        if s is not None:
-            self._read_local.audit_snap = None
-        return s
-
-    def dispatch_tier(self, snap: IndexSnapshot, allow_list=None) -> str:
-        """The costmodel TIER_* a dispatch on `snap` with `allow_list`
-        takes — the same branching as _dispatch_search, exposed so the
-        quality auditor labels its bounded-cardinality gauges without a
-        tracer-built DispatchShape."""
-        if allow_list is not None \
-                and len(allow_list) < self.config.flat_search_cutoff:
-            return costmodel.TIER_GATHER
-        if snap.compressed:
-            if snap.codes4 is not None and self.metric in (
-                    vi.DISTANCE_L2, vi.DISTANCE_DOT, vi.DISTANCE_COSINE):
-                return costmodel.TIER_PQ_ADC4
-            if self.config.pq.rescore and snap.rescore_dev is not None:
-                return costmodel.TIER_PQ_RESCORE
-            return costmodel.TIER_PQ_CODES
-        return costmodel.TIER_EXACT
+    def dispatch_tier(self, snap: IndexSnapshot, allow_list=None,
+                      b: int = 1, k: int = 1) -> str:
+        """The costmodel TIER_* a dispatch of `b` queries at depth `k` on
+        `snap` with `allow_list` takes: its plan's (index/plan.py). For the
+        quality auditor, which labels its bounded-cardinality gauges
+        without a tracer-built shape; the program is not asked, so nothing
+        is counted."""
+        return plan_search(
+            self._plan_view(snap), b, _bucket_b(b), min(k, snap.live),
+            None if allow_list is None else len(allow_list),
+            refused=frozenset((KERNEL_GMIN,))).tier
 
     # -- IVF scan plane: dispatch half ---------------------------------------
 
     def _ivf_plan(self, snap: IndexSnapshot,
                   k: int) -> Optional[tuple[int, int]]:
-        """(top_p, prefilter_c) for an IVF dispatch on `snap`, or None to
-        take the flat path. None whenever the plane is disabled, the
-        snapshot carries no trained layout, or the metric has no
-        matmul/rescore form — the first two checks are one comparison
-        each (the zero-hop contract). The effective probe count is the
-        configured value capped by the controller's recall-guarded
-        budget (serving/controller.py ivf_top_p_cap) and snapped to the
-        bounded IVF_TOP_P_BUCKETS ladder (or to nlist exactly when the
-        request covers every partition), so top_p — a jit static — can
-        only take bounded values."""
-        if snap.ivf_buckets is None:
-            return None
-        s = ivf_settings()
-        if s is None:
-            return None
-        if self.metric not in ivf_ops.MATMUL_METRICS:
-            return None
-        nlist, cap_p, _gen = snap.ivf_meta
-        req = s.top_p if s.top_p > 0 else max(1, nlist // 16)
-        req = min(req, nlist)
-        eff = max(1, min(req, controller.ivf_top_p_cap(req)))
-        if eff < nlist:
-            eff = min(_snap_top_p(eff), nlist)
-        # deep-k coverage: a probe set under ~4k candidates starves the
-        # final selection (the flat fast-scan's slack rationale) — widen
-        # up the ladder before dispatching; neither the config nor the
-        # controller cap may shrink a query below its own k
-        while eff < nlist and eff * cap_p < 4 * k:
-            nxt = _snap_top_p(min(eff * 2, nlist))
-            eff = nlist if nxt <= eff else nxt
-        pre_c = 0
-        if snap.ivf_pca_proj is not None:
-            r = eff * cap_p
-            # auto: 8k floor for selection quality, r/8 cut, capped at
-            # 2048 — past that the full-dim pass stops being the
-            # bottleneck the prefilter exists to shrink
-            pc = s.prefilter_c if s.prefilter_c > 0 \
-                else max(8 * k, min(2048, r // 8))
-            pc = _bucket_rows(min(pc, r))  # pow2: bounded jit shapes
-            if pc < r:
-                pre_c = pc
-        return (eff, pre_c)
-
-    def _ivf_shape(self, snap: IndexSnapshot, plan: tuple[int, int],
-                   b: int, padded: int, k_eff: int):
-        """The probed-aware costmodel shape of an IVF dispatch: `n` is
-        the rows the device actually reads (top_p x cap_p candidates,
-        padding included, plus the nlist centroid rows), so flops/bytes
-        — and every roofline derived from them — never credit the rows
-        the probe skipped (no phantom work)."""
-        top_p, _pre_c = plan
-        nlist, cap_p, _gen = snap.ivf_meta
-        probed = top_p * cap_p + nlist
-        rescore = (snap.compressed and self.config.pq.rescore
-                   and snap.rescore_dev is not None)
-        if not snap.compressed:
-            tier = costmodel.TIER_EXACT
-            bpr = snap.dim * snap.store.dtype.itemsize
-        elif snap.codes4 is not None and self.metric in (
-                vi.DISTANCE_L2, vi.DISTANCE_DOT, vi.DISTANCE_COSINE):
-            tier = costmodel.TIER_PQ_ADC4
-            bpr = snap.pq4.segments // 2
-        elif rescore:
-            tier = costmodel.TIER_PQ_RESCORE
-            bpr = 2 * snap.dim
-        else:
-            tier = costmodel.TIER_PQ_CODES
-            bpr = snap.pq.segments
-        return costmodel.DispatchShape(
-            tier, n=probed, dim=snap.dim, batch=b, batch_padded=padded,
-            bytes_per_row=bpr, k=int(k_eff),
-            extra={"ivf": True, "ivf_top_p": top_p, "ivf_nlist": nlist,
-                   "probed_fraction": round(
-                       min(probed / max(snap.n, 1), 1.0), 4)})
+        return ivf_probe(self.metric, snap, k)
 
     def _dispatch_ivf(self, snap: IndexSnapshot, q: np.ndarray, b: int,
-                      k: int, allow_list, plan: tuple[int, int], shape):
+                      allow_list, plan, shape):
         """Partition-pruned search: probe the centroids, score only the
         probed buckets (ops/ivf.py), finish through the SAME translate
         epilogue as every flat tier. Covers the exact,
         PQ-rescore, and PQ-codes tiers; tombstones and allowLists mask
         with identical semantics to the flat kernels (the snapshot's own
         device tombs, the same packed filter words)."""
-        top_p, pre_c = plan
+        top_p, pre_c = plan.ivf
         nlist, cap_p, _gen = snap.ivf_meta
         allow_words = (self._allow_words(snap, allow_list)
                        if allow_list is not None else None)
@@ -4033,7 +3796,7 @@ class TpuVectorIndex(VectorIndex):
         words = (allow_words if use_allow
                  else jnp.zeros((snap.capacity // 32,), jnp.uint32))
         exact = getattr(self.config, "exact_topk", False)
-        kk = min(max(k, 1), top_p * cap_p)
+        kk = min(max(plan.k_eff, 1), top_p * cap_p)
         gp = ivf_ops.group_steps(q.shape[0], cap_p, snap.dim, top_p)
         # second-stage chunking (prefilter survivors): pow2 steps so the
         # full-dim gather stays within the same element budget
@@ -4042,20 +3805,17 @@ class TpuVectorIndex(VectorIndex):
             while steps2 < pre_c and \
                     (q.shape[0] * (pre_c // steps2) * snap.dim) > (1 << 21):
                 steps2 *= 2
-        rescore = (snap.compressed and self.config.pq.rescore
-                   and snap.rescore_dev is not None)
-        funnel4 = (snap.codes4 is not None and snap.pq4 is not None
-                   and self.metric in (vi.DISTANCE_L2, vi.DISTANCE_DOT,
-                                       vi.DISTANCE_COSINE))
-        if funnel4:
+        if plan.tier == costmodel.TIER_PQ_ADC4:
             # probed three-stage funnel (ops/pq4.search_ivf_pq4): grouped
             # 4-bit byte-LUT cut -> exact 8-bit ADC of the survivors ->
             # bf16 rescore — the funnel budgets bound stages 1/2 over the
-            # probed candidate set exactly as over the full store
+            # probed candidate set exactly as over the full store (budgets
+            # that cannot cover this k over the probed set were planned as
+            # the 8-bit IVF tier: index/plan.py)
             from weaviate_tpu.ops import pq4 as pq4_ops
 
             r_cand = top_p * cap_p
-            rg4, rc = self._funnel_budgets(kk, r_cand)
+            rg4, rc = plan.funnel
             c1 = min(rg4 * 16, r_cand)
             # stage-2 chunking over the c1 survivors: pow2 steps under the
             # shared element budget, stopped early if a further halving
@@ -4065,38 +3825,30 @@ class TpuVectorIndex(VectorIndex):
                    and (q.shape[0] * (c1 // steps2_4) * snap.dim)
                    > (1 << 21)):
                 steps2_4 *= 2
-            if rc >= kk and c1 >= rc:
-                statics4 = (kk, self.metric, use_allow, top_p, c1, rc,
-                            exact, gp, steps2_4)
-                args4 = (snap.codes4, snap.codes, snap.recon_norms4,
-                         snap.recon_norms, snap.tombs, snap.n,
-                         jnp.asarray(q), words, snap.pq4._dev_codebook(),
-                         snap.pq._dev_codebook(), snap.ivf_centroids,
-                         snap.ivf_buckets, snap.opq_rot, snap.rescore_dev)
-                packed_dev = pq4_ops.search_ivf_pq4_fused(
-                    *args4, snap.slot_to_doc_dev, *statics4)
-                with self._ivf_lock:
-                    st = self._ivf_stats
-                    st["dispatches"] += 1
-                    st["probed_rows"] += top_p * cap_p
-                    st["base_rows"] += int(snap.n)
-                with self._pq4_lock:
-                    st = self._pq4_stats
-                    st["dispatches"] += 1
-                    st["stage1_rows"] += r_cand
-                    st["stage2_survivors"] += min(c1, r_cand)
-                    st["stage3_survivors"] += min(rc, r_cand)
-                return self._finalize_fused(packed_dev, shape, b)
-            if shape is not None and shape.tier == costmodel.TIER_PQ_ADC4:
-                # budgets can't cover this k over the probed set: the
-                # 8-bit IVF tier serves — re-label (no phantom traffic)
-                shape.tier = (costmodel.TIER_PQ_RESCORE if rescore
-                              else costmodel.TIER_PQ_CODES)
-                shape.bytes_per_row = (2 * snap.dim if rescore
-                                       else snap.pq.segments)
+            statics4 = (kk, self.metric, use_allow, top_p, c1, rc,
+                        exact, gp, steps2_4)
+            args4 = (snap.codes4, snap.codes, snap.recon_norms4,
+                     snap.recon_norms, snap.tombs, snap.n,
+                     jnp.asarray(q), words, snap.pq4._dev_codebook(),
+                     snap.pq._dev_codebook(), snap.ivf_centroids,
+                     snap.ivf_buckets, snap.opq_rot, snap.rescore_dev)
+            packed_dev = pq4_ops.search_ivf_pq4_fused(
+                *args4, snap.slot_to_doc_dev, *statics4)
+            with self._ivf_lock:
+                st = self._ivf_stats
+                st["dispatches"] += 1
+                st["probed_rows"] += top_p * cap_p
+                st["base_rows"] += int(snap.n)
+            with self._pq4_lock:
+                st = self._pq4_stats
+                st["dispatches"] += 1
+                st["stage1_rows"] += r_cand
+                st["stage2_survivors"] += min(c1, r_cand)
+                st["stage3_survivors"] += min(rc, r_cand)
+            return self._finalize_fused(packed_dev, shape, b)
         statics = (kk, self.metric, use_allow, top_p, pre_c, exact, gp,
                    steps2)
-        if not snap.compressed or rescore:
+        if plan.tier != costmodel.TIER_PQ_CODES:
             store = snap.store if not snap.compressed else snap.rescore_dev
             args = (store, snap.tombs, snap.n, jnp.asarray(q), words,
                     snap.ivf_centroids, snap.ivf_buckets,
@@ -4121,35 +3873,37 @@ class TpuVectorIndex(VectorIndex):
         return self._finalize_fused(packed_dev, shape, b)
 
     def _dispatch_scan(self, snap: IndexSnapshot, q: np.ndarray, b: int,
-                       k_eff: int, allow_words, store=None, sq_norms=None,
-                       shape=None):
+                       allow_words, handle: DispatchHandle, store=None,
+                       sq_norms=None):
         """Full-store scan over `store` — the f32 store uncompressed, or the
         bf16 rescore copy under PQ-with-rescore (scanning codes first would
         read MORE HBM than the copy the rescore pass consults anyway) — by
-        one of two programs: the fused gmin kernel where it is eligible,
-        compiles and is the faster at this width (`_gmin_packed_or_none`,
-        `gmin_scan.kernel_serves`), the lax.scan program otherwise. Which
-        one ran is counted (`scan_programs`) and, while the tracer is up, named
-        on the shape (`extra["program"]`) and in the `enqueue` interval's
-        stats. The slot->doc translation runs in the same program, against
-        the snapshot's device table, and finalize is a reshape; over the
-        bf16 copy the program's columns are candidates and finalize scores
-        them from the float32 rows the host keeps (`_rescore_f32`)."""
-        from weaviate_tpu.ops.gmin_scan import PROGRAM_GMIN, PROGRAM_SCAN
-
-        kk = min(max(k_eff, 1), snap.n)
-        packed_dev = self._gmin_packed_or_none(snap, q, kk, allow_words,
-                                               store, sq_norms)
-        program = PROGRAM_GMIN
+        the program the plan names: the fused gmin kernel where it is
+        eligible, compiles and is the faster at this width (index/plan.py,
+        `gmin_scan.kernel_serves`), the lax.scan program otherwise, and
+        where Mosaic refuses the kernel this shape (the one place that
+        falls back: the dispatch is planned again). Which one ran is
+        counted (`scan_programs`) and, while the tracer is up, named on the
+        shape (`extra["program"]`) and in the `enqueue` interval's stats.
+        The slot->doc translation runs in the same program, against the
+        snapshot's device table, and finalize is a reshape; over the bf16
+        copy the program's columns are candidates and finalize scores them
+        from the float32 rows the host keeps (`_rescore_f32`)."""
+        plan = handle.plan
+        kk = min(max(plan.k_eff, 1), snap.n)
+        packed_dev = None
+        if plan.gmin is not None:
+            packed_dev = self._gmin_packed_or_none(
+                snap, q, kk, allow_words, plan.gmin, store, sq_norms)
+            if packed_dev is None:
+                plan = handle.refuse(self._plan_view(snap), KERNEL_GMIN)
         if packed_dev is None:
-            program = PROGRAM_SCAN
             packed_dev = self._scan_program(
                 snap, snap.store if store is None else store,
                 snap.sq_norms if sq_norms is None else sq_norms, q,
                 allow_words, kk, candidates=store is not None)
-        self.scan_programs.count(program)
-        if shape is not None:
-            shape.extra = {**(shape.extra or {}), "program": program}
+        self.scan_programs.count(plan.program)
+        shape = handle.shape
         if store is None:
             return self._finalize_fused(packed_dev, shape, b)
 
@@ -4230,8 +3984,8 @@ class TpuVectorIndex(VectorIndex):
         return finalize
 
     def _dispatch_full_pq(self, snap: IndexSnapshot, q: np.ndarray, b: int,
-                          k: int, allow_list, shape):
-        """Compressed full-store search.
+                          allow_list, handle: DispatchHandle):
+        """Compressed full-store search, at the tier the plan names.
 
         With rescore enabled a full bf16 copy of the rows already lives in
         HBM for the rescoring pass — so the fast scan reads THAT copy
@@ -4250,31 +4004,27 @@ class TpuVectorIndex(VectorIndex):
         from weaviate_tpu.compress.pq import build_lut
 
         pqc = self.config.pq
-        # 4-bit funnel tier first (pq.bits=4): the stage-1 scan reads M/2
-        # bytes per row — less HBM than the bf16 copy (2D) or even the
-        # 8-bit codes (M) — and the two re-ranking stages restore recall.
-        # A broken/ineligible funnel falls through to the 8-bit paths
-        # below (the codes and rescore slabs both still exist).
-        packed4 = self._pq4_funnel_packed_or_none(snap, q, b, k, allow_list)
-        if packed4 is not None:
-            return self._finalize_fused(packed4, shape, b, k)
-        if shape is not None and shape.tier == costmodel.TIER_PQ_ADC4:
-            # the funnel refused mid-dispatch (broken kernel / shallow
-            # budgets): re-label the shape for the tier that actually
-            # serves, so /debug/perf carries no phantom 4-bit traffic
-            rescore_fb = pqc.rescore and snap.rescore_dev is not None
-            shape.tier = (costmodel.TIER_PQ_RESCORE if rescore_fb
-                          else costmodel.TIER_PQ_CODES)
-            shape.bytes_per_row = (2 * snap.dim if rescore_fb
-                                   else snap.pq.segments)
-        rescore = pqc.rescore and snap.rescore_dev is not None
-        if rescore:
+        plan = handle.plan
+        k = plan.k_eff
+        if plan.tier == costmodel.TIER_PQ_ADC4:
+            # 4-bit funnel tier (pq.bits=4): the stage-1 scan reads M/2
+            # bytes per row — less HBM than the bf16 copy (2D) or even the
+            # 8-bit codes (M) — and the two re-ranking stages restore
+            # recall. Shallow budgets were planned as the 8-bit tiers; a
+            # kernel refused here is planned again as them (the codes and
+            # rescore slabs both still exist), so /debug/perf carries no
+            # phantom 4-bit traffic
+            packed4 = self._pq4_funnel_packed_or_none(
+                snap, q, b, k, allow_list, plan.funnel)
+            if packed4 is not None:
+                return self._finalize_fused(packed4, handle.shape, b, k)
+            plan = handle.refuse(self._plan_view(snap), KERNEL_FUNNEL)
+        if plan.tier == costmodel.TIER_PQ_RESCORE:
             allow_words = (self._allow_words(snap, allow_list)
                            if allow_list is not None else None)
             return self._dispatch_scan(
-                snap, q, b, k, allow_words,
-                store=snap.rescore_dev, sq_norms=snap.rescore_sq_norms,
-                shape=shape)
+                snap, q, b, allow_words, handle,
+                store=snap.rescore_dev, sq_norms=snap.rescore_sq_norms)
         # codes-only tier from here: raw ADC distances, no rescoring pass.
         # Fast path: the fused PQ-ADC group-min kernel (ops/pq_gmin.py) —
         # reconstruction-as-matmul in VMEM, codes never expand in HBM
@@ -4345,7 +4095,7 @@ class TpuVectorIndex(VectorIndex):
                 )
                 packed_dev = _search_pq_fused(
                     *args, snap.slot_to_doc_dev, *statics)
-        return self._finalize_fused(packed_dev, shape, b, k)
+        return self._finalize_fused(packed_dev, handle.shape, b, k)
 
     def _allow_slots(self, snap: IndexSnapshot,
                      allow_list: AllowList) -> np.ndarray:
@@ -4535,7 +4285,7 @@ class TpuVectorIndex(VectorIndex):
         dists, inf-padded absent slots); selection is exact, so recall can
         only go UP while degraded — latency and throughput pay instead."""
         while True:
-            snap = self._read_snapshot()
+            snap, _ = self._read_snapshot()
             if snap.n == 0 or snap.live == 0:
                 b = 1 if np.asarray(vectors).ndim == 1 else len(vectors)
                 return (np.zeros((b, 0), np.uint64),
@@ -4792,7 +4542,7 @@ class TpuVectorIndex(VectorIndex):
             }
             if self._codes4 is not None and self._pq4 is not None:
                 k_ref = 10  # reference depth for the budget readout
-                rg4, rc = self._funnel_budgets(k_ref, max(self.capacity, 1))
+                rg4, rc = funnel_budgets(k_ref, max(self.capacity, 1))
                 with self._pq4_lock:
                     st = dict(self._pq4_stats)
                 d = max(st["dispatches"], 1)
@@ -4844,8 +4594,10 @@ class TpuVectorIndex(VectorIndex):
         behind the compute of batch i, and the coalescer's finalize runs on
         its dispatch pool without contending with the next enqueue.
         """
-        snap = self._read_snapshot()
-        return self._dispatch_search(snap, vectors, k, allow_list)
+        snap, wait_ms = self._read_snapshot()
+        handle = self._dispatch_search(snap, vectors, k, allow_list)
+        handle.lock_wait_ms = wait_ms
+        return handle
 
     # -- a group of slots, each with its own filter ---------------------------
 
@@ -4861,19 +4613,23 @@ class TpuVectorIndex(VectorIndex):
         (costmodel.plan_filtered_group), not cut at a constant a slot.
         Equal allowList OBJECTS are resolved once.
 
-        -> finalize() -> (ids [S, k'] uint64, dists [S, k'] float32, inf
-        where a slot has fewer than k' answers), with `finalize.shapes`,
-        the DispatchShapes of the dispatches made (empty while the tracer
-        is down); or None where this index state has no per-slot program
-        (compressed, or the IVF plane on): the caller then searches slot
-        by slot. Every slot's answer is exact over the rows its own filter
-        allows in the snapshot read here; tombstones are masked on the
-        device by that snapshot."""
-        snap = self._pin(self._read_snapshot())
+        -> a `DispatchHandle`: handle() -> (ids [S, k'] uint64, dists
+        [S, k'] float32, inf where a slot has fewer than k' answers), with
+        `handle.shapes`, the shapes of the dispatches made (empty while the
+        tracer is down); or None where this index state has no per-slot
+        program (compressed, or the IVF plane on): the caller then searches
+        slot by slot. Every slot's answer is exact over the rows its own
+        filter allows in the snapshot read here; tombstones are masked on
+        the device by that snapshot."""
+        snap, wait_ms = self._read_snapshot()
+        snap = self._pin(snap)
         try:
-            return self._enqueue_group(snap, vectors, k, allow_lists)
+            handle = self._enqueue_group(snap, vectors, k, allow_lists)
         finally:
             self._unpin(snap)
+        if handle is not None:
+            handle.lock_wait_ms = wait_ms
+        return handle
 
     def _enqueue_group(self, snap: IndexSnapshot, vectors: np.ndarray,
                        k: int, allow_lists):
@@ -4886,10 +4642,8 @@ class TpuVectorIndex(VectorIndex):
             raise ValueError(f"{len(allow_lists)} allowLists for {s} queries")
         k_eff = min(k, snap.live)
         if snap.n == 0 or k_eff <= 0:
-            empty = (np.zeros((s, 0), np.uint64), np.zeros((s, 0), np.float32))
-            fin = lambda: empty  # noqa: E731
-            fin.shapes = []
-            return fin
+            return DispatchHandle.ready((np.zeros((s, 0), np.uint64),
+                                         np.zeros((s, 0), np.float32)))
         faults.fire("index.tpu.dispatch")
         traced = tracing.get_tracer() is not None
         # one `enqueue` interval a dispatch; the first also holds the
@@ -4949,52 +4703,37 @@ class TpuVectorIndex(VectorIndex):
                 if enqueue is not None:
                     enqueue.end()   # _dispatch_search opens its own
                     enqueue = None
-                # the unfiltered dispatch as it always was; its own
-                # finalize stamps its shape
-                parts.append((plain, self._dispatch_search(
-                    snap, q[plain], k_eff, None), None, None))
-                shape = self.pop_dispatch_shape()
-                if shape is not None:
-                    shapes.append(shape)
+                # the unfiltered dispatch as it always was: its own
+                # handle stamps its shape (and carries the audit pin, which
+                # belongs to single dispatches: a group's has none)
+                handle = self._dispatch_search(snap, q[plain], k_eff, None)
+                parts.append((plain, handle, None, None))
+                if handle.shape is not None:
+                    shapes.append(handle.shape)
         finally:
             if enqueue is not None:   # nothing dispatched, or a failure
                 enqueue.end()
-        # the audit pin belongs to single dispatches; leave none behind
-        self.pop_audit_snapshot()
-        self._track_inflight(1)
-        done = [False]
 
         def finalize():
-            try:
-                faults.fire("index.tpu.finalize")
-                ids = np.zeros((s, k_eff), np.uint64)
-                dists = np.full((s, k_eff), np.inf, np.float32)
-                for sel, fin, shape, operand in parts:  # graftlint: disable=JGL015 a loop over the group's DISPATCHES (a handful: one a row bucket, one scan), each consumed by unpack_fused; no per-row work
-                    t0 = time.perf_counter()
-                    try:
-                        pi, pd = fin()
-                    finally:
-                        if shape is not None:
-                            shape.t_end = shape.end_hop()
-                            shape.finalize_ms = (shape.t_end - t0) * 1000.0
-                    # fetched: the program has consumed its operands, the
-                    # next group may write them (a fetch that failed
-                    # strands its buffer, as the staging pool's does)
-                    if operand is not None:
-                        # `_release_stage`'s rule: never into the pool
-                        # drop() cleared
-                        self._group_pool.give(
-                            operand, lambda: self.dim is not None)
-                    ids[sel, : pi.shape[1]] = pi
-                    dists[sel, : pd.shape[1]] = pd
-                return ids, dists
-            finally:
-                if not done[0]:
-                    done[0] = True
-                    self._track_inflight(-1)
+            ids = np.zeros((s, k_eff), np.uint64)
+            dists = np.full((s, k_eff), np.inf, np.float32)
+            for sel, fin, shape, operand in parts:  # graftlint: disable=JGL015 a loop over the group's DISPATCHES (a handful: one a row bucket, one scan), each consumed by unpack_fused; no per-row work
+                pi, pd = fetch_stamped(fin, shape)
+                # fetched: the program has consumed its operands, the
+                # next group may write them (a fetch that failed
+                # strands its buffer, as the staging pool's does)
+                if operand is not None:
+                    # `_release_stage`'s rule: never into the pool
+                    # drop() cleared
+                    self._group_pool.give(
+                        operand, lambda: self.dim is not None)
+                ids[sel, : pi.shape[1]] = pi
+                dists[sel, : pd.shape[1]] = pd
+            return ids, dists
 
-        finalize.shapes = shapes
-        return finalize
+        group = DispatchHandle(self, "index.tpu.finalize")
+        group.shapes = shapes
+        return group.launched(finalize)
 
     def _row_store(self, snap: IndexSnapshot):
         """The store as the per-slot programs read it: rows in whole lanes.

@@ -869,24 +869,32 @@ class Timeline:
         """The listeners are up: close the timeline to everything but
         `first_ready`, feed ``weaviate_startup_durations_ms`` one sample a
         stage and -> `seconds`, the `startup` log line's body."""
-        self.ready_ns = time.perf_counter_ns()
+        now = time.perf_counter_ns()
         doc = self.summary()
         for name, st in doc["stages"].items():
             _observe_stage(metrics, name, st["seconds"])
+        # stamped LAST: `first_ready` is taken from here on, so it is in
+        # no summary made above and is sampled once
+        self.ready_ns = now
         return doc["seconds"]
 
     def first_ready(self, metrics=None) -> None:
-        """The first readiness probe was answered: the last stage."""
+        """The first readiness probe answered after the listeners were up
+        (`ready`): the last stage. The REST server answers probes from the
+        moment it listens, while `listen` is still open (the gRPC server
+        starts after it): a probe that early is not the stage's, or the
+        stage would end before `listen` does and be sampled twice (by
+        `ready`, then here)."""
         with self._lock:
-            if self.sealed:
+            if self.sealed or self.ready_ns is None:
                 return
             self.sealed = True
             self._compiles_to = compiles.counts()
         now = time.perf_counter_ns()
-        start = self.ready_ns if self.ready_ns is not None else now
-        self.ready_ns = start
-        self.note("first_ready", start, now - start)
-        _observe_stage(metrics, "first_ready", (now - start) / 1e9)
+        # the sample before the page's stage: who reads the page and then
+        # the metrics finds the stage in both
+        _observe_stage(metrics, "first_ready", (now - self.ready_ns) / 1e9)
+        self.note("first_ready", self.ready_ns, now - self.ready_ns)
 
     # -- the page ------------------------------------------------------------
 

@@ -13,9 +13,9 @@ How it works:
 - the shard captures a sampled fraction of completed live searches at
   finalize (``RECALL_AUDIT_SAMPLE_RATE``; default 0 = off) — the query
   rows, requested k, allowList, and the returned (ids, dists);
-- the index pins the exact ``IndexSnapshot`` the dispatch read (the
-  ``pop_read_lock_wait`` TLS idiom, gated on ``get_auditor()`` so the
-  disabled path stores nothing), so the audit compares against the SAME
+- the dispatch's handle carries the exact ``IndexSnapshot`` it read
+  (index/plan.py ``DispatchHandle.snapshot``, gated on ``get_auditor()`` so
+  the disabled path stores nothing), so the audit compares against the SAME
   index state the live answer saw — deletes/compression between capture
   and audit cannot fabricate a recall drop;
 - a bounded background worker re-executes each sampled query against the
@@ -403,8 +403,8 @@ class QualityAuditor:
                       live_dists, class_name: str = "",
                       shard: str = "") -> bool:
         """Sample one completed live search. Called by db/shard.py at
-        finalize with the snapshot the dispatch read (already popped from
-        the index TLS pin). -> True when a task was admitted."""
+        finalize with the snapshot the dispatch read (off the dispatch's
+        handle). -> True when a task was admitted."""
         sampled = random.random() < self.sample_rate
         self.window.note_offered(sampled)
         if not sampled:
@@ -510,7 +510,10 @@ class QualityAuditor:
         deadline = (time.monotonic() + self.deadline_ms / 1000.0
                     if self.deadline_ms > 0 else None)
         vidx, snap = task.vidx, task.snap
-        tier = vidx.dispatch_tier(snap, task.allow)
+        # the task's own batch and k: a funnel its budgets refuse at this k
+        # is filed under the tier that served
+        tier = vidx.dispatch_tier(snap, task.allow, b=task.q.shape[0],
+                                  k=task.k)
         rows, sq = self._host_rows(vidx, snap)
         host_ids, host_d = vidx.search_by_vectors_host_pinned(
             snap, task.q, task.k, task.allow, rows=rows, sq_norms=sq,

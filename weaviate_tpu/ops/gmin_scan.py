@@ -127,8 +127,8 @@ def kernel_serves(b: int, d: int, ncols: int, ag: int,
                   store_bytes: int = 4) -> bool:
     """Whether the group-min kernel is the program to run a full-store scan
     with: it compiles (`fits_vmem`) AND it is the faster of the two at this
-    width. The one choice of both indexes (index/tpu.py _gmin_packed_or_none,
-    index/mesh.py _gmin_plan). False is a choice, not a degradation: the
+    width. The one choice of both indexes (index/plan.py plan_search asks
+    it for either). False is a choice, not a degradation: the
     caller runs ops/scan.py's program and builds nothing of the kernel's."""
     return (d < KERNEL_LOSES_FROM_DIM
             and fits_vmem(b, d, ncols, ag, store_bytes))
