@@ -28,6 +28,7 @@ import pytest
 from weaviate_tpu.config.config import (ConfigError, IVF_TOP_P_BUCKETS,
                                         IvfConfig, load_config)
 from weaviate_tpu.entities.vectorindex import parse_and_validate_config
+from weaviate_tpu.index import plan as plan_mod
 from weaviate_tpu.index import tpu
 from weaviate_tpu.index.tpu import TpuVectorIndex
 from weaviate_tpu.monitoring import memory, perf, tracing
@@ -40,7 +41,13 @@ DIM = 16
 
 
 @pytest.fixture(autouse=True)
-def _reset_globals():
+def _reset_globals(monkeypatch):
+    # these tests pin what the probed programs ANSWER, at sizes where a
+    # batch's partitions hold more rows than the whole store: the choice by
+    # bytes (index/plan.py probed_reads_less, tests/test_ivf_tiles.py) is
+    # set aside, so a layout always takes its probed program
+    monkeypatch.setattr(plan_mod, "PROBED_ROW_COST", 0.0)
+    monkeypatch.setattr(plan_mod, "GATHERED_ROW_COST", 0.0)
     yield
     tpu.set_ivf_config(None)
     tracing.configure(None)
@@ -70,6 +77,28 @@ def _mk_index(tmp_path, n=600, pq=None, seed=3, name="ivfx", spread=100,
     idx.add_batch(np.arange(n), vecs)
     idx.flush()
     return idx, vecs
+
+
+def _assert_tiles_complete(idx, docs):
+    """The tiled layout's invariant: every live doc in exactly one slot,
+    the slot's tile its partition (`slot // cap_p`), every other slot free
+    (tombstoned) on the host and on the device, the counts in step."""
+    snap = idx._read_snapshot()[0]
+    nlist, cap_p, _ = snap.ivf_meta
+    docs = sorted(docs)
+    s2d = snap.slot_to_doc[: snap.n]
+    held = np.flatnonzero(~snap.host_tombs[: snap.n])
+    assert sorted(s2d[held].tolist()) == docs
+    assert sorted(idx._doc_to_slot) == docs
+    assert all(idx._doc_to_slot[int(s2d[s])] == s for s in held)
+    np.testing.assert_array_equal(np.asarray(snap.tombs)[: snap.n],
+                                  snap.host_tombs[: snap.n])
+    fills = np.bincount(held // cap_p, minlength=nlist)
+    np.testing.assert_array_equal(idx._ivf_free_n, cap_p - fills)
+    assert fills.max() <= cap_p and int(fills.sum()) == len(docs)
+    # what a tile does not hold is zero rows
+    empty = np.flatnonzero(snap.host_tombs[: snap.n])
+    assert not np.asarray(snap.store)[empty[:64]].any()
 
 
 def assert_tie_equiv(got, want, msg=""):
@@ -111,7 +140,11 @@ def _tier(tmp_path, tier, n=600):
 def test_top_p_all_matches_flat_all_tiers_sync_async(tmp_path, tier):
     tpu.set_ivf_config(_ivf())  # trains at import time (min_n < n)
     idx, vecs, allow = _tier(tmp_path, tier)
-    assert idx._ivf_buckets is not None
+    # the uncompressed tiers keep the store in partition order (tiles), the
+    # compressed ones a bucket table over slots in order of arrival
+    assert idx._ivf_meta is not None
+    assert idx._ivf_tiled == (not tier.startswith("pq"))
+    assert (idx._ivf_buckets is None) == idx._ivf_tiled
     q = vecs[:9] + np.float32(1.0)
     # top_p=8 == nlist: every partition probed
     i_sync = idx.search_by_vectors(q, 10, allow)
@@ -182,15 +215,14 @@ def test_training_publishes_a_complete_layout(tmp_path):
     tpu.set_ivf_config(_ivf())
     idx, vecs = _mk_index(tmp_path)
     snap = idx._read_snapshot()[0]
-    assert snap.ivf_centroids is not None and snap.ivf_buckets is not None
+    assert snap.ivf_centroids is not None and snap.ivf_tiled
+    assert snap.ivf_buckets is None          # the table is slot // cap_p
     nlist, cap_p, gen = snap.ivf_meta
     assert nlist == 8 and gen == 1
-    buckets = np.asarray(snap.ivf_buckets)
-    assert buckets.shape == (nlist, cap_p)
-    slots = buckets[buckets >= 0]
-    # every live slot appears in exactly one bucket
-    assert sorted(slots.tolist()) == list(range(600))
-    assert int(idx._ivf_fills.sum()) == 600
+    assert snap.n == nlist * cap_p and snap.store.shape[0] >= snap.n
+    # every live row lies in exactly one partition's tile, the tiles fill
+    # from the front, and every other slot is a tombstoned (free) one
+    _assert_tiles_complete(idx, range(600))
 
 
 def test_bucket_shapes_stay_stable_across_small_inserts(tmp_path):
@@ -202,14 +234,13 @@ def test_bucket_shapes_stay_stable_across_small_inserts(tmp_path):
     extra = rng.integers(-100, 100, (16, DIM)).astype(np.float32)
     idx.add_batch(np.arange(600, 616), extra)
     idx.flush()
-    # incremental assignment, no retrain, same padded width: the search
-    # program's jit key ([nlist, cap_p]) is unchanged
+    # incremental assignment, no retrain, same tile width: the search
+    # program's jit key (top_p, cap_p, the store's shape) is unchanged
     assert idx._ivf_gen == gen0
     assert idx._ivf_meta[1] == cap_p0
-    # and the O(batch) incremental fold kept the bucket table COMPLETE:
-    # every slot (old and new) bucketed exactly once
-    buckets = np.asarray(idx._read_snapshot()[0].ivf_buckets)
-    assert sorted(buckets[buckets >= 0].tolist()) == list(range(616))
+    # and the new rows took free slots of their partitions' tiles: the
+    # layout stays COMPLETE, every row (old and new) in exactly one tile
+    _assert_tiles_complete(idx, range(616))
     # ...so the new rows are immediately findable through the probe
     ids, _ = idx.search_by_vectors(extra[:3], 1)
     assert ids[:, 0].tolist() == [600, 601, 602]
@@ -332,10 +363,7 @@ def test_compact_reclusters_on_the_dense_slot_space(tmp_path):
     idx.delete(*range(0, 200))
     idx.compact()
     assert idx._ivf_gen == gen0 + 1
-    snap = idx._read_snapshot()[0]
-    buckets = np.asarray(snap.ivf_buckets)
-    slots = buckets[buckets >= 0]
-    assert sorted(slots.tolist()) == list(range(400))  # dense, complete
+    _assert_tiles_complete(idx, range(200, 600))  # the live rows, complete
     ids, _ = idx.search_by_vectors(vecs[300][None], 3)
     assert int(ids[0, 0]) == 300
 

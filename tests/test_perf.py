@@ -724,14 +724,19 @@ def test_debug_perf_and_health_count_dispatches_by_program(tmp_path):
         base = f"http://127.0.0.1:{srv.port}"
         with urllib.request.urlopen(base + "/debug/perf", timeout=30) as r:
             body = json.loads(r.read())
+        # the full-store programs' three counts, and beside them the
+        # partition-pruned tier's two (no layout here: both 0)
         assert body["programs"] == {"gmin": 1, "scan": 1,
-                                    "declined_slower": 0}
+                                    "declined_slower": 0,
+                                    "ivf_declined": 0, "ivf_trainings": 0}
         assert list(body)[-3:] == ["programs", "startup", "compiles"]
         with urllib.request.urlopen(base + "/debug/index", timeout=30) as r:
             page = json.loads(r.read())
         (health,) = page["indexes"]["Pf"].values()
         gmin = health["vector_index"]["kernels"]["gmin"]
-        assert gmin["dispatches"] == body["programs"]
+        assert gmin["dispatches"] == {
+            k: body["programs"][k] for k in ("gmin", "scan",
+                                             "declined_slower")}
         assert gmin["validated"] == 1 and gmin["rejected"] == 0
         # a decline is the index's own count too
         shard.vector_index.scan_programs.declined()

@@ -16,6 +16,7 @@ import pytest
 
 from weaviate_tpu.config.config import IvfConfig
 from weaviate_tpu.entities.vectorindex import parse_and_validate_config
+from weaviate_tpu.index import plan as plan_mod
 from weaviate_tpu.index import tpu
 from weaviate_tpu.index.mesh import MeshVectorIndex
 from weaviate_tpu.index.plan import plan_search
@@ -31,7 +32,12 @@ IVF = IvfConfig(enabled=True, nlist=8, min_n=256, top_p=2,
 
 
 @pytest.fixture(autouse=True)
-def _reset_globals():
+def _reset_globals(monkeypatch):
+    # 16 queries' partitions hold more rows than these tiny stores: the
+    # choice by bytes (tests/test_ivf_tiles.py) is set aside, so a state
+    # with a layout plans its probed program
+    monkeypatch.setattr(plan_mod, "PROBED_ROW_COST", 0.0)
+    monkeypatch.setattr(plan_mod, "GATHERED_ROW_COST", 0.0)
     yield
     tpu.set_ivf_config(None)
     tracing.configure(None)
@@ -75,7 +81,8 @@ def _mesh(path, n=400, pq=None):
 def _ivf_one_chip(path):
     tpu.set_ivf_config(IVF)
     idx, vecs = _one_chip(path, n=2000)
-    assert idx._read_snapshot()[0].ivf_buckets is not None
+    # an uncompressed layout is the tiled one: no bucket table rides it
+    assert idx._read_snapshot()[0].ivf_tiled
     return idx, vecs
 
 

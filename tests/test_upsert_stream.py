@@ -404,17 +404,50 @@ def test_a_compressed_index_refuses_reuse_and_says_so(tmp_path):
     np.testing.assert_array_equal(ids[:, 0].astype(np.int64), doc_of[:8])
 
 
-def test_an_ivf_layout_refuses_reuse_and_says_so(tmp_path):
+def test_an_ivf_bucket_table_refuses_reuse_and_says_so(tmp_path):
+    """The bucket table (here: the PCA prefilter's layout) names the
+    partition of a slot's OLD row, so its index keeps appending."""
+    token = tpu.set_ivf_config(IvfConfig(
+        enabled=True, nlist=8, min_n=256, top_p=8, train_sample=4096,
+        train_iters=4, pca_dim=8))
+    try:
+        idx, vecs = _index_with(tmp_path, 600)
+        assert idx.health()["ivf"]["trained"] and not idx._ivf_tiled
+        doc_of = _re_put_at_index(idx, vecs, 4)
+        h = idx.health()
+        assert h["slot_reuse_refused"] == "ivf_layout"
+        assert h["writes"]["slots_reused"] == 0 and h["slots"] == 800
+        ids, _ = idx.search_by_vectors(vecs[:8], 1)
+        np.testing.assert_array_equal(ids[:, 0].astype(np.int64), doc_of[:8])
+    finally:
+        tpu.unset_ivf_config(token)
+
+
+def test_a_tiled_ivf_layout_puts_a_re_put_into_its_partitions_free_slots(
+        tmp_path):
+    """The tiled layout hands a row a free slot of ITS partition's tile: a
+    re-put of an unchanged vector lands where its old version lay (that
+    slot is freed first), and the slots stay the tiles' whatever is
+    written."""
     token = tpu.set_ivf_config(IvfConfig(
         enabled=True, nlist=8, min_n=256, top_p=8, train_sample=4096,
         train_iters=4))
     try:
         idx, vecs = _index_with(tmp_path, 600)
-        assert idx.health()["ivf"]["trained"]
+        assert idx.health()["ivf"]["layout"] == "tiles"
+        slots0, gen0 = idx.health()["slots"], idx._ivf_gen
+        parts0 = {d: s // idx._ivf_cap_p
+                  for d, s in idx._doc_to_slot.items()}
         doc_of = _re_put_at_index(idx, vecs, 4)
         h = idx.health()
-        assert h["slot_reuse_refused"] == "ivf_layout"
-        assert h["writes"]["slots_reused"] == 0 and h["slots"] == 800
+        assert h["slot_reuse_refused"] is None and idx._ivf_gen == gen0
+        assert h["writes"]["slots_reused"] == 200
+        assert h["slots"] == slots0 and h["live"] == 600
+        assert h["free_slots"] == slots0 - 600 == h["tombstones"]
+        # every re-put row lies in the partition its first version lay in
+        for row in range(50):
+            assert idx._doc_to_slot[int(doc_of[row])] // idx._ivf_cap_p \
+                == parts0[row]
         ids, _ = idx.search_by_vectors(vecs[:8], 1)
         np.testing.assert_array_equal(ids[:, 0].astype(np.int64), doc_of[:8])
     finally:

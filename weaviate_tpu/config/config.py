@@ -43,6 +43,12 @@ RESCORE_R_BUCKETS = (32, 48, 64, 96, 128)
 # layout a 2.7x jump)
 IVF_TOP_P_BUCKETS = (1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48, 64, 96, 128,
                      192, 256, 384, 512, 768, 1024, 1536, 2048, 3072, 4096)
+# The trained layout beside a shard's vector log (index/tpu.py
+# `_ivf_persist` / `_ivf_load`): what a restart reads instead of training.
+# Named here, where no JAX is imported, so that a client can ask whether
+# this program keeps a layout durable at all (benchmarks/datasets/
+# buckets_durable_layout.py).
+IVF_LAYOUT_FILE = "ivf.npz"
 
 # The ONE table of 4-bit funnel stage-C buckets (the pq.bits=4 three-stage
 # re-ranking funnel's FIRST budget: how many 4-bit ADC scan survivors reach

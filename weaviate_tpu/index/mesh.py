@@ -1324,7 +1324,7 @@ class MeshVectorIndex(VectorIndex):
             depth_rows=snap.n_loc,
             ivf_meta=snap.ivf_meta[:2] if ivf else None,
             ivf_probe=functools.partial(ivf_probe, self.metric, snap) if ivf
-            else None)
+            else None, ivf_gathered=ivf)
 
     def dispatch_tier(self, snap: MeshSnapshot,
                       allow_list: Optional[AllowList] = None,
@@ -1375,6 +1375,8 @@ class MeshVectorIndex(VectorIndex):
                 self, "index.mesh.finalize", plan,
                 None if enqueue is None
                 else plan.shape(enqueue.start_ns / 1e9))
+            if plan.ivf_declined:
+                self.scan_programs.declined_probe()
             packed_dev = None
             if plan.tier == TIER_PQ_ADC4:
                 # the 4-bit rung: per-chip three-stage funnel (nibble scan

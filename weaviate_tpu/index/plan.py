@@ -39,6 +39,35 @@ MIN_KERNEL_SLAB = 16384
 # kernels a dispatch may find refused inside `guarded_kernel_call`
 KERNEL_GMIN, KERNEL_FUNNEL = "gmin", "funnel"
 
+# Store rows a full-store program streams in the time a probed program
+# reads ONE row of a probed partition. A probed program reads every query's
+# partitions for that query alone, the full-store programs read the store
+# once for the whole batch: `probed_reads_less` is the one place the two
+# are weighed, and a layout pays by how it is read. On a v5e, 768-d
+# float32, 4,096 partitions (`PERF.md` section 6, PR 43):
+PROBED_ROW_COST = 2.8
+# ... the tiled layout, whole tiles read in place (`ops/ivf.py
+# ivf_tiles_topk`), timed alone over 352-slot tiles: 4.22 ms for 16 queries
+# of 64 tiles (11.7 ns a row) where the flat program takes 6.15 ms for its
+# 1.44M slots (4.3 ns a row): 2.75. One query wins 25 to 1, 16 win 1.5 to
+# 1, and 64 lose 4 to 1 (26.4 ms: a batch's tiles are a gather).
+GATHERED_ROW_COST = 7.0
+# ... a bucket table, whose probe gathers its rows by slot (the compressed
+# tiers, the PCA prefilter, the mesh): the parent's served program, 0.757 ms
+# a query of 64 buckets of 384 slots (30.8 ns a row) against the flat
+# scan's 4.45 ms for 1M rows. (One XLA gather of whole 352-row tiles takes
+# 13.7 ms: that is not how a bucket table reads.)
+
+
+def probed_reads_less(b_padded: int, ndev: int, top_p: int, cap_p: int,
+                      nlist: int, n: int, gathered: bool = False) -> bool:
+    """Is the partition-pruned program the shorter one against a full-store
+    scan of `n` rows, for a dispatch of `b_padded` queries that each probe
+    `top_p` partitions of `cap_p` slots a chip? Rows stand for bytes (both
+    programs read the same operand), weighed by how the layout is read."""
+    cost = GATHERED_ROW_COST if gathered else PROBED_ROW_COST
+    return (cost * b_padded * ndev * top_p * cap_p + nlist) < n
+
 
 def rescore_depth(config, metric: str, k: int, n: int) -> int:
     """Fast-scan candidate depth R of a scan over a slab of n rows (the
@@ -137,6 +166,9 @@ class PlanView:
     # or None, where the partition-pruned plane can serve this state
     ivf_meta: Optional[tuple] = None
     ivf_probe: Optional[Callable] = None
+    # the layout is a bucket table whose probe gathers rows by slot, not
+    # tiles of the store read in place
+    ivf_gathered: bool = False
 
 
 @dataclasses.dataclass(frozen=True, slots=True)
@@ -157,6 +189,9 @@ class SearchPlan:
     gmin: Optional[tuple] = None      # (rg, active_g) of the kernel
     rescore_r: Optional[int] = None   # the scan step's depth, where planned
     ivf: Optional[tuple] = None       # (top_p, prefilter_c)
+    # the state has a partition layout and a full-store program was taken
+    # because it reads less (`probed_reads_less`); the dispatcher counts it
+    ivf_declined: bool = False
     funnel: Optional[tuple] = None    # (rg4, rc)
     extra: Optional[dict] = None
     refused: frozenset = frozenset()
@@ -253,6 +288,13 @@ def plan_search(view: PlanView, b: int, b_padded: int, k: int,
     funnel_k, funnel_rows = min(kk, view.live), view.slab
     if view.ivf_probe is not None:
         probe = view.ivf_probe(k)
+    if probe is not None and not probed_reads_less(
+            b_padded, view.ndev, probe[0], view.ivf_meta[1],
+            view.ivf_meta[0], view.n, view.ivf_gathered):
+        # a wide batch: the store read once for all of it is fewer bytes
+        # than every query's own partitions
+        probe = None
+        common["ivf_declined"] = True
     if probe is not None:
         # partition-pruned (ROADMAP item 3): `rows` is what the device
         # actually reads (top_p x cap_p candidates a chip, padding included,
@@ -263,6 +305,13 @@ def plan_search(view: PlanView, b: int, b_padded: int, k: int,
         funnel_k = min(kk, funnel_rows)
         rows = view.ndev * funnel_rows + nlist
         extra = {"ivf": True, "ivf_top_p": probe[0], "ivf_nlist": nlist,
+                 "ivf_cap_p": cap_p,
+                 # what the program reads, every query's own partitions,
+                 # against the live rows a flat scan would have to cover
+                 "ivf_rows_read": b_padded * view.ndev * funnel_rows + nlist,
+                 "ivf_base_rows": view.live,
+                 "ivf_padding_share": round(
+                     1.0 - view.live / max(view.ndev * nlist * cap_p, 1), 4),
                  "probed_fraction": round(
                      min(rows / max(view.n, 1), 1.0), 4)}
         common["ivf"] = probe

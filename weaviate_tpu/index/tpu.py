@@ -51,7 +51,7 @@ import os
 import struct
 import threading
 import time
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -101,7 +101,8 @@ from weaviate_tpu.serving import controller
 from weaviate_tpu.testing import faults, sanitizers
 # the one rescore-candidate bucket table (shared with the control plane's
 # recall-guarded cap — serving/controller.py R_BUCKETS aliases it)
-from weaviate_tpu.config.config import (IVF_TOP_P_BUCKETS, IvfConfig,
+from weaviate_tpu.config.config import (IVF_LAYOUT_FILE, IVF_TOP_P_BUCKETS,
+                                        IvfConfig,
                                         PQ4_FUNNEL_C_BUCKETS,
                                         PQ4_FUNNEL_RESCORE_BUCKETS,
                                         RESCORE_R_BUCKETS, ivf_from_env)
@@ -216,6 +217,19 @@ def _bucket_b(b: int) -> int:
     return ((b + 1023) // 1024) * 1024
 
 
+def _fit_capacity(needed: int) -> int:
+    """The least capacity a slab may have that holds `needed` slots: a
+    power of two up to one scan chunk, whole scan chunks past it (what
+    `scan_topk` divides by). The tiled layout's capacity: its slots are
+    nlist * cap_p whatever the growth ladder's next rung would be."""
+    cap = _MIN_CAPACITY
+    while cap < needed and cap < _SCAN_CHUNK:
+        cap *= 2
+    if cap < needed:
+        cap = -(-needed // _SCAN_CHUNK) * _SCAN_CHUNK
+    return cap
+
+
 def _bucket_rows(n: int) -> int:
     """Pad gather row counts to pow2-ish buckets (min 128 for lane alignment)."""
     b = 128
@@ -316,6 +330,15 @@ def _scatter_rows(arr, idx, rows):
     sentinel, mode="drop" ignores the padding. Non-donating like every
     write kernel — snapshots may pin the previous generation."""
     return arr.at[idx].set(rows, mode="drop")
+
+
+@functools.partial(jax.jit, static_argnames=("fill",))
+def _relayout(arr, src, fill=0):
+    """A per-slot device array in a NEW slot order: slot i of the result is
+    slot `src[i]` of `arr`, `fill` where `src[i]` lies past it (an empty
+    slot of the tiled layout). One gather of whole rows into an array of
+    `len(src)` slots; `arr` stays valid (snapshots may pin it)."""
+    return jnp.take(arr, src, axis=0, mode="fill", fill_value=fill)
 
 
 @jax.jit
@@ -864,7 +887,8 @@ def restore_record(mode: str, rows: int, st, sums, replay_stats: dict,
         "rows": rows,
         "seconds": round(st.seconds, 3),
         "stages": {part: sums.seconds(*perf.STARTUP_PARTS[part])
-                   for part in ("log", "land", "drain")},
+                   for part in ("log", "land", "drain")
+                   + (("ivf",) if "ivf" in sums.sums else ())},
         "replay": dict(replay_stats),
         **extra,
     }
@@ -1386,6 +1410,19 @@ def _live_runs(events, stats: dict):
     return out
 
 
+class PersistedLayout(NamedTuple):
+    """`ivf.npz` as a restore wants it (`TpuVectorIndex._ivf_load`): the
+    docs sorted, `slots` the slot the layout recorded for each."""
+
+    centroids: np.ndarray     # [nlist, D] f32
+    cap_p: int
+    gen: int
+    trained_n: int
+    sized_n: int              # rows the tiles were sized for
+    docs: np.ndarray          # [rows] int64, ascending
+    slots: np.ndarray         # [rows] int64
+
+
 class ArrayLease:
     """What the snapshots published between two donations have in common:
     device arrays that may be the same objects from one snapshot to the
@@ -1414,7 +1451,7 @@ def ivf_probe(metric: str, snap, k: int) -> Optional[tuple[int, int]]:
     bounded IVF_TOP_P_BUCKETS ladder (or to nlist exactly when the
     request covers every partition), so top_p — a jit static — can
     only take bounded values."""
-    if snap.ivf_buckets is None:
+    if snap.ivf_meta is None:
         return None
     s = ivf_settings()
     if s is None:
@@ -1489,7 +1526,7 @@ class IndexSnapshot:
                  "rescore_dev", "rescore_sq_norms", "host_vecs",
                  "pq4", "codes4", "recon_norms4", "opq_rot",
                  "ivf_centroids", "ivf_buckets", "ivf_pca_proj",
-                 "ivf_pca_rows", "ivf_meta", "docs_ascending",
+                 "ivf_pca_rows", "ivf_meta", "ivf_tiled", "docs_ascending",
                  "doc_order", "s2d_cols", "lease")
 
     def __init__(self, gen: int, idx: "TpuVectorIndex"):
@@ -1541,6 +1578,9 @@ class IndexSnapshot:
         self.ivf_pca_rows = idx._ivf_pca_rows
         # (nlist, cap_p, recluster_gen) — host ints, frozen at publish
         self.ivf_meta = idx._ivf_meta
+        # the tiled layout: `store` itself is in partition order and there
+        # is no bucket table (partition p's tile starts at slot p * cap_p)
+        self.ivf_tiled = idx._ivf_tiled
 
 
 class TpuVectorIndex(VectorIndex):
@@ -1746,6 +1786,32 @@ class TpuVectorIndex(VectorIndex):
         self._ivf_trained_n = 0
         self._ivf_gen = 0            # recluster generation (health)
         self._ivf_dirty = False      # buckets stale vs assignments
+        # THE TILED LAYOUT (ops/ivf.py "the tiled layout"; docs/ivf.md):
+        # an uncompressed index without the PCA prefilter keeps its one
+        # copy of the rows in partition order, partition p in the slots
+        # [p * cap_p, (p + 1) * cap_p). No bucket table, no assignment
+        # mirror: a slot's partition is `slot // cap_p`, a free slot is a
+        # tombstoned one (`_host_tombs`), `n` is nlist * cap_p, and
+        # `_ivf_free_n` [nlist] counts each tile's free slots. The bucket
+        # table above stays the layout of a compressed index and of one
+        # with the prefilter, whose programs gather by slot.
+        self._ivf_tiled = False
+        self._ivf_free_n: Optional[np.ndarray] = None
+        # the layout beside the vector log (`_ivf_persist`): what a restart
+        # reads instead of training, and whether memory has moved past it
+        self._ivf_path = os.path.join(shard_path, IVF_LAYOUT_FILE)
+        self._ivf_unsaved = False
+        # a restore's persisted layout until its first row arrives
+        self._pending_ivf: Optional[PersistedLayout] = None
+        # doc -> slot of the persisted layout while a restore replays
+        self._ivf_restore_map: Optional[tuple] = None
+        self._ivf_restore_stats = {"placed": 0, "assigned": 0}
+        # the settings the layout was trained under: what a write that
+        # finds it full lays it out anew with while the plane is off
+        self._ivf_settings: Optional[IvfConfig] = None
+        self._ivf_trains = 0         # layouts this process made
+        self._no_filter_words = None  # `_dispatch_ivf`'s zero filter words
+        self._ivf_sized_n = 0        # rows the tiles were sized for
         # probe-accounting counters (health / bench probed_fraction),
         # updated per IVF dispatch under a leaf lock (lock_hierarchy
         # level 45 — nothing ever nests inside it)
@@ -1828,6 +1894,9 @@ class TpuVectorIndex(VectorIndex):
         try:
             self._pending_pq = self._load_persisted_pq()
             self._recorded = self._recorded_capacity()
+            if ivf_settings() is not None:
+                with tracing.piece_of(sums, "ivf", self.capacity):
+                    self._pending_ivf = self._ivf_load()
             sums.enter("stage")
             events = VectorLog.replay_batches(
                 self._log.path, stats=replay_stats,
@@ -1847,6 +1916,14 @@ class TpuVectorIndex(VectorIndex):
                     self._stage_delete(int(ids), log=False)
             if "records" not in log_stats:
                 self._log.records = log_stats["records"] = records
+            if ivf_settings() is not None and not self.compressed \
+                    and self.dim is not None:
+                # the layout's half of the restore: the rows still staged
+                # land, and a shard without a layout that covers them
+                # trains one here, on the device (`_ivf_end_restore`)
+                with tracing.piece_of(sums, "ivf", self.capacity):
+                    self._flush_pending()
+                    self._ivf_end_restore()
             sums.leave(self.capacity)
             VectorLog.report_replay_stats(self._log.path, replay_stats)
             if os.path.exists(self._pq_path):
@@ -1862,6 +1939,8 @@ class TpuVectorIndex(VectorIndex):
         finally:
             self._restoring = False
             self._pending_pq = None
+            self._pending_ivf = None
+            self._ivf_restore_map = None
             self._recorded = 0
 
     def _live_events(self, events, stats: dict):
@@ -1944,6 +2023,12 @@ class TpuVectorIndex(VectorIndex):
         self.dim = dim
         self.capacity = _MIN_CAPACITY
         dev = self.device
+        layout, self._pending_ivf = self._pending_ivf, None
+        if layout is not None and layout.centroids.shape[1] != dim:
+            layout = None   # another width's layout: the rows train anew
+        if layout is not None:
+            self.capacity = _fit_capacity(
+                layout.centroids.shape[0] * layout.cap_p)
         held, self._pending_pq = self._pending_pq, None
         if held is not None and held[0].dim != dim:
             e = ValueError(f"codebook of {held[0].dim} dims, rows of {dim}")
@@ -1963,6 +2048,8 @@ class TpuVectorIndex(VectorIndex):
         self._s2d_dev = jax.device_put(
             jnp.full((self.capacity, 2), _S2D_FILL, jnp.uint32), dev)
         self._host_tombs = np.zeros(self.capacity, dtype=bool)
+        if layout is not None:
+            self._ivf_adopt(layout)
         self._stamp_memory()
 
     def _ensure_capacity(self, needed: int) -> None:
@@ -2149,7 +2236,8 @@ class TpuVectorIndex(VectorIndex):
         caller holds the lock; where this returns True for arrays the
         published snapshot held, that snapshot has been retired and the
         write must end in `_publish_snapshot`, as every write does."""
-        if self.compressed or self._ivf_centroids_host is not None:
+        if self.compressed or (self._ivf_centroids_host is not None
+                               and not self._ivf_tiled):
             return False
         snap = self._snap
         if snap is None or snap.lease.retired:
@@ -2370,6 +2458,12 @@ class TpuVectorIndex(VectorIndex):
         count = len(ids64)
         self._staged_gen += 1
         self._mark_staged()
+        if self._ivf_tiled:
+            # a restore into a persisted layout: every row to the slot the
+            # layout recorded for its doc
+            self._place_rows_tiled(ids64, np.ascontiguousarray(vecs))
+            self.live += count
+            return
         self._ensure_capacity(self.n + count)
         self._cow_host_state()
         self._write_block(np.ascontiguousarray(vecs), self.n)
@@ -2478,12 +2572,14 @@ class TpuVectorIndex(VectorIndex):
     def _reuse_refused(self) -> Optional[str]:
         """Why this index never hands a dead slot to the next row (None: it
         does). The compressed branch lands rows through the codebook in
-        whole chunks at an offset, and an IVF layout's buckets name the
+        whole chunks at an offset, and an IVF bucket table names the
         partition of the slot's OLD row: both keep appending, tombstones
-        staying until `compact()`, and say so in `health()`."""
+        staying until `compact()`, and say so in `health()`. (The tiled
+        layout hands a row a free slot of ITS partition's tile:
+        `_place_rows_tiled`.)"""
         if self.compressed:
             return "compressed"
-        if self._ivf_centroids_host is not None:
+        if self._ivf_centroids_host is not None and not self._ivf_tiled:
             return "ivf_layout"
         return None
 
@@ -2514,6 +2610,9 @@ class TpuVectorIndex(VectorIndex):
         unchanged). What a published snapshot pins is never written: the
         device arrays are functional updates and the host mirrors are
         copied first where the snapshot shares them."""
+        if self._ivf_tiled:
+            self._place_rows_tiled(docs, rows)
+            return
         count = len(docs)
         dead, free = self._pending_tombs, self._free_slots
         take_dead = take_free = 0
@@ -2622,7 +2721,13 @@ class TpuVectorIndex(VectorIndex):
         if len(dead) == 0:
             return
         self._host_tombs[dead] = True
-        self._free_slots.extend(dead.tolist())
+        if self._ivf_tiled:
+            # a tile's free slots are its tombstoned ones: counted, not listed
+            self._ivf_free_n += np.bincount(
+                dead // self._ivf_cap_p, minlength=len(self._ivf_free_n))
+            self._ivf_unsaved = True
+        else:
+            self._free_slots.extend(dead.tolist())
         self._wstats["tombstones_applied"] += len(dead)
         self._note_write(tombstones_applied=len(dead))
         self._obs_index("delete", "apply_tombstones", t0, ops=len(dead))
@@ -2802,21 +2907,48 @@ class TpuVectorIndex(VectorIndex):
             return
         if self.metric not in ivf_ops.MATMUL_METRICS:
             return
-        if self.n < max(s.min_n, 256):
+        # the tiled layout's slots are nlist * cap_p whatever it holds: its
+        # size is its live rows
+        rows = self.live if self._ivf_tiled or self._tiles_next(s) else self.n
+        if rows < max(s.min_n, 256):
             return
         if self._ivf_centroids is not None and \
-                self.n < self._ivf_trained_n * (1.0 + s.retrain_growth):
+                rows < self._ivf_trained_n * (1.0 + s.retrain_growth):
+            # the centroids stand; a tiled layout whose live rows passed
+            # the rows its tiles were sized for gets larger tiles (same
+            # centroids, every row assigned and laid out again: seconds)
+            if self._ivf_tiled and \
+                    rows >= self._ivf_sized_n * ivf_ops.TILE_HEADROOM:
+                self._ivf_train_tiles(s, refit=False)
             return
         self._ivf_train_locked(s)
 
+    def _tiles_next(self, s: IvfConfig) -> bool:
+        """Does the next training lay the store out in tiles? An
+        uncompressed index without the PCA prefilter does; a compressed one
+        (its rows are codes, a bf16 copy and a host array, landed through
+        the codebook in whole chunks) and the prefilter (a second per-slot
+        table) keep the bucket table over slots in order of arrival."""
+        return not self.compressed and int(s.pca_dim) <= 0
+
     def _ivf_train_locked(self, s: IvfConfig) -> None:
-        """Train (or re-train) the clustered layout: k-means centroids,
-        full partition assignment, optional PCA basis + low-dim rows,
-        padded buckets — then a fresh snapshot publishes it. Runs under
-        the index write lock (callers hold it); a recluster replaces
-        every IVF array wholesale, so snapshots pinned by in-flight
-        dispatches keep their old layout (the COW discipline)."""
+        """Train (or re-train) the clustered layout, then a fresh snapshot
+        publishes it. Runs under the index write lock (callers hold it); a
+        recluster replaces every array it touches wholesale, so snapshots
+        pinned by in-flight dispatches keep their old layout (the COW
+        discipline). The tiled layout trains on the device
+        (`_ivf_train_tiles`), the bucket table on the host."""
+        if self._tiles_next(s):
+            self._ivf_train_tiles(s)
+        else:
+            self._ivf_train_buckets(s)
+
+    def _ivf_train_buckets(self, s: IvfConfig) -> None:
+        """The bucket table's training: k-means centroids, full partition
+        assignment, optional PCA basis + low-dim rows, padded buckets, on
+        the host from the rows it keeps (`host_vecs`) or fetches."""
         t0 = time.perf_counter()
+        self._ivf_trains += 1
         n = self.n
         rows = self._ivf_rows_for_training()
         nlist = self._ivf_nlist(s, n)
@@ -2954,6 +3086,325 @@ class TpuVectorIndex(VectorIndex):
         self._ivf_pending_slots = []
         self._ivf_trained_n = 0
         self._ivf_dirty = False
+        self._ivf_tiled = False
+        self._ivf_free_n = None
+        self._ivf_restore_map = None
+
+    # -- the tiled layout (ops/ivf.py; docs/ivf.md "The layout") -------------
+
+    def _ivf_train_tiles(self, s: IvfConfig, reserve: int = 0,
+                         refit: bool = True) -> bool:
+        """Train the layout and lay the store out in its order: the fit and
+        the assignment run on the device over the store in place (a sample
+        is gathered, the slab never copied to the host), the host balances
+        the partitions and gives every live row its slot, and ONE gather on
+        the device makes the store, its norms, tombstones and doc table in
+        the new order (`_relayout`). The arrays the published snapshot holds
+        stay valid until it goes, so for the length of this call two
+        generations of the slab are alive; where the device has no room for
+        the second, nothing is trained and the index serves flat (-> False).
+        `reserve`: rows about to land that the tiles must have room for.
+        `refit` False keeps the centroids a tiled layout has and only makes
+        its tiles anew (a regrow: `tile_capacity`'s headroom is used up).
+        The span `ivf.train` (`/debug/traces`, the capture log) and the
+        `write_phase` incident carry its pieces in ms."""
+        sw = tracing.Stopwatch("ivf.train", rows=self.live)
+        t0 = t = time.perf_counter()
+        ms: dict = {}
+
+        def lap(name):
+            nonlocal t
+            now = time.perf_counter()
+            ms[name] = round((now - t) * 1000.0, 1)
+            t = now
+
+        n_old, dim = self.n, self.dim
+        live_slots = np.flatnonzero(~self._host_tombs[:n_old])
+        rows = int(live_slots.size)
+        refit = refit or not self._ivf_tiled
+        nlist = (self._ivf_nlist(s, rows) if refit
+                 else self._ivf_centroids_host.shape[0])
+        cap_p = ivf_ops.tile_capacity(rows + reserve, nlist)
+        cap_new = _fit_capacity(nlist * cap_p)
+        if not self._copy_fits(cap_new * self._row_bytes()):
+            incidents.emit("write_phase", scope="ivf_recluster_skipped",
+                           rows=rows, nlist=nlist,
+                           needs_bytes=cap_new * self._row_bytes())
+            sw.stop()
+            return False
+        self._ivf_trains += 1
+        if refit:
+            rng = np.random.default_rng(self._ivf_gen)
+            sample = min(rows, max(s.train_sample, nlist * 16))
+            if sample > ivf_ops._DEVICE_BLOCK:   # whole blocks of the fit
+                sample -= sample % ivf_ops._DEVICE_BLOCK
+            picked = live_slots[np.sort(
+                rng.choice(rows, sample, replace=False))]
+            cent_dev = ivf_ops.kmeans_fit_device(
+                ivf_ops.gather_rows(self._store,
+                                    jnp.asarray(picked, jnp.int32)),
+                jnp.asarray(rng.choice(sample, nlist, replace=False),
+                            jnp.int32),
+                iters=int(s.train_iters),
+                normalize=self.metric == vi.DISTANCE_COSINE)
+            cent = np.asarray(cent_dev)
+            trained_n = rows
+        else:
+            cent_dev, cent = self._ivf_centroids, self._ivf_centroids_host
+            trained_n = self._ivf_trained_n
+        lap("fit")
+        prefs_dev, d0_dev = ivf_ops.nearest_partitions_device(  # graftflow: disable=JGL019 `prefs` is min(PREFS, nlist): eight values at the most, one a layout
+            self._store, cent_dev, prefs=min(ivf_ops.PREFS, nlist))
+        prefs = np.asarray(prefs_dev)[live_slots]
+        d0 = np.asarray(d0_dev)[live_slots]
+        lap("assign")
+        part = ivf_ops.balance_partitions(
+            prefs, d0, np.full(nlist, cap_p, np.int64))
+        new_slots = ivf_ops.tile_slots(part, d0, cap_p)
+        src = np.full(cap_new, self.capacity, np.int32)   # past the end: fill
+        src[new_slots] = live_slots
+        docs = np.full(cap_new, -1, np.int64)
+        docs[new_slots] = self._slot_to_doc[live_slots]
+        free = np.ones(cap_new, dtype=bool)
+        free[new_slots] = False
+        lap("layout")
+        src_dev = jnp.asarray(src)
+        store = _relayout(self._store, src_dev)
+        sq_norms = None if self._sq_norms is None else _relayout(
+            self._sq_norms, src_dev)
+        led = memory.get_ledger()
+        if led is not None:
+            led.note_cow(0, transient_peak=memory.array_bytes(store))
+        self._store, self._sq_norms = store, sq_norms
+        self._tombs = jax.device_put(jnp.asarray(free), self.device)
+        self._s2d_dev = jax.device_put(
+            jnp.asarray(_doc_pairs(docs, cap_new)), self.device)
+        self._row_store_cache = None
+        self._blk_cache.clear()
+        # the host mirrors, new objects: a snapshot keeps the old ones
+        self._slot_to_doc, self._host_tombs = docs, free
+        self._doc_to_slot = dict(zip(docs[new_slots].tolist(),
+                                     new_slots.tolist()))
+        self._free_slots = []
+        self._allow_token = object()
+        self._docs_ascending = False
+        self.n, self.capacity = nlist * cap_p, cap_new
+        self._record_capacity()
+        self._ivf_reset()   # whatever layout there was, of either kind
+        self._ivf_centroids, self._ivf_centroids_host = cent_dev, cent
+        self._ivf_cap_p = cap_p
+        self._ivf_free_n = cap_p - np.bincount(part, minlength=nlist)
+        self._ivf_tiled = True
+        self._ivf_trained_n, self._ivf_sized_n = trained_n, rows + reserve
+        self._ivf_gen += 1
+        self._ivf_meta = (nlist, cap_p, self._ivf_gen)
+        self._ivf_settings = s
+        lap("upload")
+        self._ivf_persist()
+        lap("persist")
+        self._staged_gen += 1
+        self._mark_staged()
+        self._stamp_memory()
+        total = (time.perf_counter() - t0) * 1000.0
+        sw.note(nlist=nlist, cap_p=cap_p, **{k + "_ms": v
+                                             for k, v in ms.items()})
+        sw.stop()
+        span = tracing.current_span()
+        if span is not None:   # a sampled write: the training and its pieces
+            done = span.child_done("ivf.train", total, {
+                "rows": rows, "nlist": nlist, "cap_p": cap_p})
+            for name, piece_ms in ms.items():
+                done.child_done("ivf.train." + name, piece_ms)
+        if led is not None:
+            led.note_write("ivf", "recluster", total, rows=rows)
+        incidents.emit("write_phase", scope="ivf_recluster", rows=rows,
+                       nlist=nlist, cap_p=cap_p, ms=round(total, 1), **ms)
+        return True
+
+    def _place_rows_tiled(self, docs: np.ndarray, rows: np.ndarray) -> None:
+        """`_place_rows` under the tiled layout: every row to a free slot of
+        its nearest partition's tile, assigned on the host from the rows
+        the write holds (`ivf_ops.nearest_partitions`). A row whose
+        partition is full takes the next of its `PREFS` nearest with room,
+        then the emptiest; a write the tiles have no room for lays the
+        store out anew first, with room for it. A restore that read a
+        persisted layout gives a doc the slot recorded for it and assigns
+        only the docs the layout does not know. The slots of the versions
+        this write replaces are freed first, so an upsert's row may take
+        its old slot."""
+        self._apply_pending_tombs()
+        count = len(docs)
+        cap_p = self._ivf_cap_p
+        nlist = self._ivf_centroids_host.shape[0]
+        docs = np.asarray(docs, np.int64)
+        slots = np.full(count, -1, np.int64)
+        free = self._host_tombs
+        known = self._ivf_restore_map
+        if known is not None:
+            with tracing.piece_of(self._restore_sums, "ivf", self.capacity):
+                keys, vals, reserved = known
+                if len(keys):
+                    at = np.minimum(np.searchsorted(keys, docs),
+                                    len(keys) - 1)
+                    found = np.flatnonzero(keys[at] == docs)
+                    want = vals[at[found]]
+                    ok = free[want]     # taken meanwhile: assigned anew
+                    slots[found[ok]] = want[ok]
+                    reserved[want[ok]] = False
+                    self._ivf_restore_stats["placed"] += int(ok.sum())
+                # free for the docs the layout does not know: neither
+                # reserved for a doc still to come nor just handed out
+                free = np.logical_and(free[: self.n], ~reserved[: self.n])
+                free[slots[slots >= 0]] = False
+        todo = np.flatnonzero(slots < 0)
+        if todo.size:
+            with tracing.piece_of(self._restore_sums, "ivf", self.capacity):
+                room = (free[: self.n].reshape(nlist, cap_p).sum(axis=1)
+                        if known is not None else self._ivf_free_n)
+                if int(room.sum()) < todo.size:
+                    # no tile has the room: a new layout that has it
+                    if known is not None:
+                        raise RuntimeError(
+                            "the persisted IVF layout has no room for the "
+                            "rows the log holds")
+                    if not self._ivf_train_tiles(
+                            ivf_settings() or self._ivf_settings,
+                            reserve=count, refit=False):
+                        raise RuntimeError(
+                            "the IVF layout is full and the device has no "
+                            "room for a larger one")
+                    self._place_rows_tiled(docs, rows)
+                    return
+                prefs, d0 = self._ivf_nearest(rows[todo])
+                part = ivf_ops.balance_partitions(prefs, d0, room)
+                slots[todo] = ivf_ops.place_in_tiles(part, free, cap_p)
+                if known is not None:
+                    self._ivf_restore_stats["assigned"] += int(todo.size)
+        self._ivf_free_n -= np.bincount(slots // cap_p, minlength=nlist)
+        self._cow_host_state(rewrites_slots=True)
+        # the slot layout under a cached filter changed without `n` moving
+        self._allow_token = object()
+        self._ivf_unsaved = True
+        self._wstats["slots_reused"] += count
+        self._note_write(slots_reused=count)
+        with tracing.piece_of(self._restore_sums, "land", self.capacity,
+                              rows=count):
+            self._write_small(docs, rows, slots, cleared=slots)
+
+    # rows of one write from which its assignment runs on the device: a
+    # [rows, nlist] product of 10,000 x 4,096 x 768 is seconds of host
+    # sgemm a put batch (minutes an import) and milliseconds there, while a
+    # batch of a hundred is 10 ms on the host and would wait on the device
+    # behind every queued search, under the write lock
+    _IVF_ASSIGN_ON_DEVICE = 2048
+
+    def _ivf_nearest(self, rows: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+        """The `PREFS` nearest partitions of rows about to land -> ([n,
+        prefs] ids nearest first, [n] distance to the nearest)."""
+        count = rows.shape[0]
+        if count < self._IVF_ASSIGN_ON_DEVICE:
+            return ivf_ops.nearest_partitions(rows, self._ivf_centroids_host)
+        prefs = min(ivf_ops.PREFS, self._ivf_centroids_host.shape[0])
+        out = np.empty((count, prefs), np.int32)
+        d0 = np.empty(count, np.float32)
+        for off in range(0, count, _CHUNK):
+            take = min(_CHUNK, count - off)
+            buf = np.zeros((_bucket_rows(take), rows.shape[1]), np.float32)
+            buf[:take] = rows[off: off + take]
+            ids, dist = ivf_ops.nearest_partitions_of_rows(  # graftflow: disable=JGL019 `prefs` is min(PREFS, nlist): eight values at the most, one a layout
+                jnp.asarray(buf), self._ivf_centroids, prefs=prefs)
+            out[off: off + take] = np.asarray(ids)[:take]
+            d0[off: off + take] = np.asarray(dist)[:take]
+        return out, d0
+
+    def _ivf_persist(self) -> None:
+        """The layout beside the vector log, whole or not at all: the
+        centroids, `cap_p`, the generation, the rows it was trained on and
+        the doc of every slot (the store's partition order). A restart
+        reads it before it replays (`_ivf_load`) and lands every row in the
+        slot it had: no training, no assignment."""
+        if self._log is None or not self._ivf_tiled:
+            return
+        nlist, cap_p, gen = self._ivf_meta
+        tmp = self._ivf_path + ".tmp"
+        with open(tmp, "wb") as f:
+            np.savez(f, centroids=self._ivf_centroids_host,
+                     meta=np.array([cap_p, gen, self._ivf_trained_n,
+                                    nlist * cap_p, self._ivf_sized_n],
+                                   np.int64),
+                     slot_to_doc=self._slot_to_doc[: nlist * cap_p])
+        os.replace(tmp, self._ivf_path)
+        self._ivf_unsaved = False
+
+    def _ivf_load(self) -> Optional[PersistedLayout]:
+        """The persisted layout of a shard that had one, as
+        `_pending_ivf` wants it; None where there is none, the plane is
+        off, the index is compressed, or the file cannot be used (the rows
+        then train anew at the end of the restore, never inside a search)."""
+        s = ivf_settings()
+        if s is None or not os.path.exists(self._ivf_path) \
+                or self._pending_pq is not None or not self._tiles_next(s):
+            return None
+        try:
+            with np.load(self._ivf_path) as z:
+                cent = np.ascontiguousarray(z["centroids"], np.float32)
+                cap_p, gen, trained_n, n_slots, sized_n = (
+                    int(v) for v in z["meta"])
+                docs = np.asarray(z["slot_to_doc"], np.int64)
+            if cent.ndim != 2 or docs.shape != (n_slots,) \
+                    or n_slots != cent.shape[0] * cap_p or cap_p % 32:
+                raise ValueError("layout of another shape than it states")
+        except Exception as e:  # noqa: BLE001 — an unusable layout is retrained
+            import logging
+
+            logging.getLogger(__name__).warning(
+                "persisted IVF layout rejected (%s: %s); training anew",
+                type(e).__name__, e)
+            return None
+        slots = np.flatnonzero(docs >= 0)
+        order = np.argsort(docs[slots], kind="stable")
+        return PersistedLayout(cent, cap_p, gen, trained_n, sized_n,
+                               docs[slots][order], slots[order])
+
+    def _ivf_adopt(self, layout: PersistedLayout) -> None:
+        """Enter a persisted layout with no row in it (`_init_device` made
+        the arrays at its capacity): every slot of every tile free, the
+        docs' recorded slots reserved for them."""
+        cent, cap_p, gen, trained_n, sized_n, keys, vals = layout
+        nlist = cent.shape[0]
+        self.n = nlist * cap_p
+        self._host_tombs[:] = True
+        self._tombs = jax.device_put(
+            jnp.ones((self.capacity,), jnp.bool_), self.device)
+        self._ivf_centroids_host = cent
+        self._ivf_centroids = jax.device_put(jnp.asarray(cent), self.device)
+        self._ivf_cap_p = cap_p
+        self._ivf_free_n = np.full(nlist, cap_p, np.int64)
+        self._ivf_tiled = True
+        self._ivf_gen = gen
+        self._ivf_trained_n, self._ivf_sized_n = trained_n, sized_n
+        self._ivf_meta = (nlist, cap_p, gen)
+        self._ivf_settings = ivf_settings()
+        self._docs_ascending = False
+        reserved = np.zeros(self.capacity, dtype=bool)
+        reserved[vals] = True
+        self._ivf_restore_map = (keys, vals, reserved)
+        self._stamp_memory()
+
+    def _ivf_end_restore(self) -> None:
+        """The layout's half of a restore's end: the persisted map goes,
+        and a shard that is large enough and has no layout (none persisted,
+        one rejected) or has outgrown the one it read is trained now, on
+        the device, so that no search ever is."""
+        self._ivf_restore_map = None
+        s = ivf_settings()
+        if s is None or self.dim is None or self.compressed:
+            return
+        self._restoring = False
+        try:
+            self._maybe_ivf_train()
+        finally:
+            self._restoring = True
 
     def ivf_stats(self) -> dict:
         """Cumulative probe accounting (bench probed_fraction rows and
@@ -3121,7 +3572,26 @@ class TpuVectorIndex(VectorIndex):
             rotation=self.config.pq.rotation,
         )
         vecs = np.asarray(self._store[: self.n], dtype=np.float32)
-        pq.fit(vecs, sample_max=self.config.pq.training_limit)
+        if self._ivf_tiled:
+            # the tiles' empty slots hold zero rows: fit on the live ones,
+            # and keep the layout as a bucket table over the same slots (a
+            # compressed index's rows are gathered by slot: `_tiles_next`)
+            live = ~self._host_tombs[: self.n]
+            pq.fit(vecs[live], sample_max=self.config.pq.training_limit)
+            self._ivf_assign = np.where(
+                live, np.arange(self.n) // self._ivf_cap_p, -1
+            ).astype(np.int32)
+            self._ivf_tiled = False
+            self._ivf_free_n = None
+            # a bucket table's size is its slots (`_maybe_ivf_train`)
+            self._ivf_trained_n = self.n
+            self._ivf_rebuild_buckets()
+            try:
+                os.remove(self._ivf_path)
+            except OSError:
+                pass
+        else:
+            pq.fit(vecs, sample_max=self.config.pq.training_limit)
         self._enable_pq(pq, vecs, save=True)
 
     def _fit_pq4(self, pq, vecs_n: np.ndarray):
@@ -3702,6 +4172,8 @@ class TpuVectorIndex(VectorIndex):
                 self, "index.tpu.finalize", plan,
                 None if enqueue is None
                 else plan.shape(enqueue.start_ns / 1e9))
+            if plan.ivf_declined:
+                self.scan_programs.declined_probe()
             if plan.tier == costmodel.TIER_GATHER:
                 fin = self._dispatch_small_allow(
                     snap, q, b, plan.k_eff, allow_list, handle.shape)
@@ -3744,7 +4216,7 @@ class TpuVectorIndex(VectorIndex):
                        and snap.rescore_dev is not None)
         funnel = (pq and snap.codes4 is not None and snap.pq4 is not None
                   and self.metric in ivf_ops.MATMUL_METRICS)
-        ivf = snap.ivf_buckets is not None
+        ivf = snap.ivf_meta is not None
         return PlanView(
             config=self.config, metric=self.metric,
             programs=self.scan_programs, kernels=self,
@@ -3760,7 +4232,7 @@ class TpuVectorIndex(VectorIndex):
                                    if rescore else 0),
             ivf_meta=snap.ivf_meta[:2] if ivf else None,
             ivf_probe=functools.partial(self._ivf_plan, snap) if ivf
-            else None)
+            else None, ivf_gathered=ivf and not snap.ivf_tiled)
 
     def dispatch_tier(self, snap: IndexSnapshot, allow_list=None,
                       b: int = 1, k: int = 1) -> str:
@@ -3793,8 +4265,16 @@ class TpuVectorIndex(VectorIndex):
         allow_words = (self._allow_words(snap, allow_list)
                        if allow_list is not None else None)
         use_allow = allow_words is not None
-        words = (allow_words if use_allow
-                 else jnp.zeros((snap.capacity // 32,), jnp.uint32))
+        if use_allow:
+            words = allow_words
+        else:
+            # the unread filter operand, made once a capacity: at a
+            # thousand single queries a second a fresh device array a
+            # dispatch is a launch of its own under the callers' one GIL
+            words = self._no_filter_words
+            if words is None or words.shape[0] != snap.capacity // 32:
+                words = self._no_filter_words = jnp.zeros(
+                    (snap.capacity // 32,), jnp.uint32)
         exact = getattr(self.config, "exact_topk", False)
         kk = min(max(plan.k_eff, 1), top_p * cap_p)
         gp = ivf_ops.group_steps(q.shape[0], cap_p, snap.dim, top_p)
@@ -3834,17 +4314,21 @@ class TpuVectorIndex(VectorIndex):
                      snap.ivf_buckets, snap.opq_rot, snap.rescore_dev)
             packed_dev = pq4_ops.search_ivf_pq4_fused(
                 *args4, snap.slot_to_doc_dev, *statics4)
-            with self._ivf_lock:
-                st = self._ivf_stats
-                st["dispatches"] += 1
-                st["probed_rows"] += top_p * cap_p
-                st["base_rows"] += int(snap.n)
+            self._note_probe(snap, top_p * cap_p)
             with self._pq4_lock:
                 st = self._pq4_stats
                 st["dispatches"] += 1
                 st["stage1_rows"] += r_cand
                 st["stage2_survivors"] += min(c1, r_cand)
                 st["stage3_survivors"] += min(rc, r_cand)
+            return self._finalize_fused(packed_dev, shape, b)
+        if snap.ivf_tiled:
+            # the store is in partition order: whole tiles, read in place
+            packed_dev = ivf_ops.search_ivf_tiles_fused(
+                snap.store, snap.tombs, jnp.asarray(q), words,
+                snap.ivf_centroids, snap.slot_to_doc_dev, kk, self.metric,
+                use_allow, top_p, cap_p)
+            self._note_probe(snap, top_p * cap_p)
             return self._finalize_fused(packed_dev, shape, b)
         statics = (kk, self.metric, use_allow, top_p, pre_c, exact, gp,
                    steps2)
@@ -3863,14 +4347,17 @@ class TpuVectorIndex(VectorIndex):
                     snap.pq.rotation_dev())
             packed_dev = ivf_ops.search_ivf_codes_fused(
                 *args, snap.slot_to_doc_dev, *statics)
-        # probe accounting (health / bench probed_fraction): a leaf lock,
-        # three integer adds — nothing nests inside it
+        self._note_probe(snap, top_p * cap_p)
+        return self._finalize_fused(packed_dev, shape, b)
+
+    def _note_probe(self, snap: IndexSnapshot, probed: int) -> None:
+        """Probe accounting (health / bench probed_fraction): a leaf lock,
+        three integer adds — nothing nests inside it."""
         with self._ivf_lock:
             st = self._ivf_stats
             st["dispatches"] += 1
-            st["probed_rows"] += top_p * cap_p
+            st["probed_rows"] += probed
             st["base_rows"] += int(snap.n)
-        return self._finalize_fused(packed_dev, shape, b)
 
     def _dispatch_scan(self, snap: IndexSnapshot, q: np.ndarray, b: int,
                        allow_words, handle: DispatchHandle, store=None,
@@ -4415,6 +4902,14 @@ class TpuVectorIndex(VectorIndex):
                         if self._ivf_pca_host is not None else 0),
         })
         fills = self._ivf_fills
+        free_n = self._ivf_free_n
+        if self._ivf_tiled and free_n is not None:
+            # a tile's fill is what its free slots leave of it; what a
+            # restore found recorded for its docs, and what it assigned
+            fills = cap_p - free_n
+            out["layout"] = "tiles"
+            out["restore"] = dict(self._ivf_restore_stats)
+            out["trainings"] = self._ivf_trains
         if fills is not None and fills.size and cap_p:
             total = int(fills.sum())
             mean = total / max(int(nlist), 1)
@@ -4476,7 +4971,9 @@ class TpuVectorIndex(VectorIndex):
             "tombstone_fraction": round(tombs / n, 4) if n > 0 else 0.0,
             # tombstoned slots the next rows to land take before `slots`
             # grows, and why this index hands none out (None: it does)
-            "free_slots": len(self._free_slots),
+            "free_slots": (int(self._ivf_free_n.sum())
+                           if self._ivf_tiled and self._ivf_free_n is not None
+                           else len(self._free_slots)),
             "slot_reuse_refused": self._reuse_refused(),
             "writes": dict(self._wstats),
             "log": self._log_health(),
@@ -4882,6 +5379,8 @@ class TpuVectorIndex(VectorIndex):
             self._flush_pending()
             if self._log is not None:
                 self._log.flush()
+                if self._ivf_unsaved:
+                    self._ivf_persist()
 
     def compact(self) -> None:
         """Condense: drop tombstoned slots, rewrite log (condensor.go analog).
@@ -5017,7 +5516,8 @@ class TpuVectorIndex(VectorIndex):
             self._host_vecs = None
             self._staged_gen += 1
             self._publish_snapshot()
-            for path in (self._pq_path, self._pq4_path, self._capacity_path):
+            for path in (self._pq_path, self._pq4_path, self._capacity_path,
+                         self._ivf_path):
                 try:
                     os.remove(path)
                 except FileNotFoundError:
@@ -5028,11 +5528,16 @@ class TpuVectorIndex(VectorIndex):
             self._flush_pending()
             if self._log is not None:
                 self._log.flush()
+                if self._ivf_unsaved:
+                    # the slots the rows written since the last training
+                    # took: a restart lands them there again
+                    self._ivf_persist()
                 self._log.close()
 
     def list_files(self) -> list[str]:
         files = [self._log.path] if self._log is not None else []
-        for path in (self._pq_path, self._pq4_path, self._capacity_path):
+        for path in (self._pq_path, self._pq4_path, self._capacity_path,
+                     self._ivf_path):
             if os.path.exists(path):
                 files.append(path)
         return files

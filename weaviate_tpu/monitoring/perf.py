@@ -211,7 +211,8 @@ class PerfWindow:
         self._lock = threading.Lock()
         # (t_end_mono, tier, rows, one-fetch invariant violated, store
         # rows the dispatch read: DispatchShape.n, the depth its scan step
-        # ran at where the shape carries one: extra["rescore_r"])
+        # ran at where the shape carries one: extra["rescore_r"], the
+        # shape's extra where the dispatch was a probed one, else None)
         self._entries: deque = deque()
         # phase name -> deque[(t_mono, ms)], count-capped (see
         # _PHASE_SAMPLES_MAX) on top of the time-horizon eviction
@@ -285,9 +286,10 @@ class PerfWindow:
         nrows = int(rows) or shape.batch
         with self._lock:
             self._evict(now)
+            extra = shape.extra or {}
             self._entries.append((now, shape.tier, nrows, viol,
-                                  int(shape.n),
-                                  (shape.extra or {}).get("rescore_r")))
+                                  int(shape.n), extra.get("rescore_r"),
+                                  extra if extra.get("ivf") else None))
             self._rows += nrows
             self._total_dispatches += 1
             if self._first_entry is None:
@@ -563,12 +565,15 @@ class PerfWindow:
             tier_rows: dict[str, int] = {}
             violations = 0
             depths: dict[str, int] = {}
-            for _, tier, _, viol, read, depth in self._entries:
+            probed: list = []
+            for _, tier, _, viol, read, depth, ivf in self._entries:
                 tiers[tier] = tiers.get(tier, 0) + 1
                 tier_rows[tier] = tier_rows.get(tier, 0) + read
                 violations += viol
                 if depth is not None:
                     depths[str(depth)] = depths.get(str(depth), 0) + 1
+                if ivf is not None:
+                    probed.append(ivf)
             total_dispatches = self._total_dispatches
             point_get = [sum(c) for c in list(zip(*self._point_get))[1:]]
             rescore = [sum(c) for c in list(zip(*self._rescore))[1:]]
@@ -701,6 +706,21 @@ class PerfWindow:
             # dispatches by the depth R their scan step ran at, where the
             # shape says (the mesh's exact tier: "0" is the HIGHEST scan)
             out["rescore_r"] = depths
+        if probed:
+            # the partition-pruned dispatches of the window (index/plan.py):
+            # `probed_rows` the store rows their programs read (every
+            # query's top_p tiles, padding included, and the centroids),
+            # `base_rows` the live rows a flat scan of the same dispatches
+            # would have had to cover; the layout as the newest of them
+            # saw it, `padding_share` the part of its slots that hold no row
+            last = probed[-1]
+            out["ivf"] = {
+                "dispatches": len(probed),
+                "probed_rows": sum(e["ivf_rows_read"] for e in probed),
+                "base_rows": sum(e["ivf_base_rows"] for e in probed),
+                "top_p": last["ivf_top_p"], "nlist": last["ivf_nlist"],
+                "cap_p": last["ivf_cap_p"],
+                "padding_share": last["ivf_padding_share"]}
         # invariant violations over the window
         # (costmodel.fused_invariant_ok): every dispatch translates on the
         # device, so `dispatches` is all of them; violations > 0 means a
@@ -731,6 +751,11 @@ STARTUP_PARTS = {
     "log": ("log.check", "log.read", "log.parse"),
     "land": ("stage", "grow", "land", "flush"),
     "drain": ("drain",),
+    # the partition-pruned tier's half of a restore (index/tpu.py): reading
+    # the persisted layout, finding each replayed doc's slot in it,
+    # assigning the docs it does not know and, where no layout covers the
+    # rows, training one
+    "ivf": ("ivf",),
 }
 
 # the jax.monitoring keys the tally listens to (jax 0.9: _src/dispatch.py
@@ -938,9 +963,12 @@ class Timeline:
         summed = sum(e - s for s, e in opens)
         parallel = len(tids) > 1 and union < summed
         cut = union / summed if parallel else 1.0
+        # `ivf` is a part only of a restart that ran the stage: a process
+        # without the partition-pruned tier keeps the page it always had
         parts = {k: int(sum(tot.get(n, 0) for n in names)
                         * (1.0 if k == "boot" else cut))
-                 for k, names in STARTUP_PARTS.items()}
+                 for k, names in STARTUP_PARTS.items()
+                 if k != "ivf" or "ivf" in tot}
         in_app = sum(v for k, v in parts.items() if k != "boot")
         parts["other"] = sum(tot.get(n, 0) for n in
                              ("app", "post_startup", "listen")) - in_app

@@ -143,13 +143,17 @@ class ProgramCounts:
     `kernel_serves` declined. Plain integers behind a leaf lock (four
     callers dispatch at once), kept whether or not the tracer is up."""
 
-    __slots__ = ("_lock", "gmin", "scan", "declined_slower")
+    __slots__ = ("_lock", "gmin", "scan", "declined_slower", "ivf_declined")
 
     def __init__(self):
         import threading
 
         self._lock = threading.Lock()
         self.gmin = self.scan = self.declined_slower = 0
+        # dispatches that had a partition layout and took a full-store
+        # program by the bytes (index/plan.py `probed_reads_less`); beside
+        # `as_dict`, whose three keys are the kernel's choice
+        self.ivf_declined = 0
 
     def count(self, program: str) -> None:
         with self._lock:
@@ -161,6 +165,10 @@ class ProgramCounts:
     def declined(self) -> None:
         with self._lock:
             self.declined_slower += 1
+
+    def declined_probe(self) -> None:
+        with self._lock:
+            self.ivf_declined += 1
 
     def kernel_serves(self, *shape) -> bool:
         """`kernel_serves(*shape)` as both indexes ask it: a no at a shape

@@ -265,6 +265,236 @@ def build_buckets(assign: np.ndarray, nlist: int,
     return buckets, fills
 
 
+# -- the tiled layout: partition-major slots -----------------------------------
+#
+# An uncompressed one-chip index keeps its ONE copy of the rows in partition
+# order: partition p owns the slots [p * cap_p, (p + 1) * cap_p) of the store
+# (its tile), filled from the front, the rest of the tile empty (tombstoned,
+# zero rows). The table from a partition to its tile is that product, so no
+# bucket array rides the snapshot, a probe reads whole [cap_p, D] tiles that
+# lie contiguous in HBM, and the flat program scans the same store with the
+# same masks. Training runs on the device over the store in place
+# (`kmeans_fit_device`, `nearest_partitions_device`); the host only balances
+# (`balance_partitions`) and hands out slots (`place_in_tiles`).
+
+# nearest partitions kept a row: a row that finds its nearest partition full
+# walks this many preferences before it takes the emptiest partition
+PREFS = 8
+
+# rows a block of the device's assignment pass scores against every centroid
+_DEVICE_BLOCK = 8192
+
+
+def _l2_to_centroids(rows, centroids, cn):
+    """[R, D] x [L, D] -> [R, L] squared L2 up to the row's own norm (a
+    constant a row: the ranking is the L2 ranking)."""
+    return cn[None, :] - 2.0 * jnp.matmul(
+        rows, centroids.T, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
+
+
+@jax.jit
+def gather_rows(store, slots):
+    """`slots` [S] rows of the store as float32 (a training's sample: S x D,
+    never the slab)."""
+    return jnp.take(store, slots, axis=0).astype(jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("iters", "normalize"))
+def kmeans_fit_device(sample, seeds, iters, normalize):
+    """Lloyd's k-means on the device over `sample` [S, D] (rows gathered
+    from the store: `gather_rows`), seeded from its rows `seeds` [L]
+    (distinct) -> [L, D] f32 centroids. Its shapes are the sample's and the
+    partition count's, so an import compiles it a few times, not once a
+    capacity. An empty cluster is re-seeded from the sample rows farthest
+    from their centroid (`kmeans_fit`'s rule). `normalize`: unit centroids
+    for the angular probe (cosine)."""
+    cent = jnp.take(sample, seeds, axis=0)
+    nlist = cent.shape[0]
+    s = sample.shape[0]
+    blk = min(s, _DEVICE_BLOCK)
+    nblk = s // blk  # the caller rounds S down to whole blocks
+
+    def assign(cent):
+        cn = jnp.sum(cent * cent, axis=-1)
+
+        def one(rows):
+            d = _l2_to_centroids(rows, cent, cn)
+            a = jnp.argmin(d, axis=1).astype(jnp.int32)
+            return a, jnp.min(d, axis=1) + jnp.sum(rows * rows, axis=-1)
+
+        a, dmin = jax.lax.map(one, sample[: nblk * blk].reshape(nblk, blk, -1))
+        return a.reshape(-1), dmin.reshape(-1)
+
+    def step(cent, _):
+        a, dmin = assign(cent)
+        used = sample[: nblk * blk]
+        sums = jax.ops.segment_sum(used, a, num_segments=nlist)
+        counts = jax.ops.segment_sum(jnp.ones_like(dmin), a,
+                                     num_segments=nlist)
+        mean = sums / jnp.maximum(counts, 1.0)[:, None]
+        empty = counts == 0
+        # the e-th empty cluster takes the e-th farthest sample row
+        _, far = jax.lax.top_k(dmin, min(nlist, dmin.shape[0]))
+        rank = jnp.clip(jnp.cumsum(empty) - 1, 0, far.shape[0] - 1)
+        reseed = jnp.take(used, jnp.take(far, rank), axis=0)
+        return jnp.where(empty[:, None], reseed, mean), None
+
+    cent, _ = jax.lax.scan(step, cent, None, length=iters)
+    if normalize:
+        nrm = jnp.sqrt(jnp.sum(cent * cent, axis=-1, keepdims=True))
+        cent = cent / jnp.where(nrm == 0, 1.0, nrm)
+    return cent
+
+
+@functools.partial(jax.jit, static_argnames=("prefs",))
+def nearest_partitions_device(store, centroids, prefs):
+    """The `prefs` nearest partitions (L2, the layout's metric for every
+    matmul metric: `assign_partitions`) of EVERY slot of the store, scored
+    in blocks over the store in place -> ([capacity, prefs] i32, nearest
+    first; [capacity] f32, the distance to the nearest up to the row's own
+    norm). Slots that hold no row are scored too and ignored by the host."""
+    cap, dim = store.shape
+    blk = min(cap, _DEVICE_BLOCK)
+    cent = centroids.astype(jnp.float32)
+    cn = jnp.sum(cent * cent, axis=-1)
+
+    def one(i):
+        rows = jax.lax.dynamic_slice(
+            store, (i * blk, 0), (blk, dim)).astype(jnp.float32)
+        neg, idx = jax.lax.top_k(-_l2_to_centroids(rows, cent, cn), prefs)
+        return idx.astype(jnp.int32), -neg[:, 0]
+
+    idx, d0 = jax.lax.map(one, jnp.arange(cap // blk, dtype=jnp.int32))
+    return idx.reshape(cap, prefs), d0.reshape(cap)
+
+
+@functools.partial(jax.jit, static_argnames=("prefs",))
+def nearest_partitions_of_rows(rows, centroids, prefs):
+    """`nearest_partitions_device` for rows that are not in the store yet
+    (a bulk write's, padded to a bucketed count): [R, D] -> ([R, prefs]
+    i32, [R] f32)."""
+    rows = rows.astype(jnp.float32)
+    cent = centroids.astype(jnp.float32)
+    neg, idx = jax.lax.top_k(
+        -_l2_to_centroids(rows, cent, jnp.sum(cent * cent, axis=-1)), prefs)
+    return idx.astype(jnp.int32), -neg[:, 0]
+
+
+def nearest_partitions(rows: np.ndarray, centroids: np.ndarray,
+                       prefs: int = PREFS) -> tuple[np.ndarray, np.ndarray]:
+    """`nearest_partitions_device` on the host, for the rows a write holds:
+    ([n, prefs] i32 nearest first, [n] f32 distance to the nearest up to the
+    row's norm), chunked like `assign_partitions`."""
+    rows = np.asarray(rows, np.float32)
+    nlist = centroids.shape[0]
+    prefs = min(prefs, nlist)
+    chunk = min(_ASSIGN_CHUNK, max(1024, (1 << 24) // max(nlist, 1)))
+    cn = np.einsum("ij,ij->i", centroids, centroids).astype(np.float32)
+    out = np.empty((rows.shape[0], prefs), np.int32)
+    d0 = np.empty(rows.shape[0], np.float32)
+    for s in range(0, rows.shape[0], chunk):
+        d = cn[None, :] - 2.0 * (rows[s: s + chunk] @ centroids.T)
+        if prefs < nlist:
+            top = np.argpartition(d, prefs - 1, axis=1)[:, :prefs]
+        else:
+            top = np.broadcast_to(np.arange(nlist), d.shape).copy()
+        dt = np.take_along_axis(d, top, axis=1)
+        order = np.argsort(dt, axis=1, kind="stable")
+        out[s: s + d.shape[0]] = np.take_along_axis(top, order, axis=1)
+        d0[s: s + d.shape[0]] = np.take_along_axis(dt, order[:, :1],
+                                                   axis=1)[:, 0]
+    return out, d0
+
+
+def balance_partitions(prefs: np.ndarray, d0: np.ndarray,
+                       room: np.ndarray) -> np.ndarray:
+    """Capacity-bounded assignment (`balanced_assign`'s rule, vectorized):
+    every row asks for its nearest partition; a partition with less `room`
+    [nlist] than askers keeps the closest of them and the rest ask their
+    next preference, a round a preference; a row none of whose preferences
+    has room takes the emptiest partition (placement quality for that row is
+    already marginal, liveness is not). -> [n] i32 partitions; raises
+    ValueError where the rows outnumber the room. `room` is not modified."""
+    n, w = prefs.shape
+    nlist = room.shape[0]
+    room = room.astype(np.int64).copy()
+    if n > int(room.sum()):
+        raise ValueError(f"{n} rows for {int(room.sum())} free slots")
+    part = np.full(n, -1, np.int32)
+    todo = np.arange(n)
+    for r in range(w):
+        if not todo.size:
+            break
+        want = prefs[todo, r]
+        order = np.lexsort((d0[todo], want))   # by partition, closest first
+        t, wn = todo[order], want[order]
+        first = np.searchsorted(wn, np.arange(nlist))
+        ok = np.arange(t.size) - first[wn] < room[wn]
+        part[t[ok]] = wn[ok]
+        room -= np.bincount(wn[ok], minlength=nlist)
+        todo = t[~ok]
+    if todo.size:
+        emptiest = np.argsort(-room, kind="stable")
+        part[todo] = np.repeat(emptiest, room[emptiest])[: todo.size]
+    return part
+
+
+# A tile is sized for the rows a partition holds on average, with room for
+# the clusters' own spread (rows past it spill to the next-nearest partition:
+# `balance_partitions`) and for the growth before the tiles are made anew
+# (`TpuVectorIndex._maybe_ivf_train` regrows a layout whose live rows passed
+# the rows it was sized for by TILE_HEADROOM; a regrow is seconds on the
+# device). Together with the ladder's rounding, at most an eighth, the
+# slots stay under 1.125 x 1.25 x 1.125 = 1.58 times the live rows: the
+# padding is what the layout costs in HBM and what the flat program, which
+# serves the same store's wide batches, scans for nothing.
+TILE_SLACK = 1.125
+TILE_HEADROOM = 1.25
+
+
+def tile_capacity(rows: int, nlist: int) -> int:
+    """Slots of a partition's tile for a layout sized for `rows` rows, on
+    a ladder of eight rungs an octave in multiples of 32 (a filter's words
+    tile with it), at least 128: the jit-shape ladder of the probed
+    program."""
+    want = max(128.0, TILE_SLACK * TILE_HEADROOM * rows / max(nlist, 1))
+    step = max(32, (1 << (int(want).bit_length() - 1)) // 8)
+    return int(-(-want // step) * step)
+
+
+def tile_slots(part: np.ndarray, d0: np.ndarray, cap_p: int) -> np.ndarray:
+    """Partitions [n] -> the slot of each row in the tiled layout: tile
+    `part * cap_p`, rows of a partition in order of their distance to its
+    centroid from the tile's front."""
+    order = np.lexsort((d0, part))
+    sorted_part = part[order]
+    first = np.searchsorted(sorted_part, np.arange(int(part.max()) + 1
+                                                   if part.size else 0))
+    rank = np.arange(part.size) - first[sorted_part]
+    slots = np.empty(part.size, np.int64)
+    slots[order] = sorted_part.astype(np.int64) * cap_p + rank
+    return slots
+
+
+def place_in_tiles(part: np.ndarray, free: np.ndarray,
+                   cap_p: int) -> np.ndarray:
+    """Free slots for rows assigned to partitions `part` [n]: the first
+    free slots of each partition's tile, in the rows' order. `free` [slots]
+    bool says which slots hold no row (the index's host tombstone mirror);
+    the caller made sure every partition has the room."""
+    slots = np.empty(part.size, np.int64)
+    order = np.argsort(part, kind="stable")
+    sp = part[order]
+    bounds = np.flatnonzero(np.diff(sp, prepend=-1, append=-1))
+    for lo, hi in zip(bounds[:-1], bounds[1:]):
+        p = int(sp[lo])
+        base = p * cap_p
+        got = np.flatnonzero(free[base: base + cap_p])[: hi - lo]
+        slots[order[lo:hi]] = got + base
+    return slots
+
+
 # -- device half: probe + candidate scoring ------------------------------------
 
 
@@ -325,13 +555,18 @@ def _slot_valid(slots: Array, n, tombs: Array, allow_words: Optional[Array]
 def _select(d: Array, slots: Array, kk: int, exact: bool):
     """Per-group smallest-kk selection (the flat scans' exact/approx
     split), returning (dists, slot ids) with -1 for masked winners."""
-    if exact or kk >= d.shape[1]:
-        neg, pos = jax.lax.top_k(-d, kk)
-        td = -neg
-    else:
-        td, pos = jax.lax.approx_min_k(d, kk, recall_target=0.95)
+    td, pos = _select_pos(d, kk, exact)
     ts = jnp.take_along_axis(slots, pos, axis=1)
     return td, jnp.where(jnp.isinf(td), -1, ts)
+
+
+def _select_pos(d: Array, kk: int, exact: bool):
+    """The smallest kk of each row of d -> (dists, positions)."""
+    kk = min(kk, d.shape[1])
+    if exact or kk >= d.shape[1]:
+        neg, pos = jax.lax.top_k(-d, kk)
+        return -neg, pos
+    return jax.lax.approx_min_k(d, kk, recall_target=0.95)
 
 
 def _grouped_topk(slots_g: Array, valid_g: Array, score_fn, keep: int,
@@ -521,4 +756,94 @@ def search_ivf_codes_fused(codes, recon_norms, tombs, n, q, allow_words,
                               codebook, centroids, buckets,
                               pca_proj, pca_rows, rot, k, metric,
                               use_allow, top_p, pre_c, exact, gp, steps2)
+    return translate_pack(top, idx, s2d)
+
+
+# -- the tiled layout: the probed read -----------------------------------------
+
+# tiles a step of the probed program's loop reads (`ivf_tiles_topk`)
+TILE_UNROLL = 8
+
+
+def ivf_tiles_topk(store, tombs, q, allow_words, centroids, k, metric,
+                   use_allow, top_p, cap_p):
+    """IVF search over a store kept in partition order (the tiled layout
+    above): probe -> read the top_p probed partitions' tiles, each ONE
+    contiguous [cap_p, D] slice of the store found by `partition * cap_p`,
+    and score every row of them in float32 (`rescore_distances`: the
+    distance a reply carries is the float32 distance of the row returned)
+    -> ([B, k] dists, [B, k] slots, -1 missing).
+
+    A loop over the probes, and nothing in it but the reduce: a probe's
+    tile is a dynamic slice of the whole store that the compiler reads in
+    place into the product's sum, so a probe costs the tile's bytes once
+    and nothing is gathered by row. What a metric does to the sum (cosine's
+    `1 - x`, dot's sign) waits for the whole block after the loop, and the
+    loop is unrolled by eight: a step is then an index, ONE fused reduce of
+    its eight tiles and the writes of their rows, 1.75 device ops a tile
+    where the plain loop has five (0.3157 -> 0.2448 ms a query alone on
+    the chip, and a third of the events in a profile of
+    a cell that probes 64 tiles a query). The masks are the flat program's,
+    read after the loop in one gather of whole tiles each (the tombstone
+    bits and the filter words of the nlist x cap_p slots, as [nlist, ...]
+    tables): the snapshot's own tombstones (an empty slot of a tile is a
+    tombstoned one) and the packed allowList words; every slot of a tile
+    lies under `n`. The selection is EXACT, in two levels (the k best of
+    every tile, then the k best of those): a query's neighbours lie in a
+    few tiles of ONE block, where `approx_min_k` lost them (it read recall
+    0.97 where the probe covered everything: PERF.md section 6, PR 43)."""
+    qf = q.astype(jnp.float32)
+    parts = _probe(qf, centroids, top_p, metric)            # [B, top_p]
+    b = qf.shape[0]
+    nlist = centroids.shape[0]
+    dim = store.shape[1]
+    starts = parts * cap_p                                   # [B, top_p]
+    l2 = metric == vi.DISTANCE_L2
+
+    def step(_, start):                                      # start [B]
+        if b == 1:   # one query: a plain dynamic slice, never a gather
+            rows = jax.lax.dynamic_slice(
+                store, (start[0], 0), (cap_p, dim))[None]
+        else:
+            rows = jax.vmap(lambda s: jax.lax.dynamic_slice(
+                store, (s, 0), (cap_p, dim)))(start)         # [B, cap_p, D]
+        rows = rows.astype(jnp.float32)
+        if l2:
+            return None, jnp.sum((rows - qf[:, None, :]) ** 2, axis=-1)
+        return None, jnp.sum(rows * qf[:, None, :], axis=-1)
+
+    _, d = jax.lax.scan(step, None, starts.T,
+                        unroll=min(TILE_UNROLL, top_p))      # [top_p, B, cap_p]
+    d = jnp.moveaxis(d, 0, 1)                                # [B, top_p, cap_p]
+    if not l2:   # `rescore_distances`' arithmetic, the last op after the loop
+        d = -d if metric == vi.DISTANCE_DOT else 1.0 - d
+    slots = nlist * cap_p
+    ok = jnp.logical_not(jnp.take(
+        tombs[:slots].reshape(nlist, cap_p), parts, axis=0))
+    if use_allow:
+        words = jnp.take(allow_words[: slots // 32].reshape(
+            nlist, cap_p // 32), parts, axis=0)              # [B, top_p, W]
+        allowed = (words[..., None] >> jnp.arange(32, dtype=jnp.uint32)) \
+            & jnp.uint32(1)
+        ok = jnp.logical_and(ok, allowed.reshape(b, top_p, cap_p) != 0)
+    d = jnp.where(ok, d, INF)
+    kt = min(k, cap_p)
+    neg, pos = jax.lax.top_k(-d, kt)                         # [B, top_p, kt]
+    slot_of = (starts[:, :, None] + pos).reshape(b, top_p * kt)
+    neg, best = jax.lax.top_k(neg.reshape(b, top_p * kt), k)
+    top = -neg
+    idx = jnp.take_along_axis(slot_of, best, axis=1).astype(jnp.int32)
+    return top, jnp.where(jnp.isinf(top), -1, idx)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("k", "metric", "use_allow", "top_p", "cap_p"),
+)
+def search_ivf_tiles_fused(store, tombs, q, allow_words, centroids, s2d,
+                           k, metric, use_allow, top_p, cap_p):
+    """ivf_tiles_topk as a top-level program with the slot->doc translation
+    in the SAME program, like every tier's."""
+    top, idx = ivf_tiles_topk(store, tombs, q, allow_words, centroids, k,
+                              metric, use_allow, top_p, cap_p)
     return translate_pack(top, idx, s2d)

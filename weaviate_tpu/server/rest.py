@@ -456,14 +456,22 @@ class Handler(BaseHTTPRequestHandler):
         `ops/gmin_scan.kernel_serves` chose the scan as the faster program.
         The indexes' own integers, lifetime and not the window's: with the
         tracer down `/debug/index` `kernels.gmin.dispatches` has them a
-        shard."""
-        total = {"gmin": 0, "scan": 0, "declined_slower": 0}
+        shard. `ivf_declined`: the dispatches that had a partition layout
+        and took a full-store program because the probed one would have
+        read more bytes (`index/plan.py probed_reads_less`);
+        `ivf_trainings`: the layouts this process trained (0 after a
+        restart that read its layout from disk: the `ivf.train` spans)."""
+        total = {"gmin": 0, "scan": 0, "declined_slower": 0,
+                 "ivf_declined": 0, "ivf_trainings": 0}
         for idx in list(self.app.db.indexes.values()):
             for shard in list(idx.shards.values()):
                 counts = getattr(shard.vector_index, "scan_programs", None)
                 if counts is not None:
                     for name, n in counts.as_dict().items():
                         total[name] += n
+                    total["ivf_declined"] += counts.ivf_declined
+                total["ivf_trainings"] += getattr(
+                    shard.vector_index, "_ivf_trains", 0)
         return total
 
     def h_debug_quality(self):
