@@ -601,7 +601,11 @@ def test_coalescer_config_env_parsing():
     assert cfg.coalescer.window_ms == 3.5
     assert cfg.coalescer.max_batch == 64
     assert cfg.coalescer.max_request_rows == 8
-    assert load_config({}).coalescer.enabled is False
+    # PR 44: on by default, and with no clock
+    assert load_config({}).coalescer.enabled is True
+    assert load_config({}).coalescer.window_ms == 0.0
+    assert load_config(
+        {"QUERY_COALESCER_ENABLED": "false"}).coalescer.enabled is False
     with pytest.raises(ConfigError):
         load_config({"QUERY_COALESCER_MAX_BATCH": "1"})
     with pytest.raises(ConfigError):

@@ -547,12 +547,12 @@ def test_pipeline_depth_deficit_mechanics():
         assert co._depth_deficit == 2
         # two lane completions pay down the deficit instead of releasing
         for _ in range(2):
-            lane = _Lane(("k",), None, None, K, False, 0.0)
+            lane = _Lane(("k",), None, None, K, False, 0.0, 256)
             co._release_lane(lane)
         assert co._depth_deficit == 0
         assert not co._inflight.acquire(blocking=False)
         # the third completion frees the single configured slot again
-        co._release_lane(_Lane(("k2",), None, None, K, False, 0.0))
+        co._release_lane(_Lane(("k2",), None, None, K, False, 0.0, 256))
         assert co._inflight.acquire(blocking=False)
         co._inflight.release()
     finally:

@@ -194,7 +194,7 @@ def test_drr_order_honors_weights(tmp_path):
         co = app.coalescer
 
         def lane(tenant, rows):
-            ln = _Lane(None, None, None, K, False, 0.0, tenant=tenant,
+            ln = _Lane(None, None, None, K, False, 0.0, 256, tenant=tenant,
                        tenant_label=tenant)
             ln.rows = rows
             return ln

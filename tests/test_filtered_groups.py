@@ -468,10 +468,12 @@ def _group_dispatch_fails(cfg):
                          ids=["coalescer_on", "group_dispatch_fails"])
 def test_where_no_group_is_formed_every_filtered_slot_is_served_by_itself(
         tmp_path, tweak):
-    """The per-slot path on inputs that really take it: the coalescer's
-    lanes carry one filter each, and a group whose dispatch fails before
-    the device is reached falls back slot by slot. Same answers, one query
-    a dispatch, no host fallback and the breaker closed."""
+    """The per-slot path on inputs that really take it: a group whose
+    dispatch fails before the device is reached falls back slot by slot
+    (same answers, one query a dispatch, no host fallback and the breaker
+    closed). With the coalescer on (PR 44: the default) a wide group keeps
+    the group path, whatever the coalescer is, and it is a request of ONE
+    filtered slot that takes the coalescer's per-signature lane."""
     from weaviate_tpu.grpcapi import weaviate_pb2 as pb
     from weaviate_tpu.server.grpc_server import SearchServicer
     from weaviate_tpu.serving import robustness
@@ -493,7 +495,26 @@ def test_where_no_group_is_formed_every_filtered_slot_is_served_by_itself(
             assert [uuidlib.UUID(x.id).int - 1 for x in one.results] \
                 == want.tolist()
         s = perf.get_window().summary()
-        assert s["dispatches"] == s["rows"] == 12
+        if tweak is _group_dispatch_fails:
+            assert s["dispatches"] == s["rows"] == 12
+        else:
+            # one group: its slots share their dispatches, and none of them
+            # went through a lane
+            assert s["rows"] == 12 and s["dispatches"] < 12
+            assert s["group_inputs"]["groups"] == 1
+            assert app.coalescer.stats()["dispatches"] == 0
+            # ONE filtered slot a request: the first sighting of its
+            # signature goes direct, the second rides the signature's lane
+            for _ in range(2):
+                one = _batch(sv, reqs[:1]).replies[0]
+                assert not one.error_message
+                mask = np.array([bags[rows[0]][0] in bag for bag in bags])
+                want, _ = _brute(vecs, vecs[rows[0]], mask, K)
+                assert [uuidlib.UUID(x.id).int - 1 for x in one.results] \
+                    == want.tolist()
+            st = app.coalescer.stats()
+            assert st["bypass"].get("cold_filter") == 1
+            assert st["dispatches"] == 1 and st["requests"] == 1
         br = robustness.get_breaker()
         assert br is None or br.allow()
     finally:

@@ -441,9 +441,12 @@ def test_dispatch_shape_carries_probed_aware_flops(tmp_path):
         shape = handle.shape
         assert shape is not None
         nlist, cap_p, _ = idx._ivf_meta
-        probed = 2 * cap_p + nlist
-        assert shape.n == probed          # not snap.n: no phantom work
-        assert shape.n < 2000
+        probed = 2 * cap_p + nlist        # a query's rows, not snap.n
+        assert probed < 2000
+        # what the program reads: each of the 4 padded queries its own
+        # tiles, the centroids once (PR 44: `n` was one query's rows)
+        assert shape.n == 4 * 2 * cap_p + nlist
+        assert shape.n == shape.extra["ivf_rows_read"]
         d = shape.describe()
         assert d["ivf"] is True
         assert d["ivf_top_p"] == 2

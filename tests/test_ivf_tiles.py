@@ -202,8 +202,10 @@ def test_plan_takes_the_probed_program_below_the_crossover_and_the_flat_above():
     above = min(b for b in tpu._B_BUCKETS if b > cross)
     p = plan_search(view, below, below, 10)
     assert p.ivf == (top_p, 0) and not p.ivf_declined
-    assert p.rows == top_p * cap_p + nlist and p.program is None
-    assert p.extra["ivf_rows_read"] == below * top_p * cap_p + nlist
+    # `rows` is what the program reads: every padded query's own tiles and
+    # the centroids once (PR 44: it was one query's, whatever the width)
+    assert p.rows == below * top_p * cap_p + nlist and p.program is None
+    assert p.extra["ivf_rows_read"] == p.rows
     assert p.extra["ivf_base_rows"] == 1_000_000
     p = plan_search(view, above, above, 10)
     assert p.ivf is None and p.ivf_declined

@@ -438,7 +438,14 @@ def test_coalesced_capture_has_queue_wait_on_the_waiters_threads(tmp_path):
                     near_vector={"vector": (vecs[i] + 0.5).tolist()},
                     limit=K))
 
+        # the first request of a depth brings its lane's wider programs on
+        # its own thread (PR 44): sent here, outside any trace and before
+        # the capture, so that both hold the riders alone
+        app.traverser.get_class(GetParams(
+            class_name="Pf", near_vector={"vector": vecs[0].tolist()},
+            limit=K))
         w = perf.get_window()
+        w.clear()
         w.capture_begin()
         threads = [threading.Thread(target=run, args=(i,))
                    for i in range(n_req)]

@@ -190,14 +190,22 @@ class MemUseConfig:
 @dataclass
 class CoalescerConfig:
     """Cross-request query coalescing (serving/coalescer.py). TPU extension:
-    concurrent single-query kNN requests admission-queue per
-    (shard, k, metric, filter-signature) lane and flush as one padded
-    device dispatch on bucket-fill or deadline. Disabled => the serving
-    path is byte-for-byte the direct dispatch (zero queue hops)."""
+    concurrent narrow kNN requests (up to `max_request_rows` rows)
+    admission-queue per (shard, k, metric, filter-signature) lane and a
+    lane leaves as one padded device dispatch when the dispatch in front
+    of it is done: on by default, and with no clock (`window_ms` 0: a lane
+    is due the moment it exists, so a request that meets nobody waits for
+    nothing, and under load lanes fill behind the dispatch in flight).
+    Disabled => the serving path is byte-for-byte the direct dispatch
+    (zero queue hops)."""
 
-    enabled: bool = False
-    window_ms: float = 1.5        # deadline flush window per lane
-    max_batch: int = 256          # rows that force an immediate flush
+    enabled: bool = True
+    # a lane is held this long for company after its first arrival; 0 = a
+    # lane waits for the dispatch in front of it and never for a clock
+    window_ms: float = 0.0
+    # rows that close a lane (a lane over a partition layout closes
+    # sooner: at the widest width the plan still probes, asked of the index)
+    max_batch: int = 256
     max_request_rows: int = 16    # wider requests bypass to the direct path
     # admission control (serving/robustness.py): the queue bound is
     # cost-aware — queued ROWS, not requests — and overflow sheds with
@@ -211,8 +219,8 @@ class CoalescerConfig:
     # snapshot-isolated read path (PR 4) finalize no longer contends with
     # the next lane's enqueue on an index lock, but on a CPU backend two
     # in-flight scans still contend for host cores — depth 1 (the flusher's
-    # stall IS the backpressure that fills lanes) remains the measured
-    # default; a real TPU backend is the case for 2.
+    # stall IS the backpressure that fills lanes, and with no window the
+    # only thing that does) remains the measured default.
     pipeline_depth: int = 1
 
 
@@ -831,8 +839,8 @@ def load_config(env: Optional[Mapping[str, str]] = None) -> Config:
 
     cfg.ivf = ivf_from_env(e)
 
-    cfg.coalescer.enabled = _bool(e, "QUERY_COALESCER_ENABLED")
-    cfg.coalescer.window_ms = _float(e, "QUERY_COALESCER_WINDOW_MS", 1.5)
+    cfg.coalescer.enabled = _bool(e, "QUERY_COALESCER_ENABLED", True)
+    cfg.coalescer.window_ms = _float(e, "QUERY_COALESCER_WINDOW_MS", 0.0)
     cfg.coalescer.max_batch = _int(e, "QUERY_COALESCER_MAX_BATCH", 256)
     cfg.coalescer.max_request_rows = _int(
         e, "QUERY_COALESCER_MAX_REQUEST_ROWS", 16)

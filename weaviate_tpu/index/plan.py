@@ -69,6 +69,25 @@ def probed_reads_less(b_padded: int, ndev: int, top_p: int, cap_p: int,
     return (cost * b_padded * ndev * top_p * cap_p + nlist) < n
 
 
+def same_program_width(view: "PlanView", k: int, widths) -> Optional[int]:
+    """Where a lane of single queries has to close so that its dispatch runs
+    the program each rider's own would have: the widest of `widths` (padded
+    dispatch widths, ascending) for which `probed_reads_less` still holds,
+    over a partition layout whose probe serves ONE query at depth `k` on the
+    state `view` shows (a wider lane would be declined to the flat program:
+    other bytes, and the flat step's answers). None where every width runs
+    one program: no layout serves, or it is declined at the first width
+    already, and a full-store program reads the store once whatever the
+    width."""
+    probe = view.ivf_probe(k) if view.ivf_probe is not None else None
+    if probe is None:
+        return None
+    nlist, cap_p = view.ivf_meta
+    probed = [w for w in widths if probed_reads_less(
+        w, view.ndev, probe[0], cap_p, nlist, view.n, view.ivf_gathered)]
+    return probed[-1] if probed and probed[0] == widths[0] else None
+
+
 def rescore_depth(config, metric: str, k: int, n: int) -> int:
     """Fast-scan candidate depth R of a scan over a slab of n rows (the
     one rule of both indexes: the mesh plans it against one chip's slab):
@@ -297,23 +316,28 @@ def plan_search(view: PlanView, b: int, b_padded: int, k: int,
         common["ivf_declined"] = True
     if probe is not None:
         # partition-pruned (ROADMAP item 3): `rows` is what the device
-        # actually reads (top_p x cap_p candidates a chip, padding included,
-        # plus the nlist centroid rows), so flops/bytes — and every roofline
-        # derived from them — never credit the rows the probe skipped
+        # actually reads: every query of the padded dispatch reads its OWN
+        # top_p x cap_p candidates a chip, padding included, and the nlist
+        # centroid rows are read once. So bytes, and every roofline derived
+        # from them, never credit the rows the probe skipped, and a lane of
+        # 16 riders is not charged as one query (`tier_rows` is fed from
+        # this; `costmodel.DispatchShape.flops` counts a query's rows once)
         nlist, cap_p = view.ivf_meta
         funnel_rows = probe[0] * cap_p
         funnel_k = min(kk, funnel_rows)
-        rows = view.ndev * funnel_rows + nlist
+        rows = b_padded * view.ndev * funnel_rows + nlist
         extra = {"ivf": True, "ivf_top_p": probe[0], "ivf_nlist": nlist,
                  "ivf_cap_p": cap_p,
-                 # what the program reads, every query's own partitions,
-                 # against the live rows a flat scan would have to cover
-                 "ivf_rows_read": b_padded * view.ndev * funnel_rows + nlist,
+                 # the same rows, against the live rows a flat scan of the
+                 # dispatch would have to cover
+                 "ivf_rows_read": rows,
                  "ivf_base_rows": view.live,
                  "ivf_padding_share": round(
                      1.0 - view.live / max(view.ndev * nlist * cap_p, 1), 4),
-                 "probed_fraction": round(
-                     min(rows / max(view.n, 1), 1.0), 4)}
+                 # ONE query's share of the rows
+                 "probed_fraction": round(min(
+                     (view.ndev * funnel_rows + nlist) / max(view.n, 1),
+                     1.0), 4)}
         common["ivf"] = probe
     common.update(rows=rows, extra=extra)
     if not view.compressed:

@@ -66,7 +66,7 @@ from weaviate_tpu.index.interface import (AllowList, SnapshotRetired,
 from weaviate_tpu.index.plan import (KERNEL_FUNNEL, KERNEL_GMIN,
                                      DispatchHandle, PlanView, fetch_stamped,
                                      funnel_budgets, plan_search,
-                                     rescore_depth)
+                                     rescore_depth, same_program_width)
 # dispatch-shape recording for the perf-attribution plane: a
 # costmodel.DispatchShape is built per dispatch ONLY while the tracer is
 # up (tracing.get_tracer() gate — the zero-cost-when-disabled contract)
@@ -4117,6 +4117,20 @@ class TpuVectorIndex(VectorIndex):
         width the jit cache is keyed on. Serving traces use it to report
         per-request padding waste (monitoring/tracing.py dispatch facts)."""
         return _bucket_b(max(int(b), 1))
+
+    def lane_width(self, k: int, cap: int) -> int:
+        """The widest dispatch, at most `cap` rows, that the plan serves
+        with the program ONE query at depth `k` gets (`index/plan.py
+        same_program_width`): where the coalescer closes a lane of narrow
+        requests. Over a tiled layout that is the widest width still
+        probed; `cap` where no layout serves. Answered from the published
+        snapshot with no lock (`cap` while nothing is published)."""
+        snap = self._snap
+        if snap is None or not snap.live or snap.ivf_meta is None:
+            return cap
+        return same_program_width(
+            self._plan_view(snap), min(k, snap.live),
+            [w for w in _B_BUCKETS if w <= cap]) or cap
 
     def search_by_vectors(
         self, vectors: np.ndarray, k: int, allow_list: Optional[AllowList] = None

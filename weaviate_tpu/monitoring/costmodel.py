@@ -111,10 +111,11 @@ class DispatchShape:
       n              rows the dispatch scans (live rows; the allowList size
                      on the gather tier; n_pad on the BM25 matmul; on an
                      IVF partition-pruned dispatch the PROBED rows —
-                     top_p x bucket capacity, plus the nlist centroid
-                     rows — so flops()/bytes() are probed-aware and the
-                     roofline never reports the phantom work of the rows
-                     the probe skipped; ``extra`` then carries
+                     top_p x bucket capacity a padded query, plus the
+                     nlist centroid rows — so flops()/bytes() are
+                     probed-aware and the roofline never reports the
+                     phantom work of the rows the probe skipped;
+                     ``extra`` then carries
                      {"ivf": True, "probed_fraction": probed/N})
       dim            vector dims (effective units for BM25)
       batch          ACTUAL query rows (useful work — padding is reported
@@ -185,8 +186,14 @@ class DispatchShape:
     # -- analytic totals -----------------------------------------------------
 
     def flops(self) -> int:
-        """Useful distance FLOPs for the whole dispatch (actual rows)."""
-        return int(round(2.0 * self.batch * self.n * self.dim))
+        """Useful distance FLOPs for the whole dispatch (actual rows). A
+        partition-pruned dispatch's `n` is the rows ALL its padded queries
+        read, each its own: a query is scored against its own share."""
+        n = self.n
+        if self.extra and self.extra.get("ivf"):
+            n = (self.ndev * self.extra["ivf_top_p"] * self.extra["ivf_cap_p"]
+                 + self.extra["ivf_nlist"])
+        return int(round(2.0 * self.batch * n * self.dim))
 
     def bytes(self) -> int:
         """Store bytes read from HBM for the whole dispatch. On the

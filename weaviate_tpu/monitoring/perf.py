@@ -68,7 +68,7 @@ import json
 import os
 import threading
 import time
-from collections import deque
+from collections import Counter, deque
 from typing import Optional
 
 from weaviate_tpu.monitoring import costmodel
@@ -239,6 +239,9 @@ class PerfWindow:
             p: deque(maxlen=_PHASE_SAMPLES_MAX)
             for p in WRITE_PHASES + WRITE_SPANS}
         self._write_counts: deque = deque(maxlen=_PHASE_SAMPLES_MAX)
+        # (t_mono, (event, reason, riders, rows)) of the admission queue
+        # (serving/coalescer.py): a lane dispatched, a bypass, a shed
+        self._coalescer: deque = deque(maxlen=_PHASE_SAMPLES_MAX)
         # (t_mono, ms) a search that met staged writes and took the index
         # lock (index/tpu.py `_read_snapshot`'s slow path)
         self._read_lock_waits: deque = deque(maxlen=_PHASE_SAMPLES_MAX)
@@ -418,6 +421,13 @@ class PerfWindow:
         """One search that took the index lock to see staged writes."""
         self._keep(self._read_lock_waits, float(ms))
 
+    def note_coalescer(self, event: str, reason: str = "", riders: int = 0,
+                       rows: int = 0) -> None:
+        """One event of the admission queue: a `lane` dispatched with its
+        riders (requests) and rows, a `bypass` or a `shed` with its
+        reason."""
+        self._keep(self._coalescer, (event, reason, int(riders), int(rows)))
+
     def note_interval(self, name: str, start_ns: int, end_ns: int,
                       tid: Optional[int] = None) -> None:
         """One closed host phase on ``time.perf_counter_ns``; `tid` is the
@@ -492,7 +502,8 @@ class PerfWindow:
             self._rows -= self._entries.popleft()[2]
         for d in (*self._phase.values(), self._point_get, self._rescore,
                   self._group_inputs, *self._write_phase.values(),
-                  self._write_counts, self._read_lock_waits):
+                  self._write_counts, self._read_lock_waits,
+                  self._coalescer):
             while d and d[0][0] < horizon:
                 d.popleft()
         while self._postings and self._postings[0][0] < horizon - 1.0:
@@ -545,6 +556,7 @@ class PerfWindow:
                 d.clear()
             self._write_counts.clear()
             self._read_lock_waits.clear()
+            self._coalescer.clear()
             self._duty = DutyCycle(self.window_s)
             self._rows = 0
             self._first_entry = None
@@ -588,6 +600,7 @@ class PerfWindow:
                         for p, d in self._write_phase.items() if d}
             write_counts = [c for _, c in self._write_counts]
             lock_waits = [ms for _, ms in self._read_lock_waits]
+            queue_events = [e for _, e in self._coalescer]
         out: dict = {
             "window_s": self.window_s,
             "observed_s": round(span, 3),
@@ -692,6 +705,21 @@ class PerfWindow:
                            if p in write_ms},
                 **{p + "_ms": stat(write_ms[p]) for p in WRITE_SPANS
                    if p in write_ms}}
+        if queue_events:
+            # the admission queue over the window (serving/coalescer.py):
+            # the lanes it dispatched, the requests (`riders`) and rows they
+            # carried (`riders_per_lane` is the cause beside `rows /
+            # dispatches`), and the requests that did not ride, by reason
+            lanes = [e for e in queue_events if e[0] == "lane"]
+            by_reason = (lambda kind: dict(sorted(Counter(
+                e[1] for e in queue_events if e[0] == kind).items())))
+            riders = sum(e[2] for e in lanes)
+            out["coalescer"] = {
+                "lanes": len(lanes), "riders": riders,
+                "rows": sum(e[3] for e in lanes),
+                "riders_per_lane": round(riders / len(lanes), 3)
+                if lanes else 0.0,
+                "bypass": by_reason("bypass"), "shed": by_reason("shed")}
         # searches that found staged writes and took the index lock to see
         # them (the first read after a write), and what they waited there
         out["read_lock_waits"] = len(lock_waits)
@@ -1225,6 +1253,15 @@ def note_read_lock_wait(ms: float) -> None:
     w = _window
     if w is not None:
         w.note_read_lock_wait(ms)
+
+
+def note_coalescer(event: str, reason: str = "", riders: int = 0,
+                   rows: int = 0) -> None:
+    """`PerfWindow.note_coalescer` on the installed window; one comparison
+    while the plane is down."""
+    w = _window
+    if w is not None:
+        w.note_coalescer(event, reason, riders, rows)
 
 
 def note_point_get(*counts, **events) -> None:

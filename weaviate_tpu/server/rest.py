@@ -828,11 +828,15 @@ class Handler(BaseHTTPRequestHandler):
         if not isinstance(body, list):
             raise HTTPError(400, "graphql batch body must be a list")
         pool = getattr(self.app, "serving_pool", None)
-        if pool is not None and len(body) > 1:
-            # coalescing on: run the slots CONCURRENTLY so their kNN
-            # dispatches admission-queue into one padded device batch (the
-            # REST twin of gRPC BatchSearch) instead of serializing one
-            # one-wide dispatch per slot. graphql.execute returns per-query
+        co = getattr(self.app, "coalescer", None)
+        if pool is not None and co is not None \
+                and 1 < len(body) <= co.max_request_rows:
+            # coalescing on, a NARROW batch (the widest request the
+            # coalescer admits; a wide one keeps the serial loop below and
+            # does not become a pool task a slot): run the slots
+            # CONCURRENTLY so their kNN dispatches admission-queue into one
+            # padded device batch (the REST twin of gRPC BatchSearch)
+            # instead of one after the other. graphql.execute returns per-query
             # error envelopes, so slot isolation matches the serial path.
             # Each slot runs under a COPY of this handler's context (one
             # copy per slot — a shared Context cannot be entered twice

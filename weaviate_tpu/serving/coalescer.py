@@ -13,13 +13,28 @@ their rows to share one device dispatch — (shard, k, metric,
 filter-signature, include_vector) — and a lane flushes as ONE padded
 dispatch when either
 
-  (a) its row count fills the configured batch-width bucket (`max_batch`,
-      snapped DOWN to the same padding buckets the index's `_bucket_b`
-      rounds query widths to, so a full lane hits the same jit cache as
-      direct dispatches without exceeding the configured cap), or
-  (b) the deadline window (default ~1.5 ms) since the lane's first arrival
-      expires — the Orca/vLLM-style continuous-batching tradeoff: bounded
-      added latency buys full-width dispatches.
+  (a) its row count fills its width: `max_batch` (snapped DOWN to the same
+      padding buckets the index's `_bucket_b` rounds query widths to, so a
+      full lane hits the same jit cache as direct dispatches without
+      exceeding the configured cap), or, where the index says so, less:
+      the widest dispatch that still runs the program ONE query gets
+      (`lane_width` of the index, read at the lane's creation: over a
+      tiled partition layout the widest width the plan still probes,
+      index/plan.py `same_program_width`), or
+  (b) it is due: `window_s` after its first arrival. The default window is
+      0: a lane is due the moment it exists and waits for the dispatch in
+      front of it, never for a clock. The flush loop enqueues a due lane's
+      program and then blocks until the lane before it has finalized
+      (`pipeline_depth` 1); whatever arrives meanwhile gathers in the next
+      lane. A request that meets nobody, and whose caller waits for it at
+      once (`submit(wait_now=True)`), is served on its own thread inside
+      `submit` (`_lead`: it holds the in-flight slot as a lane of one, so
+      whoever arrives meanwhile gathers behind it, and it pays no thread
+      hand-off for company it did not have; the slot is never held across
+      a return to the caller), and under load lanes fill behind the
+      dispatch in flight. A window above 0 is
+      the Orca/vLLM-style tradeoff: bounded added latency for every
+      request buys wider dispatches.
 
 Dispatch rides the existing two-phase path (`object_vector_search_async`):
 the flush thread enqueues device work in dispatch order, while finalize +
@@ -121,6 +136,7 @@ from __future__ import annotations
 import functools
 import threading
 import time
+import weakref
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
@@ -241,11 +257,11 @@ class _Lane:
     SAME label even if the labeler's top-K churns in between."""
 
     __slots__ = ("key", "shard", "flt", "k", "include_vector", "items",
-                 "rows", "deadline", "settled", "released", "dispatch_start",
-                 "tenant", "tenant_label")
+                 "rows", "width", "deadline", "settled", "released",
+                 "dispatch_start", "tenant", "tenant_label")
 
     def __init__(self, key, shard, flt, k: int, include_vector: bool,
-                 deadline: float, tenant: str = "",
+                 deadline: float, width: int, tenant: str = "",
                  tenant_label: str = ""):
         self.key = key
         self.shard = shard
@@ -254,6 +270,9 @@ class _Lane:
         self.include_vector = include_vector
         self.items: list[_Waiter] = []
         self.rows = 0
+        # rows that close the lane: `max_batch`, or what the index said
+        # when the lane was made (`QueryCoalescer._lane_width`)
+        self.width = width
         self.deadline = deadline
         self.settled = False     # waiters woken (resolved or failed)
         self.released = False    # in-flight slot given back
@@ -285,7 +304,7 @@ class _TenantState:
 
 
 class QueryCoalescer:
-    def __init__(self, window_s: float = 0.0015, max_batch: int = 256,
+    def __init__(self, window_s: float = 0.0, max_batch: int = 256,
                  max_request_rows: int = 16, metrics=None,
                  pipeline_depth: int = 1, max_queued_rows: int = 4096,
                  waiter_timeout_s: float = 30.0,
@@ -325,9 +344,15 @@ class QueryCoalescer:
         # filter-signature recency: a filtered request only queues when its
         # signature was seen within the TTL (someone to merge with is
         # plausible); a cold signature bypasses so one-off filters never
-        # pay the window for an inevitable singleton lane
+        # pay the queue for an inevitable singleton lane. One second at
+        # least, whatever the window (it is 0 by default)
         self._sig_ttl = max(1.0, self.window_s * 100.0)
         self._recent_sigs: dict[str, float] = {}
+        # shard -> {(depth, lane width)} whose lane programs are loaded or
+        # compiled (`_warm`); a shard that goes takes its entry with it, a
+        # layout trained later changes the width and is warmed anew
+        self._warmed: "weakref.WeakKeyDictionary" = \
+            weakref.WeakKeyDictionary()
         # cheap python-side counters (bench/tests read these without a
         # prometheus round trip; the histograms carry the same data)
         self._dispatches = 0
@@ -396,7 +421,8 @@ class QueryCoalescer:
     # -- admission -----------------------------------------------------------
 
     def submit(self, shard, vectors: np.ndarray, k: int, flt=None,
-               include_vector: bool = False, tenant: Optional[str] = None):
+               include_vector: bool = False, tenant: Optional[str] = None,
+               wait_now: bool = False):
         """Queue a request's rows for a coalesced dispatch.
 
         -> a blocking callable() -> list[list[SearchResult]] (one list per
@@ -411,7 +437,14 @@ class QueryCoalescer:
 
         `tenant` is the request's accounting identity; None resolves via
         robustness.effective_tenant (explicit X-Tenant-Id, else the
-        shard's class name)."""
+        shard's class name). `wait_now`: the caller invokes the returned
+        callable at once, with nothing else to enqueue or wait for first;
+        a request that meets nobody is then served on the caller's thread
+        inside this call (`_lead`) and the callable returns what is there.
+        A caller that defers its callables says False and always queues:
+        the in-flight slot is never held across a return, or a caller
+        that waited on another lane before it invoked this one would wait
+        on itself."""
         robustness.check_deadline("coalescer.admit")
         # fault-injection point: the abusive-tenant storm journeys inject
         # stalls/errors at ADMISSION — before any queue state is touched,
@@ -476,9 +509,9 @@ class QueryCoalescer:
         cold = False
         shed_reason: Optional[str] = None
         # cold-start fallback hint (no resolved dispatch yet => no drain
-        # EWMA anywhere): a few flush windows is the only drain clock the
-        # server has — every warmer path below replaces it with a
-        # measured estimate
+        # EWMA anywhere): a few flush windows, 50 ms at least (the window
+        # is 0 by default), is the only drain clock the server has — every
+        # warmer path below replaces it with a measured estimate
         retry_after = max(self.window_s * 4.0, 0.05)
         eff_cap = self._tenant_row_cap
         with self._cv:
@@ -552,12 +585,14 @@ class QueryCoalescer:
                 # the flusher once per REQUEST on the hot path instead of
                 # once per window.
                 wake = False
+                lead = warm = False
                 lane = self._lanes.get(key)
-                if lane is not None and lane.rows + q.shape[0] > self.max_batch:
-                    # this request would overflow the bucket: flush the lane
-                    # as-is and start fresh — a dispatch must never exceed
-                    # max_batch, or it pads to the NEXT bucket and compiles
-                    # a shape the direct path never uses
+                if lane is not None and lane.rows + rows > lane.width:
+                    # this request would overflow the lane: flush it as-is
+                    # and start fresh — a dispatch must never exceed its
+                    # width, or it pads to the NEXT bucket: a shape the
+                    # direct path never uses and, over a partition layout,
+                    # another program than a single query gets
                     del self._lanes[key]
                     self._full.append(lane)
                     lane = None
@@ -568,30 +603,52 @@ class QueryCoalescer:
                     # default while the plane is off/stale). Read at lane
                     # creation so an actuation applies from the NEXT lane
                     # — in-flight lanes keep the deadline they promised.
+                    window_s = controller.coalescer_window_s(self.window_s)
+                    width = self._lane_width(shard, int(k))
                     lane = _Lane(key, shard, flt, int(k),
                                  bool(include_vector),
-                                 time.monotonic()
-                                 + controller.coalescer_window_s(
-                                     self.window_s),
+                                 time.monotonic() + window_s, width,
                                  tenant=tenant,
                                  tenant_label=self._tenant_label(tenant))
-                    self._lanes[key] = lane
-                    wake = True
+                    if flt is None:
+                        # the first unfiltered lane of a depth on this
+                        # index state: its maker brings the wider programs
+                        # once it is out of the lock (`_warm`)
+                        seen = self._warmed.setdefault(shard, set())
+                        warm = (int(k), width) not in seen
+                        seen.add((int(k), width))
+                    # nothing queued, nothing in flight, no window to hold
+                    # the lane for, and a caller that waits at once: the
+                    # rider serves its lane itself before `submit` returns
+                    # (`_lead`), holding the in-flight slot meanwhile as
+                    # any lane in flight does
+                    lead = (wait_now and not warm and window_s <= 0.0
+                            and flt is None
+                            and not self._lanes and not self._full
+                            and hasattr(shard.vector_index,
+                                        "search_by_vectors_async")
+                            and self._inflight.acquire(blocking=False))
+                    if not lead:
+                        self._lanes[key] = lane
+                        wake = True
                 w = _Waiter(q, max_wait_s=self.waiter_timeout_s,
                             tenant=tenant, tenant_label=lane.tenant_label)
                 lane.items.append(w)
-                lane.rows += q.shape[0]
-                self._queued_rows += q.shape[0]
-                st.rows += q.shape[0]
-                self._pipeline_rows_total += q.shape[0]
+                lane.rows += rows
+                if not lead:   # a led lane is never queued
+                    self._queued_rows += rows
+                st.rows += rows
+                self._pipeline_rows_total += rows
                 st.last_seen = time.monotonic()
-                if lane.rows >= self.max_batch:
-                    # bucket full: pop now so later arrivals start fresh
+                if lane.rows >= lane.width and not lead:
+                    # lane full (a request wider than its lane's width is
+                    # one at once: it leaves alone, as on the direct path):
+                    # pop now so later arrivals start fresh
                     del self._lanes[key]
                     self._full.append(lane)
                     wake = True
                 self._set_depth_gauge()
-                self._tenant_gauge(lane.tenant_label, q.shape[0])
+                self._tenant_gauge(lane.tenant_label, rows)
                 if wake:
                     self._cv.notify()
         if closed:
@@ -631,7 +688,66 @@ class QueryCoalescer:
                     m.tenant_labels.observe(tenant)).inc()
             except Exception:  # noqa: BLE001 — metrics must not break serving
                 pass
+        if warm:
+            self._warm(shard, int(k), lane.width)
+        if lead:
+            self._lead(lane, w)   # settled when it returns: wait() is a read
         return w.wait
+
+    def _lead(self, lane: _Lane, w: _Waiter) -> None:
+        """The rider that met nothing in front of it serves its lane of one
+        on its own thread, inside `submit`: three thread hand-offs less
+        (flusher, pool, wake-up) for a request that shares with nobody. It
+        holds the in-flight slot until it has finalized, so the flusher
+        enqueues the next lane behind it and whoever arrives meanwhile
+        gathers as behind any lane in flight. Counted and settled as a
+        lane; a failure is the waiter's to raise."""
+        try:
+            faults.fire("serving.coalescer.dispatch")
+            self._observe_wait(lane)
+            self._resolve_lane(lane, lane.shard.object_vector_search_async(
+                w.vectors, lane.k, include_vector=lane.include_vector)())
+        except Exception as e:  # noqa: BLE001 — the lane's failure, as a lane's
+            self._fail_lane(lane, e)
+        finally:
+            self._release_lane(lane)
+
+    def _lane_width(self, shard, k: int) -> int:
+        """Rows that close a lane of `shard` at depth `k`: `max_batch`, or
+        less where the index says that a wider dispatch would run another
+        program than a single query gets (`lane_width`: index/tpu.py,
+        index/mesh.py). Asked when a lane is made, under the coalescer
+        lock: the index answers from its published snapshot with no lock
+        of its own."""
+        ask = getattr(shard.vector_index, "lane_width", None)
+        if ask is None:
+            return self.max_batch
+        return max(1, min(int(ask(k, self.max_batch)), self.max_batch))
+
+    def _warm(self, shard, k: int, width: int) -> None:
+        """The maker of the first unfiltered lane of a depth on an index
+        state loads or compiles the programs that wider lanes of narrow
+        requests take there: one dispatch of zero queries a rung of the
+        padding ladder above 1 (its own request brings that one), up to the
+        rung the widest admitted request pads to (4 and 16 at the default
+        `max_request_rows`) and no wider than the lane's width, through the
+        index's own plan, fetched and thrown away. A first compile then
+        falls on the first request of a depth (a benchmark's warm-up, a
+        deployment's first page) and not on the riders of the first lane
+        that wide. A failure is left to the lane that meets it."""
+        vidx = shard.vector_index
+        submit = getattr(vidx, "search_by_vectors_async", None)
+        dim = getattr(vidx, "dim", None)
+        if submit is None or not dim:
+            return
+        top = min(next(s for s in _B_BUCKETS if s >= self.max_request_rows),
+                  width)
+        try:
+            for b in _B_BUCKETS:
+                if 1 < b <= top:
+                    submit(np.zeros((b, int(dim)), np.float32), k)()
+        except Exception:  # noqa: BLE001 — the lane that takes the program answers
+            pass
 
     def record_bypass(self, reason: str) -> None:
         """Count a request that took the direct path instead of the queue."""
@@ -641,6 +757,7 @@ class QueryCoalescer:
         tracing.annotate_current("coalescer_bypass", reason)
         with self._lock:
             self._bypass[reason] = self._bypass.get(reason, 0) + 1
+        perf.note_coalescer("bypass", reason)
         m = self.metrics
         if m is not None:
             try:
@@ -657,6 +774,7 @@ class QueryCoalescer:
             if tenant:
                 st = self._tenant_state(tenant)
                 st.shed[reason] = st.shed.get(reason, 0) + 1
+        perf.note_coalescer("shed", reason)
         robustness.count_shed(reason)
         robustness.count_tenant_shed(tenant, reason)
 
@@ -709,15 +827,15 @@ class QueryCoalescer:
         merged dispatch is bit-identical to what the tenant-blind
         coalescer would have dispatched. DRR order is preserved: the
         accumulator lane keeps the earliest DRR position, and when a
-        merged dispatch would exceed max_batch the overflow starts a new
-        one in order — under contention the DRR-favored tenants' rows
+        merged dispatch would exceed the lanes' width the overflow starts a
+        new one in order — under contention the DRR-favored tenants' rows
         get the batch slots, which IS the weighted-fair drain."""
         groups: dict[tuple, _Lane] = {}
         out: list[_Lane] = []
         for ln in due:
             base = ln.key[1:] if isinstance(ln.key, tuple) else ln.key
             acc = groups.get(base)
-            if acc is None or acc.rows + ln.rows > self.max_batch:
+            if acc is None or acc.rows + ln.rows > min(acc.width, ln.width):
                 groups[base] = ln
                 out.append(ln)
                 continue
@@ -1305,6 +1423,7 @@ class QueryCoalescer:
                         st.ewma_rows_per_s = (
                             rate if st.ewma_rows_per_s <= 0.0
                             else 0.3 * rate + 0.7 * st.ewma_rows_per_s)
+        perf.note_coalescer("lane", riders=len(lane.items), rows=lane.rows)
         m = self.metrics
         if m is not None:
             try:

@@ -116,10 +116,12 @@ class Explorer:
     # -- cross-request coalescing (serving/coalescer.py) ---------------------
 
     def _coalesce_submit(self, idx, vecs: np.ndarray, k: int, flt,
-                         include_vector: bool):
+                         include_vector: bool, wait_now: bool = False):
         """Admission-queue a request's rows for a coalesced device dispatch.
         -> blocking finalize() (same contract as object_vector_search_async's
-        `done`) or None => the caller uses the direct path. Only the
+        `done`) or None => the caller uses the direct path. `wait_now`: the
+        caller invokes that callable at once (`QueryCoalescer.submit`: only
+        then may a lone request be served on this thread). Only the
         single-local-shard layout coalesces: multi-shard/remote fan-out
         already runs per-shard batches on the pool. The tenant identity is
         resolved HERE (explicit X-Tenant-Id riding the contextvar, else
@@ -134,7 +136,8 @@ class Explorer:
             return None
         return co.submit(shard, vecs, k, flt=flt,
                          include_vector=include_vector,
-                         tenant=robustness.effective_tenant(idx.class_name))
+                         tenant=robustness.effective_tenant(idx.class_name),
+                         wait_now=wait_now)
 
     # -- vector resolution (near_params_vector.go) ---------------------------
 
@@ -318,9 +321,13 @@ class Explorer:
                 )
                 # coalescer first: a narrow group (the gRPC single-Search /
                 # REST shape) merges with other in-flight requests into one
-                # padded dispatch; wide groups bypass inside submit()
+                # padded dispatch; wide groups bypass inside submit(). A
+                # request of ONE slot has nothing else to enqueue or wait
+                # for before its `done()` below; any other defers it, and
+                # must not be served on this thread meanwhile
                 done = self._coalesce_submit(
-                    idx, vecs, limit + offset, None, inc_vec)
+                    idx, vecs, limit + offset, None, inc_vec,
+                    wait_now=len(params_list) == 1)
                 if done is None:
                     if hasattr(idx, "object_vector_search_async"):
                         done = idx.object_vector_search_async(
@@ -396,13 +403,16 @@ class Explorer:
                         inc_vec: bool) -> list[int]:
         """A nearVector group in which some slot carries a filter: enqueue
         it whole, one filter (or none) a slot, where the index serves that
-        (hnsw_tpu on a single local shard) -> []; else search the filtered
-        slots one by one here (the mesh index and the coalescer's lanes
-        take one filter a dispatch) -> the slots without a filter, which go
-        on as a group. A group of ONE slot stays on the single path, which
-        has the coalescer's per-filter lanes and the host fallback."""
+        (hnsw_tpu on a single local shard) -> [], with or without a
+        coalescer: a group already shares its dispatches; else search the
+        filtered slots one by one here (the mesh index takes one filter a
+        dispatch) -> the slots without a filter, which go on as a group. A
+        group of ONE slot stays on the single path, which has the
+        coalescer's per-filter lanes and the host fallback; the slots of a
+        group that falls back go direct, one after the other (a request's
+        own slots, not narrow requests that meet at the server)."""
         done = None
-        if len(idxs) > 1 and self.coalescer is None:
+        if len(idxs) > 1:
             try:
                 idx = self._index(class_name)
                 submit = getattr(idx, "object_vector_search_multi_async", None)
@@ -431,7 +441,7 @@ class Explorer:
                 rest.append(i)
                 continue
             try:
-                out[i] = self._get_one(params_list[i])
+                out[i] = self._get_one(params_list[i], lanes=len(idxs) == 1)
             except Exception as e:
                 out[i] = e
         return rest
@@ -443,7 +453,10 @@ class Explorer:
             raise TraverserError(f"class {class_name!r} not found")
         return idx
 
-    def _get_one(self, params: GetParams) -> list[SearchResult]:
+    def _get_one(self, params: GetParams,
+                 lanes: bool = True) -> list[SearchResult]:
+        """One query by itself. `lanes`: a kNN may ride the coalescer's
+        lanes (not where it is one slot of a group served slot by slot)."""
         idx = self._index(params.class_name)
         limit = params.limit or self.query_limit
         if limit + params.offset > self.max_results:
@@ -467,7 +480,7 @@ class Explorer:
             if vec is not None:
                 target = self._near_threshold(params, idx)
                 res = None
-                if target is None:
+                if target is None and lanes:
                     # coalesce single kNN queries cross-request; filtered
                     # queries lane per filter SIGNATURE (a shared filter
                     # coalesces, a one-off allowList bypasses inside
@@ -475,7 +488,8 @@ class Explorer:
                     # iterative widening can't share a fixed-k dispatch.
                     wait = self._coalesce_submit(
                         idx, np.asarray(vec, np.float32)[None, :],
-                        limit + params.offset, params.filters, inc_vec)
+                        limit + params.offset, params.filters, inc_vec,
+                        wait_now=True)
                     if wait is not None:
                         try:
                             res = wait()[0][params.offset:]
