@@ -16,7 +16,8 @@ RUN apt-get update && apt-get install -y --no-install-recommends \
 WORKDIR /app
 COPY native/ native/
 COPY weaviate_tpu/ weaviate_tpu/
-# compile the native engines (CPU HNSW graph, gRPC reply marshaller) into
+# compile the native engines (CPU HNSW graph, gRPC reply marshaller, LSM
+# point gets, the compressed tier's float32 rescoring) into
 # weaviate_tpu/_native — the runtime never needs a compiler. Portable
 # baseline ISA: the image must run on any x86-64-v2 host, not just the
 # build machine (-march=native would SIGILL elsewhere).

@@ -14,3 +14,5 @@ g++ -O3 $ARCH_FLAGS -std=c++17 -shared -fPIC -o "$OUT_DIR/libreply.so" reply.cpp
 echo "built $OUT_DIR/libreply.so"
 g++ -O3 $ARCH_FLAGS -std=c++17 -shared -fPIC -o "$OUT_DIR/liblsmget.so" lsm_get.cpp
 echo "built $OUT_DIR/liblsmget.so"
+g++ -O3 $ARCH_FLAGS -std=c++17 -pthread -shared -fPIC -o "$OUT_DIR/librescore.so" rescore.cpp
+echo "built $OUT_DIR/librescore.so"

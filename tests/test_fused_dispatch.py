@@ -31,7 +31,7 @@ import numpy as np
 import pytest
 
 from weaviate_tpu.entities.vectorindex import parse_and_validate_config
-from weaviate_tpu.index import tpu
+from weaviate_tpu.index import rescore_native, tpu
 from weaviate_tpu.index.tpu import TpuVectorIndex
 from weaviate_tpu.monitoring import costmodel, perf, tracing
 from weaviate_tpu.ops import gmin_scan, pq_gmin
@@ -157,9 +157,16 @@ def _host_translated(call, snap, b, k, q=None):
         top, slots = unpack_topk(np.asarray(out))
     top, slots = top[:b], slots[:b]
     if q is not None:
-        rows = snap.host_vecs[np.clip(slots, 0, None)]
-        top = tpu._host_distances(rows, q.astype(np.float32), "l2-squared")
-        top[slots < 0] = np.inf
+        # scored as the index scores them: the one native pass where its
+        # library serves (its sums have their own order: tests/
+        # test_rescore_native.py holds them to numpy's), numpy otherwise
+        top, _ = rescore_native.distances(
+            snap.host_vecs, slots, q.astype(np.float32), "l2-squared")
+        if top is None:
+            rows = snap.host_vecs[np.clip(slots, 0, None)]
+            top = tpu._host_distances(rows, q.astype(np.float32),
+                                      "l2-squared")
+            top[slots < 0] = np.inf
         order = np.argsort(top, axis=1, kind="stable")
         top = np.take_along_axis(top, order, axis=1)
         slots = np.take_along_axis(slots, order, axis=1)

@@ -698,6 +698,10 @@ UNREGISTERED_ALLOWLIST = {
         "host-only geo index, no device work, leaf lock",
     "weaviate_tpu/index/hnsw.py:_lib_lock":
         "one-time native library load guard (module import scope)",
+    "weaviate_tpu/index/rescore_native.py:_lib_lock":
+        "one-time native library load guard: taken when an index enters "
+        "the compressed form, never by a request; no lock taken under it "
+        "but _native's own build lock",
     "weaviate_tpu/index/hnsw.py:HnswIndex._lock":
         "host-only hnswlib engine, leaf lock, no device calls under it",
     "weaviate_tpu/serving/controller.py:_TokenBuckets._lock":

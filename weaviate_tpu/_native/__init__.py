@@ -4,8 +4,8 @@ The ``.so`` files are git-ignored build outputs, so a checkout builds them on
 first use — and rebuilds one whenever it is older than its source, so a
 library left over from an older ``native/*.cpp`` is never loaded as is.
 ``STATUS`` records what happened to each library in this process; ``/v1/meta``
-serves it, so a build that was attempted and failed (two of the three loaders
-then serve from their Python twin) is visible instead of silent.
+serves it, so a build that was attempted and failed (three of the four
+loaders then serve from their Python twin) is visible instead of silent.
 """
 
 from __future__ import annotations
@@ -22,6 +22,7 @@ _LIBS = {
     "hnsw": ("hnsw.cpp", ("-fopenmp",)),
     "reply": ("reply.cpp", ()),
     "lsmget": ("lsm_get.cpp", ()),
+    "rescore": ("rescore.cpp", ("-pthread",)),
 }
 
 # library -> "loaded" (up to date on disk) | "built" (compiled by this

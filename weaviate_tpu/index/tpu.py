@@ -59,7 +59,7 @@ import numpy as np
 
 from weaviate_tpu import device
 from weaviate_tpu.entities import vectorindex as vi
-from weaviate_tpu.index import group_inputs
+from weaviate_tpu.index import group_inputs, rescore_native
 from weaviate_tpu.index.interface import (AllowList, SnapshotRetired,
                                           VectorIndex)
 # the tier and the program of a dispatch are chosen in index/plan.py, once
@@ -3631,6 +3631,11 @@ class TpuVectorIndex(VectorIndex):
         # the bf16 copy stays in HBM: half the f32 footprint the codes
         # replace, and the scan that selects the candidates reads it
         rescore = self.config.pq.rescore
+        if rescore:
+            # the library that scores its candidates (`_rescore_f32`) is
+            # built, if this checkout has not yet, and loaded HERE: a
+            # restore or a compression, never a request
+            rescore_native.load()
         self._rescore_dev = (jax.device_put(
             jnp.zeros((cap, self.dim), jnp.bfloat16), dev)
             if rescore else None)
@@ -4437,36 +4442,55 @@ class TpuVectorIndex(VectorIndex):
         their distances are computed here in float32 from the rows the
         host keeps (`host_vecs`), the top k is taken from THOSE, and the
         reply carries those. Compression may cost recall, never a
-        distance. One gather into a pooled buffer and one contraction,
-        both numpy calls that let go of the GIL; no loop over rows."""
+        distance. One native call (index/rescore_native.py) reads every
+        candidate's row once and scores it in registers, off the GIL and,
+        for a wide dispatch, on a few threads of its own; where it cannot
+        serve, numpy gathers the rows and contracts them (`_gather_score`).
+        `/debug/perf` `rescore.by` counts which."""
         ids, _, slots = unpack_fused_slots(packed[:b])
         r = slots.shape[1]
         phase = tracing.Phase("rescore") if shape is not None else None
+        d, why = rescore_native.distances(
+            snap.host_vecs, slots, q[:b], self.metric)
+        if d is None:
+            d = self._gather_score(snap, q[:b], slots)
+        # stable: candidates that tie keep the scan's order
+        order = np.argsort(d, axis=1, kind="stable")[:, :k]
+        dists = np.take_along_axis(d, order, axis=1).astype(
+            np.float32, copy=False)
+        ids = np.take_along_axis(ids, order, axis=1)
+        # bytes: what the phase read from `host_vecs`, either way
+        rows, nbytes = b * r, b * r * snap.dim * 4
+        # winners the float32 distances moved from the rank the bf16
+        # rows gave them: 0 says R is deeper than the rounding needs
+        promoted = int(np.count_nonzero(
+            (order != np.arange(order.shape[1])) & np.isfinite(dists)))
+        perf.note_rescore(rows, nbytes, promoted,
+                          perf.RESCORE_NATIVE if why is None
+                          else f"numpy:{why}")
+        if phase is not None:
+            end_ns = phase.end(rows=rows, bytes=nbytes)
+            shape.rescore_ms = (end_ns - phase.start_ns) / 1e6
+        return ids, dists
+
+    def _gather_score(self, snap: IndexSnapshot, q: np.ndarray,
+                      slots: np.ndarray) -> np.ndarray:
+        """`_rescore_f32`'s distances without the native library, and the
+        plain reference its tests compare against: one gather of the
+        candidates' rows into a pooled buffer and one contraction, both
+        numpy calls that let go of the GIL. -> [B, R] f32, +inf at -1."""
+        b, r = slots.shape
         # pooled: fresh pages a dispatch cost more than the copy (PERF.md,
         # PR 28)
         buf = self._checkout_stage((b, r, snap.dim))
         try:
             np.take(snap.host_vecs, np.maximum(slots, 0).ravel(), axis=0,
                     out=buf.reshape(b * r, snap.dim), mode="clip")
-            d = _host_distances(buf, q[:b], self.metric)
+            d = _host_distances(buf, q, self.metric)
         finally:
             self._release_stage(buf)
         d[slots < 0] = np.inf
-        # stable: candidates that tie keep the scan's order
-        order = np.argsort(d, axis=1, kind="stable")[:, :k]
-        dists = np.take_along_axis(d, order, axis=1).astype(
-            np.float32, copy=False)
-        ids = np.take_along_axis(ids, order, axis=1)
-        rows, nbytes = b * r, b * r * snap.dim * 4
-        # winners the float32 distances moved from the rank the bf16
-        # rows gave them: 0 says R is deeper than the rounding needs
-        promoted = int(np.count_nonzero(
-            (order != np.arange(order.shape[1])) & np.isfinite(dists)))
-        perf.note_rescore(rows, nbytes, promoted)
-        if phase is not None:
-            end_ns = phase.end(rows=rows, bytes=nbytes)
-            shape.rescore_ms = (end_ns - phase.start_ns) / 1e6
-        return ids, dists
+        return d
 
     def _finalize_fused(self, packed_dev, shape, b: int,
                         k: Optional[int] = None):

@@ -19,8 +19,9 @@ What the device does is read from a profiler capture and nowhere else
   segment tables probed, key bytes compared, arenas grown;
 - the **rescore counters**: what the float32 rescoring of a compressed
   index's candidates read from the host's rows (index/tpu.py
-  ``_rescore_f32``): dispatches, candidate rows scored, bytes gathered,
-  winners the float32 distances moved from their bf16 rank;
+  ``_rescore_f32``): dispatches, candidate rows scored, bytes read,
+  winners the float32 distances moved from their bf16 rank, and the
+  dispatches by what scored them (``by``: the native pass, or numpy and why);
 - the **capture log**: while ``profiling.device_trace`` has a profiler
   session open, every closed host phase is kept as ``(name, thread id,
   start_ns, end_ns)`` on ``time.perf_counter_ns``, anchored at the stamp
@@ -126,6 +127,11 @@ _PHASE_SAMPLES_MAX = 16384
 # the Python walk had to (storage/lsm.py Bucket.roaring_get names them)
 POSTING_NATIVE = "native"
 POSTING_MEMTABLE = "memtable_only"
+
+# what scored a compressed dispatch's candidates (`note_rescore`): the
+# one-pass native call, or `numpy:<reason>` (index/rescore_native.py names
+# the reasons)
+RESCORE_NATIVE = "native"
 
 
 class DutyCycle:
@@ -352,12 +358,14 @@ class PerfWindow:
             while d[0][0] < horizon:
                 d.popleft()
 
-    def note_rescore(self, rows: int, nbytes: int, promoted: int) -> None:
-        """One compressed dispatch's float32 rescoring on the host."""
+    def note_rescore(self, rows: int, nbytes: int, promoted: int,
+                     by: str) -> None:
+        """One compressed dispatch's float32 rescoring on the host, and
+        what served it (`RESCORE_NATIVE`, or `numpy:<reason>`)."""
         now = time.monotonic()
         with self._lock:
             d = self._rescore
-            d.append((now, rows, nbytes, promoted))
+            d.append((now, rows, nbytes, promoted, by))
             horizon = now - self.window_s
             while d[0][0] < horizon:
                 d.popleft()
@@ -588,8 +596,11 @@ class PerfWindow:
                     probed.append(ivf)
             total_dispatches = self._total_dispatches
             point_get = [sum(c) for c in list(zip(*self._point_get))[1:]]
-            rescore = [sum(c) for c in list(zip(*self._rescore))[1:]]
+            rescore = [sum(c) for c in list(zip(*self._rescore))[1:4]]
             rescores = len(self._rescore)
+            rescored_by: dict[str, int] = {}
+            for e in self._rescore:
+                rescored_by[e[4]] = rescored_by.get(e[4], 0) + 1
             postings = [sum(c) for c in list(zip(*self._postings))[1:4]]
             walks: dict[str, int] = {}
             for e in self._postings:
@@ -649,9 +660,13 @@ class PerfWindow:
             # window: `rows / dispatches` is the candidates a dispatch
             # scored from the host's rows (queries x R), `promoted` the
             # winners whose rank the float32 distances changed: near 0
-            # says R is deeper than the bf16 rounding needs
+            # says R is deeper than the bf16 rounding needs; `by` the
+            # dispatches by what scored them: `native` the one-pass call
+            # (index/rescore_native.py), `numpy:<reason>` the gather and
+            # contraction that serve where it cannot
             out["rescore"] = {"dispatches": rescores, **dict(zip(
-                ("rows", "bytes", "promoted"), rescore))}
+                ("rows", "bytes", "promoted"), rescore)),
+                "by": dict(sorted(rescored_by.items()))}
         if postings:
             # the roaring-set posting reads over the window (every filter
             # leaf, BM25 and hybrid allowLists, `keys()`): `native` calls
@@ -1272,12 +1287,12 @@ def note_point_get(*counts, **events) -> None:
         w.note_point_get(*counts, **events)
 
 
-def note_rescore(rows: int, nbytes: int, promoted: int) -> None:
+def note_rescore(rows: int, nbytes: int, promoted: int, by: str) -> None:
     """`PerfWindow.note_rescore` on the installed window; one comparison
     while the plane is down."""
     w = _window
     if w is not None:
-        w.note_rescore(rows, nbytes, promoted)
+        w.note_rescore(rows, nbytes, promoted, by)
 
 
 def note_group_inputs(lists: int, ids: int, reason: Optional[str],
